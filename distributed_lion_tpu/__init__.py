@@ -23,8 +23,4 @@ Brand-new JAX/XLA/Pallas design, not a port:
                        reference's ``--lion`` / ``--async_grad`` surface.
 """
 
-from distributed_lion_tpu import compat as _compat  # publishes jax.shard_map on old jax
-
-_compat.install()
-
 __version__ = "0.1.0"

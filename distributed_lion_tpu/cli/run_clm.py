@@ -31,7 +31,6 @@ from __future__ import annotations
 
 import dataclasses
 import glob
-import os
 from typing import Optional
 
 
@@ -118,6 +117,9 @@ def build_mesh(tensor_parallel: int = 1, seq_parallel: int = 1,
         make_mesh,
         multihost_initialize,
     )
+    from distributed_lion_tpu.utils.compile_cache import (
+        enable_compilation_cache,
+    )
 
     force_cpu_platform()
     # distributed init FIRST: the cache gate probes jax.default_backend(),
@@ -130,68 +132,6 @@ def build_mesh(tensor_parallel: int = 1, seq_parallel: int = 1,
     enable_compilation_cache()
     return make_mesh(tensor=tensor_parallel, seq=seq_parallel,
                      pipe=pipeline_parallel, expert=expert_parallel)
-
-
-def _host_signature() -> str:
-    """Short hash of the host's CPU identity. The cache directory is scoped
-    by it because $HOME persists while sessions migrate across hosts —
-    XLA:CPU AOT executables compiled on one machine SIGILL/abort when
-    loaded on another with different CPU features (observed in practice:
-    a cache populated on a prior host fatally aborted later CLI runs)."""
-    import hashlib
-    import platform
-
-    ident = platform.machine()
-    seen = set()
-    try:
-        with open("/proc/cpuinfo") as f:
-            for line in f:
-                key = line.split(":", 1)[0].strip()
-                # model name AND flags: same model can expose different
-                # feature sets under different hypervisors/microcode
-                if key in ("flags", "model name", "Features") and key not in seen:
-                    seen.add(key)
-                    ident += line
-    except OSError:
-        pass
-    return hashlib.sha1(ident.encode()).hexdigest()[:10]
-
-
-def enable_compilation_cache() -> None:
-    """Persistent XLA compilation cache (~20-40s per TPU compile amortized
-    across runs). Opt-out with DLION_COMPILE_CACHE=0; directory override via
-    DLION_COMPILE_CACHE_DIR.
-
-    TPU backend only. XLA:CPU AOT cache entries compiled on one host
-    fatally abort the process when loaded on a host with different CPU
-    features, and the per-CPU-signature directory suffix cannot fully
-    discriminate hosts (XLA feature-detects via cpuid; /proc/cpuinfo can be
-    virtualized identically across different hardware — an abort was still
-    observed under the signature scheme). CPU compiles are fast enough that
-    caching them buys little, so the cache is simply not enabled off-TPU;
-    the signature suffix is kept as defense in depth for session migration
-    between TPU hosts. Pin DLION_COMPILE_CACHE_DIR to share a cache across
-    known-identical hosts."""
-    import jax
-
-    if os.environ.get("DLION_COMPILE_CACHE", "1") == "0":
-        return
-    try:
-        backend = jax.default_backend()
-    except RuntimeError:
-        return
-    if backend != "tpu":
-        return
-    cache_dir = os.environ.get(
-        "DLION_COMPILE_CACHE_DIR",
-        os.path.join(os.path.expanduser("~"), ".cache",
-                     f"dlion_xla_{_host_signature()}"),
-    )
-    try:
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
-    except Exception as e:  # older jax without the knob: run uncached
-        print(f"[run_clm] compilation cache unavailable: {e}")
 
 
 VOCAB_PROBE_TOKENS = 4_000_000  # sample budget for the token-id range check

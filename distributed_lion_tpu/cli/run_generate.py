@@ -57,20 +57,13 @@ def _is_hf_dir(path: Optional[str]) -> bool:
         os.path.join(path, "config.json"))
 
 
-def build(args: GenerateArguments):
+def resolve_model_path(args: GenerateArguments) -> None:
+    """Point ``args.model_path`` at the weights file when it names a
+    training ``--output_dir`` (the weights live at ``<dir>/model.npz``)."""
     import os
-
-    import jax
-
-    from distributed_lion_tpu.data.tokenizer import load_tokenizer
-    from distributed_lion_tpu.utils.serialization import load_pytree
-
-    tok = load_tokenizer(args.tokenizer_name)
-    vocab = args.vocab_size or tok.vocab_size
 
     if (args.model_path and os.path.isdir(args.model_path)
             and not _is_hf_dir(args.model_path)):
-        # a training --output_dir: the weights live at <dir>/model.npz
         npz = os.path.join(args.model_path, "model.npz")
         if os.path.isfile(npz):
             args.model_path = npz
@@ -79,6 +72,40 @@ def build(args: GenerateArguments):
                 f"{args.model_path!r} is a directory with neither config.json "
                 "(HF checkpoint) nor model.npz (training output)"
             )
+
+
+def check_checkpoint(args: GenerateArguments):
+    """Tokenizer + a device-free look at the checkpoint: what a parent that
+    must stay off JAX (``run_serve --replica_procs`` — the children own the
+    chip) can do to fail fast on a bad ``--model_path`` before spawning
+    workers that would each fail slower. Returns the tokenizer."""
+    import numpy as np
+
+    from distributed_lion_tpu.data.tokenizer import load_tokenizer
+
+    tok = load_tokenizer(args.tokenizer_name)
+    resolve_model_path(args)
+    if _is_hf_dir(args.model_path):
+        from distributed_lion_tpu.models import hf_import
+
+        hf_import.detect_family(args.model_path)  # numpy/json only
+    elif args.model_path:
+        with np.load(args.model_path) as data:  # reads the zip directory
+            if not data.files:
+                raise ValueError(
+                    f"checkpoint {args.model_path!r} holds no arrays")
+    return tok
+
+
+def build(args: GenerateArguments):
+    import jax
+
+    from distributed_lion_tpu.data.tokenizer import load_tokenizer
+    from distributed_lion_tpu.utils.serialization import load_pytree
+
+    tok = load_tokenizer(args.tokenizer_name)
+    vocab = args.vocab_size or tok.vocab_size
+    resolve_model_path(args)
 
     hf_params = hf_cfg = None
     if _is_hf_dir(args.model_path):
@@ -137,8 +164,12 @@ def main(argv=None):
     import jax
 
     from distributed_lion_tpu.parallel.mesh import force_cpu_platform
+    from distributed_lion_tpu.utils.compile_cache import (
+        enable_compilation_cache,
+    )
 
     force_cpu_platform()
+    enable_compilation_cache()
     import jax.numpy as jnp
     import numpy as np
 

@@ -126,7 +126,8 @@ class ServeArguments:
     # pipe protocol — replica failure becomes a real OS event (the
     # replica_kill fault SIGKILLs the child mid-decode; migration stays
     # token-identical from the fleet's shadow). The parent loads the
-    # checkpoint once (tokenizer + validation); each child loads its own
+    # tokenizer and validates the checkpoint WITHOUT touching JAX (the
+    # children own the device); each child loads its own
     # copy — real isolation costs real memory. Implies the fleet path
     # even at --replicas 1.
     heartbeat_timeout_s: float = 60.0  # per-tick reply deadline for a
@@ -276,15 +277,17 @@ def build_fleet(gen_args, serve_args: "ServeArguments"):
     from distributed_lion_tpu.serve.replica_plane import ServingFleet
 
     if serve_args.replica_procs:
-        from distributed_lion_tpu.cli.run_generate import build
+        from distributed_lion_tpu.cli.run_generate import check_checkpoint
         from distributed_lion_tpu.serve.fleet_proc import (
             process_replica_factory)
 
-        # the parent builds once for the tokenizer (and to fail fast on
-        # a bad checkpoint BEFORE spawning N children that would each
-        # fail slower); children load their own weights — process
+        # the parent stays OFF JAX: a chip belongs to one process, and a
+        # parent that opened it would lock every child out. It loads the
+        # tokenizer and validates the checkpoint without a device (to
+        # fail fast on a bad path BEFORE spawning N children that would
+        # each fail slower); children load their own weights — process
         # isolation is not free, it is the point
-        tok, _, _, _, _ = build(gen_args)
+        tok = check_checkpoint(gen_args)
         builder = {"kind": "cli",
                    "gen": dataclasses.asdict(gen_args),
                    "serve": dataclasses.asdict(serve_args)}
@@ -313,6 +316,14 @@ def main(argv=None):
 
     gen_args, args = parse_dataclasses((GenerateArguments, ServeArguments),
                                        argv)
+    if not args.replica_procs:
+        # (a --replica_procs parent must not initialize a backend; each
+        # replica_worker child enables the cache for itself)
+        from distributed_lion_tpu.utils.compile_cache import (
+            enable_compilation_cache,
+        )
+
+        enable_compilation_cache()
     if args.replicas < 1:
         raise ValueError(f"--replicas must be >= 1, got {args.replicas}")
     if args.inject_serve and args.replicas < 2:

@@ -6,8 +6,8 @@
 
 Each candidate runs as a CHILD process under a hard per-candidate timeout
 (``ops/autotune.run_trial_child``) covering compile AND run — round 3 lost
->14 min of a TPU window to one hand-picked flash tile (1024x1024) hanging
-remote compile; under the tuner the worst a pathological tile can cost is
+>14 min of chip time to one hand-picked flash tile (1024x1024) hanging the
+compile; under the tuner the worst a pathological tile can cost is
 ``--timeout_s``. Winners (minimum ms, ties to the smallest tile —
 ``autotune.select_winner``) are merged into the device-keyed tuning cache
 (``scripts/tuning_cache.json`` by default, ``$DLT_TUNE_CACHE`` override),

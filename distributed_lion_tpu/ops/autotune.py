@@ -3,9 +3,9 @@
 ROADMAP item 1's remaining levers are all TILE choices — flash-attention
 fwd/bwd blocking, splash blocking, the Pallas lion ``row_block``, the vocab
 chunk count, the vote-bucket count — and until now every one of them was a
-hand-enumerated shell config in ``scripts/tpu_runbook_auto2.sh``. One bad
-hand pick (``flash@1024x1024``) hung remote compile for >14 minutes and ate
-a chunk of a TPU window. This module makes tile choice a MEASUREMENT with
+hand-enumerated shell config. One bad hand pick (``flash@1024x1024``) hung
+the compile for >14 minutes of chip time. This module makes tile choice a
+MEASUREMENT with
 three hard properties:
 
 1. **Per-candidate timeout guards.** Every timed trial runs in a child
@@ -283,7 +283,7 @@ def tile_candidates(knob: str, info: dict) -> list:
         sizes = [s for s in (128, 256, 512, 1024) if s <= max(t, 128)]
         cands = [{"block_q": bq, "block_kv": bkv}
                  for bq in sizes for bkv in sizes]
-        # flash@1024x1024 hung remote compile >14 min in round 3; keep it
+        # flash@1024x1024 hung the compile >14 min in round 3; keep it
         # OUT of the default grid — the timeout guard would absorb it, but
         # a known-bad tile should not burn a budget on every device
         return [c for c in cands

@@ -225,7 +225,10 @@ def _stats_kernel(w: int, nbins: int, ballot_ref, tot_ref, mask_ref, out_ref):
     for b in range(nbins):  # static unroll: nbins full-tile VPU reductions
         cnt = jnp.sum(jnp.where(m & (binidx == b), 1, 0))
         upd = upd + jnp.where((row == 0) & (lane == b), cnt, 0)
-    dis = jnp.sum(jnp.where(m & ((ballot_ref[:] > 0) != (t > 0)), 1, 0))
+    # widen the int8 ballots before comparing: Mosaic on v5e has no packed
+    # int8 vector compare (arith.cmpi on vector<8x128x4xi8> is refused)
+    b = ballot_ref[:].astype(jnp.int32)
+    dis = jnp.sum(jnp.where(m & ((b > 0) != (t > 0)), 1, 0))
     upd = upd + jnp.where((row == 1) & (lane == 0), dis, 0)
     out_ref[...] = out_ref[...] + upd
 

@@ -54,6 +54,7 @@ from distributed_lion_tpu.ops.codec import (
     bucket_bounds,
     pack_signs,
     parse_wire,
+    tally_packed_rows,
     unpack_signs,
 )
 from distributed_lion_tpu.train import resilience
@@ -287,15 +288,13 @@ def vote_total(vote_pos: jnp.ndarray, axis_name: str, wire: str,
         if w > 1:
             WIRE_TALLY.record("ici", w * packed.size)
         gathered = lax.all_gather(packed, axis_name)   # [W, ceil(n/8)] uint8
-        bits = unpack_signs(gathered.reshape(-1), (w, gathered.shape[1] * 8))
         if alive is not None:
             # every worker holds the full ballot matrix here, so masking is
             # a row weighting: count over healthy rows, threshold = quorum
             weights = alive.astype(jnp.int32)
-            count = (bits.astype(jnp.int32)
-                     * weights[:, None]).sum(0)[: vote_pos.shape[0]]
+            count = tally_packed_rows(gathered, weights)[: vote_pos.shape[0]]
             return count * 2 - weights.sum()
-        count = bits.astype(jnp.int32).sum(0)[: vote_pos.shape[0]]
+        count = tally_packed_rows(gathered)[: vote_pos.shape[0]]
         return count * 2 - w
     if kind == "packed_a2a":
         # Two-phase vote. The verdict (not the tally) crosses the wire in
@@ -369,15 +368,14 @@ def _packed_a2a_elect(vote_pos: jnp.ndarray, axis_name: str, w: int,
         WIRE_TALLY.record("ici", (w - 1) * chunk)
     # phase 1: worker j receives every worker's row j → [W, chunk]
     arrived = lax.all_to_all(packed, axis_name, split_axis=0, concat_axis=0, tiled=True)
-    bits = unpack_signs(arrived.reshape(-1), (w, chunk * 8))
     if alive is not None:
         # the chunk owner sees every worker's row, so the masked tally is a
         # row weighting; the threshold shrinks to the healthy quorum
         weights = alive.astype(jnp.int32)
-        count = (bits.astype(jnp.int32) * weights[:, None]).sum(0)
+        count = tally_packed_rows(arrived, weights)
         verdict = count * 2 > weights.sum()            # tie → False (−1)
     else:
-        count = bits.astype(jnp.int32).sum(0)          # per-bit True tally
+        count = tally_packed_rows(arrived)             # per-bit True tally
         verdict = count * 2 > w                        # tie → False (−1)
     if w > 1:  # phase 2: (W−1) peers each send me their chunk's verdict
         WIRE_TALLY.record("ici", (w - 1) * chunk)
@@ -519,9 +517,8 @@ def hier_consume(slot: jnp.ndarray, n: int, axis_name: str, w: int,
     effective = launch_mask
     if alive is not None:
         effective = launch_mask & alive.reshape(n_groups, g).any(axis=1)
-    bits = unpack_signs(stack.reshape(-1), (n_groups, chunk))
-    contrib = bits.astype(jnp.int32) * effective.astype(jnp.int32)[:, None]
-    counts = contrib.sum(0)  # [chunk] per-coordinate +1-verdict tally
+    # [chunk] per-coordinate +1-verdict tally over the surviving groups
+    counts = tally_packed_rows(stack, effective.astype(jnp.int32))
     elected_own = counts * 2 > effective.astype(jnp.int32).sum()
 
     # phase 3 — intra-group all-gather of the packed elected chunks.

@@ -237,8 +237,8 @@ class ProcessReplica:
         self._has_work = False
         self._rbuf = bytearray()
         self._dead = False
-        child_env = dict(os.environ)
-        child_env.setdefault("JAX_PLATFORMS", "cpu")
+        child_env = dict(os.environ)  # the platform is inherited, never
+        # chosen here: a child that cannot open its device fails its hello
         # token-identical across the boundary requires the child to
         # sample with the parent's PRNG layout: mirror jax config the
         # parent set PROGRAMMATICALLY (env vars already inherit) into

@@ -77,11 +77,6 @@ def _build_engine(builder: dict):
 
 
 def main(time_fn=time.monotonic, sleep_fn=time.sleep) -> int:
-    # force CPU before jax imports (same discipline as every CLI); the
-    # parent already set JAX_PLATFORMS in the child env, this is the
-    # belt for a directly-invoked worker
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
-
     from distributed_lion_tpu.serve.fleet_proc import (
         completion_to_wire,
         read_frame_blocking,
@@ -101,7 +96,22 @@ def main(time_fn=time.monotonic, sleep_fn=time.sleep) -> int:
     hello = read_frame_blocking(in_fd, buf=rbuf)
     if hello is None or hello.get("cmd") != "build":
         return 1
-    engine = _build_engine(hello["builder"])
+    # the worker inherits its platform from the environment and never
+    # picks one: a worker that cannot open its device (or build its
+    # engine) says so in its hello and exits non-zero
+    try:
+        from distributed_lion_tpu.parallel.mesh import force_cpu_platform
+        from distributed_lion_tpu.utils.compile_cache import (
+            enable_compilation_cache,
+        )
+
+        force_cpu_platform()  # an inherited DLION_PLATFORM request
+        enable_compilation_cache()
+        engine = _build_engine(hello["builder"])
+    except Exception as e:
+        write_frame(proto, {"ok": False, "pid": os.getpid(),
+                            "error": f"{type(e).__name__}: {e}"})
+        raise
     write_frame(proto, {"ok": True, "pid": os.getpid()})
 
     while True:

@@ -9,8 +9,8 @@ environment* rather than writing bytes (that's ``train/checkpoint.py``):
 - :class:`PreemptionGuard` — a SIGTERM/maintenance handler that sets a flag
   the Trainer checks once per dispatch. On trip the loop drains the
   in-flight async save, writes an emergency checkpoint tagged ``preempt``,
-  and returns cleanly so the process exits 0 and the watcher
-  (scripts/tpu_watch_loop.sh) restarts it into a normal resume.
+  and returns cleanly so the process exits 0 and whatever supervises the
+  job restarts the same command into a normal resume.
 - A **fault-injection registry** consumed by ``train/checkpoint.py``'s save
   pipeline, so tests (tests/test_resilience.py) and the runbook's
   resilience stage can simulate a crash mid-save, a slow serializer, or

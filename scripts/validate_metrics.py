@@ -69,8 +69,8 @@ doing so.
         runs/resilience/checkpoints/*/manifest.json
 
 Exit 0 = every file valid. Used by tests/test_telemetry.py,
-tests/test_validate_artifacts.py and the runbook's telemetry stage
-(scripts/tpu_runbook_auto2.sh).
+tests/test_validate_artifacts.py and the telemetry evidence stage
+(scripts/check_evidence.py telemetry).
 """
 
 from __future__ import annotations

@@ -17,7 +17,7 @@ FIXTURES = os.path.join(REPO, "tests", "fixtures", "analysis")
 
 # load lint.py by FILE PATH, the way dependency-light scripts must be able
 # to (scripts/check_evidence.py runs on boxes without jax; importing the
-# package would pull in compat -> jax)
+# package's modules would pull in jax)
 _spec = importlib.util.spec_from_file_location(
     "graft_lint", os.path.join(PKG, "analysis", "lint.py"))
 lint = importlib.util.module_from_spec(_spec)

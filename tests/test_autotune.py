@@ -187,7 +187,7 @@ def test_candidate_order_is_fixed_and_excludes_known_bad_tile():
     assert a == autotune.tile_candidates("flash_tiles", {"t": 1024})
     # ascending sizes (ties → smallest tile via select_winner's index rule)
     assert a[0] == {"block_q": 128, "block_kv": 128}
-    # the tile that hung remote compile >14 min in round 3 stays out
+    # the tile that hung the compile >14 min in round 3 stays out
     assert {"block_q": 1024, "block_kv": 1024} not in a
     assert autotune.tile_candidates("lion_row_block", {}) == [
         {"row_block": rb} for rb in (128, 256, 512, 1024, 2048)]

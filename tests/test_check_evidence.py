@@ -1,7 +1,7 @@
 """Evidence-window capture semantics (ADVICE r3): a window where every
 config failed fast still writes the last config's ERROR row — that must
-NOT mark the stage captured, or the re-arming TPU watcher
-(scripts/tpu_watch_loop.sh) exits with no real data for it."""
+NOT mark the stage captured, or whatever polls the `automation` exit
+condition stops with no real data for it."""
 
 import importlib.util
 import os
@@ -26,8 +26,8 @@ MARKER = {"attn": "flash@512x1024@512x512"}
 
 def test_all_error_window_is_not_captured(tmp_path):
     path = _write(tmp_path, [
-        '{"attn": "flash@512x1024", "error": "rc=1: tunnel died"}',
-        '{"attn": "flash@512x1024@512x512", "error": "rc=1: tunnel died"}',
+        '{"attn": "flash@512x1024", "error": "rc=1: backend died"}',
+        '{"attn": "flash@512x1024@512x512", "error": "rc=1: backend died"}',
     ])
     assert not ce._window_captured(path, MARKER, "tokens_per_sec_per_chip")
 

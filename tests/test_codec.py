@@ -59,3 +59,42 @@ def test_world1_wire_bytes_are_zero():
     log phantom collective traffic."""
     for wire in ("sign_psum", "packed_allgather", "packed_a2a"):
         assert wire_bytes_per_param(1000, 1, wire)["bytes_per_step"] == 0
+
+
+@pytest.mark.parametrize("n", [1, 8, 1023, 32768, 32769, 3 * 32768 + 5])
+def test_packed_bytes_equal_numpy_packbits(n):
+    """The wire format is numpy's little-endian packbits, at sizes on both
+    sides of the codec's internal 32,768-bit group (whole groups, a ragged
+    tail, less than one group) — the TPU-friendly formulation may never
+    change a byte."""
+    import jax
+
+    from distributed_lion_tpu.ops.codec import pack_signs, unpack_signs
+
+    votes = np.random.default_rng(n).random(n) < 0.5
+    ref = np.packbits(votes, bitorder="little")
+    got = np.asarray(jax.jit(pack_signs)(jnp.asarray(votes)))
+    assert got.dtype == np.uint8
+    np.testing.assert_array_equal(got, ref)
+    back = jax.jit(lambda p: unpack_signs(p, (n,)))(jnp.asarray(ref))
+    assert back.dtype == jnp.bool_
+    np.testing.assert_array_equal(np.asarray(back), votes)
+
+
+@pytest.mark.parametrize("rows,nbytes", [(1, 5), (4, 4096), (7, 3 * 4096 + 17)])
+def test_tally_packed_rows_equals_unpacked_sum(rows, nbytes):
+    """The packed wires' row tally (one row at a time under a scan) is the
+    column sum of the unpacked bit matrix, plain and alive-weighted."""
+    import jax
+
+    from distributed_lion_tpu.ops.codec import tally_packed_rows
+
+    rng = np.random.default_rng(rows)
+    packed = rng.integers(0, 256, (rows, nbytes), dtype=np.uint8)
+    alive = rng.integers(0, 2, (rows,)).astype(np.int32)
+    bits = np.unpackbits(packed, axis=1, bitorder="little").astype(np.int32)
+    got = jax.jit(tally_packed_rows)(jnp.asarray(packed))
+    np.testing.assert_array_equal(np.asarray(got), bits.sum(0))
+    got = jax.jit(tally_packed_rows)(jnp.asarray(packed), jnp.asarray(alive))
+    np.testing.assert_array_equal(np.asarray(got),
+                                  (bits * alive[:, None]).sum(0))

@@ -17,7 +17,6 @@ def test_world_130_int32_promotion():
     code = textwrap.dedent("""
         import jax
         jax.config.update("jax_platforms", "cpu")
-        import distributed_lion_tpu  # publishes jax.shard_map on old jax
         import numpy as np, jax.numpy as jnp
         from jax import shard_map
         from jax.sharding import Mesh, PartitionSpec as P

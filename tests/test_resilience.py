@@ -208,7 +208,8 @@ def test_ckpt_stall_metric_async_below_sync_baseline(tmp_path):
     model = _model()
     blocks = _blocks(model)
 
-    resilience.inject_fault("ckpt_slow_commit", 1.2)
+    delay = 1.2
+    resilience.inject_fault("ckpt_slow_commit", delay)
     ts, h_sync = _train(_cfg(str(tmp_path / "sync"), 3, async_ckpt=False),
                         mesh, model, blocks)
     sync_total = ts.checkpointer.total_stall_s  # before close() drains more
@@ -223,10 +224,16 @@ def test_ckpt_stall_metric_async_below_sync_baseline(tmp_path):
 
     # the metric reaches the log stream (the step-3 row pops the boundary)
     assert t_sync and t_async
-    assert max(t_sync) >= 1.2   # sync ate the slow commit inline
-    assert max(t_async) < 0.6   # async boundary = initiation only
-    assert sync_total >= 1.2
-    assert async_total < sync_total - 0.5
+    assert max(t_sync) >= delay   # sync ate the slow commit inline
+    assert sync_total >= delay
+    # async boundary = initiation only. Judged against the sync boundary
+    # MEASURED IN THIS RUN (initiation + the injected delay), not against
+    # absolute seconds: under six loaded xdist workers the initiation
+    # alone can take longer than any fixed bound, but it cannot hide the
+    # commit — the async boundary must sit below the sync one by at least
+    # half the injected delay.
+    assert max(t_async) < max(t_sync) - 0.5 * delay
+    assert async_total < sync_total - 0.5 * delay
     # close() drained the async commit: both checkpoints are committed
     for d in ("sync", "async"):
         assert latest_valid_step_in(tmp_path / d / "checkpoints") == 2

@@ -381,11 +381,12 @@ def test_build_mesh_orders_distributed_init_before_cache(monkeypatch):
     silently-disconnected replicas on multi-host launches)."""
     from distributed_lion_tpu.cli import run_clm
     from distributed_lion_tpu.parallel import mesh as mesh_mod
+    from distributed_lion_tpu.utils import compile_cache
 
     calls = []
     monkeypatch.setattr(mesh_mod, "multihost_initialize",
                         lambda: calls.append("multihost"))
-    monkeypatch.setattr(run_clm, "enable_compilation_cache",
+    monkeypatch.setattr(compile_cache, "enable_compilation_cache",
                         lambda: calls.append("cache"))
     run_clm.build_mesh()
     assert calls == ["multihost", "cache"]
