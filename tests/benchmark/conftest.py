@@ -1,0 +1,9 @@
+"""Tests of the benchmark's own harness (CPU, tiny sizes). The repo root
+goes on sys.path so that ``benchmark`` imports as a package."""
+
+import os
+import sys
+
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
