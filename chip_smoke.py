@@ -65,32 +65,6 @@ def check(ok, what) -> None:
         raise AssertionError(what)
 
 
-class CompileMeter:
-    """Counts persistent-cache hits/misses and sums backend compile seconds
-    through jax.monitoring (what a second run in the same call compares)."""
-
-    def __init__(self):
-        from jax import monitoring
-
-        self.hits = self.misses = 0
-        self.compile_s = 0.0
-        monitoring.register_event_listener(self._event)
-        monitoring.register_event_duration_secs_listener(self._duration)
-
-    def _event(self, name, **_):
-        if name.endswith("/compilation_cache/cache_hits"):
-            self.hits += 1
-        elif name.endswith("/compilation_cache/cache_misses"):
-            self.misses += 1
-
-    def _duration(self, name, secs, **_):
-        if name.endswith("backend_compile_duration"):
-            self.compile_s += secs
-
-    def snapshot(self):
-        return self.hits, self.misses, self.compile_s
-
-
 @contextlib.contextmanager
 def captured(owner, name, sink: list):
     """Record what ``owner.name(...)`` returns (or, for a method, ``self``)
@@ -712,13 +686,15 @@ def main() -> int:
               "devices", file=sys.stderr)
         return 2
 
-    from distributed_lion_tpu.utils.compile_cache import (
-        enable_compilation_cache,
-    )
+    from distributed_lion_tpu.utils import compile_cache
 
-    meter = CompileMeter()
+    def meter():  # what a second run in the same call compares
+        t = compile_cache.totals()
+        return t["cache_hits"], t["cache_misses"], t["compile_s"]
+
     t_start = time.time()
-    device = phase_device(enable_compilation_cache())
+    # turns the cache on and the compile ledger's listeners with it
+    device = phase_device(compile_cache.enable_compilation_cache())
     work = tempfile.mkdtemp(prefix="chip_smoke_")
     out_dir = os.path.join(work, "train")
     phases = ([("kernels", phase_kernels),
@@ -729,18 +705,20 @@ def main() -> int:
     failed = []
     for name, fn in phases:
         log(f"phase {name}")
-        before, t0 = meter.snapshot(), time.time()
+        before, t0 = meter(), time.time()
         try:
             fn()
         except Exception:
             traceback.print_exc()
             failed.append(name)
-        h, m, c = (a - b for a, b in zip(meter.snapshot(), before))
+        h, m, c = (a - b for a, b in zip(meter(), before))
         log(f"phase {name} {'FAILED' if name in failed else 'ok'} in "
             f"{time.time() - t0:.1f} s — compile {c:.1f} s, persistent "
             f"cache hits {h} misses {m}")
     shutil.rmtree(work, ignore_errors=True)
-    h, m, c = meter.snapshot()
+    for line in compile_cache.ledger_lines():
+        log(line)
+    h, m, c = meter()
     log(f"total {time.time() - t_start:.1f} s — backend compile {c:.1f} s, "
         f"persistent cache hits {h} misses {m}")
     if failed:

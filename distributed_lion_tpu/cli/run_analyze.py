@@ -62,8 +62,13 @@ BUCKET_OF = {
     "data_wait": "data",
     "ckpt": "ckpt",
     "logging_drain": "logging",
+    # the loop's own host work between the spans above
+    "retrace_check": "host",
+    "guard_apply": "host",
+    "sentinel_check": "host",
+    "membership": "host",
 }
-NAMED_BUCKETS = ("device", "dispatch", "data", "ckpt", "logging")
+NAMED_BUCKETS = ("device", "dispatch", "data", "ckpt", "logging", "host")
 # |named + other + unattributed − wall| must stay within this fraction of
 # wall (floating accumulation over thousands of spans, nothing more)
 CLOSE_TOL_FRAC = 0.01
@@ -159,15 +164,19 @@ def _bucket(name: str) -> Optional[str]:
     return BUCKET_OF.get(name.split("/", 1)[0])
 
 
-def _step_spans(events: list, rank: int) -> list:
+def _step_spans(events: list, rank: int, roots_only: bool = False) -> list:
     """This rank's step-thread spans. Any span stamped with a ``thread``
     field ran OFF the step thread (the checkpoint committer, the emulated
     DCN link's ``dcn_wait``) and is excluded: such spans overlap the step
-    wall by design and must not count against it."""
+    wall by design and must not count against it. ``roots_only`` also
+    drops spans with a ``parent`` (``serve/prefill`` under ``serve/admit``
+    under ``serve/tick``): a child's time is inside its parent's, and a
+    sum that tiles the wall must not claim it twice."""
     return [r for r in events
             if r.get("kind") == "span" and int(r.get("rank", 0)) == rank
             and isinstance(r.get("dur"), (int, float))
-            and not r.get("thread")]
+            and not r.get("thread")
+            and not (roots_only and r.get("parent") is not None)]
 
 
 def _leg_window(mine: list, key: str) -> tuple:
@@ -211,7 +220,7 @@ def attribute(events: list, rank: Optional[int] = None) -> Optional[dict]:
     wall = max(end - start, 0.0)
     buckets = {b: 0.0 for b in NAMED_BUCKETS}
     other = 0.0
-    for r in _step_spans(mine, rank):
+    for r in _step_spans(mine, rank, roots_only=True):
         if not (start <= r[key] <= end + 1e-9):
             continue
         b = _bucket(str(r.get("name", "")))

@@ -227,6 +227,7 @@ def _qkv_project(x, w):
                       preferred_element_type=jnp.float32).astype(x.dtype)
 
 
+@jax.named_scope("attn")
 def _attention(x, p, cfg: GPT2Config, key, tp_axis=None, seq_axis=None):
     """Causal multi-head attention; f32 softmax for stability.
 
@@ -292,6 +293,7 @@ def _proj(x, w):
     return lora_matmul(x, w)
 
 
+@jax.named_scope("mlp")
 def _mlp(x, p, tp_axis=None):
     if tp_axis is not None:
         x = copy_to_tp_region(x, tp_axis)
@@ -354,10 +356,12 @@ def _moe_block(x, p, key, cfg: GPT2Config, expert_axis=None, tp_axis=None,
     )
     B, T, D = x.shape
     h = _layer_norm(x, p["ln_2"]).reshape(B * T, D)
-    out = moe_ffn(p["moe"], h, capacity_factor=cfg.moe_capacity_factor,
-                  axis_name=expert_axis, tp_axis=tp_axis,
-                  balance_tokens=balance_tokens, balance_axis=balance_axis,
-                  return_tallies=return_tallies)
+    with jax.named_scope("mlp"):
+        out = moe_ffn(p["moe"], h, capacity_factor=cfg.moe_capacity_factor,
+                      axis_name=expert_axis, tp_axis=tp_axis,
+                      balance_tokens=balance_tokens,
+                      balance_axis=balance_axis,
+                      return_tallies=return_tallies)
     if return_tallies:
         y, aux, tally = out
     else:
@@ -375,6 +379,7 @@ def _moe_block_remat_for(cfg):
                    policy=_remat_policy(cfg.remat_policy))(_moe_block)
 
 
+@jax.named_scope("embed")
 def vocab_parallel_embed(wte_shard: jnp.ndarray, tokens: jnp.ndarray,
                          vocab_axis: str, out_dtype=None) -> jnp.ndarray:
     """Megatron VocabParallelEmbedding: ``wte_shard`` [V/tp, d] is this
@@ -434,15 +439,15 @@ def gpt2_hidden(
         pos_start = sidx * T
         if dropout_key is not None:
             dropout_key = jax.random.fold_in(dropout_key, sidx)
-    if vocab_axis is not None:
-        x = vocab_parallel_embed(params["wte"], tokens, vocab_axis,
-                                 out_dtype=cfg.compute_dtype)
-    else:
-        x = params["wte"][tokens]
-    x = x.astype(cfg.compute_dtype)
-    x = x + lax.dynamic_slice_in_dim(params["wpe"], pos_start, T, axis=0).astype(
-        cfg.compute_dtype
-    )
+    with jax.named_scope("embed"):
+        if vocab_axis is not None:
+            x = vocab_parallel_embed(params["wte"], tokens, vocab_axis,
+                                     out_dtype=cfg.compute_dtype)
+        else:
+            x = params["wte"][tokens]
+        x = x.astype(cfg.compute_dtype)
+        x = x + lax.dynamic_slice_in_dim(
+            params["wpe"], pos_start, T, axis=0).astype(cfg.compute_dtype)
     keys = (
         [None] * (cfg.n_layer + 1)
         if dropout_key is None
@@ -506,10 +511,11 @@ def gpt2_apply(
         return_moe_tallies=return_moe_tallies,
     )
     x, aux_total = out[0], out[1]
-    logits = jnp.einsum(
-        "btd,vd->btv", x, params["wte"].astype(x.dtype),
-        preferred_element_type=jnp.float32,
-    )
+    with jax.named_scope("head"):
+        logits = jnp.einsum(
+            "btd,vd->btv", x, params["wte"].astype(x.dtype),
+            preferred_element_type=jnp.float32,
+        )
     # padded-vocab layout: the matmul ran MXU-aligned over padded_vocab
     # columns; slicing back to vocab_size here keeps every downstream
     # consumer (losses, generation, eval) on exact true-vocab semantics
@@ -576,6 +582,7 @@ def gpt2_init_cache(cfg: GPT2Config, batch: int, max_len: int) -> list:
     ]
 
 
+@jax.named_scope("attn")
 def _decode_attention(x, p, cfg: GPT2Config, c, pos, offset=None):
     """Cache-aware attention for S new tokens at absolute position ``pos``:
     project qkv for the new tokens, write k/v into the cache, attend q over
@@ -607,6 +614,7 @@ def _decode_attention(x, p, cfg: GPT2Config, c, pos, offset=None):
     return out, {"k": k_cache, "v": v_cache}
 
 
+@jax.named_scope("mlp")
 def _decode_mlp(x, p, cfg: GPT2Config, tp_axis=None, valid=None,
                 ep_axis=None, moe_stats=None, stats_axis=None,
                 stats_lanes=None):
@@ -655,6 +663,7 @@ def _decode_mlp(x, p, cfg: GPT2Config, tp_axis=None, valid=None,
     return x + _mlp(_layer_norm(x, p["ln_2"]), p["mlp"], tp_axis)
 
 
+@jax.named_scope("embed")
 def _decode_embed(params, tokens, cfg: GPT2Config, pos, offset):
     """Token + position embeddings for a decode chunk. Scalar ``pos``
     slices wpe uniformly; with per-row ``offset`` (left-padded batch) each
@@ -675,6 +684,7 @@ def _decode_embed(params, tokens, cfg: GPT2Config, pos, offset):
     return x + lora_embed(params["wpe"], pos_ids, cfg.compute_dtype)
 
 
+@jax.named_scope("head")
 def _tied_logits(x, params, cfg: GPT2Config):
     from distributed_lion_tpu.ops.quant import maybe_dequant
 
@@ -714,6 +724,7 @@ def gpt2_decode(params: dict, tokens: jnp.ndarray, cfg: GPT2Config, cache: list,
     return _tied_logits(x, params, cfg), new_cache
 
 
+@jax.named_scope("attn")
 def _paged_attention_block(x, p, cfg: GPT2Config, c, tables, pos, valid,
                            tp_axis=None):
     """The paged twin of :func:`_decode_attention`: scatter the new k/v
@@ -792,8 +803,9 @@ def gpt2_decode_paged(params: dict, tokens: jnp.ndarray, cfg: GPT2Config,
                        0, cfg.n_ctx - 1)
     from distributed_lion_tpu.models.lora import lora_embed
 
-    x = lora_embed(params["wte"], tokens, cfg.compute_dtype)
-    x = x + lora_embed(params["wpe"], pos_ids, cfg.compute_dtype)
+    with jax.named_scope("embed"):
+        x = lora_embed(params["wte"], tokens, cfg.compute_dtype)
+        x = x + lora_embed(params["wpe"], pos_ids, cfg.compute_dtype)
     stats = [] if return_moe_stats else None
     new_pages = []
     for p, c in zip(params["blocks"], pages):

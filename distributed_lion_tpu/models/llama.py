@@ -171,6 +171,7 @@ def _matmul(x, w):
     return lora_matmul(x, w)
 
 
+@jax.named_scope("attn")
 def _attention(x, p, cfg: LlamaConfig, cos, sin, tp_axis=None, seq_axis=None):
     """GQA attention; with ``tp_axis``, wq/wk/wv are column-parallel (this
     device holds n_head/tp query and n_kv_head/tp kv heads) and wo is
@@ -210,6 +211,7 @@ def _attention(x, p, cfg: LlamaConfig, cos, sin, tp_axis=None, seq_axis=None):
     return out
 
 
+@jax.named_scope("mlp")
 def _mlp(x, p, tp_axis=None):
     if tp_axis is not None:
         x = copy_to_tp_region(x, tp_axis)
@@ -245,6 +247,7 @@ def llama_init_cache(cfg: LlamaConfig, batch: int, max_len: int) -> list:
     ]
 
 
+@jax.named_scope("attn")
 def _decode_attention(x, p, cfg: LlamaConfig, c, pos, cos, sin, offset=None):
     """``offset`` (optional [B] int32): per-row left-pad width in a
     batched variable-length prompt — cache slots below it are masked out
@@ -279,6 +282,7 @@ def _decode_attention(x, p, cfg: LlamaConfig, c, pos, cos, sin, offset=None):
     return _matmul(out, p["wo"]), {"k": k_cache, "v": v_cache}
 
 
+@jax.named_scope("head")
 def _head_logits(x, params):
     return jnp.einsum("btd,dv->btv", x,
                       maybe_dequant(params["lm_head"], x.dtype).astype(x.dtype),
@@ -296,7 +300,8 @@ def llama_decode(params: dict, tokens: jnp.ndarray, cfg: LlamaConfig, cache: lis
     B, S = tokens.shape
     from distributed_lion_tpu.models.lora import lora_embed
 
-    x = lora_embed(params["wte"], tokens, cfg.compute_dtype)
+    with jax.named_scope("embed"):
+        x = lora_embed(params["wte"], tokens, cfg.compute_dtype)
     # rope tables at the absolute positions of these S tokens: build a
     # max-length table once and slice at pos (pos is traced under jit)
     cos_all, sin_all = rope_angles(cache[0]["k"].shape[2], cfg.head_dim, cfg.rope_theta)
@@ -318,6 +323,7 @@ def llama_decode(params: dict, tokens: jnp.ndarray, cfg: LlamaConfig, cache: lis
     return _head_logits(x, params), new_cache
 
 
+@jax.named_scope("attn")
 def _paged_attention_block(x, p, cfg: LlamaConfig, c, tables, pos, cos, sin,
                            valid, tp_axis=None):
     """The paged twin of :func:`_decode_attention` (serve/kv_cache layout):
@@ -371,7 +377,8 @@ def llama_decode_paged(params: dict, tokens: jnp.ndarray, cfg: LlamaConfig,
     B, S = tokens.shape
     from distributed_lion_tpu.models.lora import lora_embed
 
-    x = lora_embed(params["wte"], tokens, cfg.compute_dtype)
+    with jax.named_scope("embed"):
+        x = lora_embed(params["wte"], tokens, cfg.compute_dtype)
     max_pos = tables.shape[1] * pages[0]["k"].shape[1]
     cos_all, sin_all = rope_angles(max_pos, cfg.head_dim, cfg.rope_theta)
     pos_ids = jnp.clip(pos[:, None] + jnp.arange(S)[None, :], 0, max_pos - 1)
@@ -408,7 +415,8 @@ def llama_hidden(
         offset = jax.lax.axis_index(seq_axis) * T
     from distributed_lion_tpu.models.lora import lora_embed
 
-    x = lora_embed(params["wte"], tokens, cfg.compute_dtype)
+    with jax.named_scope("embed"):
+        x = lora_embed(params["wte"], tokens, cfg.compute_dtype)
     cos, sin = rope_angles(T, cfg.head_dim, cfg.rope_theta, offset=offset)
     block = _block_remat_for(cfg) if cfg.remat else _block
     for p in params["blocks"]:
@@ -433,7 +441,9 @@ def llama_apply(
     the shard index and attention rings over the axis.
     """
     x = llama_hidden(params, tokens, cfg, tp_axis=tp_axis, seq_axis=seq_axis)
-    return jnp.einsum(
-        "btd,dv->btv", x, maybe_dequant(params["lm_head"], x.dtype).astype(x.dtype),
-        preferred_element_type=jnp.float32,
-    )
+    with jax.named_scope("head"):
+        return jnp.einsum(
+            "btd,dv->btv", x,
+            maybe_dequant(params["lm_head"], x.dtype).astype(x.dtype),
+            preferred_element_type=jnp.float32,
+        )

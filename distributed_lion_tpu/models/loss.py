@@ -12,6 +12,7 @@ import jax
 import jax.numpy as jnp
 
 
+@jax.named_scope("xent")
 def clm_loss_and_metrics(
     logits: jnp.ndarray,
     tokens: jnp.ndarray,
@@ -47,6 +48,7 @@ def clm_loss_and_metrics(
     return loss, {"loss": loss, "accuracy": acc, "n_tokens": mask.sum()}
 
 
+@jax.named_scope("xent")
 def clm_loss_sharded_rows(
     logits: jnp.ndarray,
     tokens: jnp.ndarray,

@@ -158,6 +158,7 @@ def attention_splash(q, k, v, *, causal: bool = True,
 # table entries inert: scatters drop out-of-range writes, gathers fill 0.
 
 
+@jax.named_scope("paged_scatter")
 def paged_scatter_kv(pages: jnp.ndarray, tables: jnp.ndarray,
                      pos: jnp.ndarray, new: jnp.ndarray,
                      valid=None) -> jnp.ndarray:
@@ -224,6 +225,7 @@ def paged_copy_pages(pages: list, src: jnp.ndarray,
     return out
 
 
+@jax.named_scope("paged_gather")
 def paged_gather_kv(pages: jnp.ndarray, tables: jnp.ndarray) -> jnp.ndarray:
     """[num_blocks, bs, KV, hd] pool + [B, nb] tables → [B, nb*bs, KV, hd]
     contiguous per-row history (sentinel pages read as zeros — they are
@@ -234,6 +236,7 @@ def paged_gather_kv(pages: jnp.ndarray, tables: jnp.ndarray) -> jnp.ndarray:
     return got.reshape((B, nb * bs) + pages.shape[2:])
 
 
+@jax.named_scope("paged_attn")
 def paged_decode_attention(q, k_pages, v_pages, tables, pos,
                            start=None):
     """Decode attention over a paged KV cache (new k/v already scattered).

@@ -179,6 +179,7 @@ def hier_ring_slot_bytes(n: int, world_size: int, group: int,
                                             world_size, f"hier:{group}"))
 
 
+@jax.named_scope("vote/pack")
 def pack_signs(positive: jnp.ndarray) -> jnp.ndarray:
     """Pack a boolean array (True = +1 vote) into uint8, 8 votes per byte.
 
@@ -207,6 +208,7 @@ def pack_signs(positive: jnp.ndarray) -> jnp.ndarray:
     return _by_groups(flat, _GROUP_BITS, pack_groups)[: packed_size(n)]
 
 
+@jax.named_scope("vote/unpack")
 def unpack_signs(packed: jnp.ndarray, shape: tuple[int, ...]) -> jnp.ndarray:
     """Inverse of :func:`pack_signs`: uint8 bytes → bool array of ``shape``.
 
@@ -239,6 +241,7 @@ def unpack_signs(packed: jnp.ndarray, shape: tuple[int, ...]) -> jnp.ndarray:
     return _by_groups(flat, _GROUP_BYTES, unpack_groups)[:n].reshape(shape)
 
 
+@jax.named_scope("vote/tally")
 def tally_packed_rows(rows: jnp.ndarray, weights=None) -> jnp.ndarray:
     """Per-bit tally over packed ballot rows: ``rows`` [R, nbytes] uint8 →
     int32 [8 * nbytes], ``sum_r weights[r] * bit_r`` (``weights`` optional

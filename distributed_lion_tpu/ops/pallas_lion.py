@@ -21,6 +21,14 @@ copies of params/grads/momentum, and bucket k's collective overlaps bucket
 k−1's apply. The kernels are elementwise VPU work tiled (≤ROW_BLOCK, 128)
 with dtype-uniform flat inputs; CPU tests run them in interpreter mode
 (``interpret=True``).
+
+Names on the device: each ``pallas_call`` sits directly inside a
+``jax.named_scope`` and carries the same ``name=`` — ``lion_ballot``,
+``lion_apply``, ``lion_stats`` (the window variants share them). A Mosaic
+custom-call's HLO instruction is named after the innermost scope that
+holds it, so a profiler trace shows ``lion_ballot.<n>`` / ``lion_apply.<n>``
+instead of the enclosing function's name. Fixed strings: no leaf index or
+step in them, so compile-cache entries do not depend on them.
 """
 
 from __future__ import annotations
@@ -91,14 +99,16 @@ def fused_ballots(
     m2, _ = _pad_to_grid(m_flat, row_block)
     rows, block = g2.shape[0], _grid_rows(n, row_block)[1]
     spec = pl.BlockSpec((block, LANES), lambda i: (i, 0), memory_space=pltpu.VMEM)
-    out = pl.pallas_call(
-        functools.partial(_ballot_kernel, b1),
-        out_shape=jax.ShapeDtypeStruct((rows, LANES), jnp.int8),
-        grid=(rows // block,),
-        in_specs=[spec, spec],
-        out_specs=spec,
-        interpret=interpret,
-    )(g2, m2)
+    with jax.named_scope("lion_ballot"):
+        out = pl.pallas_call(
+            functools.partial(_ballot_kernel, b1),
+            out_shape=jax.ShapeDtypeStruct((rows, LANES), jnp.int8),
+            grid=(rows // block,),
+            in_specs=[spec, spec],
+            out_specs=spec,
+            interpret=interpret,
+            name="lion_ballot",
+        )(g2, m2)
     return out.reshape(-1)[:n]
 
 
@@ -135,20 +145,22 @@ def fused_apply(
     rows, blk = p2.shape[0], _grid_rows(n, row_block)[1]
     lr_arr = jnp.asarray(lr, jnp.float32).reshape(1)
     block = lambda: pl.BlockSpec((blk, LANES), lambda i: (i, 0), memory_space=pltpu.VMEM)
-    p_new, m_new = pl.pallas_call(
-        functools.partial(_apply_kernel, wd, b2),
-        out_shape=(
-            jax.ShapeDtypeStruct((rows, LANES), p_flat.dtype),
-            jax.ShapeDtypeStruct((rows, LANES), m_flat.dtype),
-        ),
-        grid=(rows // blk,),
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),  # lr scalar
-            block(), block(), block(), block(),
-        ],
-        out_specs=(block(), block()),
-        interpret=interpret,
-    )(lr_arr, p2, g2, m2, t2)
+    with jax.named_scope("lion_apply"):
+        p_new, m_new = pl.pallas_call(
+            functools.partial(_apply_kernel, wd, b2),
+            out_shape=(
+                jax.ShapeDtypeStruct((rows, LANES), p_flat.dtype),
+                jax.ShapeDtypeStruct((rows, LANES), m_flat.dtype),
+            ),
+            grid=(rows // blk,),
+            in_specs=[
+                pl.BlockSpec(memory_space=pltpu.SMEM),  # lr scalar
+                block(), block(), block(), block(),
+            ],
+            out_specs=(block(), block()),
+            interpret=interpret,
+            name="lion_apply",
+        )(lr_arr, p2, g2, m2, t2)
     return p_new.reshape(-1)[:n], m_new.reshape(-1)[:n]
 
 
@@ -255,15 +267,17 @@ def bucket_vote_stats(
     rows, block = b2.shape[0], _grid_rows(n, row_block)[1]
     spec = lambda: pl.BlockSpec((block, LANES), lambda i: (i, 0),  # noqa: E731
                                 memory_space=pltpu.VMEM)
-    out = pl.pallas_call(
-        functools.partial(_stats_kernel, world, nbins),
-        out_shape=jax.ShapeDtypeStruct((8, LANES), jnp.int32),
-        grid=(rows // block,),
-        in_specs=[spec(), spec(), spec()],
-        out_specs=pl.BlockSpec((8, LANES), lambda i: (0, 0),
-                               memory_space=pltpu.VMEM),
-        interpret=interpret,
-    )(b2, t2, m2)
+    with jax.named_scope("lion_stats"):
+        out = pl.pallas_call(
+            functools.partial(_stats_kernel, world, nbins),
+            out_shape=jax.ShapeDtypeStruct((8, LANES), jnp.int32),
+            grid=(rows // block,),
+            in_specs=[spec(), spec(), spec()],
+            out_specs=pl.BlockSpec((8, LANES), lambda i: (0, 0),
+                                   memory_space=pltpu.VMEM),
+            interpret=interpret,
+            name="lion_stats",
+        )(b2, t2, m2)
     return out[0, :nbins], out[1, 0]
 
 

@@ -24,6 +24,7 @@ import jax.numpy as jnp
 from jax import lax
 
 
+@jax.named_scope("xent")
 def chunked_softmax_xent(
     hidden: jnp.ndarray,
     emb: jnp.ndarray,
@@ -120,6 +121,7 @@ def chunked_softmax_xent(
     return nll, besti == labels
 
 
+@jax.named_scope("xent")
 def tp_vocab_xent(
     hidden: jnp.ndarray,
     head_shard: jnp.ndarray,
@@ -248,6 +250,7 @@ def chunked_clm_loss_seq_parallel(
     }
 
 
+@jax.named_scope("xent")
 def masked_local_nll(
     hidden: jnp.ndarray,
     head: jnp.ndarray,

@@ -279,7 +279,8 @@ def vote_total(vote_pos: jnp.ndarray, axis_name: str, wire: str,
             ballots = jnp.where(own, ballots, jnp.zeros_like(ballots))
         if w > 1:  # ring all-reduce: received ≈ the tensor once, on-fabric
             WIRE_TALLY.record("ici", ballots.size * ballots.dtype.itemsize)
-        return lax.psum(ballots, axis_name)
+        with jax.named_scope("vote/wire"):
+            return lax.psum(ballots, axis_name)
     if kind == "packed_allgather":
         # The reference's pack → all_gather → unpack → vote pipeline
         # (distributed_lion.py:71-91) with a true-uint8 wire format;
@@ -287,7 +288,8 @@ def vote_total(vote_pos: jnp.ndarray, axis_name: str, wire: str,
         packed = pack_signs(vote_pos)                  # [ceil(n/8)] uint8
         if w > 1:
             WIRE_TALLY.record("ici", w * packed.size)
-        gathered = lax.all_gather(packed, axis_name)   # [W, ceil(n/8)] uint8
+        with jax.named_scope("vote/wire"):
+            gathered = lax.all_gather(packed, axis_name)   # [W, ceil(n/8)] uint8
         if alive is not None:
             # every worker holds the full ballot matrix here, so masking is
             # a row weighting: count over healthy rows, threshold = quorum
@@ -367,7 +369,8 @@ def _packed_a2a_elect(vote_pos: jnp.ndarray, axis_name: str, w: int,
     if w > 1:  # phase 1: (W−1) peers each send me their copy of my chunk
         WIRE_TALLY.record("ici", (w - 1) * chunk)
     # phase 1: worker j receives every worker's row j → [W, chunk]
-    arrived = lax.all_to_all(packed, axis_name, split_axis=0, concat_axis=0, tiled=True)
+    with jax.named_scope("vote/wire"):
+        arrived = lax.all_to_all(packed, axis_name, split_axis=0, concat_axis=0, tiled=True)
     if alive is not None:
         # the chunk owner sees every worker's row, so the masked tally is a
         # row weighting; the threshold shrinks to the healthy quorum
@@ -380,7 +383,9 @@ def _packed_a2a_elect(vote_pos: jnp.ndarray, axis_name: str, w: int,
     if w > 1:  # phase 2: (W−1) peers each send me their chunk's verdict
         WIRE_TALLY.record("ici", (w - 1) * chunk)
     # phase 2: broadcast my chunk's packed verdict to everyone
-    gathered = lax.all_gather(pack_signs(verdict), axis_name)  # [W, chunk]
+    verdict_bits = pack_signs(verdict)
+    with jax.named_scope("vote/wire"):
+        gathered = lax.all_gather(verdict_bits, axis_name)  # [W, chunk]
     return unpack_signs(gathered.reshape(-1), (n,))
 
 
@@ -441,7 +446,8 @@ def hier_launch(vote_pos: jnp.ndarray, axis_name: str, w: int,
     # into the arriving partial, ending with the full tally of owned chunk
     # (idx + 1) mod g.
     def _rs_hop(msg, t):
-        msg = lax.ppermute(msg, axis_name, intra_perm)
+        with jax.named_scope("vote/wire"):
+            msg = lax.ppermute(msg, axis_name, intra_perm)
         recv = (idx - t - 1) % g
         return msg + lax.dynamic_slice(buf, (recv, 0), (1, chunk))[0], None
 
@@ -470,7 +476,8 @@ def hier_launch(vote_pos: jnp.ndarray, axis_name: str, w: int,
 
     def _cross_hop(carry, t):
         stack, rot = carry
-        rot = lax.ppermute(rot, axis_name, cross_perm)
+        with jax.named_scope("vote/wire"):
+            rot = lax.ppermute(rot, axis_name, cross_perm)
         src = (my_group - t - 1) % n_groups
         stack = lax.dynamic_update_slice(stack, rot[None], (src, 0))
         return (stack, rot), None
@@ -528,7 +535,8 @@ def hier_consume(slot: jnp.ndarray, n: int, axis_name: str, w: int,
 
     def _ag_hop(carry, t):
         out, rot = carry
-        rot = lax.ppermute(rot, axis_name, intra_perm)
+        with jax.named_scope("vote/wire"):
+            rot = lax.ppermute(rot, axis_name, intra_perm)
         # the hop-t packet originated at the member t+1 behind me, which
         # owns chunk (idx − t − 1 + 1) mod g
         out = lax.dynamic_update_slice(out, rot[None], ((idx - t) % g, 0))
@@ -647,7 +655,8 @@ def masked_majority_vote_psum(
     and the majority is taken over the survivors.
     """
     ballots = jnp.where(vote_pos, 1, -1).astype(jnp.int32) * alive.astype(jnp.int32)
-    total = lax.psum(ballots, axis_name)
+    with jax.named_scope("vote/wire"):
+        total = lax.psum(ballots, axis_name)
     return total > 0
 
 

@@ -110,10 +110,38 @@ prefix_cache on and off). Requests may also carry a wall-clock
 ``deadline_s``; expiry evicts with the honest ``timeout`` status at the
 next tick boundary, partial output attached.
 
-Journal spans (``serve/admit``, ``serve/prefill``, ``serve/decode_tick``,
-``serve/cow``, ``serve/evict``) ride the PR-7 run journal when one is
-installed (train/journal.install), giving ``cli/run_analyze`` a per-tick
-timeline.
+**Spans** (``train/journal.span`` — the one span primitive: the shared
+null span unless a profiler session or an installed ``--journal_dir``
+journal listens; with a listener each span is a
+``jax.profiler.TraceAnnotation`` on the device trace's clock, a record in
+``journal.traced()`` and a journal line, all carrying ``id``/``parent``).
+Every tick is one tree::
+
+    serve/tick            tick=<n>                       the whole step()
+      serve/expire                                       deadline sweep
+      serve/admit         pending, prefills              admission + table math
+        serve/cow         copies
+        serve/prefill     req_id, prompt_len, padded..   one per admitted request
+          serve/token_read                               int(np.asarray(tok))
+        serve/evict       req_id, slot, reason
+      serve/decode_tick   batch
+        serve/decode_build                               grow, CoW, six operands, tables
+          serve/cow, serve/evict (overflow)
+        serve/decode_dispatch                            the jitted call: enqueue only
+        serve/token_read                                 the ONE blocking np.asarray(toks)
+        serve/commit      batch                          per-slot bookkeeping, evictions
+          serve/evict
+      serve/metrics                                      only with ServeConfig.metrics
+
+(under ``speculate`` the decode tick is ``serve/draft``, ``serve/verify``
+with its own ``serve/token_read``, and ``serve/commit``). A tick's span
+less the ``serve/token_read`` under it is what the host did itself.
+Construction is timed by always-on ``setup/place_weights``,
+``setup/init_pages`` and ``setup/build_dispatches`` spans and one
+``[setup]`` line; the compile ledger (utils/compile_cache) names each
+dispatch's program (``decode_tick``, ``prefill``, ``cow_copy``,
+``verify``) with its trace, lower and compile-or-load seconds when it is
+built, and the retrace guard names it when it counts a retrace.
 """
 
 from __future__ import annotations
@@ -134,6 +162,7 @@ from distributed_lion_tpu.serve.kv_cache import (
 )
 from distributed_lion_tpu.serve.metrics import RequestTimes, ServeMetrics
 from distributed_lion_tpu.train import journal
+from distributed_lion_tpu.utils import compile_cache
 
 
 @dataclasses.dataclass(frozen=True)
@@ -365,10 +394,12 @@ class _RetraceGuard:
     under ``error`` BEFORE jax pays for the lowering."""
 
     def __init__(self, mode: str, budgets: Dict[str, int],
-                 stats: Dict[str, Any]):
+                 stats: Dict[str, Any],
+                 program_of: Callable[[str], str] = str):
         self.mode = mode
         self.budgets = budgets
         self.stats = stats
+        self.program_of = program_of  # dispatch kind -> compile-ledger name
         self.seen: Dict[str, set] = {}
 
     def observe(self, kind: str, operands) -> None:
@@ -380,7 +411,10 @@ class _RetraceGuard:
         if len(seen) < budget:
             seen.add(sig)
             return
-        msg = (f"serve retrace guard: dispatch {kind!r} saw a new operand "
+        program = self.program_of(kind)
+        msg = (f"serve retrace guard: dispatch {kind!r} (program "
+               f"{program!r}, built {compile_cache.compiles_of(program)} "
+               f"time(s) so far) saw a new operand "
                f"signature past its compile budget ({budget}) — a "
                f"recompile the serving design forbids; new signature: "
                f"{sig}")
@@ -568,6 +602,11 @@ class ServingEngine:
         # latency behavior is testable without real sleeps; the metrics
         # plane (when armed) shares the same clock
         self._now = time_fn
+        # the span gate's profiler and the compile ledger's listeners
+        # (both idempotent)
+        journal.register_profiler(jax.profiler.TraceAnnotation)
+        compile_cache.listen()
+        setup = journal.SetupLaps("engine")
         params = model.params
         if cfg.quant not in ("none", "nf4", "int8"):
             raise ValueError(f"unknown quant mode {cfg.quant!r}")
@@ -682,6 +721,7 @@ class ServingEngine:
                                 for _ in range(model.n_layer)]
             pages_sharding = NamedSharding(self._mesh, pool_spec)
         self.params = params
+        setup.lap("setup/place_weights")  # quantize, mesh, shard
 
         self.tables = BlockTables(cfg.resolved_num_blocks(), cfg.block_size,
                                   cfg.max_seqs, cfg.max_blocks_per_seq,
@@ -707,6 +747,7 @@ class ServingEngine:
         else:
             self._prefix_caches = None
             self.prefix = None
+        setup.lap("setup/init_pages")  # tables, the page pool, prefix caches
         self.slots: List[Optional[_Slot]] = [None] * cfg.max_seqs
         self.pending: deque = deque()
         # req_id -> absolute time.monotonic() deadline (requests with a
@@ -743,7 +784,8 @@ class ServingEngine:
         if cfg.retrace_guard != "off":
             self.stats["serve_retraces"] = 0
             self._retrace_guard = _RetraceGuard(
-                cfg.retrace_guard, self.compile_budget(), self.stats)
+                cfg.retrace_guard, self.compile_budget(), self.stats,
+                program_of=self._program_of)
 
         samp = (cfg.temperature, cfg.top_k, cfg.top_p)
         tp_axis, ep_axis = self._tp_axis, self._ep_axis
@@ -852,6 +894,9 @@ class ServingEngine:
 
             self._speculator = build_speculator(self, cfg.speculate,
                                                 draft_model)
+        setup.lap("setup/build_dispatches")  # jit wrappers; each program
+        # compiles at its first tick, where the compile ledger names it
+        setup.emit(stderr=True)  # stdout is run_serve's response stream
 
     # ------------------------------------------------------- TP dispatch
     def _register_dispatch(self, name: Optional[str], jitted, inner,
@@ -897,6 +942,12 @@ class ServingEngine:
             budget["draft_prefill"] = len(buckets)
             budget["draft_step"] = 1
         return budget
+
+    def _program_of(self, kind: str) -> str:
+        """A dispatch kind's name in the compile ledger: the name of the
+        function that was jitted."""
+        d = self._dispatches.get(kind)
+        return getattr(d and d["inner"], "__name__", kind)
 
     def _guard(self, kind: str, operands) -> None:
         """Retrace-guard hook, called immediately before each dispatch
@@ -1147,7 +1198,7 @@ class ServingEngine:
             dst = np.full((width,), sentinel, np.int32)
             for i, (s, d) in enumerate(pairs):
                 src[i], dst[i] = s, d
-        with journal.active().span("serve/cow", copies=len(pairs)):
+        with journal.span("serve/cow", copies=len(pairs)):
             src_dev, dst_dev = jnp.asarray(src), jnp.asarray(dst)
             self._guard("cow", (src_dev, dst_dev))
             self.pages = self._cow(self.pages, src_dev, dst_dev)
@@ -1200,14 +1251,15 @@ class ServingEngine:
                                               *rest)
         # ONE host sync per prefill dispatch (the owner group's lane
         # under ep_batch; the only lane otherwise)
-        first = int(np.asarray(tok).reshape(-1)[g if self._ep_batch else 0])
+        with journal.span("serve/token_read"):
+            first = int(np.asarray(tok).reshape(-1)[
+                g if self._ep_batch else 0])
         self._absorb_moe_stats(st)
         return first
 
     def _admit(self, completions: List[Completion]) -> None:
         budget = self.cfg.prefill_cap_tokens
         admitted = 0
-        jrnl = journal.active()
         while self.pending:
             req = self.pending[0]
             # a migrated request prefills its WHOLE history — prompt plus
@@ -1263,7 +1315,7 @@ class ServingEngine:
             self.pending.popleft()
             self._flush_cow(cow_pairs)
             suffix = hist[covered:]
-            with jrnl.span("serve/prefill", req_id=str(req.req_id),
+            with journal.span("serve/prefill", req_id=str(req.req_id),
                            prompt_len=L, padded=P, slot=slot,
                            shared=covered, resumed=len(req.committed)):
                 first = self._dispatch_prefill(req, slot, covered,
@@ -1308,9 +1360,8 @@ class ServingEngine:
             reason = "length"
         if reason is None:
             return
-        with journal.active().span("serve/evict", req_id=str(s.req.req_id),
-                                   slot=slot, reason=reason,
-                                   n_generated=len(s.gen)):
+        with journal.span("serve/evict", req_id=str(s.req.req_id),
+                          slot=slot, reason=reason, n_generated=len(s.gen)):
             # refcount-honest accounting: evicting a sharer whose pages
             # all outlive it (prefix cache / other slots) frees ZERO
             # physical pages — freed_pages records what really returned
@@ -1327,17 +1378,18 @@ class ServingEngine:
             s.req.req_id, len(s.req.tokens), list(s.gen), reason,
             timing=self._finish_timing(s.req.req_id, reason)))
 
-    def _decode(self, completions: List[Completion]) -> None:
+    def _decode_operands(self, active: List[int],
+                         completions: List[Completion]):
+        """The decode tick's host half (``serve/decode_build``): grow the
+        tables for the tick's ONE write per active slot (CoW'ing a shared
+        boundary page first — the first decode write after a cache-hit
+        admit is the canonical divergent write), then build the six
+        operands. A slot the pool can't serve even after reclaim is
+        evicted as overflow (truncated output) and leaves ``active``, so
+        the rest of the batch keeps moving. Returns None when no slot is
+        left."""
         import jax.numpy as jnp
 
-        active = [i for i, s in enumerate(self.slots) if s is not None]
-        if not active:
-            return
-        # grow tables for the tick's ONE write per active slot (CoW'ing a
-        # shared boundary page first — the first decode write after a
-        # cache-hit admit is the canonical divergent write); a slot the
-        # pool can't serve even after reclaim is evicted as overflow
-        # (truncated output) so the rest of the batch keeps moving
         cow_pairs: List[tuple] = []
         for i in list(active):
             s = self.slots[i]
@@ -1346,7 +1398,7 @@ class ServingEngine:
                 self._maybe_finish(i, completions, overflow=True)
                 active.remove(i)
         if not active:
-            return
+            return None
         self._flush_cow(cow_pairs)
         S = self.cfg.max_seqs
         lens = np.zeros((S,), np.int32)
@@ -1361,23 +1413,36 @@ class ServingEngine:
             act[i] = True
             seeds[i] = s.req.seed
             counts[i] = len(s.gen)  # index of the token being sampled
-        with journal.active().span("serve/decode_tick", batch=len(active)):
-            rest = (self._device_tables(), jnp.asarray(lens),
-                    jnp.asarray(last), jnp.asarray(act),
-                    jnp.asarray(seeds), jnp.asarray(counts))
-            self._guard("decode", rest)
-            (toks, st), self.pages = self._decode_tick(
-                self.params, self.pages, *rest)
-            toks = np.asarray(toks)  # ONE host sync for the whole batch
-            self._absorb_moe_stats(st)
-        self.stats["decode_ticks"] += 1
-        self.stats["decode_tokens"] += len(active)
-        for i in active:
-            s = self.slots[i]
-            s.cache_len += 1
-            s.last_tok = int(toks[i])
-            s.gen.append(int(toks[i]))
-            self._maybe_finish(i, completions)
+        return (self._device_tables(), jnp.asarray(lens),
+                jnp.asarray(last), jnp.asarray(act),
+                jnp.asarray(seeds), jnp.asarray(counts))
+
+    def _decode(self, completions: List[Completion]) -> None:
+        active = [i for i, s in enumerate(self.slots) if s is not None]
+        if not active:
+            return
+        span = journal.span
+        with span("serve/decode_tick", batch=len(active)):
+            with span("serve/decode_build"):
+                rest = self._decode_operands(active, completions)
+            if rest is None:
+                return
+            with span("serve/decode_dispatch"):  # enqueue only
+                self._guard("decode", rest)
+                (toks, st), self.pages = self._decode_tick(
+                    self.params, self.pages, *rest)
+            with span("serve/token_read"):
+                toks = np.asarray(toks)  # ONE host sync for the whole batch
+            with span("serve/commit", batch=len(active)):
+                self._absorb_moe_stats(st)
+                self.stats["decode_ticks"] += 1
+                self.stats["decode_tokens"] += len(active)
+                for i in active:
+                    s = self.slots[i]
+                    s.cache_len += 1
+                    s.last_tok = int(toks[i])
+                    s.gen.append(int(toks[i]))
+                    self._maybe_finish(i, completions)
 
     def _expire_deadlines(self, completions: List[Completion]) -> None:
         """Evict every request past its wall-clock deadline with the
@@ -1388,7 +1453,7 @@ class ServingEngine:
         if not self._deadline_at:
             return
         now = self._now()
-        jrnl = journal.active()
+        jrnl = journal.active()  # events only: no-ops with none installed
         keep: deque = deque()
         while self.pending:
             req = self.pending.popleft()
@@ -1419,33 +1484,45 @@ class ServingEngine:
         Returns the requests that finished this tick."""
         completions: List[Completion] = []
         self.stats["ticks"] += 1
-        self._expire_deadlines(completions)
-        with journal.active().span("serve/admit",
-                                   pending=len(self.pending)):
-            self._admit(completions)
-        if self.metrics is not None:
-            # per-token decode interval = the decode dispatch's wall time
-            # over however many tokens it committed (1/slot plain, up to
-            # k+1/slot speculative) — host clock reads only, the
-            # dispatch itself is untouched
-            t0 = self._now()
-            tok0 = self.stats["decode_tokens"]
-        if self._speculator is not None:
-            self._speculator.decode_tick(completions)
-        else:
-            self._decode(completions)
-        if self.metrics is not None:
-            made = self.stats["decode_tokens"] - tok0
-            if made > 0:
-                self.metrics.on_decode_tick(
-                    (self._now() - t0) * 1e3 / made, made)
-            self.metrics.set_gauges(**self._gauge_snapshot())
-            if self.metrics.maybe_drain(self.stats["ticks"]) is not None:
-                # the SAME counters the bench banks, at the same cadence
-                # the sketches drain — crash bundles and run_analyze
-                # --serve read these, not a private in-memory dict
-                journal.active().event("serve_stats", **self.stats)
+        span = journal.span
+        with span("serve/tick", tick=self.stats["ticks"]):
+            with span("serve/expire"):
+                self._expire_deadlines(completions)
+            with span("serve/admit", pending=len(self.pending)) as admit:
+                before = self.stats["prefill_dispatches"]
+                self._admit(completions)
+                admit.set(prefills=self.stats["prefill_dispatches"] - before)
+            if self.metrics is not None:
+                # per-token decode interval = the decode dispatch's wall
+                # time over however many tokens it committed (1/slot
+                # plain, up to k+1/slot speculative) — host clock reads
+                # only, the dispatch itself is untouched
+                t0 = self._now()
+                tok0 = self.stats["decode_tokens"]
+            if self._speculator is not None:
+                self._speculator.decode_tick(completions)
+            else:
+                self._decode(completions)
+            if self.metrics is not None:
+                with span("serve/metrics"):
+                    self._tick_metrics(t0, tok0)
+        for line in compile_cache.new_lines():
+            # a dispatch's program, named when it is built (its first
+            # tick; a retrace later): trace, lower, compile or load
+            journal.emit(line, stderr=True)
         return completions
+
+    def _tick_metrics(self, t0: float, tok0: int) -> None:
+        made = self.stats["decode_tokens"] - tok0
+        if made > 0:
+            self.metrics.on_decode_tick(
+                (self._now() - t0) * 1e3 / made, made)
+        self.metrics.set_gauges(**self._gauge_snapshot())
+        if self.metrics.maybe_drain(self.stats["ticks"]) is not None:
+            # the SAME counters the bench banks, at the same cadence
+            # the sketches drain — crash bundles and run_analyze
+            # --serve read these, not a private in-memory dict
+            journal.active().event("serve_stats", **self.stats)
 
     def _gauge_snapshot(self) -> Dict[str, float]:
         """Live gauges for the metrics drain — every value is already a

@@ -463,7 +463,7 @@ class Speculator:
         if not active:
             return
         S = eng.cfg.max_seqs
-        jrnl = journal.active()
+        span = journal.span
 
         # two-phase grow. Phase 1 reserves every active slot's ONE
         # mandatory write (last_tok) first — the exact loop the plain
@@ -505,7 +505,7 @@ class Speculator:
             desired[i] = v
         eng._flush_cow(cow_pairs)
 
-        with jrnl.span("serve/draft", drafter=self.drafter.name,
+        with span("serve/draft", drafter=self.drafter.name,
                        batch=len(active), k=self.k):
             drafts, counts = self.drafter.propose(active, eng.slots, desired)
 
@@ -526,7 +526,7 @@ class Speculator:
             seeds[i] = s.req.seed
             gcounts[i] = len(s.gen)
 
-        with jrnl.span("serve/verify", batch=len(active),
+        with span("serve/verify", batch=len(active),
                        proposed=int(sum(desired[i] for i in active))):
             rest = (eng._device_tables(), jnp.asarray(lens),
                     jnp.asarray(window), jnp.asarray(vcounts),
@@ -534,11 +534,12 @@ class Speculator:
             eng._guard("verify", rest)
             (draws, st), eng.pages = self._verify(
                 eng.params, eng.pages, *rest)
-            draws = np.asarray(draws)  # ONE host sync for the whole batch
+            with span("serve/token_read"):
+                draws = np.asarray(draws)  # ONE host sync for the batch
             eng._absorb_moe_stats(st)
 
         accepted_total = committed_total = 0
-        with jrnl.span("serve/commit", batch=len(active)) as commit_span:
+        with span("serve/commit", batch=len(active)) as commit_span:
             for i in active:
                 s = eng.slots[i]
                 v = int(desired[i])

@@ -100,11 +100,13 @@ class Checkpointer:
                  journal=None):
         # the run journal (train/journal.py; NULL no-op when the trainer
         # runs without --journal): caller-thread spans (ckpt/serialize,
-        # ckpt/drain) are the step loop's checkpoint tax — the same wall
-        # time the ckpt_stall_s ledger counts, cross-checked by
+        # ckpt/drain, opened through the gated journal.span like the rest
+        # of the step loop's) are the step loop's checkpoint tax — the
+        # same wall time the ckpt_stall_s ledger counts, cross-checked by
         # tests/test_journal.py — while the committer-thread spans
-        # (thread="committer") show where the BACKGROUND commit spends its
-        # time without counting against the step wall
+        # (thread="committer", recorded to this journal only) show where
+        # the BACKGROUND commit spends its time without counting against
+        # the step wall
         self._journal = journal if journal is not None else run_journal.NULL
         self.directory = pathlib.Path(directory).absolute()
         self.directory.mkdir(parents=True, exist_ok=True)
@@ -171,7 +173,7 @@ class Checkpointer:
             # caller-thread serialize span: the D2H copy + Orbax enqueue
             # (async) or the full serialize+write+commit (sync) — with the
             # drain above, the whole of save()'s step-loop tax
-            with self._journal.span("ckpt/serialize", step=int(step)):
+            with run_journal.span("ckpt/serialize", step=int(step)):
                 delay = self.retry_backoff_s
                 for attempt in range(self.max_retries + 1):
                     try:
@@ -249,7 +251,7 @@ class Checkpointer:
         fut, step = self._inflight, self._inflight_step
         self._inflight, self._inflight_step = None, None
         try:
-            with self._journal.span("ckpt/drain", step=int(step)):
+            with run_journal.span("ckpt/drain", step=int(step)):
                 fut.result()
         except Exception as e:
             raise RuntimeError(

@@ -50,14 +50,42 @@ Record schema (validated by scripts/validate_metrics.py):
 - free-form extra fields must be JSON scalars; non-finite floats are
   serialized as ``null`` with the repr under ``<k>_repr``.
 
+- ``span`` records opened through :func:`span` also carry ``id`` (unique
+  in the process) and ``parent`` (the ``id`` of the innermost span open on
+  the same thread, or ``null``): the span tree, so a reader can take a
+  parent's self time (its span less its children's).
+
 Span taxonomy (the name's head — before any ``/`` — is the attribution
 bucket): ``data_wait`` (batch fetch + host→device put), ``dispatch`` (the
 jitted-step call), ``device_wait`` (the log-cadence device drain — the
 loop's direct view of device-bound time), ``logging_drain`` (metric
 assembly + telemetry drain + JSONL write), ``ckpt/*`` (checkpoint
 serialize/drain on the step thread; committer-thread spans carry
-``thread="committer"``). Everything else lands in the analyzer's ``other``
-bucket.
+``thread="committer"``), and the loop's own host work between them
+(``retrace_check``, ``guard_apply``, ``sentinel_check``, ``membership``).
+The serving engine's tree hangs under ``serve/tick`` (serve/engine.py's
+module doc lists it); ``setup/*`` spans time construction. Everything
+else lands in the analyzer's ``other`` bucket.
+
+**One span primitive, gated by what is listening** (:func:`span`). Hot
+paths open every span through the module-level ``journal.span(name,
+**ids)``. With nothing listening — no profiler session and no installed
+journal — it is one check and the shared null span. With a listener the
+span (a) opens a ``jax.profiler.TraceAnnotation`` of the same extent, so
+it lies in the profiler's ``.xplane.pb`` on the device trace's clock;
+(b) appends ``{name, t0, t1, id, parent, **ids}`` (``time.monotonic``
+seconds) to a process-global bounded buffer read by :func:`traced`, which
+outlives the trainer and the engine and starts empty at each profiler
+session; (c) goes to the installed journal in the schema above. The
+profiler is reached through a hook (:func:`register_profiler`) that the
+Trainer and the serving engine register, so this module still imports
+without jax. Trace times are relative to the session's start, so the
+first span of a session also emits a ``journal/clock`` annotation whose
+``monotonic_ns`` lays journal files and buffer records over the trace.
+The gate is read when a span opens, and only then: a span open when a
+session starts is not in the buffer, one open when it ends is recorded
+whole, and a session is seen to have ended by the first span that opens
+after it (the loops open spans every step and tick).
 
 Layering: stdlib + ``train.resilience`` (itself pure stdlib) only — no
 jax, no numpy — so host-side consumers (``train/vote_guard``,
@@ -68,6 +96,7 @@ loaded by file path.
 from __future__ import annotations
 
 import collections
+import itertools
 import json
 import math
 import os
@@ -123,19 +152,43 @@ def _safe_fields(fields: dict) -> dict:
     return out
 
 
-class _SpanCtx:
-    """Context manager recording one span on exit (monotonic end time +
-    duration). Exceptions propagate; the span still records, flagged
-    ``error=True``, so a failing region is visible in the timeline."""
+TRACED_MAX = 65536   # records the traced buffer holds before it drops
 
-    __slots__ = ("_journal", "_name", "_fields", "_t0")
+_IDS = itertools.count(1)          # span ids, unique in the process
+_OPEN = threading.local()          # .stack: ids of the spans open here
+_BUF_LOCK = threading.Lock()
+_TRACED: collections.deque = collections.deque(maxlen=TRACED_MAX)
+_dropped = 0
 
-    def __init__(self, journal: "Journal", name: str, fields: dict):
-        self._journal = journal
+
+class _Span:
+    """Context manager for one span (see the module doc's span primitive):
+    the profiler annotation, the buffer record and the journal record all
+    cover the same extent. Exceptions propagate; the span still records,
+    flagged ``error=True``, so a failing region is visible in the
+    timeline."""
+
+    __slots__ = ("_name", "_fields", "_sink", "_buffered", "_t0", "_id",
+                 "_parent", "_note")
+
+    def __init__(self, name: str, fields: dict, sink, buffered: bool):
         self._name = name
         self._fields = fields
+        self._sink = sink          # a Journal, or None
+        self._buffered = buffered  # also goes to the traced buffer
 
-    def __enter__(self) -> "_SpanCtx":
+    def __enter__(self) -> "_Span":
+        stack = _OPEN.__dict__.setdefault("stack", [])
+        self._parent = stack[-1] if stack else None
+        self._id = next(_IDS)
+        stack.append(self._id)
+        self._note = None
+        if _ANNOTATION is not None:
+            # a TraceMe outside a session costs well under a microsecond
+            self._note = _ANNOTATION(self._name, **{
+                k: v for k, v in self._fields.items()
+                if isinstance(v, (str, int, float, bool))})
+            self._note.__enter__()
         self._t0 = time.monotonic()
         return self
 
@@ -144,13 +197,35 @@ class _SpanCtx:
         self._fields.update(fields)
 
     def __exit__(self, exc_type, exc, tb) -> bool:
-        dur = time.monotonic() - self._t0
+        t1 = time.monotonic()
+        if self._note is not None:
+            self._note.__exit__(exc_type, exc, tb)
+        stack = _OPEN.stack
+        if stack and stack[-1] == self._id:
+            stack.pop()
+        elif self._id in stack:     # exits out of order: keep the rest
+            stack.remove(self._id)
         fields = self._fields
         if exc_type is not None:
             fields = {**fields, "error": True}
-        self._journal.record({"kind": "span", "name": self._name,
-                              "dur": round(dur, 9), **fields})
+        if self._buffered:
+            _record_traced({"name": self._name, "t0": self._t0, "t1": t1,
+                            "id": self._id, "parent": self._parent,
+                            **fields})
+        if self._sink is not None:
+            self._sink.record({"kind": "span", "name": self._name,
+                               "dur": round(t1 - self._t0, 9),
+                               "id": self._id, "parent": self._parent,
+                               **fields})
         return False
+
+
+def _record_traced(rec: dict) -> None:
+    global _dropped
+    with _BUF_LOCK:
+        if len(_TRACED) == TRACED_MAX:
+            _dropped += 1
+        _TRACED.append(rec)
 
 
 class Journal:
@@ -275,10 +350,12 @@ class Journal:
     def event(self, name: str, **fields) -> None:
         self.record({"kind": "event", "name": name, **fields})
 
-    def span(self, name: str, **fields) -> _SpanCtx:
-        """``with journal.span("data_wait", step=n): ...`` — records the
-        region's host wall time on exit."""
-        return _SpanCtx(self, name, fields)
+    def span(self, name: str, **fields) -> _Span:
+        """``with jr.span("ckpt/digest", step=n): ...`` — a span that goes
+        to THIS journal whatever is installed (off-thread recorders and
+        ring-only bench journals hold their own); hot paths use the
+        module-level :func:`span`."""
+        return _Span(name, fields, self, False)
 
     def log(self, msg: str, stream: str = "stdout") -> None:
         self.record({"kind": "log", "name": "log", "msg": str(msg),
@@ -402,3 +479,94 @@ def event(name: str, **fields) -> None:
     shard-retry counters)."""
     if _ACTIVE is not None:
         _ACTIVE.event(name, **fields)
+
+
+# ------------------------------------------------------------ the span gate
+# jax.profiler.TraceAnnotation, handed in by whoever imports jax (Trainer,
+# ServingEngine): this module stays importable without it.
+_ANNOTATION: Any = None
+_in_session = False
+
+
+def _tracing() -> bool:
+    """Is a profiler session on? (Replaced by ``TraceAnnotation.is_enabled``
+    once a profiler is registered; none known, none listening.)"""
+    return False
+
+
+def register_profiler(annotation_cls) -> None:
+    """Give the gate its profiler: ``jax.profiler.TraceAnnotation`` (its
+    ``is_enabled()`` is the gate, the class itself the annotation every
+    listened-to span opens)."""
+    global _ANNOTATION, _tracing
+    _ANNOTATION = annotation_cls
+    _tracing = annotation_cls.is_enabled
+
+
+def span(name: str, **ids):
+    """``with journal.span("dispatch", step=n): ...`` — THE span primitive
+    of the hot paths (module doc). One check and the shared null span when
+    neither a profiler session nor an installed journal listens."""
+    global _in_session
+    if _tracing():
+        if not _in_session:
+            _begin_session()
+    else:
+        _in_session = False
+        if _ACTIVE is None:
+            return _NULL_SPAN
+    return _Span(name, ids, _ACTIVE, True)
+
+
+def _begin_session() -> None:
+    """First span of a profiler session: the buffer starts empty, and a
+    ``journal/clock`` annotation pins this process's monotonic clock to
+    the trace's (whose times count from the session's start)."""
+    global _in_session, _dropped
+    _in_session = True
+    with _BUF_LOCK:
+        _TRACED.clear()
+        _dropped = 0
+    with _ANNOTATION("journal/clock", monotonic_ns=time.monotonic_ns()):
+        pass
+
+
+class SetupLaps:
+    """Construction timed as consecutive laps: ``setup = SetupLaps("engine")``
+    starts the clock, ``setup.lap("setup/init_pages")`` closes the lap
+    that ran since the last one, ``setup.emit()`` prints the one
+    ``[setup]`` line. Timed whether or not anything listens (a dozen laps
+    a run, all before a profiler session can be on): each lap goes to the
+    ``[setup]`` line and, as a span record, to the installed journal; a
+    lap that raises records nothing."""
+
+    def __init__(self, owner: str):
+        self.owner = owner
+        self._t = time.monotonic()
+        self._parts: list = []
+
+    def lap(self, name: str) -> None:
+        t0, self._t = self._t, time.monotonic()
+        if _ACTIVE is not None:
+            _ACTIVE.record({"kind": "span", "name": name,
+                            "dur": round(self._t - t0, 9),
+                            "id": next(_IDS), "parent": None,
+                            "owner": self.owner})
+        self._parts.append(f"{name.split('/', 1)[-1]} {self._t - t0:.2f} s")
+
+    def emit(self, *, stderr: bool = False) -> None:
+        emit(f"[setup] {self.owner}: " + ", ".join(self._parts),
+             stderr=stderr)
+
+
+def traced() -> list:
+    """The spans recorded since the last profiler session began (or since
+    the process started), oldest first: ``name``, ``t0``, ``t1``
+    (``time.monotonic`` seconds), ``id``, ``parent`` and the span's ids."""
+    with _BUF_LOCK:
+        return list(_TRACED)
+
+
+def traced_dropped() -> int:
+    """Records the traced buffer pushed out since it was last emptied."""
+    return _dropped

@@ -72,11 +72,11 @@ from distributed_lion_tpu.train.checkpoint import Checkpointer
 from distributed_lion_tpu.train.metrics import MetricsLogger
 from distributed_lion_tpu.train.profiling import (
     StepProfiler,
-    StepTimer,
     comm_report,
     peak_hbm_gb,
     peak_hbm_per_device,
 )
+from distributed_lion_tpu.utils import compile_cache
 from distributed_lion_tpu.train.schedule import (
     constant_schedule,
     cosine_schedule_with_warmup,
@@ -716,6 +716,11 @@ class Trainer:
         replicated). When set, ``loss_fn`` takes
         ``(params, frozen, batch, dropout_key)`` and ``frozen_specs`` gives
         its PartitionSpecs (default replicated)."""
+        # the span gate's profiler and the compile ledger's listeners: both
+        # idempotent, both needed before the first span / first compile
+        journal.register_profiler(jax.profiler.TraceAnnotation)
+        compile_cache.listen()
+        setup = journal.SetupLaps("trainer")
         n_params = count_params(params)
         cfg = resolve_auto_comm(
             cfg, mesh, n_params,
@@ -901,6 +906,8 @@ class Trainer:
             emit(f"[trainer] FAULT INJECTION armed: ballot poison "
                   f"{cfg.inject_poison!r}")
 
+        setup.lap("setup/mesh")  # what the mesh decides: wire, buckets,
+        # row block, the guard and the control plane
         self.params = jax.tree.map(
             lambda p, s: jax.device_put(p, NamedSharding(mesh, s)), params, param_specs
         )
@@ -924,6 +931,7 @@ class Trainer:
 
             self.frozen = jax.tree.map(_put, frozen_params, frozen_specs,
                                        is_leaf=_is_qt)
+        setup.lap("setup/init_params")
         rng = jax.random.key(cfg.seed)
         self._exp_avg_specs = jax.tree.map(
             lambda s: P(*((DATA_AXIS,) + tuple(s))), param_specs
@@ -1001,6 +1009,7 @@ class Trainer:
         else:
             self._n_ballot = 0
             self.vote_health = {}
+        setup.lap("setup/init_state")
         self._wire_measured: Optional[dict] = None  # trace-time byte ledger
         self._metrics_window: collections.deque = collections.deque(maxlen=16)
         self._sentinel_pending = None   # (step, metrics) awaiting the check
@@ -1031,6 +1040,7 @@ class Trainer:
         self._train_chunk = jax.jit(self._build_train_chunk(),
                                     donate_argnums=(0, 1))
         self._eval_step = self._build_eval_step()
+        setup.lap("setup/build_step")
         self.checkpointer = (
             Checkpointer(f"{cfg.output_dir}/checkpoints", cfg.save_total_limit,
                          async_save=cfg.async_ckpt,
@@ -1061,9 +1071,10 @@ class Trainer:
         self.logger = MetricsLogger(cfg.output_dir, use_wandb=cfg.report_to_wandb)
         self.profiler = StepProfiler(cfg.profile_dir, cfg.profile_start_step,
                                      cfg.profile_num_steps)
-        self.timer = StepTimer()
         self.n_params = sum(int(np.prod(p.shape)) for p in jax.tree.leaves(self.params))
         self._maybe_resume()
+        setup.lap("setup/resume")
+        setup.emit()
 
     def _frozen_arg(self):
         """The frozen pytree as passed to the jitted steps ({} when unused —
@@ -1141,7 +1152,10 @@ class Trainer:
             seen.add(sig)
             return
         self.retrace_count += 1
-        msg = (f"RETRACE: the jitted train {kind} saw a new abstract input "
+        program = f"train_{kind}"  # its name in the compile ledger
+        msg = (f"RETRACE: the jitted train {kind} (program {program!r}, "
+               f"built {compile_cache.compiles_of(program)} time(s) so far) "
+               "saw a new abstract input "
                f"signature at step {self.step_count} — jax will compile "
                "another specialization (multi-second stall now, a silent "
                "step-time cliff if it recurs). Usual causes: a batch "
@@ -1341,7 +1355,7 @@ class Trainer:
             out_specs=(self.param_specs, st_specs, vh_specs, P()),
             check_vma=False,
         )
-        def step(params, state, vh, frozen, batch, base_key):
+        def train_step(params, state, vh, frozen, batch, base_key):
             call_loss = ((lambda p, b, k, *a: loss_fn(p, frozen, b, k, *a))
                          if has_frozen else loss_fn)
             stale_balance, ring, ring_slot = None, None, None
@@ -1530,7 +1544,7 @@ class Trainer:
                     gframe["voted"] > 0).astype(jnp.int32)
             return new_params, new_state, vh, mean_metrics
 
-        return step
+        return train_step
 
     def _build_train_chunk(self):
         """K optimizer steps per device dispatch: ``lax.scan`` of the train
@@ -1538,7 +1552,7 @@ class Trainer:
         host→device round trip per K steps instead of per step."""
         step = self._train_step_core
 
-        def chunk(params, state, vh, frozen, batches, base_key):
+        def train_chunk(params, state, vh, frozen, batches, base_key):
             def body(carry, batch):
                 p, s, v = carry
                 p, s, v, m = step(p, s, v, frozen, batch, base_key)
@@ -1554,7 +1568,7 @@ class Trainer:
                 k: (v.sum(0) if k.startswith("guard_") else v.mean(0))
                 for k, v in ms.items()}
 
-        return chunk
+        return train_chunk
 
     def _build_eval_step(self):
         loss_fn = self.loss_fn
@@ -1568,12 +1582,12 @@ class Trainer:
             out_specs=P(),
             check_vma=False,
         )
-        def step(params, frozen, batch):
+        def eval_step(params, frozen, batch):
             loss, metrics = (loss_fn(params, frozen, batch, None) if has_frozen
                              else loss_fn(params, batch, None))
             return {k: lax.pmean(v, DATA_AXIS) for k, v in metrics.items()}
 
-        return jax.jit(step)
+        return jax.jit(eval_step)
 
     # ------------------------------------------------------------- train/eval
     def global_train_batch(self) -> int:
@@ -1609,8 +1623,10 @@ class Trainer:
             self._resume_skip_batches = 0
         t_last, s_last = time.time(), self.step_count
         chunk_spec = NamedSharding(self.mesh, P(None, *self.batch_spec))
-        jr = self.journal  # journal.NULL when --journal is off: every span
-        # below is a no-op, and the loop body is byte-identical in behavior
+        jr = self.journal  # journal.NULL when --journal is off: its events
+        # are no-ops. Spans go through journal.span, which is the shared
+        # null span unless a profiler session or --journal listens
+        span = journal.span
         jr.event("train_start", step=self.step_count, total=int(total))
 
         while self.step_count < total:
@@ -1618,49 +1634,55 @@ class Trainer:
                 # membership transitions land at dispatch boundaries: a
                 # due drop is masked out of the NEXT election, a due
                 # rejoin is healed before it votes again
-                self._apply_membership(self.step_count)
+                with span("membership", step=self.step_count):
+                    self._apply_membership(self.step_count)
             self.profiler.maybe_start(self.step_count)
             k = min(self.cfg.steps_per_call, total - self.step_count)
             advanced = k
             if k == self.cfg.steps_per_call and k > 1:
                 # fused K-step dispatch; the tail below K runs step-by-step
                 # (avoids a second jit specialization for the remainder)
-                with jr.span("data_wait", step=self.step_count, steps=k):
+                with span("data_wait", step=self.step_count, steps=k):
                     stack = [next(train_iter) for _ in range(k)]
                     self._measure_wire_once(stack[0])
                     batches = jax.device_put(
                         jax.tree.map(lambda *xs: np.stack(xs), *stack),
                         chunk_spec)
-                self._check_retrace("chunk", self.params, self.state,
-                                    self.vote_health, self._frozen_arg(),
-                                    batches)
+                with span("retrace_check", step=self.step_count):
+                    self._check_retrace("chunk", self.params, self.state,
+                                        self.vote_health, self._frozen_arg(),
+                                        batches)
                 with self.profiler.annotate(self.step_count), \
-                        jr.span("dispatch", step=self.step_count, steps=k):
+                        span("dispatch", step=self.step_count, steps=k):
                     (self.params, self.state, self.vote_health,
                      metrics) = self._train_chunk(
                         self.params, self.state, self.vote_health,
                         self._frozen_arg(), batches, base_key
                     )
                 self.step_count += k
-                self.timer.tick(k)
             else:
-                with jr.span("data_wait", step=self.step_count, steps=1):
+                with span("data_wait", step=self.step_count, steps=1):
                     raw_batch = next(train_iter)
                     self._measure_wire_once(raw_batch)
                     batch = jax.device_put(raw_batch, data_spec)
-                self._check_retrace("step", self.params, self.state,
-                                    self.vote_health, self._frozen_arg(),
-                                    batch)
+                with span("retrace_check", step=self.step_count):
+                    self._check_retrace("step", self.params, self.state,
+                                        self.vote_health, self._frozen_arg(),
+                                        batch)
                 with self.profiler.annotate(self.step_count), \
-                        jr.span("dispatch", step=self.step_count, steps=1):
+                        span("dispatch", step=self.step_count, steps=1):
                     (self.params, self.state, self.vote_health,
                      metrics) = self._train_step(
                         self.params, self.state, self.vote_health,
                         self._frozen_arg(), batch, base_key
                     )
                 self.step_count += 1
-                self.timer.tick()
                 advanced = 1
+            for line in compile_cache.new_lines():
+                # which program was traced, lowered, compiled or loaded,
+                # said once, when it happens (the first dispatch; a
+                # retrace later in the run)
+                emit(line)
             self.profiler.maybe_stop(self.step_count, sync=metrics)
             if self._guard is not None:
                 # pop the guard's [W]-vector observations before anything
@@ -1670,7 +1692,8 @@ class Trainer:
                 obs = {k: metrics.pop(k) for k in vote_guard.OBS_KEYS
                        if k in metrics}
                 if self._guard_pending is not None:
-                    self._apply_guard(*self._guard_pending)
+                    with span("guard_apply", step=self.step_count):
+                        self._apply_guard(*self._guard_pending)
                 self._guard_pending = (self.step_count, obs, advanced)
             if cfg.nan_sentinel:
                 # trailing isfinite watch: the PREVIOUS dispatch's metrics
@@ -1678,7 +1701,8 @@ class Trainer:
                 # pipeline stays full while anomalies are still caught one
                 # dispatch late (the bundle names the tripping step)
                 if self._sentinel_pending is not None:
-                    self._check_sentinel(*self._sentinel_pending)
+                    with span("sentinel_check", step=self.step_count):
+                        self._check_sentinel(*self._sentinel_pending)
                 self._sentinel_pending = (self.step_count, metrics)
             if (self._anomaly_deadline is not None
                     and self.step_count >= self._anomaly_deadline):
@@ -1691,136 +1715,131 @@ class Trainer:
             # boundary tests are "crossed a multiple of N during this
             # dispatch" so chunked advances never skip a log/eval/save
             if self.step_count % cfg.logging_steps < advanced or self.step_count == total:
-                if self.cfg.journal:
-                    # the ONE device drain the loop already pays per log
-                    # interval (the host-float below blocks on it either
-                    # way) made explicit, so the journal sees device-bound
-                    # time as a span instead of smearing it into the
-                    # logging bucket — no sync is added that the float()
-                    # conversions were not about to perform
-                    with jr.span("device_wait", step=self.step_count):
-                        jax.block_until_ready(metrics)
-                _t_log = time.monotonic()
-                m = {k: float(v) for k, v in metrics.items()}
-                now = time.time()
-                steps_per_sec = (self.step_count - s_last) / max(now - t_last, 1e-9)
-                m["tokens_per_sec"] = tokens_per_step * steps_per_sec
-                # the step just executed ran with optimizer count step_count-1
-                m["lr"] = float(self._schedule(jnp.asarray(self.step_count - 1, jnp.float32)))
-                m.update(self.timer.stats())
-                comm = self.comm_stats(steps_per_sec)
-                if comm:
-                    m["comm_bytes_per_step"] = comm["comm_bytes_per_step"]
-                    m["comm_mbytes_per_sec"] = comm.get("comm_mbytes_per_sec", 0.0)
-                    # analytic pipelineable wire share under vote_buckets
-                    # (profiling.comm_report); the measured counterpart is
-                    # bench.py's overlap-ablation comm_overlap_frac
-                    m["comm_overlap_frac"] = comm.get("comm_overlap_frac", 0.0)
-                    if "dcn_overlap_frac" in comm:
-                        # analytic share of the hier wire's level-2 latency
-                        # off the critical path under --dcn_pipeline_depth;
-                        # measured counterpart: bench_dcn's depth ablation
-                        m["dcn_overlap_frac"] = comm["dcn_overlap_frac"]
-                from distributed_lion_tpu.parallel.collectives import (
-                    DCN_WAIT,
-                )
+                # the ONE device drain the loop already pays per log
+                # interval (the host-float below blocks on it either way)
+                # made explicit, so a listener sees device-bound time as a
+                # span instead of smearing it into the logging bucket — no
+                # sync is added that the float() conversions were not
+                # about to perform
+                with span("device_wait", step=self.step_count):
+                    jax.block_until_ready(metrics)
+                # everything since the device drain — metric assembly,
+                # telemetry drain, the strict-JSON write — is the logging
+                # tax
+                with span("logging_drain", step=self.step_count):
+                    m = {k: float(v) for k, v in metrics.items()}
+                    now = time.time()
+                    steps_per_sec = (self.step_count - s_last) / max(now - t_last, 1e-9)
+                    m["tokens_per_sec"] = tokens_per_step * steps_per_sec
+                    # the step just executed ran with optimizer count step_count-1
+                    m["lr"] = float(self._schedule(jnp.asarray(self.step_count - 1, jnp.float32)))
+                    comm = self.comm_stats(steps_per_sec)
+                    if comm:
+                        m["comm_bytes_per_step"] = comm["comm_bytes_per_step"]
+                        m["comm_mbytes_per_sec"] = comm.get("comm_mbytes_per_sec", 0.0)
+                        # analytic pipelineable wire share under vote_buckets
+                        # (profiling.comm_report); the measured counterpart is
+                        # bench.py's overlap-ablation comm_overlap_frac
+                        m["comm_overlap_frac"] = comm.get("comm_overlap_frac", 0.0)
+                        if "dcn_overlap_frac" in comm:
+                            # analytic share of the hier wire's level-2 latency
+                            # off the critical path under --dcn_pipeline_depth;
+                            # measured counterpart: bench_dcn's depth ablation
+                            m["dcn_overlap_frac"] = comm["dcn_overlap_frac"]
+                    from distributed_lion_tpu.parallel.collectives import (
+                        DCN_WAIT,
+                    )
 
-                dcn_waits = DCN_WAIT.pop()
-                if dcn_waits:
-                    # the emulated DCN link's measured residual (unhidden)
-                    # wait this interval — nonzero only under the dcn_delay
-                    # fault (train/resilience registry); sub-delay values
-                    # are the cross-step pipeline visibly hiding the leg
-                    wait_s = sum(dcn_waits.values())
-                    m["dcn_wait_s"] = wait_s
+                    dcn_waits = DCN_WAIT.pop()
+                    if dcn_waits:
+                        # the emulated DCN link's measured residual (unhidden)
+                        # wait this interval — nonzero only under the dcn_delay
+                        # fault (train/resilience registry); sub-delay values
+                        # are the cross-step pipeline visibly hiding the leg
+                        wait_s = sum(dcn_waits.values())
+                        m["dcn_wait_s"] = wait_s
+                        if self.cfg.journal:
+                            # thread-tagged: the wait happened inside the
+                            # device program (run_analyze excludes it from
+                            # step-thread attribution — it overlaps dispatch)
+                            jr.record({"kind": "span", "name": "dcn_wait",
+                                       "dur": round(wait_s, 9),
+                                       "step": self.step_count,
+                                       "thread": "dcn-link"})
+                    hbm = peak_hbm_gb()
+                    if hbm is not None:
+                        m["peak_hbm_gb"] = hbm
+                    if self.checkpointer:
+                        # seconds the loop spent blocked on checkpointing since
+                        # the last log — async saves keep this near 0 while the
+                        # sync path pays the full serialize+write here
+                        m["ckpt_stall_s"] = self.checkpointer.pop_stall_s()
+                    if self.retrace_count:
+                        # recompilations the retrace guard observed (should stay
+                        # 0 for the whole run; see --retrace_guard)
+                        m["retraces"] = self.retrace_count
+                    if self._telemetry_on:
+                        # drain the on-device accumulator (the interval's ONLY
+                        # telemetry host transfer) and reset its counters; the
+                        # previous election carries over so flip rates stay
+                        # continuous across intervals
+                        vote = telemetry.drain(self.vote_health,
+                                               self._margin_exact)
+                        self.vote_health = telemetry.reset_counters(
+                            self.vote_health)
+                        m.update({f"vote/{k}": v for k, v in vote.items()})
+                        if self._wire_measured:
+                            mw = self._wire_measured
+                            m["comm_measured_bytes_per_step"] = mw[
+                                "bytes_per_step"]
+                            m["comm_measured_calls_per_step"] = mw[
+                                "calls_per_step"]
+                            if mw.get("dcn_bytes_per_step"):
+                                m["comm_measured_dcn_bytes_per_step"] = mw[
+                                    "dcn_bytes_per_step"]
+                            if comm:
+                                # analytic-vs-measured drift, a first-class
+                                # metric: 0 unless the accounting and the
+                                # collectives have diverged
+                                m["comm_drift_bytes"] = (
+                                    mw["bytes_per_step"]
+                                    - comm["comm_bytes_per_step"])
+                        skew = telemetry.host_step_skew(self.step_count)
+                        if skew is not None:
+                            m["host_step_skew"] = skew
+                        per_dev = peak_hbm_per_device()
+                        if per_dev is not None and len(per_dev) > 1:
+                            m["peak_hbm_per_device"] = per_dev
+                    if self._guard is not None:
+                        # scalar guard health for the record stream (the [W]
+                        # observation vectors were popped above)
+                        m.update(self._guard.summary())
+                    if self._cplane is not None:
+                        m.update(self._cplane.summary())
+                    if hasattr(train_iter, "health_metrics"):
+                        # input-pipeline health (e.g. the native loader's
+                        # skipped_shards / shard_read_retries counters) rides
+                        # the same strict-JSON metrics stream
+                        m.update(train_iter.health_metrics())
+                    t_last, s_last = now, self.step_count
+                    self.logger.log(self.step_count, m, prefix="train")
+                    self._metrics_window.append({"step": self.step_count, **m})
+                    history.append({"step": self.step_count, **m})
                     if self.cfg.journal:
-                        # thread-tagged: the wait happened inside the
-                        # device program (run_analyze excludes it from
-                        # step-thread attribution — it overlaps dispatch)
-                        jr.record({"kind": "span", "name": "dcn_wait",
-                                   "dur": round(wait_s, 9),
-                                   "step": self.step_count,
-                                   "thread": "dcn-link"})
-                hbm = peak_hbm_gb()
-                if hbm is not None:
-                    m["peak_hbm_gb"] = hbm
-                if self.checkpointer:
-                    # seconds the loop spent blocked on checkpointing since
-                    # the last log — async saves keep this near 0 while the
-                    # sync path pays the full serialize+write here
-                    m["ckpt_stall_s"] = self.checkpointer.pop_stall_s()
-                if self.retrace_count:
-                    # recompilations the retrace guard observed (should stay
-                    # 0 for the whole run; see --retrace_guard)
-                    m["retraces"] = self.retrace_count
-                if self._telemetry_on:
-                    # drain the on-device accumulator (the interval's ONLY
-                    # telemetry host transfer) and reset its counters; the
-                    # previous election carries over so flip rates stay
-                    # continuous across intervals
-                    vote = telemetry.drain(self.vote_health,
-                                           self._margin_exact)
-                    self.vote_health = telemetry.reset_counters(
-                        self.vote_health)
-                    m.update({f"vote/{k}": v for k, v in vote.items()})
-                    if self._wire_measured:
-                        mw = self._wire_measured
-                        m["comm_measured_bytes_per_step"] = mw[
-                            "bytes_per_step"]
-                        m["comm_measured_calls_per_step"] = mw[
-                            "calls_per_step"]
-                        if mw.get("dcn_bytes_per_step"):
-                            m["comm_measured_dcn_bytes_per_step"] = mw[
-                                "dcn_bytes_per_step"]
-                        if comm:
-                            # analytic-vs-measured drift, a first-class
-                            # metric: 0 unless the accounting and the
-                            # collectives have diverged
-                            m["comm_drift_bytes"] = (
-                                mw["bytes_per_step"]
-                                - comm["comm_bytes_per_step"])
-                    skew = telemetry.host_step_skew(self.step_count)
-                    if skew is not None:
-                        m["host_step_skew"] = skew
-                    per_dev = peak_hbm_per_device()
-                    if per_dev is not None and len(per_dev) > 1:
-                        m["peak_hbm_per_device"] = per_dev
-                if self._guard is not None:
-                    # scalar guard health for the record stream (the [W]
-                    # observation vectors were popped above)
-                    m.update(self._guard.summary())
-                if self._cplane is not None:
-                    m.update(self._cplane.summary())
-                if hasattr(train_iter, "health_metrics"):
-                    # input-pipeline health (e.g. the native loader's
-                    # skipped_shards / shard_read_retries counters) rides
-                    # the same strict-JSON metrics stream
-                    m.update(train_iter.health_metrics())
-                t_last, s_last = now, self.step_count
-                self.logger.log(self.step_count, m, prefix="train")
-                self._metrics_window.append({"step": self.step_count, **m})
-                history.append({"step": self.step_count, **m})
-                if self.cfg.journal:
-                    # the multi-host step-skew heartbeat becomes a journal
-                    # event (PR 2 only PRINTED it, and only under
-                    # --telemetry): run_analyze derives cross-host skew
-                    # percentiles from these per-rank step_log records
-                    jskew = (m.get("host_step_skew") if self._telemetry_on
-                             else telemetry.host_step_skew(self.step_count))
-                    jr.event("step_log", step=self.step_count,
-                             steps_per_sec=round(steps_per_sec, 6),
-                             **({} if jskew is None
-                                else {"skew_steps": int(jskew)}))
-                    # everything since the device drain — metric assembly,
-                    # telemetry drain, the strict-JSON write — is the
-                    # logging tax
-                    jr.record({"kind": "span", "name": "logging_drain",
-                               "dur": round(time.monotonic() - _t_log, 9),
-                               "step": self.step_count})
-                    jr.flush()
+                        # the multi-host step-skew heartbeat becomes a journal
+                        # event (PR 2 only PRINTED it, and only under
+                        # --telemetry): run_analyze derives cross-host skew
+                        # percentiles from these per-rank step_log records
+                        jskew = (m.get("host_step_skew") if self._telemetry_on
+                                 else telemetry.host_step_skew(self.step_count))
+                        jr.event("step_log", step=self.step_count,
+                                 steps_per_sec=round(steps_per_sec, 6),
+                                 **({} if jskew is None
+                                    else {"skew_steps": int(jskew)}))
+                jr.flush()
 
             if eval_blocks is not None and self.step_count % cfg.eval_steps < advanced:
-                with jr.span("eval", step=self.step_count):
+                with span("eval", step=self.step_count):
                     history.append({"step": self.step_count,
                                     **self.evaluate(eval_blocks)})
 

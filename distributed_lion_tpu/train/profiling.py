@@ -9,9 +9,9 @@ profiling is a first-class trainer subsystem:
   every step with ``StepTraceAnnotation`` so the trace viewer groups ops by
   step. Capturing a bounded window (not the whole run) keeps trace files
   small and the steady-state steps representative.
-- :class:`StepTimer` — lightweight wall-clock EMA of step latency with
-  percentile tracking, always on (no device sync: it times the *dispatch*
-  cadence which equals steady-state step time once the pipeline fills).
+  While its window is open the run journal's span gate is on too
+  (train/journal.span reads ``TraceAnnotation.is_enabled()``), so the
+  loop's spans land in the same trace.
 - :func:`comm_report` — analytic bytes-on-the-wire accounting for the vote
   collective (ops/codec.wire_bytes_per_param), the number BASELINE.md's
   ≤1/32-of-bf16-all-reduce budget is judged against.
@@ -19,11 +19,7 @@ profiling is a first-class trainer subsystem:
 
 from __future__ import annotations
 
-import collections
-import time
 from typing import Optional
-
-import numpy as np
 
 from distributed_lion_tpu.ops.codec import wire_bytes_per_param
 from distributed_lion_tpu.train.journal import emit
@@ -86,44 +82,6 @@ class StepProfiler:
     def close(self, sync=None) -> None:
         if self._active:
             self.maybe_stop(self.stop_step, sync)
-
-
-class StepTimer:
-    """Step-latency stats from dispatch timestamps: EMA + p50/p95 over a
-    sliding window."""
-
-    def __init__(self, ema_alpha: float = 0.1, window: int = 256):
-        self.alpha = ema_alpha
-        self.window = window
-        # deque(maxlen) evicts in O(1); the old list.pop(0) shifted the
-        # whole 256-sample window on every steady-state step
-        self._samples: collections.deque[float] = collections.deque(
-            maxlen=window)
-        self.ema: Optional[float] = None
-        self._last: Optional[float] = None
-
-    def tick(self, n_steps: int = 1) -> Optional[float]:
-        """Call once per dispatch covering ``n_steps`` optimizer steps;
-        returns per-step latency (None on first call)."""
-        now = time.perf_counter()
-        if self._last is None:
-            self._last = now
-            return None
-        dt = (now - self._last) / max(n_steps, 1)
-        self._last = now
-        self.ema = dt if self.ema is None else self.alpha * dt + (1 - self.alpha) * self.ema
-        self._samples.append(dt)
-        return dt
-
-    def stats(self) -> dict:
-        if not self._samples:
-            return {}
-        arr = np.asarray(self._samples)
-        return {
-            "step_time_ema_s": float(self.ema),
-            "step_time_p50_s": float(np.percentile(arr, 50)),
-            "step_time_p95_s": float(np.percentile(arr, 95)),
-        }
 
 
 def peak_hbm_per_device() -> Optional[list[float]]:

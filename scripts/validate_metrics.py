@@ -293,7 +293,8 @@ def validate_journal_file(path: str) -> list[str]:
     """Strict-schema check for run-journal JSONL (train/journal.py): the
     per-line single-doc + allow_nan=False discipline of validate_file, plus
     the journal record contract — kind/name/t/rank on every record, a
-    finite non-negative dur on spans, scalar-or-flat-list values
+    finite non-negative dur on spans (whose optional id/parent are an
+    integer and an integer or null), scalar-or-flat-list values
     throughout. Returns violation strings (empty = valid)."""
     errors: list[str] = []
     try:
@@ -332,6 +333,14 @@ def validate_journal_file(path: str) -> list[str]:
                 _finite_number(rec.get("dur")) and rec["dur"] >= 0):
             errors.append(f"{path}:{i}: span without a finite non-negative "
                           "'dur'")
+        # the span tree (journal.span): 'id' an integer, 'parent' an
+        # integer or null; both optional (synthetic spans carry neither)
+        for k, may_be_null in (("id", False), ("parent", True)):
+            if k in rec and not ((rec[k] is None and may_be_null) or (
+                    isinstance(rec[k], int)
+                    and not isinstance(rec[k], bool))):
+                errors.append(f"{path}:{i}: {k!r} must be an integer"
+                              + (" or null" if may_be_null else ""))
         for k, v in rec.items():
             if _scalar_ok(v):
                 continue

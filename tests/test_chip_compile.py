@@ -15,6 +15,7 @@ back without a chip).
 """
 
 import os
+import re
 import time
 
 import jax
@@ -49,6 +50,13 @@ def topo():
 @pytest.fixture(scope="module")
 def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
+
+
+def _named_custom_call(text, name):
+    """A Mosaic custom-call instruction ``%<name>[.<n>] = ... custom-call``
+    in compiled HLO text."""
+    return re.search(r"%%%s(\.\d+)? = [^\n]*custom-call\([^\n]*"
+                     r'custom_call_target="tpu_custom_call"' % name, text)
 
 
 def _compile(fn, *args):
@@ -104,6 +112,10 @@ def test_lion_kernels_compile_on_odd_window(one_chip):
 
     text, _ = _compile(fn, s("float32"), s("float32"), s("float32"))
     assert text.count("tpu_custom_call") >= 3
+    # each Mosaic custom-call's instruction is named after its kernel, which
+    # is the name a device trace shows (not ``fn.<n>``)
+    for kernel in ("lion_ballot", "lion_stats", "lion_apply"):
+        assert _named_custom_call(text, kernel), kernel
 
 
 def test_sign_codec_compile_time_is_flat_in_n(one_chip):
@@ -190,7 +202,12 @@ def test_paged_decode_compiles_with_donated_pool(one_chip, kind):
     compiled = jax.jit(fn, donate_argnums=(1,)).lower(
         params, pages, i32(b, s_len), i32(b, per_seq), i32(b)).compile()
     assert time.monotonic() - t0 < 120
-    assert "input_output_alias" in compiled.as_text()  # pool really aliased
+    text = compiled.as_text()
+    assert "input_output_alias" in text  # pool really aliased
+    # the named regions reach the compiled ops' metadata
+    for scope in ("embed", "attn", "mlp", "head", "paged_scatter",
+                  "paged_gather", "paged_attn"):
+        assert re.search(r'op_name="[^"]*/%s/' % scope, text), scope
 
 
 # --------------------------------------------------------- 4-device vote step
@@ -236,6 +253,10 @@ def test_vote_step_compiles_on_2x2_mesh_with_auto_wire(topo):
         params, grads, state).compile().as_text()
     assert time.monotonic() - t0 < 120
     assert "tpu_custom_call" in text
+    assert _named_custom_call(text, "lion_ballot")
+    assert _named_custom_call(text, "lion_apply")
+    for scope in ("vote/pack", "vote/unpack", "vote/tally", "vote/wire"):
+        assert re.search(r'op_name="[^"]*/%s/' % scope, text), scope
     # phase 1 of the packed wire; the compiler is free to rewrite phase 2's
     # all_gather (it becomes dynamic-update-slice + all-reduce on v5e)
     assert "all-to-all" in text

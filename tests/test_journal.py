@@ -110,6 +110,45 @@ def test_bit_identity_journal_on_vs_off(mesh8, tmp_path, kern, buckets):
         np.asarray(a), np.asarray(b)), runs[True][1], runs[False][1])
 
 
+def test_bit_identity_profiler_session_on_vs_off(mesh8, tmp_path):
+    """The same pin for the span gate's other listener: with a profiler
+    session open around the whole leg (every loop span a TraceAnnotation
+    and a buffer record) losses and params are bit-identical to the run
+    with nothing listening, and the buffer holds the loop's spans with
+    their step."""
+    runs = {}
+    for on in (False, True):
+        cfg = _tiny_cfg(kernel="pallas", vote_buckets=4, nan_sentinel=True)
+        if on:
+            opts = jax.profiler.ProfileOptions()
+            opts.python_tracer_level = 0
+            jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+        try:
+            tr, hist = _train(mesh8, cfg)
+        finally:
+            if on:
+                jax.profiler.stop_trace()
+        runs[on] = ([h["loss"] for h in hist if "loss" in h],
+                    jax.device_get(tr.params))
+        tr.close()
+    assert runs[True][0] == runs[False][0]
+    jax.tree.map(lambda a, b: np.testing.assert_array_equal(
+        np.asarray(a), np.asarray(b)), runs[True][1], runs[False][1])
+    spans = journal.traced()
+    by_name = {}
+    for r in spans:
+        by_name.setdefault(r["name"], []).append(r)
+    assert [r["step"] for r in by_name["dispatch"]] == [0, 1, 2]
+    assert {"data_wait", "retrace_check", "dispatch", "sentinel_check",
+            "device_wait", "logging_drain"} <= set(by_name)
+    assert all(r["parent"] is None and r["t0"] <= r["t1"] for r in spans)
+    # the spans of one step do not overlap: they tile the loop body
+    steps = sorted((r for r in spans if r.get("step") == 1
+                    and r["name"] in ("retrace_check", "dispatch")),
+                   key=lambda r: r["t0"])
+    assert steps[0]["t1"] <= steps[1]["t0"]
+
+
 # --------------------------------------------------- recorder micro-contracts
 def test_event_overhead_bounded(tmp_path):
     """The recorder rides every dispatch: per-event cost (serialize +

@@ -283,6 +283,54 @@ def test_metrics_on_is_bit_identical_to_metrics_off(variant):
     assert snap["slo"]["requests"] == len(reqs)
 
 
+@pytest.mark.parametrize("variant", [
+    {"prefix_cache": True},                      # CoW spans inside the tick
+    {"speculate": "ngram:4"},                    # draft / verify / commit
+])
+def test_profiler_session_on_is_bit_identical_to_off(variant, tmp_path):
+    """The span gate's profiler side is as free as the metrics plane: with
+    a session open (every tick a tree of TraceAnnotations and buffer
+    records, ``serve/metrics`` among them) the token streams are those of
+    the bare engine."""
+    import jax
+
+    from distributed_lion_tpu.train import journal
+
+    eng_off, cfg = _tiny_engine(**variant)
+    reqs, arrivals = _workload(cfg)
+    base = eng_off.run(reqs, dict(arrivals))
+
+    eng_on, _ = _tiny_engine(metrics=True, **variant)
+    reqs2, _ = _workload(cfg)
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        done = eng_on.run(reqs2, dict(arrivals))
+    finally:
+        jax.profiler.stop_trace()
+    assert {i: (c.tokens, c.reason) for i, c in done.items()} == \
+        {i: (c.tokens, c.reason) for i, c in base.items()}
+    spans = journal.traced()
+    ids = {r["id"]: r for r in spans}
+    names = {r["name"] for r in spans}
+    assert {"serve/tick", "serve/admit", "serve/prefill", "serve/token_read",
+            "serve/commit", "serve/metrics"} <= names
+    assert ({"serve/draft", "serve/verify"} if "speculate" in variant
+            else {"serve/decode_tick", "serve/decode_build",
+                  "serve/decode_dispatch"}) <= names
+    # every span but the ticks hangs under a span of the same tick
+    for r in spans:
+        if r["name"] == "serve/tick":
+            assert r["parent"] is None
+        elif r["parent"] in ids:
+            assert ids[r["parent"]]["t0"] <= r["t0"]
+            assert r["t1"] <= ids[r["parent"]]["t1"]
+    prefills = [r for r in spans if r["name"] == "serve/prefill"]
+    assert sorted(r["req_id"] for r in prefills) == sorted(
+        str(q.req_id) for q in reqs2)
+
+
 def test_metrics_on_fleet_migration_identity_and_aggregation():
     """The fleet leg of the matrix: a metrics-armed 2-replica fleet with
     an injected replica crash produces the same token streams as the
