@@ -373,6 +373,32 @@ def assert_donated(engine) -> None:
           f"page pool not donated off-CPU: {donated}")
 
 
+def assert_pool_in_place(engine) -> None:
+    """The gate on the pool's resident layout: compile ``decode``,
+    ``prefill`` and ``cow`` as the engine jitted them and fail if the
+    optimized HLO copies anything the size of a pool leaf (a re-layout of
+    the pool inside a dispatch: serve/kv_cache's module note), or if the
+    decode program lacks the ``paged_attn`` kernel."""
+    from distributed_lion_tpu.analysis.serve_check import (
+        lowered_dispatch,
+        pool_leaf_copies,
+    )
+
+    leaf = engine.pages[0]["k"]
+    bucket = engine.cfg.block_size * engine.cfg.max_blocks_per_seq
+    for kind in ("decode", "prefill", "cow"):
+        lowered = lowered_dispatch(engine, kind, bucket)
+        copies = pool_leaf_copies(lowered.compile().as_text(), leaf)
+        check(not copies, f"{kind} copies the pool: {copies[:2]}")
+        if kind == "decode":
+            kernels = mosaic_kernels(lowered.as_text())
+            check("paged_attn" in kernels,
+                  f"decode holds no paged_attn kernel: {kernels}")
+    log(f"  decode, prefill@{bucket} and cow hold no copy of a pool leaf "
+        f"({leaf.dtype.name}{list(leaf.shape)}); decode holds the "
+        "paged_attn kernel")
+
+
 def phase_serve(out_dir: str) -> None:
     import jax
     import jax.numpy as jnp
@@ -413,6 +439,12 @@ def phase_serve(out_dir: str) -> None:
     check(stats["decode_ticks"] > 0 and counts.get("prefill", 0) >= 2,
           (stats, counts))
     assert_donated(engine)
+    assert_pool_in_place(engine)
+    check(stats["decode_attn_kernel_ticks"] == stats["decode_ticks"], stats)
+    log(f"  decode_attn_kernel_ticks {stats['decode_attn_kernel_ticks']} of "
+        f"{stats['decode_ticks']} decode ticks; kv_pages_read "
+        f"{stats['kv_pages_read']} of kv_pages_table "
+        f"{stats['kv_pages_table']}")
     log(f"  run_serve: {len(records)} requests complete "
         f"({[r['reason'] for r in records]}), pool back to "
         f"{engine.tables.free_blocks}/{engine.tables.num_blocks} free, "
@@ -438,23 +470,47 @@ def phase_serve(out_dir: str) -> None:
     toks = np.zeros((n_seq, width), np.int32)
     for row, seq in zip(toks, seqs):
         row[:len(seq)] = seq
-    lens = jnp.asarray([len(seq) for seq in seqs], jnp.int32)
     dense = jax.jit(lambda p, t: decode(
         p, t, init_cache(n_seq, attended), 0)[0])(params, toks)
     pages = init_pages(cfg.n_layer, n_seq * max_blocks, block, cfg.n_head,
                        cfg.head_dim, cfg.compute_dtype)
-    # shuffled page ownership: the gather must work through the table
+    # shuffled page ownership: every read must go through the table
     tables = jnp.arange(n_seq * max_blocks, dtype=jnp.int32)[::-1].reshape(
         n_seq, max_blocks)
-    paged = jax.jit(lambda p, t, pg: gpt2_decode_paged(
+    # as the engine serves them: the prompts in one window (the gather
+    # path), then the served tokens one a step (S = 1: the paged_attn
+    # kernel), each row at its own position
+    gots = [rec["tokens"] for rec in records]
+    plens = np.asarray([len(s) - len(g) + 1 for s, g in zip(seqs, gots)])
+    p_width = -(-int(plens.max()) // block) * block
+    window, pages = jax.jit(lambda p, t, pg: gpt2_decode_paged(
         p, t, cfg, pg, tables, jnp.zeros((n_seq,), jnp.int32),
-        jnp.arange(width)[None, :] < lens[:, None])[0])(params, toks, pages)
+        jnp.arange(p_width)[None, :] < jnp.asarray(plens)[:, None]))(
+            params, toks[:, :p_width], pages)
+    step = jax.jit(lambda p, t, pg, pos, act: gpt2_decode_paged(
+        p, t, cfg, pg, tables, pos, act), donate_argnums=(2,))
+    text = step.lower(params, np.zeros((n_seq, 1), np.int32), pages,
+                      jnp.asarray(plens, jnp.int32),
+                      np.ones((n_seq, 1), bool)).as_text()
+    check("paged_attn" in mosaic_kernels(text),
+          f"the single-token step holds no paged_attn kernel: "
+          f"{mosaic_kernels(text)}")
+    rows = [[np.asarray(window[i, n - 1], np.float32)]
+            for i, n in enumerate(plens)]
+    for j in range(max(map(len, gots)) - 1):
+        act = np.asarray([j < len(g) - 1 for g in gots])
+        nxt = np.asarray([g[j] if a else 0 for g, a in zip(gots, act)],
+                         np.int32)
+        logits, pages = step(params, nxt[:, None], pages,
+                             jnp.asarray(plens + j, jnp.int32), act[:, None])
+        for i in np.flatnonzero(act):
+            rows[i].append(np.asarray(logits[i, 0], np.float32))
     worst, checked, total = 0.0, 0, 0
     for i, (prompt, rec) in enumerate(zip(SERVE_PROMPTS, records)):
         got = np.asarray(rec["tokens"])
         first = len(seqs[i]) - len(got)  # the last prompt position
         d = np.asarray(dense[i, first:len(seqs[i])], np.float32)
-        g = np.asarray(paged[i, first:len(seqs[i])], np.float32)
+        g = np.stack(rows[i])
         check(np.isfinite(d).all() and d.shape == (len(got), VOCAB), d.shape)
         worst = max(worst, float(np.abs(d - g).max()))
         top2 = np.sort(d, axis=-1)[:, -2:]

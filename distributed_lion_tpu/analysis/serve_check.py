@@ -57,6 +57,7 @@ Run it::
 from __future__ import annotations
 
 import json
+import re
 from typing import Any, Dict, List, Optional
 
 import jax
@@ -225,6 +226,29 @@ def _dispatch_args(eng, kind: str, window: Optional[int] = None) -> tuple:
     if kind == "cow":
         return (eng.pages,) + rest
     return (eng.params, eng.pages) + rest
+
+
+def lowered_dispatch(eng, kind: str, window: Optional[int] = None):
+    """The registered dispatch lowered on the engine's own example
+    operands (``.as_text()`` names its Mosaic kernels, ``.compile()`` is
+    what the backend runs) — ``chip_smoke.py`` reads both on the chip."""
+    return eng._dispatches[kind]["jitted"].lower(
+        *_dispatch_args(eng, kind, window))
+
+
+def pool_leaf_copies(hlo_text: str, leaf) -> List[str]:
+    """``copy`` instructions of optimized HLO whose result is as large as
+    the pool leaf ``leaf`` (anything with ``dtype`` and ``size``): a
+    re-layout of the pool inside a dispatch (serve/kv_cache's module
+    note). One rule for ``chip_smoke.py`` and tests/test_chip_compile.py."""
+    out = []
+    for m in re.finditer(r"= (\w+)\[([\d,]+)\]\S* copy\(", hlo_text):
+        n = 1
+        for d in m[2].split(","):
+            n *= int(d)
+        if m[1] == jnp.dtype(leaf.dtype).name and n == leaf.size:
+            out.append(m[0])
+    return out
 
 
 def _prefill_buckets(scfg) -> List[int]:

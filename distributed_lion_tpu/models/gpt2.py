@@ -749,7 +749,7 @@ def _paged_attention_block(x, p, cfg: GPT2Config, c, tables, pos, valid,
     k_pages = paged_scatter_kv(c["k"], tables, pos, k.astype(c["k"].dtype), valid)
     v_pages = paged_scatter_kv(c["v"], tables, pos, v.astype(c["v"].dtype), valid)
     out = paged_decode_attention(q.transpose(0, 2, 1, 3), k_pages, v_pages,
-                                 tables, pos)
+                                 tables, pos, kv_heads=H)
     out = out.transpose(0, 2, 1, 3).reshape(B, S, H * hd)
     out = _proj(out, p["proj"])
     if tp_axis is not None:
@@ -766,7 +766,8 @@ def gpt2_decode_paged(params: dict, tokens: jnp.ndarray, cfg: GPT2Config,
     """Block-table decode (the serving engine's model hook): ``tokens``
     [B, S] where row b's tokens sit at absolute positions
     ``pos[b] .. pos[b]+S-1`` of its own sequence; ``pages`` is the
-    per-layer page pool ({"k","v"} of [num_blocks, block_size, H, hd]),
+    per-layer page pool ({"k","v"} leaves laid out by
+    serve/kv_cache.init_pages; [num_blocks, block_size, H, hd] reads too),
     ``tables`` [B, blocks_per_seq] the per-row block tables, ``valid``
     optional [B, S] (False = right-pad tail of a bucketed prefill — no
     page write, logits discarded by the caller). Returns (logits

@@ -351,7 +351,8 @@ def _paged_attention_block(x, p, cfg: LlamaConfig, c, tables, pos, cos, sin,
     k = apply_rope(k, cos, sin).transpose(0, 2, 1, 3)  # back to [B, S, KV, hd]
     k_pages = paged_scatter_kv(c["k"], tables, pos, k.astype(c["k"].dtype), valid)
     v_pages = paged_scatter_kv(c["v"], tables, pos, v.astype(c["v"].dtype), valid)
-    out = paged_decode_attention(q, k_pages, v_pages, tables, pos)
+    out = paged_decode_attention(q, k_pages, v_pages, tables, pos,
+                                 kv_heads=KV)
     out = out.transpose(0, 2, 1, 3).reshape(B, S, H * hd)
     out = _matmul(out, p["wo"])
     if tp_axis is not None:
@@ -365,8 +366,9 @@ def llama_decode_paged(params: dict, tokens: jnp.ndarray, cfg: LlamaConfig,
     """Block-table decode (the serving engine's model hook): row b's
     ``tokens`` [B, S] sit at positions ``pos[b] .. pos[b]+S-1`` of its own
     sequence (rotary angles gathered per row); ``pages`` is the per-layer
-    {"k","v"} pool of [num_blocks, block_size, n_kv_head, hd] (GQA: pages
-    store kv heads un-repeated, like the dense cache). Returns (logits
+    {"k","v"} pool laid out by serve/kv_cache.init_pages
+    ([num_blocks, block_size, n_kv_head, hd] reads too; GQA: pages store
+    kv heads un-repeated, like the dense cache). Returns (logits
     [B, S, vocab] f32, updated pages). One jitted program serves both the
     bucketed prefill (S = padded prompt, ``valid`` masks the tail) and the
     rolling decode tick (S = 1, pos = per-slot lengths). With ``tp_axis``

@@ -149,13 +149,31 @@ def attention_splash(q, k, v, *, causal: bool = True,
 
 # ------------------------------------------------------------- paged decode
 # The serving engine's KV layout (serve/kv_cache.py, vLLM's PagedAttention
-# design): each layer's cache is a fixed pool of pages [num_blocks,
-# block_size, kv_heads, head_dim]; a sequence owns an ordered list of page
-# indices (its block table). Allocation/free is HOST-side table math — the
-# device functions below are pure static-shape gathers/scatters, so the
-# decode tick stays one jitted program no matter how sequences come and go.
-# The sentinel block index == num_blocks (one past the pool) makes unused
-# table entries inert: scatters drop out-of-range writes, gathers fill 0.
+# design): each layer's cache is a fixed pool of pages; a sequence owns an
+# ordered list of page indices (its block table). Allocation/free is
+# HOST-side table math — the device functions below are pure static-shape
+# gathers/scatters, so the decode tick stays one jitted program no matter
+# how sequences come and go. The sentinel block index == num_blocks (one
+# past the pool) makes unused table entries inert: scatters drop
+# out-of-range writes, gathers fill 0, the kernel never walks that far.
+#
+# A pool leaf is [num_blocks, block_size, G, W]: a page row holds G groups
+# of kv heads, each group's KV/G heads side by side in W >= KV/G * hd lanes
+# (zero-padded). The engine builds G = 1 a tensor shard and W a multiple of
+# 128 (serve/kv_cache.init_pages), the one shape whose resident layout on
+# the chip is a page = one contiguous [block_size, W] slab: the scatter
+# writes it, the gather and the kernel read it and the donated output keeps
+# it with no copy. [num_blocks, block_size, KV, hd] is the same thing with
+# one head a group (tests and tools build it; on the chip XLA keeps such a
+# leaf with num_blocks minor-most and every dispatch re-lays it out).
+#
+# Which calls take the Mosaic kernel (ops/pallas_paged_attn) is decided
+# from what the call shows, in :func:`paged_kernel_applies`: one query
+# token a row, no ``start``, a TPU backend, a pool the kernel takes as it
+# lies. Every other call (S > 1: bucketed prefill, speculative verify;
+# left-padded batches; the CPU) takes the gather path below, which is the
+# reference the kernel is tested against and stays bit-identical to the
+# dense cache.
 
 
 @jax.named_scope("paged_scatter")
@@ -164,8 +182,9 @@ def paged_scatter_kv(pages: jnp.ndarray, tables: jnp.ndarray,
                      valid=None) -> jnp.ndarray:
     """Write per-row new k (or v) rows into their block-table pages.
 
-    pages  [num_blocks, block_size, KV, hd] — one layer's pool (k or v);
-    tables [B, blocks_per_seq] int32 page ids (sentinel = num_blocks);
+    pages  [num_blocks, block_size, G, W] — one layer's pool (k or v; the
+    layout note above); tables [B, blocks_per_seq] int32 page ids
+    (sentinel = num_blocks);
     pos    [B] int32 — absolute position of each row's FIRST new token;
     new    [B, S, KV, hd] — the S new tokens' projections per row;
     valid  optional [B, S] bool — False entries are dropped (right-padded
@@ -193,7 +212,9 @@ def paged_scatter_kv(pages: jnp.ndarray, tables: jnp.ndarray,
         # out-of-range page id ⇒ the scatter drops the write
         blk = jnp.where(valid, blk, pages.shape[0])
     off = abs_pos % bs
-    flat = new.reshape((B * S,) + new.shape[2:])
+    G, W = pages.shape[2:]
+    flat = new.reshape(B * S, G, -1)  # a group's kv heads side by side
+    flat = jnp.pad(flat, ((0, 0), (0, 0), (0, W - flat.shape[-1])))
     return pages.at[blk.reshape(-1), off.reshape(-1)].set(
         flat, mode="drop", unique_indices=False)
 
@@ -227,7 +248,7 @@ def paged_copy_pages(pages: list, src: jnp.ndarray,
 
 @jax.named_scope("paged_gather")
 def paged_gather_kv(pages: jnp.ndarray, tables: jnp.ndarray) -> jnp.ndarray:
-    """[num_blocks, bs, KV, hd] pool + [B, nb] tables → [B, nb*bs, KV, hd]
+    """[num_blocks, bs, G, W] pool + [B, nb] tables → [B, nb*bs, G, W]
     contiguous per-row history (sentinel pages read as zeros — they are
     masked out of attention by the caller's position bound anyway)."""
     B, nb = tables.shape
@@ -236,21 +257,36 @@ def paged_gather_kv(pages: jnp.ndarray, tables: jnp.ndarray) -> jnp.ndarray:
     return got.reshape((B, nb * bs) + pages.shape[2:])
 
 
+def paged_kernel_applies(n_new, pool_shape, pool_dtype, start=None) -> bool:
+    """True when :func:`paged_decode_attention` takes the Mosaic kernel
+    for a call with ``n_new`` query tokens a row over such a pool (the rule
+    is in the layout note above). The serving engine asks the same
+    question to count the ticks that ran it."""
+    from distributed_lion_tpu.ops.pallas_paged_attn import kernel_takes
+
+    return (n_new == 1 and start is None
+            and jax.default_backend() == "tpu"
+            and kernel_takes(pool_shape, pool_dtype))
+
+
 @jax.named_scope("paged_attn")
 def paged_decode_attention(q, k_pages, v_pages, tables, pos,
-                           start=None):
+                           start=None, kv_heads=None):
     """Decode attention over a paged KV cache (new k/v already scattered).
 
     q [B, H, S, hd] — queries for the S newest tokens of each row (rope
-    already applied by the model); k_pages/v_pages [num_blocks, bs, KV, hd];
+    already applied by the model); k_pages/v_pages [num_blocks, bs, G, W];
     tables [B, nb]; pos [B] — absolute position of each row's first new
     token; ``start`` optional [B] — first VALID history slot (left-padded
-    batches mask the pad prefix). Returns [B, H, S, hd] in q's dtype.
+    batches mask the pad prefix); ``kv_heads`` — kv heads in a page row
+    (default: what the row's lanes hold, ``G * (W // hd)``; a caller whose
+    pool pads a whole head's lanes, as 25 heads of 64 do, says so).
+    Returns [B, H, S, hd] in q's dtype.
 
-    The gather reassembles each row's history into the SAME contiguous
-    [B, T, KV, hd] layout the dense cache holds, then runs the identical
-    masked-softmax einsum chain — so greedy decode through pages is
-    bit-identical to the dense path whenever T matches (pinned by
+    The gather path reassembles each row's history into the SAME
+    contiguous [B, T, KV, hd] layout the dense cache holds, then runs the
+    identical masked-softmax einsum chain — so greedy decode through pages
+    is bit-identical to the dense path whenever T matches (pinned by
     tests/test_serve.py). GQA kv heads are repeated at attend time, exactly
     like the dense caches store them un-repeated.
 
@@ -260,11 +296,28 @@ def paged_decode_attention(q, k_pages, v_pages, tables, pos,
     safe without extra masking — a valid query s < v only ever sees
     history plus window tokens 0..s, all freshly scattered this dispatch;
     queries at invalid positions produce garbage rows the caller discards.
+
+    The kernel path (:func:`paged_kernel_applies`) reads each row's own
+    ``ceil((pos+1)/bs)`` pages where they lie, bounded besides by the
+    row's count of real table entries: a row whose table is all sentinel
+    (an inactive slot) reads nothing and returns zeros, which is what the
+    gather path's zero-filled page gives it.
     """
     B, H, S, hd = q.shape
-    KV = k_pages.shape[2]
-    k_full = paged_gather_kv(k_pages, tables).transpose(0, 2, 1, 3)
-    v_full = paged_gather_kv(v_pages, tables).transpose(0, 2, 1, 3)
+    NB, bs, G, W = k_pages.shape
+    KV = kv_heads or G * (W // hd)
+    if paged_kernel_applies(S, k_pages.shape, k_pages.dtype, start):
+        from distributed_lion_tpu.ops.pallas_paged_attn import paged_attn
+
+        lengths = jnp.minimum(pos + 1, jnp.sum(tables < NB, axis=1) * bs)
+        return paged_attn(q[:, :, 0], k_pages, v_pages, tables, lengths,
+                          kv_heads=KV)[:, :, None]
+
+    def history(pages):  # [B, T, G, W] -> [B, KV, T, hd], pad lanes dropped
+        got = paged_gather_kv(pages, tables)[..., :KV // G * hd]
+        return got.reshape(B, -1, KV, hd).transpose(0, 2, 1, 3)
+
+    k_full, v_full = history(k_pages), history(v_pages)
     if KV != H:
         rep = H // KV
         k_full = jnp.repeat(k_full, rep, axis=1)

@@ -24,7 +24,11 @@ Scheduling (the vLLM recipe, simplified to two tick kinds):
   request's sample stream depends only on the request, NOT on which slot
   it rides or who shares the batch, which is what makes a staggered
   continuous-batching run produce outputs identical to solo runs
-  (tests/test_serve.py pins it).
+  (tests/test_serve.py pins it). On a TPU its attention is the
+  ``paged_attn`` kernel reading each row's own pages where they lie
+  (ops/attention's layout note): ``stats["decode_attn_kernel_ticks"]``
+  counts those ticks and ``kv_pages_read`` / ``kv_pages_table`` is the
+  share of the tables' width they read (both ride ``serve_stats``).
 - **evict** — EOS / ``max_new_tokens`` / cache-overflow slots release
   their page refs; the block table row goes back to sentinel, so the next
   decode tick simply ignores the slot (no recompile, the shapes never
@@ -728,7 +732,8 @@ class ServingEngine:
                                   groups=groups)
         self.pages = init_pages(model.n_layer, cfg.resolved_num_blocks(),
                                 cfg.block_size, model.kv_heads,
-                                model.head_dim, model.cache_dtype)
+                                model.head_dim, model.cache_dtype,
+                                groups=max(cfg.tp, 1))
         if pages_sharding is not None:
             self.pages = [
                 {k: jax.device_put(v, pages_sharding)
@@ -757,7 +762,19 @@ class ServingEngine:
                       "decode_tokens": 0, "prefill_tokens": 0,
                       "padded_prefill_tokens": 0, "evictions": 0,
                       "freed_pages": 0, "timeouts": 0, "resumed_requests": 0,
-                      "resumed_tokens": 0}
+                      "resumed_tokens": 0,
+                      # ticks whose decode program holds the Mosaic kernel
+                      # (every decode tick on a TPU, none on the CPU), and
+                      # the pages their rows' lengths need against the
+                      # tables' whole width, which the gather path reads
+                      "decode_attn_kernel_ticks": 0, "kv_pages_read": 0,
+                      "kv_pages_table": 0}
+        from distributed_lion_tpu.ops.attention import paged_kernel_applies
+
+        nb, bs, _, width = self.pages[0]["k"].shape
+        self._decode_kernel = paged_kernel_applies(
+            1, (nb // groups, bs, 1, width),  # one shard's share of the pool
+            model.cache_dtype)
         if self.prefix is not None:
             self.stats.update(prefix_hits=0, shared_tokens=0, cow_copies=0,
                               reclaimed_pages=0)
@@ -1437,9 +1454,14 @@ class ServingEngine:
                 self._absorb_moe_stats(st)
                 self.stats["decode_ticks"] += 1
                 self.stats["decode_tokens"] += len(active)
+                self.stats["decode_attn_kernel_ticks"] += self._decode_kernel
+                self.stats["kv_pages_table"] += (
+                    self.cfg.max_seqs * self.cfg.max_blocks_per_seq)
                 for i in active:
                     s = self.slots[i]
                     s.cache_len += 1
+                    self.stats["kv_pages_read"] += self.tables.blocks_for(
+                        s.cache_len)
                     s.last_tok = int(toks[i])
                     s.gen.append(int(toks[i]))
                     self._maybe_finish(i, completions)

@@ -1,15 +1,32 @@
 """Paged KV cache: a fixed page pool per layer + host-side block tables.
 
 The vLLM PagedAttention design (Kwon et al., 2023) mapped onto the repo's
-static-shape discipline: each layer's cache is ONE device array
-``[num_blocks, block_size, kv_heads, head_dim]`` (the pool), and a
-sequence owns an ordered list of page indices — its block table. All
+static-shape discipline: each layer's cache is ONE device array (the
+pool, laid out as below), and a sequence owns an ordered list of page
+indices — its block table. All
 allocation and free is HOST-side integer table math in this module; the
 device never sees a dynamic shape, so the decode tick stays one jitted
 program while sequences join and leave the batch (serve/engine.py). The
 device-side scatter/gather/attend primitives live in
 ``ops.attention`` (``paged_scatter_kv`` / ``paged_gather_kv`` /
 ``paged_decode_attention``).
+
+The pool's layout: ``[num_blocks, block_size, groups, W]``. A page row
+holds all its kv heads side by side, ``W = kv_heads / groups * head_dim``
+lanes rounded up to 128 (:func:`pool_row_width`; the pad lanes stay zero),
+in one group a tensor shard (the engine shards axis 2). Why not
+``[num_blocks, block_size, kv_heads, head_dim]``: the chip tiles an
+array's two minor-most dims (16 x 128 for bf16), and for 25 heads of 64 no
+order of those four dims is dense, so XLA kept such a leaf with
+``num_blocks`` minor-most — a page strewn over every tile — and every
+dispatch re-laid the whole pool out for its scatter and gather and back
+again for the donated output (77 ms of cell 2's 364 ms tick, PERF.md
+section 6, PR 24). With whole lane tiles the row-major order is the dense
+one, XLA keeps it from dispatch to dispatch, and a page is one contiguous
+``[block_size, W]`` slab: what ``paged_scatter_kv`` writes, what the
+gather path and the decode kernel (``ops/pallas_paged_attn``) read, what
+the donated output aliases. The cost is the pad: 4% at GPT-2 XL (1600 ->
+1664 lanes), none where ``kv_heads * head_dim`` is a multiple of 128.
 
 Sentinel convention: unallocated table entries hold ``num_blocks`` (one
 past the pool). Scatters to a sentinel page drop (XLA scatter
@@ -39,14 +56,22 @@ from typing import Optional
 import numpy as np
 
 
+def pool_row_width(kv_heads: int, head_dim: int) -> int:
+    """Lanes of one group's page row: its kv heads side by side, rounded
+    up to whole 128-lane tiles (the module note says why)."""
+    return -(-kv_heads * head_dim // 128) * 128
+
+
 def init_pages(n_layer: int, num_blocks: int, block_size: int,
-               kv_heads: int, head_dim: int, dtype) -> list:
+               kv_heads: int, head_dim: int, dtype, groups: int = 1) -> list:
     """The per-layer device page pool: ``[{"k", "v"}] * n_layer`` of
-    zeros ``[num_blocks, block_size, kv_heads, head_dim]``. Allocated
+    zeros ``[num_blocks, block_size, groups, W]`` (the module note;
+    ``groups`` = the tensor shards the kv heads split over). Allocated
     once at engine start — ticks update it in place (donated)."""
     import jax.numpy as jnp
 
-    shape = (num_blocks, block_size, kv_heads, head_dim)
+    shape = (num_blocks, block_size, groups,
+             pool_row_width(kv_heads // groups, head_dim))
     return [
         {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
         for _ in range(n_layer)

@@ -210,6 +210,143 @@ def test_paged_decode_compiles_with_donated_pool(one_chip, kind):
         assert re.search(r'op_name="[^"]*/%s/' % scope, text), scope
 
 
+@pytest.mark.parametrize("B,H,KV,hd", [(32, 25, 25, 64),    # GPT-2 XL
+                                       (16, 32, 32, 128),   # Llama 7B
+                                       (16, 32, 8, 128)],   # GQA 4:1
+                         ids=["gpt2xl", "llama7b", "gqa"])
+def test_paged_attn_kernel_compiles_at_real_widths(one_chip, B, H, KV, hd):
+    """The decode kernel at the widths it serves, pool at cell 2's size:
+    Mosaic takes the page slabs (25 x 64 = 1600 lanes padded to 1664), the
+    kernel keeps its name, and the pool is handed to it where it lies."""
+    from distributed_lion_tpu.analysis.serve_check import pool_leaf_copies
+    from distributed_lion_tpu.ops.pallas_paged_attn import paged_attn
+    from distributed_lion_tpu.serve.kv_cache import pool_row_width
+
+    def on_chip(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    leaf = on_chip((2048, 16, 1, pool_row_width(KV, hd)))
+    text, secs = _compile(
+        lambda q, k, v, t, n: paged_attn(q, k, v, t, n, kv_heads=KV),
+        on_chip((B, H, hd)), leaf, leaf, on_chip((B, 64), jnp.int32),
+        on_chip((B,), jnp.int32))
+    assert secs < 60
+    assert _named_custom_call(text, "paged_attn")
+    assert not pool_leaf_copies(text, leaf)
+
+
+@pytest.mark.parametrize("kind", ["decode_tick", "prefill_bucket", "cow"])
+def test_serving_programs_read_the_pool_in_place(one_chip, kind, monkeypatch):
+    """The three serving programs over the pool as the engine lays it out
+    (serve/kv_cache.init_pages), donated, at 124M widths: none copies a
+    pool leaf, and the decode tick holds the ``paged_attn`` kernel where
+    the prefill window keeps the gather. The kernel is a TPU backend's
+    choice (ops/attention.paged_kernel_applies) and this process's backend
+    is the CPU, so the test says "tpu" in its place."""
+    from distributed_lion_tpu.models.gpt2 import (
+        GPT2Config, gpt2_decode_paged, gpt2_init,
+    )
+    from distributed_lion_tpu.analysis.serve_check import pool_leaf_copies
+    from distributed_lion_tpu.ops.attention import paged_copy_pages
+    from distributed_lion_tpu.serve.kv_cache import init_pages
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = GPT2Config.gpt2_124m()
+    block, per_seq = 16, 64
+    b, s_len = (32, 1) if kind == "decode_tick" else (1, 512)
+
+    def place(tree):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=one_chip), tree)
+
+    pages = place(jax.eval_shape(lambda: init_pages(
+        cfg.n_layer, 32 * per_seq, block, cfg.n_head, cfg.head_dim,
+        cfg.compute_dtype)))
+    leaf = pages[0]["k"]
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+    if kind == "cow":
+        compiled = jax.jit(paged_copy_pages, donate_argnums=(0,)).lower(
+            pages, i32(32), i32(32)).compile()
+    else:
+        params = place(jax.eval_shape(
+            lambda: gpt2_init(jax.random.key(0), cfg)))
+
+        def fn(params, pages, toks, tables, pos):
+            valid = jnp.arange(s_len)[None, :] < jnp.maximum(pos[:, None], 1)
+            logits, pages = gpt2_decode_paged(params, toks, cfg, pages,
+                                              tables, pos, valid)
+            return jnp.argmax(logits[:, -1], -1), pages
+
+        compiled = jax.jit(fn, donate_argnums=(1,)).lower(
+            params, pages, i32(b, s_len), i32(b, per_seq), i32(b)).compile()
+    text = compiled.as_text()
+    assert "input_output_alias" in text
+    assert not pool_leaf_copies(text, leaf)
+    assert bool(_named_custom_call(text, "paged_attn")) == (
+        kind == "decode_tick")
+    if kind == "prefill_bucket":
+        assert re.search(r'op_name="[^"]*/paged_gather/', text)
+
+
+def test_tp_decode_tick_runs_the_kernel_shard_local(topo, monkeypatch):
+    """The TP engine's decode tick on two chips of the described mesh:
+    inside ``shard_map`` every rank holds its own kv-head group of the pool
+    (``[num_blocks, block_size, 1, W/tp]``) and runs ``paged_attn`` on it;
+    the only collectives stay the two row-parallel psums a layer."""
+    from distributed_lion_tpu.models.gpt2 import (
+        GPT2Config, gpt2_decode_paged, gpt2_init,
+    )
+    from distributed_lion_tpu.analysis.serve_check import pool_leaf_copies
+    from distributed_lion_tpu.parallel.mesh import TENSOR_AXIS
+    from distributed_lion_tpu.parallel.tensor_parallel import gpt2_param_specs
+    from distributed_lion_tpu.serve.kv_cache import init_pages
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = GPT2Config.gpt2_124m()
+    tp, block, per_seq, b = 2, 16, 64, 32
+    mesh = Mesh(np.asarray(topo.devices[:tp]), (TENSOR_AXIS,))
+    specs = gpt2_param_specs(cfg)
+    pool_spec = P(None, None, TENSOR_AXIS, None)
+
+    def place(tree, spec_tree):
+        return jax.tree.map(
+            lambda x, sp: jax.ShapeDtypeStruct(
+                x.shape, x.dtype, sharding=NamedSharding(mesh, sp)),
+            tree, spec_tree)
+
+    params = place(jax.eval_shape(lambda: gpt2_init(jax.random.key(0), cfg)),
+                   specs)
+    pages = jax.eval_shape(lambda: init_pages(
+        cfg.n_layer, b * per_seq, block, cfg.n_head, cfg.head_dim,
+        cfg.compute_dtype, groups=tp))
+    pages_spec = jax.tree.map(lambda _: pool_spec, pages)
+    pages = place(pages, pages_spec)
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32,
+                                    sharding=NamedSharding(mesh, P()))
+
+    def tick(params, pages, toks, tables, pos):
+        logits, pages = gpt2_decode_paged(params, toks, cfg, pages, tables,
+                                          pos, tp_axis=TENSOR_AXIS)
+        return jnp.argmax(logits[:, -1], -1), pages
+
+    body = jax.shard_map(tick, mesh=mesh,
+                         in_specs=(specs, pages_spec, P(), P(), P()),
+                         out_specs=(P(), pages_spec), check_vma=False)
+    text = jax.jit(body, donate_argnums=(1,)).lower(
+        params, pages, i32(b, 1), i32(b, per_seq), i32(b)).compile().as_text()
+    local = jax.ShapeDtypeStruct((b * per_seq, block, 1, 384),
+                                 cfg.compute_dtype)
+    assert "input_output_alias" in text
+    assert _named_custom_call(text, "paged_attn")
+    assert not pool_leaf_copies(text, local)
+    assert len(re.findall(r" all-reduce(-start)?\(", text)) <= 2 * cfg.n_layer
+
+
 # --------------------------------------------------------- 4-device vote step
 def test_vote_step_compiles_on_2x2_mesh_with_auto_wire(topo):
     """The optimizer step on a Mesh of the described 2x2: the wire, bucket
