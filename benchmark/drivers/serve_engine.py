@@ -1,9 +1,8 @@
 """Driver ``serve_engine``: one serving cell through ``ServingEngine``.
 
-Built with the constructors ``run_serve.build_engine_factory`` itself calls
-(``GPT2Config`` -> ``ServeModel.for_gpt2`` -> ``ServingEngine``); the
-checkpoint loader is bypassed because the weights are made on the device
-from ``--seed`` (and the CLI cannot name GPT-2 XL today). The driver submits
+The model comes from the configuration's family (``harness.load_family``:
+its ``ServeModel`` over weights made on the device from ``--seed``); this
+file names no family and reads no key of a configuration. The driver submits
 each request when it is due (open loop: never waits for a reply), calls
 ``engine.step()`` in a loop, and stamps its own wall clock after every step
 against ``engine.stats['ticks']``; ``lib/ticklog`` turns the engine's tick
@@ -21,8 +20,7 @@ import time
 
 import numpy as np
 
-from benchmark.lib import gpt2_program, harness, ticklog, traffic
-from benchmark.reference import gpt2 as ref
+from benchmark.lib import harness, ticklog, traffic
 
 OK_REASONS = ("length", "eos")
 
@@ -47,23 +45,30 @@ def buckets_of(cell: dict) -> list:
     return out + [hi]
 
 
-def build_engine(cell: dict, seed: int):
+def weights_dtype(cell: dict):
     import jax.numpy as jnp
 
-    from distributed_lion_tpu.models.gpt2 import GPT2Config
-    from distributed_lion_tpu.serve.engine import (
-        ServeConfig,
-        ServeModel,
-        ServingEngine,
-    )
+    return jnp.dtype(cell["program"].get("weights_dtype", "bfloat16"))
 
-    cfg = cell["config"]
-    dtype = jnp.dtype(cell["program"].get("weights_dtype", "bfloat16"))
-    model_cfg = GPT2Config(**gpt2_program.gpt2_config_kwargs(cfg),
-                           param_dtype=dtype, compute_dtype=jnp.bfloat16)
-    params = gpt2_program.make_program_weights(seed, cfg, dtype)
-    return ServingEngine(ServeModel.for_gpt2(params, model_cfg),
+
+def build_engine(cell: dict, seed: int, family):
+    from distributed_lion_tpu.serve.engine import ServeConfig, ServingEngine
+
+    cfg, dtype = cell["config"], weights_dtype(cell)
+    params = harness.seeded_weights(family, cfg, seed, dtype)
+    return ServingEngine(family.serve_model(params, cfg, dtype),
                          ServeConfig(**cell["program"]["serve_config"]))
+
+
+def pool_facts(engine) -> dict:
+    """The page pool as the engine holds it: the shape of one leaf
+    (``[pages, block_size, groups, row width]``), how many leaves (keys and
+    values of every layer) and the bytes of a value."""
+    import jax
+
+    leaves = jax.tree.leaves(engine.pages)
+    return {"leaf_shape": list(leaves[0].shape), "leaves": len(leaves),
+            "itemsize": leaves[0].dtype.itemsize}
 
 
 class Loop:
@@ -143,10 +148,11 @@ def run(cell: dict, seed: int, seconds: float, trace_dir, clock, t_process,
     )
 
     enable_compilation_cache()
-    vocab = int(cell["config"]["vocab_size"])
+    family = harness.load_family(cell["config"])
+    vocab = family.vocab(cell["config"])
     window = cell["program"]["window"]
     drain_s = float(window.get("drain_s", 5.0))
-    engine = build_engine(cell, seed)
+    engine = build_engine(cell, seed, family)
     loop = Loop(engine, clock, annotate=bool(trace_dir))
     warm_buckets(loop, cell, vocab)
     warm_ids = set(loop.submitted)
@@ -158,6 +164,7 @@ def run(cell: dict, seed: int, seconds: float, trace_dir, clock, t_process,
     after_ticks = window["open"] == "after_ticks"
     t_open = None if after_ticks else t0 + warm_s
     t_close = full_tick = None
+    stats: dict = {}      # the engine's counters at the windows' edges
     nxt = 0
     trace = {"state": "armed" if trace_dir else "off",
              "after_s": min(float(window.get("trace_after_s", 1.0)),
@@ -185,7 +192,13 @@ def run(cell: dict, seed: int, seconds: float, trace_dir, clock, t_process,
             elif not after_ticks and now - t_open >= seconds:
                 t_close = t_open + seconds
         if t_open is not None and now >= t_open:
-            _trace_edges(trace, trace_dir, now, t_open, clock)
+            # no tick runs between an edge and its copy of the counters
+            edge = _trace_edges(trace, trace_dir, now, t_open, clock)
+            for at in ("open", edge):
+                if at and at not in stats:
+                    stats[at] = dict(engine.stats)
+        if t_close is not None and "close" not in stats:
+            stats["close"] = dict(engine.stats)
         if t_close is not None and trace["state"] in ("off", "done"):
             waiting = [] if after_ticks else [
                 r for r in reqs if t_open <= t0 + r["due_s"] < t_close
@@ -202,7 +215,7 @@ def run(cell: dict, seed: int, seconds: float, trace_dir, clock, t_process,
     peak = harness.memory_peak_bytes()
     facts = reduce_run(loop, reqs, warm_ids, t0, t_open, t_close, t_end,
                        after_ticks, cell)
-    facts["trace"] = trace
+    facts.update(trace=trace, engine_stats=stats, kv_pool=pool_facts(engine))
     sample = pick_sample(loop, reqs, t_open, t_close, seed, cell)
 
     # free the program's state before the reference runs
@@ -243,7 +256,9 @@ def run(cell: dict, seed: int, seconds: float, trace_dir, clock, t_process,
             "facts": facts}
 
 
-def _trace_edges(trace, trace_dir, now, t_open, clock) -> None:
+def _trace_edges(trace, trace_dir, now, t_open, clock):
+    """Open or close the traced sub-window when it is due; returns the
+    edge crossed (``trace_open`` / ``trace_close``) or None."""
     import jax
 
     if trace["state"] == "armed" and now - t_open >= trace["after_s"]:
@@ -251,10 +266,13 @@ def _trace_edges(trace, trace_dir, now, t_open, clock) -> None:
 
         start_trace(trace_dir)
         trace.update(state="on", t0=clock())
-    elif trace["state"] == "on" and now - trace["t0"] >= trace["for_s"]:
+        return "trace_open"
+    if trace["state"] == "on" and now - trace["t0"] >= trace["for_s"]:
         trace.update(t1=clock())
         jax.profiler.stop_trace()
         trace.update(state="done", window_s=trace["t1"] - trace["t0"])
+        return "trace_close"
+    return None
 
 
 # ------------------------------------------------------------- reduction
@@ -314,6 +332,13 @@ def reduce_run(loop: Loop, reqs, warm_ids, t0, t_open, t_close, t_end,
               f"complete by the end of the drain ({done_due / len(due):.3f}); "
               f"queue at mid-window {mid['pending']}, at its end "
               f"{in_window[-1]['pending']}", flush=True)
+    slowest = sorted(in_window, key=lambda t: t["t0"] - t["t1"])[:3]
+    print("[serve_engine] slowest ticks (ms at s into the window x prefills): "
+          + ", ".join(f"{(t['t1'] - t['t0']) * 1e3:.1f} at "
+                      f"{t['t0'] - t_open:.2f} x {t['prefills']}"
+                      for t in slowest)
+          + f"; {sum(t['prefills'] for t in in_window)} prefills in all",
+          flush=True)
     print(f"[serve_engine] window {t_close - t_open:.3f} s: {len(in_window)} "
           f"ticks, {tokens} output tokens, {finished} requests finished, "
           f"{len(due)} due; ttft median "
@@ -367,10 +392,11 @@ def served_token_gaps(cell, seed, sample, quants=()) -> dict:
     out = {"program": [], **{q: [] for q in quants}}
     if not sample:
         return out
-    dtype = jnp.dtype(cell["program"].get("weights_dtype", "bfloat16"))
+    family = harness.load_family(cfg)
+    ref, dtype = family.reference, weights_dtype(cell)
     weights = jax.jit(lambda key: ref.init_weights(key, cfg, dtype))(
         ref.seed_key(seed))
-    width = int(cfg["n_positions"])
+    width = family.reference_row_len(cell)
     rows = np.zeros((len(sample), width), np.int32)
     for row, s in zip(rows, sample):
         seq = list(s["prompt"]) + list(s["tokens"])

@@ -2,7 +2,9 @@
 
 The program is driven through its own entry point and its own loop
 (``Trainer.train``). The benchmark supplies the weights (made on the device
-from ``--seed``), the token batches (its own iterator, also from the seed)
+from ``--seed``, in the layout the configuration's family gives:
+``harness.load_family``; this file names no family and reads no key of a
+configuration), the token batches (its own iterator, also from the seed)
 and the clock. The compiled step and its state that set-up drives through
 the first ``CHECK_STEPS`` steps is the same object the window then times.
 
@@ -24,8 +26,7 @@ import time
 
 import numpy as np
 
-from benchmark.lib import gpt2_program, harness, traffic
-from benchmark.reference import gpt2 as ref
+from benchmark.lib import harness, traffic
 
 CHECK_STEPS = 3          # steps the reference follows (set-up, not timed)
 RUN_AHEAD = 2            # steps the host may lead the device by
@@ -90,10 +91,14 @@ def job(cell: dict) -> dict:
 
 
 def flags_of(cell: dict) -> dict:
+    """The program's flags: the cell's own, the model as the family names
+    it to the trainer, the batch shape from the traffic mix."""
     sizes = {k: cell["traffic"][k] for k in (
         "block_size", "per_device_train_batch_size",
         "gradient_accumulation_steps")}
-    return {**cell["program"]["flags"], **sizes}
+    family = harness.load_family(cell["config"])
+    return {**cell["program"]["flags"],
+            **family.train_flags(cell["config"]), **sizes}
 
 
 def argv_of(cell: dict) -> list:
@@ -117,9 +122,10 @@ class Feed:
         self.tr, self.cell, self.seed = trainer, cell, seed
         self.seconds, self.trace_dir, self.clock = seconds, trace_dir, clock
         self.t_process = t_process
+        self.family = harness.load_family(cell["config"])
         self.job = job(cell)
         self.rows = self.job["world"] * self.job["accum"] * self.job["micro"]
-        self.vocab = int(cell["config"]["vocab_size"])
+        self.vocab = self.family.vocab(cell["config"])
         self.calls = 0
         self.marks: dict = {}
         self.done: dict = {}
@@ -152,7 +158,7 @@ class Feed:
         scale = 1.0 / (1.0 - self.job["b2"])
 
         def numbers(tree):
-            out = leaf_numbers(gpt2_program.program_leaves(tree), lead=1)
+            out = leaf_numbers(self.family.program_leaves(tree), lead=1)
             return {"norm": scale * out["norm"],
                     "sketch": scale * out["sketch"]}
 
@@ -162,16 +168,16 @@ class Feed:
         import jax
         import jax.numpy as jnp
 
-        cfg = self.cell["config"]
+        cfg, family = self.cell["config"], self.family
 
         def norms(params, key):
-            start = gpt2_program.to_program(
-                ref.init_weights(key, cfg, jnp.float32))
+            start = family.program_weights(key, cfg, jnp.float32)
             return jax.tree.map(
                 lambda a, b: jnp.sqrt(jnp.sum(jnp.square(a - b))),
                 params, start)
 
-        return jax.jit(norms)(self.tr.params, ref.seed_key(self.seed))
+        return jax.jit(norms)(self.tr.params,
+                              family.reference.seed_key(self.seed))
 
     # -- the iterator ----------------------------------------------------
     def __iter__(self):
@@ -250,13 +256,14 @@ def run(cell: dict, seed: int, seconds: float, trace_dir, clock, t_process,
     from distributed_lion_tpu.train import loop
 
     state: dict = {}
+    family = harness.load_family(cell["config"])
     real_train = loop.Trainer.train
 
     def train(self, train_iter, eval_blocks=None, max_steps=None):
         # the program's own weights are replaced by the benchmark's, made
         # on the device from the seed in one call (same tree, same places)
-        self.params = gpt2_program.make_program_weights(
-            seed, cell["config"], jnp.float32,
+        self.params = harness.seeded_weights(
+            family, cell["config"], seed, jnp.float32,
             jax.tree.map(lambda p: p.sharding, self.params))
         feed = Feed(self, cell, seed, seconds, trace_dir, clock, t_process)
         state.update(feed=feed, n_params=self.n_params,
@@ -301,8 +308,8 @@ def run(cell: dict, seed: int, seconds: float, trace_dir, clock, t_process,
 
     t_ref = time.monotonic()
     reference = reference_numbers(cell, seed, quant=None)
-    compare(program_numbers_as_reference(program, cell["config"]), reference,
-            cell["correct"]["limits"], check)
+    compare(program_numbers_as_reference(program, cell["config"], family),
+            reference, cell["correct"]["limits"], check)
     print(f"[train_clm] reference: {time.monotonic() - t_ref:.1f} s "
           "(after the window, not in setup_s)", flush=True)
     for quant in cell.get("control_quants", ()):     # benchmark/control.py
@@ -333,17 +340,19 @@ def reference_numbers(cell: dict, seed: int, quant=None) -> dict:
     import jax.numpy as jnp
 
     cfg, j = cell["config"], job(cell)
+    family = harness.load_family(cfg)
+    ref = family.reference
     micro = int(cell["correct"].get("reference_micro", 2))
     per_worker = j["accum"] * j["micro"]
     rows = j["world"] * per_worker
-    vocab = int(cfg["vocab_size"])
+    vocab = family.vocab(cfg)
     w0 = jax.jit(lambda key: ref.init_weights(key, cfg, jnp.float32))(
         ref.seed_key(seed))
     grad_fn = jax.jit(lambda w, r: ref.loss_and_grad(w, r, cfg, micro, quant))
     step_fn = jax.jit(lambda w, m, g, lr: ref.vote_lion_step(
         w, m, g, lr, j["wd"], j["b1"], j["b2"]))
     numbers_fn = jax.jit(lambda g: {k: v for k, v in leaf_numbers(
-        gpt2_program.program_leaves(gpt2_program.to_program(g)),
+        family.program_leaves(family.to_program(g)),
         lead=0).items() if k != "keys"})
     w = w0
     momenta = [jax.tree.map(jnp.zeros_like, w0) for _ in range(j["world"])]
@@ -360,39 +369,34 @@ def reference_numbers(cell: dict, seed: int, quant=None) -> dict:
         if s == 0:
             per = [jax.device_get(numbers_fn(g)) for g in grads]
             out.update(by_leaf(np.stack([p["norm"] for p in per]),
-                               np.stack([p["sketch"] for p in per]), cfg))
+                               np.stack([p["sketch"] for p in per]),
+                               family.leaf_keys(cfg)))
         lr = ref.cosine_warmup_lr(s, j["lr"], j["warmup"], j["max_steps"])
         w, momenta = step_fn(w, momenta, grads, lr)
         del grads
-    delta = jax.jit(lambda a, b: gpt2_program.reference_leaf_norms(
+    delta = jax.jit(lambda a, b: family.reference_leaf_norms(
         jax.tree.map(jnp.subtract, a, b)))(w, w0)
     out["update_norms"] = {k: float(v)
                            for k, v in jax.device_get(delta).items()}
     return out
 
 
-def leaf_keys(cfg: dict) -> list:
-    """The program's leaves in :func:`leaf_numbers`' order."""
-    keys = [(name, None) for name in ("wte", "wpe", "ln_f_g", "ln_f_b")]
-    keys += [(name, i) for i in range(cfg["n_layer"])
-             for name in ref._PER_LAYER]
-    return sorted(keys, key=str)
-
-
-def by_leaf(norm, sketch, cfg: dict) -> dict:
+def by_leaf(norm, sketch, keys: list) -> dict:
     """``[workers, leaves]`` norms and ``[workers, leaves, SKETCH]``
-    sketches as dicts keyed by leaf."""
-    keys = leaf_keys(cfg)
+    sketches as dicts keyed by leaf (``keys``: the family's ``leaf_keys``,
+    put in :func:`leaf_numbers`' order here)."""
+    keys = sorted(keys, key=str)
     norm, sketch = np.asarray(norm, np.float64), np.asarray(sketch, np.float64)
     return {"grad_norms": {k: norm[:, i] for i, k in enumerate(keys)},
             "grad_sketch": {k: sketch[:, i] for i, k in enumerate(keys)}}
 
 
-def program_numbers_as_reference(program: dict, cfg: dict) -> dict:
+def program_numbers_as_reference(program: dict, cfg: dict, family) -> dict:
     """The feed's captures, keyed like the reference's numbers."""
-    upd = gpt2_program.program_leaves(program["update_norms"])
+    upd = family.program_leaves(program["update_norms"])
     return {"loss": [float(x) for x in program["loss"]],
-            **by_leaf(program["grad"]["norm"], program["grad"]["sketch"], cfg),
+            **by_leaf(program["grad"]["norm"], program["grad"]["sketch"],
+                      family.leaf_keys(cfg)),
             "update_norms": {k: float(v) for k, v in upd.items()}}
 
 

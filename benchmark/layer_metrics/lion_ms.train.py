@@ -1,5 +1,5 @@
-"""Device time per optimizer step of the two Lion kernels (`_ballot_kernel`
-and `_apply_kernel`), by kernel name in the trace."""
+"""Device time per optimizer step of the two Lion kernels (`lion_ballot`
+and `lion_apply`, ops/pallas_lion), by kernel name in the trace."""
 from benchmark.lib.layer_common import LION_KERNELS, kernel_ms_per_unit
 
 

@@ -1,7 +1,7 @@
 """What every run of the benchmark shares: finding a cell's files by the
 names in ``BENCHMARK.json``, the chip gate, compile accounting, the peaks
-table and the result line. Nothing here knows a cell, a configuration or a
-metric by name."""
+table and the result line. Nothing here knows a cell, a configuration, a
+model family or a metric by name."""
 
 from __future__ import annotations
 
@@ -67,6 +67,52 @@ def load_module(kind: str, name: str):
     module = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(module)
     return module
+
+
+def load_family(cfg: dict):
+    """The model family of a configuration: ``benchmark/families/
+    <model_type>.py``, by the key its file carries (what a family gives is
+    set out in ``families/__init__.py``)."""
+    return load_module("families", cfg["model_type"])
+
+
+def check_config_file(entry: dict, body: dict) -> None:
+    """Hold a configuration file (``body``) to its ``configs`` entry and to
+    what a cut configuration must state (the model-configs guide, section
+    4): the source, every key changed from it, each size assumed and the
+    deployment it stands for; then to its family's own assertions."""
+    def need(ok, what):
+        if not ok:
+            raise ValueError(f"configuration {entry.get('name')!r}: {what}")
+
+    need(body.get("source") == entry["source"],
+         "source differs between BENCHMARK.json and the file")
+    need(body.get("reduced") == entry["reduced"],
+         "reduced differs between BENCHMARK.json and the file")
+    missing = {"assumed", "deployment", "model_type"} - set(body)
+    need(not missing, f"the file lacks {sorted(missing)}")
+    published = body.get("published", {})
+    for key in entry["reduced"]:
+        need(key in body, f"reduced names {key!r}, which the file lacks")
+        need(key in published and published[key] != body[key],
+             f"reduced key {key!r} needs its published value, another than "
+             "the file's own, under 'published'")
+    try:
+        family = load_family(body)
+    except FileNotFoundError:
+        need(False, f"no family file for model_type {body['model_type']!r}")
+    family.check_config(body)
+
+
+def seeded_weights(family, cfg: dict, seed: int, dtype, shardings=None):
+    """The program's weights, made on the device from the seed in ONE
+    jitted call, in the dtype they are used in. The key is an argument: a
+    seed traced in as a constant would miss the compile cache every run."""
+    import jax
+
+    fn = jax.jit(lambda key: family.program_weights(key, cfg, dtype),
+                 out_shardings=shardings)
+    return fn(family.reference.seed_key(seed))
 
 
 def require_tpu(chips: int) -> dict:

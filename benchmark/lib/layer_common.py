@@ -63,16 +63,12 @@ def median_of(ctx, key):
 
 # ---- kernels by name. On the chip the flash kernels are flash_attention
 # (forward), jvp_jit_flash_attention (the forward inside remat) and
-# flash_mha_bwd_dq / _dkv. The Lion kernels carry no name of their own in
-# today's trace: they are Mosaic custom-calls named after the function that
-# holds them (``step.<n>`` on one chip, ``shard_map.<n>`` on four), 2 a leaf
-# a step. So: every Mosaic kernel of the step that is not a flash-attention
-# kernel (without --telemetry those are ``_ballot_kernel`` and
-# ``_apply_kernel``), or any op that carries a Lion kernel's name once the
-# program gives it one.
+# flash_mha_bwd_dq / _dkv; the Lion kernels are the Mosaic calls the program
+# names lion_ballot and lion_apply (ops/pallas_lion), 2 a leaf a step. By
+# name alone: an unnamed Mosaic call, or another kernel's, is not Lion time.
 FLASH_KERNELS = r"flash_attention|flash_mha"
-LION_KERNELS = (r"_ballot_kernel|_apply_kernel|lion_ballot|lion_apply"
-                r'|^(?!.*flash).*custom_call_target="tpu_custom_call"')
+LION_KERNELS = r"lion_ballot|lion_apply"
+PAGED_ATTN_KERNEL = r"paged_attn"
 
 
 def peak_hbm_gb(ctx):

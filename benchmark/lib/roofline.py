@@ -43,3 +43,14 @@ def lion_kernel_bytes(n_params: int, world: int, mom_bytes: int = 4,
     ballot = grad_bytes + mom_bytes + 1
     apply = param_bytes + grad_bytes + mom_bytes + 4 + param_bytes + mom_bytes
     return n_params * (ballot + apply)
+
+
+def paged_attn_bytes(pages: int, block_size: int, row_width: int,
+                     layers: int, itemsize: int = 2) -> int:
+    """Least HBM bytes of decode attention over a paged KV pool read in
+    place: every page that a live row's length needs (the engine's
+    ``kv_pages_read``), ``block_size`` rows of ``row_width`` values (all the
+    kv heads of a token, lane padding included: a page is one DMA), keys and
+    values, in every layer. The queries in and the outputs back (one row a
+    sequence) are some thousandth of it and are not counted."""
+    return pages * block_size * row_width * 2 * itemsize * layers

@@ -17,23 +17,22 @@ from __future__ import annotations
 
 import json
 import os
+import re
 import sys
 import time
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-TINY = {"vocab_size": 256, "n_positions": 128, "n_embd": 64, "n_layer": 2,
-        "n_head": 4, "layer_norm_epsilon": 1e-5}
 
 
 def tiny_cell(name: str, chips: int = 1) -> dict:
-    """The cell with a tiny model and traffic: same driver, same code."""
+    """The cell with its family's tiny model and a tiny traffic: same
+    driver, same code."""
     from benchmark.lib import harness
 
     cell = harness.load_cell(name)
-    cell["config"] = dict(TINY)
+    cell["config"] = dict(harness.load_family(cell["config"]).TINY)
     cell["chips"] = chips
     if cell["driver"] == "train_clm":
-        cell["program"]["flags"]["model_name"] = "tiny"
         cell["traffic"].update(block_size=64, per_device_train_batch_size=2,
                                gradient_accumulation_steps=2)
     else:
@@ -71,45 +70,39 @@ def compile_serve(name: str) -> None:
     from jax.sharding import SingleDeviceSharding
 
     from benchmark.drivers import serve_engine
-    from benchmark.lib import gpt2_program, harness
-    from benchmark.reference import gpt2 as ref
-    from distributed_lion_tpu.models.gpt2 import GPT2Config
-    from distributed_lion_tpu.serve.engine import (
-        ServeConfig,
-        ServeModel,
-        ServingEngine,
-    )
+    from benchmark.lib import harness
+    from distributed_lion_tpu.serve.engine import ServeConfig, ServingEngine
 
     jax.config.update("jax_enable_compilation_cache", False)
     cell = harness.load_cell(name)
     cfg, sc = cell["config"], dict(cell["program"]["serve_config"])
+    family = harness.load_family(cfg)
     chip = SingleDeviceSharding(topologies.get_topology_desc(
         platform="tpu", topology_name="v5e:2x2").devices[0])
-    dtype = jnp.dtype(cell["program"].get("weights_dtype", "bfloat16"))
+    dtype = serve_engine.weights_dtype(cell)
 
-    def on_chip(tree):
+    def on_chip(tree, shape=lambda s: s):
         return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
-            x.shape, x.dtype, sharding=chip), tree)
+            shape(x.shape), x.dtype, sharding=chip), tree)
 
-    params = on_chip(jax.eval_shape(lambda: gpt2_program.to_program(
-        ref.init_weights(jax.random.key(0), cfg, dtype))))
-    model_cfg = GPT2Config(**gpt2_program.gpt2_config_kwargs(cfg),
-                           param_dtype=dtype, compute_dtype=jnp.bfloat16)
+    params = on_chip(jax.eval_shape(
+        lambda: family.program_weights(jax.random.key(0), cfg, dtype)))
     pool = ServeConfig(**sc).resolved_num_blocks()
     # the engine itself is built over a token pool of pages (its host
     # tables do not enter the compiled programs); the programs are then
-    # lowered with the pool at its real size
-    engine = ServingEngine(ServeModel.for_gpt2(params, model_cfg),
+    # lowered with the pool's leaves as the engine lays them out, at the
+    # pool's real number of pages
+    engine = ServingEngine(family.serve_model(params, cfg, dtype),
                            ServeConfig(**dict(sc, num_blocks=64)))
-    page = jax.ShapeDtypeStruct(
-        (pool, sc["block_size"], cfg["n_head"], cfg["n_embd"] // cfg["n_head"]),
-        jnp.bfloat16, sharding=chip)
-    pages = [{"k": page, "v": page} for _ in range(cfg["n_layer"])]
+    pages = on_chip(jax.eval_shape(lambda: engine.pages),
+                    shape=lambda s: (pool,) + tuple(s[1:]))
+    leaves = jax.tree.leaves(pages)
     weights = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(params))
-    pool_bytes = 2 * cfg["n_layer"] * page.size * 2
+    pool_bytes = sum(x.size * x.dtype.itemsize for x in leaves)
     hbm = harness.peaks_for("TPU v5 lite")["hbm_bytes"]
     print(f"[rehearse] {name}: weights {weights / 1e9:.2f} GB, pool {pool} "
-          f"pages = {pool_bytes / 1e9:.2f} GB, chip {hbm / 1e9:.0f} GB")
+          f"pages in {len(leaves)} leaves {list(leaves[0].shape)} = "
+          f"{pool_bytes / 1e9:.2f} GB, chip {hbm / 1e9:.0f} GB")
 
     def s(shape, dt=jnp.int32):
         return jax.ShapeDtypeStruct(shape, dt, sharding=chip)
@@ -121,22 +114,33 @@ def compile_serve(name: str) -> None:
         programs[f"prefill@{bucket}"] = (
             s((1, W)), s((1, bucket)), s((1,)), s(()), s((), jnp.uint32),
             s(()))
-    for label, rest in programs.items():
-        inner = engine._dispatches[label.split("@")[0]]["inner"]
-        t0 = time.monotonic()
-        compiled = jax.jit(inner, donate_argnums=(1,)).lower(
-            params, pages, *rest).compile()
-        m = compiled.memory_analysis()
-        live = m.argument_size_in_bytes + m.temp_size_in_bytes \
-            + m.output_size_in_bytes - m.alias_size_in_bytes
-        print(f"[rehearse]   {label}: compiled in "
-              f"{time.monotonic() - t0:.1f} s; arguments "
-              f"{m.argument_size_in_bytes / 1e9:.2f} GB, temporaries "
-              f"{m.temp_size_in_bytes / 1e9:.2f} GB, outputs "
-              f"{m.output_size_in_bytes / 1e9:.2f} GB of which aliased "
-              f"{m.alias_size_in_bytes / 1e9:.2f} GB -> live "
-              f"{live / 1e9:.2f} GB ({'fits' if live < hbm else 'DOES NOT FIT'})",
-              flush=True)
+    # the decode program takes its kernel where the backend is a TPU, and
+    # this process's backend is the CPU: say "tpu" in its place while the
+    # programs lower (as tests/test_chip_compile.py does)
+    real_backend, jax.default_backend = jax.default_backend, lambda: "tpu"
+    try:
+        for label, rest in programs.items():
+            inner = engine._dispatches[label.split("@")[0]]["inner"]
+            t0 = time.monotonic()
+            compiled = jax.jit(inner, donate_argnums=(1,)).lower(
+                params, pages, *rest).compile()
+            report(label, compiled, time.monotonic() - t0, hbm)
+    finally:
+        jax.default_backend = real_backend
+
+
+def report(label: str, compiled, secs: float, hbm: float) -> None:
+    m = compiled.memory_analysis()
+    live = m.argument_size_in_bytes + m.temp_size_in_bytes \
+        + m.output_size_in_bytes - m.alias_size_in_bytes
+    kernels = len(re.findall(r'custom_call_target="tpu_custom_call"',
+                             compiled.as_text()))
+    print(f"[rehearse]   {label}: compiled in {secs:.1f} s, {kernels} Mosaic "
+          f"kernel call(s); arguments {m.argument_size_in_bytes / 1e9:.2f} "
+          f"GB, temporaries {m.temp_size_in_bytes / 1e9:.2f} GB, outputs "
+          f"{m.output_size_in_bytes / 1e9:.2f} GB of which aliased "
+          f"{m.alias_size_in_bytes / 1e9:.2f} GB -> live {live / 1e9:.2f} GB "
+          f"({'fits' if live < hbm else 'DOES NOT FIT'})", flush=True)
 
 
 def main(argv=None) -> int:

@@ -52,7 +52,11 @@ def main(argv=None) -> int:
                 result = None
             row = {"set": which, "seed": seed, "rc": proc.returncode,
                    "result": result,
-                   "checks": [ln for ln in lines if ln.startswith("[check]")]}
+                   "checks": [ln for ln in lines if ln.startswith("[check]")],
+                   # what a far-off run is explained from: step times,
+                   # the window's ticks and its slowest ones
+                   "notes": [ln for ln in lines if ln.startswith(
+                       ("[train_clm]", "[serve_engine]"))]}
             rows.append(row)
             if args.out:
                 os.makedirs(os.path.dirname(args.out) or ".", exist_ok=True)

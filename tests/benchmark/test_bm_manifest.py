@@ -74,15 +74,55 @@ def test_cell_files_are_found_by_name(name):
     assert cell["correct"]["limits"]
 
 
+# configurations whose cut is pinned here: both fit one chip whole
+UNCUT = ("gpt2-124m", "gpt2-xl")
+
+
 @pytest.mark.parametrize("config", MANIFEST["configs"],
                          ids=lambda c: c["name"])
 def test_config_file_states_source_and_widths(config):
     body = harness.read_json(harness.ROOT, config["file"])
-    assert body["source"] == config["source"]
-    assert body["reduced"] == config["reduced"] == []
-    assert body["n_embd"] % body["n_head"] == 0
-    assert body["n_embd"] // body["n_head"] == 64     # published head size
-    assert {"assumed", "deployment"} <= set(body)
+    harness.check_config_file(config, body)   # the family's widths among it
+    if config["name"] in UNCUT:
+        assert body["reduced"] == config["reduced"] == []
+        assert body["n_embd"] // body["n_head"] == 64   # published head size
+
+
+def cut_config():
+    """GPT-2 124M cut in depth, as a later configuration would be: the cut
+    in ``reduced`` in both places, the published value beside it."""
+    entry = dict(MANIFEST["configs"][0], reduced=["n_layer"])
+    body = dict(harness.read_json(harness.ROOT, entry["file"]), n_layer=4,
+                reduced=["n_layer"], published={"n_layer": 12})
+    return entry, body
+
+
+def test_a_configuration_cut_in_depth_passes():
+    harness.check_config_file(*cut_config())
+
+
+@pytest.mark.parametrize("fault", [
+    "reduced_differs", "no_published_value", "published_value_is_the_same",
+    "reduced_key_not_in_file", "no_family", "no_deployment",
+    "family_refuses_widths"])
+def test_config_check_refuses(fault):
+    entry, body = cut_config()
+    if fault == "reduced_differs":
+        entry["reduced"] = []
+    elif fault == "no_published_value":
+        body["published"] = {}
+    elif fault == "published_value_is_the_same":
+        body["published"] = {"n_layer": 4}
+    elif fault == "reduced_key_not_in_file":
+        del body["n_layer"]
+    elif fault == "no_family":
+        body["model_type"] = "no-such-family"
+    elif fault == "no_deployment":
+        del body["deployment"]
+    elif fault == "family_refuses_widths":
+        body["n_head"] = 16                 # 768 / 16 = 48, not GPT-2's 64
+    with pytest.raises((ValueError, AssertionError)):
+        harness.check_config_file(entry, body)
 
 
 def test_command_on_the_cpu_exits_nonzero_and_prints_no_metric():

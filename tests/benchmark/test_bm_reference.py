@@ -7,7 +7,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from benchmark.lib import gpt2_program
+from benchmark.families import gpt2 as gpt2_program
 from benchmark.reference import gpt2 as ref
 
 CFG = {"vocab_size": 256, "n_positions": 128, "n_embd": 64, "n_layer": 2,
@@ -23,7 +23,7 @@ def weights():
 def test_forward_matches_gpt2_apply_in_float32(weights):
     from distributed_lion_tpu.models.gpt2 import GPT2Config, gpt2_apply
 
-    cfg = GPT2Config(**gpt2_program.gpt2_config_kwargs(CFG),
+    cfg = GPT2Config(**gpt2_program.config_kwargs(CFG),
                      compute_dtype=jnp.float32, remat=False)
     tokens = np.random.default_rng(0).integers(0, 256, (3, 48), dtype=np.int32)
     with jax.default_matmul_precision("highest"):

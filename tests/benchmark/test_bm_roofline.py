@@ -26,6 +26,20 @@ def test_lion_kernel_bytes():
     assert roofline.lion_kernel_bytes(1000, world=1) == 1000 * (9 + 24)
 
 
+def test_paged_attn_bytes_at_cell_2s_traced_run():
+    # PR 24's traced run of serve.gpt2-xl.decode-backlog (PERF.md section
+    # 5): 1,376 pages a tick, a page 16 rows of 1,664 bf16 lanes = 53,248 B
+    # a leaf, keys and values, 48 layers: 7.03 GB a tick; the kernel took
+    # 10.13 ms a tick: 694 GB/s, 85% of the chip's 819 GB/s
+    per_tick = roofline.paged_attn_bytes(1376, 16, 1664, 48)
+    assert per_tick == 1376 * 53_248 * 2 * 48 == 7_033_847_808
+    share = per_tick / 819e9 / 10.13e-3
+    assert 0.84 < share < 0.86
+    # a float32 pool moves twice the bytes
+    assert roofline.paged_attn_bytes(1376, 16, 1664, 48, itemsize=4) \
+        == 2 * per_tick
+
+
 def test_peaks_table_is_keyed_by_device_kind_with_source():
     table = harness.read_json(harness.BENCH_DIR, "peaks.json")
     v5e = table["devices"]["TPU v5 lite"]

@@ -35,18 +35,47 @@ def test_kernel_time_by_name_and_top_ops():
                                      ["_flash_attention_kernel", 2.0]]
 
 
+MOSAIC = 'custom-call( custom_call_target="tpu_custom_call" | '
+
+
 def test_a_consumer_of_a_kernels_output_is_not_the_kernel():
     plane = {"name": "/device:TPU:0", "lines": [{"name": "XLA Ops", "events": [
-        ["flash_attention.1", 0, 5e9, 'flash_attention.1 custom-call( '
-         'custom_call_target="tpu_custom_call" | bf16[4,12,1024,64] %q)'],
+        ["flash_attention.1", 0, 5e9, "flash_attention.1 " + MOSAIC
+         + "bf16[4,12,1024,64] %q)"],
         ["multiply_reduce_fusion.2", 6e9, 1e9, "multiply_reduce_fusion.2 "
          "fusion( kind=kLoop | bf16[4,12,1024,64] %jit_flash_attention_.6)"],
-        ["step.9", 8e9, 2e9, 'step.9 custom-call( '
-         'custom_call_target="tpu_custom_call" | f32[32,128] %pad.1)']]}]}
+        ["lion_apply.9", 8e9, 2e9, "lion_apply.9 " + MOSAIC
+         + "f32[32,128] %pad.1)"],
+        ["slice.11", 11e9, 1e9, "slice.11 slice( | f32[32,128] %lion_apply.9)"]
+    ]}]}
     from benchmark.lib.layer_common import FLASH_KERNELS, LION_KERNELS
 
     assert xplane.matching_s(plane, FLASH_KERNELS) == pytest.approx(5.0)
     assert xplane.matching_s(plane, LION_KERNELS) == pytest.approx(2.0)
+
+
+def test_another_mosaic_kernel_is_not_lion_time():
+    """The Lion kernels are found by the names the program gives them: a
+    Mosaic call without a name of theirs (``step.9``, as the chip's trace
+    had them before the program named its kernels) and another kernel's
+    (``paged_attn``) are not counted, alone or beside a Lion kernel."""
+    from benchmark.lib.layer_common import LION_KERNELS, PAGED_ATTN_KERNEL
+
+    others = [["step.9", 0, 2e9, "step.9 " + MOSAIC + "f32[32,128] %pad.1)"],
+              ["paged_attn.1", 3e9, 4e9, "paged_attn.1 " + MOSAIC
+               + "s32[32] %multiply_minimum_fusion, s32[2048] %reshape.27)"]]
+    lion = ["lion_ballot.7", 8e9, 1e9, "lion_ballot.7 " + MOSAIC
+            + "f32[4608,128] %pad_bitcast_fusion.2)"]
+
+    def plane(events):
+        return {"name": "/device:TPU:0",
+                "lines": [{"name": "XLA Ops", "events": events}]}
+
+    assert xplane.matching_s(plane(others), LION_KERNELS) == 0.0
+    assert xplane.matching_s(plane(others + [lion]), LION_KERNELS) \
+        == pytest.approx(1.0)
+    assert xplane.matching_s(plane(others + [lion]), PAGED_ATTN_KERNEL) \
+        == pytest.approx(4.0)
 
 
 def test_idle_gaps_are_named_after_the_covering_host_span():
