@@ -373,30 +373,30 @@ def assert_donated(engine) -> None:
           f"page pool not donated off-CPU: {donated}")
 
 
-def assert_pool_in_place(engine) -> None:
+def assert_pool_in_place(engine, kernels=("paged_attn",)) -> None:
     """The gate on the pool's resident layout: compile ``decode``,
     ``prefill`` and ``cow`` as the engine jitted them and fail if the
     optimized HLO copies anything the size of a pool leaf (a re-layout of
     the pool inside a dispatch: serve/kv_cache's module note), or if the
-    decode program lacks the ``paged_attn`` kernel."""
+    decode program lacks one of ``kernels``."""
     from distributed_lion_tpu.analysis.serve_check import (
         lowered_dispatch,
         pool_leaf_copies,
     )
 
-    leaf = engine.pages[0]["k"]
+    leaf = next(iter(engine.pages[0].values()))
     bucket = engine.cfg.block_size * engine.cfg.max_blocks_per_seq
     for kind in ("decode", "prefill", "cow"):
         lowered = lowered_dispatch(engine, kind, bucket)
         copies = pool_leaf_copies(lowered.compile().as_text(), leaf)
         check(not copies, f"{kind} copies the pool: {copies[:2]}")
         if kind == "decode":
-            kernels = mosaic_kernels(lowered.as_text())
-            check("paged_attn" in kernels,
-                  f"decode holds no paged_attn kernel: {kernels}")
+            held = mosaic_kernels(lowered.as_text())
+            check(set(kernels) <= set(held),
+                  f"decode holds {held}, not all of {kernels}")
     log(f"  decode, prefill@{bucket} and cow hold no copy of a pool leaf "
-        f"({leaf.dtype.name}{list(leaf.shape)}); decode holds the "
-        "paged_attn kernel")
+        f"({leaf.dtype.name}{list(leaf.shape)}); decode holds "
+        f"{', '.join(kernels)}")
 
 
 def phase_serve(out_dir: str) -> None:
@@ -525,6 +525,109 @@ def phase_serve(out_dir: str) -> None:
         f"top-2 margin exceeds {2 * LOGIT_TOL}")
     check(worst <= LOGIT_TOL,
           f"paged logits off by {worst}")
+
+
+def phase_serve_latent() -> None:
+    """The latent-cache family (models/joyai) at a small lane-aligned size
+    through the constructors ``run_serve --model_family joyai`` calls: the
+    decode tick holds ``mla_paged_attn`` and ``moe_gmm`` and no dispatch
+    copies the one pool leaf; the dropless layer's counters conserve
+    tokens; and the S = 1 kernel path (absorbed attention, grouped-matmul
+    kernel at a few rows an expert) gives the logits of the one-window
+    gather path (expanded keys and values) on the tokens it served."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from distributed_lion_tpu.models.joyai import (
+        JoyAIConfig, joyai_decode_paged, joyai_init,
+    )
+    from distributed_lion_tpu.serve.engine import (
+        Request, ServeConfig, ServeModel, ServingEngine,
+    )
+    from distributed_lion_tpu.serve.kv_cache import init_page_leaves
+
+    cfg = JoyAIConfig.tiny(
+        vocab_size=1024, d_model=256, n_head=4, q_lora_rank=128,
+        kv_lora_rank=128, qk_nope_head_dim=64, qk_rope_head_dim=64,
+        v_head_dim=64, d_ff=512, n_experts=8, top_k=2, moe_d_ff=128)
+    params = joyai_init(jax.random.key(26), cfg)
+    block, max_blocks = 16, 8
+    model = ServeModel.for_joyai(params, cfg)
+    engine = ServingEngine(model, ServeConfig(
+        max_seqs=4, block_size=block, max_blocks_per_seq=max_blocks,
+        moe_stats=True, prefill_cap_tokens=128))
+    rng = np.random.default_rng(26)
+    prompts = [rng.integers(0, cfg.vocab_size, n).tolist()
+               for n in (21, 37, 50)]
+    done = engine.run([Request(req_id=i, tokens=p,
+                               max_new_tokens=SERVE_NEW_TOKENS)
+                       for i, p in enumerate(prompts)])
+    stats = engine.stats
+    check(all(done[i].reason == "length" for i in range(len(prompts))), done)
+    check(engine.tables.free_blocks == engine.tables.num_blocks,
+          "latent page pool did not return to empty")
+    assert_donated(engine)
+    assert_pool_in_place(engine, kernels=("mla_paged_attn", "moe_gmm"))
+    check(stats["mla_kernel_ticks"] == stats["decode_ticks"] > 0, stats)
+    moe_layers = cfg.n_layer - cfg.first_dense
+    check(stats["moe_assignments"]
+          == stats["decode_tokens"] * cfg.top_k * moe_layers, stats)
+    check(stats["moe_prefill_assignments"]
+          == stats["prefill_tokens"] * cfg.top_k * moe_layers, stats)
+    log(f"  mla_kernel_ticks {stats['mla_kernel_ticks']} of "
+        f"{stats['decode_ticks']} decode ticks; kv_pages_read "
+        f"{stats['kv_pages_read']} of kv_pages_table "
+        f"{stats['kv_pages_table']}; moe_assignments "
+        f"{stats['moe_assignments']} (= tokens x {cfg.top_k}), "
+        f"moe_experts_hit {stats['moe_experts_hit']}, moe_load_max "
+        f"{stats['moe_load_max']}; prefill: "
+        f"{stats['moe_prefill_assignments']} / "
+        f"{stats['moe_prefill_experts_hit']} / "
+        f"{stats['moe_prefill_load_max']}")
+
+    # teacher-forced on the served tokens: one window over prompt + served
+    # (S > 1: gather, expand, chunked attention) against the prompt's window
+    # then one token a step (S = 1: the absorbed kernel)
+    n_seq = len(prompts)
+    gots = [done[i].tokens for i in range(n_seq)]
+    seqs = [p + g[:-1] for p, g in zip(prompts, gots)]
+    width = -(-max(map(len, seqs)) // block) * block
+    toks = np.zeros((n_seq, width), np.int32)
+    for row, seq in zip(toks, seqs):
+        row[:len(seq)] = seq
+    tables = jnp.arange(n_seq * max_blocks, dtype=jnp.int32)[::-1].reshape(
+        n_seq, max_blocks)
+
+    def fresh():
+        return init_page_leaves(cfg.n_layer, n_seq * max_blocks, block,
+                                model.page_leaves, cfg.compute_dtype)
+
+    zero = jnp.zeros((n_seq,), jnp.int32)
+    whole = jax.jit(lambda p, t, pg: joyai_decode_paged(
+        p, t, cfg, pg, tables, zero)[0])(params, toks, fresh())
+    plens = np.asarray([len(p) for p in prompts])
+    p_width = -(-int(plens.max()) // block) * block
+    window, pages = jax.jit(lambda p, t, pg: joyai_decode_paged(
+        p, t, cfg, pg, tables, zero,
+        jnp.arange(p_width)[None, :] < jnp.asarray(plens)[:, None]))(
+            params, toks[:, :p_width], fresh())
+    step = jax.jit(lambda p, t, pg, pos, act: joyai_decode_paged(
+        p, t, cfg, pg, tables, pos, act), donate_argnums=(2,))
+    worst = max(float(jnp.abs(window[i, n - 1] - whole[i, n - 1]).max())
+                for i, n in enumerate(plens))
+    for j in range(SERVE_NEW_TOKENS - 1):
+        nxt = np.asarray([g[j] for g in gots], np.int32)
+        logits, pages = step(params, nxt[:, None], pages,
+                             jnp.asarray(plens + j, jnp.int32),
+                             np.ones((n_seq, 1), bool))
+        for i in range(n_seq):
+            worst = max(worst, float(jnp.abs(
+                logits[i, 0] - whole[i, plens[i] + j]).max()))
+    log(f"  absorbed kernel path vs expanded gather path, logits "
+        f"teacher-forced on the served tokens: max |diff| {worst:.5f} "
+        f"(tol {LOGIT_TOL})")
+    check(worst <= LOGIT_TOL, f"latent decode logits off by {worst}")
 
 
 # --------------------------------------------------------------- multichip
@@ -728,6 +831,9 @@ def phase_multichip(work: str) -> None:
 def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--chips", type=int, default=1, choices=(1, 4))
+    ap.add_argument("--only", default="",
+                    help="run this one phase (kernels, train, serve, "
+                         "serve_latent, multichip) and no other")
     args = ap.parse_args()
 
     import jax
@@ -755,9 +861,12 @@ def main() -> int:
     out_dir = os.path.join(work, "train")
     phases = ([("kernels", phase_kernels),
                ("train", lambda: phase_train(out_dir)),
-               ("serve", lambda: phase_serve(out_dir))]
+               ("serve", lambda: phase_serve(out_dir)),
+               ("serve_latent", phase_serve_latent)]
               if args.chips == 1 else
               [("multichip", lambda: phase_multichip(work))])
+    if args.only:
+        phases = [p for p in phases if p[0] == args.only]
     failed = []
     for name, fn in phases:
         log(f"phase {name}")

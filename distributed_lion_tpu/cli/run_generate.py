@@ -23,8 +23,9 @@ class GenerateArguments:
     model_path: Optional[str] = None  # .npz from utils.serialization, or an
     # HF save_pretrained directory (hf_export/--merged_output output, family
     # auto-detected); unset → random init (smoke mode)
-    model_family: str = "gpt2"  # gpt2 | llama
-    model_name: str = "tiny"    # gpt2: gpt2_124m | tiny; llama: llama2_7b | llama3_8b | tiny
+    model_family: str = "gpt2"  # gpt2 | llama | joyai (run_serve only)
+    model_name: str = "tiny"    # gpt2: gpt2_124m | tiny; llama: llama2_7b | llama3_8b | tiny;
+    # joyai: tiny | the path of a JSON file with the published config.json keys
     tokenizer_name: Optional[str] = None  # HF cache name; byte tokenizer otherwise
     prompt: List[str] = dataclasses.field(default_factory=list)
     # one or more prompts (--prompt "a" "b" "c"); several prompts batch into
@@ -153,6 +154,19 @@ def build(args: GenerateArguments):
             lambda c, p, t, k, pos, off=None: llama_decode(p, t, c, k, pos, off),
             cfg)
         init_cache = partial(llama_init_cache, cfg)
+    elif args.model_family == "joyai":
+        from distributed_lion_tpu.models.joyai import JoyAIConfig, joyai_init
+
+        # --model_name: 'tiny', or the path of a JSON file with the
+        # published config.json keys (benchmark/configs/joyai-llm-flash.json)
+        cfg = JoyAIConfig.named(
+            args.model_name,
+            **({"vocab_size": vocab} if args.model_name == "tiny" else {}))
+        params = (load_pytree(args.model_path) if args.model_path
+                  else joyai_init(jax.random.key(args.seed), cfg))
+        # no dense-cache decode: the family serves through the paged
+        # engine's latent pool (run_serve)
+        decode = init_cache = None
     else:
         raise ValueError(f"unknown model family {args.model_family!r}")
     return tok, cfg, params, decode, init_cache
@@ -178,6 +192,10 @@ def main(argv=None):
 
     (args,) = parse_dataclasses((GenerateArguments,), argv)
     tok, cfg, params, decode, init_cache = build(args)
+    if decode is None:
+        raise ValueError(
+            f"run_generate has no dense-cache decode for --model_family "
+            f"{args.model_family}: serve it with run_serve")
     prompts = list(args.prompt)
     if args.prompt_file:
         with open(args.prompt_file) as f:

@@ -224,7 +224,8 @@ def paged_copy_pages(pages: list, src: jnp.ndarray,
     """Copy whole pages inside each layer's pool — the device half of
     copy-on-write prefix sharing (serve/kv_cache.BlockTables.cow).
 
-    pages — the engine's per-layer ``[{"k", "v"}]`` pool list;
+    pages — the engine's per-layer pool list (``{"k", "v"}`` a layer, or
+    the one latent leaf ``{"kv"}``: every leaf is copied alike);
     src/dst [C] int32 — page-id pairs to copy this dispatch, padded with
     the sentinel (== num_blocks): a sentinel ``dst`` drops the write and a
     sentinel ``src`` gathers zeros (never kept — its dst is sentinel too),
@@ -241,7 +242,7 @@ def paged_copy_pages(pages: list, src: jnp.ndarray,
                 jnp.take(layer[name], src, axis=0, mode="fill",
                          fill_value=0),
                 mode="drop", unique_indices=False)
-            for name in ("k", "v")
+            for name in layer
         })
     return out
 
@@ -334,6 +335,69 @@ def paged_decode_attention(q, k_pages, v_pages, tables, pos,
     out = jnp.einsum("bhst,bhtd->bhsd", probs, v_full,
                      preferred_element_type=jnp.float32)
     return out.astype(q.dtype)
+
+
+# ---------------------------------------------------------- latent (MLA)
+# One leaf a layer, ``[num_blocks, block_size, 1, W]``: a token's row is
+# ``[c_kv | k_rope | 0]`` (serve/kv_cache.latent_row_width). Two attention
+# paths read it (models/joyai chooses by :func:`paged_kernel_applies`, the
+# rule the k/v pool's kernel follows): the decode tick absorbs the key and
+# value up-projections into the query and the output and runs
+# :func:`mla_decode_attention`, one page read for scores and values; every
+# other call (S > 1, the CPU) gathers the rows, expands keys and values and
+# runs :func:`chunked_causal_attention`, which takes keys wider than values.
+
+
+@jax.named_scope("mla_attn")
+def mla_decode_attention(q_abs, kv_pages, tables, pos, *, scale: float):
+    """Absorbed latent decode, one query token a row, over the pool in
+    place (``ops/pallas_mla_attn``). q_abs [B, H, W]: head h's query in the
+    row's own layout (``[q_nope W_k[h] | q_rope | 0]``); ``pos`` [B] the
+    position of the row's new token, already scattered. Returns
+    ``softmax(scale * q . row) @ row`` [B, H, W]; the value is its leading
+    ``kv_lora_rank`` lanes. Rows with an all-sentinel table read nothing and
+    return zeros."""
+    from distributed_lion_tpu.ops.pallas_mla_attn import mla_paged_attn
+
+    NB, bs = kv_pages.shape[:2]
+    lengths = jnp.minimum(pos + 1, jnp.sum(tables < NB, axis=1) * bs)
+    return mla_paged_attn(q_abs, kv_pages, tables, lengths, scale=scale)
+
+
+@jax.named_scope("mla_attn")
+def chunked_causal_attention(q, k, v, pos, *, scale: float,
+                             chunk: int = 256):
+    """Masked-softmax attention of S new tokens a row over T cached
+    positions, the queries taken ``chunk`` at a time so that no
+    ``[H, S, T]`` float32 scores are held (32 heads x 2,048 x 3,072 would be
+    0.8 GB; a chunk of 256 is 0.1 GB). q [B, H, S, dk]; k [B, H, T, dk];
+    v [B, H, T, dv] (dv may differ from dk); query s of row b sits at
+    position ``pos[b] + s`` and sees positions ``<=`` its own. Returns
+    [B, H, S, dv] in q's dtype. The arithmetic is
+    :func:`paged_decode_attention`'s gather path, chunk by chunk."""
+    B, H, S, _ = q.shape
+    T = k.shape[2]
+    chunk = min(chunk, S)
+    assert S % chunk == 0, (S, chunk)
+    t_idx = jnp.arange(T)[None, None, :]
+
+    def one(args):
+        qc, first = args                                   # [B,H,c,dk], []
+        scores = jnp.einsum("bhsd,bhtd->bhst", qc, k,
+                            preferred_element_type=jnp.float32) * scale
+        s_pos = pos[:, None] + first + jnp.arange(chunk)[None, :]
+        valid = t_idx <= s_pos[:, :, None]
+        scores = jnp.where(valid[:, None], scores, -1e30)
+        probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
+        return jnp.einsum("bhst,bhtd->bhsd", probs, v,
+                          preferred_element_type=jnp.float32).astype(q.dtype)
+
+    if chunk == S:
+        return one((q, 0))
+    n = S // chunk
+    qs = q.reshape(B, H, n, chunk, -1).transpose(2, 0, 1, 3, 4)
+    out = jax.lax.map(one, (qs, jnp.arange(n) * chunk))    # [n,B,H,c,dv]
+    return out.transpose(1, 2, 0, 3, 4).reshape(B, H, S, -1)
 
 
 def parse_attn_spec(spec: str) -> tuple[str, int, int, int, int]:

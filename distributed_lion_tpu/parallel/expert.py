@@ -309,3 +309,101 @@ def moe_ffn(
     if return_tallies:
         return y, aux, tallies
     return y, aux
+
+
+# ------------------------------------------------- top-k dropless experts
+# Beside the Switch layer above (top-1, capacity, one-hot dispatch; its
+# tests stay): the routing of the DeepSeek-V3 line of models, as served.
+# Every token goes to its ``top_k`` experts whatever the imbalance: the
+# assignments are sorted by expert and the experts run as ONE grouped matmul
+# over the sorted rows (O(tokens x top_k), no ``[E, tokens, D]`` buffer, no
+# one-hot mask, no capacity), then the rows go back to their tokens and are
+# summed with the routing weights; a shared expert sees every token. Single
+# device: the layer holds every expert it routes over (a layer told which
+# experts it holds is ROADMAP Reach).
+
+MOE_COUNTERS = ("moe_assignments", "moe_experts_hit", "moe_load_max")
+
+
+def sigmoid_topk_route(x, router, bias, top_k: int, scale: float):
+    """``s = sigmoid(float32(x) router^T)``; the ``top_k`` experts by
+    ``s + bias`` (the correction bias moves the choice, never the weight);
+    weights ``s[idx] / (sum s[idx] + 1e-20) * scale``. The matmul and the
+    scores are float32 (``Precision.HIGHEST``: a TPU's default would round
+    float32 operands to bfloat16). x [N, D], router [E, D], bias [E] ->
+    idx [N, k] int32, w [N, k] float32."""
+    with jax.named_scope("moe/route"):
+        s = jax.nn.sigmoid(jnp.einsum(
+            "nd,ed->ne", x.astype(jnp.float32), router.astype(jnp.float32),
+            precision=lax.Precision.HIGHEST))
+        _, idx = lax.top_k(s + bias.astype(jnp.float32), top_k)
+        w = jnp.take_along_axis(s, idx, axis=1)
+        return idx, w / (w.sum(-1, keepdims=True) + 1e-20) * scale
+
+
+def grouped_matmul(lhs, rhs, group_sizes):
+    """Rows of ``lhs [M, K]`` sorted by group against ``rhs [E, K, N]``:
+    the Mosaic kernel ``moe_gmm`` on a TPU where the widths are whole lane
+    tiles, ``jax.lax.ragged_dot`` elsewhere (the CPU; the tests hold the
+    two together). Chosen from what the call shows, like
+    ``ops/attention.paged_kernel_applies``: no flag. Rows past the last
+    group are undefined on the kernel's path."""
+    from distributed_lion_tpu.ops import pallas_moe_gmm
+
+    if jax.default_backend() == "tpu" and pallas_moe_gmm.kernel_takes(
+            lhs.shape[1], rhs.shape[2]):
+        return pallas_moe_gmm.moe_gmm(lhs, rhs, group_sizes)
+    return lax.ragged_dot(lhs, rhs, group_sizes.astype(jnp.int32),
+                          preferred_element_type=jnp.float32
+                          ).astype(lhs.dtype)
+
+
+def moe_dropless_ffn(params, x, *, top_k: int, scale: float, valid=None,
+                     return_counters: bool = False):
+    """``sum_i w_i E_idx_i(x) + E_shared(x)`` for local tokens ``x [N, D]``,
+    every ``E`` a SwiGLU ``(silu(x W_g) * (x W_u)) W_d``.
+
+    ``params``: ``router [E, D]``, ``bias [E]`` (float32), the banks
+    ``w_gate`` / ``w_up`` ``[E, D, F]`` and ``w_down [E, F, D]``, and
+    ``shared`` (``w_gate``, ``w_up``, ``w_down`` of one expert every token
+    takes). ``valid`` (optional ``[N]`` bool): pad and inactive lanes sort
+    behind every group, so no expert runs them, and give zero rows.
+
+    ``return_counters``: also a dict of int32 scalars over the valid lanes
+    (``MOE_COUNTERS``): assignments made (tokens x top_k: none is ever
+    dropped), distinct experts hit, and the most rows at one expert."""
+    n, d = x.shape
+    n_experts = params["router"].shape[0]
+    idx, w = sigmoid_topk_route(x, params["router"], params["bias"], top_k,
+                                scale)
+    with jax.named_scope("moe/sort"):
+        flat = idx.reshape(-1)
+        if valid is not None:
+            # a lane with no token sorts past the last expert's rows
+            flat = jnp.where(jnp.repeat(valid, top_k), flat, n_experts)
+        order = jnp.argsort(flat)            # stable: ties keep token order
+        ends = jnp.searchsorted(flat[order], jnp.arange(n_experts + 1))
+        sizes = jnp.diff(ends).astype(jnp.int32)       # [E] rows an expert
+        rows = x[order // top_k]                       # [N k, D], by expert
+    with jax.named_scope("moe/experts"):
+        h = jax.nn.silu(grouped_matmul(rows, params["w_gate"], sizes)) \
+            * grouped_matmul(rows, params["w_up"], sizes)
+        y = grouped_matmul(h, params["w_down"], sizes)
+    with jax.named_scope("moe/combine"):
+        back = jnp.argsort(order)            # each token's k rows, in order
+        y = y[back].reshape(n, top_k, d)
+        if valid is not None:
+            y = jnp.where(valid[:, None, None], y, 0)
+        out = jnp.einsum("nkd,nk->nd", y.astype(jnp.float32), w)
+    with jax.named_scope("moe/shared"):
+        from distributed_lion_tpu.models.llama import _mlp  # the SwiGLU
+
+        out = out + _mlp(x, params["shared"])
+    out = out.astype(x.dtype)
+    if valid is not None:
+        out = jnp.where(valid[:, None], out, 0)
+    if not return_counters:
+        return out
+    return out, {"moe_assignments": sizes.sum(),
+                 "moe_experts_hit": (sizes > 0).sum().astype(jnp.int32),
+                 "moe_load_max": sizes.max()}

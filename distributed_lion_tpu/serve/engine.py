@@ -162,7 +162,7 @@ from distributed_lion_tpu.serve.kv_cache import (
     BlockTables,
     PrefixCache,
     bucket_tokens,
-    init_pages,
+    init_page_leaves,
 )
 from distributed_lion_tpu.serve.metrics import RequestTimes, ServeMetrics
 from distributed_lion_tpu.train import journal
@@ -445,7 +445,11 @@ class ServeModel:
     def __init__(self, family: str, cfg: Any, params: Any,
                  decode_paged: Callable, n_layer: int, kv_heads: int,
                  head_dim: int, cache_dtype: Any,
-                 max_positions: Optional[int] = None):
+                 max_positions: Optional[int] = None,
+                 page_leaves: Optional[Dict[str, tuple]] = None,
+                 kernel_stat: str = "decode_attn_kernel_ticks",
+                 moe_counters: tuple = (), last_logit: bool = False,
+                 shardable: bool = True):
         self.family = family
         self.cfg = cfg
         self.params = params
@@ -454,6 +458,26 @@ class ServeModel:
         self.kv_heads = kv_heads
         self.head_dim = head_dim
         self.cache_dtype = cache_dtype
+        # what one layer of the page pool holds, ``{leaf: (heads, width)}``
+        # (serve/kv_cache.init_page_leaves): keys and values a kv head
+        # unless the family says otherwise (a latent cache: one leaf, one
+        # row a token)
+        self.page_leaves = page_leaves or {"k": (kv_heads, head_dim),
+                                           "v": (kv_heads, head_dim)}
+        # the engine.stats counter of decode ticks that ran the family's
+        # in-place attention kernel
+        self.kernel_stat = kernel_stat
+        # names of the int32 routing counters the hook returns (a dict)
+        # under ``return_moe_stats``; the engine packs them behind the
+        # sampled tokens so that they ride the one transfer a dispatch
+        # already makes
+        self.moe_counters = tuple(moe_counters)
+        # the hook takes ``logit_index`` and returns logits of that one
+        # position ``[B, 1, V]``: a 2,048-token prefill over a 129,280-row
+        # head would otherwise hold 1 GB of float32 logits to read one row
+        self.last_logit = last_logit
+        # False: no tensor / expert sharding and no quantized weights here
+        self.shardable = shardable
         # the model's position budget (gpt2: learned wpe rows; llama's
         # rope extrapolates but n_ctx is still the trained horizon) — the
         # engine refuses a page geometry that would silently alias/exceed
@@ -512,6 +536,31 @@ class ServeModel:
         return ServeModel("llama", cfg, params, decode, cfg.n_layer,
                           cfg.n_kv_head, cfg.head_dim, cfg.compute_dtype,
                           max_positions=cfg.n_ctx)
+
+    @staticmethod
+    def for_joyai(params: Any, cfg: Any) -> "ServeModel":
+        """JoyAI-LLM-Flash (models/joyai): latent attention over ONE page
+        leaf a layer (``[c_kv | k_rope]``, 576 values in 640 lanes at the
+        published widths) and top-k dropless experts with a shared one."""
+        from distributed_lion_tpu.models.joyai import (
+            MOE_COUNTERS,
+            joyai_decode_paged,
+        )
+
+        def decode(p, toks, pages, tables, pos, valid=None, tp_axis=None,
+                   ep_axis=None, return_moe_stats=False, stats_axis=None,
+                   stats_lanes=None, logit_index=None):
+            # the engine refuses tp / ep for this family at build
+            assert tp_axis is None and ep_axis is None and stats_axis is None
+            return joyai_decode_paged(p, toks, cfg, pages, tables, pos,
+                                      valid, return_moe_stats, logit_index)
+
+        return ServeModel(
+            "joyai", cfg, params, decode, cfg.n_layer, 1, cfg.latent_dim,
+            cfg.compute_dtype, max_positions=cfg.n_ctx,
+            page_leaves={"kv": (1, cfg.latent_dim)},
+            kernel_stat="mla_kernel_ticks", moe_counters=MOE_COUNTERS,
+            last_logit=True, shardable=False)
 
 
 def weight_bytes(params: Any) -> int:
@@ -618,6 +667,14 @@ class ServingEngine:
             raise ValueError(
                 f"unknown retrace_guard mode {cfg.retrace_guard!r} "
                 "(off | warn | error)")
+        if not model.shardable and (cfg.tp or cfg.ep or cfg.ep_overlap
+                                    or cfg.quant != "none"):
+            raise ValueError(
+                f"family {model.family!r} serves on one device, unquantized "
+                f"(got --serve_tp {cfg.tp}, --serve_ep {cfg.ep}, "
+                f"--serve_ep_overlap {cfg.ep_overlap}, --quant {cfg.quant}): "
+                "its latent page leaf has no kv-head axis to shard and its "
+                "expert layer holds every expert (ROADMAP Reach)")
         if cfg.quant != "none":
             from distributed_lion_tpu.ops.quant import quantize_tree
 
@@ -721,7 +778,8 @@ class ServingEngine:
             pool_spec = (P(EXPERT_AXIS, None, TENSOR_AXIS, None)
                          if cfg.ep_batch
                          else P(None, None, TENSOR_AXIS, None))
-            self._pages_spec = [{"k": pool_spec, "v": pool_spec}
+            self._pages_spec = [{name: pool_spec
+                                 for name in model.page_leaves}
                                 for _ in range(model.n_layer)]
             pages_sharding = NamedSharding(self._mesh, pool_spec)
         self.params = params
@@ -730,10 +788,9 @@ class ServingEngine:
         self.tables = BlockTables(cfg.resolved_num_blocks(), cfg.block_size,
                                   cfg.max_seqs, cfg.max_blocks_per_seq,
                                   groups=groups)
-        self.pages = init_pages(model.n_layer, cfg.resolved_num_blocks(),
-                                cfg.block_size, model.kv_heads,
-                                model.head_dim, model.cache_dtype,
-                                groups=max(cfg.tp, 1))
+        self.pages = init_page_leaves(
+            model.n_layer, cfg.resolved_num_blocks(), cfg.block_size,
+            model.page_leaves, model.cache_dtype, groups=max(cfg.tp, 1))
         if pages_sharding is not None:
             self.pages = [
                 {k: jax.device_put(v, pages_sharding)
@@ -767,11 +824,11 @@ class ServingEngine:
                       # (every decode tick on a TPU, none on the CPU), and
                       # the pages their rows' lengths need against the
                       # tables' whole width, which the gather path reads
-                      "decode_attn_kernel_ticks": 0, "kv_pages_read": 0,
+                      model.kernel_stat: 0, "kv_pages_read": 0,
                       "kv_pages_table": 0}
         from distributed_lion_tpu.ops.attention import paged_kernel_applies
 
-        nb, bs, _, width = self.pages[0]["k"].shape
+        nb, bs, _, width = next(iter(self.pages[0].values())).shape
         self._decode_kernel = paged_kernel_applies(
             1, (nb // groups, bs, 1, width),  # one shard's share of the pool
             model.cache_dtype)
@@ -783,6 +840,13 @@ class ServingEngine:
             # serving itself never drops — inference routing is no-drop)
             self.stats.update(moe_valid_tokens=0.0, moe_kept_tokens=0.0,
                               moe_capacity_slots=0.0)
+        # a dropless expert layer has no capacity to report against: its
+        # int32 counters (assignments, distinct experts hit, the largest
+        # load at one expert) are packed behind the sampled tokens, decode
+        # and prefill dispatches apart
+        self._moe_counters = (model.moe_counters if cfg.moe_stats else ())
+        for prefix in ("moe_", "moe_prefill_"):
+            self._absorb_counters([0] * len(self._moe_counters), prefix)
         # tick-domain request clocks: always on (integer bookkeeping on
         # events the loop already handles); the wall-clock/sketch plane
         # only when armed. ``self.metrics`` may be replaced before the
@@ -806,7 +870,17 @@ class ServingEngine:
 
         samp = (cfg.temperature, cfg.top_k, cfg.top_p)
         tp_axis, ep_axis = self._tp_axis, self._ep_axis
-        moe_stats = self._moe_stats
+        counters = self._moe_counters
+        moe_stats = self._moe_stats or bool(counters)
+
+        def ride(toks, st):
+            """The dropless layer's counters behind the tokens, one int32
+            vector: no transfer of their own."""
+            if not counters:
+                return toks, st
+            return jnp.concatenate(
+                [toks.astype(jnp.int32),
+                 jnp.stack([st[k] for k in counters]).astype(jnp.int32)]), {}
         # batch-sharded ep: each shard routes only its batch slice, so
         # the routing-load counters must psum over the expert axis to
         # stay global (parallel/expert.moe_ffn stats_axis)
@@ -843,8 +917,8 @@ class ServingEngine:
                 lb, sb, pages = run(pages, slice(n // 2, None))
                 logits = jnp.concatenate([la, lb], axis=0)
                 st = {k: sa[k] + sb[k] for k in sa} if moe_stats else {}
-            return (_sample_rows(logits[:, -1], seeds, counts, *samp),
-                    st), pages
+            return ride(_sample_rows(logits[:, -1], seeds, counts, *samp),
+                        st), pages
 
         def prefill(params, pages, tables, toks, start, length, seed, count):
             # toks [1, P] — the prompt SUFFIX not covered by shared prefix
@@ -864,19 +938,22 @@ class ServingEngine:
             # lane invalid — fake lanes that must not inflate the stats
             # capacity budget past the unsharded prefill's (ceil is
             # nonlinear, so the budget can't be corrected after the fact)
+            at = jnp.maximum(L - 1, 0)
             out = model.decode_paged(params, toks, pages, tables,
                                      start, valid, tp_axis=tp_axis,
                                      ep_axis=ep_axis,
                                      return_moe_stats=moe_stats,
                                      stats_axis=stats_axis,
                                      stats_lanes=(toks.shape[1]
-                                                  if stats_axis else None))
+                                                  if stats_axis else None),
+                                     **({"logit_index": at}
+                                        if model.last_logit else {}))
             logits, pages = out[0], out[1]
             st = out[2] if moe_stats else {}
-            last = jax.lax.dynamic_index_in_dim(
-                logits[0], jnp.maximum(L - 1, 0), 0, keepdims=False)
+            last = logits[0, 0] if model.last_logit else \
+                jax.lax.dynamic_index_in_dim(logits[0], at, 0, keepdims=False)
             tok = _sample_rows(last[None], seed[None], count[None], *samp)
-            return (tok, st), pages
+            return ride(tok, st), pages
 
         def cow_copy(pages, src, dst):
             from distributed_lion_tpu.ops.attention import paged_copy_pages
@@ -1037,6 +1114,15 @@ class ServingEngine:
         self._register_dispatch("cow", jitted, body, donate,
                                 (idx, idx), None)
         return jitted
+
+    def _absorb_counters(self, tail, prefix: str = "moe_") -> None:
+        """Fold the dropless layer's counters, read from behind a
+        dispatch's tokens, into engine.stats: sums, and the largest load
+        as a maximum. ``prefix`` keeps prefill dispatches apart."""
+        for name, value in zip(self._moe_counters, tail):
+            key = name.replace("moe_", prefix, 1)
+            join = max if name.endswith("_max") else int.__add__
+            self.stats[key] = join(self.stats.get(key, 0), int(value))
 
     def _absorb_moe_stats(self, st) -> None:
         """Fold a dispatch's MoE routing-load scalars into engine.stats —
@@ -1269,9 +1355,10 @@ class ServingEngine:
         # ONE host sync per prefill dispatch (the owner group's lane
         # under ep_batch; the only lane otherwise)
         with journal.span("serve/token_read"):
-            first = int(np.asarray(tok).reshape(-1)[
-                g if self._ep_batch else 0])
+            tok = np.asarray(tok).reshape(-1)
+            first = int(tok[g if self._ep_batch else 0])
         self._absorb_moe_stats(st)
+        self._absorb_counters(tok[1:], "moe_prefill_")
         return first
 
     def _admit(self, completions: List[Completion]) -> None:
@@ -1452,9 +1539,10 @@ class ServingEngine:
                 toks = np.asarray(toks)  # ONE host sync for the whole batch
             with span("serve/commit", batch=len(active)):
                 self._absorb_moe_stats(st)
+                self._absorb_counters(toks[self.cfg.max_seqs:])
                 self.stats["decode_ticks"] += 1
                 self.stats["decode_tokens"] += len(active)
-                self.stats["decode_attn_kernel_ticks"] += self._decode_kernel
+                self.stats[self.model.kernel_stat] += self._decode_kernel
                 self.stats["kv_pages_table"] += (
                     self.cfg.max_seqs * self.cfg.max_blocks_per_seq)
                 for i in active:

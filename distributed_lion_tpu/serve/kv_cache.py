@@ -62,20 +62,35 @@ def pool_row_width(kv_heads: int, head_dim: int) -> int:
     return -(-kv_heads * head_dim // 128) * 128
 
 
-def init_pages(n_layer: int, num_blocks: int, block_size: int,
-               kv_heads: int, head_dim: int, dtype, groups: int = 1) -> list:
-    """The per-layer device page pool: ``[{"k", "v"}] * n_layer`` of
-    zeros ``[num_blocks, block_size, groups, W]`` (the module note;
-    ``groups`` = the tensor shards the kv heads split over). Allocated
-    once at engine start — ticks update it in place (donated)."""
+def init_page_leaves(n_layer: int, num_blocks: int, block_size: int,
+                     leaves: dict, dtype, groups: int = 1) -> list:
+    """The per-layer device page pool from a description of its leaves
+    (``ServeModel.page_leaves``): ``{name: (heads, width)}``, each leaf a
+    zero ``[num_blocks, block_size, groups, W]`` with ``W`` the lanes of
+    ``heads / groups`` rows of ``width`` side by side (the module note).
+    GPT-2 and Llama hold ``{"k", "v"}`` of ``(kv_heads, head_dim)``; a
+    latent (MLA) cache holds ONE leaf ``{"kv": (1, kv_lora_rank +
+    rope_dim)}``: a token's row is ``[c_kv | k_rope]`` with no kv-head
+    axis, 576 values in 640 lanes at the published widths (pad lanes
+    zero), so ``groups`` stays 1. Allocated once at engine start; ticks
+    update it in place (donated)."""
     import jax.numpy as jnp
 
-    shape = (num_blocks, block_size, groups,
-             pool_row_width(kv_heads // groups, head_dim))
-    return [
-        {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
-        for _ in range(n_layer)
-    ]
+    def leaf(heads, width):
+        return jnp.zeros((num_blocks, block_size, groups,
+                          pool_row_width(heads // groups, width)), dtype)
+
+    return [{name: leaf(*hw) for name, hw in leaves.items()}
+            for _ in range(n_layer)]
+
+
+def init_pages(n_layer: int, num_blocks: int, block_size: int,
+               kv_heads: int, head_dim: int, dtype, groups: int = 1) -> list:
+    """The ``{"k", "v"}`` pool of a model that caches keys and values a kv
+    head (:func:`init_page_leaves` with that pair)."""
+    pair = {"k": (kv_heads, head_dim), "v": (kv_heads, head_dim)}
+    return init_page_leaves(n_layer, num_blocks, block_size, pair, dtype,
+                            groups)
 
 
 def bucket_tokens(n: int, block_size: int, max_blocks_per_seq: int) -> int:

@@ -80,7 +80,7 @@ from typing import List, Optional, Tuple
 
 import numpy as np
 
-from distributed_lion_tpu.serve.kv_cache import BlockTables, init_pages
+from distributed_lion_tpu.serve.kv_cache import BlockTables, init_page_leaves
 from distributed_lion_tpu.train import journal
 
 
@@ -246,9 +246,8 @@ class DraftModelDrafter:
                 "to at least the serving horizon")
         self.tables = BlockTables(nb, cfg.block_size, cfg.max_seqs,
                                   cfg.max_blocks_per_seq)
-        self.pages = init_pages(model.n_layer, nb, cfg.block_size,
-                                model.kv_heads, model.head_dim,
-                                model.cache_dtype)
+        self.pages = init_page_leaves(model.n_layer, nb, cfg.block_size,
+                                      model.page_leaves, model.cache_dtype)
         self.len = np.zeros((cfg.max_seqs,), np.int32)
         self.dead = np.zeros((cfg.max_seqs,), bool)
         self.draft_dead = 0

@@ -34,7 +34,9 @@ import sys
 SCOPES = ("embed", "attn", "mlp", "head", "xent", "paged_attn",
           "paged_scatter", "paged_gather", "vote/pack", "vote/unpack",
           "vote/tally", "vote/wire", "lion_ballot", "lion_apply",
-          "lion_stats")
+          "lion_stats", "mla/q", "mla/kv_latent", "mla_attn",
+          "mla_paged_attn", "moe/route", "moe/sort", "moe/experts",
+          "moe/shared", "moe/combine", "moe_gmm")
 NO_SCOPE = "(no scope)"
 _FIND = [(s, re.compile(r"(?<=[(/])%s(?=[)/])" % re.escape(s)))
          for s in SCOPES]

@@ -291,6 +291,125 @@ def test_serving_programs_read_the_pool_in_place(one_chip, kind, monkeypatch):
         assert re.search(r'op_name="[^"]*/paged_gather/', text)
 
 
+def test_mla_kernel_compiles_at_published_widths(one_chip):
+    """``mla_paged_attn`` at JoyAI-LLM-Flash's widths and the cell's pool:
+    32 absorbed queries of 640 lanes (576 of them the latent row) a
+    sequence, 128 sequences, pages of 16 rows read where they lie."""
+    from distributed_lion_tpu.analysis.serve_check import pool_leaf_copies
+    from distributed_lion_tpu.ops.pallas_mla_attn import mla_paged_attn
+    from distributed_lion_tpu.serve.kv_cache import pool_row_width
+
+    def on_chip(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    width = pool_row_width(1, 512 + 64)
+    assert width == 640
+    leaf = on_chip((128 * 192, 16, 1, width))
+    text, secs = _compile(
+        lambda q, kv, t, n: mla_paged_attn(q, kv, t, n, scale=192 ** -0.5),
+        on_chip((128, 32, width)), leaf, on_chip((128, 192), jnp.int32),
+        on_chip((128,), jnp.int32))
+    assert secs < 60
+    assert _named_custom_call(text, "mla_paged_attn")
+    assert not pool_leaf_copies(text, leaf)
+
+
+@pytest.mark.parametrize("m,k,n", [(1024, 2048, 768), (1024, 768, 2048),
+                                   (16384, 2048, 768), (16384, 768, 2048)],
+                         ids=["decode_up", "decode_down", "prefill_up",
+                              "prefill_down"])
+def test_moe_gmm_kernel_compiles_at_published_widths(one_chip, m, k, n):
+    """The grouped matmul over 256 experts' banks at a decode tick's 1,024
+    assignments and a 2,048-token prefill's 16,384: Mosaic takes a whole
+    ``[2048, 768]`` bank as one block, and no bank is copied."""
+    from distributed_lion_tpu.analysis.serve_check import pool_leaf_copies
+    from distributed_lion_tpu.ops.pallas_moe_gmm import moe_gmm
+
+    def on_chip(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    bank = on_chip((256, k, n))
+    text, secs = _compile(moe_gmm, on_chip((m, k)), bank,
+                          on_chip((256,), jnp.int32))
+    assert secs < 60
+    assert _named_custom_call(text, "moe_gmm")
+    assert not pool_leaf_copies(text, bank)
+
+
+@pytest.mark.parametrize("kind", ["decode_tick", "prefill_bucket", "cow"])
+def test_latent_serving_programs_read_pool_and_banks_in_place(
+        one_chip, kind, monkeypatch):
+    """JoyAI-LLM-Flash's three serving programs at the published widths
+    (one dense and one expert layer, the whole vocabulary) over the latent
+    pool as the engine lays it out, donated: none copies the pool's one
+    leaf or an expert bank or builds an ``[E, tokens, D]`` buffer; the
+    decode tick holds ``mla_paged_attn`` and ``moe_gmm``, the prefill keeps
+    the gather for attention and takes ``moe_gmm`` for its experts."""
+    from distributed_lion_tpu.analysis.serve_check import pool_leaf_copies
+    from distributed_lion_tpu.models.joyai import (
+        JoyAIConfig, joyai_decode_paged, joyai_init,
+    )
+    from distributed_lion_tpu.ops.attention import paged_copy_pages
+    from distributed_lion_tpu.serve.kv_cache import init_page_leaves
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = JoyAIConfig(n_layer=2)
+    block, per_seq, slots = 16, 192, 128
+    # bucket 1,024 (2,048 tokens would read as an expert bank's d_model)
+    b, s_len = (slots, 1) if kind == "decode_tick" else (1, 1024)
+
+    def place(tree):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=one_chip), tree)
+
+    pages = place(jax.eval_shape(lambda: init_page_leaves(
+        cfg.n_layer, slots * per_seq, block, {"kv": (1, cfg.latent_dim)},
+        cfg.compute_dtype)))
+    leaf = pages[0]["kv"]
+    assert leaf.shape == (slots * per_seq, block, 1, 640)
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+    if kind == "cow":
+        compiled = jax.jit(paged_copy_pages, donate_argnums=(0,)).lower(
+            pages, i32(slots), i32(slots)).compile()
+    else:
+        params = place(jax.eval_shape(
+            lambda: joyai_init(jax.random.key(0), cfg)))
+
+        def fn(params, pages, toks, tables, pos):
+            valid = jnp.arange(s_len)[None, :] < jnp.maximum(pos[:, None], 1)
+            logits, pages, st = joyai_decode_paged(
+                params, toks, cfg, pages, tables, pos, valid, True,
+                None if kind == "decode_tick" else pos[0])
+            return (jnp.argmax(logits[:, -1], -1), st), pages
+
+        compiled = jax.jit(fn, donate_argnums=(1,)).lower(
+            params, pages, i32(b, s_len), i32(b, per_seq), i32(b)).compile()
+    text = compiled.as_text()
+    assert "input_output_alias" in text
+    assert not pool_leaf_copies(text, leaf)
+    if kind == "cow":
+        return
+    bank = jax.ShapeDtypeStruct((cfg.n_experts, cfg.d_model, cfg.moe_d_ff),
+                                jnp.bfloat16)
+    assert not pool_leaf_copies(text, bank)
+    for m in re.finditer(r"= \w+\[([\d,]+)\]", text):   # [E, tokens, .]
+        dims = [int(d) for d in m[1].split(",")]
+        assert len(dims) < 3 or dims[:2] != [cfg.n_experts, b * s_len], m[0]
+    assert _named_custom_call(text, "moe_gmm")
+    assert bool(_named_custom_call(text, "mla_paged_attn")) == (
+        kind == "decode_tick")
+    for scope in ("mla/q", "mla/kv_latent", "mla_attn", "moe/route",
+                  "moe/sort", "moe/experts", "moe/shared", "moe/combine"):
+        assert re.search(r'op_name="[^"]*/%s/' % scope, text), scope
+    if kind == "prefill_bucket":
+        assert re.search(r'op_name="[^"]*/paged_gather/', text)
+        # one position's logits, not 1,024 x 129,280 of them
+        assert not re.search(r"f32\[1,1024,129280\]", text)
+
+
 def test_tp_decode_tick_runs_the_kernel_shard_local(topo, monkeypatch):
     """The TP engine's decode tick on two chips of the described mesh:
     inside ``shard_map`` every rank holds its own kv-head group of the pool

@@ -330,6 +330,13 @@ def _scopes(text):
      "paged_gather"),
     ("jit(train_step)/shard_map/vote/wire/all_to_all:", "vote/wire"),
     ("jit(train_step)/lion_apply/pallas_call:", "lion_apply"),
+    ("jit(decode_tick)/mla_attn/jit(mla_paged_attn)/mla_paged_attn/"
+     "pallas_call:", "mla_paged_attn"),
+    ("jit(decode_tick)/mla/kv_latent/paged_scatter/scatter:",
+     "paged_scatter"),
+    ("jit(prefill)/moe/experts/jit(moe_gmm)/moe_gmm/pallas_call:", "moe_gmm"),
+    ("jit(prefill)/moe/sort/jit(argsort)/sort:", "moe/sort"),
+    ("jit(prefill)/moe/shared/mlp/dot_general:", "mlp"),
     ("pages[45]['k']:", "(no scope)"),
     ("jit(train_step)/headroom/attnx/add:", "(no scope)"),
     ("", "(no scope)"),
