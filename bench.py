@@ -29,8 +29,9 @@ config), microbatch 4 with 16-step grad accumulation — small microbatches
 keep attention-score traffic per pass low while accumulation amortizes the
 optimizer's full-pytree ballot/vote/apply passes over 16x the tokens —
 chunked-vocab CE (vocab_chunks 8: the streaming logsumexp kills the dense
-[B,T,V] f32 logits round-trip), tile-tuned Pallas flash attention
-(flash@512x1024 — the stock tiles LOSE to xla at T=1024, tuned tiles win),
+[B,T,V] f32 logits round-trip), Pallas flash attention (round 3:
+flash@512x1024 of jax's bundled kernel — its stock tiles LOSE to xla at
+T=1024; since PR 27 `auto` takes the repo's own kernel, PERF.md),
 and bf16 Lion momentum. The round-3 sweep measured the combination at
 98,099 tokens/s/chip (~42.8% MFU) vs 82.8k for the round-2 xla/f32-momentum
 config (scripts/SWEEP_r3_raw/sweep2.jsonl).
@@ -271,10 +272,10 @@ def run_inner() -> None:
                                      "vocab_chunks", 8)),
             "mom_dtype": str(knob("BENCH_MOM_DTYPE", "mom_dtype",
                                   "bfloat16")),
-            # 'auto' resolves to the tile-tuned flash winner at the
-            # flagship shape (T=1024 on TPU → flash@512x1024,
-            # ops/attention.attention dispatch, round-3 sweep row) — the
-            # flagship bench needs no explicit attn spec
+            # 'auto' resolves from the shapes (ops/attention): at the
+            # flagship shape on a TPU the repo's own token-major kernel
+            # (ops/pallas_flash_attn, PR 27), which superseded round 3's
+            # flash@512x1024 — the flagship bench needs no explicit spec
             "attn": str(knob("BENCH_ATTN", "attn", "auto")),
             "vocab_pad": int(knob("BENCH_VOCAB_PAD", "vocab_pad", 0)),
             # bucketed, overlapped vote wire (optim.distributed_lion):

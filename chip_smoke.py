@@ -137,9 +137,10 @@ def phase_kernels() -> None:
     import jax.numpy as jnp
 
     from distributed_lion_tpu.ops import lion_math, pallas_lion
-    from distributed_lion_tpu.ops.attention import (
-        attention_flash,
-        attention_xla,
+    from distributed_lion_tpu.ops.attention import attention_xla
+    from distributed_lion_tpu.ops.pallas_flash_attn import (
+        flash_qkv,
+        kernel_takes,
     )
     from distributed_lion_tpu.train import telemetry
 
@@ -226,36 +227,51 @@ def phase_kernels() -> None:
         f"histogram and disagreement count equal telemetry.margin_hist")
     del g, p, total, ballots
 
-    # flash attention at the train step's shape, fwd + bwd, vs attention_xla
-    shape = (4, 12, 1024, 64)
-    kq, kk, kv, kw = jax.random.split(jax.random.key(1), 4)
-    q, k, v, w = (jax.random.normal(x, shape, jnp.bfloat16)
-                  for x in (kq, kk, kv, kw))
+    # the trainer's own flash kernels (ops/pallas_flash_attn), token-major,
+    # fwd + fused bwd, vs attention_xla on the same values laid out
+    # head-major: at the train step's shape, and at one shape `auto` takes
+    # on the kernel's construction alone (a head a lane block, T = 2048)
+    for B, T, H, hd in ((4, 1024, 12, 64), (1, 2048, 8, 128)):
+        D = H * hd
+        check(kernel_takes(T, H, hd, jnp.bfloat16),
+              f"pallas_flash_attn refuses T {T}, {H} heads of {hd}")
+        kq, kw = jax.random.split(jax.random.key(T))
+        qkv = jax.random.normal(kq, (B, T, 3 * D), jnp.bfloat16)
+        w = jax.random.normal(kw, (B, T, D), jnp.bfloat16)
 
-    def run(attn):
-        def loss(q, k, v):
-            out = attn(q, k, v, causal=True)
-            return jnp.sum(out.astype(jnp.float32) * w.astype(jnp.float32)), out
+        def run(attn):
+            def loss(x):
+                out = attn(x)
+                return jnp.sum(out.astype(jnp.float32)
+                               * w.astype(jnp.float32)), out
 
-        return jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2),
-                                          has_aux=True))
+            return jax.jit(jax.value_and_grad(loss, has_aux=True))
 
-    flash = run(lambda q, k, v, causal: attention_flash(
-        q, k, v, causal=causal, block_q=512, block_kv=1024))
-    check(has_mosaic(flash.lower(q, k, v).as_text()),
-          "attention_flash lowered without a Mosaic kernel")
-    (_, out_f), grads_f = flash(q, k, v)
-    (_, out_x), grads_x = run(attention_xla)(q, k, v)
-    errs = [float(jnp.max(jnp.abs(a.astype(jnp.float32)
-                                  - b.astype(jnp.float32))))
-            for a, b in [(out_f, out_x), *zip(grads_f, grads_x)]]
-    log(f"  flash@512x1024 {shape} fwd+bwd vs attention_xla: max |diff| "
-        f"out/dq/dk/dv = {[round(e, 5) for e in errs]} (tol {FLASH_TOL} "
-        "relative to the largest reference value)")
-    for err, ref in zip(errs, (out_x, *grads_x)):
-        check(err <= FLASH_TOL * max(1.0, float(jnp.max(jnp.abs(
-            ref.astype(jnp.float32))))),
-              f"flash attention off by {err}")
+        def reference(x):
+            q, k, v = (x[:, :, i * D:(i + 1) * D].reshape(B, T, H, hd)
+                       .transpose(0, 2, 1, 3) for i in range(3))
+            return attention_xla(q, k, v).transpose(0, 2, 1, 3).reshape(
+                B, T, D)
+
+        kernel = run(lambda x: flash_qkv(x, H))
+        names = mosaic_kernels(kernel.lower(qkv).as_text())
+        check(names == ["flash_attention_fwd", "flash_mha_bwd"],
+              f"flash_qkv lowered to {names}")
+        (_, out_k), grad_k = kernel(qkv)
+        (_, out_x), grad_x = run(reference)(qkv)
+        pairs = [(out_k, out_x)] + [
+            (grad_k[:, :, i * D:(i + 1) * D], grad_x[:, :, i * D:(i + 1) * D])
+            for i in range(3)]
+        errs = [float(jnp.max(jnp.abs(a.astype(jnp.float32)
+                                      - b.astype(jnp.float32))))
+                for a, b in pairs]
+        log(f"  flash_qkv [{B},{T},3x{H}x{hd}] fwd+bwd vs attention_xla: max "
+            f"|diff| out/dq/dk/dv = {[round(e, 5) for e in errs]} (tol "
+            f"{FLASH_TOL} relative to the largest reference value)")
+        for err, (_, ref) in zip(errs, pairs):
+            check(err <= FLASH_TOL * max(1.0, float(jnp.max(jnp.abs(
+                ref.astype(jnp.float32))))),
+                  f"flash_qkv off by {err}")
 
 
 # ------------------------------------------------------------------- train
@@ -284,18 +300,15 @@ def phase_train(out_dir: str) -> None:
     import math
 
     from distributed_lion_tpu.cli import run_clm
-    from distributed_lion_tpu.ops import autotune
+    from distributed_lion_tpu.ops import attention as attention_ops
     from distributed_lion_tpu.ops.pallas_lion import resolve_kernel_mode
     from distributed_lion_tpu.train import loop, resilience
 
     check(resolve_kernel_mode("auto") is False,
           "kernel=auto did not resolve to the compiled Pallas path")
-    spec = autotune.resolve_attn_spec("auto", t=1024, head_dim=64,
-                                      dtype="bfloat16")
-    log("  attention auto at T=1024 hd=64 bf16: tuning cache "
-        + (f"resolves {spec}" if spec != "auto" else
-           "(scripts/tuning_cache.json) has no entry for this device -> "
-           "ops.attention heuristic flash@512x1024"))
+    check(attention_ops.qkv_kernel_applies(1024, 12, 64, "bfloat16"),
+          "attention auto does not take ops/pallas_flash_attn at the train "
+          "step's shape (T 1024, 12 heads of 64, bf16)")
 
     argv = TRAIN_ARGV + ["--output_dir", out_dir, "--save_steps",
                          str(TRAIN_STEPS)]
@@ -320,8 +333,12 @@ def phase_train(out_dir: str) -> None:
     kernels = mosaic_kernels(text)
     check(kernels,
           "train step lowered without a Mosaic kernel")
-    check(any("flash" in k for k in kernels),
-          f"attention auto did not put a flash kernel in the step: {kernels}")
+    check({"flash_attention_fwd", "flash_mha_bwd"} <= set(kernels),
+          f"attention auto did not put ops/pallas_flash_attn's kernels in "
+          f"the step: {kernels}")
+    log("  " + "; ".join(attention_ops.new_resolved_lines()
+                        or ["attention: resolved lines already printed by "
+                            "the trainer"]))
     tokens_per_step = trainer.global_train_batch() * trainer.cfg.block_size
     step_s = [tokens_per_step / r["train/tokens_per_sec"] for r in rows]
     log(f"  run_clm: {TRAIN_STEPS} steps, losses {losses}, wire="

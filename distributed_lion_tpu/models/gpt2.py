@@ -27,7 +27,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from distributed_lion_tpu.ops.attention import attention as shared_attention
+from distributed_lion_tpu.ops.attention import attention_qkv
 from distributed_lion_tpu.parallel.tensor_parallel import (
     copy_to_tp_region,
     reduce_from_tp_region,
@@ -207,8 +207,19 @@ def _dropout(x, rate, key):
     return jnp.where(keep, x / (1.0 - rate), 0.0).astype(x.dtype)
 
 
-def _qkv_project(x, w):
-    """[B,T,d] @ [d,3,d] stacked qkv — dense or LoRA-adapted (factored)."""
+def _qkv_project(x, w, flat: bool = False):
+    """[B,T,d] @ [d,3,d] stacked qkv — dense or LoRA-adapted (factored).
+    ``flat`` gives ``[B, T, 3 * d]`` (a token's q, k, v side by side) and
+    contracts against the weight as ``[d, 3 * d]``: the training path's
+    form. Born 3-D, the activation is row-major, which is how the
+    token-major attention kernel reads it; born ``[B, T, 3, d]`` the TPU
+    compiler lays it out token-minor and re-lays 94 MB a layer on each side
+    of the kernel (tests/test_chip_compile.py pins that no such copy
+    exists). The serving programs keep the 4-D contraction: re-laying a
+    bf16 weight ``[d, 3, d]`` out as ``[d, 3 * d]`` is free beside the
+    trainer's float32 -> bf16 convert and costs GPT-2 XL's decode tick
+    0.9 ms where the weights are the only thing it reads (my chip run,
+    PR 27)."""
     from distributed_lion_tpu.models.lora import LoraTensor
     from distributed_lion_tpu.ops.quant import maybe_dequant
 
@@ -219,11 +230,16 @@ def _qkv_project(x, w):
         xa = x @ w.A.astype(x.dtype)
         delta = jnp.einsum("btr,rce->btce", xa, w.B.astype(x.dtype),
                            preferred_element_type=jnp.float32).astype(x.dtype)
-        return base + w.scaling * delta
+        out = base + w.scaling * delta
+        return out.reshape(x.shape[:2] + (-1,)) if flat else out
     # maybe_dequant: NF4/int8 frozen-weight serving (ops/quant) — a
     # QuantizedTensor in the qkv slot dequantizes into the matmul's
     # producer fusion; dense weights pass through untouched
-    return jnp.einsum("btd,dce->btce", x, maybe_dequant(w, x.dtype).astype(x.dtype),
+    w = maybe_dequant(w, x.dtype).astype(x.dtype)
+    if flat:
+        return jnp.einsum("btd,dn->btn", x, w.reshape(w.shape[0], -1),
+                          preferred_element_type=jnp.float32).astype(x.dtype)
+    return jnp.einsum("btd,dce->btce", x, w,
                       preferred_element_type=jnp.float32).astype(x.dtype)
 
 
@@ -245,41 +261,46 @@ def _attention(x, p, cfg: GPT2Config, key, tp_axis=None, seq_axis=None):
         # ranks so upstream (LN/embedding) grads are complete, not partials
         x = copy_to_tp_region(x, tp_axis)
     H, hd = cfg.n_head // tp, cfg.head_dim
-    qkv = _qkv_project(x, p["qkv"]) + p["qkv_b"].astype(x.dtype)
-    q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
-    q = q.reshape(B, T, H, hd).transpose(0, 2, 1, 3)
-    k = k.reshape(B, T, H, hd).transpose(0, 2, 1, 3)
-    v = v.reshape(B, T, H, hd).transpose(0, 2, 1, 3)
-
-    if cfg.dropout > 0.0 and key is not None and seq_axis is None:
-        # attention-prob dropout needs materialized scores; training with
-        # dropout keeps the XLA path. Under sequence parallelism the scores
-        # never exist in one place, so attention-prob dropout is skipped
-        # (residual/embedding dropout still applies).
-        scores = jnp.einsum("bhqd,bhkd->bhqk", q, k, preferred_element_type=jnp.float32)
-        scores = scores / math.sqrt(hd)
-        causal = jnp.tril(jnp.ones((T, T), bool))
-        scores = jnp.where(causal, scores, -1e30)
-        probs = jax.nn.softmax(scores, axis=-1).astype(x.dtype)
-        probs = _dropout(probs, cfg.dropout, key)
-        out = jnp.einsum("bhqk,bhkd->bhqd", probs, v, preferred_element_type=jnp.float32)
-        out = out.astype(x.dtype)
-    elif seq_axis is not None:
-        from distributed_lion_tpu.parallel.ring_attention import (
-            ring_attention,
-            ulysses_attention,
-        )
-
-        seq_attn = (ulysses_attention if cfg.seq_impl == "ulysses"
-                    else ring_attention)
-        out = seq_attn(q, k, v, axis_name=seq_axis)
+    qkv = (_qkv_project(x, p["qkv"], flat=True)
+           + p["qkv_b"].reshape(-1).astype(x.dtype))          # [B, T, 3 H hd]
+    attn_dropout = cfg.dropout > 0.0 and key is not None and seq_axis is None
+    if not attn_dropout and seq_axis is None:
+        # the projection's output as it lies: on a TPU `auto` hands it to
+        # the token-major kernel (ops/pallas_flash_attn) and gets [B, T, D]
+        # back; no head-major copy exists on that path
+        out = attention_qkv(qkv, H, impl=cfg.attn_impl,
+                            block_q=cfg.flash_block_q,
+                            block_kv=cfg.flash_block_kv,
+                            block_q_bwd=cfg.flash_block_q_bwd,
+                            block_kv_bwd=cfg.flash_block_kv_bwd)
     else:
-        out = shared_attention(q, k, v, causal=True, impl=cfg.attn_impl,
-                               block_q=cfg.flash_block_q,
-                               block_kv=cfg.flash_block_kv,
-                               block_q_bwd=cfg.flash_block_q_bwd,
-                               block_kv_bwd=cfg.flash_block_kv_bwd)
-    out = out.transpose(0, 2, 1, 3).reshape(B, T, H * hd)
+        q, k, v = (x.reshape(B, T, H, hd).transpose(0, 2, 1, 3)
+                   for x in jnp.split(qkv, 3, axis=2))
+        if attn_dropout:
+            # attention-prob dropout needs materialized scores; training
+            # with dropout keeps the XLA path. Under sequence parallelism
+            # the scores never exist in one place, so attention-prob
+            # dropout is skipped (residual/embedding dropout still applies).
+            scores = jnp.einsum("bhqd,bhkd->bhqk", q, k,
+                                preferred_element_type=jnp.float32)
+            scores = scores / math.sqrt(hd)
+            causal = jnp.tril(jnp.ones((T, T), bool))
+            scores = jnp.where(causal, scores, -1e30)
+            probs = jax.nn.softmax(scores, axis=-1).astype(x.dtype)
+            probs = _dropout(probs, cfg.dropout, key)
+            out = jnp.einsum("bhqk,bhkd->bhqd", probs, v,
+                             preferred_element_type=jnp.float32)
+            out = out.astype(x.dtype)
+        else:
+            from distributed_lion_tpu.parallel.ring_attention import (
+                ring_attention,
+                ulysses_attention,
+            )
+
+            seq_attn = (ulysses_attention if cfg.seq_impl == "ulysses"
+                        else ring_attention)
+            out = seq_attn(q, k, v, axis_name=seq_axis)
+        out = out.transpose(0, 2, 1, 3).reshape(B, T, H * hd)
     out = _proj(out, p["proj"])
     if tp_axis is not None:
         out = reduce_from_tp_region(out, tp_axis)  # row-parallel exit (g op)

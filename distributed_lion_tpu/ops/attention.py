@@ -1,35 +1,63 @@
 """Attention implementations with a single dispatch point.
 
+Two entries, one for each layout a caller holds:
+
+- :func:`attention_qkv` — token-major: ``qkv [B, T, 3 * D]``, a token's q,
+  k and v rows side by side as GPT-2's fused projection writes them;
+  returns ``[B, T, D]`` as the output projection reads it.
+- :func:`attention` — head-major: q, k, v ``[B, H, T, head_dim]``; returns
+  the same. A family whose q and k are built separately (RoPE, fewer kv
+  heads: ``models/llama``) calls this one.
+
+Implementations (``impl``):
+
 - ``xla``   — materialized-scores reference: einsum → masked f32 softmax →
-  einsum. Beats the flash kernel's DEFAULT tiles at T=1024 on v5e (82.3k vs
-  59.2k tokens/s/chip, GPT-2 124M train step) — but tile-TUNED flash
-  (``flash_block_q=512, flash_block_kv=1024``) beats xla by ~12% at the
-  same shape (92.2k; scripts/SWEEP_v5e.md round-3 sweep). ``xla`` stays the
-  ``auto`` default below the threshold because the tuned tiles are a
-  per-shape measurement, not a safe generalization.
+  einsum. What every kernel is tested against.
 - ``xla_bf16`` — ``xla`` with the [B,H,T,T] scores stored in bf16 (softmax
   still f32 internally): halves the largest attention intermediate's HBM
   round-trip at ~1e-2 relative error on probs. Opt-in throughput config.
-- ``flash`` — Pallas TPU flash attention (jax's bundled
-  ``pallas.ops.tpu.flash_attention``): O(T) memory online-softmax blocking,
-  the choice for long sequences where [B,H,T,T] scores would blow HBM.
-- ``splash`` — the newer Pallas TPU splash kernel family (sparse-mask
-  blocking); faster than ``flash`` at moderate T but still behind ``xla``
-  at T=1024 on v5e (scripts/SWEEP_v5e.md).
-- ``auto``  — on TPU, in priority order: caller-pinned tiles → flash with
-  those tiles at any shape (an explicit ``auto@BQxBKV`` spec is an
-  operator decision — it must stay sweepable even when a cache entry
-  exists for the shape); otherwise an autotune-cache hit for this
-  device_kind × (T, head_dim) × dtype → flash with the MEASURED winning
-  tiles (ops/autotune, knob ``flash_tiles`` — produced by
-  ``cli/run_tune``); flash for T ≥ 2048 (its memory regime); tile-tuned
-  flash (512x1024) at the swept flagship shape (T=1024, head_dim=64 —
-  GPT-2); xla everywhere else (tuned tiles are per-shape measurements,
-  not safe generalizations). Off TPU: always xla (pinned forward tiles
-  are unused there — Pallas kernels are TPU-only).
+- ``flash`` — jax's bundled Pallas kernel
+  (``pallas.ops.tpu.flash_attention``), head-major, with caller-pinned
+  tiles (``block_q`` ...). The tuner (``ops/autotune``) and
+  ``chip_smoke.py`` call it by name.
+- ``splash`` — the bundled splash kernel family (sparse-mask blocking),
+  head-major, head_dim padded to 128.
+- the repo's own training kernel (``ops/pallas_flash_attn``): token-major
+  operands, one float32 a row as residual, a fused backward. Not an
+  ``impl`` name: it is what ``auto`` resolves to, below.
 
-All take q, k, v as [B, H, T, head_dim] and return [B, H, T, head_dim] in
-q's dtype. Causal only (decoder framework).
+``auto`` resolves from what the call shows, and takes no option to get
+there:
+
+- :func:`attention_qkv` on a TPU, no caller-pinned tiles, a shape the
+  kernel takes as it lies (``pallas_flash_attn.kernel_takes``: head_dim 64
+  or 128, whole 128-lane blocks, T a multiple of 128 up to 8,192) and
+  T >= 1024 → the repo's kernel, reading ``qkv`` in place. Measured on the
+  chip at the training cells' shape (T = 1024, head_dim 64: PERF.md,
+  PR 27); the other shapes follow by what the kernel does not do (no
+  [B,H,T,T] scores, no head-major copy), and ``chip_smoke.py`` checks one
+  of them against ``xla``.
+- every other :func:`attention_qkv` call splits ``qkv`` head-major and
+  goes through :func:`attention`.
+- :func:`attention` on a TPU, in priority order: caller-pinned tiles →
+  ``flash`` with those tiles at any shape (an explicit ``auto@BQxBKV`` spec
+  is an operator decision and stays sweepable); an autotune-cache hit for
+  this device_kind × (T, head_dim) × dtype (``ops/autotune``, knob
+  ``flash_tiles``: the LIBRARY kernel's tiles; ``scripts/tuning_cache.json``
+  holds none for a TPU) → ``flash`` with them; T >= 2048 → ``flash`` at the
+  library's default tiles (its memory regime); ``xla`` everywhere else.
+  Such an entry never outranks the repo's kernel: :func:`attention_qkv`
+  takes that before it gets here (a tuned tile pair, 512x1024, is what made
+  the library's backward write a 1 GB ``di`` buffer a layer: PERF.md,
+  PR 27).
+- off a TPU: always ``xla`` (pinned tiles are dropped: Pallas kernels are
+  TPU-only).
+
+What ``auto`` resolved to is recorded once a shape at trace time
+(:func:`_note_resolved`): an ``attn_resolved`` event in the run journal and
+a line the trainer prints after its first dispatch.
+
+Causal only (decoder framework).
 """
 
 from __future__ import annotations
@@ -418,6 +446,79 @@ def parse_attn_spec(spec: str) -> tuple[str, int, int, int, int]:
     return impl, bq, bkv, bqb, bkvb
 
 
+# What `auto` resolved to, by (entry, T, head_dim, dtype): recorded once at
+# trace time, said by whoever drives the program (Trainer.train prints
+# :func:`new_resolved_lines` after a dispatch that traced).
+_RESOLVED: dict = {}
+_resolved_said = 0
+
+
+def _note_resolved(entry: str, impl: str, T: int, head_dim: int, dtype,
+                   tiles: str) -> None:
+    key = (entry, T, head_dim, jnp.dtype(dtype).name)
+    if key in _RESOLVED:
+        return
+    fields = {"entry": entry, "impl": impl, "T": T, "head_dim": head_dim,
+              "dtype": key[3], "tiles": tiles}
+    _RESOLVED[key] = fields
+    from distributed_lion_tpu.train import journal
+
+    journal.event("attn_resolved", **fields)
+
+
+def new_resolved_lines() -> list:
+    """One ``[setup]`` line for each resolution recorded since the last
+    call (an integer compare when there is none)."""
+    global _resolved_said
+    if _resolved_said == len(_RESOLVED):
+        return []
+    fresh = list(_RESOLVED.values())[_resolved_said:]
+    _resolved_said = len(_RESOLVED)
+    return [f"[setup] attention: {f['entry']} auto -> {f['impl']} "
+            f"(T {f['T']}, head_dim {f['head_dim']}, {f['dtype']}, "
+            f"tiles {f['tiles']})" for f in fresh]
+
+
+def qkv_kernel_applies(T: int, n_head: int, head_dim: int, dtype,
+                       pinned: bool = False) -> bool:
+    """True when :func:`attention_qkv` ``auto`` takes the repo's training
+    kernel for such a call (the rule is in the module doc)."""
+    from distributed_lion_tpu.ops.pallas_flash_attn import kernel_takes
+
+    return (not pinned and jax.default_backend() == "tpu" and T >= 1024
+            and kernel_takes(T, n_head, head_dim, dtype))
+
+
+def attention_qkv(qkv, n_head: int, *, impl: str = "auto",
+                  block_q: int = 0, block_kv: int = 0,
+                  block_q_bwd: int = 0, block_kv_bwd: int = 0):
+    """Causal attention of a fused projection's output: ``qkv`` is
+    ``[B, T, 3, D]`` or ``[B, T, 3 * D]`` (``D = n_head * head_dim``),
+    the result ``[B, T, D]`` in its dtype. ``auto`` hands ``qkv`` to the
+    repo's kernel as it lies where :func:`qkv_kernel_applies`; every other
+    call splits it head-major for :func:`attention`."""
+    B, T = qkv.shape[:2]
+    D = math.prod(qkv.shape[2:]) // 3
+    hd = D // n_head
+    pinned = bool(block_q or block_kv or block_q_bwd or block_kv_bwd)
+    if impl == "auto" and qkv_kernel_applies(T, n_head, hd, qkv.dtype,
+                                             pinned):
+        from distributed_lion_tpu.ops.pallas_flash_attn import (
+            block_for,
+            flash_qkv,
+        )
+
+        blk = block_for(T)
+        _note_resolved("qkv", "pallas_flash_attn", T, hd, qkv.dtype,
+                       f"{blk}x{blk}")
+        return flash_qkv(qkv.reshape(B, T, 3 * D), n_head)
+    q, k, v = (x.reshape(B, T, n_head, hd).transpose(0, 2, 1, 3)
+               for x in jnp.split(qkv.reshape(B, T, 3, D), 3, axis=2))
+    out = attention(q, k, v, impl=impl, block_q=block_q, block_kv=block_kv,
+                    block_q_bwd=block_q_bwd, block_kv_bwd=block_kv_bwd)
+    return out.transpose(0, 2, 1, 3).reshape(B, T, D)
+
+
 def attention(q, k, v, *, causal: bool = True, impl: str = "auto",
               block_q: int = 0, block_kv: int = 0,
               block_q_bwd: int = 0, block_kv_bwd: int = 0):
@@ -427,13 +528,15 @@ def attention(q, k, v, *, causal: bool = True, impl: str = "auto",
         tuned = None
         if on_tpu and not (block_q or block_kv or block_q_bwd or block_kv_bwd):
             # no caller pins → consult the autotune cache (ops/autotune,
-            # knob 'flash_tiles'): a measured winner for THIS device_kind
-            # × (T, head_dim) × dtype outranks every heuristic below —
-            # but never an explicit pin (the elif), which is how sweeps
-            # measure non-cached tiles. Device-keyed, so a cache produced
-            # elsewhere never leaks here; a corrupt cache is loud and
-            # reads as a miss. The lookup is host-side at trace time —
-            # one file read per process (module-level memo in autotune).
+            # knob 'flash_tiles'): a measured winner of the LIBRARY kernel
+            # for THIS device_kind × (T, head_dim) × dtype outranks the
+            # heuristics below — but never an explicit pin (the elif), which
+            # is how sweeps measure non-cached tiles, and never the repo's
+            # own kernel, which attention_qkv takes before it gets here.
+            # Device-keyed, so a cache produced elsewhere never leaks here; a
+            # corrupt cache is loud and reads as a miss. The lookup is
+            # host-side at trace time — one file read per process
+            # (module-level memo in autotune).
             from distributed_lion_tpu.ops.autotune import (
                 attn_shape_key,
                 lookup,
@@ -458,25 +561,20 @@ def attention(q, k, v, *, causal: bool = True, impl: str = "auto",
             impl = "flash"
         elif on_tpu and T >= 2048:
             impl = "flash"
-        elif on_tpu and T == 1024 and q.shape[3] == 64:
-            # measured winner at the swept flagship shape — GPT-2 124M,
-            # T=1024, head_dim=64: tile-tuned flash beats xla by ~12% on
-            # v5e (flash@512x1024 → 98,099 tokens/s/chip vs xla 85.7k,
-            # scripts/SWEEP_r3_raw/sweep2.jsonl). The head_dim gate keeps
-            # OTHER T=1024 workloads (e.g. Llama-7B, head_dim 128 — the 7B
-            # bench leg) on the conservative xla path: the tiles are a
-            # per-shape measurement, not a safe generalization
-            impl = "flash"
-            block_q, block_kv = 512, 1024
         else:
             impl = "xla"
-            # auto resolved AWAY from flash (no TPU backend): pinned tiles
+            # auto resolved AWAY from flash (no TPU backend, or a shape
+            # below the library kernel's regime): pinned tiles
             # — bwd like fwd — are flash knobs with nothing left to tune.
             # Drop them instead of tripping the explicit-impl guard below:
             # an auto@...@BQBxBKVB spec must degrade off-TPU exactly like
             # auto@... does, not raise the flash-knob ValueError that
             # exists for EXPLICIT xla/splash requests
             block_q_bwd = block_kv_bwd = 0
+        _note_resolved(
+            "head-major", impl, T, q.shape[3], q.dtype,
+            f"{block_q}x{block_kv}@{block_q_bwd}x{block_kv_bwd}"
+            if impl == "flash" else "-")
     if impl == "flash":
         return attention_flash(q, k, v, causal=causal,
                                block_q=block_q, block_kv=block_kv,

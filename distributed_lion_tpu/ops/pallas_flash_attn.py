@@ -1,0 +1,347 @@
+"""Causal flash attention for the training path, token-major (Mosaic kernels).
+
+The projection's output is the operand: ``qkv [B, T, 3 * D]`` (``D = H *
+head_dim``; q, k and v side by side in a token's row, as
+``models/gpt2._qkv_project`` writes them) is handed in three times under
+three index maps, the output is ``[B, T, D]`` as ``_proj`` reads it, and
+the cotangent comes back as one ``[B, T, 3 * D]`` array, which the
+projection's weight-gradient matmul reads as it is. No head-major copy of
+an activation exists on either side.
+
+Heads without a head axis. A 128-lane block of a token's row holds
+``128 // head_dim`` heads (two of 64, one of 128), and a 64-lane slice is
+not tile-aligned. So a grid step takes one lane block and, for each of its
+heads, zeroes the other head's lanes of one operand: ``S_h = q_h k^T``
+over the 128-deep contraction is exactly head h's scores (the zeros drop
+the other head's products) and costs the 128 x 128 MXU what a 64-deep
+contraction would; ``P_h v`` gives 128 lanes of which head h's own are
+kept. No lane shuffles (``ops/pallas_paged_attn`` meets the same
+misalignment by spreading the queries block-diagonally).
+
+One float32 a row is the residual: the log-sum-exp, laid out as
+``[B, lane_blocks, q_blocks, heads_per_block, block]`` (a row of ``block``
+lanes a head: the backward works on transposed scores ``S^T = k q^T``,
+where a query's statistic is a lane and broadcasts over sublanes for free).
+``di = sum(o * do)`` is computed in the backward kernel, in VMEM. Nothing is
+spread over 128 lanes in HBM.
+
+Forward, ``flash_attention_fwd``: grid ``(B, lane_blocks, q_blocks)``; the
+lane block's k and v for all T stay in VMEM across the q blocks (their
+index map does not move), and a q block loops over the kv blocks at or
+below its diagonal only: online softmax in float32 (running max, sum,
+accumulator), MXU operands in the input dtype, probabilities cast to v's
+dtype before ``P v``: the library kernel's precision
+(``jax.experimental.pallas.ops.tpu.flash_attention``), and
+``ops/attention.attention_xla`` is the reference the tests hold it to.
+
+Backward, ``flash_mha_bwd``: one fused kernel, grid
+``(B, lane_blocks, 3)``. Step 0 of the last axis does the work for the
+lane block: for each kv block and head, over the q blocks at or above the
+diagonal, ``S^T``, ``P^T = exp(S^T - lse)``, ``dV += P^T do``,
+``dP^T = v do^T``, ``dS^T = P^T (dP^T - di)``, ``dK += dS^T q``,
+``dQ[q block] += dS k`` (float32 accumulators in VMEM): five matmuls and one
+exponential a block where a dq pass and a dkv pass make seven and two.
+Steps 0, 1, 2 then write dq, dk, dv into the cotangent's three column
+ranges (the output's index map moves with the step; the operands' do not,
+so nothing is fetched again).
+
+Only the diagonal blocks are masked; blocks above it are never visited.
+The block is chosen from T (:func:`block_for`); :func:`kernel_takes` says
+which shapes the kernels take as they lie (a caller with another shape
+keeps ``ops/attention``'s other paths).
+
+Names on the device: ``flash_attention_fwd`` and ``flash_mha_bwd``
+(``name=`` and the innermost ``jax.named_scope``, as ``ops/pallas_lion``
+names its kernels).
+"""
+
+from __future__ import annotations
+
+import functools
+import math
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+LANES = 128
+MASKED = -1e30       # attention_xla's mask value
+MAX_T = 8192         # k, v (forward) and the five operands (backward) of a
+# lane block stay in VMEM for all T: 256 B a token an operand, twice for the
+# pipeline's second buffer; at 8,192 the backward holds 34 MB of 128
+
+_NT = (((1,), (1,)), ((), ()))   # a @ b^T
+_TN = (((0,), (0,)), ((), ()))   # a^T @ b
+
+
+def block_for(T: int) -> int:
+    """Rows of a q block = rows of a kv block: the largest of 512, 256, 128
+    that divides T. On the chip at T = 1024, B = 20 (forward / backward of
+    one call): 128 3.40 / 4.38 ms, 256 1.35 / 2.66, 512 0.84 / 1.85 (my chip
+    run, PR 27): a block's fixed cost (the loop step, the accumulators'
+    round trip, the [block, 1] statistics) outweighs what a finer causal
+    walk saves (10 of 16 blocks at 256 against 3 of 4 at 512)."""
+    return next(b for b in (512, 256, 128) if T % b == 0)
+
+
+def kernel_takes(T: int, n_head: int, head_dim: int, dtype) -> bool:
+    """Whether the kernels take ``qkv [B, T, 3 * n_head * head_dim]`` of
+    this dtype as it lies: whole lane blocks of whole heads, whole blocks
+    of rows, and a lane block's operands inside VMEM."""
+    return (head_dim in (64, 128) and (n_head * head_dim) % LANES == 0
+            and T % 128 == 0 and 128 <= T <= MAX_T
+            and jnp.dtype(dtype) in (jnp.dtype(jnp.bfloat16),
+                                     jnp.dtype(jnp.float32)))
+
+
+def _head_masks(head_dim: int):
+    """One ``[1, 128]`` bool row a head of the lane block (None for a head
+    of 128: nothing to mask)."""
+    if head_dim == LANES:
+        return [None]
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, LANES), 1)
+    return [lane // head_dim == h for h in range(LANES // head_dim)]
+
+
+def _own(mask, x):
+    return x if mask is None else jnp.where(mask, x, jnp.zeros_like(x))
+
+
+def _merge(masks, parts):
+    """Each head's own lanes of its part."""
+    out = parts[0]
+    for mask, part in zip(masks[1:], parts[1:]):
+        out = jnp.where(mask, part, out)
+    return out
+
+
+def _rows(i, blk):
+    return pl.ds(pl.multiple_of(i * blk, blk), blk)
+
+
+# ----------------------------------------------------------------- forward
+def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, *,
+                scale: float, head_dim: int):
+    blk = q_ref.shape[0]
+    qi = pl.program_id(2)
+    masks = _head_masks(head_dim)
+    q = q_ref[...]
+    q_heads = [_own(mask, q) for mask in masks]
+
+    def block(ki, stats, diagonal):
+        """One kv block for every head of the lane block (the heads side by
+        side in one loop body: one's matmuls run under the other's
+        softmax)."""
+        k, v = k_ref[_rows(ki, blk), :], v_ref[_rows(ki, blk), :]
+        out = []
+        for h, (q_h, (m_prev, l_prev)) in enumerate(zip(q_heads, stats)):
+            s = jax.lax.dot_general(
+                q_h, k, _NT, preferred_element_type=jnp.float32) * scale
+            if diagonal:
+                row = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+                col = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+                s = jnp.where(col <= row, s, MASKED)
+            m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            p = jnp.exp(s - m_new)
+            acc_ref[h] = alpha * acc_ref[h] + jnp.dot(
+                p.astype(v.dtype), v, preferred_element_type=jnp.float32)
+            out.append((m_new, alpha * l_prev + p.sum(axis=1, keepdims=True)))
+        return tuple(out)
+
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+    stats = jax.lax.fori_loop(
+        0, qi, lambda ki, stats: block(ki, stats, False),
+        tuple((jnp.full((blk, 1), MASKED, jnp.float32),
+               jnp.zeros((blk, 1), jnp.float32)) for _ in masks))
+    stats = block(qi, stats, True)
+    o_ref[...] = _merge(masks, [acc_ref[h] / l for h, (_, l) in
+                                enumerate(stats)]).astype(o_ref.dtype)
+    # a head's statistic is a column here and a row of lanes in HBM: one
+    # [blk, 128] transpose, a head's value in its own lanes
+    lse_t = _merge(masks, [jnp.broadcast_to(m + jnp.log(l), (blk, LANES))
+                           for m, l in stats]).T
+    for h in range(len(masks)):
+        lse_ref[h:h + 1, :] = lse_t[h * head_dim:h * head_dim + 1, :]
+
+
+def _vmem_limit(T: int, itemsize: int, operands: int, scratch: int) -> int:
+    """Bytes the kernel may use: ``operands`` [T, 128] blocks of the input
+    dtype, double-buffered, ``scratch`` bytes beside them, and room for the
+    block-sized temporaries; never under the compiler's own default."""
+    need = 2 * operands * T * LANES * itemsize + scratch + (12 << 20)
+    return max(need, 32 << 20)
+
+
+def _geometry(qkv, n_head: int):
+    """B, T, D, head_dim, block, lane blocks, q blocks, heads a lane block."""
+    B, T, width = qkv.shape
+    D = width // 3
+    hd, blk = D // n_head, block_for(T)
+    return B, T, D, hd, blk, D // LANES, T // blk, LANES // hd
+
+
+def _fwd(qkv, n_head: int, interpret: bool):
+    B, T, D, hd, blk, nj, nq, hpb = _geometry(qkv, n_head)
+    q_spec = pl.BlockSpec((None, blk, LANES), lambda b, j, i: (b, i, j))
+    k_spec = pl.BlockSpec((None, T, LANES), lambda b, j, i: (b, 0, nj + j))
+    v_spec = pl.BlockSpec((None, T, LANES),
+                          lambda b, j, i: (b, 0, 2 * nj + j))
+    with jax.named_scope("flash_attention_fwd"):
+        return pl.pallas_call(
+            functools.partial(_fwd_kernel, scale=1.0 / math.sqrt(hd),
+                              head_dim=hd),
+            grid=(B, nj, nq),
+            in_specs=[q_spec, k_spec, v_spec],
+            out_specs=[
+                pl.BlockSpec((None, blk, LANES), lambda b, j, i: (b, i, j)),
+                pl.BlockSpec((None, None, None, hpb, blk),
+                             lambda b, j, i: (b, j, i, 0, 0)),
+            ],
+            out_shape=[
+                jax.ShapeDtypeStruct((B, T, D), qkv.dtype),
+                jax.ShapeDtypeStruct((B, nj, nq, hpb, blk), jnp.float32),
+            ],
+            scratch_shapes=[pltpu.VMEM((hpb, blk, LANES), jnp.float32)],
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "parallel", "arbitrary"),
+                vmem_limit_bytes=_vmem_limit(T, qkv.dtype.itemsize, 2, 0)),
+            interpret=interpret,
+            name="flash_attention_fwd",
+        )(qkv, qkv, qkv)
+
+
+# ---------------------------------------------------------------- backward
+def _bwd_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, out_ref,
+                dq_ref, dkv_ref, di_ref, acc_ref, *, scale: float,
+                head_dim: int):
+    nq, _, blk = lse_ref.shape
+    which = pl.program_id(2)
+    masks = _head_masks(head_dim)
+
+    @pl.when(which == 0)
+    def _():
+        dq_ref[...] = jnp.zeros_like(dq_ref)
+
+        def di_block(i, _):
+            # di = sum over a head's lanes of o * do, as a row of lanes
+            od_t = (o_ref[_rows(i, blk), :].astype(jnp.float32)
+                    * do_ref[_rows(i, blk), :].astype(jnp.float32)).T
+            for h in range(len(masks)):
+                di_ref[i, h:h + 1, :] = od_t[
+                    h * head_dim:(h + 1) * head_dim].sum(axis=0,
+                                                         keepdims=True)
+            return 0
+
+        jax.lax.fori_loop(0, nq, di_block, 0)
+
+        def kv_block(ki, _):
+            k, v = k_ref[_rows(ki, blk), :], v_ref[_rows(ki, blk), :]
+            kv_heads = [(_own(mask, k), _own(mask, v)) for mask in masks]
+
+            def q_block(qi, diagonal):
+                """One q block for every head of the lane block (side by
+                side in one loop body, as in the forward)."""
+                q, do = q_ref[_rows(qi, blk), :], do_ref[_rows(qi, blk), :]
+                dq = None
+                for h, (k_h, v_h) in enumerate(kv_heads):
+                    s_t = jax.lax.dot_general(
+                        k_h, q, _NT, preferred_element_type=jnp.float32)
+                    p_t = jnp.exp(s_t * scale - lse_ref[qi, h:h + 1, :])
+                    if diagonal:
+                        row = jax.lax.broadcasted_iota(jnp.int32, p_t.shape, 0)
+                        col = jax.lax.broadcasted_iota(jnp.int32, p_t.shape, 1)
+                        p_t = jnp.where(row <= col, p_t, 0.0)
+                    acc_ref[1, h] += jnp.dot(
+                        p_t.astype(do.dtype), do,
+                        preferred_element_type=jnp.float32)
+                    dp_t = jax.lax.dot_general(
+                        v_h, do, _NT, preferred_element_type=jnp.float32)
+                    ds_t = ((dp_t - di_ref[qi, h:h + 1, :]) * p_t
+                            * scale).astype(q.dtype)
+                    acc_ref[0, h] += jnp.dot(
+                        ds_t, q, preferred_element_type=jnp.float32)
+                    # k_h's other lanes are zero: each head adds to its own
+                    dq_h = jax.lax.dot_general(
+                        ds_t, k_h, _TN, preferred_element_type=jnp.float32)
+                    dq = dq_h if dq is None else dq + dq_h
+                dq_ref[_rows(qi, blk), :] += dq
+
+            def below(qi, _):
+                q_block(qi, False)
+                return 0
+
+            acc_ref[...] = jnp.zeros_like(acc_ref)
+            q_block(ki, True)
+            jax.lax.fori_loop(ki + 1, nq, below, 0)
+            for n in range(2):           # dk, dv: each head's own lanes
+                dkv_ref[n, _rows(ki, blk), :] = _merge(
+                    masks, [acc_ref[n, h] for h in range(len(masks))]
+                ).astype(dkv_ref.dtype)
+            return 0
+
+        jax.lax.fori_loop(0, nq, kv_block, 0)
+        out_ref[...] = dq_ref[...].astype(out_ref.dtype)
+
+    for n in range(2):
+        @pl.when(which == n + 1)
+        def _(n=n):
+            out_ref[...] = dkv_ref[n]
+
+
+def _bwd(qkv, o, lse, do, n_head: int, interpret: bool):
+    B, T, _, hd, blk, nj, nq, hpb = _geometry(qkv, n_head)
+
+    def cols(first):
+        return pl.BlockSpec((None, T, LANES),
+                            lambda b, j, w: (b, 0, first + j))
+
+    scratch = T * LANES * (4 + 2 * qkv.dtype.itemsize)
+    with jax.named_scope("flash_mha_bwd"):
+        return pl.pallas_call(
+            functools.partial(_bwd_kernel, scale=1.0 / math.sqrt(hd),
+                              head_dim=hd),
+            grid=(B, nj, 3),
+            in_specs=[cols(0), cols(nj), cols(2 * nj), cols(0), cols(0),
+                      pl.BlockSpec((None, None, nq, hpb, blk),
+                                   lambda b, j, w: (b, j, 0, 0, 0))],
+            out_specs=pl.BlockSpec((None, T, LANES),
+                                   lambda b, j, w: (b, 0, w * nj + j)),
+            out_shape=jax.ShapeDtypeStruct(qkv.shape, qkv.dtype),
+            scratch_shapes=[
+                pltpu.VMEM((T, LANES), jnp.float32),             # dq
+                pltpu.VMEM((2, T, LANES), qkv.dtype),            # dk, dv
+                pltpu.VMEM((nq, hpb, blk), jnp.float32),         # di
+                pltpu.VMEM((2, hpb, blk, LANES), jnp.float32),   # dk, dv acc
+            ],
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "parallel", "arbitrary"),
+                vmem_limit_bytes=_vmem_limit(T, qkv.dtype.itemsize, 6,
+                                             scratch)),
+            interpret=interpret,
+            name="flash_mha_bwd",
+        )(qkv, qkv, qkv, o, do, lse)
+
+
+# ------------------------------------------------------------------- entry
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2))
+def flash_qkv(qkv, n_head: int, interpret: bool = False):
+    """Causal attention of ``qkv [B, T, 3 * D]`` (a token's q, k, v rows
+    side by side, ``n_head`` heads each, head h in lanes
+    ``h * head_dim ..``): ``[B, T, D]`` in qkv's dtype, scores scaled by
+    ``1 / sqrt(head_dim)``. :func:`kernel_takes` says which shapes."""
+    return _fwd(qkv, n_head, interpret)[0]
+
+
+def _flash_qkv_fwd(qkv, n_head, interpret):
+    o, lse = _fwd(qkv, n_head, interpret)
+    return o, (qkv, o, lse)
+
+
+def _flash_qkv_bwd(n_head, interpret, res, do):
+    qkv, o, lse = res
+    return (_bwd(qkv, o, lse, do, n_head, interpret),)
+
+
+flash_qkv.defvjp(_flash_qkv_fwd, _flash_qkv_bwd)

@@ -35,6 +35,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from distributed_lion_tpu.models.gpt2 import GPT2Config, count_params, gpt2_apply, gpt2_init
 from distributed_lion_tpu.models.loss import clm_loss_and_metrics
+from distributed_lion_tpu.ops import attention as attention_ops
 from distributed_lion_tpu.ops.codec import vote_chunk_elems, wire_bytes_per_param
 from distributed_lion_tpu.optim import (
     distributed_lion,
@@ -1678,10 +1679,12 @@ class Trainer:
                     )
                 self.step_count += 1
                 advanced = 1
-            for line in compile_cache.new_lines():
-                # which program was traced, lowered, compiled or loaded,
-                # said once, when it happens (the first dispatch; a
-                # retrace later in the run)
+            for line in (attention_ops.new_resolved_lines()
+                         + compile_cache.new_lines()):
+                # what attention `auto` resolved to while the program was
+                # traced; which program was traced, lowered, compiled or
+                # loaded: said once, when it happens (the first dispatch;
+                # a retrace later in the run)
                 emit(line)
             self.profiler.maybe_stop(self.step_count, sync=metrics)
             if self._guard is not None:

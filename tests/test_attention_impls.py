@@ -108,14 +108,17 @@ def test_bwd_tiles_refused_off_flash():
         attention(q, k, v, impl="xla", block_kv_bwd=128)
 
 def test_auto_picks_tuned_flash_at_swept_flagship_shape(monkeypatch):
-    """VERDICT r3 item 6: `auto` on TPU at the swept flagship shape
-    (T=1024, no caller-pinned tiles) must dispatch to the MEASURED winner —
-    tile-tuned flash@512x1024 (98,099 tok/s/chip vs xla's 85.7k,
-    scripts/SWEEP_r3_raw/sweep2.jsonl) — while unswept shapes keep the xla
-    fallback and caller-pinned tiles are honored. Backend + kernel are
-    monkeypatched: this pins DISPATCH, the kernels' math is pinned by the
-    equivalence tests above."""
+    """What `auto` resolves to at the swept flagship shape (T=1024,
+    head_dim 64) since PR 27: the token-major entry, which GPT-2 calls,
+    takes the repo's own kernel there (``ops/pallas_flash_attn``, no tiles
+    to pin: tests/test_flash_attn_kernel.py pins that resolution shape by
+    shape), and the hard-coded flash@512x1024 is gone from the head-major
+    entry, which keeps xla below the library kernel's regime, honors
+    caller-pinned tiles at any shape and takes default flash from T=2048.
+    Backend + kernels are monkeypatched: this pins DISPATCH, the kernels'
+    math is pinned by the equivalence tests."""
     from distributed_lion_tpu.ops import attention as A
+    from distributed_lion_tpu.ops import pallas_flash_attn as F
 
     calls = []
 
@@ -128,27 +131,44 @@ def test_auto_picks_tuned_flash_at_swept_flagship_shape(monkeypatch):
         calls.append("xla")
         return q
 
+    def fake_kernel(qkv, n_head, interpret=False):
+        calls.append("kernel")
+        return qkv[..., :qkv.shape[-1] // 3]
+
     monkeypatch.setattr(A, "attention_flash", fake_flash)
     monkeypatch.setattr(A, "attention_xla", fake_xla)
+    monkeypatch.setattr(F, "flash_qkv", fake_kernel)
     monkeypatch.setattr(A.jax, "default_backend", lambda: "tpu")
 
+    def fused(q, k, v):
+        """[B, H, T, hd] x 3 -> the projection's [B, T, 3, D]."""
+        B, H, T, hd = q.shape
+        return jnp.stack([x.transpose(0, 2, 1, 3).reshape(B, T, H * hd)
+                          for x in (q, k, v)], axis=2)
+
     q, k, v = _qkv(T=1024)
+    A.attention_qkv(fused(q, k, v), 4, impl="auto")
+    assert calls[-1] == "kernel"  # the swept shape: the repo's kernel
+
+    A.attention_qkv(fused(q, k, v), 4, impl="auto", block_q=512,
+                    block_kv=1024)
+    assert calls[-1] == (512, 1024, 0, 0)  # pinned tiles: library flash
+
     A.attention(q, k, v, impl="auto")
-    assert calls[-1] == (512, 1024, 0, 0)  # tuned tiles at the swept shape
+    assert calls[-1] == "xla"  # head-major at T=1024: no tuned-tile branch
 
     q, k, v = _qkv(T=1024, hd=128)
     A.attention(q, k, v, impl="auto")
-    # T=1024 but head_dim 128 (Llama shapes): NOT the swept shape — the
-    # GPT-2-tuned tiles must not leak onto it (keeps the 7B bench leg's
-    # round-3 xla methodology)
-    assert calls[-1] == "xla"
+    assert calls[-1] == "xla"  # Llama shapes keep the 7B bench leg's xla
 
     A.attention(q, k, v, impl="auto", block_q=256, block_kv=256)
     assert calls[-1] == (256, 256, 0, 0)  # pinned tiles honored via flash
 
     q, k, v = _qkv(T=512)
     A.attention(q, k, v, impl="auto")
-    assert calls[-1] == "xla"  # unswept shape keeps the conservative path
+    assert calls[-1] == "xla"  # below the kernels' regime
+    A.attention_qkv(fused(q, k, v), 4, impl="auto")
+    assert calls[-1] == "xla"  # ... from the token-major entry too
 
     A.attention(q, k, v, impl="auto", block_q=128, block_kv=128)
     assert calls[-1] == (128, 128, 0, 0)  # pinned tiles win at any shape
@@ -156,11 +176,15 @@ def test_auto_picks_tuned_flash_at_swept_flagship_shape(monkeypatch):
     q, k, v = _qkv(T=2048)
     A.attention(q, k, v, impl="auto")
     assert calls[-1] == (0, 0, 0, 0)  # long-context regime: default flash
+    A.attention_qkv(fused(q, k, v), 4, impl="auto")
+    assert calls[-1] == "kernel"  # ... the repo's kernel where qkv is fused
 
     monkeypatch.setattr(A.jax, "default_backend", lambda: "cpu")
     q, k, v = _qkv(T=1024)
     A.attention(q, k, v, impl="auto")
-    assert calls[-1] == "xla"  # no TPU: never the pallas kernel
+    assert calls[-1] == "xla"  # no TPU: never a pallas kernel
+    A.attention_qkv(fused(q, k, v), 4, impl="auto")
+    assert calls[-1] == "xla"
 
 
 def test_auto_bwd_only_tiles_dispatch(monkeypatch):

@@ -165,6 +165,139 @@ def test_flash_attention_fwd_bwd_compiles(one_chip, shape, tiles):
     assert text.count("tpu_custom_call") >= 3  # fwd, dq, dkv
 
 
+@pytest.mark.parametrize("shape,dtype", [
+    ((4, 1024, 12, 64), jnp.bfloat16),    # the 124M train step
+    ((1, 2048, 32, 128), jnp.bfloat16),   # a head a lane block
+    ((1, 8192, 8, 128), jnp.bfloat16),    # the longest T the kernel takes
+    ((2, 1024, 12, 64), jnp.float32),     # compute_dtype float32
+], ids=["124m", "T2048-hd128", "T8192-hd128", "124m-f32"])
+def test_repo_flash_kernels_compile(one_chip, shape, dtype):
+    """``ops/pallas_flash_attn`` forward and fused backward, token-major,
+    at real widths: two Mosaic calls under the names the benchmark's
+    readers find."""
+    from distributed_lion_tpu.ops.pallas_flash_attn import (
+        flash_qkv, kernel_takes,
+    )
+
+    B, T, H, hd = shape
+    assert kernel_takes(T, H, hd, dtype)
+    x = jax.ShapeDtypeStruct((B, T, 3 * H * hd), dtype, sharding=one_chip)
+    text, _ = _compile(
+        jax.grad(lambda x: flash_qkv(x, H).astype(jnp.float32).sum()), x)
+    for kernel in ("flash_attention_fwd", "flash_mha_bwd"):
+        assert _named_custom_call(text, kernel), kernel
+
+
+# ``benchmark/lib/layer_common.FLASH_KERNELS``, copied: tests outside
+# tests/benchmark hold the harness to its string, not to its module
+FLASH_KERNELS = r"flash_attention|flash_mha"
+
+
+def _two_remat_blocks(place):
+    """Two remat GPT-2 124M blocks, forward and backward: ``(loss, blocks)``
+    with the blocks' float32 parameters as shapes under ``place(shape)``."""
+    from distributed_lion_tpu.models import gpt2
+
+    cfg = gpt2.GPT2Config.gpt2_124m(n_layer=2, dropout=0.0)
+    blocks = jax.tree.map(
+        place, jax.eval_shape(lambda: gpt2.gpt2_init(jax.random.key(0), cfg)
+                              )["blocks"])
+    block = gpt2._block_remat_for(cfg)
+
+    def loss(blocks, x):
+        for p in blocks:
+            p = jax.tree.map(lambda a: a.astype(jnp.bfloat16), p)
+            x = block(x, p, None, cfg, None, None)
+        return x.astype(jnp.float32).sum()
+
+    return loss, blocks
+
+
+@pytest.fixture(scope="module")
+def train_blocks_hlo(one_chip):
+    """The two blocks at a training cell's microbatch, compiled for the
+    described chip with attention ``auto`` as a TPU backend resolves it
+    (this process's backend is the CPU, so the fixture says "tpu" in its
+    place while it traces). One compile a microbatch (7 s), shared by the
+    pins below."""
+    texts = {}
+
+    def compiled(B):
+        if B not in texts:
+            loss, blocks = _two_remat_blocks(lambda a: jax.ShapeDtypeStruct(
+                a.shape, a.dtype, sharding=one_chip))
+            x = jax.ShapeDtypeStruct((B, 1024, 768), jnp.bfloat16,
+                                     sharding=one_chip)
+            real = jax.default_backend
+            jax.default_backend = lambda: "tpu"
+            try:
+                texts[B], _ = _compile(jax.grad(loss, argnums=(0, 1)),
+                                       blocks, x)
+            finally:
+                jax.default_backend = real
+        return texts[B]
+
+    return compiled
+
+
+def _instructions(text, opcode):
+    """(name, shape, op_name) of every ``opcode`` instruction in compiled
+    HLO text, fused computations included."""
+    out = []
+    for line in text.splitlines():
+        m = re.search(r"%(\S+) = (\S+) " + opcode + r"\(", line)
+        if m:
+            op = re.search(r'op_name="([^"]*)"', line)
+            out.append((m.group(1), m.group(2), op.group(1) if op else ""))
+    return out
+
+
+@pytest.mark.parametrize("B", [20, 4], ids=["readme-20x8", "vote-4x2"])
+def test_train_blocks_hold_the_repo_flash_kernels(train_blocks_hlo, B):
+    """A layer's forward, the remat's second forward and one fused
+    backward, each under a name ``FLASH_KERNELS`` matches (``flash_ms.train``
+    and ``flash_roofline`` read the device ops by that pattern), and no
+    call of the library's kernels."""
+    text = train_blocks_hlo(B)
+    names = re.findall(r'%(\S+) = [^\n]*custom_call_target="tpu_custom_call"',
+                       text)
+    kinds = [re.sub(r"\.\d+$", "", n) for n in names]
+    # (the last block's forward and its recompute are one call: XLA merges
+    # the two, which nothing separates in a two-block program)
+    assert set(kinds) == {"flash_attention_fwd", "flash_mha_bwd"}, names
+    assert kinds.count("flash_mha_bwd") == 2, names
+    assert kinds.count("flash_attention_fwd") in (3, 4), names
+    assert all(re.search(FLASH_KERNELS, n) for n in names)
+
+
+@pytest.mark.parametrize("B", [20, 4], ids=["readme-20x8", "vote-4x2"])
+def test_train_blocks_broadcast_no_softmax_statistic(train_blocks_hlo, B):
+    """What ISSUE 27 found in the library's backward: ``m``, ``l`` and
+    ``di`` spread over 128 lanes (``f32[B,12,1024,128]`` x 5) and ``di``
+    over ``block_k_major`` (``f32[B,12,1024,1024]``, 1 GB at B = 20) a
+    layer. The residual is one float32 a row now: no float32 broadcast of
+    a per-row statistic to 128 lanes or more exists, in any layout of it."""
+    wide = [(n, shape) for n, shape, _ in
+            _instructions(train_blocks_hlo(B), "broadcast")
+            if re.match(r"f32\[%d,(12,1024|1024,12),(\d+)\]" % B, shape)
+            and int(re.match(r"f32\[[\d,]*,(\d+)\]", shape).group(1)) >= 128]
+    assert not wide, wide
+
+
+@pytest.mark.parametrize("B", [20, 4], ids=["readme-20x8", "vote-4x2"])
+def test_train_blocks_copy_no_attention_activation(train_blocks_hlo, B):
+    """No head-major copy (``[B,1024,12,64]`` / ``[B,12,1024,64]``: twelve a
+    layer before) and no re-laying of the fused projection or its cotangent
+    (``[B,1024,2304]`` / ``[B,1024,3,768]``: the projection is born in the
+    kernel's layout) under an ``attn`` op_name."""
+    acts = r"bf16\[%d,(1024,12,64|12,1024,64|1024,2304|1024,3,768)\]" % B
+    bad = [(n, shape, op) for n, shape, op in
+           _instructions(train_blocks_hlo(B), "copy")
+           + _instructions(train_blocks_hlo(B), "transpose")
+           if "/attn/" in op and re.match(acts, shape)]
+    assert not bad, bad
+
+
 # ------------------------------------------------------ paged serving engine
 @pytest.mark.parametrize("kind", ["decode_tick", "prefill_bucket"])
 def test_paged_decode_compiles_with_donated_pool(one_chip, kind):
@@ -334,6 +467,29 @@ def test_moe_gmm_kernel_compiles_at_published_widths(one_chip, m, k, n):
     assert secs < 60
     assert _named_custom_call(text, "moe_gmm")
     assert not pool_leaf_copies(text, bank)
+
+
+def test_train_blocks_compile_under_the_workers_shard_map(topo, monkeypatch):
+    """Cell 4's path: the same two remat blocks inside a ``shard_map`` over
+    the four chips' ``data`` axis, 4 sequences a worker: each worker runs
+    the repo's flash kernels on its own shard, no collective among them."""
+    mesh = Mesh(np.array(topo.devices).reshape(4), ("data",))
+    repl, split = NamedSharding(mesh, P()), NamedSharding(mesh, P("data"))
+    loss, blocks = _two_remat_blocks(lambda a: jax.ShapeDtypeStruct(
+        a.shape, a.dtype, sharding=repl))
+    x = jax.ShapeDtypeStruct((16, 1024, 768), jnp.bfloat16, sharding=split)
+
+    def worker(blocks, x):
+        g = jax.grad(loss)(blocks, x)
+        return jax.tree.map(lambda a: a[None], g)   # a worker's own gradient
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    step = jax.shard_map(worker, mesh=mesh, in_specs=(P(), P("data")),
+                         out_specs=P("data"), check_vma=False)
+    text, _ = _compile(step, blocks, x)
+    assert _named_custom_call(text, "flash_attention_fwd")
+    assert _named_custom_call(text, "flash_mha_bwd")
+    assert not re.search(r"all-(reduce|gather|to-all)", text)
 
 
 @pytest.mark.parametrize("kind", ["decode_tick", "prefill_bucket", "cow"])

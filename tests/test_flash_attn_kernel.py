@@ -1,0 +1,319 @@
+"""The trainer's own flash-attention kernels (ops/pallas_flash_attn), through
+Pallas interpret mode at small sizes, against ``attention_xla``; the
+token-major entry against the head-major one; and the rule by which
+``auto`` takes the kernel, from the shapes a call shows."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from distributed_lion_tpu.ops import attention as A
+from distributed_lion_tpu.ops import pallas_flash_attn as F
+
+FLASH_TOL = 2e-2   # chip_smoke.py's: |kernel - xla| on bf16 values of O(1)
+
+
+def _heads(x, H):
+    """[B, T, H * hd] -> [B, H, T, hd]."""
+    B, T, D = x.shape
+    return x.reshape(B, T, H, D // H).transpose(0, 2, 1, 3)
+
+
+def _reference(qkv, H):
+    """``attention_xla`` on the three column ranges of ``qkv``."""
+    B, T, W = qkv.shape
+    D = W // 3
+    q, k, v = (_heads(qkv[:, :, i * D:(i + 1) * D], H) for i in range(3))
+    return A.attention_xla(q, k, v).transpose(0, 2, 1, 3).reshape(B, T, D)
+
+
+def _inputs(B, T, H, hd, dtype, seed=0):
+    kq, kw = jax.random.split(jax.random.key(seed))
+    return (jax.random.normal(kq, (B, T, 3 * H * hd), dtype),
+            jax.random.normal(kw, (B, T, H * hd), dtype))
+
+
+def _out_and_grad(fn, qkv, w):
+    """fn's output and the gradient of ``sum(out * w)``: dq, dk, dv are
+    the three column ranges of the one cotangent."""
+    def loss(x):
+        out = fn(x)
+        return jnp.sum(out.astype(jnp.float32) * w.astype(jnp.float32)), out
+
+    (_, out), grad = jax.value_and_grad(loss, has_aux=True)(qkv)
+    return out, grad
+
+
+SHAPES = [  # B, T, H, head_dim: T of one block and of several, B > 1
+    (2, 128, 2, 64),      # one block of 128
+    (2, 512, 4, 64),      # one block of 512, two lane blocks
+    (2, 1024, 2, 64),     # two blocks of 512: the cells' T
+    (3, 384, 2, 64),      # three blocks of 128
+    (2, 256, 1, 128),     # a head a lane block, one block of 256
+    (2, 768, 2, 128),     # three blocks of 256
+]
+
+
+@pytest.mark.parametrize("B,T,H,hd", SHAPES,
+                         ids=[f"B{b}-T{t}-H{h}-hd{d}" for b, t, h, d in SHAPES])
+def test_kernel_matches_xla_in_float32(B, T, H, hd):
+    """Forward and the three gradients, float32 end to end."""
+    assert F.kernel_takes(T, H, hd, jnp.float32)
+    qkv, w = _inputs(B, T, H, hd, jnp.float32, seed=T + hd)
+    got, g_got = _out_and_grad(lambda x: F.flash_qkv(x, H, True), qkv, w)
+    want, g_want = _out_and_grad(lambda x: _reference(x, H), qkv, w)
+    assert got.shape == (B, T, H * hd) and g_got.shape == qkv.shape
+    np.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-5)
+    D = H * hd
+    for i, name in enumerate(("dq", "dk", "dv")):
+        np.testing.assert_allclose(
+            g_got[:, :, i * D:(i + 1) * D], g_want[:, :, i * D:(i + 1) * D],
+            atol=5e-5, rtol=5e-5, err_msg=name)
+
+
+@pytest.mark.parametrize("B,T,H,hd", [(2, 1024, 2, 64), (2, 512, 1, 128)],
+                         ids=["hd64", "hd128"])
+def test_kernel_in_bfloat16_is_inside_the_smoke_tolerance(B, T, H, hd):
+    """bf16 operands, float32 accumulation and statistics: within
+    ``FLASH_TOL`` of the float32 ``attention_xla`` on the same bf16 values,
+    relative to the largest reference value, output and gradients."""
+    qkv, w = _inputs(B, T, H, hd, jnp.bfloat16, seed=7)
+    got, g_got = _out_and_grad(lambda x: F.flash_qkv(x, H, True), qkv, w)
+    want, g_want = _out_and_grad(
+        lambda x: _reference(x, H), qkv.astype(jnp.float32),
+        w.astype(jnp.float32))
+    assert got.dtype == jnp.bfloat16 and g_got.dtype == jnp.bfloat16
+    for a, b in ((got, want), (g_got, g_want)):
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        assert np.abs(a - b).max() <= FLASH_TOL * max(1.0, np.abs(b).max())
+
+
+def test_rows_see_nothing_above_the_diagonal():
+    """Poison every token after position p: rows up to p do not move."""
+    B, T, H, hd, p = 1, 512, 2, 64, 300
+    qkv, _ = _inputs(B, T, H, hd, jnp.float32, seed=3)
+    poisoned = qkv.at[:, p + 1:].set(1e4)
+    a = F.flash_qkv(qkv, H, True)
+    b = F.flash_qkv(poisoned, H, True)
+    np.testing.assert_array_equal(a[:, :p + 1], b[:, :p + 1])
+
+
+@pytest.mark.parametrize("T,H,hd,dtype,takes", [
+    (1024, 12, 64, jnp.bfloat16, True),     # the training cells
+    (2048, 32, 128, jnp.bfloat16, True),
+    (8192, 8, 128, jnp.float32, True),
+    (1024, 25, 64, jnp.bfloat16, False),    # GPT-2 XL: 12.5 lane blocks
+    (1024, 12, 80, jnp.bfloat16, False),    # a head the lanes do not hold
+    (1000, 12, 64, jnp.bfloat16, False),    # T not in whole blocks
+    (16384, 8, 128, jnp.bfloat16, False),   # operands past VMEM
+    (1024, 12, 64, jnp.float16, False),
+], ids=["cells", "T2048-hd128", "T8192-f32", "xl-25-heads", "hd80", "T1000",
+        "T16384", "f16"])
+def test_kernel_takes(T, H, hd, dtype, takes):
+    assert F.kernel_takes(T, H, hd, dtype) is takes
+
+
+@pytest.mark.parametrize("T,block", [(128, 128), (384, 128), (256, 256),
+                                     (768, 256), (1024, 512), (4096, 512)])
+def test_block_is_chosen_from_T(T, block):
+    assert F.block_for(T) == block
+
+
+# ------------------------------------------------- the token-major entry
+@pytest.fixture
+def spy(monkeypatch):
+    """``attention_qkv`` / ``attention`` with every implementation replaced
+    by a recorder (this pins DISPATCH; the arithmetic is pinned above) and
+    the resolution memo emptied."""
+    calls = []
+
+    def kernel(qkv, n_head, interpret=False):
+        calls.append(("kernel", qkv.shape, n_head))
+        return qkv[..., :qkv.shape[-1] // 3]
+
+    def flash(q, k, v, *, causal=True, block_q=0, block_kv=0,
+              block_q_bwd=0, block_kv_bwd=0):
+        calls.append(("flash", q.shape, (block_q, block_kv, block_q_bwd,
+                                         block_kv_bwd)))
+        return q
+
+    def xla(q, k, v, *, causal=True, score_dtype=None):
+        calls.append(("xla", q.shape))
+        return q
+
+    monkeypatch.setattr(F, "flash_qkv", kernel)
+    monkeypatch.setattr(A, "attention_flash", flash)
+    monkeypatch.setattr(A, "attention_xla", xla)
+    monkeypatch.setattr(A, "_RESOLVED", {})
+    monkeypatch.setattr(A, "_resolved_said", 0)
+    return calls
+
+
+def _trace_qkv(B, T, H, hd, dtype=jnp.bfloat16, **kw):
+    """Trace ``attention_qkv`` at a real shape without running it."""
+    x = jax.ShapeDtypeStruct((B, T, 3, H * hd), dtype)
+    return jax.eval_shape(lambda x: A.attention_qkv(x, H, **kw), x)
+
+
+@pytest.mark.parametrize("B,T,H,hd", [(20, 1024, 12, 64),   # cell 1
+                                      (4, 1024, 12, 64),    # cell 4
+                                      (1, 2048, 32, 128),
+                                      (2, 4096, 16, 64)],
+                         ids=["readme-20x8", "vote-4x2", "T2048-hd128",
+                              "T4096-hd64"])
+def test_auto_takes_the_kernel_on_a_tpu(spy, monkeypatch, B, T, H, hd):
+    """At the two training cells' shapes and wherever `auto` took the
+    library's flash before: the kernel, handed ``qkv`` as it lies."""
+    monkeypatch.setattr(A.jax, "default_backend", lambda: "tpu")
+    out = _trace_qkv(B, T, H, hd)
+    assert out.shape == (B, T, H * hd)
+    assert spy == [("kernel", (B, T, 3 * H * hd), H)]
+    (line,) = A.new_resolved_lines()
+    blk = F.block_for(T)
+    assert line == (f"[setup] attention: qkv auto -> pallas_flash_attn "
+                    f"(T {T}, head_dim {hd}, bfloat16, tiles {blk}x{blk})")
+    assert A.new_resolved_lines() == []       # said once
+
+
+AWAY = {  # why: backend, T, H, head_dim, the call's options, where it goes
+    "off-tpu": ("cpu", 1024, 12, 64, {}, "xla"),
+    "head_dim-80": ("tpu", 1024, 12, 80, {}, "xla"),
+    "xl-25-heads": ("tpu", 1024, 25, 64, {}, "xla"),
+    "T512": ("tpu", 512, 12, 64, {}, "xla"),
+    "T16384": ("tpu", 16384, 8, 128, {}, "flash"),
+    "pinned-tiles": ("tpu", 1024, 12, 64,
+                     {"block_q": 512, "block_kv": 1024}, "flash"),
+    "pinned-bwd-tiles": ("tpu", 1024, 12, 64,
+                         {"block_q_bwd": 256, "block_kv_bwd": 512}, "flash"),
+    "explicit-flash": ("tpu", 1024, 12, 64, {"impl": "flash"}, "flash"),
+    "explicit-xla": ("tpu", 1024, 12, 64, {"impl": "xla"}, "xla"),
+}
+
+
+@pytest.mark.parametrize("why", list(AWAY))
+def test_auto_resolves_away_from_the_kernel(spy, monkeypatch, why):
+    """Off a TPU, at a shape the kernel does not take, below T = 1024 and
+    under caller-pinned tiles or an explicit impl: the head-major paths,
+    as before (pinned tiles reach the library kernel intact)."""
+    backend, T, H, hd, kw, first = AWAY[why]
+    monkeypatch.setattr(A.jax, "default_backend", lambda: backend)
+    out = _trace_qkv(2, T, H, hd, **kw)
+    assert out.shape == (2, T, H * hd)
+    assert [c[0] for c in spy] == [first]
+    assert spy[0][1] == (2, H, T, hd)
+    if first == "flash":
+        assert spy[0][2] == tuple(kw.get(k, 0) for k in (
+            "block_q", "block_kv", "block_q_bwd", "block_kv_bwd"))
+
+
+def test_dropout_keeps_the_xla_scores(spy, monkeypatch):
+    """``models/gpt2._attention`` under attention dropout never reaches the
+    dispatcher (materialized scores); without dropout, same shapes, it
+    hands the projection's output to the kernel."""
+    from distributed_lion_tpu.models import gpt2
+
+    monkeypatch.setattr(A.jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(gpt2, "_proj", lambda x, w: x)
+    cfg = gpt2.GPT2Config(vocab_size=64, n_layer=1, n_head=2, d_model=128,
+                          n_ctx=1024, dropout=0.1)
+    p = jax.eval_shape(lambda: gpt2.gpt2_init(jax.random.key(0), cfg)
+                       )["blocks"][0]["attn"]
+    x = jax.ShapeDtypeStruct((2, 1024, 128), jnp.float32)
+    key = jax.eval_shape(lambda: jax.random.key(0))
+    jax.eval_shape(lambda x, p, k: gpt2._attention(x, p, cfg, k), x, p, key)
+    assert spy == []
+    jax.eval_shape(lambda x, p: gpt2._attention(x, p, cfg, None), x, p)
+    assert spy == [("kernel", (2, 1024, 3 * 128), 2)]
+
+
+def test_resolution_reaches_the_journal(spy, monkeypatch, tmp_path):
+    from distributed_lion_tpu.train import journal
+
+    monkeypatch.setattr(A.jax, "default_backend", lambda: "tpu")
+    j = journal.Journal(str(tmp_path))
+    journal.install(j)
+    try:
+        _trace_qkv(4, 1024, 12, 64)
+        _trace_qkv(4, 1024, 12, 64)          # same shape: recorded once
+        events = [r for r in j.records() if r.get("name") == "attn_resolved"]
+    finally:
+        journal.uninstall(j)
+        j.close()
+    assert len(events) == 1
+    assert {k: events[0][k] for k in ("entry", "impl", "T", "head_dim",
+                                      "tiles")} == {
+        "entry": "qkv", "impl": "pallas_flash_attn", "T": 1024,
+        "head_dim": 64, "tiles": "512x512"}
+
+
+@pytest.mark.parametrize("layout", ["fused-4d", "flat-3d"])
+def test_token_major_entry_equals_head_major_entry(layout):
+    """``attention_qkv`` (here: off a TPU, so through the split) against
+    ``attention`` on the same values laid out head-major."""
+    B, T, H, hd = 2, 64, 2, 16
+    qkv, _ = _inputs(B, T, H, hd, jnp.float32, seed=5)
+    x = qkv.reshape(B, T, 3, H * hd) if layout == "fused-4d" else qkv
+    got = A.attention_qkv(x, H)
+    D = H * hd
+    q, k, v = (_heads(qkv[:, :, i * D:(i + 1) * D], H) for i in range(3))
+    want = A.attention(q, k, v).transpose(0, 2, 1, 3).reshape(B, T, D)
+    np.testing.assert_array_equal(got, want)
+
+
+def test_kernel_entry_equals_head_major_entry(monkeypatch):
+    """The same comparison with the kernel behind the token-major entry (a
+    TPU's resolution, interpret mode), output and gradient."""
+    real = F.flash_qkv
+    monkeypatch.setattr(A.jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(F, "flash_qkv",
+                        lambda qkv, n_head: real(qkv, n_head, True))
+    B, T, H, hd = 1, 1024, 2, 64
+    qkv, w = _inputs(B, T, H, hd, jnp.float32, seed=11)
+    got, g_got = _out_and_grad(
+        lambda x: A.attention_qkv(x.reshape(B, T, 3, H * hd), H), qkv, w)
+    monkeypatch.setattr(A.jax, "default_backend", lambda: "cpu")
+    D = H * hd
+
+    def head_major(x):
+        q, k, v = (_heads(x[:, :, i * D:(i + 1) * D], H) for i in range(3))
+        return A.attention(q, k, v).transpose(0, 2, 1, 3).reshape(B, T, D)
+
+    want, g_want = _out_and_grad(head_major, qkv, w)
+    np.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-5)
+    np.testing.assert_allclose(g_got, g_want, atol=5e-5, rtol=5e-5)
+
+
+def test_gpt2_through_the_kernel_equals_the_xla_path(monkeypatch):
+    """One GPT-2 block's loss and parameter gradients at T = 1024 with the
+    kernel in the step (a TPU's resolution, interpret mode) against the
+    same block off a TPU (XLA scores)."""
+    from distributed_lion_tpu.models import gpt2
+
+    real = F.flash_qkv
+    cfg = gpt2.GPT2Config(vocab_size=64, n_layer=1, n_head=2, d_model=128,
+                          n_ctx=1024, dropout=0.0, remat=True,
+                          compute_dtype=jnp.float32)
+    params = gpt2.gpt2_init(jax.random.key(0), cfg)
+    tokens = jax.random.randint(jax.random.key(1), (1, 1024), 0, 64)
+
+    def loss(params):
+        logits = gpt2.gpt2_apply(params, tokens, cfg)
+        return jnp.mean(jax.nn.logsumexp(logits, axis=-1) - logits[..., 0])
+
+    want, g_want = jax.value_and_grad(loss)(params)
+    seen = []
+
+    def kernel(qkv, n_head):
+        seen.append(qkv.shape)
+        return real(qkv, n_head, True)
+
+    monkeypatch.setattr(A.jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(F, "flash_qkv", kernel)
+    jax.clear_caches()    # the remat block's trace is memoised by its avals
+    got, g_got = jax.value_and_grad(loss)(params)
+    jax.clear_caches()
+    assert seen and all(s == (1, 1024, 3 * 128) for s in seen)
+    np.testing.assert_allclose(got, want, rtol=1e-5)
+    for a, b in zip(jax.tree.leaves(g_got), jax.tree.leaves(g_want)):
+        np.testing.assert_allclose(a, b, atol=1e-5, rtol=1e-4)
