@@ -2,7 +2,7 @@
 
     python -m distributed_lion_tpu.cli.run_analyze runs/journal/journal
     python -m distributed_lion_tpu.cli.run_analyze runs/journal \\
-        --baseline scripts/last_tpu_measurement.json --json-out report.json
+        --baseline baseline.json --json-out report.json
 
 Consumes the JSONL journals ``train/journal.py`` records (one file per
 rank, plus rotations), merges multi-host journals onto one wall timeline
@@ -26,9 +26,9 @@ plus ``other`` (named spans outside the taxonomy, e.g. ``eval``) and
 (check_evidence's ``journal`` stage requires ≥ 0.95 on a real leg). The
 report also ranks the top stall sources by full span name, reports
 cross-host step-skew percentiles from the per-rank ``step_log`` events,
-and — given ``--baseline`` — diffs the bucket fractions against a
-``BENCH_*.json`` / ``last_tpu_measurement.json`` row's
-``journal_attribution`` summary to NAME the regressing bucket.
+and — given ``--baseline`` — diffs the bucket fractions against the
+``journal_attribution`` summary a JSON file holds (an earlier report's
+``attribution`` saved under that key) to NAME the regressing bucket.
 
 ``--serve`` switches to the serve-side view (ISSUE 17): per-request
 lifecycle waterfalls (queue → prefill → decode, from the engine's
@@ -403,10 +403,8 @@ def step_skew(events: list) -> Optional[dict]:
 
 # ------------------------------------------------------------- baseline diff
 def load_baseline_attribution(path: str) -> Optional[dict]:
-    """The ``journal_attribution`` summary from a bench artifact — a
-    ``BENCH_*.json`` capture (summary under ``parsed``) or a bare bench row
-    (``last_tpu_measurement.json``). None when the artifact predates the
-    journal (bench rows only carry the summary from ISSUE 7 on)."""
+    """The ``journal_attribution`` summary a JSON file holds, at its top
+    level or under ``parsed``. None when it holds none."""
     try:
         with open(path) as f:
             doc = json.load(f)
@@ -719,8 +717,8 @@ def main(argv: Optional[list] = None) -> int:
     ap.add_argument("--rank", type=int, default=None,
                     help="attribute this rank (default: lowest present)")
     ap.add_argument("--baseline", default=None,
-                    help="BENCH_*.json / last_tpu_measurement.json to diff "
-                         "bucket fractions against")
+                    help="JSON file with a journal_attribution summary "
+                         "to diff bucket fractions against")
     ap.add_argument("--json-out", default=None,
                     help="also write the full report as strict JSON")
     ap.add_argument("--serve", action="store_true",

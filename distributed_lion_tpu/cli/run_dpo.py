@@ -37,7 +37,7 @@ class DPOArguments:
     num_train_samples: int = 512
     size_valid_set: int = 64
     sanity_check: bool = False
-    attn_impl: str = "auto"  # ops.attention: auto | xla | xla_bf16 | flash | splash
+    attn_impl: str = "auto"  # ops.attention: auto | xla
     seq_impl: str = "ring"   # under --seq_parallel: ring | ulysses
     quant_ref: str = "none"        # none | int8 | nf4 — frozen ref model
     quant_block: Optional[int] = None  # quant block size override; shrink so

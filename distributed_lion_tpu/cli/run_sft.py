@@ -48,7 +48,7 @@ class SFTArguments:
     packing: bool = True
     group_by_length: bool = False
     gradient_checkpointing: bool = False
-    attn_impl: str = "auto"  # ops.attention: auto | xla | xla_bf16 | flash | splash
+    attn_impl: str = "auto"  # ops.attention: auto | xla
     seq_impl: str = "ring"   # under --seq_parallel: ring | ulysses
     tokenizer_name: Optional[str] = None
     adapter_path: Optional[str] = None  # start from a PEFT adapter

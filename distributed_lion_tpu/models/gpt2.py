@@ -42,11 +42,7 @@ class GPT2Config:
     d_model: int = 768
     n_ctx: int = 1024
     dropout: float = 0.0
-    attn_impl: str = "auto"  # ops.attention: auto | xla | xla_bf16 | flash | splash
-    flash_block_q: int = 0   # flash kernel tile overrides (0 = defaults);
-    flash_block_kv: int = 0  # see ops.attention.attention_flash
-    flash_block_q_bwd: int = 0   # backward-pass tile overrides (0 = inherit
-    flash_block_kv_bwd: int = 0  # the fwd tiles); spec impl@FWD@BWD
+    attn_impl: str = "auto"  # ops.attention: auto | xla
     seq_impl: str = "ring"   # sequence-parallel attention: 'ring' (k/v
     # blocks rotate over the seq axis — O(T/S) memory, any head count) or
     # 'ulysses' (all_to_all to head sharding — needs n_head % sp == 0,
@@ -268,11 +264,7 @@ def _attention(x, p, cfg: GPT2Config, key, tp_axis=None, seq_axis=None):
         # the projection's output as it lies: on a TPU `auto` hands it to
         # the token-major kernel (ops/pallas_flash_attn) and gets [B, T, D]
         # back; no head-major copy exists on that path
-        out = attention_qkv(qkv, H, impl=cfg.attn_impl,
-                            block_q=cfg.flash_block_q,
-                            block_kv=cfg.flash_block_kv,
-                            block_q_bwd=cfg.flash_block_q_bwd,
-                            block_kv_bwd=cfg.flash_block_kv_bwd)
+        out = attention_qkv(qkv, H, impl=cfg.attn_impl)
     else:
         q, k, v = (x.reshape(B, T, H, hd).transpose(0, 2, 1, 3)
                    for x in jnp.split(qkv, 3, axis=2))

@@ -41,7 +41,7 @@ class LlamaConfig:
     n_ctx: int = 4096
     rope_theta: float = 10000.0
     rms_eps: float = 1e-5
-    attn_impl: str = "auto"  # ops.attention: auto | xla | xla_bf16 | flash | splash
+    attn_impl: str = "auto"  # ops.attention: auto | xla
     seq_impl: str = "ring"   # sequence-parallel attention: ring | ulysses
     remat: bool = True  # per-block jax.checkpoint; off when activations fit
     remat_policy: str = "full"  # 'full' | 'dots' (keep matmul outputs,

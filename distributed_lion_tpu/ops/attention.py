@@ -9,49 +9,26 @@ Two entries, one for each layout a caller holds:
   the same. A family whose q and k are built separately (RoPE, fewer kv
   heads: ``models/llama``) calls this one.
 
-Implementations (``impl``):
+``impl`` takes two names. ``xla`` is the materialized-scores reference
+(einsum → masked f32 softmax → einsum) that every kernel is tested against.
+``auto`` resolves from what the call shows, with two outcomes a platform:
 
-- ``xla``   — materialized-scores reference: einsum → masked f32 softmax →
-  einsum. What every kernel is tested against.
-- ``xla_bf16`` — ``xla`` with the [B,H,T,T] scores stored in bf16 (softmax
-  still f32 internally): halves the largest attention intermediate's HBM
-  round-trip at ~1e-2 relative error on probs. Opt-in throughput config.
-- ``flash`` — jax's bundled Pallas kernel
-  (``pallas.ops.tpu.flash_attention``), head-major, with caller-pinned
-  tiles (``block_q`` ...). The tuner (``ops/autotune``) and
-  ``chip_smoke.py`` call it by name.
-- ``splash`` — the bundled splash kernel family (sparse-mask blocking),
-  head-major, head_dim padded to 128.
-- the repo's own training kernel (``ops/pallas_flash_attn``): token-major
-  operands, one float32 a row as residual, a fused backward. Not an
-  ``impl`` name: it is what ``auto`` resolves to, below.
-
-``auto`` resolves from what the call shows, and takes no option to get
-there:
-
-- :func:`attention_qkv` on a TPU, no caller-pinned tiles, a shape the
-  kernel takes as it lies (``pallas_flash_attn.kernel_takes``: head_dim 64
-  or 128, whole 128-lane blocks, T a multiple of 128 up to 8,192) and
-  T >= 1024 → the repo's kernel, reading ``qkv`` in place. Measured on the
-  chip at the training cells' shape (T = 1024, head_dim 64: PERF.md,
-  PR 27); the other shapes follow by what the kernel does not do (no
-  [B,H,T,T] scores, no head-major copy), and ``chip_smoke.py`` checks one
-  of them against ``xla``.
-- every other :func:`attention_qkv` call splits ``qkv`` head-major and
-  goes through :func:`attention`.
-- :func:`attention` on a TPU, in priority order: caller-pinned tiles →
-  ``flash`` with those tiles at any shape (an explicit ``auto@BQxBKV`` spec
-  is an operator decision and stays sweepable); an autotune-cache hit for
-  this device_kind × (T, head_dim) × dtype (``ops/autotune``, knob
-  ``flash_tiles``: the LIBRARY kernel's tiles; ``scripts/tuning_cache.json``
-  holds none for a TPU) → ``flash`` with them; T >= 2048 → ``flash`` at the
-  library's default tiles (its memory regime); ``xla`` everywhere else.
-  Such an entry never outranks the repo's kernel: :func:`attention_qkv`
-  takes that before it gets here (a tuned tile pair, 512x1024, is what made
-  the library's backward write a 1 GB ``di`` buffer a layer: PERF.md,
-  PR 27).
-- off a TPU: always ``xla`` (pinned tiles are dropped: Pallas kernels are
-  TPU-only).
+- :func:`attention_qkv` on a TPU where :func:`qkv_kernel_applies` (a shape
+  the kernel takes as it lies, ``pallas_flash_attn.kernel_takes``:
+  head_dim 64 or 128, whole 128-lane blocks, T a multiple of 128 up to
+  8,192; and T >= 1024) → the repo's training kernel
+  (``ops/pallas_flash_attn``), reading ``qkv`` in place: token-major
+  operands, one float32 a row as residual, a fused backward. Measured on
+  the chip at the training cells' shape (T = 1024, head_dim 64: PERF.md,
+  PR 27); ``chip_smoke.py`` checks another against ``xla``. Every other
+  :func:`attention_qkv` call splits ``qkv`` head-major for
+  :func:`attention`.
+- :func:`attention` on a TPU and T >= 2048 → jax's bundled flash kernel
+  (``pallas.ops.tpu.flash_attention``) at its own default tiles: the one
+  path that runs ``models/llama`` at long context on the chip without
+  ``[B, H, T, T]`` float32 scores. Everywhere else :func:`attention_xla`.
+- off a TPU both entries always end in :func:`attention_xla` (Pallas
+  kernels are TPU-only).
 
 What ``auto`` resolved to is recorded once a shape at trace time
 (:func:`_note_resolved`): an ``attn_resolved`` event in the run journal and
@@ -68,111 +45,31 @@ import jax
 import jax.numpy as jnp
 
 
-def attention_xla(q, k, v, *, causal: bool = True,
-                  score_dtype=jnp.float32):
-    """Materialized-scores attention. ``score_dtype=jnp.bfloat16`` is the
-    ``xla_bf16`` impl: the [B, H, T, T] scores tensor — the largest
-    attention intermediate (201 MB/layer at mb4 T=1024 in f32) and pure HBM
-    traffic between the two matmuls — is stored in bf16, halving its
-    round-trip. The softmax always runs in f32 (the upcast fuses into the
-    softmax elementwise chain, costing registers, not HBM), so only the one
-    rounding of the scores differs; max-subtraction bounds the exponent so
-    bf16's 8 mantissa bits cost ~1e-2 relative on probs — an opt-in
-    throughput config, not the parity default."""
+def attention_xla(q, k, v, *, causal: bool = True):
+    """Materialized-scores attention: the reference every kernel is tested
+    against. Scores and softmax are float32."""
     T = q.shape[2]
     scale = 1.0 / math.sqrt(q.shape[-1])
-    # accumulate in f32 regardless of score_dtype; only the STORED scores
-    # are rounded (the cast fuses into the matmul/mask epilogue, so the
-    # HBM write is score_dtype-wide) — rounding is the only delta vs f32
     scores = jnp.einsum("bhqd,bhkd->bhqk", q, k,
                         preferred_element_type=jnp.float32)
-    scores = (scores * scale).astype(score_dtype)
+    scores = scores * scale
     if causal:
         mask = jnp.tril(jnp.ones((T, T), bool))
-        scores = jnp.where(mask, scores, jnp.asarray(-1e30, score_dtype))
-    probs = jax.nn.softmax(scores.astype(jnp.float32), axis=-1).astype(q.dtype)
+        scores = jnp.where(mask, scores, jnp.asarray(-1e30, jnp.float32))
+    probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
     out = jnp.einsum("bhqk,bhkd->bhqd", probs, v, preferred_element_type=jnp.float32)
     return out.astype(q.dtype)
 
 
-def attention_flash(q, k, v, *, causal: bool = True,
-                    block_q: int = 0, block_kv: int = 0,
-                    block_q_bwd: int = 0, block_kv_bwd: int = 0):
-    """Pallas TPU flash attention. ``block_q``/``block_kv`` override the
-    kernel's VMEM tile sizes (0 = library defaults); exposed because the
-    default blocking lost to XLA at T=1024 on v5e (scripts/SWEEP_v5e.md) and
-    tile shape is the first knob to turn. ``block_q_bwd``/``block_kv_bwd``
-    tune the dq/dkv backward passes independently (0 = inherit fwd) — the
-    backward is ~2× the fwd FLOPs with different operand shapes, so its
-    optimum tile need not match the forward's."""
+def attention_flash(q, k, v, *, causal: bool = True):
+    """jax's bundled Pallas TPU flash attention at its default tiles."""
     from jax.experimental.pallas.ops.tpu.flash_attention import (
-        BlockSizes,
         flash_attention,
     )
 
-    T = q.shape[2]
-    bs = None
-    if block_q or block_kv or block_q_bwd or block_kv_bwd:
-        bq = min(block_q or 512, T)
-        bkv = min(block_kv or 512, T)
-        bqb = min(block_q_bwd or bq, T)
-        bkvb = min(block_kv_bwd or bkv, T)
-        bs = BlockSizes(
-            block_q=bq, block_k_major=bkv, block_k=bkv, block_b=1,
-            block_q_major_dkv=bqb, block_k_major_dkv=bkvb, block_k_dkv=bkvb,
-            block_q_dkv=bqb, block_k_major_dq=bkvb, block_k_dq=bkvb,
-            block_q_dq=bqb,
-        )
     return flash_attention(
         q, k, v, causal=causal, sm_scale=1.0 / math.sqrt(q.shape[-1]),
-        block_sizes=bs,
     ).astype(q.dtype)
-
-
-def attention_splash(q, k, v, *, causal: bool = True,
-                     block_q: int = 0, block_kv: int = 0,
-                     interpret: bool = False):
-    """Splash attention (the newer Pallas TPU kernel family): sparse-mask
-    blocking, fused bwd option — typically faster than the older flash
-    kernel at moderate T. Takes the same [B, H, T, hd] as the others; the
-    kernel is per-(heads, T, hd) so batch rides a vmap. q is pre-scaled
-    (splash applies no sm_scale)."""
-    from jax.experimental.pallas.ops.tpu.splash_attention import (
-        splash_attention_kernel as sk,
-        splash_attention_mask as ml,
-    )
-
-    B, H, T, hd = q.shape
-    # the installed splash kernel requires head_dim % 128 == 0 (lane width);
-    # GPT-2's hd=64 (and any other non-multiple) is padded up with zero
-    # columns and the output sliced back. Exact, not approximate: q·k over
-    # the zero columns adds nothing to any score, and the zero v columns
-    # only produce output columns that are sliced away. The pad costs real
-    # MXU FLOPs (hd 64 → 128 doubles the qk/pv inner dim), which is why
-    # `auto` never dispatches here — explicit splash requests and the
-    # autotune tuner (which times the kernel PADDED, so its numbers stay
-    # honest) accept the cost knowingly.
-    hd_pad = -(-hd // 128) * 128
-    if hd_pad != hd:
-        pad = [(0, 0)] * 3 + [(0, hd_pad - hd)]
-        q, k, v = (jnp.pad(x, pad) for x in (q, k, v))
-    one = ml.CausalMask((T, T)) if causal else ml.FullMask((T, T))
-    mask = ml.MultiHeadMask([one for _ in range(H)])
-    bs = None
-    if block_q or block_kv:
-        bq = min(block_q or 512, T)
-        bkv = min(block_kv or 512, T)
-        bs = sk.BlockSizes(block_q=bq, block_kv=bkv,
-                           block_q_dkv=bq, block_kv_dkv=bkv,
-                           block_q_dq=bq, block_kv_dq=bkv)
-    kernel = sk.make_splash_mha_single_device(mask=mask, block_sizes=bs,
-                                              interpret=interpret)
-    # scale by the REAL head_dim — the zero pad must not change the softmax
-    qs = (q * (1.0 / math.sqrt(hd))).astype(q.dtype)
-    out = jax.vmap(kernel)(qs, k, v)
-    if hd_pad != hd:
-        out = out[..., :hd]
-    return out.astype(q.dtype)
 
 
 # ------------------------------------------------------------- paged decode
@@ -428,24 +325,6 @@ def chunked_causal_attention(q, k, v, pos, *, scale: float,
     return out.transpose(1, 2, 0, 3, 4).reshape(B, H, S, -1)
 
 
-def parse_attn_spec(spec: str) -> tuple[str, int, int, int, int]:
-    """Parse an attention spec ``impl[@BQxBKV[@BQBxBKVB]]`` into
-    ``(impl, block_q, block_kv, block_q_bwd, block_kv_bwd)`` — e.g.
-    ``"flash@512x1024"`` → ``("flash", 512, 1024, 0, 0)`` and
-    ``"flash@512x1024@256x512"`` tunes the BACKWARD tiles independently
-    (the bwd passes are ~2× the fwd FLOPs with different operand shapes,
-    so their optimum need not match; 0 = inherit the fwd tiles). No ``@``
-    → all 0 (kernel defaults). The one grammar shared by bench.py's
-    BENCH_ATTN env knob and scripts/bench_sweep.py's config specs."""
-    if "@" not in spec:
-        return spec, 0, 0, 0, 0
-    impl, _, blocks = spec.partition("@")
-    fwd, _, bwd = blocks.partition("@")
-    bq, bkv = (int(x) for x in fwd.split("x"))
-    bqb, bkvb = (int(x) for x in bwd.split("x")) if bwd else (0, 0)
-    return impl, bq, bkv, bqb, bkvb
-
-
 # What `auto` resolved to, by (entry, T, head_dim, dtype): recorded once at
 # trace time, said by whoever drives the program (Trainer.train prints
 # :func:`new_resolved_lines` after a dispatch that traced).
@@ -479,19 +358,16 @@ def new_resolved_lines() -> list:
             f"tiles {f['tiles']})" for f in fresh]
 
 
-def qkv_kernel_applies(T: int, n_head: int, head_dim: int, dtype,
-                       pinned: bool = False) -> bool:
+def qkv_kernel_applies(T: int, n_head: int, head_dim: int, dtype) -> bool:
     """True when :func:`attention_qkv` ``auto`` takes the repo's training
     kernel for such a call (the rule is in the module doc)."""
     from distributed_lion_tpu.ops.pallas_flash_attn import kernel_takes
 
-    return (not pinned and jax.default_backend() == "tpu" and T >= 1024
+    return (jax.default_backend() == "tpu" and T >= 1024
             and kernel_takes(T, n_head, head_dim, dtype))
 
 
-def attention_qkv(qkv, n_head: int, *, impl: str = "auto",
-                  block_q: int = 0, block_kv: int = 0,
-                  block_q_bwd: int = 0, block_kv_bwd: int = 0):
+def attention_qkv(qkv, n_head: int, *, impl: str = "auto"):
     """Causal attention of a fused projection's output: ``qkv`` is
     ``[B, T, 3, D]`` or ``[B, T, 3 * D]`` (``D = n_head * head_dim``),
     the result ``[B, T, D]`` in its dtype. ``auto`` hands ``qkv`` to the
@@ -500,9 +376,7 @@ def attention_qkv(qkv, n_head: int, *, impl: str = "auto",
     B, T = qkv.shape[:2]
     D = math.prod(qkv.shape[2:]) // 3
     hd = D // n_head
-    pinned = bool(block_q or block_kv or block_q_bwd or block_kv_bwd)
-    if impl == "auto" and qkv_kernel_applies(T, n_head, hd, qkv.dtype,
-                                             pinned):
+    if impl == "auto" and qkv_kernel_applies(T, n_head, hd, qkv.dtype):
         from distributed_lion_tpu.ops.pallas_flash_attn import (
             block_for,
             flash_qkv,
@@ -514,84 +388,22 @@ def attention_qkv(qkv, n_head: int, *, impl: str = "auto",
         return flash_qkv(qkv.reshape(B, T, 3 * D), n_head)
     q, k, v = (x.reshape(B, T, n_head, hd).transpose(0, 2, 1, 3)
                for x in jnp.split(qkv.reshape(B, T, 3, D), 3, axis=2))
-    out = attention(q, k, v, impl=impl, block_q=block_q, block_kv=block_kv,
-                    block_q_bwd=block_q_bwd, block_kv_bwd=block_kv_bwd)
+    out = attention(q, k, v, impl=impl)
     return out.transpose(0, 2, 1, 3).reshape(B, T, D)
 
 
-def attention(q, k, v, *, causal: bool = True, impl: str = "auto",
-              block_q: int = 0, block_kv: int = 0,
-              block_q_bwd: int = 0, block_kv_bwd: int = 0):
+def attention(q, k, v, *, causal: bool = True, impl: str = "auto"):
+    """Attention of head-major q, k, v ``[B, H, T, head_dim]``. ``auto``
+    takes the library's flash kernel on a TPU at T >= 2048 (its memory
+    regime) and :func:`attention_xla` everywhere else."""
     if impl == "auto":
-        on_tpu = jax.default_backend() == "tpu"
         T = q.shape[2]
-        tuned = None
-        if on_tpu and not (block_q or block_kv or block_q_bwd or block_kv_bwd):
-            # no caller pins → consult the autotune cache (ops/autotune,
-            # knob 'flash_tiles'): a measured winner of the LIBRARY kernel
-            # for THIS device_kind × (T, head_dim) × dtype outranks the
-            # heuristics below — but never an explicit pin (the elif), which
-            # is how sweeps measure non-cached tiles, and never the repo's
-            # own kernel, which attention_qkv takes before it gets here.
-            # Device-keyed, so a cache produced elsewhere never leaks here; a
-            # corrupt cache is loud and reads as a miss. The lookup is
-            # host-side at trace time — one file read per process
-            # (module-level memo in autotune).
-            from distributed_lion_tpu.ops.autotune import (
-                attn_shape_key,
-                lookup,
-            )
-
-            tuned = lookup("flash_tiles", attn_shape_key(T, q.shape[3]),
-                           jnp.dtype(q.dtype).name)
-        if tuned:
-            impl = "flash"
-            block_q = int(tuned.get("block_q", 0))
-            block_kv = int(tuned.get("block_kv", 0))
-            block_q_bwd = int(tuned.get("block_q_bwd", 0))
-            block_kv_bwd = int(tuned.get("block_kv_bwd", 0))
-        elif on_tpu and (block_q or block_kv or block_q_bwd or block_kv_bwd):
-            # caller-pinned tiles are a flash knob: honor them at ANY shape
-            # rather than silently running untiled xla (a config like
-            # auto@256x512 would otherwise report numbers and tune nothing
-            # — same trap the bwd-tile guard below raises for). Backward-only
-            # pins (auto@@BQBxBKVB-style resolved specs) count too: falling
-            # through to xla would hit that guard's ValueError instead of
-            # honoring the tiles (advisor r4)
-            impl = "flash"
-        elif on_tpu and T >= 2048:
-            impl = "flash"
-        else:
-            impl = "xla"
-            # auto resolved AWAY from flash (no TPU backend, or a shape
-            # below the library kernel's regime): pinned tiles
-            # — bwd like fwd — are flash knobs with nothing left to tune.
-            # Drop them instead of tripping the explicit-impl guard below:
-            # an auto@...@BQBxBKVB spec must degrade off-TPU exactly like
-            # auto@... does, not raise the flash-knob ValueError that
-            # exists for EXPLICIT xla/splash requests
-            block_q_bwd = block_kv_bwd = 0
-        _note_resolved(
-            "head-major", impl, T, q.shape[3], q.dtype,
-            f"{block_q}x{block_kv}@{block_q_bwd}x{block_kv_bwd}"
-            if impl == "flash" else "-")
-    if impl == "flash":
-        return attention_flash(q, k, v, causal=causal,
-                               block_q=block_q, block_kv=block_kv,
-                               block_q_bwd=block_q_bwd,
-                               block_kv_bwd=block_kv_bwd)
-    if block_q_bwd or block_kv_bwd:
-        # fail loudly: a sweep config like splash@128x256@64x128 would
-        # otherwise run, report numbers, and silently tune nothing
-        raise ValueError(
-            f"backward-tile overrides (@BQBxBKVB) are a flash-kernel knob; "
-            f"impl {impl!r} does not consume them")
-    if impl == "splash":
-        return attention_splash(q, k, v, causal=causal,
-                                block_q=block_q, block_kv=block_kv)
+        flash = jax.default_backend() == "tpu" and T >= 2048
+        _note_resolved("head-major", "flash" if flash else "xla", T,
+                       q.shape[3], q.dtype, "default" if flash else "-")
+        if flash:
+            return attention_flash(q, k, v, causal=causal)
+        impl = "xla"
     if impl == "xla":
         return attention_xla(q, k, v, causal=causal)
-    if impl == "xla_bf16":
-        return attention_xla(q, k, v, causal=causal,
-                             score_dtype=jnp.bfloat16)
     raise ValueError(f"unknown attention impl {impl!r}")

@@ -393,8 +393,8 @@ def wire_bytes_per_param(num_params: int, world_size: int, wire: str,
     # collective with bucket k−1's fused apply, so every bucket AFTER the
     # first can hide behind compute — the fraction of wire bytes eligible
     # for overlap is buckets[1:]'s share. 0.0 for the monolithic vote and
-    # at world=1 (no wire to hide). The MEASURED counterpart lives in
-    # bench.py's overlap-ablation rows (comm_overlap_frac).
+    # at world=1 (no wire to hide). The MEASURED counterpart is the
+    # benchmark's vote_exposed_ms.train4 (the wire time no compute covers).
     overlappable = (sum(b for b, _ in per_bucket[1:]) / ours
                     if ours and world_size > 1 else 0.0)
     if kind == "hier":

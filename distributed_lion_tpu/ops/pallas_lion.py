@@ -45,10 +45,9 @@ from jax.experimental.pallas import tpu as pltpu
 LANES = 128
 ROW_BLOCK = 512  # default rows per grid step → (512, 128) f32 blocks =
 # 256 KiB. Every kernel below takes a ``row_block`` override (0 = this
-# default) threaded from the autotune cache (ops/autotune, knob
-# 'lion_row_block') — tile geometry is a measured perf knob, never a
-# numerics knob: outputs are bit-identical at any row_block (pinned by
-# tests/test_autotune.py).
+# default): how a test of a few hundred coordinates drives a multi-step
+# grid. Tile geometry is never a numerics knob: outputs are bit-identical
+# at any row_block (pinned by tests/test_pallas_lion.py).
 MIN_ROWS = 32    # min row granularity: covers the (8,128) f32, (16,128)
 # bf16 and (32,128) int8 native tile shapes, so small bucket windows
 # compile on hardware without padding all the way to a full ROW_BLOCK

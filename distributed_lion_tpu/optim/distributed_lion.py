@@ -218,11 +218,10 @@ def distributed_lion(
             The Pallas path covers the deterministic mode with
             dtype-uniform pytrees; other cases fall back to XLA.
         row_block: Pallas kernel tile override (rows per grid step,
-            multiple of 32; 0 = pallas_lion.ROW_BLOCK). A pure tiling
-            knob resolved from the autotune cache by the Trainer
-            (ops/autotune, knob 'lion_row_block'): params/momentum/
-            elections are bit-identical at any value
-            (tests/test_autotune.py), only VMEM residency and grid
+            multiple of 32; 0 = pallas_lion.ROW_BLOCK, what the
+            Trainer always runs). A pure tiling knob for tests:
+            params/momentum/elections are bit-identical at any value
+            (tests/test_pallas_lion.py), only VMEM residency and grid
             geometry change.
         telemetry: True → ``step`` returns a third value, the per-step
             vote-health *frame* (train.telemetry: margin bincount over the

@@ -157,18 +157,12 @@ class TrainConfig:
     # LionState); needs MoE blocks; checkpoints carry the ring and a depth
     # toggle on resume errors loudly, like --dcn_pipeline_depth.
     kernel: str = "auto"  # auto | pallas | xla (ops/pallas_lion fused path)
-    row_block: int = 0  # Pallas lion kernel tile rows (multiple of 32).
-    # 0 = auto: the Trainer consults the device-keyed autotune cache
-    # (ops/autotune, knob 'lion_row_block', cli/run_tune) when the Pallas
-    # path is live on TPU, else pallas_lion.ROW_BLOCK. Pure tiling — the
-    # elections/params are bit-identical at any value
-    # (tests/test_autotune.py); only VMEM residency changes.
     remat_policy: str = dataclasses.field(
         default="", metadata={"cli": False})  # '' = honor the model
     # config's own remat/remat_policy; 'full' | 'dots' overrides it at
     # Trainer build. Programmatic only (no CLI flag — run_clm's
     # model-level --remat_policy drives the model config directly; this
-    # field is the override bench.py and tests hand the Trainer builders).
+    # field is the override tests hand the Trainer builders).
     # (models/gpt2._remat_policy: 'dots' keeps matmul outputs and
     # recomputes elementwise — the cheaper backward the sweep's dots leg
     # measures). A perf knob under the vote, not a semantics knob: at f32
@@ -506,59 +500,13 @@ def resolve_auto_comm(cfg: TrainConfig, mesh, n_params: int,
         # the per-step ballot slice is big enough that each of 4 buckets
         # still amortizes collective launch latency. Elections are
         # bit-identical at any B, so auto never changes the trajectory —
-        # only whether the wire can hide behind the fused apply. A
-        # device-keyed autotune measurement for THIS ballot size
-        # (ops/autotune knob 'vote_buckets', key dtype int8 — the wire
-        # payload) outranks the heuristic; the heuristic stays the miss
-        # path.
+        # only whether the wire can hide behind the fused apply.
         n_voted = (n_params if ve <= 1
                    else min(n_params, vote_chunk_elems(n_params, ve)))
-        tuned_vb = None
-        if cfg.lion and world > 1:
-            from distributed_lion_tpu.ops.autotune import lookup
-
-            v = lookup("vote_buckets", f"N{n_voted}", "int8") or {}
-            # .get, not [..]: the schema admits any {str:int} value, and a
-            # mistyped operator-written entry must degrade to the
-            # heuristic (the autotune failure philosophy), never crash
-            # trainer construction
-            if isinstance(v.get("vote_buckets"), int):
-                tuned_vb = v["vote_buckets"]
-        if tuned_vb:
-            vb = tuned_vb
-        else:
-            vb = (4 if (cfg.lion and world > 1
-                        and n_voted >= AUTO_BUCKET_MIN_COORDS) else 1)
+        vb = (4 if (cfg.lion and world > 1
+                    and n_voted >= AUTO_BUCKET_MIN_COORDS) else 1)
     return dataclasses.replace(cfg, wire=wire, vote_every=ve,
                                vote_buckets=vb)
-
-
-def _resolve_row_block_auto(cfg: TrainConfig, n_params: int,
-                            params) -> TrainConfig:
-    """Resolve ``row_block=0`` (auto) from the device-keyed autotune cache
-    when the Pallas lion path is actually live — TPU backend and
-    ``kernel`` auto/pallas. Key: knob ``lion_row_block``, shape
-    ``N<ballot coords>``, dtype = the momentum dtype (mom_dtype override
-    or the param dtype, mirroring distributed_lion's state init). Off-TPU
-    and on cache miss the 0 passes through and pallas_lion.ROW_BLOCK
-    applies — interpret-mode tests stay independent of whatever cache the
-    repo happens to carry."""
-    if cfg.row_block != 0 or not cfg.lion or cfg.kernel == "xla":
-        return cfg
-    from distributed_lion_tpu.ops.autotune import lookup
-    from distributed_lion_tpu.ops.pallas_lion import pallas_available
-
-    if not pallas_available():
-        return cfg
-    leaves = jax.tree.leaves(params)
-    mom_dtype = (cfg.mom_dtype
-                 or (jnp.dtype(leaves[0].dtype).name if leaves else "float32"))
-    v = lookup("lion_row_block", f"N{n_params}", jnp.dtype(mom_dtype).name)
-    # .get, not [..]: a mistyped operator-written entry degrades to the
-    # built-in ROW_BLOCK (autotune failure philosophy), never crashes init
-    if not v or not isinstance(v.get("row_block"), int):
-        return cfg
-    return dataclasses.replace(cfg, row_block=v["row_block"])
 
 
 def make_optimizer(cfg: TrainConfig) -> FunctionalOptimizer:
@@ -639,7 +587,6 @@ def make_optimizer(cfg: TrainConfig) -> FunctionalOptimizer:
             vote_buckets=cfg.vote_buckets or 1,
             dcn_pipeline_depth=cfg.dcn_pipeline_depth,
             kernel=cfg.kernel,
-            row_block=cfg.row_block,
             mom_dtype=mom_dtype,
             telemetry=cfg.telemetry,
             guard=cfg.vote_guard,
@@ -727,7 +674,6 @@ class Trainer:
             cfg, mesh, n_params,
             params_replicated=not _spec_sharded_axes(param_specs),
         )
-        cfg = _resolve_row_block_auto(cfg, n_params, params)
         cplane_auto_armed = False
         if cfg.control_plane:
             if not cfg.lion:
@@ -1099,8 +1045,8 @@ class Trainer:
     # -------------------------------------------------------------- telemetry
     def telemetry_summary(self, reset: bool = False) -> Optional[dict]:
         """Current vote-health summary as host floats (None when telemetry
-        is off) — used by bench.py's record rows and available to callers
-        that drive the jitted steps directly instead of train()."""
+        is off), for callers that drive the jitted steps directly instead
+        of train()."""
         if not self._telemetry_on:
             return None
         out = telemetry.drain(self.vote_health, self._margin_exact)
@@ -1742,7 +1688,7 @@ class Trainer:
                         m["comm_mbytes_per_sec"] = comm.get("comm_mbytes_per_sec", 0.0)
                         # analytic pipelineable wire share under vote_buckets
                         # (profiling.comm_report); the measured counterpart is
-                        # bench.py's overlap-ablation comm_overlap_frac
+                        # the benchmark's vote_exposed_ms.train4
                         m["comm_overlap_frac"] = comm.get("comm_overlap_frac", 0.0)
                         if "dcn_overlap_frac" in comm:
                             # analytic share of the hier wire's level-2 latency

@@ -123,8 +123,8 @@ def comm_report(num_params: int, world: int, wire: str,
     under ``vote_buckets`` bucketing: the optimizer overlaps bucket k's
     collective with bucket k−1's fused apply, so every bucket after the
     first can ride behind compute — 0.0 for the monolithic vote, ≈(B−1)/B
-    for B equal buckets. The measured counterpart (step-time actually
-    recovered on hardware) comes from bench.py's overlap-ablation rows.
+    for B equal buckets. The measured counterpart is the benchmark's
+    ``vote_exposed_ms.train4``: the wire time no compute covers.
 
     ``dcn_overlap_frac`` (hier wire only) is the analytic share of the
     level-2 (DCN) leg's LATENCY eligible to leave the critical path under

@@ -52,9 +52,9 @@ def main() -> None:
         str(max(int(max_len) // 2, 8)),
         "--num_train_samples", "512", "--size_valid_set", "32",
         "--lion", "--async_grad",
-        # pin the banked-row comm methodology (same pin as bench.py /
+        # pin the banked-row comm methodology (same pin as
         # bench_sft_7b.py): every-step sign_psum voting, so rows rank
-        # comparably across backends and against the sweep tables
+        # comparably across backends
         "--wire", "sign_psum", "--vote_every", "1",
         "--per_device_train_batch_size", bs,
         "--gradient_accumulation_steps", accum,

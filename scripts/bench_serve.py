@@ -1,5 +1,5 @@
 #!/usr/bin/env python
-"""Decode benchmark for the serving engine — the serving twin of bench.py.
+"""Decode benchmark for the serving engine.
 
 Measures the continuous-batching engine (distributed_lion_tpu/serve/) the
 way the training bench measures the train step, and writes ONE strict-JSON

@@ -7,7 +7,7 @@ q/v r=8). This script runs that workload's train step at FULL 7B shapes
 about weight values) and reports tokens/s/chip plus peak HBM, the number
 VERDICT r1 asked to have recorded.
 
-Methodology matches scripts/bench_sweep.py: fused K-step dispatches via
+Methodology: fused K-step dispatches via
 Trainer._train_chunk, timer stopped on a device_get of the final loss so
 queued-but-unexecuted work can't inflate the number.
 
@@ -66,8 +66,7 @@ def run(quant: str = "nf4", batch_per_dev: int = 1, accum: int = 4,
         vocab_chunks=vocab_chunks,
         # pin the banked-row methodology: the auto sentinels would resolve
         # to packed_a2a (+ lazy votes) on a W>1 mesh and rank incomparably
-        # against rows measured under every-step sign_psum (same pin as
-        # bench.py)
+        # against rows measured under every-step sign_psum
         wire="sign_psum", vote_every=1,
     )
 

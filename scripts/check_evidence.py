@@ -13,7 +13,6 @@ judgments stay until ROADMAP D2 replaces them.)
     python scripts/check_evidence.py telemetry      # vote-health JSONL
     python scripts/check_evidence.py static         # graft-check both tiers
     python scripts/check_evidence.py vote_guard     # poisoned-run rescue
-    python scripts/check_evidence.py autotune       # TPU-keyed tuning cache
     python scripts/check_evidence.py journal        # run-journal attribution
     python scripts/check_evidence.py dcn_overlap    # pipelined hier DCN leg
     python scripts/check_evidence.py serving        # paged-KV decode bench
@@ -178,9 +177,9 @@ def _window_captured(path: str, marker: dict, result_key: str) -> bool:
     file-global "any result row" check would be satisfied by a PREVIOUS
     window's banked rows. This is the watcher's EXIT condition only —
     earlier configs that errored transiently are retried regardless: the
-    runbook's sweep stages run UNCONDITIONALLY on every recovery and
-    bench_sweep's SWEEP_SKIP_FILE skips result-row configs only, so
-    retries cost seconds, not chip time."""
+    runbook's sweep stages ran UNCONDITIONALLY on every recovery and the
+    sweep skipped result-row configs only, so retries cost seconds, not
+    chip time."""
     try:
         with open(path) as f:
             for line in f:
@@ -479,48 +478,6 @@ def static_ok() -> bool:
     return report.get("ok") is True
 
 
-# the autotune stage (ISSUE 6): the committed device-keyed tuning cache
-# (scripts/tuning_cache.json, written by cli/run_tune) exists, passes the
-# strict schema, and holds at least one TPU-keyed entry — i.e. the on-chip
-# tile search actually ran. The validator is ops/autotune's stdlib-only
-# validate_cache_doc, loaded by FILE PATH so this script stays jax-free
-# (the package __init__ pulls in jax).
-TUNE_CACHE = os.path.join(REPO, "scripts", "tuning_cache.json")
-
-
-def _autotune_module():
-    import importlib.util
-
-    spec = importlib.util.spec_from_file_location(
-        "dlt_autotune_standalone",
-        os.path.join(REPO, "distributed_lion_tpu", "ops", "autotune.py"))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
-def autotune_ok() -> bool:
-    """Captured = the cache validates AND EVERY knob holds a TPU-keyed
-    entry — all five are tunable on chip, so 'search complete' means all
-    five landed. Requiring any-one-entry would let a window that dropped
-    after the first knob permanently skip the rest (the runbook re-fires
-    with --skip_cached, so finished knobs cost nothing on recovery)."""
-    try:
-        with open(TUNE_CACHE) as f:
-            doc = json.load(f)
-    except (OSError, json.JSONDecodeError):
-        return False
-    try:
-        at = _autotune_module()
-    except Exception:
-        return False
-    if at.validate_cache_doc(doc):
-        return False
-    tpu_knobs = {key.split("|")[1] for key in doc["entries"]
-                 if key.split("|")[0].lower().startswith("tpu")}
-    return set(at.KNOBS) <= tpu_knobs
-
-
 # the run-journal stage (ISSUE 7): the runbook's journal leg records a
 # --journal training (runs/journal) whose journal must (a) exist and parse
 # under the strict schema (run_analyze counts schema errors), (b) close —
@@ -529,7 +486,7 @@ def autotune_ok() -> bool:
 # buckets (device / dispatch / data / ckpt / logging): the acceptance
 # criterion that makes the next MFU push start from a named stall budget
 # instead of a guess. The analyzer is cli/run_analyze — stdlib-only,
-# loaded by FILE PATH like the autotune validator, so this script stays
+# loaded by FILE PATH, so this script stays
 # jax-free.
 JOURNAL_MIN_COVERAGE = 0.95
 
@@ -1076,7 +1033,6 @@ STAGES = [
     ("static", static_ok),
     ("static_serve", static_serve_ok),
     ("vote_guard", vote_guard_ok),
-    ("autotune", autotune_ok),
     ("journal", journal_ok),
     ("dcn_overlap", dcn_overlap_ok),
     ("serving", serving_ok),
@@ -1149,8 +1105,6 @@ def check(what: str, arg: str | None = None) -> bool:
         return static_serve_ok(arg)
     if what == "vote_guard":
         return vote_guard_ok(arg or "vote_guard")
-    if what == "autotune":
-        return autotune_ok()
     if what == "journal":
         return journal_ok(arg or "journal")
     if what == "dcn_overlap":

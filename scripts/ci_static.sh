@@ -16,7 +16,7 @@ cd "$(dirname "$0")/.."
 rc=0
 
 if command -v ruff >/dev/null 2>&1; then
-  ruff check distributed_lion_tpu scripts bench.py || rc=1
+  ruff check distributed_lion_tpu scripts || rc=1
 else
   echo "ci_static: ruff not installed — skipped (baseline lives in pyproject.toml)"
 fi

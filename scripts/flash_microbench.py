@@ -1,5 +1,4 @@
-"""Time the trainer's flash kernels alone on the chip, against the library
-kernel at the tiles `auto` pinned before (512x1024), at a train cell's
+"""Time the trainer's flash kernels alone on the chip at a train cell's
 shape: ``python scripts/flash_microbench.py [B] [blocks...]``. Forward and
 forward + backward (a vjp with a given cotangent: kernels only), and the
 error against ``attention_xla`` in bfloat16. A chip-only tool."""
@@ -10,7 +9,7 @@ import jax
 import jax.numpy as jnp
 
 from distributed_lion_tpu.ops import pallas_flash_attn as F
-from distributed_lion_tpu.ops.attention import attention_flash, attention_xla
+from distributed_lion_tpu.ops.attention import attention_xla
 
 H, HD, T = 12, 64, 1024
 D = H * HD
@@ -39,15 +38,6 @@ def main():
     do = jax.random.normal(jax.random.key(1), (B, T, D), jnp.bfloat16)
     q, k, v = (heads(qkv[:, :, i * D:(i + 1) * D]) for i in range(3))
     do_h = heads(do)
-
-    def lib(q, k, v):
-        return attention_flash(q, k, v, block_q=512, block_kv=1024)
-
-    lib_f = timed(jax.jit(lib), q, k, v)
-    lib_fb = timed(jax.jit(lambda q, k, v, d: jax.vjp(lib, q, k, v)[1](d)),
-                   q, k, v, do_h)
-    print(f"B={B} library 512x1024: fwd {lib_f:.3f} ms, fwd+bwd "
-          f"{lib_fb:.3f} ms", flush=True)
 
     want = jax.jit(attention_xla)(q, k, v)
     want_g = jax.jit(lambda q, k, v, d: jax.vjp(attention_xla, q, k, v)[1](d)

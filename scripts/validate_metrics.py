@@ -39,9 +39,7 @@ JSON artifacts, so EVERY JSON artifact the repo writes passes one
 validator: crash bundles (``crash/step_*/bundle.json`` — must carry
 step/reason/config, telemetry.write_crash_bundle), checkpoint
 manifests (``manifest.json`` — must carry format/step/files with
-sha256+bytes per file, checkpoint.write_manifest), the autotune
-tuning cache (``tuning_cache.json`` — full check delegated to
-ops/autotune.validate_cache_doc, the cache's single schema authority),
+sha256+bytes per file, checkpoint.write_manifest),
 the DCN-overlap evidence artifact (``dcn_overlap.json`` —
 scripts/bench_dcn.py's ablation/frontier/parity document; the frontier
 rows are strict-validated per row), the serving-bench artifact
@@ -354,12 +352,9 @@ def validate_journal_file(path: str) -> list[str]:
 
 
 # required top-level keys per known single-document artifact name.
-# (tuning_cache.json is NOT listed here: it dispatches below on its
-# embedded format stamp — any filename, e.g. a $DLT_TUNE_CACHE override —
-# and delegates wholesale to ops/autotune.validate_cache_doc.
-# dcn_overlap.json, serving.json and elasticity.json have their own
-# branches too: their rows carry per-row schemas the generic
-# required-keys check can't express.)
+# (dcn_overlap.json, serving.json and elasticity.json have their own
+# branches: their rows carry per-row schemas the generic required-keys
+# check can't express.)
 _DOC_SCHEMAS = {
     "bundle.json": ("step", "reason", "config"),
     "manifest.json": ("format", "step", "files"),
@@ -877,27 +872,6 @@ def _elasticity_errors(path: str, doc: dict) -> list[str]:
 
 
 _SHA256 = re.compile(r"^[0-9a-f]{64}$")
-_TUNE_CACHE_FORMAT = "dlt-tune-cache-v1"  # == ops/autotune.CACHE_FORMAT
-
-
-def _tuning_cache_errors(path: str, doc) -> list[str]:
-    """Full strict-schema check for the autotune cache artifact, delegated
-    to the single source of truth — ops/autotune.validate_cache_doc —
-    loaded by FILE PATH (autotune is stdlib-only at import, like
-    train/resilience) so this validator stays jax-free."""
-    import importlib.util
-
-    at_path = os.path.join(
-        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-        "distributed_lion_tpu", "ops", "autotune.py")
-    try:
-        spec = importlib.util.spec_from_file_location("dlt_autotune_vm",
-                                                      at_path)
-        mod = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(mod)
-    except Exception as e:
-        return [f"{path}: cannot load autotune validator ({e})"]
-    return [f"{path}: {e}" for e in mod.validate_cache_doc(doc)]
 
 
 # the serve-plane graft-check matrix (analysis/serve_check.MATRIX): the
@@ -1040,11 +1014,6 @@ def validate_json_doc(path: str) -> list[str]:
         return _serve_check_errors(path, doc)
     if name == "elasticity.json":
         return _elasticity_errors(path, doc)
-    if name == "tuning_cache.json" or doc.get("format") == _TUNE_CACHE_FORMAT:
-        # dispatch on the embedded format stamp as well as the canonical
-        # name: a cache at any $DLT_TUNE_CACHE path (the documented drive)
-        # must get the strict schema, not just the generic checks
-        return _tuning_cache_errors(path, doc)
     for key in _DOC_SCHEMAS.get(name, ()):
         if key not in doc:
             errors.append(f"{path}: missing required key {key!r}")

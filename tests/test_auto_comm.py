@@ -8,6 +8,9 @@ VERDICT weak #1). The recipe itself lives in ONE place,
 train/loop.resolve_auto_comm; these tests pin its decision matrix and that
 the Trainer applies it end to end."""
 
+import builtins
+import os
+
 import jax
 import numpy as np
 import pytest
@@ -142,6 +145,67 @@ def test_trainer_resolves_and_steps_with_auto_recipe(mesh8):
                     max_steps=1)
     tr.close()
     assert np.isfinite([h["loss"] for h in hist if "loss" in h]).all()
+
+
+@pytest.fixture
+def host_reads(monkeypatch):
+    """Every path ``open`` is given and every environment variable that is
+    looked up, while the test runs."""
+    seen = {"open": [], "env": []}
+    real_open = builtins.open
+
+    def spy_open(file, *a, **k):
+        seen["open"].append(os.path.abspath(os.fspath(file))
+                            if isinstance(file, (str, os.PathLike))
+                            else file)
+        return real_open(file, *a, **k)
+
+    env_type = type(os.environ)
+    for name in ("get", "__getitem__", "__contains__"):
+        real = getattr(env_type, name)
+
+        def spy(self, key, *a, _real=real):
+            seen["env"].append(key)
+            return _real(self, key, *a)
+
+        monkeypatch.setattr(env_type, name, spy)
+    monkeypatch.setattr(builtins, "open", spy_open)
+    return seen
+
+
+def _reads_of_a_tuner(seen):
+    scripts = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "scripts") + os.sep
+    return ([p for p in seen["open"]
+             if isinstance(p, str) and p.startswith(scripts)]
+            + [k for k in seen["env"] if str(k).startswith("DLT_")])
+
+
+def test_resolve_auto_comm_reads_nothing_from_the_host(mesh8, host_reads):
+    """The bucket count comes from the ballot's size and nothing else: no
+    file under ``scripts/``, no ``DLT_*`` variable (a tuning cache keyed
+    by device kind sat in front of this heuristic until PR 28, and missed
+    on every chip)."""
+    r = resolve_auto_comm(TrainConfig(), mesh8, 124_000_000,
+                          params_replicated=True)
+    assert (r.wire, r.vote_buckets) == ("packed_a2a", 4)
+    assert _reads_of_a_tuner(host_reads) == []
+    assert host_reads["open"] == []
+
+
+def test_trainer_construction_reads_no_tuning_cache(mesh8, host_reads):
+    from distributed_lion_tpu.models.gpt2 import GPT2Config
+
+    cfg = TrainConfig(lion=True, async_grad=True, kernel="pallas",
+                      max_steps=1, per_device_train_batch_size=1,
+                      gradient_accumulation_steps=1, block_size=32,
+                      output_dir=None)
+    os.environ.get("DLT_PROBE")         # the recorder sees what is read
+    assert set(_reads_of_a_tuner(host_reads)) == {"DLT_PROBE"}
+    host_reads["env"].clear()
+    tr = Trainer.for_gpt2(cfg, mesh8, GPT2Config.tiny())
+    tr.close()
+    assert _reads_of_a_tuner(host_reads) == []
 
 
 def test_make_optimizer_degrades_sentinels_strict():

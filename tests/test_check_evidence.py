@@ -66,44 +66,6 @@ def test_missing_file_is_not_captured(tmp_path):
                                    "tokens_per_sec_per_chip")
 
 
-def test_sweep_skip_keys_round_trip(tmp_path, monkeypatch):
-    """bench_sweep's per-config resume: result rows (old round-3 schema and
-    new backend-carrying schema) produce skip keys; error rows don't."""
-    import importlib.util
-    import json as _json
-
-    p = tmp_path / "sweep.jsonl"
-    p.write_text("\n".join([
-        # round-3 row (no backend/block fields)
-        _json.dumps({"remat": "noremat", "batch_per_dev": 4,
-                     "attn": "flash@512x1024", "accum": 16, "dtype": "bf16",
-                     "vocab_chunks": 8, "mom_dtype": "bfloat16",
-                     "ms_per_step": 668.1, "loss": 9.045,
-                     "tokens_per_sec_per_chip": 98099.3}),
-        # round-4 row
-        _json.dumps({"remat": "noremat", "batch_per_dev": 2,
-                     "attn": "flash@512x1024", "accum": 16, "dtype": "bf16",
-                     "vocab_chunks": 8, "mom_dtype": "bfloat16",
-                     "vocab_pad": 0, "block": 2048,
-                     "tokens_per_sec_per_chip": 50000.0, "backend": "tpu"}),
-        # error row: must be retried, not skipped
-        _json.dumps({"remat": "noremat", "batch_per_dev": 8,
-                     "attn": "flash@512x1024", "accum": 8, "dtype": "bf16",
-                     "error": "timeout"}),
-    ]) + "\n")
-    monkeypatch.setenv("SWEEP_SKIP_FILE", str(p))
-    spec = importlib.util.spec_from_file_location(
-        "bench_sweep", os.path.join(REPO, "scripts", "bench_sweep.py"))
-    bs = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bs)
-    keys = bs._captured_keys()
-    assert ("noremat", 4, "flash@512x1024", 16, "bf16", 8, "bfloat16",
-            0, 1024, 1) in keys
-    assert ("noremat", 2, "flash@512x1024", 16, "bf16", 8, "bfloat16",
-            0, 2048, 1) in keys
-    assert len(keys) == 2  # the error row contributed nothing
-
-
 def test_marker_matches_any_field_order(tmp_path):
     """The structural compare must be immune to key order and spacing —
     the exact failure mode of the old substring needles."""
@@ -193,42 +155,6 @@ def test_parity_strict_requires_numeric_pass(tmp_path, monkeypatch):
     assert not ce.parity("lazy") and not ce.parity_strict("lazy")
 
 
-def test_autotune_stage(tmp_path, monkeypatch):
-    """The 'autotune' stage: captured only when the committed tuning cache
-    exists, passes the strict schema, AND carries TPU-keyed entries for
-    EVERY knob (a window that dropped after the first knob must re-fire,
-    not permanently skip the rest) — the CPU-produced pipeline-proof
-    artifact alone must read MISSING, as must a corrupt or
-    schema-violating cache."""
-    import json as _json
-
-    KNOBS = ("flash_tiles", "splash_tiles", "lion_row_block",
-             "vocab_chunks", "vote_buckets")
-    cache = tmp_path / "tuning_cache.json"
-    monkeypatch.setattr(ce, "TUNE_CACHE", str(cache))
-    assert not ce.autotune_ok()                       # absent
-    entry = {"value": {"x": 512}, "ms": 1.0}
-    cache.write_text(_json.dumps({
-        "format": "dlt-tune-cache-v1",
-        "entries": {f"cpu|{k}|N10|float32": entry for k in KNOBS}}))
-    assert not ce.autotune_ok()                       # cpu-keyed only
-    cache.write_text(_json.dumps({
-        "format": "dlt-tune-cache-v1",
-        "entries": {"TPU v5 lite|lion_row_block|N10|float32": entry}}))
-    assert not ce.autotune_ok()                       # one knob ≠ complete
-    cache.write_text(_json.dumps({
-        "format": "dlt-tune-cache-v1",
-        "entries": {f"TPU v5 lite|{k}|N10|float32": entry for k in KNOBS}}))
-    assert ce.autotune_ok()                           # all knobs: captured
-    cache.write_text(_json.dumps({
-        "format": "dlt-tune-cache-v1",
-        "entries": {"TPU v5 lite|lion_row_block|N10|float32":
-                    {"value": {}, "ms": 1.0}}}))
-    assert not ce.autotune_ok()                       # schema violation
-    cache.write_text("{torn")
-    assert not ce.autotune_ok()                       # corrupt
-
-
 def test_parity_short_leg_unqualified(tmp_path):
     d = tmp_path / "legs"
     d.mkdir()
@@ -309,7 +235,7 @@ def test_overlap_stage_needs_all_three_bucket_rows(tmp_path, monkeypatch):
             "tokens_per_sec_per_chip": 98000.0, "ms_per_step": 668.0,
             "backend": "tpu"}
     p = tmp_path / "overlap.jsonl"
-    # B=1 rows omit the field (bench_sweep default-elision) — the marker's
+    # B=1 rows omit the field (the sweep elided defaults) — the marker's
     # _MARKER_DEFAULTS fill must still match them
     rows = [_json.dumps(base),
             _json.dumps({**base, "vote_buckets": 4, "ms_per_step": 640.0})]
@@ -319,95 +245,6 @@ def test_overlap_stage_needs_all_three_bucket_rows(tmp_path, monkeypatch):
                              "ms_per_step": 645.0, "error": "x"}))
     p.write_text("\n".join(rows) + "\n")
     assert ce.overlap()
-
-
-def test_bench_overlap_from_ablation(tmp_path, monkeypatch):
-    """bench.overlap_from_ablation: measured comm_overlap_frac =
-    (ms[1] − min_B ms[B]) / ms[1] over TPU rows of one config; CPU rows and
-    slower-than-anchor pipelined rows never produce a negative fraction."""
-    import importlib.util
-    import json as _json
-
-    spec = importlib.util.spec_from_file_location(
-        "bench_mod3", os.path.join(REPO, "bench.py"))
-    b = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(b)
-    d = tmp_path / "SWEEP_r9_raw"
-    d.mkdir()
-    base = {"remat": "noremat", "batch_per_dev": 4, "attn": "flash",
-            "accum": 16, "dtype": "bf16", "tokens_per_sec_per_chip": 9.0}
-    rows = [
-        _json.dumps({**base, "ms_per_step": 700.0}),                # B=1
-        _json.dumps({**base, "ms_per_step": 630.0, "vote_buckets": 4}),
-        _json.dumps({**base, "ms_per_step": 665.0, "vote_buckets": 16}),
-        # a CPU-attested row must be ignored entirely
-        _json.dumps({**base, "ms_per_step": 1.0, "vote_buckets": 4,
-                     "backend": "cpu"}),
-    ]
-    (d / "overlap.jsonl").write_text("\n".join(rows) + "\n")
-    import glob as _glob
-    monkeypatch.setattr(
-        _glob, "glob", lambda pat: [str(d / "overlap.jsonl")])
-    got = b.overlap_from_ablation()
-    assert abs(got["comm_overlap_frac"] - (700.0 - 630.0) / 700.0) < 1e-9
-    assert set(got["ms_per_step"]) == {"1", "4", "16"}
-    # pipelined slower than anchor → clipped at 0, never negative
-    (d / "overlap.jsonl").write_text("\n".join([
-        _json.dumps({**base, "ms_per_step": 700.0}),
-        _json.dumps({**base, "ms_per_step": 800.0, "vote_buckets": 4}),
-    ]) + "\n")
-    assert b.overlap_from_ablation()["comm_overlap_frac"] == 0.0
-
-
-def test_sweep_row_promotable_rule():
-    """bench.sweep_row_promotable: the ONE eligibility rule shared by
-    _best_sweep_row and the runbook winner promotion."""
-    import importlib.util
-
-    spec = importlib.util.spec_from_file_location(
-        "bench_mod", os.path.join(REPO, "bench.py"))
-    b = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(b)
-    ok = {"tokens_per_sec_per_chip": 98099.3}
-    assert b.sweep_row_promotable(ok)                       # legacy row
-    assert b.sweep_row_promotable({**ok, "backend": "tpu"})
-    assert not b.sweep_row_promotable({**ok, "backend": "cpu"})
-    assert not b.sweep_row_promotable({**ok, "block": 2048})  # not anchor
-    # pipelined-wire ablation rows never displace the monolithic anchor
-    # (the adoption probe in run_inner must carry this field too)
-    assert not b.sweep_row_promotable({**ok, "vote_buckets": 4})
-    assert not b.sweep_row_promotable({"error": "boom"})
-
-
-def test_unpromoted_capture_cannot_clobber_promoted_artifact(tmp_path):
-    """bench._record_tpu_measurement (advisor r4, medium): a debug run's
-    record must not overwrite the promoted flagship artifact that future
-    bare runs adopt their config from — but promoted records, and writes
-    over unpromoted ones, still land."""
-    import importlib.util
-    import json as _json
-
-    spec = importlib.util.spec_from_file_location(
-        "bench_mod2", os.path.join(REPO, "bench.py"))
-    b = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(b)
-    art = tmp_path / "last.json"
-    b.LAST_TPU_ARTIFACT = str(art)
-    b._record_tpu_measurement({"value": 90000.0, "promoted": True,
-                               "backend": "tpu"})
-    assert _json.loads(art.read_text())["value"] == 90000.0
-    # unpromoted over promoted: refused
-    b._record_tpu_measurement({"value": 10.0, "promoted": False,
-                               "backend": "tpu"})
-    assert _json.loads(art.read_text())["value"] == 90000.0
-    # promoted over promoted: recorded
-    b._record_tpu_measurement({"value": 95000.0, "promoted": True,
-                               "backend": "tpu"})
-    assert _json.loads(art.read_text())["value"] == 95000.0
-    # unpromoted over unpromoted: recorded (no promoted chain to protect)
-    art.write_text(_json.dumps({"value": 1.0, "promoted": False}))
-    b._record_tpu_measurement({"value": 2.0, "promoted": False})
-    assert _json.loads(art.read_text())["value"] == 2.0
 
 
 def test_telemetry_stage_mass_conservation(tmp_path, monkeypatch):

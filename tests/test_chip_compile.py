@@ -147,22 +147,58 @@ def test_packed_row_tally_compiles_flat_at_group_aligned_rows(one_chip):
 
 
 # ------------------------------------------------------------ flash attention
-@pytest.mark.parametrize("shape,tiles", [
-    ((4, 12, 1024, 64), (512, 1024)),   # the 124M train step, tuned tiles
-    ((1, 8, 2048, 128), (0, 0)),        # T=2048 hd=128, library defaults
-])
-def test_flash_attention_fwd_bwd_compiles(one_chip, shape, tiles):
+def test_flash_attention_fwd_bwd_compiles(one_chip):
+    """The library's kernel at its own tiles, T = 2048, head_dim 128."""
     from distributed_lion_tpu.ops.attention import attention_flash
 
-    x = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+    x = jax.ShapeDtypeStruct((1, 8, 2048, 128), jnp.bfloat16,
+                             sharding=one_chip)
 
     def loss(q, k, v):
-        out = attention_flash(q, k, v, causal=True, block_q=tiles[0],
-                              block_kv=tiles[1])
-        return out.astype(jnp.float32).sum()
+        return attention_flash(q, k, v).astype(jnp.float32).sum()
 
     text, _ = _compile(jax.grad(loss, argnums=(0, 1, 2)), x, x, x)
     assert text.count("tpu_custom_call") >= 3  # fwd, dq, dkv
+
+
+@pytest.mark.parametrize("T", [1024, 2048, 4096])
+def test_llama_block_takes_the_library_kernel_from_T2048(one_chip, monkeypatch,
+                                                         T):
+    """Why the library's kernel stays: one ``models/llama`` block of
+    Llama's head_dim (128; 4 query heads over 2 kv heads keep the compile
+    short), forward and backward, with attention ``auto`` as a TPU backend
+    resolves it. From T = 2048 the program holds the library's three
+    kernels and no ``[.., T, T]`` buffer of any type; at T = 1024 it holds
+    no Pallas call and materialises the float32 scores."""
+    from distributed_lion_tpu.models import llama
+
+    cfg = llama.LlamaConfig(vocab_size=256, n_layer=1, n_head=4, n_kv_head=2,
+                            d_model=512, d_ff=1024, n_ctx=T, remat=False)
+    assert cfg.head_dim == 128 and cfg.attn_impl == "auto"
+    p = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, jnp.bfloat16,
+                                       sharding=one_chip),
+        jax.eval_shape(lambda: llama.llama_init(jax.random.key(0), cfg)
+                       )["blocks"][0])
+    x = jax.ShapeDtypeStruct((2, T, 512), jnp.bfloat16, sharding=one_chip)
+
+    def loss(p, x):
+        cos, sin = llama.rope_angles(T, cfg.head_dim, cfg.rope_theta)
+        return llama._block(x, p, cfg, cos, sin).astype(jnp.float32).sum()
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    text, _ = _compile(jax.grad(loss, argnums=(0, 1)), p, x)
+    kernels = re.findall(
+        r'%(\S+) = [^\n]*custom_call_target="tpu_custom_call"', text)
+    scores = set(re.findall(r"\w+\[[\d,]*%d,%d\]" % (T, T), text))
+    if T >= 2048:
+        assert len(kernels) == 3 and all(
+            re.match(r"flash_(attention|mha_bwd_dq|mha_bwd_dkv)", k)
+            for k in kernels), kernels
+        assert not scores, scores
+    else:
+        assert not kernels, kernels
+        assert any(s.startswith("f32[") for s in scores), scores
 
 
 @pytest.mark.parametrize("shape,dtype", [

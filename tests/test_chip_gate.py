@@ -72,15 +72,17 @@ def test_cache_stays_off_on_cpu_and_on_opt_out(tpu_backend, monkeypatch):
     assert not writes
 
 
-@pytest.mark.parametrize("script", ["chip_smoke.py", "bench.py"])
+@pytest.mark.parametrize("script", ["chip_smoke.py",
+                                    "scripts/flash_microbench.py"])
 def test_chip_entry_points_refuse_the_cpu(script):
     """No accelerator -> non-zero exit within seconds and no result line."""
     proc = subprocess.run([sys.executable, os.path.join(REPO, script)],
                           capture_output=True, text=True, timeout=240,
                           env=_cpu_env(), cwd=REPO)
     assert proc.returncode != 0, proc.stdout[-500:]
-    # neither script's result line (`"ok": true` / a `"metric"` row)
-    assert '"ok": true' not in proc.stdout and '"metric"' not in proc.stdout
+    assert "TPU" in proc.stderr, proc.stderr[-500:]   # refused, not crashed
+    # neither script's result line (`"ok": true` / a `B=... block` row)
+    assert '"ok": true' not in proc.stdout and "B=" not in proc.stdout
 
 
 def test_replica_procs_parent_stays_off_jax(tmp_path):

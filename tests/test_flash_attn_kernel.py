@@ -132,13 +132,11 @@ def spy(monkeypatch):
         calls.append(("kernel", qkv.shape, n_head))
         return qkv[..., :qkv.shape[-1] // 3]
 
-    def flash(q, k, v, *, causal=True, block_q=0, block_kv=0,
-              block_q_bwd=0, block_kv_bwd=0):
-        calls.append(("flash", q.shape, (block_q, block_kv, block_q_bwd,
-                                         block_kv_bwd)))
+    def flash(q, k, v, **kw):
+        calls.append(("flash", q.shape, kw))
         return q
 
-    def xla(q, k, v, *, causal=True, score_dtype=None):
+    def xla(q, k, v, *, causal=True):
         calls.append(("xla", q.shape))
         return q
 
@@ -156,23 +154,36 @@ def _trace_qkv(B, T, H, hd, dtype=jnp.bfloat16, **kw):
     return jax.eval_shape(lambda x: A.attention_qkv(x, H, **kw), x)
 
 
-@pytest.mark.parametrize("B,T,H,hd", [(20, 1024, 12, 64),   # cell 1
-                                      (4, 1024, 12, 64),    # cell 4
-                                      (1, 2048, 32, 128),
-                                      (2, 4096, 16, 64)],
-                         ids=["readme-20x8", "vote-4x2", "T2048-hd128",
-                              "T4096-hd64"])
-def test_auto_takes_the_kernel_on_a_tpu(spy, monkeypatch, B, T, H, hd):
-    """At the two training cells' shapes and wherever `auto` took the
-    library's flash before: the kernel, handed ``qkv`` as it lies."""
+KERNEL = {  # where: B, T, H, head_dim, dtype
+    "readme-20x8": (20, 1024, 12, 64, jnp.bfloat16),      # cell 1
+    "vote-4x2": (4, 1024, 12, 64, jnp.bfloat16),          # cell 4
+    "T2048-hd128": (1, 2048, 32, 128, jnp.bfloat16),
+    "T4096-hd64": (2, 4096, 16, 64, jnp.bfloat16),
+    # every GPT-2 width a CLI or a benchmark configuration can name that
+    # the kernel takes, and the T it takes them at
+    "gpt2_124m-f32": (4, 1024, 12, 64, jnp.float32),      # run_clm's default
+    "gpt2_124m-T8192": (1, 8192, 12, 64, jnp.bfloat16),   # the kernel's MAX_T
+    "gpt2_124m-T2176": (1, 2176, 12, 64, jnp.bfloat16),   # 17 blocks of 128
+    "gpt2-medium": (2, 1024, 16, 64, jnp.bfloat16),
+    "gpt2-large": (2, 1024, 20, 64, jnp.bfloat16),
+}
+
+
+@pytest.mark.parametrize("where", list(KERNEL))
+def test_auto_takes_the_kernel_on_a_tpu(spy, monkeypatch, where):
+    """At the two training cells' shapes, at the other GPT-2 widths and
+    wherever the head-major `auto` would take the library's flash: the
+    kernel, handed ``qkv`` as it lies."""
+    B, T, H, hd, dtype = KERNEL[where]
     monkeypatch.setattr(A.jax, "default_backend", lambda: "tpu")
-    out = _trace_qkv(B, T, H, hd)
+    out = _trace_qkv(B, T, H, hd, dtype)
     assert out.shape == (B, T, H * hd)
     assert spy == [("kernel", (B, T, 3 * H * hd), H)]
     (line,) = A.new_resolved_lines()
     blk = F.block_for(T)
     assert line == (f"[setup] attention: qkv auto -> pallas_flash_attn "
-                    f"(T {T}, head_dim {hd}, bfloat16, tiles {blk}x{blk})")
+                    f"(T {T}, head_dim {hd}, {jnp.dtype(dtype).name}, "
+                    f"tiles {blk}x{blk})")
     assert A.new_resolved_lines() == []       # said once
 
 
@@ -182,29 +193,65 @@ AWAY = {  # why: backend, T, H, head_dim, the call's options, where it goes
     "xl-25-heads": ("tpu", 1024, 25, 64, {}, "xla"),
     "T512": ("tpu", 512, 12, 64, {}, "xla"),
     "T16384": ("tpu", 16384, 8, 128, {}, "flash"),
-    "pinned-tiles": ("tpu", 1024, 12, 64,
-                     {"block_q": 512, "block_kv": 1024}, "flash"),
-    "pinned-bwd-tiles": ("tpu", 1024, 12, 64,
-                         {"block_q_bwd": 256, "block_kv_bwd": 512}, "flash"),
-    "explicit-flash": ("tpu", 1024, 12, 64, {"impl": "flash"}, "flash"),
     "explicit-xla": ("tpu", 1024, 12, 64, {"impl": "xla"}, "xla"),
+    "T1000": ("tpu", 1000, 12, 64, {}, "xla"),     # no whole row block
+    "gpt2-tiny": ("tpu", 128, 4, 16, {}, "xla"),   # GPT2Config.tiny
+    "gpt2_small": ("tpu", 256, 5, 64, {}, "xla"),  # GPT2Config.small: 2.5
+                                                   # lane blocks
 }
 
 
 @pytest.mark.parametrize("why", list(AWAY))
 def test_auto_resolves_away_from_the_kernel(spy, monkeypatch, why):
     """Off a TPU, at a shape the kernel does not take, below T = 1024 and
-    under caller-pinned tiles or an explicit impl: the head-major paths,
-    as before (pinned tiles reach the library kernel intact)."""
+    under ``impl="xla"``: the head-major entry, which decides the rest."""
     backend, T, H, hd, kw, first = AWAY[why]
     monkeypatch.setattr(A.jax, "default_backend", lambda: backend)
     out = _trace_qkv(2, T, H, hd, **kw)
     assert out.shape == (2, T, H * hd)
     assert [c[0] for c in spy] == [first]
     assert spy[0][1] == (2, H, T, hd)
-    if first == "flash":
-        assert spy[0][2] == tuple(kw.get(k, 0) for k in (
-            "block_q", "block_kv", "block_q_bwd", "block_kv_bwd"))
+
+
+HEAD_MAJOR = {  # who: backend, B, T, H, head_dim, where `auto` goes
+    # Llama-2-7B / Llama-3-8B: 32 query heads of 128 (kv heads repeated
+    # before the call), at the T a chip's memory lets a block train at
+    "llama-T1024": ("tpu", 1, 1024, 32, 128, "xla"),
+    "llama-T2048": ("tpu", 1, 2048, 32, 128, "flash"),
+    "llama-T4096": ("tpu", 1, 4096, 32, 128, "flash"),
+    "llama-T8192": ("tpu", 1, 8192, 32, 128, "flash"),
+    "llama-T4096-cpu": ("cpu", 1, 4096, 32, 128, "xla"),
+    "llama-T512": ("tpu", 2, 512, 32, 128, "xla"),
+    "llama-small": ("tpu", 2, 1024, 8, 64, "xla"),     # LlamaConfig.small
+    "T2047": ("tpu", 1, 2047, 8, 64, "xla"),           # one under the line
+    "hd64-T2048": ("tpu", 1, 2048, 8, 64, "flash"),
+}
+
+
+@pytest.mark.parametrize("who", list(HEAD_MAJOR))
+def test_head_major_auto_has_two_outcomes(spy, monkeypatch, who):
+    """``attention`` `auto`: the library's flash kernel on a TPU from
+    T = 2048, called with q, k, v and ``causal`` and nothing else (its own
+    default tiles); ``attention_xla`` everywhere else."""
+    backend, B, T, H, hd, goes = HEAD_MAJOR[who]
+    monkeypatch.setattr(A.jax, "default_backend", lambda: backend)
+    x = jax.ShapeDtypeStruct((B, H, T, hd), jnp.bfloat16)
+    out = jax.eval_shape(lambda q, k, v: A.attention(q, k, v), x, x, x)
+    assert out.shape == (B, H, T, hd)
+    want = ("flash", (B, H, T, hd), {"causal": True}) if goes == "flash" \
+        else ("xla", (B, H, T, hd))
+    assert spy == [want]
+
+
+def test_head_major_resolution_is_said_once(spy, monkeypatch):
+    monkeypatch.setattr(A.jax, "default_backend", lambda: "tpu")
+    x = jax.ShapeDtypeStruct((1, 32, 2048, 128), jnp.bfloat16)
+    for _ in range(2):
+        jax.eval_shape(lambda q, k, v: A.attention(q, k, v), x, x, x)
+    assert A.new_resolved_lines() == [
+        "[setup] attention: head-major auto -> flash (T 2048, head_dim 128, "
+        "bfloat16, tiles default)"]
+    assert A.new_resolved_lines() == []
 
 
 def test_dropout_keeps_the_xla_scores(spy, monkeypatch):

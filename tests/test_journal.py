@@ -478,21 +478,3 @@ def test_vote_guard_journals_transitions():
     g.update(13, clean, 1)  # cooldown elapsed → readmission probe
     r = [x for x in jr.records_ if x["name"] == "guard_readmit"]
     assert r and r[0]["worker"] == 1
-
-
-def test_autotune_trial_records_span():
-    """run_trial_child journals one autotune/trial span per candidate —
-    including the timeout path, where the span carries the error row."""
-    from distributed_lion_tpu.ops.autotune import run_trial_child
-
-    jr = _FakeJournal()
-    out = run_trial_child({"knob": "lion_row_block",
-                           "candidate": {"row_block": 128},
-                           "info": {"n": 256}, "_test_sleep_s": 30},
-                          timeout_s=0.5, journal=jr)
-    assert "timeout" in out["error"]
-    spans = [r for r in jr.records_ if r.get("name") == "autotune/trial"]
-    assert len(spans) == 1
-    assert spans[0]["knob"] == "lion_row_block"
-    assert "timeout" in spans[0]["error"]
-    assert spans[0]["dur"] >= 0.4

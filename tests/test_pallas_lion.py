@@ -87,3 +87,48 @@ def test_pallas_step_equals_xla_step(wire):
 def test_kernel_mode_validation():
     with pytest.raises(ValueError):
         distributed_lion(kernel="cuda")
+
+
+@pytest.mark.parametrize("vote_buckets", [1, 4])
+def test_elections_bit_identical_across_row_blocks(vote_buckets):
+    """Any ``row_block`` (and the XLA path) produces BYTE-identical
+    params/momenta across vote_buckets {1, 4} — tiling is never allowed to
+    move an election or a weight."""
+    mesh = make_mesh(data=8)
+    rng = np.random.default_rng(11)
+    params = {
+        "w": jnp.asarray(rng.normal(size=(777, 13)).astype(np.float32)),
+        "b": jnp.asarray(rng.normal(size=(259,)).astype(np.float32)),
+    }
+    grads = {
+        "w": jnp.asarray(rng.normal(size=(8, 777, 13)).astype(np.float32)),
+        "b": jnp.asarray(rng.normal(size=(8, 259)).astype(np.float32)),
+    }
+    results = []
+    configs = [("xla", 0), ("pallas", 0), ("pallas", 128), ("pallas", 2048)]
+    for kern, rb in configs:
+        opt = distributed_lion(learning_rate=0.02, weight_decay=0.05,
+                               wire="sign_psum", kernel=kern, row_block=rb,
+                               vote_buckets=vote_buckets)
+        state = shard_state(init_global_state(opt, params, 8), mesh)
+        step = make_sharded_step(opt, mesh)
+        p = params
+        for _ in range(3):
+            p, state = step(p, grads, state)
+        results.append((kern, rb, p, state))
+    _, _, p0, s0 = results[0]
+    for kern, rb, p, s in results[1:]:
+        for k in params:
+            np.testing.assert_array_equal(
+                np.asarray(p0[k]), np.asarray(p[k]),
+                err_msg=f"params diverged at kernel={kern} row_block={rb}")
+            np.testing.assert_array_equal(
+                np.asarray(s0.exp_avg[k]), np.asarray(s.exp_avg[k]),
+                err_msg=f"momentum diverged at kernel={kern} row_block={rb}")
+
+
+def test_bad_row_block_rejected_at_build():
+    with pytest.raises(ValueError, match="multiple of 32"):
+        distributed_lion(row_block=100)
+    with pytest.raises(ValueError, match="multiple of 32"):
+        distributed_lion(row_block=16)
