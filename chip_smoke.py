@@ -33,6 +33,9 @@ N_124M = 124_439_808          # its flat parameter count
 TRAIN_STEPS = 4
 MULTICHIP_STEPS = 3
 FLASH_TOL = 2e-2              # |flash - xla| on bf16 outputs / grads of O(1)
+XENT_LOSS_TOL = 1e-5          # |fused - dense| / loss, the loss head's kernels
+XENT_GRAD_TOL = 2e-2          # |fused - dense| of a bf16-rounded gradient,
+#                               relative to the largest reference value
 LOGIT_TOL = 5e-2              # |paged - dense| on f32 logits (bf16 compute)
 LOSS_TOL = 2e-3               # |loss_auto - loss_ref| / loss, multichip phase
 
@@ -273,6 +276,55 @@ def phase_kernels() -> None:
                 ref.astype(jnp.float32))))),
                   f"flash_qkv off by {err}")
 
+    # the loss head's kernel pair (ops/pallas_xent) through the entry the
+    # trainer's dense branch calls, at the train step's microbatch against
+    # the published head as it lies ([4 x 1023 labelled rows, 768] x
+    # [50257, 768]), vs the einsum + clm_loss_and_metrics it replaces:
+    # loss, accuracy and both gradients
+    from distributed_lion_tpu.models.loss import clm_loss_and_metrics
+    from distributed_lion_tpu.ops import xent as xent_ops
+
+    kh, kw, kt = jax.random.split(jax.random.key(29), 3)
+    hidden = jax.random.normal(kh, (4, 1024, 768), jnp.bfloat16)
+    head = jax.random.normal(kw, (VOCAB, 768), jnp.float32) * 0.05
+    tokens = jax.random.randint(kt, (4, 1024), 0, VOCAB)
+    check(xent_ops.fused_kernel_applies(768, jnp.bfloat16),
+          "the loss head does not take ops/pallas_xent at d 768, bf16")
+
+    def dense(h, w, t):
+        logits = jnp.einsum("btd,vd->btv", h, w.astype(h.dtype),
+                            preferred_element_type=jnp.float32)
+        return clm_loss_and_metrics(logits, t)
+
+    def run(fn):
+        return jax.jit(jax.value_and_grad(fn, argnums=(0, 1), has_aux=True))
+
+    fused = run(xent_ops.tied_head_clm_loss_and_metrics)
+    names = mosaic_kernels(fused.lower(hidden, head, tokens).as_text())
+    check(names == ["fused_xent_bwd", "fused_xent_fwd"],
+          f"the loss head lowered to {names}")
+    (loss_k, m_k), grads_k = fused(hidden, head, tokens)
+    (loss_x, m_x), grads_x = run(dense)(hidden, head, tokens)
+    gap = abs(float(loss_k) - float(loss_x)) / float(loss_x)
+    errs = [float(jnp.max(jnp.abs(a.astype(jnp.float32)
+                                  - b.astype(jnp.float32))))
+            / float(jnp.max(jnp.abs(b.astype(jnp.float32))))
+            for a, b in zip(grads_k, grads_x)]
+    log(f"  fused_xent [4x1023,768]x[{VOCAB},768] fwd+bwd vs einsum + "
+        f"clm_loss_and_metrics: loss {float(loss_k):.6f} / "
+        f"{float(loss_x):.6f} (gap {gap:.2e}, tol {XENT_LOSS_TOL}), accuracy "
+        f"{float(m_k['accuracy']):.6f} / {float(m_x['accuracy']):.6f}, max "
+        f"|diff| dh/dwte over the largest reference value = "
+        f"{[round(e, 5) for e in errs]} (tol {XENT_GRAD_TOL})")
+    check(gap <= XENT_LOSS_TOL, f"fused_xent loss off by {gap}")
+    check(float(m_k["n_tokens"]) == float(m_x["n_tokens"]) == 4 * 1023,
+          "fused_xent counts other tokens than the dense loss")
+    check(abs(float(m_k["accuracy"]) - float(m_x["accuracy"]))
+          <= 2 / (4 * 1023), "fused_xent argmax differs from the dense one")
+    for err in errs:
+        check(err <= XENT_GRAD_TOL, f"fused_xent gradient off by {err}")
+    del hidden, head, grads_k, grads_x
+
 
 # ------------------------------------------------------------------- train
 def _metrics_rows(out_dir: str) -> list:
@@ -302,7 +354,7 @@ def phase_train(out_dir: str) -> None:
     from distributed_lion_tpu.cli import run_clm
     from distributed_lion_tpu.ops import attention as attention_ops
     from distributed_lion_tpu.ops.pallas_lion import resolve_kernel_mode
-    from distributed_lion_tpu.train import loop, resilience
+    from distributed_lion_tpu.train import journal, loop, resilience
 
     check(resolve_kernel_mode("auto") is False,
           "kernel=auto did not resolve to the compiled Pallas path")
@@ -336,9 +388,14 @@ def phase_train(out_dir: str) -> None:
     check({"flash_attention_fwd", "flash_mha_bwd"} <= set(kernels),
           f"attention auto did not put ops/pallas_flash_attn's kernels in "
           f"the step: {kernels}")
-    log("  " + "; ".join(attention_ops.new_resolved_lines()
-                        or ["attention: resolved lines already printed by "
-                            "the trainer"]))
+    check({"fused_xent_fwd", "fused_xent_bwd"} <= set(kernels),
+          f"the dense branch's loss head did not put ops/pallas_xent's "
+          f"kernels in the step: {kernels}")
+    check("50257xf32" not in text,
+          "the step holds a float32 buffer of the logits' shape")
+    log("  " + "; ".join(journal.new_resolved_lines()
+                        or ["attention, cross-entropy: resolved lines "
+                            "already printed by the trainer"]))
     tokens_per_step = trainer.global_train_batch() * trainer.cfg.block_size
     step_s = [tokens_per_step / r["train/tokens_per_sec"] for r in rows]
     log(f"  run_clm: {TRAIN_STEPS} steps, losses {losses}, wire="

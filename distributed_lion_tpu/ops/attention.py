@@ -325,37 +325,20 @@ def chunked_causal_attention(q, k, v, pos, *, scale: float,
     return out.transpose(1, 2, 0, 3, 4).reshape(B, H, S, -1)
 
 
-# What `auto` resolved to, by (entry, T, head_dim, dtype): recorded once at
-# trace time, said by whoever drives the program (Trainer.train prints
-# :func:`new_resolved_lines` after a dispatch that traced).
-_RESOLVED: dict = {}
-_resolved_said = 0
-
-
 def _note_resolved(entry: str, impl: str, T: int, head_dim: int, dtype,
                    tiles: str) -> None:
-    key = (entry, T, head_dim, jnp.dtype(dtype).name)
-    if key in _RESOLVED:
-        return
-    fields = {"entry": entry, "impl": impl, "T": T, "head_dim": head_dim,
-              "dtype": key[3], "tiles": tiles}
-    _RESOLVED[key] = fields
+    """What `auto` resolved to, once a shape at trace time: an
+    ``attn_resolved`` journal event and a ``[setup]`` line for whoever
+    drives the program (``train/journal.resolved``)."""
     from distributed_lion_tpu.train import journal
 
-    journal.event("attn_resolved", **fields)
-
-
-def new_resolved_lines() -> list:
-    """One ``[setup]`` line for each resolution recorded since the last
-    call (an integer compare when there is none)."""
-    global _resolved_said
-    if _resolved_said == len(_RESOLVED):
-        return []
-    fresh = list(_RESOLVED.values())[_resolved_said:]
-    _resolved_said = len(_RESOLVED)
-    return [f"[setup] attention: {f['entry']} auto -> {f['impl']} "
-            f"(T {f['T']}, head_dim {f['head_dim']}, {f['dtype']}, "
-            f"tiles {f['tiles']})" for f in fresh]
+    name = jnp.dtype(dtype).name
+    journal.resolved(
+        "attn_resolved",
+        f"[setup] attention: {entry} auto -> {impl} (T {T}, head_dim "
+        f"{head_dim}, {name}, tiles {tiles})",
+        entry=entry, impl=impl, T=T, head_dim=head_dim, dtype=name,
+        tiles=tiles)
 
 
 def qkv_kernel_applies(T: int, n_head: int, head_dim: int, dtype) -> bool:

@@ -13,6 +13,17 @@ drops to [N, V/chunks] — and backward recomputes each chunk's logits from
 
 Exact same math as ``log_softmax`` + gather (pinned to the dense path by
 tests/test_xent.py, gradients included); only the schedule differs.
+
+The dense path's loss head (no vocab chunks, no vocab or sequence axis) is
+:func:`tied_head_clm_loss_and_metrics`: on a TPU, for a ``[V, d]`` head
+under bfloat16 hidden states with ``d`` a multiple of 128, it is the Mosaic
+kernel pair of ``ops/pallas_xent`` (no ``[B, T, V]`` logits in HBM, forward
+or backward); everywhere else the einsum and
+``models/loss.clm_loss_and_metrics``, bit for bit what ``gpt2_apply`` and
+the default loss compute. No option chooses. What it resolved to is
+recorded once a shape at trace time: an ``xent_resolved`` event in the run
+journal and a ``[setup] cross-entropy: ...`` line after the trainer's
+first dispatch (``train/journal.resolved``), as ``ops/attention`` does.
 """
 
 from __future__ import annotations
@@ -206,6 +217,12 @@ def _shifted_clm_metrics(xent_fn, hidden, tokens, loss_mask):
         mask = jnp.ones_like(nll)
     else:
         mask = loss_mask[:, 1:].reshape(-1).astype(jnp.float32)
+    return _masked_mean_metrics(nll, correct, mask)
+
+
+def _masked_mean_metrics(nll, correct, mask):
+    """Per-row ``nll`` / ``correct`` under a float ``mask`` -> the contract
+    of models/loss.clm_loss_and_metrics."""
     nmask = jnp.maximum(mask.sum(), 1.0)
     loss = (nll * mask).sum() / nmask
     acc = (correct.astype(jnp.float32) * mask).sum() / nmask
@@ -326,3 +343,71 @@ def chunked_clm_loss_and_metrics(
         lambda h, lab: chunked_softmax_xent(h, emb, lab, n_chunks, emb_layout,
                                             valid_v),
         hidden, tokens, loss_mask)
+
+
+def fused_kernel_applies(d: int, dtype) -> bool:
+    """True when :func:`tied_head_clm_loss_and_metrics` takes the kernel
+    pair for such hidden states (the rule is in the module doc)."""
+    from distributed_lion_tpu.ops.pallas_xent import kernel_takes
+
+    return jax.default_backend() == "tpu" and kernel_takes(d, dtype)
+
+
+@jax.named_scope("xent")
+def _fused_clm_loss_and_metrics(hidden, head, tokens, loss_mask, valid_v,
+                                tiles=None, interpret=False):
+    """The kernel path: every position is a row (a free reshape where
+    ``hidden[:, :-1]`` is a copy); a sequence's last position has no label
+    and weighs nothing. ``tiles`` and ``interpret`` are the tests' (small
+    tiles through the Pallas interpreter on the CPU)."""
+    from distributed_lion_tpu.ops.pallas_xent import fused_xent, tiles_for
+    from distributed_lion_tpu.train import journal
+
+    b, t, d = hidden.shape
+    v = valid_v if valid_v > 0 else head.shape[0]
+    tiles = tiles or tiles_for(b * t, v)
+    name = jnp.dtype(hidden.dtype).name
+    journal.resolved(
+        "xent_resolved",
+        f"[setup] cross-entropy: tied head auto -> pallas_fused_xent (rows "
+        f"{b * t}, vocab {v}, d {d}, {name}, tiles %dx%d)" % tiles,
+        impl="pallas_fused_xent", rows=b * t, vocab=v, d=d, dtype=name,
+        tiles="%dx%d" % tiles)
+    last = jnp.zeros((b, 1), jnp.float32)
+    labels = jnp.concatenate(
+        [tokens[:, 1:], last.astype(tokens.dtype)], axis=1).reshape(-1)
+    mask = (jnp.ones((b, t - 1), jnp.float32) if loss_mask is None
+            else loss_mask[:, 1:].astype(jnp.float32))
+    mask = jnp.concatenate([mask, last], axis=1).reshape(-1)
+    nll, pred = fused_xent(hidden.reshape(b * t, d),
+                           head.astype(hidden.dtype), labels, valid_v, tiles,
+                           interpret)
+    return _masked_mean_metrics(nll, pred == labels, mask)
+
+
+def tied_head_clm_loss_and_metrics(
+    hidden: jnp.ndarray,
+    head: jnp.ndarray,
+    tokens: jnp.ndarray,
+    loss_mask: jnp.ndarray | None = None,
+    valid_v: int = 0,
+) -> tuple[jnp.ndarray, dict]:
+    """Shift-by-one CLM loss from FINAL HIDDEN STATES ``[B, T, d]`` and the
+    tied head as it lies (``[V, d]``, whole on this device): the dense
+    path's loss head, with the return contract of
+    models/loss.clm_loss_and_metrics (a masked position gives no loss and
+    no gradient). ``valid_v`` marks a padded head's alignment rows. Where
+    :func:`fused_kernel_applies` the logits stay in VMEM; elsewhere they
+    are the einsum ``gpt2_apply`` makes, sliced to ``valid_v``."""
+    from distributed_lion_tpu.models.loss import clm_loss_and_metrics
+
+    if fused_kernel_applies(hidden.shape[-1], hidden.dtype):
+        return _fused_clm_loss_and_metrics(hidden, head, tokens, loss_mask,
+                                           valid_v)
+    with jax.named_scope("head"):
+        logits = jnp.einsum(
+            "btd,vd->btv", hidden, head.astype(hidden.dtype),
+            preferred_element_type=jnp.float32)
+    if valid_v > 0:
+        logits = logits[..., :valid_v]
+    return clm_loss_and_metrics(logits, tokens, loss_mask)

@@ -481,6 +481,35 @@ def event(name: str, **fields) -> None:
         _ACTIVE.event(name, **fields)
 
 
+# What a module's `auto` resolved to while a program was traced (ops/attention,
+# ops/xent): recorded once a resolution, said by whoever drives the program
+# (Trainer.train prints :func:`new_resolved_lines` after a dispatch that
+# traced).
+_RESOLVED: dict = {}
+_resolved_said = 0
+
+
+def resolved(name: str, line: str, **fields) -> None:
+    """Record that a module resolved its `auto` to ``fields``: once, a
+    ``name`` event in the active journal and ``line`` for
+    :func:`new_resolved_lines`."""
+    key = (name, tuple(sorted(fields.items())))
+    if key not in _RESOLVED:
+        _RESOLVED[key] = line
+        event(name, **fields)
+
+
+def new_resolved_lines() -> list:
+    """The ``[setup]`` lines of the resolutions recorded since the last
+    call (an integer compare when there is none)."""
+    global _resolved_said
+    if _resolved_said == len(_RESOLVED):
+        return []
+    fresh = list(_RESOLVED.values())[_resolved_said:]
+    _resolved_said = len(_RESOLVED)
+    return fresh
+
+
 # ------------------------------------------------------------ the span gate
 # jax.profiler.TraceAnnotation, handed in by whoever imports jax (Trainer,
 # ServingEngine): this module stays importable without it.

@@ -33,9 +33,15 @@ import numpy as np
 from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from distributed_lion_tpu.models.gpt2 import GPT2Config, count_params, gpt2_apply, gpt2_init
+from distributed_lion_tpu.models.gpt2 import (
+    GPT2Config,
+    count_params,
+    gpt2_apply,
+    gpt2_hidden,
+    gpt2_init,
+)
 from distributed_lion_tpu.models.loss import clm_loss_and_metrics
-from distributed_lion_tpu.ops import attention as attention_ops
+from distributed_lion_tpu.ops import xent as xent_ops
 from distributed_lion_tpu.ops.codec import vote_chunk_elems, wire_bytes_per_param
 from distributed_lion_tpu.optim import (
     distributed_lion,
@@ -377,6 +383,21 @@ def apply_remat_policy(cfg: "TrainConfig", model_cfg):
             "remat=False — the policy would silently never apply; drop "
             "the override or enable remat")
     return dataclasses.replace(model_cfg, remat_policy=cfg.remat_policy)
+
+
+def gpt2_dense_loss(model_cfg: GPT2Config, tp_axis: Optional[str] = None):
+    """The loss of ``Trainer.for_gpt2``'s dense branch (no MoE, no vocab or
+    sequence axis, no vocab chunks): the backbone's hidden states and the
+    tied head as it lies go to ``ops/xent``'s loss head, which keeps the
+    logits out of HBM where its kernels apply and computes what
+    ``gpt2_apply`` + ``clm_loss_and_metrics`` compute everywhere else."""
+    def loss_fn(params, batch, dropout_key):
+        hidden, _ = gpt2_hidden(params, batch, model_cfg,
+                                dropout_key=dropout_key, tp_axis=tp_axis)
+        return xent_ops.tied_head_clm_loss_and_metrics(
+            hidden, params["wte"], batch, valid_v=model_cfg.vocab_size)
+
+    return loss_fn
 
 
 def validate_seq_block(cfg: "TrainConfig", model_cfg, sp: int) -> None:
@@ -1625,12 +1646,12 @@ class Trainer:
                     )
                 self.step_count += 1
                 advanced = 1
-            for line in (attention_ops.new_resolved_lines()
+            for line in (journal.new_resolved_lines()
                          + compile_cache.new_lines()):
-                # what attention `auto` resolved to while the program was
-                # traced; which program was traced, lowered, compiled or
-                # loaded: said once, when it happens (the first dispatch;
-                # a retrace later in the run)
+                # what attention `auto` and the loss head resolved to while
+                # the program was traced; which program was traced, lowered,
+                # compiled or loaded: said once, when it happens (the first
+                # dispatch; a retrace later in the run)
                 emit(line)
             self.profiler.maybe_stop(self.step_count, sync=metrics)
             if self._guard is not None:
@@ -2662,6 +2683,9 @@ class Trainer:
                     valid_v=model_cfg.vocab_size)
 
             loss_fn._vocab_chunked = True  # consumed; don't trip the guard
+
+        elif loss_fn is None:
+            loss_fn = gpt2_dense_loss(model_cfg, tp_axis)
 
         return Trainer(cfg, mesh, apply_fn, params, param_specs=param_specs,
                        loss_fn=loss_fn, batch_spec=batch_spec)

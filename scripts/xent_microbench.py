@@ -1,0 +1,86 @@
+"""Time the loss head's kernel pair alone on the chip at a train cell's
+shape: ``python scripts/xent_microbench.py [sequences] [tnxtv[xgroupMB]...]``.
+Forward and forward + backward (a vjp with a given cotangent: kernels and
+the label's gathered product only) against the dense einsum + log_softmax
+at the same rows, and the error against it. A chip-only tool."""
+import sys
+import time
+
+import jax
+import jax.numpy as jnp
+
+from distributed_lion_tpu.ops import pallas_xent as X
+
+V, D, T = 50257, 768, 1024
+
+
+def dense(h, w, labels):
+    logits = jnp.einsum("nd,vd->nv", h, w, preferred_element_type=jnp.float32)
+    logp = jax.nn.log_softmax(logits, axis=-1)
+    nll = -jnp.take_along_axis(logp, labels[:, None], axis=-1)[:, 0]
+    return nll, logits.argmax(-1).astype(jnp.int32)
+
+
+def timed(fn, *args, n=10):
+    jax.block_until_ready(fn(*args))
+    jax.block_until_ready(fn(*args))
+    t0 = time.perf_counter()
+    for _ in range(n):
+        out = fn(*args)
+    jax.block_until_ready(out)
+    return (time.perf_counter() - t0) / n * 1e3
+
+
+def rel(a, b):
+    a, b = a.astype(jnp.float32), b.astype(jnp.float32)
+    return float(jnp.linalg.norm(a - b) / jnp.linalg.norm(b))
+
+
+def main():
+    if jax.default_backend() != "tpu":
+        raise SystemExit("xent_microbench needs a TPU")
+    B = int(sys.argv[1]) if len(sys.argv) > 1 else 20
+    specs = sys.argv[2:] or ["x".join(map(str, X.tiles_for(B * T, V)))]
+    n = B * T
+    h = jax.random.normal(jax.random.key(0), (n, D), jnp.bfloat16)
+    w = (jax.random.normal(jax.random.key(1), (V, D)) * 0.05
+         ).astype(jnp.bfloat16)
+    labels = jax.random.randint(jax.random.key(2), (n,), 0, V)
+    g = jax.random.uniform(jax.random.key(3), (n,)) / n
+    flop = 2 * n * D * V
+
+    def vjp_of(fn):
+        return jax.jit(lambda h, w, g: jax.vjp(
+            lambda h, w: fn(h, w)[0], h, w)[1](g))
+
+    ref = lambda h, w: dense(h, w, labels)                 # noqa: E731
+    f0 = timed(jax.jit(ref), h, w)
+    fb0 = timed(vjp_of(ref), h, w, g)
+    print(f"rows {n}: dense fwd {f0:.2f} ms, fwd+bwd {fb0:.2f} ms; one "
+          f"product at 197 TFLOP/s {flop / 197e9:.2f} ms", flush=True)
+    want, want_idx = jax.jit(ref)(h, w)
+    want_dh, want_dw = vjp_of(ref)(h, w, g)
+    for spec in specs:
+        tn, tv, *mb = (int(x) for x in spec.split("x"))
+        X.DH_VMEM_BYTES = (mb[0] if mb else 32) << 20
+        mine = lambda h, w: X.fused_xent(h, w, labels, 0, (tn, tv))  # noqa: E731
+        try:
+            f = timed(jax.jit(mine), h, w)
+            fb = timed(vjp_of(mine), h, w, g)
+        except Exception as e:  # a refused tile pair: say so, try the next
+            print(f"tiles {spec}: {type(e).__name__}: {str(e)[:300]}",
+                  flush=True)
+            continue
+        got, idx = jax.jit(mine)(h, w)
+        dh, dw = vjp_of(mine)(h, w, g)
+        print(f"tiles {spec} (groups {X.row_groups(n, D, tn)[0]}): fwd "
+              f"{f:.2f} ms ({flop / f / 1.97e9:.1f}% of peak), fwd+bwd "
+              f"{fb:.2f} ms (bwd {fb - f:.2f}: {3 * flop / (fb - f) / 1.97e9:.1f}%"
+              f" of peak over its 3 products); nll max err "
+              f"{float(jnp.abs(got - want).max()):.2e}, argmax differs "
+              f"{int((idx != want_idx).sum())}, dh rel {rel(dh, want_dh):.2e},"
+              f" dw rel {rel(dw, want_dw):.2e}", flush=True)
+
+
+if __name__ == "__main__":
+    main()

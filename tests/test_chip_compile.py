@@ -335,6 +335,129 @@ def test_train_blocks_copy_no_attention_activation(train_blocks_hlo, B):
 
 
 # ------------------------------------------------------ paged serving engine
+# ------------------------------------------------- the loss head (ops/xent)
+# tests/benchmark hold the harness to its string, not to its module
+XENT_KERNELS = r"fused_xent"
+
+
+@pytest.mark.parametrize("rows,valid_v", [(20 * 1024, 0), (4 * 1024, 0),
+                                          (3 * 1023, 50257)],
+                         ids=["readme-20x8", "vote-4x2", "ragged-padded"])
+def test_fused_xent_kernels_compile(one_chip, rows, valid_v):
+    """The kernel pair alone at both training cells' rows against the
+    published head ``[50257, 768]`` as it lies (the last vocabulary tile a
+    partial block), and at rows that are no multiple of a block under a
+    head padded to 50,304 rows: Mosaic takes the tiles, the 31.5 MB float32
+    ``dh`` scratch and the index maps."""
+    from distributed_lion_tpu.ops import pallas_xent
+
+    def s(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    def grads(h, w, labels, g):
+        def loss(h, w):
+            nll, _ = pallas_xent.fused_xent(h, w, labels, valid_v)
+            return (nll * g).sum()
+        return jax.grad(loss, argnums=(0, 1))(h, w)
+
+    text, _ = _compile(grads, s((rows, 768), jnp.bfloat16),
+                       s((50304 if valid_v else 50257, 768), jnp.bfloat16),
+                       s((rows,), jnp.int32), s((rows,), jnp.float32))
+    for kernel in ("fused_xent_fwd", "fused_xent_bwd"):
+        assert _named_custom_call(text, kernel), kernel
+
+
+@pytest.fixture(scope="module")
+def train_step_hlo(one_chip):
+    """Loss and gradients of the trainer's dense branch
+    (``train/loop.gpt2_dense_loss``: what ``Trainer.for_gpt2`` steps at both
+    training cells) for a GPT-2 of the published widths and vocabulary cut
+    to two layers, at a cell's microbatch, compiled for the described chip
+    with every ``auto`` resolved as a TPU backend resolves it."""
+    from distributed_lion_tpu.models import gpt2
+    from distributed_lion_tpu.train.loop import gpt2_dense_loss
+
+    texts = {}
+
+    def compiled(B):
+        if B not in texts:
+            cfg = gpt2.GPT2Config.gpt2_124m(n_layer=2, dropout=0.0)
+            params = jax.tree.map(
+                lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                               sharding=one_chip),
+                jax.eval_shape(lambda: gpt2.gpt2_init(jax.random.key(0), cfg)))
+            tokens = jax.ShapeDtypeStruct((B, 1024), jnp.int32,
+                                          sharding=one_chip)
+            loss = gpt2_dense_loss(cfg)
+            real = jax.default_backend
+            jax.default_backend = lambda: "tpu"
+            try:
+                texts[B], _ = _compile(
+                    jax.value_and_grad(lambda p, t: loss(p, t, None),
+                                       has_aux=True), params, tokens)
+            finally:
+                jax.default_backend = real
+        return texts[B]
+
+    return compiled
+
+
+@pytest.mark.parametrize("B", [20, 4], ids=["readme-20x8", "vote-4x2"])
+def test_train_step_holds_the_loss_head_kernels(train_step_hlo, B):
+    """One forward and one backward kernel, under names the benchmark's
+    pattern matches (``xent_ms.train`` and ``xent_roofline`` read the
+    device ops by it)."""
+    names = re.findall(r'%(\S+) = [^\n]*custom_call_target="tpu_custom_call"',
+                       train_step_hlo(B))
+    kinds = [re.sub(r"\.\d+$", "", n) for n in names
+             if re.search(XENT_KERNELS, n)]
+    assert sorted(kinds) == ["fused_xent_bwd", "fused_xent_fwd"], names
+
+
+@pytest.mark.parametrize("B", [20, 4], ids=["readme-20x8", "vote-4x2"])
+def test_train_step_holds_no_float32_logits(train_step_hlo, B):
+    """No float32 buffer of the logits' shape, in any instruction, fused
+    computations included: ``[B, 1023 | 1024, 50257]`` (4.11 GB at B = 20,
+    two of them alive at the parent's peak) or its rows flattened."""
+    text = train_step_hlo(B)
+    rows = "|".join(str(B * t) for t in (1023, 1024))
+    logits = re.findall(r"f32\[(?:%d,(?:1023|1024)|%s),50\d\d\d\]"
+                        % (B, rows), text)
+    assert not logits, sorted(set(logits))
+    assert not re.search(r"\[[\d,]*50257\]", text), "a vocabulary-minor buffer"
+
+
+def test_loss_head_compiles_under_the_workers_shard_map(topo, monkeypatch):
+    """Cell 4's path: the loss head inside a ``shard_map`` over the four
+    chips' ``data`` axis, 4 sequences a worker, the head replicated: each
+    worker runs the kernel pair on its own rows, no collective among them,
+    no float32 logits on any."""
+    from distributed_lion_tpu.ops import xent as xent_ops
+
+    mesh = Mesh(np.array(topo.devices).reshape(4), ("data",))
+    repl, split = NamedSharding(mesh, P()), NamedSharding(mesh, P("data"))
+    hidden = jax.ShapeDtypeStruct((16, 1024, 768), jnp.bfloat16,
+                                  sharding=split)
+    head = jax.ShapeDtypeStruct((50257, 768), jnp.float32, sharding=repl)
+    tokens = jax.ShapeDtypeStruct((16, 1024), jnp.int32, sharding=split)
+
+    def worker(h, w, t):
+        dh, dw = jax.grad(
+            lambda h, w: xent_ops.tied_head_clm_loss_and_metrics(h, w, t)[0],
+            argnums=(0, 1))(h, w)
+        return dh, dw[None]                     # a worker's own gradient
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    step = jax.shard_map(worker, mesh=mesh,
+                         in_specs=(P("data"), P(), P("data")),
+                         out_specs=(P("data"), P("data")), check_vma=False)
+    text, _ = _compile(step, hidden, head, tokens)
+    assert _named_custom_call(text, "fused_xent_fwd")
+    assert _named_custom_call(text, "fused_xent_bwd")
+    assert not re.search(r"all-(reduce|gather|to-all)", text)
+    assert not re.search(r"f32\[[\d,]*50257\]", text)
+
+
 @pytest.mark.parametrize("kind", ["decode_tick", "prefill_bucket"])
 def test_paged_decode_compiles_with_donated_pool(one_chip, kind):
     """gpt2_decode_paged at 124M widths with the page pool donated — the

@@ -10,6 +10,7 @@ import pytest
 
 from distributed_lion_tpu.ops import attention as A
 from distributed_lion_tpu.ops import pallas_flash_attn as F
+from distributed_lion_tpu.train import journal
 
 FLASH_TOL = 2e-2   # chip_smoke.py's: |kernel - xla| on bf16 values of O(1)
 
@@ -143,8 +144,8 @@ def spy(monkeypatch):
     monkeypatch.setattr(F, "flash_qkv", kernel)
     monkeypatch.setattr(A, "attention_flash", flash)
     monkeypatch.setattr(A, "attention_xla", xla)
-    monkeypatch.setattr(A, "_RESOLVED", {})
-    monkeypatch.setattr(A, "_resolved_said", 0)
+    monkeypatch.setattr(journal, "_RESOLVED", {})
+    monkeypatch.setattr(journal, "_resolved_said", 0)
     return calls
 
 
@@ -179,12 +180,12 @@ def test_auto_takes_the_kernel_on_a_tpu(spy, monkeypatch, where):
     out = _trace_qkv(B, T, H, hd, dtype)
     assert out.shape == (B, T, H * hd)
     assert spy == [("kernel", (B, T, 3 * H * hd), H)]
-    (line,) = A.new_resolved_lines()
+    (line,) = journal.new_resolved_lines()
     blk = F.block_for(T)
     assert line == (f"[setup] attention: qkv auto -> pallas_flash_attn "
                     f"(T {T}, head_dim {hd}, {jnp.dtype(dtype).name}, "
                     f"tiles {blk}x{blk})")
-    assert A.new_resolved_lines() == []       # said once
+    assert journal.new_resolved_lines() == []       # said once
 
 
 AWAY = {  # why: backend, T, H, head_dim, the call's options, where it goes
@@ -248,10 +249,10 @@ def test_head_major_resolution_is_said_once(spy, monkeypatch):
     x = jax.ShapeDtypeStruct((1, 32, 2048, 128), jnp.bfloat16)
     for _ in range(2):
         jax.eval_shape(lambda q, k, v: A.attention(q, k, v), x, x, x)
-    assert A.new_resolved_lines() == [
+    assert journal.new_resolved_lines() == [
         "[setup] attention: head-major auto -> flash (T 2048, head_dim 128, "
         "bfloat16, tiles default)"]
-    assert A.new_resolved_lines() == []
+    assert journal.new_resolved_lines() == []
 
 
 def test_dropout_keeps_the_xla_scores(spy, monkeypatch):
@@ -275,8 +276,6 @@ def test_dropout_keeps_the_xla_scores(spy, monkeypatch):
 
 
 def test_resolution_reaches_the_journal(spy, monkeypatch, tmp_path):
-    from distributed_lion_tpu.train import journal
-
     monkeypatch.setattr(A.jax, "default_backend", lambda: "tpu")
     j = journal.Journal(str(tmp_path))
     journal.install(j)

@@ -1,0 +1,296 @@
+"""The loss head's kernel pair (ops/pallas_xent), through Pallas interpret
+mode at small sizes, against ``clm_loss_and_metrics`` on dense
+float32-accumulated logits: loss, accuracy, n_tokens and both gradients;
+and the rule by which ``ops/xent.tied_head_clm_loss_and_metrics`` takes the
+kernels, from what a call shows (never an option)."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from distributed_lion_tpu.models.loss import clm_loss_and_metrics
+from distributed_lion_tpu.ops import pallas_xent as PX
+from distributed_lion_tpu.ops import xent as X
+
+TILES = (128, 128)
+
+
+def _dense(hidden, head, tokens, mask, valid_v):
+    logits = jnp.einsum("btd,vd->btv", hidden, head.astype(hidden.dtype),
+                        preferred_element_type=jnp.float32)
+    return clm_loss_and_metrics(logits[..., :valid_v or None], tokens, mask)
+
+
+def _fused(hidden, head, tokens, mask, valid_v, tiles=TILES):
+    return X._fused_clm_loss_and_metrics(hidden, head, tokens, mask, valid_v,
+                                         tiles, True)
+
+
+def _case(B, T, V, d, dtype, *, valid_v=0, mask=None, seed=0):
+    kh, kw, kt = jax.random.split(jax.random.key(seed), 3)
+    hidden = jax.random.normal(kh, (B, T, d), dtype)
+    head = jax.random.normal(kw, (V, d), jnp.float32) * 0.3
+    tokens = jax.random.randint(kt, (B, T), 0, valid_v or V)
+    return hidden, head, tokens, mask, valid_v
+
+
+def _whole_rows_off(B, T):
+    m = np.ones((B, T), np.float32)
+    m[1] = 0.0                      # a whole sequence off
+    m[0, : T // 2] = 0.0            # and half of another
+    return jnp.asarray(m)
+
+
+def _close(got, want, f32):
+    """float32: element by element. bfloat16: both sides round their
+    float32 sums to 8 bits, in another order: the whole gradient to 1%, an
+    element to a hundredth of the largest."""
+    got, want = (np.asarray(x, np.float32) for x in (got, want))
+    if f32:
+        np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-7)
+        return
+    assert np.linalg.norm(got - want) <= 1e-2 * np.linalg.norm(want)
+    assert np.abs(got - want).max() <= 1e-2 * np.abs(want).max()
+
+
+CASES = {
+    # vocabulary neither a multiple of the tile nor of 128; rows a multiple
+    "ragged_vocab": dict(B=2, T=128, V=333, d=128),
+    # rows (2 x 75 = 150) not a multiple of the 128-row tile
+    "ragged_rows": dict(B=2, T=75, V=256, d=128),
+    "both_ragged": dict(B=3, T=50, V=300, d=256),
+    "one_tile": dict(B=1, T=64, V=100, d=128),
+    "masked_rows": dict(B=3, T=64, V=333, d=128, mask=True),
+    # a padded head: rows 321.. of 384 are alignment padding
+    "padded_head": dict(B=2, T=64, V=384, d=128, valid_v=321),
+    # padding that fills whole tiles (they are never visited)
+    "padded_tiles": dict(B=2, T=64, V=512, d=128, valid_v=200),
+}
+
+
+def _build(name, dtype):
+    kw = dict(CASES[name])
+    if kw.pop("mask", False):
+        kw["mask"] = _whole_rows_off(kw["B"], kw["T"])
+    return _case(dtype=dtype, **kw)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("name", sorted(CASES))
+def test_kernel_pair_matches_the_dense_loss(name, dtype):
+    args = _build(name, dtype)
+    hidden, head = args[:2]
+
+    def grads(fn):
+        (loss, metrics), g = jax.value_and_grad(
+            lambda h, w: fn(h, w, *args[2:]), argnums=(0, 1),
+            has_aux=True)(hidden, head)
+        return loss, metrics, g
+
+    loss, m, (dh, dw) = grads(_fused)
+    loss0, m0, (dh0, dw0) = grads(_dense)
+    f32 = dtype == jnp.float32
+    np.testing.assert_allclose(loss, loss0, rtol=2e-6 if f32 else 2e-5)
+    assert m["n_tokens"] == m0["n_tokens"]
+    # bf16 logits tie more often and a tile's sum order can move one; the
+    # float32 argmax is the dense one exactly
+    np.testing.assert_allclose(m["accuracy"], m0["accuracy"],
+                               atol=0 if f32 else 2.0 / m0["n_tokens"])
+    assert dh.dtype == hidden.dtype and dw.dtype == head.dtype
+    _close(dh, dh0, f32)
+    _close(dw, dw0, f32)
+    valid_v = args[4]
+    if valid_v:                     # a padded head's pad rows: no gradient
+        assert not np.asarray(dw[valid_v:]).any()
+
+
+def test_masked_rows_give_no_loss_and_no_gradient():
+    hidden, head, tokens, mask, _ = _build("masked_rows", jnp.float32)
+    dh = jax.grad(lambda h: _fused(h, head, tokens, mask, 0)[0])(hidden)
+    assert not np.asarray(dh[1]).any()                 # the sequence off
+    assert not np.asarray(dh[0, : 64 // 2 - 1]).any()  # labels masked
+    assert not np.asarray(dh[:, -1]).any()             # no label at all
+    assert np.asarray(dh[2, :-1]).any(axis=-1).all()
+    all_off = jnp.zeros_like(mask)
+    loss, m = _fused(hidden, head, tokens, all_off, 0)
+    assert loss == 0.0 and m["n_tokens"] == 0.0
+
+
+def test_label_in_the_last_partial_tile():
+    """V = 333 with tiles of 128: the last tile holds rows 256..332 and 51
+    rows of whatever the buffer held. Every label lies in it."""
+    hidden, head, tokens, _, _ = _build("ragged_vocab", jnp.float32)
+    tokens = 256 + tokens % (333 - 256)
+    loss, m = _fused(hidden, head, tokens, None, 0)
+    loss0, m0 = _dense(hidden, head, tokens, None, 0)
+    np.testing.assert_allclose(loss, loss0, rtol=2e-6)
+    assert m["accuracy"] == m0["accuracy"]
+    dw = jax.grad(lambda w: _fused(hidden, w, tokens, None, 0)[0])(head)
+    dw0 = jax.grad(lambda w: _dense(hidden, w, tokens, None, 0)[0])(head)
+    np.testing.assert_allclose(dw, dw0, rtol=1e-4, atol=1e-7)
+
+
+@pytest.mark.parametrize("pair", [(5, 200), (130, 131), (3, 300)],
+                         ids=["across_tiles", "within_a_tile", "last_tile"])
+def test_a_tie_between_two_logits_takes_the_first_index(pair):
+    """Two equal rows of the head give equal logits, and larger than any
+    other: ``argmax`` is the lower index, in one tile or across two."""
+    lo, hi = pair
+    kh, kw = jax.random.split(jax.random.key(7))
+    hidden = jax.random.normal(kh, (40, 128), jnp.float32)
+    head = jax.random.normal(kw, (333, 128), jnp.float32) * 0.01
+    top = hidden.mean(axis=0) * 50.0
+    head = head.at[lo].set(top).at[hi].set(top)
+    labels = jnp.zeros((40,), jnp.int32)
+    _, idx = PX.fused_xent(hidden, head, labels, 0, TILES, True)
+    dense = jnp.einsum("nd,vd->nv", hidden, head).argmax(-1)
+    np.testing.assert_array_equal(idx, dense)
+    assert (np.asarray(idx) == lo).sum() > 20      # the tie decided rows
+
+
+def test_tiles_and_row_groups():
+    assert PX.tiles_for(20 * 1024, 50257) == (1024, 512)
+    assert PX.tiles_for(4 * 1024, 50257) == (1024, 512)
+    assert PX.tiles_for(150, 100) == (256, 128)
+    # 20 x 1,024 rows of 768 float32: 63 MB of dh in two groups of ten
+    # blocks; 4 x 1,024 in one
+    assert PX.row_groups(20 * 1024, 768, 1024) == (2, 10)
+    assert PX.row_groups(4 * 1024, 768, 1024) == (1, 4)
+    assert PX.row_groups(150, 128, 128) == (1, 2)
+
+
+def test_more_rows_than_one_group_holds(monkeypatch):
+    """Three row groups (the float32 dh of one block each), the last padded:
+    partial head gradients a group, summed."""
+    monkeypatch.setattr(PX, "DH_VMEM_BYTES", 128 * 128 * 4)
+    hidden, head, tokens, _, _ = _case(1, 300, 200, 128, jnp.float32)
+    assert PX.row_groups(300, 128, 128) == (3, 1)
+    g = jax.grad(lambda h, w: _fused(h, w, tokens, None, 0)[0], (0, 1))
+    g0 = jax.grad(lambda h, w: _dense(h, w, tokens, None, 0)[0], (0, 1))
+    for a, b in zip(g(hidden, head), g0(hidden, head)):
+        np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-7)
+
+
+# ------------------------------------------------------------ the entry's rule
+@pytest.mark.parametrize("backend,d,dtype,takes", [
+    ("tpu", 768, jnp.bfloat16, True),       # both training cells
+    ("tpu", 1600, jnp.bfloat16, False),     # GPT-2 XL: 12.5 lane blocks
+    ("tpu", 768, jnp.float32, False),
+    ("tpu", 64, jnp.bfloat16, False),       # the tiny preset
+    ("cpu", 768, jnp.bfloat16, False),
+    ("gpu", 768, jnp.bfloat16, False),
+])
+def test_kernel_applies_from_what_a_call_shows(monkeypatch, backend, d, dtype,
+                                               takes):
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    assert X.fused_kernel_applies(d, dtype) is takes
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("valid_v", [0, 321])
+def test_on_the_cpu_the_entry_is_the_dense_path_bit_for_bit(dtype, valid_v):
+    hidden, head, tokens, _, _ = _case(2, 32, 384, 128, dtype,
+                                       valid_v=valid_v)
+    mask = _whole_rows_off(2, 32)
+
+    def both(fn):
+        (loss, m), g = jax.jit(jax.value_and_grad(
+            lambda h, w: fn(h, w, tokens, mask, valid_v), argnums=(0, 1),
+            has_aux=True))(hidden, head)
+        return [loss, m["accuracy"], m["n_tokens"], *g]
+
+    for a, b in zip(both(X.tied_head_clm_loss_and_metrics), both(_dense)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_on_a_tpu_backend_the_entry_takes_the_kernels_and_says_so(monkeypatch,
+                                                                  tmp_path):
+    from distributed_lion_tpu.train import journal
+
+    seen, real = {}, PX.fused_xent
+
+    def fake(h, w, labels, valid_v, tiles, interpret):
+        seen.update(rows=h.shape[0], valid_v=valid_v, w=w.dtype)
+        return real(h, w, labels, valid_v, TILES, True)
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(PX, "fused_xent", fake)
+    hidden, head, tokens, _, _ = _case(2, 64, 333, 128, jnp.bfloat16)
+    j = journal.Journal(str(tmp_path))
+    journal.install(j)
+    try:
+        loss, m = X.tied_head_clm_loss_and_metrics(hidden, head, tokens)
+        X.tied_head_clm_loss_and_metrics(hidden, head, tokens)  # said once
+        events = [r for r in j.records() if r.get("name") == "xent_resolved"]
+    finally:
+        journal.uninstall(j)
+        j.close()
+    assert seen == {"rows": 128, "valid_v": 0, "w": jnp.bfloat16}
+    loss0, m0 = _dense(hidden, head, tokens, None, 0)
+    np.testing.assert_allclose(loss, loss0, rtol=2e-5)
+    assert m["n_tokens"] == m0["n_tokens"] == 2 * 63
+    assert [(e["impl"], e["rows"], e["vocab"], e["d"], e["dtype"])
+            for e in events] == [("pallas_fused_xent", 128, 333, 128,
+                                  "bfloat16")]
+    lines = journal.new_resolved_lines()
+    assert any(line.startswith(
+        "[setup] cross-entropy: tied head auto -> pallas_fused_xent "
+        "(rows 128, vocab 333, d 128, bfloat16, tiles ") for line in lines)
+    assert journal.new_resolved_lines() == []    # said once
+
+
+def _trainer(monkeypatch, mesh_kw, **cfg_kw):
+    """``Trainer.for_gpt2`` at the tiny preset with the dense branch's loss
+    builder and the kernel entry replaced by recorders."""
+    from distributed_lion_tpu.models.gpt2 import GPT2Config
+    from distributed_lion_tpu.parallel.mesh import make_mesh
+    from distributed_lion_tpu.train import loop
+
+    calls = []
+    real = loop.gpt2_dense_loss
+    monkeypatch.setattr(loop, "gpt2_dense_loss",
+                        lambda *a: calls.append("dense") or real(*a))
+    monkeypatch.setattr(
+        X, "_fused_clm_loss_and_metrics",
+        lambda *a, **k: pytest.fail("the kernel path was reached"))
+    cfg = loop.TrainConfig(lion=True, per_device_train_batch_size=2,
+                           gradient_accumulation_steps=1, block_size=32,
+                           max_steps=1, **cfg_kw)
+    n = int(np.prod(list(mesh_kw.values())))
+    t = loop.Trainer.for_gpt2(cfg, make_mesh(devices=jax.devices()[:n],
+                                             **mesh_kw), GPT2Config.tiny())
+    return t, calls
+
+
+@pytest.mark.parametrize("mesh_kw,cfg_kw", [
+    (dict(data=2, tensor=2), dict(tp_vocab=True)),
+    (dict(data=2, seq=2), dict()),
+    (dict(data=2), dict(vocab_chunks=4)),
+    (dict(data=2, seq=2), dict(vocab_chunks=4)),
+], ids=["tp_vocab", "seq_axis", "vocab_chunks", "seq_axis+vocab_chunks"])
+def test_other_head_strategies_never_reach_the_entry(monkeypatch, mesh_kw,
+                                                     cfg_kw):
+    """``tp_vocab``, a sequence axis and ``vocab_chunks > 0`` keep their own
+    losses: the dense branch's builder is not called, and one train step
+    on a backend that says "tpu" never enters the kernel path."""
+    from distributed_lion_tpu.data.sources import (
+        batch_iterator,
+        synthetic_lm_dataset,
+    )
+
+    t, calls = _trainer(monkeypatch, mesh_kw, **cfg_kw)
+    assert calls == []
+    blocks = synthetic_lm_dataset(64, 32, 256)
+    monkeypatch.setattr(X, "fused_kernel_applies", lambda *a: True)
+    t.train(batch_iterator(blocks, t.global_train_batch(), seed=1),
+            max_steps=1)
+    t.close()
+
+
+def test_the_dense_branch_builds_its_loss_from_the_entry(monkeypatch):
+    t, calls = _trainer(monkeypatch, dict(data=2))
+    assert calls == ["dense"]
+    t.close()
