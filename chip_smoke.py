@@ -20,7 +20,9 @@ from __future__ import annotations
 import argparse
 import contextlib
 import json
+import math
 import os
+import re
 import shutil
 import sys
 import tempfile
@@ -458,19 +460,23 @@ def assert_pool_in_place(engine, kernels=("paged_attn",)) -> None:
         pool_leaf_copies,
     )
 
-    leaf = next(iter(engine.pages[0].values()))
+    # one leaf of every shape the pool holds (a window family: pages, rings)
+    leaves = list({leaf.shape: leaf for layer in engine.pages
+                   for leaf in layer.values()}.values())
     bucket = engine.cfg.block_size * engine.cfg.max_blocks_per_seq
     for kind in ("decode", "prefill", "cow"):
         lowered = lowered_dispatch(engine, kind, bucket)
-        copies = pool_leaf_copies(lowered.compile().as_text(), leaf)
-        check(not copies, f"{kind} copies the pool: {copies[:2]}")
+        text = lowered.compile().as_text()
+        for leaf in leaves:
+            copies = pool_leaf_copies(text, leaf)
+            check(not copies, f"{kind} copies the pool: {copies[:2]}")
         if kind == "decode":
             held = mosaic_kernels(lowered.as_text())
             check(set(kernels) <= set(held),
                   f"decode holds {held}, not all of {kernels}")
-    log(f"  decode, prefill@{bucket} and cow hold no copy of a pool leaf "
-        f"({leaf.dtype.name}{list(leaf.shape)}); decode holds "
-        f"{', '.join(kernels)}")
+    log(f"  decode, prefill@{bucket} and cow hold no copy of a pool leaf ("
+        + ", ".join(f"{x.dtype.name}{list(x.shape)}" for x in leaves)
+        + f"); decode holds {', '.join(kernels)}")
 
 
 def phase_serve(out_dir: str) -> None:
@@ -601,6 +607,44 @@ def phase_serve(out_dir: str) -> None:
           f"paged logits off by {worst}")
 
 
+def teacher_forced_gap(decode, fresh, prompts, gots, block) -> float:
+    """Largest |difference| of logits, teacher-forced on the served tokens,
+    between one window over prompt + served (S > 1) and the prompt's window
+    followed by one token a step (S = 1: the kernel path).
+    ``decode(toks, pages, pos, valid) -> (logits, pages)`` is the family's
+    paged hook over fixed tables; ``fresh()`` an empty pool."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    n_seq = len(prompts)
+    seqs = [p + g[:-1] for p, g in zip(prompts, gots)]
+    width = -(-max(map(len, seqs)) // block) * block
+    toks = np.zeros((n_seq, width), np.int32)
+    for row, seq in zip(toks, seqs):
+        row[:len(seq)] = seq
+    zero = jnp.zeros((n_seq,), jnp.int32)
+    whole = jax.jit(lambda t, pg: decode(t, pg, zero, None)[0])(toks, fresh())
+    plens = np.asarray([len(p) for p in prompts])
+    p_width = -(-int(plens.max()) // block) * block
+    window, pages = jax.jit(lambda t, pg: decode(
+        t, pg, zero,
+        jnp.arange(p_width)[None, :] < jnp.asarray(plens)[:, None]))(
+            toks[:, :p_width], fresh())
+    step = jax.jit(decode, donate_argnums=(1,))
+    worst = max(float(jnp.abs(window[i, n - 1] - whole[i, n - 1]).max())
+                for i, n in enumerate(plens))
+    for j in range(len(gots[0]) - 1):
+        nxt = np.asarray([g[j] for g in gots], np.int32)
+        logits, pages = step(nxt[:, None], pages,
+                             jnp.asarray(plens + j, jnp.int32),
+                             np.ones((n_seq, 1), bool))
+        for i in range(n_seq):
+            worst = max(worst, float(jnp.abs(
+                logits[i, 0] - whole[i, plens[i] + j]).max()))
+    return worst
+
+
 def phase_serve_latent() -> None:
     """The latent-cache family (models/joyai) at a small lane-aligned size
     through the constructors ``run_serve --model_family joyai`` calls: the
@@ -664,44 +708,236 @@ def phase_serve_latent() -> None:
     # (S > 1: gather, expand, chunked attention) against the prompt's window
     # then one token a step (S = 1: the absorbed kernel)
     n_seq = len(prompts)
-    gots = [done[i].tokens for i in range(n_seq)]
-    seqs = [p + g[:-1] for p, g in zip(prompts, gots)]
-    width = -(-max(map(len, seqs)) // block) * block
-    toks = np.zeros((n_seq, width), np.int32)
-    for row, seq in zip(toks, seqs):
-        row[:len(seq)] = seq
     tables = jnp.arange(n_seq * max_blocks, dtype=jnp.int32)[::-1].reshape(
         n_seq, max_blocks)
-
-    def fresh():
-        return init_page_leaves(cfg.n_layer, n_seq * max_blocks, block,
-                                model.page_leaves, cfg.compute_dtype)
-
-    zero = jnp.zeros((n_seq,), jnp.int32)
-    whole = jax.jit(lambda p, t, pg: joyai_decode_paged(
-        p, t, cfg, pg, tables, zero)[0])(params, toks, fresh())
-    plens = np.asarray([len(p) for p in prompts])
-    p_width = -(-int(plens.max()) // block) * block
-    window, pages = jax.jit(lambda p, t, pg: joyai_decode_paged(
-        p, t, cfg, pg, tables, zero,
-        jnp.arange(p_width)[None, :] < jnp.asarray(plens)[:, None]))(
-            params, toks[:, :p_width], fresh())
-    step = jax.jit(lambda p, t, pg, pos, act: joyai_decode_paged(
-        p, t, cfg, pg, tables, pos, act), donate_argnums=(2,))
-    worst = max(float(jnp.abs(window[i, n - 1] - whole[i, n - 1]).max())
-                for i, n in enumerate(plens))
-    for j in range(SERVE_NEW_TOKENS - 1):
-        nxt = np.asarray([g[j] for g in gots], np.int32)
-        logits, pages = step(params, nxt[:, None], pages,
-                             jnp.asarray(plens + j, jnp.int32),
-                             np.ones((n_seq, 1), bool))
-        for i in range(n_seq):
-            worst = max(worst, float(jnp.abs(
-                logits[i, 0] - whole[i, plens[i] + j]).max()))
+    worst = teacher_forced_gap(
+        lambda t, pg, pos, valid: joyai_decode_paged(
+            params, t, cfg, pg, tables, pos, valid),
+        lambda: init_page_leaves(cfg.n_layer, n_seq * max_blocks, block,
+                                 model.page_leaves, cfg.compute_dtype),
+        prompts, [done[i].tokens for i in range(n_seq)], block)
     log(f"  absorbed kernel path vs expanded gather path, logits "
         f"teacher-forced on the served tokens: max |diff| {worst:.5f} "
         f"(tol {LOGIT_TOL})")
     check(worst <= LOGIT_TOL, f"latent decode logits off by {worst}")
+
+
+def phase_serve_window() -> None:
+    """The window-and-full family (models/laguna) at a small lane-aligned
+    size through the constructors ``run_serve --model_family laguna``
+    calls: the decode tick holds ``paged_attn`` for both layer kinds (one
+    call a layer) and ``moe_gmm`` over the banks held, no dispatch copies a
+    page leaf or a ring; the counters say what a window layer's walk read
+    against a full layer's and how many picks were held here; and the S = 1
+    kernel path over ring and pages gives the logits of one prefill window
+    over the same tokens (fresh keys, banded). Then the ring's walk at the
+    published shape, held to the position (:func:`ring_edges_at_size`)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from distributed_lion_tpu.analysis.serve_check import lowered_dispatch
+    from distributed_lion_tpu.models.laguna import (
+        LagunaConfig, Rope, laguna_decode_paged, laguna_init,
+    )
+    from distributed_lion_tpu.serve.engine import (
+        Request, ServeConfig, ServeModel, ServingEngine,
+    )
+    from distributed_lion_tpu.ops.attention import ring_pages
+    from distributed_lion_tpu.serve.kv_cache import init_page_leaves
+
+    cfg = LagunaConfig.tiny(
+        vocab_size=1024, d_model=256, n_kv_head=2, head_dim=64,
+        heads=(4, 6, 6, 6, 4), window=40, d_ff=512, moe_d_ff=128,
+        shared_d_ff=128, held=(0, 4),
+        rope_full=Rope(5e5, 32, 128.0, 64, 32.0, 1.0, 1.4852030263919618),
+        rope_window=Rope(1e4, 64))
+    params = laguna_init(jax.random.key(30), cfg)
+    block, max_blocks = 16, 8
+    ring = ring_pages(cfg.window, block)                      # 4 pages
+    model = ServeModel.for_laguna(params, cfg)
+    engine = ServingEngine(model, ServeConfig(
+        max_seqs=4, block_size=block, max_blocks_per_seq=max_blocks,
+        moe_stats=True, prefill_cap_tokens=128))
+    rng = np.random.default_rng(30)
+    prompts = [rng.integers(0, cfg.vocab_size, n).tolist()
+               for n in (21, 64, 90)]
+    done = engine.run([Request(req_id=i, tokens=p,
+                               max_new_tokens=SERVE_NEW_TOKENS)
+                       for i, p in enumerate(prompts)])
+    stats = engine.stats
+    check(all(done[i].reason == "length" for i in range(len(prompts))), done)
+    check(engine.tables.free_blocks == engine.tables.num_blocks,
+          "page pool did not return to empty")
+    check([layer["k"].shape[0] for layer in engine.pages]
+          == [32, 4 * ring, 4 * ring, 4 * ring, 32], engine.pages)
+    assert_donated(engine)
+    assert_pool_in_place(engine, kernels=("paged_attn", "moe_gmm"))
+    calls = len(re.findall(
+        r"%paged_attn(?:\.\d+)? = [^\n]*custom-call",
+        lowered_dispatch(engine, "decode").compile().as_text()))
+    check(calls == cfg.n_layer, f"{calls} paged_attn calls in decode")
+    check(stats["window_kernel_ticks"] == stats["decode_attn_kernel_ticks"]
+          == stats["decode_ticks"] > 0, stats)
+    moe_layers = cfg.n_layer - len(cfg.dense_layers)
+    check(stats["moe_routed"]
+          == stats["decode_tokens"] * cfg.top_k * moe_layers, stats)
+    check(0 < stats["moe_assignments"] < stats["moe_routed"], stats)
+    check(stats["kv_window_pages_read"] < stats["kv_pages_read"], stats)
+    log(f"  window_kernel_ticks {stats['window_kernel_ticks']} and "
+        f"decode_attn_kernel_ticks {stats['decode_attn_kernel_ticks']} of "
+        f"{stats['decode_ticks']} decode ticks; kv_pages_read "
+        f"{stats['kv_pages_read']} of kv_pages_table "
+        f"{stats['kv_pages_table']}, kv_window_pages_read "
+        f"{stats['kv_window_pages_read']} (a ring of {ring} pages a slot); "
+        f"moe_assignments {stats['moe_assignments']} of moe_routed "
+        f"{stats['moe_routed']} (experts 0-3 of 8 held), moe_experts_hit "
+        f"{stats['moe_experts_hit']}, moe_load_max {stats['moe_load_max']}; "
+        f"prefill: {stats['moe_prefill_assignments']} of "
+        f"{stats['moe_prefill_routed']} / "
+        f"{stats['moe_prefill_experts_hit']} / "
+        f"{stats['moe_prefill_load_max']}")
+
+    # teacher-forced on the served tokens: one prefill window over prompt +
+    # served (fresh keys, banded) against the prompt's window and then one
+    # token a step through ring and pages (S = 1: the kernel, both kinds)
+    n_seq = len(prompts)
+    tables = jnp.arange(n_seq * max_blocks, dtype=jnp.int32)[::-1].reshape(
+        n_seq, max_blocks)
+    slots = jnp.arange(n_seq, dtype=jnp.int32)[::-1]
+    worst = teacher_forced_gap(
+        lambda t, pg, pos, valid: laguna_decode_paged(
+            params, t, cfg, pg, tables, slots, pos, valid),
+        lambda: init_page_leaves(
+            cfg.n_layer, n_seq * max_blocks, block, model.page_leaves,
+            cfg.compute_dtype, ring=(cfg.window_layers, n_seq * ring)),
+        prompts, [done[i].tokens for i in range(n_seq)], block)
+    log(f"  kernel path over ring and pages vs one banded prefill window, "
+        f"logits teacher-forced on the served tokens: max |diff| "
+        f"{worst:.5f} (tol {LOGIT_TOL})")
+    check(worst <= LOGIT_TOL, f"window decode logits off by {worst}")
+    ring_edges_at_size()
+    full_prefill_at_size()
+
+
+def ring_edges_at_size() -> None:
+    """A window layer's decode walk at the PUBLISHED shape (72 query heads
+    over 8 kv heads of 128, window 512, pages of 16: a ring of 33), held to
+    the position. Random keys weigh nearly alike, so 16 keys more or fewer
+    of 512 move a head's output by 3% and bfloat16 hides a key at the edge
+    (PERF.md, PR 30: the benchmark's ``correct`` passes a window of 496).
+    Here a loud key (a score some 17 above the others') sits just inside
+    each edge of every row's window, at ``pos - 511`` and at ``pos``, and
+    one just outside at ``pos - 512``: a walk that starts a key early or
+    late, drops the query's own position or reads a page the ring has
+    given to a later position changes a head's output by a third of its
+    size. Rows end inside a page, at a page's edge and past one, two and
+    seventeen laps of the ring. The reference is a float32 softmax over
+    the last 512 keys of the plain sequence. Then the check itself is
+    checked: the same walk told the window is 496 or 513, or over rings of
+    32 pages, must fail it."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    from distributed_lion_tpu.ops import attention as ops
+
+    H, KV, hd, window, bs = 72, 8, 128, 512, 16
+    R = ops.ring_pages(window, bs)
+    ends = [511, 512, 527, 528, 1040, 33 * 16 * 2 - 1, 33 * 16 * 2, 8959]
+    B, T = len(ends), max(ends) + 1
+    rng = np.random.default_rng(3030)
+    loud = rng.standard_normal((B, KV, hd)).astype(np.float32)
+    loud *= math.sqrt(hd) / np.linalg.norm(loud, axis=-1, keepdims=True)
+    k = rng.standard_normal((B, T, KV, hd)).astype(np.float32)
+    v = rng.standard_normal((B, T, KV, hd)).astype(np.float32)
+    for b, p in enumerate(ends):
+        for at in (p - window, p - window + 1, p):
+            if at >= 0:
+                k[b, at] = 1.5 * loud[b]
+    q = np.repeat(loud, H // KV, 1) \
+        + 0.5 * rng.standard_normal((B, H, hd)).astype(np.float32)
+    q, k, v = (jnp.asarray(t, jnp.bfloat16) for t in (q, k, v))
+    pos = jnp.asarray(ends, jnp.int32)
+    slots = jnp.arange(B, dtype=jnp.int32)[::-1]
+
+    def walk(window):
+        """The sequences written into their rings as a prefill writes
+        them, then one decode read a row."""
+        leaf = jnp.zeros((B * ops.ring_pages(window, bs), bs, 1, KV * hd),
+                         jnp.bfloat16)
+        kp, vp = (ops.ring_scatter_kv(leaf, slots, jnp.zeros_like(pos), t,
+                                      pos + 1, window=window) for t in (k, v))
+        out, read = ops.ring_decode_attention(
+            q[:, :, None], kp, vp, slots, pos, window=window, kv_heads=KV)
+        return np.asarray(out[:, :, 0], np.float32), np.asarray(read)
+
+    want = np.zeros((B, H, hd), np.float32)
+    kf, vf, qf = (np.asarray(t, np.float32) for t in (k, v, q))
+    for b, p in enumerate(ends):
+        lo = max(p - window + 1, 0)
+        kk = np.repeat(kf[b, lo:p + 1], H // KV, 1)
+        vv = np.repeat(vf[b, lo:p + 1], H // KV, 1)
+        sc = np.einsum("hd,thd->ht", qf[b], kk) / math.sqrt(hd)
+        w = np.exp(sc - sc.max(1, keepdims=True))
+        want[b] = np.einsum("ht,thd->hd", w / w.sum(1, keepdims=True), vv)
+
+    def off(got):
+        return float(np.abs(got - want).max() / np.abs(want).max())
+
+    got, read = walk(window)
+    sound = off(got)
+    check(read.tolist() == [p // bs - max(p - window + 1, 0) // bs + 1
+                            for p in ends] and read.max() <= R, read)
+    early, late = off(walk(window + 1)[0]), off(walk(window - 16)[0])
+    whole, ops.ring_pages = ops.ring_pages, lambda w, b: -(-w // b)
+    try:       # a ring that holds the pages a window fills and none more
+        short = off(walk(window)[0])
+    finally:
+        ops.ring_pages = whole
+    log(f"  ring walk at 72 heads x 128, window 512, {B} rows ending at "
+        f"{ends}: worst |diff| / max {sound:.5f} (tol 0.02; pages handed "
+        f"{read.tolist()}); told 513: {early:.3f}, told 496: {late:.3f}, "
+        f"a ring of 32 pages: {short:.3f} (each must exceed 0.1)")
+    check(sound <= 0.02, f"ring walk at the published shape off by {sound}")
+    check(min(early, late, short) > 0.1,
+          f"the ring check does not see a wrong window: {early} {late} "
+          f"{short}")
+
+
+def full_prefill_at_size() -> None:
+    """A full layer's prefill attention at the PUBLISHED shape (48 query
+    heads over 8 kv heads of 128) over 2,048 fresh keys: on the chip
+    ``banded_causal_attention`` without a band is the tiled kernel
+    ``flash_gqa_fwd``, held to a float32 softmax over each query's own
+    and earlier keys; a loud key sits right after every 100th query, so a
+    block that reads one position past the diagonal fails it."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from distributed_lion_tpu.ops import attention as ops
+
+    H, KV, hd, S = 48, 8, 128, 2048
+    rng = np.random.default_rng(3031)
+    q, k, v = (rng.standard_normal((1, n, S, hd)).astype(np.float32)
+               for n in (H, KV, KV))
+    k[:, :, 1::100] = 3.0 * q[:, ::H // KV, 0:-1:100]
+    q, k, v = (jnp.asarray(t, jnp.bfloat16) for t in (q, k, v))
+    fn = jax.jit(ops.banded_causal_attention)
+    kernels = mosaic_kernels(fn.lower(q, k, v).as_text())
+    check(kernels == ["flash_gqa_fwd"], f"a full layer's prefill holds "
+          f"{kernels}, not the tiled kernel")
+    got = np.asarray(fn(q, k, v), np.float32)
+    qf, kf, vf = (np.asarray(t, np.float32) for t in (q, k, v))
+    kf, vf = (np.repeat(t, H // KV, 1) for t in (kf, vf))
+    sc = np.einsum("bhsd,bhtd->bhst", qf, kf) / math.sqrt(hd)
+    sc = np.where(np.tri(S, dtype=bool), sc, -np.inf)
+    w = np.exp(sc - sc.max(-1, keepdims=True))
+    want = np.einsum("bhst,bhtd->bhsd", w / w.sum(-1, keepdims=True), vf)
+    worst = float(np.abs(got - want).max() / np.abs(want).max())
+    log(f"  full layer's prefill at 48 heads x 128 over {S} keys through "
+        f"flash_gqa_fwd: worst |diff| / max {worst:.5f} (tol 0.02)")
+    check(worst <= 0.02, f"the tiled prefill kernel is off by {worst}")
 
 
 # --------------------------------------------------------------- multichip
@@ -907,7 +1143,8 @@ def main() -> int:
     ap.add_argument("--chips", type=int, default=1, choices=(1, 4))
     ap.add_argument("--only", default="",
                     help="run this one phase (kernels, train, serve, "
-                         "serve_latent, multichip) and no other")
+                         "serve_latent, serve_window, multichip) and no "
+                         "other")
     args = ap.parse_args()
 
     import jax
@@ -936,7 +1173,8 @@ def main() -> int:
     phases = ([("kernels", phase_kernels),
                ("train", lambda: phase_train(out_dir)),
                ("serve", lambda: phase_serve(out_dir)),
-               ("serve_latent", phase_serve_latent)]
+               ("serve_latent", phase_serve_latent),
+               ("serve_window", phase_serve_window)]
               if args.chips == 1 else
               [("multichip", lambda: phase_multichip(work))])
     if args.only:

@@ -209,7 +209,9 @@ def _example_rest(eng, kind: str, window: Optional[int] = None) -> tuple:
             return (jnp.zeros((g, w_), i32), toks, jnp.zeros((g,), i32),
                     jnp.zeros((g,), i32), u32(0), i32(0))
         return (jnp.zeros((1, w_), i32), toks, jnp.zeros((1,), i32),
-                i32(0), u32(0), i32(0))
+                i32(0), u32(0), i32(0)) + (
+            # a window family's prefill names the slot whose ring it fills
+            (jnp.zeros((1,), i32),) if eng.model.window_layers else ())
     if kind == "verify":
         return (jnp.zeros((s_, w_), i32), jnp.zeros((s_,), i32),
                 jnp.zeros((s_, int(window)), i32), jnp.zeros((s_,), i32),

@@ -23,9 +23,10 @@ class GenerateArguments:
     model_path: Optional[str] = None  # .npz from utils.serialization, or an
     # HF save_pretrained directory (hf_export/--merged_output output, family
     # auto-detected); unset → random init (smoke mode)
-    model_family: str = "gpt2"  # gpt2 | llama | joyai (run_serve only)
+    model_family: str = "gpt2"  # gpt2 | llama | joyai, laguna (run_serve only)
     model_name: str = "tiny"    # gpt2: gpt2_124m | tiny; llama: llama2_7b | llama3_8b | tiny;
-    # joyai: tiny | the path of a JSON file with the published config.json keys
+    # joyai, laguna: tiny | the path of a JSON file with the published
+    # config.json keys
     tokenizer_name: Optional[str] = None  # HF cache name; byte tokenizer otherwise
     prompt: List[str] = dataclasses.field(default_factory=list)
     # one or more prompts (--prompt "a" "b" "c"); several prompts batch into
@@ -166,6 +167,19 @@ def build(args: GenerateArguments):
                   else joyai_init(jax.random.key(args.seed), cfg))
         # no dense-cache decode: the family serves through the paged
         # engine's latent pool (run_serve)
+        decode = init_cache = None
+    elif args.model_family == "laguna":
+        from distributed_lion_tpu.models.laguna import (
+            LagunaConfig, laguna_init,
+        )
+
+        # --model_name as for joyai (benchmark/configs/laguna-s-2.1.json);
+        # served through the paged engine's pages and rings (run_serve)
+        cfg = LagunaConfig.named(
+            args.model_name,
+            **({"vocab_size": vocab} if args.model_name == "tiny" else {}))
+        params = (load_pytree(args.model_path) if args.model_path
+                  else laguna_init(jax.random.key(args.seed), cfg))
         decode = init_cache = None
     else:
         raise ValueError(f"unknown model family {args.model_family!r}")

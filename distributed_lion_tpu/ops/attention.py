@@ -262,6 +262,167 @@ def paged_decode_attention(q, k_pages, v_pages, tables, pos,
     return out.astype(q.dtype)
 
 
+# ------------------------------------------------ window layers: the ring
+# A layer whose queries see only the last ``window`` positions keeps a
+# bounded span a slot, whatever the sequence's length: ``R`` pages
+# (:func:`ring_pages`), and the ring leaf is a pool leaf like any other,
+# ``[slots * R, block_size, 1, W]``. Slot ``s`` owns pages ``s * R .. s * R +
+# R - 1`` for good and position ``p`` lives in page ``s * R + (p //
+# block_size) % R``: arithmetic on the row's slot id, no table, nothing
+# allocated or freed. The decode tick hands the kernel the ring's pages in
+# logical order, from the window's first page on, and the rows of that page
+# before the window as ``starts``; every other call (the CPU) takes the
+# gather path over the same walk. A prefill writes the last ``R`` pages of
+# its prompt and drops the rest (:func:`ring_scatter_kv`); its own attention
+# is over the fresh keys (:func:`banded_causal_attention`), not the ring.
+
+
+def ring_pages(window: int, block_size: int) -> int:
+    """Pages a slot holds in a window layer's ring: the pages ``window``
+    positions fill, and one more for a window that starts inside a page
+    (512 positions over pages of 16 touch 33)."""
+    return -(-window // block_size) + 1
+
+
+@jax.named_scope("paged_scatter")
+def ring_scatter_kv(pages, slots, pos, new, lengths, *, window: int):
+    """Write the new k (or v) rows of a window layer into their ring pages.
+    pages [n_slots * R, block_size, G, W]; slots [B] the slot each row
+    owns; pos [B] the position of each row's first new token; new [B, S,
+    KV, hd]; lengths [B] how many of the S are real (0 = an inactive lane:
+    nothing is written). Of a row's real tokens only those in the last
+    ``R`` pages are written: an earlier one would land in a ring page a
+    later one owns."""
+    B, S = new.shape[:2]
+    bs = pages.shape[1]
+    R = ring_pages(window, bs)
+    at = pos[:, None] + jnp.arange(S)[None, :]                     # [B, S]
+    page, last = at // bs, (pos + lengths - 1) // bs
+    keep = (jnp.arange(S)[None, :] < lengths[:, None]) \
+        & (page > last[:, None] - R)
+    walk = slots[:, None] * R + page % R                           # [B, S]
+    # one table entry a token and offsets inside a page: the scatter's own
+    # page arithmetic then lands token s in ``walk[b, s]``
+    return paged_scatter_kv(pages, walk.reshape(-1, 1), (at % bs).reshape(-1),
+                            new.reshape((B * S, 1) + new.shape[2:]),
+                            keep.reshape(-1, 1))
+
+
+@jax.named_scope("paged_attn")
+def ring_decode_attention(q, k_pages, v_pages, slots, pos, *, window: int,
+                          active=None, kv_heads=None):
+    """One query token a row over a window layer's ring (new k/v already
+    scattered): row b at position ``pos[b]`` sees positions
+    ``pos[b] - window + 1 .. pos[b]``, its own counted. q [B, H, 1, hd];
+    slots [B] the slot each row owns; ``active`` (optional [B] bool): a
+    lane with no sequence reads nothing. The Mosaic kernel where
+    :func:`paged_kernel_applies`, else the gather path, both over the
+    ring's pages in logical order from the window's first page on. Returns
+    (out [B, H, 1, hd], pages [B] int32: the pages of the walk that either
+    path is handed, ``ceil(length / block_size)`` of the walk's own
+    length, which is what the kernel reads)."""
+    bs = k_pages.shape[1]
+    R = ring_pages(window, bs)
+    length = pos + 1 if active is None else jnp.where(active, pos + 1, 0)
+    first = jnp.maximum(length - window, 0)
+    page0 = first // bs
+    walk = slots[:, None] * R + (page0[:, None] + jnp.arange(R)[None, :]) % R
+    rel_len, rel_start = length - page0 * bs, first - page0 * bs
+    read = ((rel_len + bs - 1) // bs).astype(jnp.int32)
+    KV = kv_heads or k_pages.shape[2] * (k_pages.shape[3] // q.shape[-1])
+    if paged_kernel_applies(q.shape[2], k_pages.shape, k_pages.dtype):
+        from distributed_lion_tpu.ops.pallas_paged_attn import paged_attn
+
+        return paged_attn(q[:, :, 0], k_pages, v_pages, walk, rel_len,
+                          rel_start, kv_heads=KV)[:, :, None], read
+    return paged_decode_attention(q, k_pages, v_pages, walk, rel_len - 1,
+                                  start=rel_start, kv_heads=KV), read
+
+
+# most float32 score bytes one chunk of banded_causal_attention may hold
+SCORE_BYTES = 64 << 20
+
+
+def banded_causal_attention(q, k, v, *, window=None):
+    """Causal self-attention of S fresh tokens at positions ``0 .. S-1`` (a
+    prefill from the start of a sequence), grouped queries, with no
+    ``[H, S, S]`` float32 scores held (72 heads x 8,192 x 8,192 would be
+    19 GB). q [B, H, S, hd]; k, v [B, KV, S, hd], head h reading kv head
+    ``h // (H // KV)`` with no repeat. ``window``: query i sees keys
+    ``i - window + 1 .. i`` only; None: every earlier key. Returns
+    [B, H, S, hd] in q's dtype; float32 scores and softmax, the arithmetic
+    of :func:`attention_xla`.
+
+    Two paths, chosen from what the call shows. Without a band, on a TPU,
+    where ``pallas_flash_attn.gqa_kernel_takes`` (heads of 128, S a
+    multiple of 128 up to 8,192): the repo's tiled forward kernel
+    ``flash_gqa_fwd``, token-major, which visits no block above the
+    diagonal: 48 heads over 8,192 keys, a layer, 7.2 ms on a v5e with the
+    transposes either side, 2.1 over 4,096, 0.7 over 2,048. Every other
+    call takes the queries a chunk at a time in plain XLA, and a window
+    layer's chunk reads only the chunk + ``window`` keys its band can touch
+    (O(S x window)). A chunk is the largest power of two up to 256 queries
+    whose scores (B x heads x chunk x keys in reach, float32) stay within
+    ``SCORE_BYTES``, because the chip's compiler tiles the softmax fusion
+    ever worse above that: a window layer's 72 heads at 256 queries and
+    768 keys in reach (57 MB) 2.6 ms over 8,192; the full layers' 48 heads
+    over 8,192 keys took 18 ms at 32 queries a chunk alone and 56 inside
+    the prefill program, 46 at 64, 1,168 at 128 (there the fusion's cost
+    estimate overflows in the compiled HLO, which
+    tests/test_chip_compile.py looks for), which is why they left this
+    path (PERF.md, PR 30)."""
+    B, H, S, hd = q.shape
+    KV = k.shape[1]
+    if window is None and jax.default_backend() == "tpu":
+        from distributed_lion_tpu.ops.pallas_flash_attn import (
+            flash_gqa_fwd, gqa_kernel_takes,
+        )
+
+        if gqa_kernel_takes(S, hd, q.dtype):
+            # no band: the tiled kernel, token-major, which skips every
+            # block above the diagonal (module note of pallas_flash_attn)
+            def rows(x):
+                return x.transpose(0, 2, 1, 3).reshape(B, S, -1)
+
+            out = flash_gqa_fwd(rows(q), rows(k), rows(v), H)
+            return out.reshape(B, S, H, hd).transpose(0, 2, 1, 3)
+    chunk = 256
+    while chunk > 8 and B * H * chunk * 4 * (
+            S if window is None else min(S, chunk + window)) > SCORE_BYTES:
+        chunk //= 2
+    chunk = min(chunk, S)
+    assert S % chunk == 0 and H % KV == 0, (S, chunk, H, KV)
+    span = S if window is None else min(S, chunk + window)
+    scale = 1.0 / math.sqrt(hd)
+    qg = q.reshape(B, KV, H // KV, S // chunk, chunk, hd)
+
+    def one(args):
+        qc, c0 = args                                  # [B,KV,rep,c,hd], []
+        k0 = jnp.maximum(c0 + chunk - span, 0)         # first key in reach
+        kc = jax.lax.dynamic_slice_in_dim(k, k0, span, axis=2)
+        vc = jax.lax.dynamic_slice_in_dim(v, k0, span, axis=2)
+        scores = jnp.einsum("bgrsd,bgtd->bgrst", qc, kc,
+                            preferred_element_type=jnp.float32) * scale
+        q_pos = (c0 + jnp.arange(chunk))[:, None]
+        k_pos = (k0 + jnp.arange(span))[None, :]
+        seen = k_pos <= q_pos
+        if window is not None:
+            seen &= k_pos > q_pos - window
+        probs = jax.nn.softmax(jnp.where(seen, scores, -1e30),
+                               axis=-1).astype(q.dtype)
+        return jnp.einsum("bgrst,bgtd->bgrsd", probs, vc,
+                          preferred_element_type=jnp.float32).astype(q.dtype)
+
+    n = S // chunk
+    if n == 1:
+        out = one((qg[:, :, :, 0], 0))
+    else:
+        out = jax.lax.map(one, (jnp.moveaxis(qg, 3, 0),
+                                jnp.arange(n) * chunk))
+        out = jnp.moveaxis(out, 0, 3)                  # [B,KV,rep,n,c,hd]
+    return out.reshape(B, H, S, hd)
+
+
 # ---------------------------------------------------------- latent (MLA)
 # One leaf a layer, ``[num_blocks, block_size, 1, W]``: a token's row is
 # ``[c_kv | k_rope | 0]`` (serve/kv_cache.latent_row_width). Two attention

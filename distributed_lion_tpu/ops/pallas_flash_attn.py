@@ -53,6 +53,13 @@ keeps ``ops/attention``'s other paths).
 Names on the device: ``flash_attention_fwd`` and ``flash_mha_bwd``
 (``name=`` and the innermost ``jax.named_scope``, as ``ops/pallas_lion``
 names its kernels).
+
+A serving prefill with grouped queries (``flash_gqa_fwd``, on the device
+under that name): the forward kernel alone over separate token-major q, k
+and v, heads of 128, a query head's k and v block picked by ``h // rep``.
+``ops/attention.banded_causal_attention`` sends a full layer's prefill here
+(``models/laguna``: 48 query heads over 8 kv heads, 8,192 keys in 7.2 ms
+with the transposes either side; my chip run, PR 30).
 """
 
 from __future__ import annotations
@@ -210,6 +217,51 @@ def _fwd(qkv, n_head: int, interpret: bool):
             interpret=interpret,
             name="flash_attention_fwd",
         )(qkv, qkv, qkv)
+
+
+def gqa_kernel_takes(T: int, head_dim: int, dtype) -> bool:
+    """Whether :func:`flash_gqa_fwd` takes these operands: a head is one
+    lane block, whole blocks of rows, k and v of a kv head inside VMEM."""
+    return head_dim == LANES and kernel_takes(T, 1, head_dim, dtype)
+
+
+def flash_gqa_fwd(q, k, v, n_head: int, interpret: bool = False):
+    """The forward kernel alone over grouped queries, for a prefill (no
+    gradient): q ``[B, T, n_head * 128]``, k and v ``[B, T, KV * 128]``,
+    all token-major as a projection writes them, head h reading kv head
+    ``h // (n_head // KV)``; ``[B, T, n_head * 128]`` in q's dtype. The
+    grid walks the query heads and a kv head's keys and values stay in
+    VMEM under all of its query heads (their index map does not move), so
+    nothing is repeated in HBM. Causal from position 0; on the device the
+    kernel is ``flash_gqa_fwd``."""
+    B, T, width = q.shape
+    rep = n_head // (k.shape[2] // LANES)
+    blk, nq = block_for(T), T // block_for(T)
+    kv_spec = pl.BlockSpec((None, T, LANES), lambda b, j, i: (b, 0, j // rep))
+    with jax.named_scope("flash_gqa_fwd"):
+        return pl.pallas_call(
+            functools.partial(_fwd_kernel, scale=1.0 / math.sqrt(LANES),
+                              head_dim=LANES),
+            grid=(B, n_head, nq),
+            in_specs=[pl.BlockSpec((None, blk, LANES),
+                                   lambda b, j, i: (b, i, j)),
+                      kv_spec, kv_spec],
+            out_specs=[
+                pl.BlockSpec((None, blk, LANES), lambda b, j, i: (b, i, j)),
+                pl.BlockSpec((None, None, None, 1, blk),
+                             lambda b, j, i: (b, j, i, 0, 0)),
+            ],
+            out_shape=[
+                jax.ShapeDtypeStruct((B, T, width), q.dtype),
+                jax.ShapeDtypeStruct((B, n_head, nq, 1, blk), jnp.float32),
+            ],
+            scratch_shapes=[pltpu.VMEM((1, blk, LANES), jnp.float32)],
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "parallel", "arbitrary"),
+                vmem_limit_bytes=_vmem_limit(T, q.dtype.itemsize, 2, 0)),
+            interpret=interpret,
+            name="flash_gqa_fwd",
+        )(q, k, v)[0]
 
 
 # ---------------------------------------------------------------- backward

@@ -23,6 +23,12 @@ head's lanes: each score is exactly its own head's dot product) and
 ``jnp.repeat``. The MXU does H x W where H x hd would do; at one query a
 head the page's weight tile is loaded either way.
 
+A window layer's walk (``starts``, optional): the caller hands the pages of
+a row's window in logical order (a bounded ring, rotated:
+``ops/attention.ring_decode_attention``) and the count of leading rows of the
+first page that lie before the window; the kernel masks them. Without
+``starts`` the program is the one it was before the operand existed.
+
 Rows with nothing to read (``lengths[b] == 0``: an inactive slot) touch no
 page and return zeros; ids at or past the pool (the sentinel) are never
 dereferenced because the walk is bounded by the length, which the caller
@@ -62,9 +68,11 @@ def kernel_takes(pool_shape, dtype) -> bool:
     return pool_shape[3] % LANES == 0 and pool_shape[1] % sublanes == 0
 
 
-def _kernel(lens_ref, tables_ref, q_ref, k_hbm, v_hbm, o_ref,
-            k_buf, v_buf, sems, acc_ref, ahead_ref, *, scale: float,
-            table_width: int):
+def _kernel(lens_ref, tables_ref, *refs, scale: float, table_width: int,
+            windowed: bool):
+    if windowed:
+        starts_ref, *refs = refs
+    q_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf, sems, acc_ref, ahead_ref = refs
     b = pl.program_id(0)
     last_row = pl.num_programs(0) - 1
     n_slots, pages, bs, width = k_buf.shape
@@ -136,7 +144,10 @@ def _kernel(lens_ref, tables_ref, q_ref, k_hbm, v_hbm, o_ref,
         s = jax.lax.dot_general(q_ref[...], k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
         t_idx = blk * tokens + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(t_idx < length, s, MASKED)
+        keep = t_idx < length
+        if windowed:   # rows of the walk's first page before the window
+            keep = jnp.logical_and(keep, t_idx >= starts_ref[b])
+        s = jnp.where(keep, s, MASKED)
         m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
         p = jnp.exp(s - m_new)
@@ -176,26 +187,34 @@ def _own_lanes(o_bd, H: int, kv_heads: int, hd: int):
 
 
 @functools.partial(jax.jit, static_argnames=("kv_heads", "interpret"))
-def paged_attn(q, k_pages, v_pages, tables, lengths, *, kv_heads: int,
-               interpret: bool = False):
+def paged_attn(q, k_pages, v_pages, tables, lengths, starts=None, *,
+               kv_heads: int, interpret: bool = False):
     """q [B, H, hd] (one token a row); k_pages / v_pages
     ``[num_blocks, block_size, 1, W]`` (see :func:`kernel_takes`); tables
     [B, nb] int32 page ids; lengths [B] int32 — tokens row b attends
     (positions ``0 .. lengths[b] - 1``; 0 = read nothing, return zeros).
     Every id among a row's first ``ceil(lengths[b] / block_size)`` entries
-    must lie inside the pool. Returns [B, H, hd] in q's dtype."""
+    must lie inside the pool. ``starts`` (optional [B] int32, each below
+    ``block_size * PAGES_PER_BLOCK`` so that no block is masked whole): row
+    b attends positions ``starts[b] .. lengths[b] - 1`` of its walk.
+    Returns [B, H, hd] in q's dtype."""
     B, H, hd = q.shape
     NB, bs, _, W = k_pages.shape
     nb = tables.shape[1]
     q_bd = _spread_heads(q, kv_heads, W)
     rows = q_bd.shape[1]
     row_spec = pl.BlockSpec((None, rows, W), lambda b, *_: (b, 0, 0))
+    scalars = (lengths.astype(jnp.int32),
+               tables.reshape(-1).astype(jnp.int32))
+    if starts is not None:
+        scalars += (starts.astype(jnp.int32),)
     with jax.named_scope("paged_attn"):
         o_bd = pl.pallas_call(
             functools.partial(_kernel, scale=1.0 / math.sqrt(hd),
-                              table_width=nb),
+                              table_width=nb,
+                              windowed=starts is not None),
             grid_spec=pltpu.PrefetchScalarGridSpec(
-                num_scalar_prefetch=2,
+                num_scalar_prefetch=len(scalars),
                 grid=(B,),
                 in_specs=[row_spec,
                           pl.BlockSpec(memory_space=pl.ANY),
@@ -215,6 +234,6 @@ def paged_attn(q, k_pages, v_pages, tables, lengths, *, kv_heads: int,
                 dimension_semantics=("arbitrary",)),
             interpret=interpret,
             name="paged_attn",
-        )(lengths.astype(jnp.int32), tables.reshape(-1).astype(jnp.int32),
-          q_bd, k_pages.reshape(NB, bs, W), v_pages.reshape(NB, bs, W))
+        )(*scalars, q_bd, k_pages.reshape(NB, bs, W),
+          v_pages.reshape(NB, bs, W))
     return _own_lanes(o_bd, H, kv_heads, hd)

@@ -319,8 +319,9 @@ def moe_ffn(
 # over the sorted rows (O(tokens x top_k), no ``[E, tokens, D]`` buffer, no
 # one-hot mask, no capacity), then the rows go back to their tokens and are
 # summed with the routing weights; a shared expert sees every token. Single
-# device: the layer holds every expert it routes over (a layer told which
-# experts it holds is ROADMAP Reach).
+# device: the layer holds every expert it routes over, or the range it is
+# told it holds (``held``: one chip's share of an expert-parallel
+# deployment; the exchange with the chips that hold the rest is not here).
 
 MOE_COUNTERS = ("moe_assignments", "moe_experts_hit", "moe_load_max")
 
@@ -341,7 +342,7 @@ def sigmoid_topk_route(x, router, bias, top_k: int, scale: float):
         return idx, w / (w.sum(-1, keepdims=True) + 1e-20) * scale
 
 
-def grouped_matmul(lhs, rhs, group_sizes):
+def grouped_matmul(lhs, rhs, group_sizes, tail: bool = False):
     """Rows of ``lhs [M, K]`` sorted by group against ``rhs [E, K, N]``:
     the Mosaic kernel ``moe_gmm`` on a TPU where the widths are whole lane
     tiles, ``jax.lax.ragged_dot`` elsewhere (the CPU; the tests hold the
@@ -352,14 +353,14 @@ def grouped_matmul(lhs, rhs, group_sizes):
 
     if jax.default_backend() == "tpu" and pallas_moe_gmm.kernel_takes(
             lhs.shape[1], rhs.shape[2]):
-        return pallas_moe_gmm.moe_gmm(lhs, rhs, group_sizes)
+        return pallas_moe_gmm.moe_gmm(lhs, rhs, group_sizes, tail=tail)
     return lax.ragged_dot(lhs, rhs, group_sizes.astype(jnp.int32),
                           preferred_element_type=jnp.float32
                           ).astype(lhs.dtype)
 
 
 def moe_dropless_ffn(params, x, *, top_k: int, scale: float, valid=None,
-                     return_counters: bool = False):
+                     return_counters: bool = False, held=None):
     """``sum_i w_i E_idx_i(x) + E_shared(x)`` for local tokens ``x [N, D]``,
     every ``E`` a SwiGLU ``(silu(x W_g) * (x W_u)) W_d``.
 
@@ -369,30 +370,59 @@ def moe_dropless_ffn(params, x, *, top_k: int, scale: float, valid=None,
     takes). ``valid`` (optional ``[N]`` bool): pad and inactive lanes sort
     behind every group, so no expert runs them, and give zero rows.
 
+    ``held = (first, count)``: the layer is told which experts it holds
+    (one chip's share of an expert-parallel deployment). The router still
+    scores all ``E`` and a token still picks ``top_k`` of them, weighted
+    over all its picks; the banks are the ``count`` experts from ``first``
+    on, a pick of any other expert sorts behind every group like an invalid
+    lane (no bank read, no row computed) and adds nothing: the result is
+    this share's part of the layer, the shared expert included. Default:
+    every expert is held.
+
+    The layer takes all of ``x`` in one pass, so the banks are read once
+    a call whatever its length. What bounds a call is the sorted rows and
+    the rows gathered back (bfloat16 ``[N top_k, D]``: 0.5 GB each for an
+    8,192-token prefill at top 10 of 3,072); the combine's float32
+    ``[N, top_k, D]`` lives inside one fusion and is never held
+    (tests/test_chip_compile.py looks for it among the buffers).
+
     ``return_counters``: also a dict of int32 scalars over the valid lanes
-    (``MOE_COUNTERS``): assignments made (tokens x top_k: none is ever
-    dropped), distinct experts hit, and the most rows at one expert."""
+    (``MOE_COUNTERS`` and ``moe_routed``): rows computed here (tokens x
+    top_k where every expert is held: none is ever dropped), distinct
+    experts hit, the most rows at one expert, and the picks made, held or
+    not (tokens x top_k)."""
     n, d = x.shape
     n_experts = params["router"].shape[0]
+    groups = n_experts if held is None else held[1]
     idx, w = sigmoid_topk_route(x, params["router"], params["bias"], top_k,
                                 scale)
     with jax.named_scope("moe/sort"):
         flat = idx.reshape(-1)
+        if held is not None:
+            # a pick of an expert held elsewhere sorts past the last group
+            local = flat - held[0]
+            flat = jnp.where((local >= 0) & (local < groups), local, groups)
         if valid is not None:
             # a lane with no token sorts past the last expert's rows
-            flat = jnp.where(jnp.repeat(valid, top_k), flat, n_experts)
+            flat = jnp.where(jnp.repeat(valid, top_k), flat, groups)
         order = jnp.argsort(flat)            # stable: ties keep token order
-        ends = jnp.searchsorted(flat[order], jnp.arange(n_experts + 1))
+        ends = jnp.searchsorted(flat[order], jnp.arange(groups + 1))
         sizes = jnp.diff(ends).astype(jnp.int32)       # [E] rows an expert
         rows = x[order // top_k]                       # [N k, D], by expert
     with jax.named_scope("moe/experts"):
-        h = jax.nn.silu(grouped_matmul(rows, params["w_gate"], sizes)) \
-            * grouped_matmul(rows, params["w_up"], sizes)
-        y = grouped_matmul(h, params["w_down"], sizes)
+        # a held range leaves the picks held elsewhere past the last group
+        tail = held is not None
+        h = jax.nn.silu(grouped_matmul(rows, params["w_gate"], sizes, tail)) \
+            * grouped_matmul(rows, params["w_up"], sizes, tail)
+        y = grouped_matmul(h, params["w_down"], sizes, tail)
     with jax.named_scope("moe/combine"):
         back = jnp.argsort(order)            # each token's k rows, in order
         y = y[back].reshape(n, top_k, d)
-        if valid is not None:
+        if held is not None:
+            # rows past the last group are undefined: picks held elsewhere
+            # and lanes with no token alike
+            y = jnp.where((flat < groups).reshape(n, top_k, 1), y, 0)
+        elif valid is not None:
             y = jnp.where(valid[:, None, None], y, 0)
         out = jnp.einsum("nkd,nk->nd", y.astype(jnp.float32), w)
     with jax.named_scope("moe/shared"):
@@ -404,6 +434,8 @@ def moe_dropless_ffn(params, x, *, top_k: int, scale: float, valid=None,
         out = jnp.where(valid[:, None], out, 0)
     if not return_counters:
         return out
+    lanes = n if valid is None else valid.sum()
     return out, {"moe_assignments": sizes.sum(),
                  "moe_experts_hit": (sizes > 0).sum().astype(jnp.int32),
-                 "moe_load_max": sizes.max()}
+                 "moe_load_max": sizes.max(),
+                 "moe_routed": jnp.asarray(lanes * top_k, jnp.int32)}

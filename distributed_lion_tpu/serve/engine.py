@@ -440,7 +440,9 @@ class ServeModel:
     models/gpt2.gpt2_decode_paged and models/llama.llama_decode_paged
     are the two implementations; with ``tp_axis``/``ep_axis`` the call
     runs inside the engine's shard_map and the hook threads the axes
-    into the model's Megatron-split blocks / expert banks."""
+    into the model's Megatron-split blocks / expert banks. A family with
+    ``window_layers`` is also handed ``slots`` [B] int32, the slot each row
+    owns: its window layers' rings are found from that alone."""
 
     def __init__(self, family: str, cfg: Any, params: Any,
                  decode_paged: Callable, n_layer: int, kv_heads: int,
@@ -449,7 +451,8 @@ class ServeModel:
                  page_leaves: Optional[Dict[str, tuple]] = None,
                  kernel_stat: str = "decode_attn_kernel_ticks",
                  moe_counters: tuple = (), last_logit: bool = False,
-                 shardable: bool = True):
+                 shardable: bool = True, window: int = 0,
+                 window_layers: tuple = ()):
         self.family = family
         self.cfg = cfg
         self.params = params
@@ -478,6 +481,12 @@ class ServeModel:
         self.last_logit = last_logit
         # False: no tensor / expert sharding and no quantized weights here
         self.shardable = shardable
+        # layers that see only the last ``window`` positions keep a bounded
+        # ring a slot beside the growing pages (ops/attention.ring_pages);
+        # the hook then takes ``slots`` [B], the slot each row owns, which
+        # is all a ring's page ids are made of
+        self.window = window
+        self.window_layers = tuple(window_layers)
         # the model's position budget (gpt2: learned wpe rows; llama's
         # rope extrapolates but n_ctx is still the trained horizon) — the
         # engine refuses a page geometry that would silently alias/exceed
@@ -561,6 +570,32 @@ class ServeModel:
             page_leaves={"kv": (1, cfg.latent_dim)},
             kernel_stat="mla_kernel_ticks", moe_counters=MOE_COUNTERS,
             last_logit=True, shardable=False)
+
+    @staticmethod
+    def for_laguna(params: Any, cfg: Any) -> "ServeModel":
+        """Laguna (models/laguna): window and full GQA layers with their
+        own head counts over one pool list, a bounded ring a slot for the
+        window layers beside the full layers' growing pages, and dropless
+        experts told which they hold."""
+        from distributed_lion_tpu.models.laguna import (
+            LAGUNA_COUNTERS,
+            laguna_decode_paged,
+        )
+
+        def decode(p, toks, pages, tables, pos, valid=None, tp_axis=None,
+                   ep_axis=None, return_moe_stats=False, stats_axis=None,
+                   stats_lanes=None, logit_index=None, slots=None):
+            # the engine refuses tp / ep for this family at build
+            assert tp_axis is None and ep_axis is None and stats_axis is None
+            return laguna_decode_paged(
+                p, toks, cfg, pages, tables, slots, pos, valid,
+                return_moe_stats, logit_index)
+
+        return ServeModel(
+            "laguna", cfg, params, decode, cfg.n_layer, cfg.n_kv_head,
+            cfg.head_dim, cfg.compute_dtype, max_positions=cfg.n_ctx,
+            moe_counters=LAGUNA_COUNTERS, last_logit=True, shardable=False,
+            window=cfg.window, window_layers=cfg.window_layers)
 
 
 def weight_bytes(params: Any) -> int:
@@ -667,6 +702,24 @@ class ServingEngine:
             raise ValueError(
                 f"unknown retrace_guard mode {cfg.retrace_guard!r} "
                 "(off | warn | error)")
+        for on, flag, why in (
+                (cfg.prefix_cache, "--prefix_cache", "a shared prefix's "
+                 "pages say nothing of the window layers' rings, which "
+                 "would have to be rebuilt for every sharer"),
+                (cfg.speculate, "--speculate", "a rejected draft cannot be "
+                 "rolled back out of a ring that has overwritten its "
+                 "oldest page"),
+                (cfg.tp, "--serve_tp", "its query heads differ by layer and "
+                 "the ring leaves have no sharding spec"),
+                (cfg.ep, "--serve_ep", "its expert layer is told the one "
+                 "range it holds; the exchange between ranges is not "
+                 "built")):
+            if on and model.window_layers:
+                raise ValueError(
+                    f"family {model.family!r} keeps a ring of "
+                    f"{model.window} positions a slot for its window "
+                    f"layers and does not serve under {flag}: {why} "
+                    "(ROADMAP Reach)")
         if not model.shardable and (cfg.tp or cfg.ep or cfg.ep_overlap
                                     or cfg.quant != "none"):
             raise ValueError(
@@ -788,9 +841,17 @@ class ServingEngine:
         self.tables = BlockTables(cfg.resolved_num_blocks(), cfg.block_size,
                                   cfg.max_seqs, cfg.max_blocks_per_seq,
                                   groups=groups)
+        # window layers: a ring of fixed pages a slot, owned for good and
+        # never counted against num_blocks (admission sees full-layer
+        # pages only); the dispatches name a row's ring by its slot id
+        from distributed_lion_tpu.ops.attention import ring_pages
+
+        self._windowed = bool(model.window_layers)
         self.pages = init_page_leaves(
             model.n_layer, cfg.resolved_num_blocks(), cfg.block_size,
-            model.page_leaves, model.cache_dtype, groups=max(cfg.tp, 1))
+            model.page_leaves, model.cache_dtype, groups=max(cfg.tp, 1),
+            ring=(model.window_layers, cfg.max_seqs * ring_pages(
+                model.window, cfg.block_size) if self._windowed else 0))
         if pages_sharding is not None:
             self.pages = [
                 {k: jax.device_put(v, pages_sharding)
@@ -832,6 +893,13 @@ class ServingEngine:
         self._decode_kernel = paged_kernel_applies(
             1, (nb // groups, bs, 1, width),  # one shard's share of the pool
             model.cache_dtype)
+        if self._windowed:
+            # ticks whose walk over the ring ran the kernel. The pages
+            # those walks were handed, ``kv_window_pages_read`` (ONE window
+            # layer's; at most ring_pages a row, where a full layer reads
+            # them all), is counted in the program from the kernel's own
+            # operands and rides with ``model.moe_counters``
+            self.stats["window_kernel_ticks"] = 0
         if self.prefix is not None:
             self.stats.update(prefix_hits=0, shared_tokens=0, cow_copies=0,
                               reclaimed_pages=0)
@@ -886,6 +954,7 @@ class ServingEngine:
         # stay global (parallel/expert.moe_ffn stats_axis)
         stats_axis = ep_axis if cfg.ep_batch else None
         overlap = self._ep_overlap
+        windowed = self._windowed
 
         def decode_tick(params, pages, tables, lens, last, act, seeds,
                         counts):
@@ -898,7 +967,10 @@ class ServingEngine:
                 out = model.decode_paged(
                     params, last[sl][:, None], pages, tables[sl], lens[sl],
                     act[sl][:, None], tp_axis=tp_axis, ep_axis=ep_axis,
-                    return_moe_stats=moe_stats, stats_axis=stats_axis)
+                    return_moe_stats=moe_stats, stats_axis=stats_axis,
+                    # row i of a decode tick is slot i
+                    **({"slots": jnp.arange(lens.shape[0])[sl]}
+                       if windowed else {}))
                 return out[0], (out[2] if moe_stats else {}), out[1]
 
             if not overlap:
@@ -920,7 +992,9 @@ class ServingEngine:
             return ride(_sample_rows(logits[:, -1], seeds, counts, *samp),
                         st), pages
 
-        def prefill(params, pages, tables, toks, start, length, seed, count):
+        def prefill(params, pages, tables, toks, start, length, seed, count,
+                    *slot):
+            # ``slot`` ([1] int32): a window family's one operand more
             # toks [1, P] — the prompt SUFFIX not covered by shared prefix
             # pages, scattered at absolute positions start..start+P-1
             # (start == 0 without prefix sharing: the whole prompt).
@@ -947,7 +1021,9 @@ class ServingEngine:
                                      stats_lanes=(toks.shape[1]
                                                   if stats_axis else None),
                                      **({"logit_index": at}
-                                        if model.last_logit else {}))
+                                        if model.last_logit else {}),
+                                     **({"slots": slot[0]}
+                                        if windowed else {}))
             logits, pages = out[0], out[1]
             st = out[2] if moe_stats else {}
             last = logits[0, 0] if model.last_logit else \
@@ -978,7 +1054,7 @@ class ServingEngine:
         else:
             self._decode_tick = self._jit_paged(decode_tick, n_rest=6,
                                                 name="decode")
-            self._prefill = self._jit_paged(prefill, n_rest=6,
+            self._prefill = self._jit_paged(prefill, n_rest=6 + windowed,
                                             name="prefill")
         self._cow = self._jit_cow(cow_copy)
 
@@ -1349,6 +1425,8 @@ class ServingEngine:
         # pre-migration engine would use next
         rest = (tab_dev, jnp.asarray(toks), start_dev, len_dev,
                 jnp.uint32(req.seed), jnp.int32(len(req.committed)))
+        if self._windowed:    # whose ring the window layers write
+            rest += (jnp.full((1,), slot, jnp.int32),)
         self._guard("prefill", rest)
         (tok, st), self.pages = self._prefill(self.params, self.pages,
                                               *rest)
@@ -1545,6 +1623,8 @@ class ServingEngine:
                 self.stats[self.model.kernel_stat] += self._decode_kernel
                 self.stats["kv_pages_table"] += (
                     self.cfg.max_seqs * self.cfg.max_blocks_per_seq)
+                if self._windowed:
+                    self.stats["window_kernel_ticks"] += self._decode_kernel
                 for i in active:
                     s = self.slots[i]
                     s.cache_len += 1

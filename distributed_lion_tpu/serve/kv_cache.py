@@ -63,7 +63,8 @@ def pool_row_width(kv_heads: int, head_dim: int) -> int:
 
 
 def init_page_leaves(n_layer: int, num_blocks: int, block_size: int,
-                     leaves: dict, dtype, groups: int = 1) -> list:
+                     leaves: dict, dtype, groups: int = 1,
+                     ring: tuple = ((), 0)) -> list:
     """The per-layer device page pool from a description of its leaves
     (``ServeModel.page_leaves``): ``{name: (heads, width)}``, each leaf a
     zero ``[num_blocks, block_size, groups, W]`` with ``W`` the lanes of
@@ -72,16 +73,21 @@ def init_page_leaves(n_layer: int, num_blocks: int, block_size: int,
     latent (MLA) cache holds ONE leaf ``{"kv": (1, kv_lora_rank +
     rope_dim)}``: a token's row is ``[c_kv | k_rope]`` with no kv-head
     axis, 576 values in 640 lanes at the published widths (pad lanes
-    zero), so ``groups`` stays 1. Allocated once at engine start; ticks
-    update it in place (donated)."""
+    zero), so ``groups`` stays 1. ``ring = (layers, blocks)``: those layers
+    (window attention) hold ``blocks`` pages instead, ``max_seqs`` rings of
+    ``ops/attention.ring_pages`` each, slot ``s`` owning pages ``s * R .. s
+    * R + R - 1`` for good, whatever ``num_blocks`` is: two lifetimes in
+    one pool list. Allocated once at engine start; ticks update it in place
+    (donated)."""
     import jax.numpy as jnp
 
-    def leaf(heads, width):
-        return jnp.zeros((num_blocks, block_size, groups,
+    def leaf(blocks, heads, width):
+        return jnp.zeros((blocks, block_size, groups,
                           pool_row_width(heads // groups, width)), dtype)
 
-    return [{name: leaf(*hw) for name, hw in leaves.items()}
-            for _ in range(n_layer)]
+    ring_layers, ring_blocks = ring
+    return [{name: leaf(ring_blocks if i in ring_layers else num_blocks, *hw)
+             for name, hw in leaves.items()} for i in range(n_layer)]
 
 
 def init_pages(n_layer: int, num_blocks: int, block_size: int,

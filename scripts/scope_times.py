@@ -36,7 +36,8 @@ SCOPES = ("embed", "attn", "mlp", "head", "xent", "paged_attn",
           "vote/tally", "vote/wire", "lion_ballot", "lion_apply",
           "lion_stats", "mla/q", "mla/kv_latent", "mla_attn",
           "mla_paged_attn", "moe/route", "moe/sort", "moe/experts",
-          "moe/shared", "moe/combine", "moe_gmm")
+          "moe/shared", "moe/combine", "moe_gmm", "attn/qkv", "attn/rope",
+          "attn/gate", "window_attn", "full_attn")
 NO_SCOPE = "(no scope)"
 _FIND = [(s, re.compile(r"(?<=[(/])%s(?=[)/])" % re.escape(s)))
          for s in SCOPES]

@@ -628,6 +628,30 @@ def test_moe_gmm_kernel_compiles_at_published_widths(one_chip, m, k, n):
     assert not pool_leaf_copies(text, bank)
 
 
+@pytest.mark.parametrize("m,k,n", [(640, 3072, 1024), (40960, 3072, 1024),
+                                   (40960, 1024, 3072)],
+                         ids=["decode_up", "prefill_up", "prefill_down"])
+def test_moe_gmm_kernel_told_of_a_tail_compiles(one_chip, m, k, n):
+    """The grouped matmul as the layer that holds 128 of 256 experts calls
+    it (``tail``: the picks held elsewhere lie past the last group), at a
+    decode tick's 640 assignments and a 4,096-token slice's 40,960 over
+    ``[3072, 1024]`` banks: one scalar operand more, no bank copied."""
+    import functools
+
+    from distributed_lion_tpu.analysis.serve_check import pool_leaf_copies
+    from distributed_lion_tpu.ops.pallas_moe_gmm import moe_gmm
+
+    def on_chip(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    bank = on_chip((128, k, n))
+    text, secs = _compile(functools.partial(moe_gmm, tail=True),
+                          on_chip((m, k)), bank, on_chip((128,), jnp.int32))
+    assert secs < 60
+    assert _named_custom_call(text, "moe_gmm")
+    assert not pool_leaf_copies(text, bank)
+
+
 def test_train_blocks_compile_under_the_workers_shard_map(topo, monkeypatch):
     """Cell 4's path: the same two remat blocks inside a ``shard_map`` over
     the four chips' ``data`` axis, 4 sequences a worker: each worker runs
@@ -723,6 +747,113 @@ def test_latent_serving_programs_read_pool_and_banks_in_place(
         assert re.search(r'op_name="[^"]*/paged_gather/', text)
         # one position's logits, not 1,024 x 129,280 of them
         assert not re.search(r"f32\[1,1024,129280\]", text)
+
+
+@pytest.mark.parametrize("kind", ["decode_tick", "prefill_8192",
+                                  "prefill_4096"])
+def test_window_and_full_serving_programs_at_the_published_shapes(
+        one_chip, kind, monkeypatch):
+    """Laguna-S-2.1 as ``serve.laguna-s-2.1.backlog-8k`` runs it (the cut
+    configuration file: 5 layers, 128 of 256 experts held, half the
+    vocabulary; 64 slots, 20,480 pages, rings of 33), donated. The decode
+    tick holds ``paged_attn`` once a layer, both kinds (48 and 72 queries
+    over the same 1,024 lanes), and ``moe_gmm`` over the 128 banks held; no
+    program copies a pool leaf, a ring or a bank. A prefill (the longest
+    bucket, and the 4,096 bucket of the median prompt) holds no float32
+    ``[heads, S, S]`` scores, no ``[tokens, 10, 3072]`` float32 buffer, no
+    ``[E, tokens, D]`` buffer and one position's logits, and fits the chip
+    beside 14.24 GB of weights and cache."""
+    from distributed_lion_tpu.analysis.serve_check import pool_leaf_copies
+    from distributed_lion_tpu.models.laguna import (
+        LAGUNA_COUNTERS, LagunaConfig, laguna_decode_paged, laguna_init,
+    )
+    from distributed_lion_tpu.ops.attention import ring_pages
+    from distributed_lion_tpu.serve.kv_cache import init_page_leaves
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    cfg = LagunaConfig.named(os.path.join(
+        root, "benchmark", "configs", "laguna-s-2.1.json"))
+    block, per_seq, slots, pool = 16, 560, 64, 20480
+    ring = ring_pages(cfg.window, block)
+    b, s_len = (slots, 1) if kind == "decode_tick" \
+        else (1, int(kind.split("_")[1]))
+
+    def place(tree):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=one_chip), tree)
+
+    kv = (cfg.n_kv_head, cfg.head_dim)
+    pages = place(jax.eval_shape(lambda: init_page_leaves(
+        cfg.n_layer, pool, block, {"k": kv, "v": kv}, cfg.compute_dtype,
+        ring=(cfg.window_layers, slots * ring))))
+    assert [p["k"].shape for p in pages] == [
+        (n, block, 1, 1024) for n in (pool, 2112, 2112, 2112, pool)]
+    params = place(jax.eval_shape(
+        lambda: laguna_init(jax.random.key(0), cfg)))
+    n_params = sum(x.size for x in jax.tree.leaves(params))
+    assert round(n_params / 1e6) == 5572                      # 11.14 GB
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+    def fn(params, pages, toks, tables, owned, pos):
+        valid = jnp.arange(s_len)[None, :] < jnp.maximum(pos[:, None], 1)
+        logits, pages, st = laguna_decode_paged(
+            params, toks, cfg, pages, tables, owned,
+            pos if kind == "decode_tick" else jnp.zeros_like(pos), valid,
+            True, None if kind == "decode_tick" else pos[0])
+        tail = jnp.stack([st[k] for k in LAGUNA_COUNTERS])
+        return (jnp.argmax(logits[:, -1], -1), tail), pages
+
+    compiled = jax.jit(fn, donate_argnums=(1,)).lower(
+        params, pages, i32(b, s_len), i32(b, per_seq), i32(b),
+        i32(b)).compile()
+    text = compiled.as_text()
+    assert "input_output_alias" in text
+    for leaf in (pages[0]["k"], pages[1]["k"]):       # pages, then a ring
+        assert not pool_leaf_copies(text, leaf)
+    bank = jax.ShapeDtypeStruct((cfg.banks, cfg.d_model, cfg.moe_d_ff),
+                                jnp.bfloat16)
+    assert cfg.banks == 128 and not pool_leaf_copies(text, bank)
+    assert not re.search(r"bf16\[256,(3072,1024|1024,3072)\]", text)
+    tokens = b * s_len
+    # buffers: what an instruction of a fused computation yields lives in
+    # registers and VMEM, so those bodies are set aside
+    held = re.sub(r"(?ms)^%?fused_computation[^\n]*\{\n.*?^\}\n", "", text)
+    assert "fusion(" in held and len(held) < len(text)
+    for m in re.finditer(r"= (\w+)\[([\d,]+)\]", held):
+        dims = [int(d) for d in m[2].split(",")]
+        assert len(dims) < 3 or dims[:2] != [cfg.banks, tokens], m[0]
+        if m[1] == "f32" and kind != "decode_tick":
+            size = 1
+            for d in dims:
+                size *= d
+            # scores of a whole row would be 48 x 8,192 x 8,192; the
+            # combine of a whole prefill 8,192 x 10 x 3,072
+            assert size < 8192 * 10 * 3072, m[0]
+    assert _named_custom_call(text, "moe_gmm")
+    calls = re.findall(r"%paged_attn(?:\.\d+)? = [^\n]*custom-call", text)
+    assert len(calls) == (cfg.n_layer if kind == "decode_tick" else 0)
+    # a prefill's two full layers go through the tiled kernel
+    calls = re.findall(r"%flash_gqa_fwd(?:\.\d+)? = [^\n]*custom-call", text)
+    assert len(calls) == (0 if kind == "decode_tick" else 2)
+    for scope in ("attn/qkv", "attn/rope", "attn/gate", "window_attn",
+                  "full_attn", "moe/route", "moe/sort", "moe/experts",
+                  "moe/shared", "moe/combine"):
+        assert re.search(r'op_name="[^"]*/%s/' % scope, text), scope
+    mem = compiled.memory_analysis()
+    live = mem.argument_size_in_bytes + mem.temp_size_in_bytes \
+        + mem.output_size_in_bytes - mem.alias_size_in_bytes
+    assert 14.2e9 < mem.argument_size_in_bytes < 14.3e9
+    assert live < 15.75 * 2 ** 30 - 0.5e9, live               # the chip's HBM
+    if kind != "decode_tick":
+        assert not re.search(r"f32\[1,%d,50176\]" % s_len, text)
+        assert mem.temp_size_in_bytes < 2.0e9
+        # the compiler's cost estimate of a fusion overflows where it tiles
+        # it badly: a full layer's softmax over 8,192 keys at 128 queries a
+        # chunk did, and ran 64 times slower on the chip than at 32 (PR 30)
+        assert '"estimated_cycles":"9223372036854775807"' not in text
 
 
 def test_tp_decode_tick_runs_the_kernel_shard_local(topo, monkeypatch):
