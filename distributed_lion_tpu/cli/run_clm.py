@@ -19,6 +19,14 @@ sources (zero-egress substitutes for HF-hub streaming): ``synthetic``,
 ``bin:<path>`` (pre-tokenized uint16 memmap, e.g. an openwebtext dump).
 Set env ``DLION_PLATFORM=cpu8`` to force an 8-virtual-device CPU mesh.
 
+What the per-block checkpoint saves (``--remat_policy``, default ``auto``)
+is picked once at Trainer build from the shapes and the device's memory:
+plain blocks where every residual fits (the README command on a 16 GB
+chip), else ``dots``, else ``full``; the ``[setup] remat:`` line says which
+and the peak it predicted. ``--remat_policy full|dots`` and ``--remat
+false`` are obeyed as given; MoE blocks, ``--pipeline_parallel``,
+``--seq_parallel`` and the CPU keep ``full``.
+
 Observability flags (train/telemetry.py; README "Observability"):
 ``--telemetry`` arms vote-health telemetry (on-device margin histogram /
 flip rate / disagreement, measured-vs-analytic wire drift, multi-host
@@ -65,10 +73,16 @@ class ModelArguments:
     # sharding; needs n_head % seq_parallel == 0)
     param_dtype: str = "float32"
     compute_dtype: str = "bfloat16"
-    remat: bool = True  # per-block activation remat (off = faster when HBM allows)
-    remat_policy: str = "full"  # 'full' (recompute the whole block) |
-    # 'dots' (keep matmul outputs, recompute elementwise — cheaper backward
-    # at slightly more HBM; models/gpt2._remat_policy)
+    remat: bool = True  # per-block activation remat; --remat false is
+    # obeyed as it stands: plain blocks, nothing recomputed
+    remat_policy: str = "auto"  # what the per-block checkpoint saves:
+    # 'auto' (the trainer picks, once, from the shapes and the device's
+    # memory: plain blocks where every residual fits, else 'dots', else
+    # 'full'; its `[setup] remat:` line says which and what it predicted;
+    # MoE blocks, --pipeline_parallel, --seq_parallel and the CPU stay
+    # 'full': train/loop.apply_remat_policy) | 'full' (recompute the whole
+    # block) | 'dots' (keep matmul outputs, recompute elementwise —
+    # models/gpt2._remat_policy). An explicit value always wins.
     moe_experts: int = 0  # > 0: Switch-MoE FFN every moe_every-th block
     moe_every: int = 2
     moe_capacity_factor: float = 1.25
@@ -316,6 +330,7 @@ def main(argv=None):
                 param_dtype=dtypes[model_args.param_dtype],
                 compute_dtype=dtypes[model_args.compute_dtype],
                 remat=model_args.remat,
+                remat_policy=model_args.remat_policy,
                 seq_impl=model_args.seq_impl,
             )
         else:
@@ -325,6 +340,7 @@ def main(argv=None):
                 param_dtype=dtypes[model_args.param_dtype],
                 compute_dtype=dtypes[model_args.compute_dtype],
                 remat=model_args.remat,
+                remat_policy=model_args.remat_policy,
                 seq_impl=model_args.seq_impl,
             )
         print(f"[run_clm] loaded pretrained {family} from {model_args.model_path}: "

@@ -93,8 +93,11 @@ def main(argv=None):
     else:
         model_cfg = LlamaConfig.named(script_args.model_name,
                                       vocab_size=max(tok.vocab_size, 259))
+    # remat_policy 'auto': the trainer's pick from the shapes and the
+    # device's memory, resolved below once the trees to count exist
     model_cfg = dataclasses.replace(model_cfg, attn_impl=script_args.attn_impl,
-                                    seq_impl=script_args.seq_impl)
+                                    seq_impl=script_args.seq_impl,
+                                    remat_policy="auto")
     if script_args.max_length > model_cfg.n_ctx:
         script_args.max_length = model_cfg.n_ctx
     if sp > 1 and script_args.max_length % sp:
@@ -150,6 +153,15 @@ def main(argv=None):
             target_patterns=DPO_TARGET_PATTERNS,
         )
         adapters = lora_init(jax.random.key(train_cfg.seed + 1), base_params, lora_cfg)
+
+    from distributed_lion_tpu.train.loop import apply_remat_policy
+
+    # the policy runs chosen and rejected rows: two forwards' residuals a
+    # pair; a quantized reference is a second frozen tree beside the base
+    model_cfg, remat_decision = apply_remat_policy(
+        train_cfg, model_cfg, mesh, adapters, rows_per_sample=2,
+        frozen=(base_params if ref_params is base_params
+                else (base_params, ref_params)))
 
     vc = train_cfg.vocab_chunks
     if vc > 0 and train_cfg.tensor_parallel > 1:
@@ -273,7 +285,7 @@ def main(argv=None):
     trainer = Trainer(train_cfg, mesh, apply_fn=None, params=adapters,
                       loss_fn=loss_fn, param_specs=adapter_specs,
                       frozen_params=frozen_params, frozen_specs=frozen_specs,
-                      batch_spec=batch_spec)
+                      batch_spec=batch_spec, remat_decision=remat_decision)
     it = dpo_batch_iterator(train_data, trainer.global_train_batch(), seed=train_cfg.seed)
     try:
         trainer.train(it, eval_blocks=eval_data)

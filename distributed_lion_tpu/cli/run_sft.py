@@ -101,8 +101,9 @@ def main(argv=None):
     if script_args.gradient_checkpointing:
         raise ValueError(
             "gradient_checkpointing with LoRA is rejected for parity with the "
-            "reference (sft_llama2.py:56-59); note this framework remats every "
-            "block regardless, so the memory benefit is already in place"
+            "reference (sft_llama2.py:56-59); note this framework picks what "
+            "its per-block checkpoint saves from the device's memory "
+            "(remat_policy auto), so the memory benefit is already in place"
         )
 
     import jax
@@ -168,8 +169,11 @@ def main(argv=None):
     else:
         model_cfg = LlamaConfig.named(script_args.model_name,
                                       vocab_size=max(tok.vocab_size, 259))
+    # remat_policy 'auto': the trainer's pick from the shapes and the
+    # device's memory, resolved below once the trees to count exist
     model_cfg = dataclasses.replace(model_cfg, attn_impl=script_args.attn_impl,
-                                    seq_impl=script_args.seq_impl)
+                                    seq_impl=script_args.seq_impl,
+                                    remat_policy="auto")
     if script_args.seq_length > model_cfg.n_ctx:
         script_args.seq_length = model_cfg.n_ctx
     if sp > 1 and script_args.seq_length % sp:
@@ -205,6 +209,10 @@ def main(argv=None):
     print(f"[run_sft] LoRA adapters: {len(adapters)} sites, {n_adapter/1e3:.1f}k trainable params")
 
     from distributed_lion_tpu.models.loss import clm_loss_and_metrics
+    from distributed_lion_tpu.train.loop import apply_remat_policy
+
+    model_cfg, remat_decision = apply_remat_policy(
+        train_cfg, model_cfg, mesh, adapters, frozen=base_params)
 
     def _split_batch(batch):
         # packed: plain [B, T] token array; non-packed: {"tokens", "mask"}
@@ -275,6 +283,7 @@ def main(argv=None):
 
             loss_fn._vocab_chunked = True
             trainer = Trainer(train_cfg, mesh, apply_fn=None, params=adapters,
+                              remat_decision=remat_decision,
                               param_specs=adapter_specs, loss_fn=loss_fn,
                               frozen_params=base_params,
                               frozen_specs=base_specs,
@@ -290,6 +299,7 @@ def main(argv=None):
 
             loss_fn._vocab_chunked = True
             trainer = Trainer(train_cfg, mesh, apply_fn=None, params=adapters,
+                              remat_decision=remat_decision,
                               param_specs=adapter_specs, loss_fn=loss_fn,
                               frozen_params=base_params, frozen_specs=base_specs)
     elif sp > 1:
@@ -305,6 +315,7 @@ def main(argv=None):
 
         loss_fn._vocab_chunked = True
         trainer = Trainer(train_cfg, mesh, apply_fn=None, params=adapters,
+                              remat_decision=remat_decision,
                           loss_fn=loss_fn,
                           batch_spec=P(DATA_AXIS, SEQ_AXIS))
     else:
@@ -316,6 +327,7 @@ def main(argv=None):
 
         loss_fn._vocab_chunked = True
         trainer = Trainer(train_cfg, mesh, apply_fn=None, params=adapters,
+                              remat_decision=remat_decision,
                           loss_fn=loss_fn)
 
     if script_args.packing:

@@ -52,7 +52,13 @@ class GPT2Config:
     remat_policy: str = "full"  # what the per-block checkpoint SAVES:
     # 'full' (nothing — recompute everything), 'dots' (keep matmul outputs,
     # recompute elementwise/softmax — the usual best trade on TPU: matmuls
-    # are the expensive recompute, elementwise is free next to HBM)
+    # are the expensive recompute, elementwise is free next to HBM).
+    # A config handed to gpt2_apply means what it says. 'auto' is a
+    # request to the TRAINER (the default of run_clm's model arguments):
+    # train/loop.apply_remat_policy resolves it once, from the shapes and
+    # the device's memory, to remat=False | 'dots' | 'full'; explicit
+    # values win, MoE / pipeline / sequence-parallel / CPU runs stay
+    # 'full', and a model function that still sees 'auto' raises.
     param_dtype: Any = jnp.float32
     compute_dtype: Any = jnp.bfloat16
     moe_experts: int = 0  # > 0: Switch-MoE FFN (parallel/expert.py) replaces
@@ -338,6 +344,11 @@ def _remat_policy(name: str):
         return jax.checkpoint_policies.dots_with_no_batch_dims_saveable
     if name == "full":
         return None  # save nothing: recompute the whole block in backward
+    if name == "auto":
+        raise ValueError(
+            "remat_policy 'auto' is the trainer's to resolve "
+            "(train/loop.apply_remat_policy, at Trainer build); a model "
+            "function takes full | dots, or remat=False")
     raise ValueError(f"unknown remat_policy {name!r} (full | dots)")
 
 

@@ -45,7 +45,9 @@ class LlamaConfig:
     seq_impl: str = "ring"   # sequence-parallel attention: ring | ulysses
     remat: bool = True  # per-block jax.checkpoint; off when activations fit
     remat_policy: str = "full"  # 'full' | 'dots' (keep matmul outputs,
-    # recompute elementwise — models/gpt2._remat_policy)
+    # recompute elementwise — models/gpt2._remat_policy); 'auto' asks the
+    # trainer to pick from the shapes and the device's memory
+    # (train/loop.apply_remat_policy) and never reaches llama_apply
     param_dtype: Any = jnp.float32
     compute_dtype: Any = jnp.bfloat16
 

@@ -536,13 +536,18 @@ def attention_qkv(qkv, n_head: int, *, impl: str = "auto"):
     return out.transpose(0, 2, 1, 3).reshape(B, T, D)
 
 
+def library_kernel_applies(T: int) -> bool:
+    """True when :func:`attention` ``auto`` takes jax's bundled kernel."""
+    return jax.default_backend() == "tpu" and T >= 2048
+
+
 def attention(q, k, v, *, causal: bool = True, impl: str = "auto"):
     """Attention of head-major q, k, v ``[B, H, T, head_dim]``. ``auto``
     takes the library's flash kernel on a TPU at T >= 2048 (its memory
     regime) and :func:`attention_xla` everywhere else."""
     if impl == "auto":
         T = q.shape[2]
-        flash = jax.default_backend() == "tpu" and T >= 2048
+        flash = library_kernel_applies(T)
         _note_resolved("head-major", "flash" if flash else "xla", T,
                        q.shape[3], q.dtype, "default" if flash else "-")
         if flash:

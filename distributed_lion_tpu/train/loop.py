@@ -165,11 +165,17 @@ class TrainConfig:
     kernel: str = "auto"  # auto | pallas | xla (ops/pallas_lion fused path)
     remat_policy: str = dataclasses.field(
         default="", metadata={"cli": False})  # '' = honor the model
-    # config's own remat/remat_policy; 'full' | 'dots' overrides it at
-    # Trainer build. Programmatic only (no CLI flag — run_clm's
+    # config's own remat/remat_policy; 'auto' | 'full' | 'dots' overrides
+    # it at Trainer build. Programmatic only (no CLI flag — run_clm's
     # model-level --remat_policy drives the model config directly; this
     # field is the override tests hand the Trainer builders).
-    # (models/gpt2._remat_policy: 'dots' keeps matmul outputs and
+    # 'auto' (the default of run_clm's, run_sft's and run_dpo's model
+    # configs) is resolved once, by apply_remat_policy below, from the
+    # shapes and the device's memory: the first of none (plain blocks) |
+    # dots | full whose predicted peak fits (train/remat.py); an explicit
+    # 'full' | 'dots' or remat=False always wins, and MoE blocks, a pipeline
+    # or sequence axis and a backend without bytes_limit (the CPU) stay
+    # 'full'. (models/gpt2._remat_policy: 'dots' keeps matmul outputs and
     # recomputes elementwise — the cheaper backward the sweep's dots leg
     # measures). A perf knob under the vote, not a semantics knob: at f32
     # compute the Lion trajectory AND the lazy elected-sign cache are
@@ -366,23 +372,50 @@ class TrainConfig:
         return constant_schedule(self.learning_rate)
 
 
-def apply_remat_policy(cfg: "TrainConfig", model_cfg):
-    """Thread ``TrainConfig.remat_policy`` through the Trainer builders:
-    ``''`` honors the model config's own setting; ``'full' | 'dots'``
-    replaces it (models/gpt2._remat_policy). Loud on an unknown policy
-    and on an override with remat disabled — a policy that silently
-    never applies is the kind of no-op a sweep leg would then measure."""
-    if not cfg.remat_policy:
-        return model_cfg
-    if cfg.remat_policy not in ("full", "dots"):
+def device_bytes_limit() -> Optional[int]:
+    """One device's memory as its backend reports it (``memory_stats()``'s
+    ``bytes_limit``: 16.91 GB on a v5e), or None (the CPU reports none)."""
+    stats = jax.local_devices()[0].memory_stats() or {}
+    return stats.get("bytes_limit")
+
+
+def apply_remat_policy(cfg: "TrainConfig", model_cfg, mesh, params, *,
+                       frozen=None, rows_per_sample: int = 1):
+    """What the per-block checkpoint saves, decided once at Trainer build:
+    ``(model_cfg, decision)``. ``TrainConfig.remat_policy`` ``''`` honors
+    the model config's own setting; ``'auto' | 'full' | 'dots'`` replaces it
+    (models/gpt2._remat_policy). An explicit ``full`` / ``dots`` and
+    ``remat=False`` are obeyed as they stand (``decision`` None). ``auto``
+    is resolved here and never reaches a model function: the first of
+    none | dots | full whose predicted peak fits the device
+    (train/remat.py), from the model config, the microbatch one device sees
+    (``per_device_train_batch_size x rows_per_sample`` rows of
+    ``block_size``), the bytes of ``params``, optimizer state and
+    ``frozen`` trees on one device, the world size and the device's
+    ``bytes_limit``. What the count does not model stays ``full``: MoE
+    blocks, a pipeline or sequence axis, no ``bytes_limit`` (the CPU).
+    Loud on an unknown policy and on an override with remat disabled — a
+    policy that silently never applies is the kind of no-op a sweep leg
+    would then measure."""
+    from distributed_lion_tpu.train import remat
+
+    if cfg.remat_policy not in ("", "auto", "full", "dots"):
         raise ValueError(
-            f"unknown remat_policy {cfg.remat_policy!r} (full | dots)")
-    if not model_cfg.remat:
+            f"unknown remat_policy {cfg.remat_policy!r} (auto | full | dots)")
+    if cfg.remat_policy and not model_cfg.remat:
         raise ValueError(
             "TrainConfig.remat_policy set but the model config has "
             "remat=False — the policy would silently never apply; drop "
             "the override or enable remat")
-    return dataclasses.replace(model_cfg, remat_policy=cfg.remat_policy)
+    policy = cfg.remat_policy or model_cfg.remat_policy
+    if not model_cfg.remat or policy != "auto":
+        if cfg.remat_policy:
+            model_cfg = dataclasses.replace(model_cfg, remat_policy=policy)
+        return model_cfg, None
+    decision = remat.resolve_for(
+        cfg, model_cfg, mesh, params, frozen=frozen,
+        rows_per_sample=rows_per_sample, bytes_limit=device_bytes_limit())
+    return remat.with_rung(model_cfg, decision.rung), decision
 
 
 def gpt2_dense_loss(model_cfg: GPT2Config, tp_axis: Optional[str] = None):
@@ -670,6 +703,7 @@ class Trainer:
         batch_spec: Optional[P] = None,
         frozen_params: Any = None,
         frozen_specs: Any = None,
+        remat_decision: Any = None,
     ):
         """``loss_fn(params, batch, dropout_key) -> (loss, metrics)`` may
         replace the default CLM loss; ``batch`` is then any pytree whose
@@ -684,7 +718,10 @@ class Trainer:
         sharded over a non-data mesh axis (a closure capture would be
         replicated). When set, ``loss_fn`` takes
         ``(params, frozen, batch, dropout_key)`` and ``frozen_specs`` gives
-        its PartitionSpecs (default replicated)."""
+        its PartitionSpecs (default replicated). ``remat_decision`` is
+        what :func:`apply_remat_policy` resolved ``auto`` to for the model
+        inside ``loss_fn`` (None: nothing was resolved); said here, once the
+        journal is up."""
         # the span gate's profiler and the compile ledger's listeners: both
         # idempotent, both needed before the first span / first compile
         journal.register_profiler(jax.profiler.TraceAnnotation)
@@ -732,6 +769,9 @@ class Trainer:
                         if cfg.journal else journal.NULL)
         if cfg.journal:
             journal.install(self.journal)
+        if remat_decision is not None:
+            emit(remat_decision.line())
+            self.journal.event("remat_resolved", **remat_decision.fields())
         if cfg.zero1:
             shape = dict(mesh.shape)
             for ax in (TENSOR_AXIS, SEQ_AXIS):
@@ -2387,9 +2427,10 @@ class Trainer:
             validate_tp,
         )
 
-        model_cfg = apply_remat_policy(cfg, model_cfg)
         params = (initial_params if initial_params is not None else
                   gpt2_init(jax.random.key(seed if seed is not None else cfg.seed), model_cfg))
+        model_cfg, remat_decision = apply_remat_policy(cfg, model_cfg, mesh,
+                                                       params)
         n = count_params(params)
         shape = dict(mesh.shape)
         cfg = resolve_auto_comm(
@@ -2469,6 +2510,7 @@ class Trainer:
                 param_specs=pipeline_param_specs(tensor=tp > 1),
                 loss_fn=loss_fn,
                 batch_spec=(P(DATA_AXIS, SEQ_AXIS) if sp_pipe > 1 else None),
+                remat_decision=remat_decision,
             )
 
         ep = dict(mesh.shape).get(EXPERT_AXIS, 1)
@@ -2580,7 +2622,8 @@ class Trainer:
                   f"experts every {model_cfg.moe_every} blocks | ep={ep}")
             return Trainer(cfg, mesh, apply_fn=None, params=params,
                            param_specs=moe_specs, loss_fn=moe_loss,
-                           batch_spec=moe_batch_spec)
+                           batch_spec=moe_batch_spec,
+                           remat_decision=remat_decision)
 
         if cfg.tp_vocab and tp <= 1:
             raise ValueError("--tp_vocab needs --tensor_parallel > 1 (it "
@@ -2688,7 +2731,8 @@ class Trainer:
             loss_fn = gpt2_dense_loss(model_cfg, tp_axis)
 
         return Trainer(cfg, mesh, apply_fn, params, param_specs=param_specs,
-                       loss_fn=loss_fn, batch_spec=batch_spec)
+                       loss_fn=loss_fn, batch_spec=batch_spec,
+                       remat_decision=remat_decision)
 
     @staticmethod
     def for_llama(cfg: TrainConfig, mesh, model_cfg, seed: Optional[int] = None,
@@ -2715,10 +2759,11 @@ class Trainer:
                 "an 'expert' mesh axis is wired for GPT-2-MoE only; Llama "
                 "composes with dp x tp x sp x pp"
             )
-        model_cfg = apply_remat_policy(cfg, model_cfg)
         params = (initial_params if initial_params is not None else
                   llama_init(jax.random.key(seed if seed is not None else cfg.seed),
                              model_cfg))
+        model_cfg, remat_decision = apply_remat_policy(cfg, model_cfg, mesh,
+                                                       params)
         n = count_params(params)
         shape = dict(mesh.shape)
         cfg = resolve_auto_comm(
@@ -2776,6 +2821,7 @@ class Trainer:
                 param_specs=llama_pipeline_param_specs(tensor=tp > 1),
                 loss_fn=loss_fn,
                 batch_spec=(P(DATA_AXIS, SEQ_AXIS) if sp_pipe > 1 else None),
+                remat_decision=remat_decision,
             )
         if cfg.tp_vocab and tp <= 1:
             raise ValueError("--tp_vocab needs --tensor_parallel > 1 (it "
@@ -2859,7 +2905,8 @@ class Trainer:
             loss_fn._vocab_chunked = True  # consumed; don't trip the guard
 
         return Trainer(cfg, mesh, apply_fn, params, param_specs=param_specs,
-                       loss_fn=loss_fn, batch_spec=batch_spec)
+                       loss_fn=loss_fn, batch_spec=batch_spec,
+                       remat_decision=remat_decision)
 
 
 def _count_of(state) -> jnp.ndarray:

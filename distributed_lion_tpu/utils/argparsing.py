@@ -92,7 +92,7 @@ def parse_dataclasses(
     for dc in dataclass_types:
         # cli:False fields never populate from parsed flags or JSON —
         # without this, a same-named FLAG owned by another group leaks in
-        # (e.g. ModelArguments.remat_policy default 'full' would land in
+        # (e.g. ModelArguments.remat_policy default 'auto' would land in
         # TrainConfig.remat_policy and break `--remat false`)
         kwargs = {f.name: values[f.name] for f in dataclasses.fields(dc)
                   if f.name in values and f.metadata.get("cli", True)}

@@ -12,3 +12,8 @@ if "xla_force_host_platform_device_count" not in _flags:
 import jax  # noqa: E402
 
 jax.config.update("jax_threefry_partitionable", True)
+
+
+def pytest_configure(config):
+    config.addinivalue_line(
+        "markers", "slow: left out of the tier-1 run (-m 'not slow')")
