@@ -19,6 +19,7 @@ from __future__ import annotations
 
 import argparse
 import contextlib
+import dataclasses
 import json
 import math
 import os
@@ -941,6 +942,172 @@ def full_prefill_at_size() -> None:
 
 
 # --------------------------------------------------------------- multichip
+def phase_serve_state() -> None:
+    """The recurrent-state family (models/ling) at a small lane-aligned
+    size through the constructors ``run_serve --model_family ling`` calls:
+    the decode tick holds ``kda_step`` once a KDA layer beside
+    ``mla_paged_attn`` and ``moe_gmm``, no dispatch copies a state leaf or
+    the latent pool; slots are admitted twice (a second tenant reads nothing
+    of the first); and the S = 1 kernel path through state and pages gives
+    the logits of one prefill window (chunked form) on the tokens it served.
+    That comparison runs with float32 weights and activations under
+    ``default_matmul_precision("highest")``: in bfloat16 (and in float32 at
+    a TPU's default precision, which rounds float32 operands to bfloat16:
+    0.24 on the chip, PR 32) the two paths' matmuls round their outputs
+    apart by an ulp here and there, and this family at these toy widths
+    carries that to 0.2-0.3 in a logit ON THE CPU TOO (no kernel there;
+    float32 agrees to 2e-6), which would hide a kernel's fault. Before it,
+    the delta rule at the published shape (:func:`kda_at_size`)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from distributed_lion_tpu.models.ling import (
+        LingConfig, ling_decode_paged, ling_init,
+    )
+    from distributed_lion_tpu.serve.engine import (
+        Request, ServeConfig, ServeModel, ServingEngine,
+    )
+    from distributed_lion_tpu.serve.kv_cache import init_page_leaves
+
+    cfg = LingConfig.tiny(
+        vocab_size=1024, d_model=256, n_head=32, head_dim=128,
+        kv_lora_rank=128, qk_nope_head_dim=64, qk_rope_head_dim=64,
+        v_head_dim=64, d_ff=512, n_experts=16, top_k=2, n_group=4,
+        topk_group=2, moe_d_ff=128, shared_d_ff=128, held=(0, 8))
+    params = ling_init(jax.random.key(32), cfg)
+    block, max_blocks, n_seq = 16, 8, 4
+    model = ServeModel.for_ling(params, cfg)
+    engine = ServingEngine(model, ServeConfig(
+        max_seqs=n_seq, block_size=block, max_blocks_per_seq=max_blocks,
+        moe_stats=True, prefill_cap_tokens=128))
+    rng = np.random.default_rng(32)
+    prompts = [rng.integers(0, cfg.vocab_size, n).tolist()
+               for n in (21, 37, 50, 64, 9, 30)]
+    done = engine.run([Request(req_id=i, tokens=p,
+                               max_new_tokens=SERVE_NEW_TOKENS)
+                       for i, p in enumerate(prompts)])
+    stats = engine.stats
+    check(all(done[i].reason == "length" for i in range(len(prompts))), done)
+    assert_donated(engine)
+    assert_pool_in_place(engine, kernels=("kda_step", "mla_paged_attn",
+                                          "moe_gmm"))
+    kda = len(cfg.kda_layers)
+    check(stats["state_rows_stepped"] == stats["decode_tokens"] * kda, stats)
+    check(stats["state_resets"] == len(prompts) > n_seq, stats)
+    check(stats["mla_kernel_ticks"] == stats["decode_ticks"] > 0, stats)
+    log(f"  state_rows_stepped {stats['state_rows_stepped']} (= decode "
+        f"tokens x {kda} KDA layers), state_resets {stats['state_resets']} "
+        f"over {n_seq} slots, state_bytes {stats['state_bytes']}; "
+        f"moe_assignments {stats['moe_assignments']} of moe_routed "
+        f"{stats['moe_routed']}")
+
+    kda_at_size()
+    tables = jnp.arange(n_seq * max_blocks, dtype=jnp.int32)[::-1].reshape(
+        n_seq, max_blocks)
+    slots = jnp.arange(n_seq, dtype=jnp.int32)
+    f32 = jnp.float32
+    cfg32 = dataclasses.replace(cfg, param_dtype=f32, compute_dtype=f32)
+    params32 = jax.tree.map(lambda x: x.astype(f32), params)
+    leaves32 = ServeModel.for_ling(params32, cfg32).state_leaves
+    with jax.default_matmul_precision("highest"):
+        worst = teacher_forced_gap(
+            lambda t, pg, pos, valid: ling_decode_paged(
+                params32, t, cfg32, pg, tables, slots, pos, valid),
+            lambda: init_page_leaves(
+                cfg.n_layer, n_seq * max_blocks, block, model.page_leaves,
+                f32, state=(cfg.kda_layers, n_seq, leaves32)),
+            prompts[:n_seq], [done[i].tokens for i in range(n_seq)], block)
+    log(f"  kernel path through state and pages vs one chunked prefill "
+        f"window in float32 at the highest matmul precision, logits "
+        f"teacher-forced on the served tokens: max |diff| {worst:.5f} "
+        f"(tol {LOGIT_TOL})")
+    check(worst <= LOGIT_TOL, f"state decode logits off by {worst}")
+
+
+def kda_at_size() -> None:
+    """The gated delta rule at the published shape (32 heads of 128 x 128),
+    every gate at the bound -5 and then near 0: 4,096 positions in chunks,
+    by the ``kda_chunk`` kernel (what ``ops/kda.kda_chunked`` runs here) and
+    by the XLA form, each against the token-by-token scan, where the form
+    that divides keys by their cumulative decay would overflow; then the
+    ``kda_step`` kernel over 128 slots with dead slots among them against
+    the plain step, the dead slots' states bit for bit untouched, timed."""
+    import jax
+    import jax.numpy as jnp
+
+    from distributed_lion_tpu.ops import kda, pallas_kda
+
+    H, d, T, B = 32, 128, 4096, 128
+    ks = jax.random.split(jax.random.key(3232), 8)
+
+    def unit(x):
+        return x / jnp.linalg.norm(x, axis=-1, keepdims=True)
+
+    q = unit(jax.random.normal(ks[0], (1, T, H, d))) * d ** -0.5
+    k = unit(jax.random.normal(ks[1], (1, T, H, d)))
+    v = jax.random.normal(ks[2], (1, T, H, d))
+    beta = jax.nn.sigmoid(jax.random.normal(ks[3], (1, T, H)))
+    zero = jnp.zeros((1, H, d, d), jnp.float32)
+
+    @jax.jit
+    def scan(q, k, v, g, beta):
+        def step(S, x):
+            o, S = kda.kda_step_xla(S, *x)
+            return S, o
+        S, o = jax.lax.scan(step, zero, tuple(
+            jnp.moveaxis(x, 1, 0) for x in (q, k, v, g, beta)))
+        return jnp.moveaxis(o, 0, 1), S
+
+    check(pallas_kda.chunk_kernel_takes(zero.shape, zero.dtype),
+          "the chunk kernel refuses the published shape")
+    forms = (("kda_chunk kernel", jax.jit(kda.kda_chunked)),
+             ("XLA form", jax.jit(kda.kda_chunked_xla)))
+    for name, gate in (("-5 (the bound)", -5.0), ("-0.001", -1e-3)):
+        g = jnp.full((1, T, H, d), gate)
+        o2, s2 = scan(q, k, v, g, beta)
+        scale = float(jnp.abs(o2).max())
+        for form, chunked in forms:
+            o1, s1 = chunked(q, k, v, g, beta, zero)
+            err = max(float(jnp.abs(o1 - o2).max()),
+                      float(jnp.abs(s1 - s2).max()))
+            check(bool(jnp.isfinite(o1).all()),
+                  f"{form} not finite at {name}")
+            check(err <= 2e-3 * max(scale, 1.0),
+                  f"{form} vs scan at gates {name}: {err} of {scale}")
+            t0, n = time.time(), 5
+            for _ in range(n):
+                o1 = chunked(q, k, v, g, beta, zero)[0]
+            jax.block_until_ready(o1)
+            log(f"  {form}, {T} positions, gates {name}: max |diff| to the "
+                f"scan {err:.2e} (outputs to {scale:.3f}); "
+                f"{1e3 * (time.time() - t0) / n:.2f} ms a layer's worth")
+
+    state = jax.random.normal(ks[4], (B, H, d, d))
+    q1, k1, v1 = (x[0, :B] for x in (q, k, v))
+    g1 = -5.0 * jax.nn.sigmoid(jax.random.normal(ks[5], (B, H, d)) - 3)
+    b1 = beta[0, :B]
+    live = (jnp.arange(B) % 7 != 3) & (jnp.arange(B) > 1)
+    o_ref, s_ref = jax.jit(kda.kda_step_xla)(state, q1, k1, v1, g1, b1, live)
+    step = jax.jit(pallas_kda.kda_step, donate_argnums=(0,))
+    o_k, s_k = step(state + 0, q1, k1, v1, g1, b1, live)
+    err = max(float(jnp.abs(o_k - o_ref).max()),
+              float(jnp.abs(s_k - s_ref).max()))
+    check(err <= 1e-4, f"kda_step kernel vs plain step: {err}")
+    check(bool((s_k[~live] == state[~live]).all()),
+          "kda_step wrote a dead slot's state")
+    s_k = jax.block_until_ready(step(s_k, q1, k1, v1, g1, b1, live)[1])
+    t0, n = time.time(), 20
+    for _ in range(n):
+        s_k = step(s_k, q1, k1, v1, g1, b1, live)[1]
+    jax.block_until_ready(s_k)
+    ms = 1e3 * (time.time() - t0) / n
+    rows = int(live.sum())
+    log(f"  kda_step kernel, {rows} live of {B} slots: max |diff| {err:.2e}, "
+        f"dead slots untouched; {ms:.3f} ms a call = "
+        f"{rows * 2 * H * d * d * 4 / ms / 1e6:.0f} GB/s of state")
+
+
 def _replica_check(tree, what: str) -> int:
     """Every leaf fully replicated over distinct devices and its replicas
     bit-identical (exact). Returns the device count seen."""
@@ -1143,7 +1310,8 @@ def main() -> int:
     ap.add_argument("--chips", type=int, default=1, choices=(1, 4))
     ap.add_argument("--only", default="",
                     help="run this one phase (kernels, train, serve, "
-                         "serve_latent, serve_window, multichip) and no "
+                         "serve_latent, serve_window, serve_state, "
+                         "multichip) and no "
                          "other")
     args = ap.parse_args()
 
@@ -1174,7 +1342,8 @@ def main() -> int:
                ("train", lambda: phase_train(out_dir)),
                ("serve", lambda: phase_serve(out_dir)),
                ("serve_latent", phase_serve_latent),
-               ("serve_window", phase_serve_window)]
+               ("serve_window", phase_serve_window),
+               ("serve_state", phase_serve_state)]
               if args.chips == 1 else
               [("multichip", lambda: phase_multichip(work))])
     if args.only:

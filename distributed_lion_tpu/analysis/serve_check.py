@@ -211,7 +211,7 @@ def _example_rest(eng, kind: str, window: Optional[int] = None) -> tuple:
         return (jnp.zeros((1, w_), i32), toks, jnp.zeros((1,), i32),
                 i32(0), u32(0), i32(0)) + (
             # a window family's prefill names the slot whose ring it fills
-            (jnp.zeros((1,), i32),) if eng.model.window_layers else ())
+            (jnp.zeros((1,), i32),) if eng._slotted else ())
     if kind == "verify":
         return (jnp.zeros((s_, w_), i32), jnp.zeros((s_,), i32),
                 jnp.zeros((s_, int(window)), i32), jnp.zeros((s_,), i32),

@@ -23,9 +23,9 @@ class GenerateArguments:
     model_path: Optional[str] = None  # .npz from utils.serialization, or an
     # HF save_pretrained directory (hf_export/--merged_output output, family
     # auto-detected); unset → random init (smoke mode)
-    model_family: str = "gpt2"  # gpt2 | llama | joyai, laguna (run_serve only)
+    model_family: str = "gpt2"  # gpt2 | llama | joyai, laguna, ling (run_serve only)
     model_name: str = "tiny"    # gpt2: gpt2_124m | tiny; llama: llama2_7b | llama3_8b | tiny;
-    # joyai, laguna: tiny | the path of a JSON file with the published
+    # joyai, laguna, ling: tiny | the path of a JSON file with the published
     # config.json keys
     tokenizer_name: Optional[str] = None  # HF cache name; byte tokenizer otherwise
     prompt: List[str] = dataclasses.field(default_factory=list)
@@ -99,6 +99,12 @@ def check_checkpoint(args: GenerateArguments):
     return tok
 
 
+# families with no dense-cache decode (module and ``<family>_init`` by the
+# family's name): their configuration class
+PAGED_ONLY = {"joyai": "JoyAIConfig", "laguna": "LagunaConfig",
+              "ling": "LingConfig"}
+
+
 def build(args: GenerateArguments):
     import jax
 
@@ -155,31 +161,22 @@ def build(args: GenerateArguments):
             lambda c, p, t, k, pos, off=None: llama_decode(p, t, c, k, pos, off),
             cfg)
         init_cache = partial(llama_init_cache, cfg)
-    elif args.model_family == "joyai":
-        from distributed_lion_tpu.models.joyai import JoyAIConfig, joyai_init
+    elif args.model_family in PAGED_ONLY:
+        import importlib
 
         # --model_name: 'tiny', or the path of a JSON file with the
-        # published config.json keys (benchmark/configs/joyai-llm-flash.json)
-        cfg = JoyAIConfig.named(
+        # published config.json keys (benchmark/configs/<configuration>.json)
+        family = args.model_family
+        module = importlib.import_module(
+            f"distributed_lion_tpu.models.{family}")
+        cfg = getattr(module, PAGED_ONLY[family]).named(
             args.model_name,
             **({"vocab_size": vocab} if args.model_name == "tiny" else {}))
         params = (load_pytree(args.model_path) if args.model_path
-                  else joyai_init(jax.random.key(args.seed), cfg))
-        # no dense-cache decode: the family serves through the paged
-        # engine's latent pool (run_serve)
-        decode = init_cache = None
-    elif args.model_family == "laguna":
-        from distributed_lion_tpu.models.laguna import (
-            LagunaConfig, laguna_init,
-        )
-
-        # --model_name as for joyai (benchmark/configs/laguna-s-2.1.json);
-        # served through the paged engine's pages and rings (run_serve)
-        cfg = LagunaConfig.named(
-            args.model_name,
-            **({"vocab_size": vocab} if args.model_name == "tiny" else {}))
-        params = (load_pytree(args.model_path) if args.model_path
-                  else laguna_init(jax.random.key(args.seed), cfg))
+                  else getattr(module, f"{family}_init")(
+                      jax.random.key(args.seed), cfg))
+        # no dense-cache decode: these families serve through the paged
+        # engine alone (latent pages, rings, slot-indexed states: run_serve)
         decode = init_cache = None
     else:
         raise ValueError(f"unknown model family {args.model_family!r}")

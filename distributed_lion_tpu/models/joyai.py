@@ -195,7 +195,12 @@ def joyai_init(key: jax.Array, cfg: JoyAIConfig) -> dict:
 def _mla_block(x, p, cfg: JoyAIConfig, c, tables, pos, cos, sin, valid):
     """Latent attention over the paged cache: scatter the new tokens'
     latent rows, then attend (the module note says which path). Returns
-    (output ``[B, S, d]``, the layer's updated page leaf)."""
+    (output ``[B, S, d]``, the layer's updated page leaf). ``cfg`` is read
+    for its head count, widths and ``rms_eps`` alone, so another family's
+    configuration with the same names serves (``models/ling``). Two leaves of
+    ``p`` are optional: ``wq`` in place of ``wq_a`` / ``q_norm`` / ``wq_b``
+    (a query with no low-rank step), and ``wg``, a per-head output gate
+    before ``wo`` (``models/laguna.head_gate``)."""
     from distributed_lion_tpu.ops.attention import (
         chunked_causal_attention,
         mla_decode_attention,
@@ -209,8 +214,12 @@ def _mla_block(x, p, cfg: JoyAIConfig, c, tables, pos, cos, sin, valid):
     dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
     scale = 1.0 / math.sqrt(dn + dr)
     with jax.named_scope("mla/q"):
-        c_q = _rms_norm(_matmul(x, p["wq_a"]), p["q_norm"], cfg.rms_eps)
-        q = _matmul(c_q, p["wq_b"]).reshape(B, S, H, dn + dr)
+        if "wq" in p:
+            q = _matmul(x, p["wq"])
+        else:
+            c_q = _rms_norm(_matmul(x, p["wq_a"]), p["q_norm"], cfg.rms_eps)
+            q = _matmul(c_q, p["wq_b"])
+        q = q.reshape(B, S, H, dn + dr)
         q = q.transpose(0, 2, 1, 3)                       # [B, H, S, dn+dr]
         q_nope, q_rope = q[..., :dn], apply_rope(q[..., dn:], cos, sin)
     with jax.named_scope("mla/kv_latent"):
@@ -256,6 +265,11 @@ def _mla_block(x, p, cfg: JoyAIConfig, c, tables, pos, cos, sin, valid):
         else:
             out = attend(tables)
         out = out.transpose(0, 2, 1, 3).reshape(B, S, H * dv)
+    if "wg" in p:
+        from distributed_lion_tpu.models.laguna import gate_heads, head_gate
+
+        out = gate_heads(out.reshape(B, S, H, dv), head_gate(x, p["wg"]),
+                         x.dtype)
     return _matmul(out, p["wo"]), {"kv": pool}
 
 

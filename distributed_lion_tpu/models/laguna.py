@@ -285,6 +285,21 @@ def laguna_init(key: jax.Array, cfg: LagunaConfig) -> dict:
     return params
 
 
+def head_gate(u, wg):
+    """The per-head output gate's scalars, ``sigmoid(u W_g)`` ``[B, S, H]``
+    float32, ``u`` the block's normed input."""
+    with jax.named_scope("attn/gate"):
+        return jax.nn.sigmoid(_matmul(u, wg).astype(jnp.float32))
+
+
+def gate_heads(out, gate, dtype):
+    """``out [B, S, H, hd]`` (a mixer's output a head, before ``W_o``) times
+    its head's scalar, as ``[B, S, H * hd]`` of ``dtype``."""
+    with jax.named_scope("attn/gate"):
+        B, S, H, hd = out.shape
+        return (out * gate[..., None]).astype(dtype).reshape(B, S, H * hd)
+
+
 def _attention_block(u, p, cfg: LagunaConfig, layer: int, c, tables, slots,
                      pos, lengths, valid, cos, sin):
     """One layer's gated attention over its cache (the module note says
@@ -306,8 +321,7 @@ def _attention_block(u, p, cfg: LagunaConfig, layer: int, c, tables, slots,
         q = _matmul(u, p["wq"]).reshape(B, S, H, hd).transpose(0, 2, 1, 3)
         k = _matmul(u, p["wk"]).reshape(B, S, KV, hd).transpose(0, 2, 1, 3)
         v = _matmul(u, p["wv"]).reshape(B, S, KV, hd)
-    with jax.named_scope("attn/gate"):
-        gate = jax.nn.sigmoid(_matmul(u, p["wg"]).astype(jnp.float32))
+    gate = head_gate(u, p["wg"])
     with jax.named_scope("attn/rope"):
         q, k = apply_rope_half(q, cos, sin), apply_rope_half(k, cos, sin)
     with jax.named_scope("window_attn" if windowed else "full_attn"):
@@ -333,9 +347,7 @@ def _attention_block(u, p, cfg: LagunaConfig, layer: int, c, tables, slots,
         else:
             out = paged_decode_attention(q, k_pages, v_pages, tables, pos,
                                          kv_heads=KV)
-    with jax.named_scope("attn/gate"):
-        out = out.transpose(0, 2, 1, 3) * gate[..., None]     # [B, S, H, hd]
-        out = out.astype(u.dtype).reshape(B, S, H * hd)
+    out = gate_heads(out.transpose(0, 2, 1, 3), gate, u.dtype)
     return _matmul(out, p["wo"]), {"k": k_pages, "v": v_pages}, walked
 
 

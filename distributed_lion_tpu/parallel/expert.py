@@ -326,18 +326,38 @@ def moe_ffn(
 MOE_COUNTERS = ("moe_assignments", "moe_experts_hit", "moe_load_max")
 
 
-def sigmoid_topk_route(x, router, bias, top_k: int, scale: float):
+def sigmoid_topk_route(x, router, bias, top_k: int, scale: float,
+                       groups: tuple = (1, 1)):
     """``s = sigmoid(float32(x) router^T)``; the ``top_k`` experts by
     ``s + bias`` (the correction bias moves the choice, never the weight);
     weights ``s[idx] / (sum s[idx] + 1e-20) * scale``. The matmul and the
     scores are float32 (``Precision.HIGHEST``: a TPU's default would round
     float32 operands to bfloat16). x [N, D], router [E, D], bias [E] ->
-    idx [N, k] int32, w [N, k] float32."""
+    idx [N, k] int32, w [N, k] float32.
+
+    ``groups = (n_group, topk_group)``: group-limited selection. The experts
+    are ``n_group`` runs of ``E / n_group``; a group's score is the sum of
+    its two largest ``s + bias``; only experts of the ``topk_group`` best
+    groups can be picked (the others' selection scores go to ``-inf``; the
+    weights are still the picks' own ``s``). ``(1, 1)``, every other
+    family's, is no limit and adds nothing to the program."""
+    n_group, topk_group = groups
     with jax.named_scope("moe/route"):
         s = jax.nn.sigmoid(jnp.einsum(
             "nd,ed->ne", x.astype(jnp.float32), router.astype(jnp.float32),
             precision=lax.Precision.HIGHEST))
-        _, idx = lax.top_k(s + bias.astype(jnp.float32), top_k)
+        choice = s + bias.astype(jnp.float32)
+        if n_group > 1:
+            with jax.named_scope("moe/groups"):
+                n, e = choice.shape
+                by_group = choice.reshape(n, n_group, e // n_group)
+                score = lax.top_k(by_group, 2)[0].sum(-1)    # [N, n_group]
+                _, best = lax.top_k(score, topk_group)
+                kept = jnp.zeros((n, n_group), bool).at[
+                    jnp.arange(n)[:, None], best].set(True)
+                choice = jnp.where(kept[:, :, None], by_group,
+                                   -jnp.inf).reshape(n, e)
+        _, idx = lax.top_k(choice, top_k)
         w = jnp.take_along_axis(s, idx, axis=1)
         return idx, w / (w.sum(-1, keepdims=True) + 1e-20) * scale
 
@@ -359,8 +379,19 @@ def grouped_matmul(lhs, rhs, group_sizes, tail: bool = False):
                           ).astype(lhs.dtype)
 
 
+def _swiglu_limited(gate, up, limit: float):
+    """``silu(gate) * up``; with a ``limit`` > 0 the gate is clamped from
+    above and the up-projection to ``[-limit, limit]`` first (a per-layer
+    SwiGLU clamp some configurations list; 0 is no clamp and no op)."""
+    if limit > 0:
+        gate, up = jnp.minimum(gate, limit), jnp.clip(up, -limit, limit)
+    return jax.nn.silu(gate) * up
+
+
 def moe_dropless_ffn(params, x, *, top_k: int, scale: float, valid=None,
-                     return_counters: bool = False, held=None):
+                     return_counters: bool = False, held=None,
+                     route_groups: tuple = (1, 1),
+                     limits: tuple = (0.0, 0.0)):
     """``sum_i w_i E_idx_i(x) + E_shared(x)`` for local tokens ``x [N, D]``,
     every ``E`` a SwiGLU ``(silu(x W_g) * (x W_u)) W_d``.
 
@@ -390,12 +421,17 @@ def moe_dropless_ffn(params, x, *, top_k: int, scale: float, valid=None,
     (``MOE_COUNTERS`` and ``moe_routed``): rows computed here (tokens x
     top_k where every expert is held: none is ever dropped), distinct
     experts hit, the most rows at one expert, and the picks made, held or
-    not (tokens x top_k)."""
+    not (tokens x top_k).
+
+    ``route_groups``: group-limited selection
+    (:func:`sigmoid_topk_route`'s ``groups``).
+    ``limits = (routed, shared)``: the SwiGLU clamps of the routed experts
+    and of the shared one (:func:`_swiglu_limited`; 0 = none)."""
     n, d = x.shape
     n_experts = params["router"].shape[0]
     groups = n_experts if held is None else held[1]
     idx, w = sigmoid_topk_route(x, params["router"], params["bias"], top_k,
-                                scale)
+                                scale, route_groups)
     with jax.named_scope("moe/sort"):
         flat = idx.reshape(-1)
         if held is not None:
@@ -412,8 +448,9 @@ def moe_dropless_ffn(params, x, *, top_k: int, scale: float, valid=None,
     with jax.named_scope("moe/experts"):
         # a held range leaves the picks held elsewhere past the last group
         tail = held is not None
-        h = jax.nn.silu(grouped_matmul(rows, params["w_gate"], sizes, tail)) \
-            * grouped_matmul(rows, params["w_up"], sizes, tail)
+        h = _swiglu_limited(
+            grouped_matmul(rows, params["w_gate"], sizes, tail),
+            grouped_matmul(rows, params["w_up"], sizes, tail), limits[0])
         y = grouped_matmul(h, params["w_down"], sizes, tail)
     with jax.named_scope("moe/combine"):
         back = jnp.argsort(order)            # each token's k rows, in order
@@ -426,9 +463,12 @@ def moe_dropless_ffn(params, x, *, top_k: int, scale: float, valid=None,
             y = jnp.where(valid[:, None, None], y, 0)
         out = jnp.einsum("nkd,nk->nd", y.astype(jnp.float32), w)
     with jax.named_scope("moe/shared"):
-        from distributed_lion_tpu.models.llama import _mlp  # the SwiGLU
+        from distributed_lion_tpu.models.llama import _matmul, _mlp
 
-        out = out + _mlp(x, params["shared"])
+        sh = params["shared"]
+        out = out + (_mlp(x, sh) if limits[1] <= 0 else _matmul(
+            _swiglu_limited(_matmul(x, sh["w_gate"]), _matmul(x, sh["w_up"]),
+                            limits[1]), sh["w_down"]))
     out = out.astype(x.dtype)
     if valid is not None:
         out = jnp.where(valid[:, None], out, 0)

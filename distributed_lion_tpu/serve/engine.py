@@ -442,7 +442,8 @@ class ServeModel:
     runs inside the engine's shard_map and the hook threads the axes
     into the model's Megatron-split blocks / expert banks. A family with
     ``window_layers`` is also handed ``slots`` [B] int32, the slot each row
-    owns: its window layers' rings are found from that alone."""
+    owns: its window layers' rings are found from that alone; so is a
+    family with ``state_layers``."""
 
     def __init__(self, family: str, cfg: Any, params: Any,
                  decode_paged: Callable, n_layer: int, kv_heads: int,
@@ -452,7 +453,8 @@ class ServeModel:
                  kernel_stat: str = "decode_attn_kernel_ticks",
                  moe_counters: tuple = (), last_logit: bool = False,
                  shardable: bool = True, window: int = 0,
-                 window_layers: tuple = ()):
+                 window_layers: tuple = (), state_layers: tuple = (),
+                 state_leaves: Optional[Dict[str, tuple]] = None):
         self.family = family
         self.cfg = cfg
         self.params = params
@@ -487,6 +489,12 @@ class ServeModel:
         # is all a ring's page ids are made of
         self.window = window
         self.window_layers = tuple(window_layers)
+        # layers that carry a recurrent state a slot instead of a cache by
+        # position: ``{leaf: (shape, dtype)}`` a slot
+        # (serve/kv_cache.init_state_leaves); the hook takes ``slots`` for
+        # them too
+        self.state_layers = tuple(state_layers)
+        self.state_leaves = dict(state_leaves or {})
         # the model's position budget (gpt2: learned wpe rows; llama's
         # rope extrapolates but n_ctx is still the trained horizon) — the
         # engine refuses a page geometry that would silently alias/exceed
@@ -597,6 +605,40 @@ class ServeModel:
             moe_counters=LAGUNA_COUNTERS, last_logit=True, shardable=False,
             window=cfg.window, window_layers=cfg.window_layers)
 
+    @staticmethod
+    def for_ling(params: Any, cfg: Any) -> "ServeModel":
+        """Ling 3.0 flash (models/ling): five KDA layers to one MLA layer.
+        The MLA layers' latent rows live in pages (JoyAI's leaf), a KDA
+        layer's recurrent state and convolution tail in slot-indexed leaves
+        beside them; group-limited dropless experts told which they hold."""
+        import jax.numpy as jnp
+
+        from distributed_lion_tpu.models.ling import (
+            LING_COUNTERS,
+            ling_decode_paged,
+        )
+
+        def decode(p, toks, pages, tables, pos, valid=None, tp_axis=None,
+                   ep_axis=None, return_moe_stats=False, stats_axis=None,
+                   stats_lanes=None, logit_index=None, slots=None):
+            # the engine refuses tp / ep for this family at build
+            assert tp_axis is None and ep_axis is None and stats_axis is None
+            return ling_decode_paged(
+                p, toks, cfg, pages, tables, slots, pos, valid,
+                return_moe_stats, logit_index)
+
+        H, hd = cfg.n_head, cfg.head_dim
+        return ServeModel(
+            "ling", cfg, params, decode, cfg.n_layer, 1, cfg.latent_dim,
+            cfg.compute_dtype, max_positions=cfg.n_ctx,
+            page_leaves={"kv": (1, cfg.latent_dim)},
+            kernel_stat="mla_kernel_ticks", moe_counters=LING_COUNTERS,
+            last_logit=True, shardable=False, state_layers=cfg.kda_layers,
+            state_leaves={
+                "state": ((H, hd, hd), jnp.float32),
+                "conv": ((cfg.conv_width - 1, cfg.conv_channels),
+                         cfg.compute_dtype)})
+
 
 def weight_bytes(params: Any) -> int:
     """Actual storage bytes of a (possibly quantized) weight tree —
@@ -702,24 +744,36 @@ class ServingEngine:
             raise ValueError(
                 f"unknown retrace_guard mode {cfg.retrace_guard!r} "
                 "(off | warn | error)")
-        for on, flag, why in (
-                (cfg.prefix_cache, "--prefix_cache", "a shared prefix's "
-                 "pages say nothing of the window layers' rings, which "
-                 "would have to be rebuilt for every sharer"),
-                (cfg.speculate, "--speculate", "a rejected draft cannot be "
-                 "rolled back out of a ring that has overwritten its "
-                 "oldest page"),
-                (cfg.tp, "--serve_tp", "its query heads differ by layer and "
-                 "the ring leaves have no sharding spec"),
-                (cfg.ep, "--serve_ep", "its expert layer is told the one "
-                 "range it holds; the exchange between ranges is not "
-                 "built")):
-            if on and model.window_layers:
-                raise ValueError(
-                    f"family {model.family!r} keeps a ring of "
-                    f"{model.window} positions a slot for its window "
-                    f"layers and does not serve under {flag}: {why} "
-                    "(ROADMAP Reach)")
+        # what a cache that is found by the slot cannot serve under, by its
+        # kind, each with its own sentence
+        flags = ((cfg.prefix_cache, "--prefix_cache"),
+                 (cfg.speculate, "--speculate"), (cfg.tp, "--serve_tp"),
+                 (cfg.ep, "--serve_ep"))
+        no_exchange = ("its expert layer is told the one range it holds; "
+                       "the exchange between ranges is not built")
+        for layers, keeps, whys in (
+                (model.window_layers,
+                 f"keeps a ring of {model.window} positions a slot for its "
+                 "window layers",
+                 ("a shared prefix's pages say nothing of the window layers' "
+                  "rings, which would have to be rebuilt for every sharer",
+                  "a rejected draft cannot be rolled back out of a ring that "
+                  "has overwritten its oldest page",
+                  "its query heads differ by layer and the ring leaves have "
+                  "no sharding spec", no_exchange)),
+                (model.state_layers,
+                 f"carries a recurrent state a slot in "
+                 f"{len(model.state_layers)} of its layers",
+                 ("a shared prefix's pages say nothing of the state the "
+                  "prefix leaves behind, and a state has no pages to share",
+                  "a rejected draft cannot be rolled back out of a state that "
+                  "has already decayed and been written",
+                  "the state leaves have no sharding spec", no_exchange))):
+            for (on, flag), why in zip(flags, whys):
+                if on and layers:
+                    raise ValueError(
+                        f"family {model.family!r} {keeps} and does not serve "
+                        f"under {flag}: {why} (ROADMAP Reach)")
         if not model.shardable and (cfg.tp or cfg.ep or cfg.ep_overlap
                                     or cfg.quant != "none"):
             raise ValueError(
@@ -847,11 +901,16 @@ class ServingEngine:
         from distributed_lion_tpu.ops.attention import ring_pages
 
         self._windowed = bool(model.window_layers)
+        # the dispatches name a row's ring or state by its slot id
+        self._slotted = self._windowed or bool(model.state_layers)
         self.pages = init_page_leaves(
             model.n_layer, cfg.resolved_num_blocks(), cfg.block_size,
             model.page_leaves, model.cache_dtype, groups=max(cfg.tp, 1),
             ring=(model.window_layers, cfg.max_seqs * ring_pages(
-                model.window, cfg.block_size) if self._windowed else 0))
+                model.window, cfg.block_size) if self._windowed else 0),
+            # state layers: slot-indexed leaves in that layer's place,
+            # never counted against num_blocks either
+            state=(model.state_layers, cfg.max_seqs, model.state_leaves))
         if pages_sharding is not None:
             self.pages = [
                 {k: jax.device_put(v, pages_sharding)
@@ -889,7 +948,9 @@ class ServingEngine:
                       "kv_pages_table": 0}
         from distributed_lion_tpu.ops.attention import paged_kernel_applies
 
-        nb, bs, _, width = next(iter(self.pages[0].values())).shape
+        paged = next(i for i in range(model.n_layer)
+                     if i not in model.state_layers)
+        nb, bs, _, width = next(iter(self.pages[paged].values())).shape
         self._decode_kernel = paged_kernel_applies(
             1, (nb // groups, bs, 1, width),  # one shard's share of the pool
             model.cache_dtype)
@@ -900,6 +961,16 @@ class ServingEngine:
             # them all), is counted in the program from the kernel's own
             # operands and rides with ``model.moe_counters``
             self.stats["window_kernel_ticks"] = 0
+        if model.state_layers:
+            # rows the decode ticks stepped (live slots x state layers),
+            # slots whose state an admission's prefill started afresh, and
+            # what the state leaves hold
+            self.stats.update(
+                state_rows_stepped=0, state_resets=0,
+                state_bytes=sum(
+                    int(v.size) * v.dtype.itemsize
+                    for i in model.state_layers
+                    for v in self.pages[i].values()))
         if self.prefix is not None:
             self.stats.update(prefix_hits=0, shared_tokens=0, cow_copies=0,
                               reclaimed_pages=0)
@@ -954,7 +1025,7 @@ class ServingEngine:
         # stay global (parallel/expert.moe_ffn stats_axis)
         stats_axis = ep_axis if cfg.ep_batch else None
         overlap = self._ep_overlap
-        windowed = self._windowed
+        slotted = self._slotted
 
         def decode_tick(params, pages, tables, lens, last, act, seeds,
                         counts):
@@ -970,7 +1041,7 @@ class ServingEngine:
                     return_moe_stats=moe_stats, stats_axis=stats_axis,
                     # row i of a decode tick is slot i
                     **({"slots": jnp.arange(lens.shape[0])[sl]}
-                       if windowed else {}))
+                       if slotted else {}))
                 return out[0], (out[2] if moe_stats else {}), out[1]
 
             if not overlap:
@@ -994,7 +1065,8 @@ class ServingEngine:
 
         def prefill(params, pages, tables, toks, start, length, seed, count,
                     *slot):
-            # ``slot`` ([1] int32): a window family's one operand more
+            # ``slot`` ([1] int32): a window or state family's one operand
+            # more
             # toks [1, P] — the prompt SUFFIX not covered by shared prefix
             # pages, scattered at absolute positions start..start+P-1
             # (start == 0 without prefix sharing: the whole prompt).
@@ -1023,7 +1095,7 @@ class ServingEngine:
                                      **({"logit_index": at}
                                         if model.last_logit else {}),
                                      **({"slots": slot[0]}
-                                        if windowed else {}))
+                                        if slotted else {}))
             logits, pages = out[0], out[1]
             st = out[2] if moe_stats else {}
             last = logits[0, 0] if model.last_logit else \
@@ -1054,7 +1126,7 @@ class ServingEngine:
         else:
             self._decode_tick = self._jit_paged(decode_tick, n_rest=6,
                                                 name="decode")
-            self._prefill = self._jit_paged(prefill, n_rest=6 + windowed,
+            self._prefill = self._jit_paged(prefill, n_rest=6 + slotted,
                                             name="prefill")
         self._cow = self._jit_cow(cow_copy)
 
@@ -1425,7 +1497,7 @@ class ServingEngine:
         # pre-migration engine would use next
         rest = (tab_dev, jnp.asarray(toks), start_dev, len_dev,
                 jnp.uint32(req.seed), jnp.int32(len(req.committed)))
-        if self._windowed:    # whose ring the window layers write
+        if self._slotted:     # whose ring or state the layers write
             rest += (jnp.full((1,), slot, jnp.int32),)
         self._guard("prefill", rest)
         (tok, st), self.pages = self._prefill(self.params, self.pages,
@@ -1507,6 +1579,10 @@ class ServingEngine:
             self.stats["prefill_dispatches"] += 1
             self.stats["prefill_tokens"] += len(suffix)
             self.stats["padded_prefill_tokens"] += P
+            if self.model.state_layers:
+                # the prefill started the slot's state from zero and
+                # overwrote what its last tenant left
+                self.stats["state_resets"] += 1
             if req.committed:
                 self.stats["resumed_requests"] += 1
                 self.stats["resumed_tokens"] += len(req.committed)
@@ -1625,6 +1701,9 @@ class ServingEngine:
                     self.cfg.max_seqs * self.cfg.max_blocks_per_seq)
                 if self._windowed:
                     self.stats["window_kernel_ticks"] += self._decode_kernel
+                if self.model.state_layers:
+                    self.stats["state_rows_stepped"] += (
+                        len(active) * len(self.model.state_layers))
                 for i in active:
                     s = self.slots[i]
                     s.cache_len += 1

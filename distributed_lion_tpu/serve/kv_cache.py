@@ -64,7 +64,8 @@ def pool_row_width(kv_heads: int, head_dim: int) -> int:
 
 def init_page_leaves(n_layer: int, num_blocks: int, block_size: int,
                      leaves: dict, dtype, groups: int = 1,
-                     ring: tuple = ((), 0)) -> list:
+                     ring: tuple = ((), 0),
+                     state: tuple = ((), 0, {})) -> list:
     """The per-layer device page pool from a description of its leaves
     (``ServeModel.page_leaves``): ``{name: (heads, width)}``, each leaf a
     zero ``[num_blocks, block_size, groups, W]`` with ``W`` the lanes of
@@ -77,8 +78,10 @@ def init_page_leaves(n_layer: int, num_blocks: int, block_size: int,
     (window attention) hold ``blocks`` pages instead, ``max_seqs`` rings of
     ``ops/attention.ring_pages`` each, slot ``s`` owning pages ``s * R .. s
     * R + R - 1`` for good, whatever ``num_blocks`` is: two lifetimes in
-    one pool list. Allocated once at engine start; ticks update it in place
-    (donated)."""
+    one pool list. ``state = (layers, max_seqs, leaves)``: those layers carry
+    a recurrent state and hold :func:`init_state_leaves` instead, a third
+    kind in the same list. Allocated once at engine start; ticks update it
+    in place (donated)."""
     import jax.numpy as jnp
 
     def leaf(blocks, heads, width):
@@ -86,8 +89,28 @@ def init_page_leaves(n_layer: int, num_blocks: int, block_size: int,
                           pool_row_width(heads // groups, width)), dtype)
 
     ring_layers, ring_blocks = ring
-    return [{name: leaf(ring_blocks if i in ring_layers else num_blocks, *hw)
-             for name, hw in leaves.items()} for i in range(n_layer)]
+    state_layers, max_seqs, state_leaves = state
+    return [init_state_leaves(max_seqs, state_leaves) if i in state_layers
+            else {name: leaf(ring_blocks if i in ring_layers else num_blocks,
+                             *hw) for name, hw in leaves.items()}
+            for i in range(n_layer)]
+
+
+def init_state_leaves(max_seqs: int, leaves: dict) -> dict:
+    """One layer's slot-indexed leaves, the third kind of cache beside
+    growing pages and a slot's ring: ``{name: (shape, dtype)}`` (``ServeModel
+    .state_leaves``) -> zero ``[max_seqs, *shape]`` each. A layer that mixes
+    the sequence through a recurrent state (``models/ling``'s KDA layers)
+    keeps what it carries from token to token here: slot ``s`` owns row
+    ``s`` for good, found from the slot id alone; nothing grows, nothing is
+    paged, nothing is counted against ``num_blocks`` (admission sees pages
+    only). The dict stands in that layer's place in the engine's page list
+    (:func:`init_page_leaves`), so one donation covers all three kinds and
+    the decode tick steps the rows in place."""
+    import jax.numpy as jnp
+
+    return {name: jnp.zeros((max_seqs,) + tuple(shape), dtype)
+            for name, (shape, dtype) in leaves.items()}
 
 
 def init_pages(n_layer: int, num_blocks: int, block_size: int,

@@ -924,6 +924,165 @@ def test_window_and_full_serving_programs_at_the_published_shapes(
         assert '"estimated_cycles":"9223372036854775807"' not in text
 
 
+@pytest.mark.parametrize("kind", ["decode_tick", "prefill_4096"])
+def test_state_and_latent_serving_programs_at_the_published_shapes(
+        one_chip, kind, monkeypatch):
+    """Ling-3.0-flash-VL as ``serve.ling-3.0-flash-vl.backlog-1k-long`` runs
+    it (the cut configuration file: 7 layers, 128 of 512 experts held, a
+    quarter of the vocabulary; 128 slots, 40,960 latent pages, 1.61 GB of
+    float32 state in six KDA layers), donated. The decode tick holds
+    ``kda_step`` once a KDA layer, ``mla_paged_attn`` once and ``moe_gmm``
+    over the 128 banks held, and steps the state IN PLACE: no program copies
+    a state leaf, a convolution tail, the latent pool or a bank, and the
+    tick's temporaries are a few tens of MB beside 2.5 GB of cache. The
+    4,096-token prefill (the longest bucket) runs the chunked rule as
+    ``kda_chunk`` once a KDA layer (no ``[chunk, chunk]`` product of it in
+    memory), keeps one position's logits, and fits the chip beside 12.84 GB
+    of weights and cache."""
+    from distributed_lion_tpu.analysis.serve_check import pool_leaf_copies
+    from distributed_lion_tpu.models.ling import (
+        LING_COUNTERS, LingConfig, ling_decode_paged, ling_init,
+    )
+    from distributed_lion_tpu.serve.engine import ServeModel
+    from distributed_lion_tpu.serve.kv_cache import init_page_leaves
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    cfg = LingConfig.named(os.path.join(
+        root, "benchmark", "configs", "ling-3.0-flash-vl.json"))
+    block, per_seq, slots, pool = 16, 512, 128, 40960
+    b, s_len = (slots, 1) if kind == "decode_tick" \
+        else (1, int(kind.split("_")[1]))
+
+    def place(tree):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=one_chip), tree)
+
+    model = ServeModel.for_ling(None, cfg)
+    pages = place(jax.eval_shape(lambda: init_page_leaves(
+        cfg.n_layer, pool, block, model.page_leaves, cfg.compute_dtype,
+        state=(cfg.kda_layers, slots, model.state_leaves))))
+    assert [sorted(p) for p in pages] == [["conv", "state"]] * 5 \
+        + [["kv"], ["conv", "state"]]
+    assert pages[0]["state"].shape == (slots, 32, 128, 128)
+    assert pages[0]["conv"].shape == (slots, 3, 12288)
+    assert pages[5]["kv"].shape == (pool, block, 1, 640)
+    params = place(jax.eval_shape(lambda: ling_init(jax.random.key(0), cfg)))
+    n_params = sum(x.size for x in jax.tree.leaves(params))
+    assert round(n_params / 1e6) == 5169                      # 10.34 GB
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+    def fn(params, pages, toks, tables, owned, pos):
+        valid = jnp.arange(s_len)[None, :] < jnp.maximum(pos[:, None], 1)
+        logits, pages, st = ling_decode_paged(
+            params, toks, cfg, pages, tables, owned,
+            pos if kind == "decode_tick" else jnp.zeros_like(pos), valid,
+            True, None if kind == "decode_tick" else pos[0])
+        tail = jnp.stack([st[k] for k in LING_COUNTERS])
+        return (jnp.argmax(logits[:, -1], -1), tail), pages
+
+    compiled = jax.jit(fn, donate_argnums=(1,)).lower(
+        params, pages, i32(b, s_len), i32(b, per_seq), i32(b),
+        i32(b)).compile()
+    text = compiled.as_text()
+    assert "input_output_alias" in text
+    for leaf in (pages[0]["state"], pages[0]["conv"], pages[5]["kv"]):
+        assert not pool_leaf_copies(text, leaf)
+    bank = jax.ShapeDtypeStruct((cfg.banks, cfg.d_model, cfg.moe_d_ff),
+                                jnp.bfloat16)
+    assert cfg.banks == 128 and not pool_leaf_copies(text, bank)
+    assert not re.search(r"bf16\[512,(2560,768|768,2560)\]", text)
+    held = re.sub(r"(?ms)^%?fused_computation[^\n]*\{\n.*?^\}\n", "", text)
+    assert "fusion(" in held and len(held) < len(text)
+    for m in re.finditer(r"= (\w+)\[([\d,]+)\]", held):
+        dims = [int(d) for d in m[2].split(",")]
+        # an [E, tokens, D] buffer (128 slots beside 128 banks: all three)
+        assert dims[:3] != [cfg.banks, b * s_len, cfg.d_model], m[0]
+        # the chunked rule's products a chunk (the XLA form's, ops/kda)
+        assert dims[-3:] != [16, 16, 128] and dims[-2:] != [64, 64], m[0]
+    assert _named_custom_call(text, "moe_gmm")
+    decode = kind == "decode_tick"
+    calls = re.findall(r"%kda_step(?:\.\d+)? = [^\n]*custom-call", text)
+    assert len(calls) == (len(cfg.kda_layers) if decode else 0) == 6 * decode
+    calls = re.findall(r"%kda_chunk(?:\.\d+)? = [^\n]*custom-call", text)
+    assert len(calls) == 6 * (not decode)
+    calls = re.findall(r"%mla_paged_attn(?:\.\d+)? = [^\n]*custom-call",
+                       text)
+    assert len(calls) == decode
+    for scope in ("kda/conv", "kda/gate", "kda/step" if decode
+                  else "kda/chunk", "attn/gate", "mla/q", "mla/kv_latent",
+                  "moe/route", "moe/groups", "moe/sort", "moe/experts",
+                  "moe/shared", "moe/combine"):
+        assert re.search(r'op_name="[^"]*/%s/' % scope, text), scope
+    mem = compiled.memory_analysis()
+    live = mem.argument_size_in_bytes + mem.temp_size_in_bytes \
+        + mem.output_size_in_bytes - mem.alias_size_in_bytes
+    assert 12.8e9 < mem.argument_size_in_bytes < 12.9e9
+    assert mem.alias_size_in_bytes > 2.5e9          # state, tails and pool
+    assert live < 15.75 * 2 ** 30 - 0.5e9, live               # the chip's HBM
+    if decode:
+        # a copy of the state leaves would be 1.61 GB of temporaries
+        assert mem.temp_size_in_bytes < 0.2e9, mem.temp_size_in_bytes
+    else:
+        assert not re.search(r"f32\[1,%d,39296\]" % s_len, text)
+        assert mem.temp_size_in_bytes < 2.2e9
+        assert '"estimated_cycles":"9223372036854775807"' not in text
+
+
+def test_kda_step_kernel_compiles_at_the_published_shape(one_chip):
+    """``kda_step`` over 128 slots of 32 heads of 128 x 128 float32, the
+    state aliased in and out."""
+    from distributed_lion_tpu.ops.pallas_kda import kda_step
+
+    def on_chip(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    vec = on_chip((128, 32, 128))
+    t0 = time.monotonic()
+    compiled = jax.jit(kda_step, donate_argnums=(0,)).lower(
+        on_chip((128, 32, 128, 128)), vec, vec, vec, vec, on_chip((128, 32)),
+        on_chip((128,), jnp.bool_)).compile()
+    assert time.monotonic() - t0 < 60
+    text = compiled.as_text()
+    assert _named_custom_call(text, "kda_step")
+    assert "input_output_alias" in text
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 0.1e9, mem.temp_size_in_bytes
+
+
+def test_kda_chunk_kernel_compiles_at_the_published_shape(one_chip):
+    """``kda_chunk`` over a 4,096-token prompt's 32 heads of 128 x 128: a
+    head's lanes are read where the projections left them (no head-major
+    copy of q, k, v or g around the kernel)."""
+    from distributed_lion_tpu.ops.pallas_kda import chunk_kernel_takes, kda_chunk
+
+    def on_chip(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+
+    rows = on_chip(1, 4096, 32, 128)
+    assert chunk_kernel_takes((1, 32, 128, 128), jnp.float32)
+    t0 = time.monotonic()
+    compiled = jax.jit(kda_chunk).lower(
+        rows, rows, rows, rows, on_chip(1, 4096, 32),
+        on_chip(1, 32, 128, 128)).compile()
+    assert time.monotonic() - t0 < 60
+    text = compiled.as_text()
+    assert _named_custom_call(text, "kda_chunk")
+    assert not re.search(r"f32\[1,32,4096,128\]", text)      # head-major
+    mem = compiled.memory_analysis()
+    # beta k and the padded operands: nothing a chunk squared
+    assert mem.temp_size_in_bytes < 0.3e9, mem.temp_size_in_bytes
+    # a caller's float32 context (chip_smoke's comparison in float32) must
+    # not reach the kernel's bfloat16 passes: Mosaic refuses that product
+    rows = on_chip(1, 256, 32, 128)
+    with jax.default_matmul_precision("highest"):
+        jax.jit(lambda *a: kda_chunk.__wrapped__(*a)).lower(
+            rows, rows, rows, rows, on_chip(1, 256, 32),
+            on_chip(1, 32, 128, 128)).compile()
+
+
 def test_tp_decode_tick_runs_the_kernel_shard_local(topo, monkeypatch):
     """The TP engine's decode tick on two chips of the described mesh:
     inside ``shard_map`` every rank holds its own kv-head group of the pool
