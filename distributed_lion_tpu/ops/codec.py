@@ -14,9 +14,41 @@ two deliberate differences:
 
 All functions are pure and shape-polymorphic at trace time (no data-dependent
 control flow), so they fuse into the surrounding optimizer update under jit.
+
+**Two bit orders, and why.**
+
+- *The reference's order* (:func:`pack_signs` / :func:`unpack_signs` /
+  :func:`tally_packed_rows`): eight CONSECUTIVE coordinates a byte, numpy's
+  little-endian ``packbits``. Everything that is stored or read by the host
+  is in it — ``LionState.elected``, ``prev_ballot``, ``dcn_ring`` (so the
+  whole ``hier`` wire), the telemetry frame's ``elected``, the guard's
+  flips, ``packed_allgather`` — so checkpoints and host-side readers never
+  see anything else.
+- *The planar order* (:func:`pack_wire` / :func:`unpack_wire` /
+  :func:`elect_packed_rows`): a group of 32,768 votes is 8 PLANES of one
+  (32, 128) byte tile, and vote ``(plane j, position)`` is bit ``j`` of byte
+  ``position``. Used where the bytes are transient — ``packed_a2a``'s
+  all_to_all and all_gather operands, produced and consumed inside one step
+  by workers that all pack alike. An election is per coordinate, so any bit
+  order the workers share elects the same signs, and the bytes on the wire
+  are the same count (``packed_size(n)``).
+
+The second exists because of the chip's tile geometry. A uint8 array's two
+minor dimensions live in tiles 128 lanes wide, so the consecutive order's
+``[rows, 128, 8]`` view (minor dimension 8, padded to 128) turns one of GPT-2
+124M's four 31 MB ballot buckets into a 0.5 GB array written by a
+lane-scattering ``reshape`` and read back by a lane reduction: measured on
+four v5e chips at 15.4 ms of pack (7.5 of them the ``reshape``) in a 96 ms
+step, 1.1 ms in the planar order (PERF.md, PR 33). In the planar order
+every view is ``[groups, 8, 32, 128]`` — a bitcast of the flat vector — and
+pack / unpack combine eight whole tiles elementwise over a MAJOR axis; the
+chunk owner's election (:func:`elect_packed_rows`) goes from arrived bytes
+to verdict bytes without unpacking at all.
 """
 
 from __future__ import annotations
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -27,10 +59,11 @@ import numpy as np
 # physically re-tile: a pad fused in front of the reshape, a row count that
 # is not a whole number of native (32, 128) uint8 tiles) compiles in time
 # LINEAR in n — measured for a described v5e at 10 s (pack) and 20-28 s
-# (unpack) per million coordinates, i.e. an hour at GPT-2 124M. The codec
-# therefore works on whole groups of 32 lane rows of 128 bytes, which
+# (unpack) per million coordinates, i.e. an hour at GPT-2 124M. Both codecs
+# therefore work on whole groups of 32 lane rows of 128 bytes, which
 # reshape as bitcasts and compile in about a second at any n; the ragged
-# tail (< one group) is padded to one group and handled on its own.
+# tail (< one group) is handled on its own. (The consecutive order's
+# ``[rows, 128, 8]`` view compiles fast and RUNS slow: module docstring.)
 _LANE_BYTES = 128
 _GROUP_BYTES = 32 * _LANE_BYTES
 _GROUP_BITS = 8 * _GROUP_BYTES
@@ -78,6 +111,14 @@ def parse_wire(wire: str) -> tuple[str, int | None]:
     if wire in ("sign_psum", "packed_allgather", "packed_a2a"):
         return wire, None
     raise ValueError(f"unknown wire format: {wire!r}")
+
+
+def wire_codec(wire: str) -> str:
+    """Which of the module's two bit orders ``wire``'s bytes are in (module
+    docstring), as the trainer's ``[setup] vote:`` line says it."""
+    kind, _ = parse_wire(wire)
+    return {"sign_psum": "no codec (int8 ballots)",
+            "packed_a2a": "planar codec"}.get(kind, "reference-order codec")
 
 
 def vote_chunk_elems(n: int, vote_every: int) -> int:
@@ -268,6 +309,93 @@ def tally_packed_rows(rows: jnp.ndarray, weights=None) -> jnp.ndarray:
     return jax.lax.scan(
         lambda acc, rw: (acc + weighted(*rw), None),
         weighted(rows[0], weights[0]), (rows[1:], weights[1:]))[0]
+
+
+def _wire_blocks(n: int) -> list[tuple[int, int, tuple[int, ...]]]:
+    """The planar wire's layout of ``n`` votes as ``(first vote, votes,
+    plane shape)`` blocks: the group-aligned prefix in planes of one
+    (32, 128) byte tile, then the ragged tail (< one group) as ONE short
+    group whose planes are ``packed_size(tail)`` bytes long — so the wire
+    is ``packed_size(n)`` bytes whatever ``n`` is."""
+    n_al = n - n % _GROUP_BITS
+    blocks = []
+    if n_al:
+        blocks.append((0, n_al, (_GROUP_BYTES // _LANE_BYTES, _LANE_BYTES)))
+    if n > n_al:
+        blocks.append((n_al, n - n_al, (packed_size(n - n_al),)))
+    return blocks
+
+
+def _plane_shifts(plane: tuple[int, ...]) -> jnp.ndarray:
+    """Bit position of each of a group's 8 planes, shaped to broadcast
+    against ``[groups, 8, *plane]``."""
+    return jnp.arange(8, dtype=jnp.uint8).reshape((8,) + (1,) * len(plane))
+
+
+@jax.named_scope("vote/pack")
+def pack_wire(positive: jnp.ndarray) -> jnp.ndarray:
+    """Pack a boolean array (True = +1 vote) into the planar wire format:
+    ``packed_size(n)`` uint8 bytes, the same count as :func:`pack_signs`,
+    another bit order (module docstring). Within a group of 8 planes, vote
+    ``(j, position)`` is bit ``j`` of byte ``position``:
+    ``byte[k, r, l] = OR_j vote[k, j, r, l] << j`` — eight whole tiles
+    combined elementwise, a reduction over a MAJOR axis. Pad bits (the
+    tail's last plane) are zeros."""
+    flat = positive.reshape(-1).astype(jnp.uint8)
+    parts = []
+    for start, votes, plane in _wire_blocks(flat.shape[0]):
+        bits = flat[start:start + votes]
+        short = -votes % (8 * int(np.prod(plane)))  # the tail alone
+        if short:
+            bits = jnp.concatenate([bits, jnp.zeros((short,), jnp.uint8)])
+        planes = bits.reshape((-1, 8) + plane) << _plane_shifts(plane)
+        parts.append(jax.lax.reduce(planes, np.uint8(0), jax.lax.bitwise_or,
+                                    (1,)).reshape(-1))
+    if not parts:
+        return jnp.zeros((0,), jnp.uint8)
+    return parts[0] if len(parts) == 1 else jnp.concatenate(parts)
+
+
+@jax.named_scope("vote/unpack")
+def unpack_wire(packed: jnp.ndarray, shape: tuple[int, ...]) -> jnp.ndarray:
+    """Inverse of :func:`pack_wire`: ``packed_size(n)`` planar bytes → bool
+    array of ``shape``; ``bit[k, j, r, l] = (byte[k, r, l] >> j) & 1``."""
+    n = int(np.prod(shape)) if shape else 1
+    flat = packed.reshape(-1)
+    parts = []
+    for start, votes, plane in _wire_blocks(n):
+        tiles = flat[start // 8: start // 8 + packed_size(votes)]
+        tiles = tiles.reshape((-1, 1) + plane)
+        bits = ((tiles >> _plane_shifts(plane)) & 1) > 0
+        parts.append(bits.reshape(-1)[:votes])
+    if not parts:
+        return jnp.zeros(shape, jnp.bool_)
+    bits = parts[0] if len(parts) == 1 else jnp.concatenate(parts)
+    return bits.reshape(shape)
+
+
+@jax.named_scope("vote/tally")
+def elect_packed_rows(rows: jnp.ndarray, weights=None) -> jnp.ndarray:
+    """The packed wires' election at the chunk owner, bytes in and bytes
+    out: ``rows`` [R, nbytes] uint8 (one packed ballot a row, in ANY bit
+    order the rows share) → [nbytes] uint8 whose bit is set where a strict
+    majority of the rows set it. ``weights`` (optional [R] bool or int, the
+    masked elections' alive mask) shrink the quorum to their sum; a tie
+    elects 0 (−1). An election is per coordinate, so nothing is unpacked:
+    per bit position ``j`` the rows' bit-``j`` bytes are counted and
+    compared elementwise. O(1) trace in R."""
+    if weights is None:
+        quorum = rows.shape[0]
+    else:
+        weights = weights.astype(jnp.int32)
+        quorum = weights.sum()
+    verdict = []
+    for j in range(8):
+        bit = ((rows >> j) & 1).astype(jnp.int32)
+        if weights is not None:
+            bit = bit * weights[:, None]
+        verdict.append((bit.sum(0) * 2 > quorum).astype(jnp.uint8) << j)
+    return functools.reduce(jnp.bitwise_or, verdict)
 
 
 def _recv_bytes(n: int, world_size: int, kind: str,

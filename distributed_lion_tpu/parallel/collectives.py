@@ -52,10 +52,13 @@ from jax import lax
 from distributed_lion_tpu.ops.codec import (
     a2a_chunk_bytes,
     bucket_bounds,
+    elect_packed_rows,
     pack_signs,
+    pack_wire,
     parse_wire,
     tally_packed_rows,
     unpack_signs,
+    unpack_wire,
 )
 from distributed_lion_tpu.train import resilience
 
@@ -360,33 +363,36 @@ def majority_vote_bucketed(
 def _packed_a2a_elect(vote_pos: jnp.ndarray, axis_name: str, w: int,
                       alive=None) -> jnp.ndarray:
     """Elected bool votes via all_to_all of 1-bit ballots + all_gather of
-    1-bit verdicts (~2 bits/param received per worker, W-independent)."""
+    1-bit verdicts (~2 bits/param received per worker, W-independent).
+
+    The bytes are in the codec's PLANAR order (``ops/codec`` docstring):
+    nothing but this function ever reads them, an election is per
+    coordinate, and every worker packs alike — so the chunk owner elects
+    bytes to bytes (``elect_packed_rows``) and only the final verdict is
+    unpacked. Worker j's chunk is a byte range of the wire, not a
+    coordinate range of the ballot."""
     n = vote_pos.shape[0]
     chunk = a2a_chunk_bytes(n, w)  # uint8 bytes per worker-chunk
-    pad = chunk * 8 * w - n
-    padded = jnp.concatenate([vote_pos, jnp.zeros((pad,), vote_pos.dtype)]) if pad else vote_pos
-    packed = pack_signs(padded).reshape(w, chunk)  # row j = my ballot for chunk j
+    packed = pack_wire(vote_pos)   # [ceil(n/8)] uint8
+    pad = w * chunk - packed.shape[0]
+    if pad:  # zero bytes elect zero bytes; unpack_wire never reads them
+        packed = jnp.concatenate([packed, jnp.zeros((pad,), jnp.uint8)])
+    packed = packed.reshape(w, chunk)  # row j = my ballot for chunk j
     if w > 1:  # phase 1: (W−1) peers each send me their copy of my chunk
         WIRE_TALLY.record("ici", (w - 1) * chunk)
     # phase 1: worker j receives every worker's row j → [W, chunk]
     with jax.named_scope("vote/wire"):
         arrived = lax.all_to_all(packed, axis_name, split_axis=0, concat_axis=0, tiled=True)
-    if alive is not None:
-        # the chunk owner sees every worker's row, so the masked tally is a
-        # row weighting; the threshold shrinks to the healthy quorum
-        weights = alive.astype(jnp.int32)
-        count = tally_packed_rows(arrived, weights)
-        verdict = count * 2 > weights.sum()            # tie → False (−1)
-    else:
-        count = tally_packed_rows(arrived)             # per-bit True tally
-        verdict = count * 2 > w                        # tie → False (−1)
+    # the chunk owner sees every worker's row, so the masked election is a
+    # row weighting and the threshold shrinks to the healthy quorum; a tie
+    # elects False (−1) either way
+    verdict_bits = elect_packed_rows(arrived, alive)
     if w > 1:  # phase 2: (W−1) peers each send me their chunk's verdict
         WIRE_TALLY.record("ici", (w - 1) * chunk)
     # phase 2: broadcast my chunk's packed verdict to everyone
-    verdict_bits = pack_signs(verdict)
     with jax.named_scope("vote/wire"):
         gathered = lax.all_gather(verdict_bits, axis_name)  # [W, chunk]
-    return unpack_signs(gathered.reshape(-1), (n,))
+    return unpack_wire(gathered.reshape(-1), (n,))
 
 
 def _intra_perm(w: int, g: int) -> list:

@@ -42,7 +42,11 @@ from distributed_lion_tpu.models.gpt2 import (
 )
 from distributed_lion_tpu.models.loss import clm_loss_and_metrics
 from distributed_lion_tpu.ops import xent as xent_ops
-from distributed_lion_tpu.ops.codec import vote_chunk_elems, wire_bytes_per_param
+from distributed_lion_tpu.ops.codec import (
+    vote_chunk_elems,
+    wire_bytes_per_param,
+    wire_codec,
+)
 from distributed_lion_tpu.optim import (
     distributed_lion,
     expand_worker_state,
@@ -772,6 +776,9 @@ class Trainer:
         if remat_decision is not None:
             emit(remat_decision.line())
             self.journal.event("remat_resolved", **remat_decision.fields())
+        if cfg.lion:
+            emit(f"[setup] vote: {cfg.wire} x{cfg.vote_buckets} buckets, "
+                 f"{wire_codec(cfg.wire)}")
         if cfg.zero1:
             shape = dict(mesh.shape)
             for ax in (TENSOR_AXIS, SEQ_AXIS):

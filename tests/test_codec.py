@@ -98,3 +98,73 @@ def test_tally_packed_rows_equals_unpacked_sum(rows, nbytes):
     got = jax.jit(tally_packed_rows)(jnp.asarray(packed), jnp.asarray(alive))
     np.testing.assert_array_equal(np.asarray(got),
                                   (bits * alive[:, None]).sum(0))
+
+
+@pytest.mark.parametrize("shape", [(0,), (1,), (7,), (8,), (4095,), (32768,),
+                                   (32769,), (100003,), (3, 32768 + 5)])
+def test_planar_wire_roundtrip(shape):
+    """The transient wires' planar codec, at sizes on both sides of its
+    32,768-vote group: the same number of bytes as ``pack_signs`` and the
+    same votes in them (equal popcount, so the pad bits are zeros) in
+    another order, and ``unpack_wire`` undoes ``pack_wire``."""
+    import jax
+
+    from distributed_lion_tpu.ops.codec import pack_wire, unpack_wire
+
+    n = int(np.prod(shape))
+    votes = np.random.default_rng(n).random(shape) < 0.5
+    packed = np.asarray(jax.jit(pack_wire)(jnp.asarray(votes)))
+    assert packed.dtype == np.uint8 and packed.shape == (packed_size(n),)
+    reference = np.asarray(pack_signs(jnp.asarray(votes)))
+    assert np.unpackbits(packed).sum() == votes.sum()
+    assert np.unpackbits(packed).sum() == np.unpackbits(reference).sum()
+    back = jax.jit(lambda p: unpack_wire(p, shape))(jnp.asarray(packed))
+    assert back.dtype == jnp.bool_ and back.shape == shape
+    np.testing.assert_array_equal(np.asarray(back), votes)
+    if n > 8 * 4096:  # plane j of a whole group is bit j of its 4,096 bytes
+        first = votes.reshape(-1)[:32768].reshape(8, 4096)
+        np.testing.assert_array_equal((packed[:4096] >> 3) & 1, first[3])
+
+
+@pytest.mark.parametrize("masked", [False, True], ids=["all", "alive"])
+@pytest.mark.parametrize("world", [2, 3, 4, 8])
+def test_packed_row_election_is_the_majority_of_the_bits(world, masked):
+    """``elect_packed_rows`` (bytes in, bytes out, nothing unpacked) elects
+    ``2 * sum(bits) > quorum`` per bit, ties and the all-dead quorum
+    included, for any bit order the rows share."""
+    import jax
+
+    from distributed_lion_tpu.ops.codec import elect_packed_rows
+
+    rng = np.random.default_rng(world)
+    nbytes = 4096 + 17
+    rows = rng.integers(0, 256, (world, nbytes), dtype=np.uint8)
+    rows[:, :64] = rng.integers(0, 2, (world, 1), dtype=np.uint8) * 255
+    bits = np.unpackbits(rows, axis=1).astype(np.int32)
+    masks = [None]
+    if masked:
+        masks = [rng.integers(0, 2, (world,)).astype(np.int32),
+                 np.ones((world,), np.int32), np.zeros((world,), np.int32),
+                 np.eye(world, dtype=np.int32)[0]]
+    for alive in masks:
+        weights = np.ones((world,), np.int32) if alive is None else alive
+        want = 2 * (bits * weights[:, None]).sum(0) > weights.sum()
+        if alive is None and world % 2 == 0:  # a tie elects 0 (-1)
+            assert (2 * bits.sum(0) == world).any()
+        got = jax.jit(elect_packed_rows)(
+            jnp.asarray(rows), None if alive is None else jnp.asarray(alive))
+        assert got.dtype == jnp.uint8 and got.shape == (nbytes,)
+        np.testing.assert_array_equal(
+            np.unpackbits(np.asarray(got)).astype(bool), want)
+
+
+@pytest.mark.parametrize("wire,says", [
+    ("sign_psum", "no codec (int8 ballots)"), ("packed_a2a", "planar codec"),
+    ("packed_allgather", "reference-order codec"),
+    ("hier:4", "reference-order codec")])
+def test_wire_codec_names_the_bit_order(wire, says):
+    from distributed_lion_tpu.ops.codec import wire_codec
+
+    assert wire_codec(wire) == says
+    with pytest.raises(ValueError):
+        wire_codec("carrier_pigeon")

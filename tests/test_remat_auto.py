@@ -266,5 +266,9 @@ def test_an_explicit_policy_is_not_a_resolution(capsys):
                     block_size=32),
         make_mesh(data=8), GPT2Config.tiny(remat_policy="dots"))
     trainer.close()
-    assert "[setup] remat:" not in capsys.readouterr().out
+    out = capsys.readouterr().out
+    assert "[setup] remat:" not in out
     assert _remat_eqns(trainer)
+    # the other set-up line a Lion trainer prints: the wire auto resolved
+    # to and which of ops/codec's two bit orders its bytes are in
+    assert "[setup] vote: packed_a2a x1 buckets, planar codec" in out
