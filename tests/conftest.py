@@ -10,8 +10,23 @@ if "xla_force_host_platform_device_count" not in _flags:
     os.environ["XLA_FLAGS"] = (_flags + " --xla_force_host_platform_device_count=8").strip()
 
 import jax  # noqa: E402
+import pytest  # noqa: E402
+
+from distributed_lion_tpu.parallel.mesh import make_mesh  # noqa: E402
 
 jax.config.update("jax_threefry_partitionable", True)
+
+
+# The virtual meshes every election / optimizer test runs over, built once a
+# worker. Bodies run over them COMPILED, through ``tests/_sharded.py``.
+@pytest.fixture(scope="session")
+def mesh8():
+    return make_mesh(data=8)
+
+
+@pytest.fixture(scope="session")
+def mesh4():
+    return make_mesh(data=4, devices=jax.devices()[:4])
 
 
 def pytest_configure(config):
@@ -34,8 +49,6 @@ PINNED_TO_AN_OLDER_MANIFEST = {
 
 
 def pytest_collection_modifyitems(config, items):
-    import pytest
-
     for item in items:
         why = PINNED_TO_AN_OLDER_MANIFEST.get(item.nodeid)
         if why:

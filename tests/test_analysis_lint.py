@@ -390,3 +390,72 @@ def test_metrics_fixture_and_metrics_module_clean():
     for rel in ("serve/metrics.py", "serve/engine.py",
                 "serve/replica_plane.py"):
         assert lint.lint_file(os.path.join(PKG, rel)) == [], rel
+
+
+# ------------------------------------------------------ the tests' own idiom
+# Files let off the pass below, each with its reason.
+EAGER_SHARD_MAP_ALLOWED = {
+    "test_chip_compile.py":
+        "never runs a body: it lowers and compiles each shard_map for a "
+        "described v5e, explicitly, through its own _compile",
+}
+
+
+def eager_shard_map_calls(path):
+    """``(line, what)`` for every ``shard_map(...)`` in the file whose
+    result is called outside ``jit``: called on the spot
+    (``shard_map(f, ...)(x)``), or bound to a name that the same function
+    then calls. A function decorated with ``jax.jit`` (or a ``partial`` of
+    it) is compiled whole, and ``jax.jit(shard_map(...))`` hands the result
+    to ``jit`` and not to a call: neither is a finding."""
+    import ast
+
+    def is_shard_map(node):
+        return isinstance(node, ast.Call) and (
+            getattr(node.func, "id", None) == "shard_map"
+            or getattr(node.func, "attr", None) == "shard_map")
+
+    def is_jit(dec):
+        if isinstance(dec, ast.Call):       # jax.jit(...), partial(jax.jit, ...)
+            return is_jit(dec.func) or any(is_jit(a) for a in dec.args)
+        return getattr(dec, "id", getattr(dec, "attr", None)) == "jit"
+
+    found = []
+
+    def walk(node, bound):
+        if isinstance(node, (ast.FunctionDef, ast.AsyncFunctionDef)):
+            if any(is_jit(d) for d in node.decorator_list):
+                return
+            bound = bound | {
+                t.id for a in ast.walk(node) if isinstance(a, ast.Assign)
+                and is_shard_map(a.value)
+                for t in a.targets if isinstance(t, ast.Name)}
+        if isinstance(node, ast.Call):
+            if is_shard_map(node.func):
+                found.append((node.lineno, "shard_map(...)(...)"))
+            elif getattr(node.func, "id", None) in bound:
+                found.append((node.lineno, f"{node.func.id}(...), a "
+                              "shard_map bound to a name"))
+        for child in ast.iter_child_nodes(node):
+            walk(child, bound)
+
+    with open(path, encoding="utf-8") as f:
+        walk(ast.parse(f.read(), path), frozenset())
+    return found
+
+
+def test_tests_run_their_shard_map_bodies_compiled():
+    """ISSUE 35: a ``shard_map`` called outside ``jit`` dispatches its body
+    primitive by primitive over the eight device threads (17 s for a 0.7 s
+    election), and enough of them cut tier-1 at its limit. Tests run a body
+    through ``tests/_sharded.py`` (``run_sharded`` / ``sharded``) or under
+    their own ``jax.jit``."""
+    tests = os.path.join(REPO, "tests")
+    found = []
+    for name in sorted(os.listdir(tests)):
+        if name.endswith(".py") and name not in EAGER_SHARD_MAP_ALLOWED:
+            found += [f"tests/{name}:{line}: {what}" for line, what in
+                      eager_shard_map_calls(os.path.join(tests, name))]
+    assert not found, "eager shard_map, use tests/_sharded.py:\n" + \
+        "\n".join(found)
+    assert set(EAGER_SHARD_MAP_ALLOWED) <= set(os.listdir(tests))

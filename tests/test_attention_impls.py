@@ -84,7 +84,7 @@ def _loss_and_grads(family: str, attn_impl: str):
         picked = jnp.take_along_axis(logits, tokens[:, 1:, None], axis=-1)
         return jnp.mean(jax.nn.logsumexp(logits, axis=-1) - picked[..., 0])
 
-    return jax.value_and_grad(loss)(params)
+    return jax.jit(jax.value_and_grad(loss))(params)   # one program each
 
 
 @pytest.mark.parametrize("family", ["gpt2", "llama"])

@@ -25,11 +25,6 @@ from distributed_lion_tpu.train.loop import (
 )
 
 
-@pytest.fixture(scope="module")
-def mesh8():
-    return make_mesh(data=8, devices=jax.devices()[:8])
-
-
 def test_big_replicated_dp_gets_budget_recipe(mesh8):
     r = resolve_auto_comm(TrainConfig(), mesh8, 124_000_000,
                           params_replicated=True)

@@ -30,35 +30,23 @@ import pytest
 
 import jax
 import jax.numpy as jnp
-from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
+from _sharded import (
+    assert_trees_equal,
+    sharded,
+    sharded_opt_step,
+    toy_problem,
+)
 from distributed_lion_tpu.ops.codec import (
     a2a_chunk_bytes,
     hier_chunk_slot_bytes,
     hier_ring_slot_bytes,
     vote_chunk_elems,
 )
-from distributed_lion_tpu.optim import (
-    distributed_lion,
-    expand_worker_state,
-    init_global_state,
-    squeeze_worker_state,
-)
-from distributed_lion_tpu.optim.lion import LionState
+from distributed_lion_tpu.optim import distributed_lion, init_global_state
 from distributed_lion_tpu.parallel import collectives
-from distributed_lion_tpu.parallel.mesh import make_mesh
 from distributed_lion_tpu.train import resilience
-
-
-@pytest.fixture(scope="module")
-def mesh8():
-    return make_mesh(data=8)
-
-
-@pytest.fixture(scope="module")
-def mesh4():
-    return make_mesh(data=4, devices=jax.devices()[:4])
 
 
 # ------------------------------------------------- independent reference
@@ -80,15 +68,14 @@ def _ref_hier(ballots: np.ndarray, g: int, alive=None) -> np.ndarray:
     return verdicts[counted].sum(0) * 2 > counted.sum()
 
 
-def _vote(mesh, ballots, wire, alive=None):
-    def body(b, *a):
-        return collectives.majority_vote(b[0], "data", wire,
-                                         a[0] if a else None)
+def _election(mesh, wire, masked):
+    """The compiled election ``(ballots[, alive]) -> elected``: the mask is
+    an argument, so one masked program serves every mask."""
+    def body(b, *alive):
+        return collectives.majority_vote(b[0], "data", wire, *alive)
 
-    args = (ballots,) if alive is None else (ballots, alive)
-    specs = (P("data"),) if alive is None else (P("data"), P())
-    return np.asarray(shard_map(body, mesh=mesh, in_specs=specs,
-                                out_specs=P(), check_vma=False)(*args))
+    specs = (P("data"), P()) if masked else (P("data"),)
+    return sharded(body, mesh, specs, P(), check_vma=False)
 
 
 @pytest.mark.parametrize("g", [2, 4, 8])
@@ -99,12 +86,13 @@ def test_hier_depth0_matches_reference(mesh8, g, n):
     the depth-0 bit-identity pin the ISSUE-8 refactor must not move."""
     rng = np.random.default_rng(5)
     ballots = jnp.asarray(rng.integers(0, 2, size=(8, n)).astype(bool))
-    got = _vote(mesh8, ballots, f"hier:{g}")
+    got = np.asarray(_election(mesh8, f"hier:{g}", masked=False)(ballots))
     np.testing.assert_array_equal(got, _ref_hier(np.asarray(ballots), g))
     # masked: one quarantined worker, and one fully-dead group
+    masked = _election(mesh8, f"hier:{g}", masked=True)
     for alive in (np.array([True] * 7 + [False]),
                   np.array([False] * g + [True] * (8 - g))):
-        got = _vote(mesh8, ballots, f"hier:{g}", jnp.asarray(alive))
+        got = np.asarray(masked(ballots, jnp.asarray(alive)))
         np.testing.assert_array_equal(
             got, _ref_hier(np.asarray(ballots), g, alive))
 
@@ -119,15 +107,16 @@ def test_mid_flight_quarantine_gates_stale_tally(mesh8):
     all_alive = np.ones(8, bool)
     g1_dead = np.array([True] * 4 + [False] * 4)
 
-    def run(launch_alive, consume_alive):
-        def body(b, la, ca):
-            slot = collectives.hier_launch(b[0], "data", 8, g, la)
-            return collectives.hier_consume(slot, n, "data", 8, g, ca)
+    def body(b, la, ca):
+        slot = collectives.hier_launch(b[0], "data", 8, g, la)
+        return collectives.hier_consume(slot, n, "data", 8, g, ca)
 
-        return np.asarray(shard_map(
-            body, mesh=mesh8, in_specs=(P("data"), P(), P()),
-            out_specs=P(), check_vma=False,
-        )(ballots, jnp.asarray(launch_alive), jnp.asarray(consume_alive)))
+    flight = sharded(body, mesh8, (P("data"), P(), P()), P(),
+                     check_vma=False)
+
+    def run(launch_alive, consume_alive):
+        return np.asarray(flight(ballots, jnp.asarray(launch_alive),
+                                 jnp.asarray(consume_alive)))
 
     ref_excluded = _ref_hier(np.asarray(ballots), g, g1_dead)
     # dead at launch, revived before consume: still excluded (its launch
@@ -141,46 +130,11 @@ def test_mid_flight_quarantine_gates_stale_tally(mesh8):
 
 
 # ----------------------------------------------------- optimizer matrix
-def _toy_problem(world=8, n=40):
-    key = jax.random.key(0)
-    params = {"w": jax.random.normal(key, (n,)), "b": jnp.zeros((3,))}
-    grads = {
-        "w": jax.random.normal(jax.random.key(1), (world, n)),
-        "b": jax.random.normal(jax.random.key(2), (world, 3)),
-    }
-    return params, grads
-
-
-def _run_steps(opt, params, grads_per_step, mesh, world, rng=None,
-               has_elected=False, depth=0, guard=False):
+def _run_steps(opt, params, grads_per_step, mesh, world, rng=None):
     """Drive opt.step under shard_map over a SEQUENCE of per-step grads;
     returns the param trajectory (host copies) + final state."""
     state = init_global_state(opt, params, world, rng=rng)
-    p_spec = jax.tree.map(lambda _: P(), params)
-    st_spec = LionState(
-        count=P(),
-        exp_avg=jax.tree.map(lambda _: P("data"), state.exp_avg),
-        rng=None if rng is None else P(),
-        elected=P() if has_elected else None,
-        health=P() if guard else None,
-        prev_ballot=P("data") if guard else None,
-        dcn_ring=P("data") if depth else None,
-    )
-    g_spec = jax.tree.map(lambda _: P("data"), grads_per_step[0])
-
-    @jax.jit
-    def step(params, grads, state):
-        def body(p, g, st):
-            st = squeeze_worker_state(st)
-            g = jax.tree.map(lambda x: x[0], g)
-            outs = opt.step(p, g, st)
-            return outs[0], expand_worker_state(outs[1])
-
-        return shard_map(
-            body, mesh=mesh, in_specs=(p_spec, g_spec, st_spec),
-            out_specs=(p_spec, st_spec), check_vma=False,
-        )(params, grads, state)
-
+    step = sharded_opt_step(opt, mesh, state)
     traj = [jax.device_get(params)]
     p, st = params, state
     for g in grads_per_step:
@@ -196,12 +150,6 @@ def _grad_seq(steps, world=8, n=40):
     } for i in range(steps)]
 
 
-def _assert_trees_equal(a, b):
-    jax.tree.map(
-        lambda x, y: np.testing.assert_array_equal(np.asarray(x),
-                                                   np.asarray(y)), a, b)
-
-
 @pytest.mark.parametrize("stoch", [False, True], ids=["det", "stoch"])
 @pytest.mark.parametrize("buckets", [1, 4])
 @pytest.mark.parametrize("guard", ["off", "enforce"])
@@ -210,27 +158,26 @@ def test_depth0_bit_identical_to_default_wire(mesh8, buckets, stoch, guard):
     the default hier wire across vote_buckets × det/stoch × guard (XLA
     path; the Pallas cell is below — its gate only admits det × guard
     combinations it compiled before this PR)."""
-    params, _ = _toy_problem()
+    params, _ = toy_problem()
     gseq = _grad_seq(3)
     kw = dict(learning_rate=0.01, weight_decay=0.01, wire="hier:4",
               vote_buckets=buckets, guard=guard,
               max_grad_norm=1.0 if stoch else None)
     rng = jax.random.key(7) if stoch else None
     base, base_st = _run_steps(distributed_lion(**kw), params, gseq, mesh8,
-                               8, rng=rng, guard=guard != "off")
+                               8, rng=rng)
     expl, expl_st = _run_steps(distributed_lion(dcn_pipeline_depth=0, **kw),
-                               params, gseq, mesh8, 8, rng=rng,
-                               guard=guard != "off")
+                               params, gseq, mesh8, 8, rng=rng)
     for a, b in zip(base, expl):
-        _assert_trees_equal(a, b)
-    _assert_trees_equal(base_st.exp_avg, expl_st.exp_avg)
+        assert_trees_equal(a, b)
+    assert_trees_equal(base_st.exp_avg, expl_st.exp_avg)
 
 
 def test_depth0_bit_identical_pallas(mesh8):
     """The Pallas window path at depth 0 (its gate) still matches the XLA
     default wire — and a depth > 0 build routes to the XLA path instead of
     the fused kernels, bit-identical to an explicit kernel='xla' build."""
-    params, _ = _toy_problem(n=300)
+    params, _ = toy_problem(n=300)
     gseq = _grad_seq(3, n=300)
     base, _ = _run_steps(
         distributed_lion(learning_rate=0.01, wire="hier:4", kernel="xla"),
@@ -240,17 +187,17 @@ def test_depth0_bit_identical_pallas(mesh8):
                          dcn_pipeline_depth=0, vote_buckets=4),
         params, gseq, mesh8, 8)
     for a, b in zip(base, pall):
-        _assert_trees_equal(a, b)
+        assert_trees_equal(a, b)
     d_pall, _ = _run_steps(
         distributed_lion(learning_rate=0.01, wire="hier:4", kernel="pallas",
                          dcn_pipeline_depth=1),
-        params, gseq, mesh8, 8, depth=1)
+        params, gseq, mesh8, 8)
     d_xla, _ = _run_steps(
         distributed_lion(learning_rate=0.01, wire="hier:4", kernel="xla",
                          dcn_pipeline_depth=1),
-        params, gseq, mesh8, 8, depth=1)
+        params, gseq, mesh8, 8)
     for a, b in zip(d_pall, d_xla):
-        _assert_trees_equal(a, b)
+        assert_trees_equal(a, b)
 
 
 @pytest.mark.parametrize("depth,buckets", [(1, 1), (2, 3)])
@@ -260,21 +207,21 @@ def test_staleness_shift_is_exact(mesh8, depth, buckets):
     constant lr the signs applied at depth-d step t are EXACTLY the signs
     the synchronous wire applies at step t−d — param deltas shift by d
     steps, bit-for-bit — and the first d steps apply no update at all."""
-    params, _ = _toy_problem()
+    params, _ = toy_problem()
     gseq = _grad_seq(6)
     kw = dict(learning_rate=0.01, weight_decay=0.0, wire="hier:4",
               vote_buckets=buckets)
     t0, _ = _run_steps(distributed_lion(**kw), params, gseq, mesh8, 8)
     td, _ = _run_steps(distributed_lion(dcn_pipeline_depth=depth, **kw),
-                       params, gseq, mesh8, 8, depth=depth)
+                       params, gseq, mesh8, 8)
     for t in range(depth):  # cold start: no update (wd=0 → params frozen)
-        _assert_trees_equal(td[t + 1], td[t])
+        assert_trees_equal(td[t + 1], td[t])
     for t in range(depth, 6):
         d_now = jax.tree.map(lambda a, b: np.asarray(a) - np.asarray(b),
                              td[t + 1], td[t])
         d_ref = jax.tree.map(lambda a, b: np.asarray(a) - np.asarray(b),
                              t0[t - depth + 1], t0[t - depth])
-        _assert_trees_equal(d_now, d_ref)
+        assert_trees_equal(d_now, d_ref)
 
 
 def test_lazy_cache_trails_by_depth(mesh8):
@@ -282,36 +229,15 @@ def test_lazy_cache_trails_by_depth(mesh8):
     after step t equals the synchronous lazy cache after step t−d (the
     consumed election lands in slot (t−d) mod K), and cold-start slots
     stay at their zero init."""
-    params, _ = _toy_problem()
+    params, _ = toy_problem()
     gseq = _grad_seq(9)
     kw = dict(learning_rate=0.01, weight_decay=0.0, wire="hier:4",
               vote_every=4)
 
     def caches(depth):
-        state = init_global_state(distributed_lion(
-            dcn_pipeline_depth=depth, **kw), params, 8)
         opt = distributed_lion(dcn_pipeline_depth=depth, **kw)
-        p_spec = jax.tree.map(lambda _: P(), params)
-        st_spec = LionState(
-            count=P(),
-            exp_avg=jax.tree.map(lambda _: P("data"), state.exp_avg),
-            rng=None, elected=P(),
-            dcn_ring=P("data") if depth else None)
-        g_spec = jax.tree.map(lambda _: P("data"), gseq[0])
-
-        @jax.jit
-        def step(params, grads, state):
-            def body(p, g, st):
-                st = squeeze_worker_state(st)
-                g = jax.tree.map(lambda x: x[0], g)
-                outs = opt.step(p, g, st)
-                return outs[0], expand_worker_state(outs[1])
-
-            return shard_map(
-                body, mesh=mesh8, in_specs=(p_spec, g_spec, st_spec),
-                out_specs=(p_spec, st_spec), check_vma=False,
-            )(params, grads, state)
-
+        state = init_global_state(opt, params, 8)
+        step = sharded_opt_step(opt, mesh8, state)
         out, p, st = [], params, state
         for g in gseq:
             p, st = step(p, g, st)
@@ -335,7 +261,7 @@ def test_dcn_delay_charges_sync_and_depth_hides(mesh4):
     so the residual wait measurably shrinks. Wait-based, not wall-based —
     immune to CI box noise — and the fault is timing-only: the parameter
     trajectory is bit-identical armed vs unarmed."""
-    params, _ = _toy_problem(world=4, n=20_000)
+    params, _ = toy_problem(world=4, n=20_000)
     gseq = _grad_seq(6, world=4, n=20_000)
     delay = 0.08
     kw = dict(learning_rate=0.01, wire="hier:2")
@@ -346,7 +272,7 @@ def test_dcn_delay_charges_sync_and_depth_hides(mesh4):
         try:
             traj, _ = _run_steps(
                 distributed_lion(dcn_pipeline_depth=depth, **kw), params,
-                gseq, mesh4, 4, depth=depth)
+                gseq, mesh4, 4)
             waits = collectives.DCN_WAIT.pop()
             return traj, sum(waits.values())
         finally:
@@ -356,7 +282,7 @@ def test_dcn_delay_charges_sync_and_depth_hides(mesh4):
     t0_armed, wait0 = run(0, True)
     t0_plain, _ = run(0, False)
     for a, b in zip(t0_armed, t0_plain):  # timing-only
-        _assert_trees_equal(a, b)
+        assert_trees_equal(a, b)
     # the synchronous wire pays ~the full round trip every step (first
     # consume may ride the compile window; demand 4 of 6)
     assert wait0 >= 4 * delay, wait0
@@ -379,30 +305,13 @@ def test_hier_depth_wire_bytes_drift_zero(mesh8, depth, ve, buckets):
     from distributed_lion_tpu.ops.codec import wire_bytes_per_param
     from distributed_lion_tpu.train import telemetry
 
-    params, grads = _toy_problem()
+    params, grads = toy_problem()
     n = sum(p.size for p in jax.tree.leaves(params))
     opt = distributed_lion(0.01, wire="hier:4", vote_every=ve,
                            vote_buckets=buckets, dcn_pipeline_depth=depth)
     state = init_global_state(opt, params, 8)
-    p_spec = jax.tree.map(lambda _: P(), params)
-    st_spec = LionState(
-        count=P(), exp_avg=jax.tree.map(lambda _: P("data"), state.exp_avg),
-        rng=None, elected=P() if ve > 1 else None,
-        dcn_ring=P("data") if depth else None)
-    g_spec = jax.tree.map(lambda _: P("data"), grads)
-
-    def step(params, grads, state):
-        def body(p, g, st):
-            st = squeeze_worker_state(st)
-            g = jax.tree.map(lambda x: x[0], g)
-            p2, st2 = opt.step(p, g, st)
-            return p2, expand_worker_state(st2)
-
-        return shard_map(body, mesh=mesh8, in_specs=(p_spec, g_spec, st_spec),
-                         out_specs=(p_spec, st_spec), check_vma=False,
-                         )(params, grads, state)
-
-    measured = telemetry.measure_step_wire(step, params, grads, state)
+    measured = telemetry.measure_step_wire(
+        sharded_opt_step(opt, mesh8, state), params, grads, state)
     acct = wire_bytes_per_param(n, 8, "hier:4", vote_every=ve,
                                 vote_buckets=buckets,
                                 dcn_pipeline_depth=depth)
@@ -442,7 +351,7 @@ def test_ring_slot_bytes_layout():
 def test_ring_rides_state_with_expected_shape(mesh8):
     opt = distributed_lion(wire="hier:4", dcn_pipeline_depth=3,
                            vote_buckets=2)
-    params, _ = _toy_problem()
+    params, _ = toy_problem()
     n = sum(p.size for p in jax.tree.leaves(params))
     state = init_global_state(opt, params, 8)
     assert state.dcn_ring.shape == (8, 3, hier_ring_slot_bytes(n, 8, 4, 2))

@@ -8,6 +8,7 @@ import numpy as np
 import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from _sharded import run_sharded, sharded
 from distributed_lion_tpu.optim import distributed_lion, init_global_state, lion
 from distributed_lion_tpu.optim.sharded import make_sharded_step, shard_state
 from distributed_lion_tpu.parallel import collectives, make_mesh
@@ -233,12 +234,9 @@ def test_dropout_robust_training_converges():
         p = p - lr * jnp.where(elected, 1.0, -1.0)
         return p, b2 * m + (1 - b2) * g
 
-    run = jax.jit(jax.shard_map(
-        step, mesh=mesh,
-        in_specs=(P(), P(DATA_AXIS), P(DATA_AXIS), P()),
-        out_specs=(P(), P(DATA_AXIS)),
-        check_vma=False,
-    ))
+    run = sharded(
+        step, mesh, (P(), P(DATA_AXIS), P(DATA_AXIS), P()),
+        (P(), P(DATA_AXIS)), check_vma=False)
     m = jnp.zeros((world, 64))
     key = jax.random.key(1)
     loss0 = float(jnp.mean((params - target) ** 2))
@@ -260,9 +258,8 @@ def test_dropout_robust_masked_vote():
     votes[:3] = True  # 3 True, 5 False → False wins alive; kill 4 False voters
     alive = np.ones((8, 1), bool)
     alive[3:7] = False
-    out = jax.shard_map(
-        f, mesh=mesh, in_specs=(P(DATA_AXIS), P(DATA_AXIS)), out_specs=P(),
-        check_vma=False,
-    )(jnp.asarray(votes), jnp.asarray(alive))
+    out = run_sharded(
+        f, mesh, (P(DATA_AXIS), P(DATA_AXIS)), P(),
+        jnp.asarray(votes), jnp.asarray(alive), check_vma=False)
     # survivors: workers 0,1,2 (True) and 7 (False) → 3 vs 1 → True elected
     assert np.asarray(out).all()

@@ -54,8 +54,9 @@ def test_sequence_logprob_chunked_matches_dense():
         return sequence_logprob_chunked(hidden, head, tokens, mask,
                                         n_chunks=4, emb_layout="dv").sum()
 
-    v_d, g_d = jax.value_and_grad(dense, argnums=(0, 1))(hidden, head)
-    v_c, g_c = jax.value_and_grad(chunked, argnums=(0, 1))(hidden, head)
+    v_d, g_d = jax.jit(jax.value_and_grad(dense, argnums=(0, 1)))(hidden, head)
+    v_c, g_c = jax.jit(jax.value_and_grad(chunked, argnums=(0, 1)))(hidden,
+                                                                    head)
     np.testing.assert_allclose(v_d, v_c, rtol=1e-5, atol=1e-5)
     for a, b in zip(g_d, g_c):
         np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-5)
@@ -96,10 +97,11 @@ def test_dpo_loss_and_grads_match_dense():
                          _rand_batch(np.random.default_rng(1), 2, 32,
                                      model_cfg.vocab_size))
 
-    (l_d, m_d), g_d = jax.value_and_grad(
-        lambda a: dense(a, batch, None), has_aux=True)(adapters)
-    (l_c, m_c), g_c = jax.value_and_grad(
-        lambda a: chunked(a, batch, None), has_aux=True)(adapters)
+    # each loss and its grads as ONE compiled program (ISSUE 35)
+    (l_d, m_d), g_d = jax.jit(jax.value_and_grad(
+        lambda a: dense(a, batch, None), has_aux=True))(adapters)
+    (l_c, m_c), g_c = jax.jit(jax.value_and_grad(
+        lambda a: chunked(a, batch, None), has_aux=True))(adapters)
     np.testing.assert_allclose(l_d, l_c, rtol=1e-5, atol=1e-5)
     np.testing.assert_allclose(m_d["reward_margin"], m_c["reward_margin"],
                                rtol=1e-4, atol=1e-5)

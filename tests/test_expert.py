@@ -6,13 +6,15 @@ zero output (residual carries them); the load-balance aux loss is ~1 at
 uniform routing; everything is differentiable.
 """
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 from jax.sharding import Mesh, PartitionSpec as P
-from jax import shard_map
 
+from _sharded import run_sharded
 from distributed_lion_tpu.parallel.expert import (
     capacity,
     moe_ffn,
@@ -22,6 +24,12 @@ from distributed_lion_tpu.parallel.expert import (
 
 E, D, F = 8, 6, 12
 EP = 4  # expert shards
+
+
+# the single-device layer as ONE compiled program a shape (ISSUE 35): eagerly
+# it is some eighty one-op programs
+ffn = jax.jit(functools.partial(moe_ffn, axis_name=None),
+              static_argnames="capacity_factor")
 
 
 @pytest.fixture(scope="module")
@@ -49,7 +57,7 @@ def _dense_reference(params, x):
 
 def test_single_device_matches_dense_reference(params):
     x = jax.random.normal(jax.random.key(1), (32, D))
-    y, aux = moe_ffn(params, x, capacity_factor=E * 1.0, axis_name=None)
+    y, aux = ffn(params, x, capacity_factor=E * 1.0)
     np.testing.assert_allclose(
         np.asarray(y), np.asarray(_dense_reference(params, x)), rtol=1e-5, atol=1e-6
     )
@@ -69,12 +77,11 @@ def test_expert_parallel_matches_single_device(params, ep_mesh):
         return y, aux[None]
 
     specs = moe_param_specs()
-    y_sharded, _ = shard_map(
-        body, mesh=ep_mesh, in_specs=(specs, P("expert")),
-        out_specs=(P("expert"), P("expert")),
-    )(params, x)
+    y_sharded, _ = run_sharded(
+        body, ep_mesh, (specs, P("expert")), (P("expert"), P("expert")),
+        params, x)
 
-    y_single, _ = moe_ffn(params, x, capacity_factor=cf, axis_name=None)
+    y_single, _ = ffn(params, x, capacity_factor=cf)
     np.testing.assert_allclose(
         np.asarray(y_sharded), np.asarray(y_single), rtol=1e-4, atol=1e-5
     )
@@ -82,8 +89,8 @@ def test_expert_parallel_matches_single_device(params, ep_mesh):
 
 def test_capacity_drops_zero_out_tokens(params):
     x = jax.random.normal(jax.random.key(3), (64, D))
-    y_full, _ = moe_ffn(params, x, capacity_factor=float(E), axis_name=None)
-    y_tight, _ = moe_ffn(params, x, capacity_factor=0.25, axis_name=None)
+    y_full, _ = ffn(params, x, capacity_factor=float(E))
+    y_tight, _ = ffn(params, x, capacity_factor=0.25)
     # tight capacity: some tokens dropped (zero rows), none invented
     dropped = np.all(np.asarray(y_tight) == 0, axis=-1)
     assert dropped.any()
@@ -99,7 +106,7 @@ def test_aux_loss_near_one_for_uniform_routing():
     # tokens: frac_tokens ~ 1/E, frac_probs ~ 1/E -> aux ~ 1
     params = moe_init(jax.random.key(7), E, D, F)
     x = jax.random.normal(jax.random.key(8), (4096, D)) * 5.0
-    _, aux = moe_ffn(params, x, axis_name=None)
+    _, aux = ffn(params, x)
     assert 0.8 < float(aux) < 1.6
 
 
@@ -110,7 +117,7 @@ def test_differentiable(params):
         y, aux = moe_ffn(p, x, axis_name=None)
         return jnp.sum(y**2) + 0.01 * aux
 
-    g = jax.grad(loss)(params)
+    g = jax.jit(jax.grad(loss))(params)
     assert all(np.isfinite(np.asarray(l)).all() for l in jax.tree.leaves(g))
     assert float(jnp.abs(g["gate"]).sum()) > 0
 
@@ -134,10 +141,9 @@ def test_bf16_routing_no_slot_collisions():
     x = jax.random.normal(jax.random.key(12), (n, D), jnp.bfloat16)
     params["gate"] = params["gate"].at[:, 0].add(5.0)
 
-    y16, _ = moe_ffn(params, x, capacity_factor=float(E), axis_name=None)
+    y16, _ = ffn(params, x, capacity_factor=float(E))
     p32 = jax.tree.map(lambda a: a.astype(jnp.float32), params)
-    y32, _ = moe_ffn(p32, x.astype(jnp.float32), capacity_factor=float(E),
-                     axis_name=None)
+    y32, _ = ffn(p32, x.astype(jnp.float32), capacity_factor=float(E))
     # no dropped-vs-kept disagreement and no summed-slot corruption:
     # bf16 output tracks the float32 reference within bf16 tolerance
     np.testing.assert_allclose(

@@ -42,7 +42,7 @@ def _out_and_grad(fn, qkv, w):
         out = fn(x)
         return jnp.sum(out.astype(jnp.float32) * w.astype(jnp.float32)), out
 
-    (_, out), grad = jax.value_and_grad(loss, has_aux=True)(qkv)
+    (_, out), grad = jax.jit(jax.value_and_grad(loss, has_aux=True))(qkv)
     return out, grad
 
 
@@ -347,7 +347,7 @@ def test_gpt2_through_the_kernel_equals_the_xla_path(monkeypatch):
         logits = gpt2.gpt2_apply(params, tokens, cfg)
         return jnp.mean(jax.nn.logsumexp(logits, axis=-1) - logits[..., 0])
 
-    want, g_want = jax.value_and_grad(loss)(params)
+    want, g_want = jax.jit(jax.value_and_grad(loss))(params)
     seen = []
 
     def kernel(qkv, n_head):
@@ -357,7 +357,7 @@ def test_gpt2_through_the_kernel_equals_the_xla_path(monkeypatch):
     monkeypatch.setattr(A.jax, "default_backend", lambda: "tpu")
     monkeypatch.setattr(F, "flash_qkv", kernel)
     jax.clear_caches()    # the remat block's trace is memoised by its avals
-    got, g_got = jax.value_and_grad(loss)(params)
+    got, g_got = jax.jit(jax.value_and_grad(loss))(params)
     jax.clear_caches()
     assert seen and all(s == (1, 1024, 3 * 128) for s in seen)
     np.testing.assert_allclose(got, want, rtol=1e-5)

@@ -17,6 +17,14 @@ from distributed_lion_tpu.models.llama import (
 )
 
 
+# Model calls run COMPILED, one program a shape (ISSUE 35): eagerly a forward
+# pass is a few hundred one-op programs. ``pos`` is traced, as in generate.
+_gpt2_apply = jax.jit(gpt2_apply, static_argnums=2)
+_gpt2_decode = jax.jit(gpt2_decode, static_argnums=2)
+_llama_apply = jax.jit(llama_apply, static_argnums=2)
+_llama_decode = jax.jit(llama_decode, static_argnums=2)
+
+
 def _tokens(vocab, b, t, seed=0):
     return jnp.asarray(
         np.random.default_rng(seed).integers(0, vocab, (b, t)), jnp.int32
@@ -27,15 +35,15 @@ def test_gpt2_decode_matches_apply():
     cfg = GPT2Config.tiny()
     params = gpt2_init(jax.random.key(0), cfg)
     toks = _tokens(cfg.vocab_size, 2, 12)
-    full = gpt2_apply(params, toks, cfg)
+    full = _gpt2_apply(params, toks, cfg)
 
     cache = gpt2_init_cache(cfg, 2, 16)
     # prefill with the first 8, then decode one token at a time
-    pre, cache = gpt2_decode(params, toks[:, :8], cfg, cache, 0)
+    pre, cache = _gpt2_decode(params, toks[:, :8], cfg, cache, 0)
     np.testing.assert_allclose(np.asarray(pre), np.asarray(full[:, :8]),
                                rtol=2e-2, atol=2e-2)
     for i in range(8, 12):
-        step, cache = gpt2_decode(params, toks[:, i:i + 1], cfg, cache, i)
+        step, cache = _gpt2_decode(params, toks[:, i:i + 1], cfg, cache, i)
         np.testing.assert_allclose(np.asarray(step[:, 0]), np.asarray(full[:, i]),
                                    rtol=2e-2, atol=2e-2)
 
@@ -44,14 +52,14 @@ def test_llama_decode_matches_apply():
     cfg = LlamaConfig.tiny()  # GQA: 4 heads, 2 kv heads
     params = llama_init(jax.random.key(1), cfg)
     toks = _tokens(cfg.vocab_size, 2, 10)
-    full = llama_apply(params, toks, cfg)
+    full = _llama_apply(params, toks, cfg)
 
     cache = llama_init_cache(cfg, 2, 12)
-    pre, cache = llama_decode(params, toks[:, :6], cfg, cache, 0)
+    pre, cache = _llama_decode(params, toks[:, :6], cfg, cache, 0)
     np.testing.assert_allclose(np.asarray(pre), np.asarray(full[:, :6]),
                                rtol=2e-2, atol=2e-2)
     for i in range(6, 10):
-        step, cache = llama_decode(params, toks[:, i:i + 1], cfg, cache, i)
+        step, cache = _llama_decode(params, toks[:, i:i + 1], cfg, cache, i)
         np.testing.assert_allclose(np.asarray(step[:, 0]), np.asarray(full[:, i]),
                                    rtol=2e-2, atol=2e-2)
 
@@ -69,7 +77,7 @@ def test_generate_greedy_deterministic():
     np.testing.assert_array_equal(np.asarray(out1), np.asarray(out2))
     assert int(np.asarray(out1).max()) < cfg.vocab_size
     # first generated token == argmax of the full forward's last position
-    full = gpt2_apply(params, prompt, cfg)
+    full = _gpt2_apply(params, prompt, cfg)
     np.testing.assert_array_equal(
         np.asarray(out1[:, 0]), np.asarray(jnp.argmax(full[:, -1], -1))
     )
@@ -204,7 +212,7 @@ def test_generate_max_new_tokens_1():
     init_cache = partial(gpt2_init_cache, cfg)
     out = np.asarray(generate(decode, init_cache, params, prompt, 1))
     assert out.shape == (2, 1)
-    full = gpt2_apply(params, prompt, cfg)
+    full = _gpt2_apply(params, prompt, cfg)
     np.testing.assert_array_equal(out[:, 0],
                                   np.asarray(jnp.argmax(full[:, -1], -1)))
 

@@ -15,8 +15,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 from jax.sharding import Mesh, PartitionSpec as P
-from jax import shard_map
 
+from _sharded import run_sharded
 from distributed_lion_tpu.ops.codec import parse_wire, wire_bytes_per_param
 from distributed_lion_tpu.parallel.collectives import (
     majority_vote,
@@ -26,21 +26,15 @@ from distributed_lion_tpu.parallel.collectives import (
 W = 8
 
 
-def _mesh():
-    return Mesh(np.array(jax.devices()[:W]), ("data",))
-
-
-def _vote_all(votes: np.ndarray, wire: str) -> np.ndarray:
+def _vote_all(mesh, votes: np.ndarray, wire: str) -> np.ndarray:
     """Run majority_vote over the data axis; votes is [W, n] bool.
     Returns the elected bools from every worker, stacked [W, n]."""
-    mesh = _mesh()
-
     def body(v):
         elected = majority_vote(v[0], "data", wire)
         return elected[None]
 
-    f = shard_map(body, mesh=mesh, in_specs=P("data"), out_specs=P("data"))
-    return np.asarray(f(jnp.asarray(votes)))
+    return np.asarray(run_sharded(body, mesh, P("data"), P("data"),
+                                  jnp.asarray(votes)))
 
 
 def test_parse_wire():
@@ -55,15 +49,15 @@ def test_parse_wire():
 
 
 @pytest.mark.parametrize("g", [1, W])
-def test_degenerate_groups_match_flat_vote(g):
+def test_degenerate_groups_match_flat_vote(mesh8, g):
     rng = np.random.default_rng(0)
     votes = rng.random((W, 203)) < 0.5
-    flat = _vote_all(votes, "sign_psum")
-    hier = _vote_all(votes, f"hier:{g}")
+    flat = _vote_all(mesh8, votes, "sign_psum")
+    hier = _vote_all(mesh8, votes, f"hier:{g}")
     np.testing.assert_array_equal(hier, flat)
 
 
-def test_majority_of_majorities_semantics():
+def test_majority_of_majorities_semantics(mesh8):
     # W=8, g=4 → 2 subgroups. Coordinate 0: ballots [+,+,+,-] [-,-,-,+]
     # → verdicts [+, -] → group-level tie → -1, though the flat vote is 4-4
     # tie → -1 as well. Coordinate 1: [+,+,-,-] [+,+,+,+] → group 0 tie → -,
@@ -72,18 +66,18 @@ def test_majority_of_majorities_semantics():
     votes = np.zeros((W, 2), bool)
     votes[:, 0] = [1, 1, 1, 0, 0, 0, 0, 1]
     votes[:, 1] = [1, 1, 0, 0, 1, 1, 1, 1]
-    flat = _vote_all(votes, "sign_psum")
-    hier = _vote_all(votes, "hier:4")
+    flat = _vote_all(mesh8, votes, "sign_psum")
+    hier = _vote_all(mesh8, votes, "hier:4")
     assert not flat[0, 0] and not hier[0, 0]
     assert flat[0, 1] and not hier[0, 1]
 
 
-def test_replica_consistency_and_unanimity():
+def test_replica_consistency_and_unanimity(mesh8):
     rng = np.random.default_rng(1)
     votes = rng.random((W, 130)) < 0.5
     votes[:, :10] = True   # unanimous + must elect +
     votes[:, 10:20] = False  # unanimous - must elect -
-    out = _vote_all(votes, "hier:2")
+    out = _vote_all(mesh8, votes, "hier:2")
     for w in range(1, W):
         np.testing.assert_array_equal(out[0], out[w])
     assert out[0, :10].all() and not out[0, 10:20].any()
@@ -101,8 +95,7 @@ def test_hier_matches_numpy_oracle(w, g):
     def body(v):
         return majority_vote(v[0], "data", f"hier:{g}")[None]
 
-    out = shard_map(body, mesh=mesh, in_specs=P("data"),
-                    out_specs=P("data"))(jnp.asarray(votes))
+    out = run_sharded(body, mesh, P("data"), P("data"), jnp.asarray(votes))
     got = np.asarray(out)[0]
 
     groups = votes.reshape(w // g, g, -1)
@@ -114,10 +107,10 @@ def test_hier_matches_numpy_oracle(w, g):
         np.testing.assert_array_equal(row, got)
 
 
-def test_group_size_must_divide_world():
+def test_group_size_must_divide_world(mesh8):
     votes = np.zeros((W, 16), bool)
     with pytest.raises(ValueError, match="divide"):
-        _vote_all(votes, "hier:3")
+        _vote_all(mesh8, votes, "hier:3")
 
 
 def test_wire_accounting_hier():

@@ -33,7 +33,6 @@ import jax
 import numpy as np
 import pytest
 
-from distributed_lion_tpu.parallel.mesh import make_mesh
 from distributed_lion_tpu.train import journal, resilience
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -53,11 +52,6 @@ run_analyze = _load_by_path("journal_run_analyze",
                             "distributed_lion_tpu/cli/run_analyze.py")
 validate_metrics = _load_by_path("journal_validate_metrics",
                                  "scripts/validate_metrics.py")
-
-
-@pytest.fixture(scope="module")
-def mesh8():
-    return make_mesh(data=8)
 
 
 def _tiny_cfg(**kw):

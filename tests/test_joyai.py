@@ -70,6 +70,20 @@ def rows_of(n_seq, width, seed=0):
         0, TINY["vocab_size"], (n_seq, width)).astype(np.int32)
 
 
+# Model calls run COMPILED, one program a shape: eagerly a forward pass is a
+# few hundred one-op programs (ISSUE 35).
+reference = jax.jit(lambda weights, rows: ref.forward(weights, rows, TINY))
+
+
+def program_of(cfg):
+    """``joyai_decode_paged`` at ``cfg``, compiled. Built anew in every
+    test: a trace holds the choices the backend's name made, and
+    ``interpret_kernels`` changes that name."""
+    return jax.jit(lambda params, toks, pages, tables, pos, valid=None:
+                   joyai_decode_paged(params, toks, cfg, pages, tables, pos,
+                                      valid))
+
+
 def interpret_kernels(monkeypatch):
     """Take the TPU's choices on the CPU: the Mosaic kernels in interpret
     mode (the test says "tpu" in the backend's place, as
@@ -83,11 +97,12 @@ def interpret_kernels(monkeypatch):
 
 def test_expanded_prefill_matches_the_reference(model):
     weights, params, cfg = model
+    program = program_of(cfg)
     rows = rows_of(2, 32)
     pages, tables = pool(cfg, 2)
-    logits, _ = joyai_decode_paged(params, rows, cfg, pages, tables,
-                                   jnp.zeros((2,), jnp.int32))
-    want = ref.forward(weights, rows, TINY)
+    logits, _ = program(params, rows, pages, tables,
+                        jnp.zeros((2,), jnp.int32))
+    want = reference(weights, rows)
     assert logits.shape == want.shape == (2, 32, TINY["vocab_size"])
     assert float(jnp.abs(logits - want).max()) < TOL
 
@@ -103,22 +118,22 @@ def test_prefill_then_decode_through_the_latent_pages(model, path,
     weights, params, cfg = model
     if path == "absorbed_kernel":
         interpret_kernels(monkeypatch)
+    program = program_of(cfg)
     rows = rows_of(3, 40, seed=1)
-    want = ref.forward(weights, rows, TINY)
+    want = reference(weights, rows)
     plens = np.asarray([9, 16, 23])
     pages, tables = pool(cfg, 3)
     valid = jnp.arange(24)[None, :] < jnp.asarray(plens)[:, None]
-    window, pages = joyai_decode_paged(params, rows[:, :24], cfg, pages,
-                                       tables, jnp.zeros((3,), jnp.int32),
-                                       valid)
+    window, pages = program(params, rows[:, :24], pages, tables,
+                            jnp.zeros((3,), jnp.int32), valid)
     for i, n in enumerate(plens):
         assert float(jnp.abs(window[i, :n] - want[i, :n]).max()) < TOL
     for j in range(10):
         pos = plens + j
         toks = rows[np.arange(3), pos][:, None]
-        logits, pages = joyai_decode_paged(
-            params, toks, cfg, pages, tables, jnp.asarray(pos, jnp.int32),
-            jnp.ones((3, 1), bool))
+        logits, pages = program(params, toks, pages, tables,
+                                jnp.asarray(pos, jnp.int32),
+                                jnp.ones((3, 1), bool))
         got = np.asarray(logits[:, 0])
         assert np.abs(got - np.asarray(want)[np.arange(3), pos]).max() < TOL
 
@@ -233,11 +248,11 @@ def test_lanes_without_a_token_reach_no_expert(model):
     x = jnp.asarray(np.random.default_rng(9).normal(0, 1, (24, 64)),
                     jnp.float32)
     valid = jnp.arange(24) % 3 != 1
-    got, st = expert.moe_dropless_ffn(moe, x, top_k=cfg.top_k,
-                                      scale=cfg.routed_scale, valid=valid,
-                                      return_counters=True)
-    alone = expert.moe_dropless_ffn(moe, x[valid], top_k=cfg.top_k,
-                                    scale=cfg.routed_scale)
+    ffn = jax.jit(functools.partial(
+        expert.moe_dropless_ffn, top_k=cfg.top_k, scale=cfg.routed_scale),
+        static_argnames="return_counters")
+    got, st = ffn(moe, x, valid=valid, return_counters=True)
+    alone = ffn(moe, x[valid])
     assert float(jnp.abs(got[valid] - alone).max()) < 1e-6
     assert float(jnp.abs(got[~valid]).max()) == 0.0
     assert int(st["moe_assignments"]) == 16 * cfg.top_k
@@ -308,8 +323,9 @@ def test_engine_batched_equals_solo(model, batched):
     _, out = batched
     assert all(c.reason == "length" and len(c.tokens) == 6
                for c in out.values())
+    alone = engine_of(model)    # one engine, its programs compiled once
     for req in requests()[:3]:
-        solo = engine_of(model).run([req])
+        solo = alone.run([req])
         assert solo[req.req_id].tokens == out[req.req_id].tokens
 
 

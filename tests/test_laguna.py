@@ -87,6 +87,25 @@ def rows_of(n_seq, width, seed=0):
         0, TINY["vocab_size"], (n_seq, width)).astype(np.int32)
 
 
+# Model calls run COMPILED, one program a shape: eagerly a forward pass is a
+# few hundred one-op programs (ISSUE 35).
+reference = jax.jit(lambda weights, rows: ref.forward(weights, rows, TINY))
+dropless_ffn = jax.jit(expert.moe_dropless_ffn, static_argnames=(
+    "top_k", "scale", "return_counters", "held"))
+
+
+def program_of(cfg):
+    """``laguna_decode_paged`` at ``cfg``, compiled. Built anew in every
+    test: a trace holds the choices the backend's name made, and
+    ``interpret_kernels`` changes that name."""
+    return jax.jit(
+        lambda params, toks, pages, tables, slots, pos, valid=None,
+        logit_index=None: laguna_decode_paged(
+            params, toks, cfg, pages, tables, slots, pos, valid,
+            logit_index=logit_index),
+        static_argnames="logit_index")
+
+
 def interpret_kernels(monkeypatch):
     """Take the TPU's choices on the CPU: the Mosaic kernels in interpret
     mode (the test says "tpu" in the backend's place, as
@@ -110,11 +129,14 @@ def test_attention_block_of_each_kind_matches_the_reference(model, layer):
     pages, tables, ring = pool(cfg, 2)
     pos = jnp.zeros((2,), jnp.int32)
     rope = cfg.rope_window if cfg.windowed[layer] else cfg.rope_full
-    got, leaves, _ = laguna._attention_block(
-        u, params["blocks"][layer]["attn"], cfg, layer, pages[layer], tables,
-        ring, pos, jnp.full((2,), 40, jnp.int32), None,
-        *rope.angles(jnp.arange(40)[None, :].repeat(2, 0)))
-    want = ref._attention(u, weights["layers"][layer], TINY, layer, None)
+    got, leaves, _ = jax.jit(
+        lambda u, attn, leaves, *angles: laguna._attention_block(
+            u, attn, cfg, layer, leaves, tables, ring, pos,
+            jnp.full((2,), 40, jnp.int32), None, *angles)
+    )(u, params["blocks"][layer]["attn"], pages[layer],
+      *rope.angles(jnp.arange(40)[None, :].repeat(2, 0)))
+    want = jax.jit(lambda u, w: ref._attention(u, w, TINY, layer, None))(
+        u, weights["layers"][layer])
     assert got.shape == want.shape
     assert float(jnp.abs(got - want).max()) < TOL
     # a window layer keeps the ring's pages and no more; a full layer all
@@ -126,24 +148,24 @@ def test_expert_layer_told_its_range_matches_the_reference(model):
     weights, params, cfg = model
     x = jnp.asarray(np.random.default_rng(3).standard_normal((1, 24, 64)),
                     jnp.float32)
-    got = expert.moe_dropless_ffn(params["blocks"][2]["moe"],
-                                  x.reshape(24, 64), top_k=cfg.top_k,
-                                  scale=cfg.routed_scale, held=cfg.held)
+    got = dropless_ffn(params["blocks"][2]["moe"], x.reshape(24, 64),
+                       top_k=cfg.top_k, scale=cfg.routed_scale, held=cfg.held)
     want = ref._experts(x, weights["layers"][2], TINY, None)[0]
     assert cfg.held == (0, 4) and float(jnp.abs(got - want).max()) < TOL
 
 
 def test_prefill_matches_the_reference(model):
     weights, params, cfg = model
+    program = program_of(cfg)
     rows = rows_of(2, 48)
     pages, tables, ring = pool(cfg, 2)
-    logits, _ = laguna_decode_paged(params, rows, cfg, pages, tables, ring,
-                                    jnp.zeros((2,), jnp.int32))
-    want = ref.forward(weights, rows, TINY)
+    logits, _ = program(params, rows, pages, tables, ring,
+                        jnp.zeros((2,), jnp.int32))
+    want = reference(weights, rows)
     assert logits.shape == want.shape == (2, 48, TINY["vocab_size"])
     assert float(jnp.abs(logits - want).max()) < TOL
-    one, _ = laguna_decode_paged(params, rows, cfg, pages, tables, ring,
-                                 jnp.zeros((2,), jnp.int32), logit_index=17)
+    one, _ = program(params, rows, pages, tables, ring,
+                     jnp.zeros((2,), jnp.int32), logit_index=17)
     assert float(jnp.abs(one[:, 0] - want[:, 17]).max()) < TOL
 
 
@@ -161,14 +183,14 @@ def test_prefill_then_decode_through_ring_and_pages(model, path, monkeypatch):
         interpret_kernels(monkeypatch)
         assert attn_ops.paged_kernel_applies(1, (4 * RING, BLOCK, 1, 128),
                                              jnp.float32)
+    program = program_of(cfg)
     rows = rows_of(4, 64, seed=1)
-    want = np.asarray(ref.forward(weights, rows, TINY))
+    want = np.asarray(reference(weights, rows))
     plens = np.asarray([5, 16, 17, 30])
     pages, tables, ring = pool(cfg, 4)
     valid = jnp.arange(32)[None, :] < jnp.asarray(plens)[:, None]
-    window, pages = laguna_decode_paged(
-        params, rows[:, :32], cfg, pages, tables, ring,
-        jnp.zeros((4,), jnp.int32), valid)
+    window, pages = program(params, rows[:, :32], pages, tables, ring,
+                            jnp.zeros((4,), jnp.int32), valid)
     for i, n in enumerate(plens):
         assert np.abs(np.asarray(window[i, :n]) - want[i, :n]).max() < TOL
     step = jax.jit(lambda toks, pages, pos: laguna_decode_paged(
@@ -187,12 +209,12 @@ def test_a_wrong_window_or_rope_fails_the_comparison(model):
     more than the tolerance."""
     weights, params, cfg = model
     rows = rows_of(1, 40, seed=5)
-    want = ref.forward(weights, rows, TINY)
+    want = reference(weights, rows)
     pages, tables, ring = pool(cfg, 1)
     for wrong in (dict(window=7), dict(rope_window=cfg.rope_full)):
         bad = dataclasses.replace(cfg, **wrong)
-        got, _ = laguna_decode_paged(params, rows, bad, pages, tables, ring,
-                                     jnp.zeros((1,), jnp.int32))
+        got, _ = program_of(bad)(params, rows, pages, tables, ring,
+                                 jnp.zeros((1,), jnp.int32))
         assert float(jnp.abs(got - want).max()) > 100 * TOL, wrong
 
 
@@ -447,7 +469,7 @@ def test_the_two_shares_sum_to_the_whole_layer(whole):
     kw = dict(top_k=cfg.top_k, scale=cfg.routed_scale, valid=valid,
               return_counters=True)
     (lo, c_lo), (hi, c_hi) = (
-        expert.moe_dropless_ffn(p, x, held=(first, 4), **kw)
+        dropless_ffn(p, x, held=(first, 4), **kw)
         for p, first in zip(shares(moe), (0, 4)))
     shared = laguna._mlp(jnp.where(valid[:, None], x, 0), moe["shared"])
     want = ref._experts(x[None], weights["layers"][1], WHOLE, None)[0]
@@ -469,8 +491,8 @@ def test_holding_every_expert_is_the_layer_as_it_was(whole):
     valid = jnp.arange(32) % 5 != 0
     kw = dict(top_k=cfg.top_k, scale=cfg.routed_scale, valid=valid,
               return_counters=True)
-    plain, c0 = expert.moe_dropless_ffn(moe, x, **kw)
-    told, c1 = expert.moe_dropless_ffn(moe, x, held=(0, 8), **kw)
+    plain, c0 = dropless_ffn(moe, x, **kw)
+    told, c1 = dropless_ffn(moe, x, held=(0, 8), **kw)
     np.testing.assert_array_equal(np.asarray(plain), np.asarray(told))
     assert {k: int(v) for k, v in c0.items()} \
         == {k: int(v) for k, v in c1.items()}
@@ -509,8 +531,8 @@ def test_an_expert_held_elsewhere_reads_no_bank(model):
     x = jnp.asarray(np.random.default_rng(6).standard_normal((16, 64)),
                     jnp.float32)
     one = dict(moe, **{k: moe[k][2:3] for k in ("w_gate", "w_up", "w_down")})
-    out, c = expert.moe_dropless_ffn(one, x, top_k=2, scale=2.5,
-                                     held=(2, 1), return_counters=True)
+    out, c = dropless_ffn(one, x, top_k=2, scale=2.5, held=(2, 1),
+                          return_counters=True)
     assert np.isfinite(np.asarray(out)).all()
     assert int(c["moe_experts_hit"]) <= 1
     assert int(c["moe_load_max"]) == int(c["moe_assignments"]) <= 16
@@ -545,15 +567,16 @@ def test_engine_batched_equals_solo_and_the_reference(model, batched):
     for req in requests():
         assert out[req.req_id].reason == "length"
         assert len(out[req.req_id].tokens) == req.max_new_tokens
+    alone = engine_of(model)    # one engine, its programs compiled once
     for req in requests()[:3]:
-        solo = engine_of(model).run([req])
+        solo = alone.run([req])
         assert solo[req.req_id].tokens == out[req.req_id].tokens
     # served greedily, the tokens are the reference's own first choices
     req = requests()[0]
     seq = list(req.tokens) + out[0].tokens
     rows = np.zeros((1, 64), np.int32)
     rows[0, :len(seq)] = seq
-    first = np.asarray(ref.forward(weights, rows, TINY)[0].argmax(-1))
+    first = np.asarray(reference(weights, rows)[0].argmax(-1))
     assert first[len(req.tokens) - 1:len(seq) - 1].tolist() == out[0].tokens
 
 

@@ -18,8 +18,8 @@ def test_world_130_int32_promotion():
         import jax
         jax.config.update("jax_platforms", "cpu")
         import numpy as np, jax.numpy as jnp
-        from jax import shard_map
         from jax.sharding import Mesh, PartitionSpec as P
+        from _sharded import run_sharded
         from distributed_lion_tpu.parallel.collectives import (
             majority_vote, vote_total)
 
@@ -32,9 +32,8 @@ def test_world_130_int32_promotion():
             t = vote_total(v[0], "data", "sign_psum")
             return t[None], majority_vote(v[0], "data", "hier:13")[None]
 
-        f = shard_map(body, mesh=mesh, in_specs=P("data"),
-                      out_specs=(P("data"), P("data")))
-        totals, hier = f(jnp.asarray(votes))
+        totals, hier = run_sharded(body, mesh, P("data"),
+                                   (P("data"), P("data")), jnp.asarray(votes))
         count = votes.sum(0)
         np.testing.assert_array_equal(np.asarray(totals[0]), count * 2 - W)
         assert np.asarray(totals).dtype == np.int32
@@ -46,7 +45,7 @@ def test_world_130_int32_promotion():
     """)
     env = dict(os.environ)
     env.update({"XLA_FLAGS": "--xla_force_host_platform_device_count=130",
-                "PYTHONPATH": "."})
+                "PYTHONPATH": os.pathsep.join([".", "tests"])})
     proc = subprocess.run(
         [sys.executable, "-c", code],
         capture_output=True, text=True, timeout=300,

@@ -389,6 +389,16 @@ def whole():
     return build(WHOLE)
 
 
+# the dropless layer and the reference's, compiled: one program a call
+dropless_ffn = jax.jit(expert.moe_dropless_ffn, static_argnames=(
+    "top_k", "scale", "return_counters", "held", "route_groups", "limits"))
+
+
+def ref_experts(x, layer, body):
+    return jax.jit(lambda x, layer: ref._experts(x[None], layer, body, 1,
+                                                 None)[0])(x, layer)
+
+
 def test_the_four_shares_sum_to_the_whole_layer(whole):
     """Experts 0-3, 4-7, 8-11, 12-15 held in turn (a routing group each),
     group-limited routing on, the shared expert counted once: the uncut
@@ -397,15 +407,15 @@ def test_the_four_shares_sum_to_the_whole_layer(whole):
     layer, moe = weights["layers"][1], params["blocks"][1]["moe"]
     x = jnp.asarray(np.random.default_rng(5).standard_normal((24, 64)),
                     jnp.float32)
-    want = ref._experts(x[None], layer, WHOLE, 1, None)[0]
+    want = ref_experts(x, layer, WHOLE)
     kw = dict(top_k=cfg.top_k, scale=cfg.routed_scale,
               route_groups=(cfg.n_group, cfg.topk_group))
     parts, rows = [], 0
     for first in range(0, 16, 4):
         held = dict(moe, **{k: moe[k][first:first + 4]
                             for k in ("w_gate", "w_up", "w_down")})
-        y, st = expert.moe_dropless_ffn(held, x, held=(first, 4),
-                                        return_counters=True, **kw)
+        y, st = dropless_ffn(held, x, held=(first, 4), return_counters=True,
+                             **kw)
         parts.append(y)
         rows += int(st["moe_assignments"])
         assert int(st["moe_routed"]) == 24 * cfg.top_k
@@ -414,7 +424,7 @@ def test_the_four_shares_sum_to_the_whole_layer(whole):
     assert float(jnp.abs(sum(parts) - 3 * shared - want).max()) < 1e-5
     assert float(jnp.abs(parts[0] - want).max()) > 1e-3
     # all held: the layer as one
-    y = expert.moe_dropless_ffn(moe, x, **kw)
+    y = dropless_ffn(moe, x, **kw)
     assert float(jnp.abs(y - want).max()) < 1e-5
 
 
@@ -454,9 +464,9 @@ def test_swiglu_limits_clamp_where_over_zero(whole):
                         jnp.float32)
     limited = dict(WHOLE, expert_swiglu_limit_list=[0, 0.05, 0],
                    share_expert_swiglu_limit_list=[0, 0.02, 0])
-    want = ref._experts(x[None], layer, limited, 1, None)[0]
-    plain = ref._experts(x[None], layer, WHOLE, 1, None)[0]
-    got = expert.moe_dropless_ffn(
+    want = ref_experts(x, layer, limited)
+    plain = ref_experts(x, layer, WHOLE)
+    got = dropless_ffn(
         moe, x, top_k=2, scale=2.5, route_groups=(4, 2),
         limits=LingConfig.from_hf(limited).limits(1))
     assert LingConfig.from_hf(limited).limits(1) == (0.05, 0.02)

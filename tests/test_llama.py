@@ -16,7 +16,8 @@ from distributed_lion_tpu.models.llama import (
 def test_forward_shapes():
     cfg = LlamaConfig.tiny()
     params = llama_init(jax.random.key(0), cfg)
-    logits = llama_apply(params, jnp.zeros((2, 16), jnp.int32), cfg)
+    logits = jax.jit(llama_apply, static_argnums=2)(
+        params, jnp.zeros((2, 16), jnp.int32), cfg)
     assert logits.shape == (2, 16, cfg.vocab_size)
     assert logits.dtype == jnp.float32
 
@@ -28,8 +29,9 @@ def test_causality():
     toks = rng.integers(0, cfg.vocab_size, size=(1, 10)).astype(np.int32)
     toks2 = toks.copy()
     toks2[0, -1] = (toks2[0, -1] + 1) % cfg.vocab_size
-    l1 = llama_apply(params, jnp.asarray(toks), cfg)
-    l2 = llama_apply(params, jnp.asarray(toks2), cfg)
+    apply = jax.jit(llama_apply, static_argnums=2)
+    l1 = apply(params, jnp.asarray(toks), cfg)
+    l2 = apply(params, jnp.asarray(toks2), cfg)
     np.testing.assert_array_equal(np.asarray(l1[0, :-1]), np.asarray(l2[0, :-1]))
 
 

@@ -46,7 +46,7 @@ def _train(mesh, cfg, n_steps=5):
 
 def test_llama_pp_forward_matches_sequential():
     """Pipeline forward loss == plain forward loss on identical params."""
-    from jax import shard_map
+    from _sharded import run_sharded
     from jax.sharding import PartitionSpec as P
 
     from distributed_lion_tpu.models.llama_pipe import (
@@ -69,14 +69,12 @@ def test_llama_pp_forward_matches_sequential():
         loss, m = loss_fn(pp_params, toks, None)
         return m["loss"]
 
-    loss_pp = shard_map(
-        body, mesh=mesh,
-        in_specs=(llama_pipeline_param_specs(), P()),
-        out_specs=P(), check_vma=False,
-    )(pparams, tokens)
+    loss_pp = run_sharded(
+        body, mesh, (llama_pipeline_param_specs(), P()), P(),
+        pparams, tokens, check_vma=False)
 
     loss_seq, _ = clm_loss_and_metrics(
-        llama_apply(params, tokens, MODEL), tokens)
+        jax.jit(llama_apply, static_argnums=2)(params, tokens, MODEL), tokens)
     np.testing.assert_allclose(float(loss_pp), float(loss_seq),
                                rtol=2e-4, atol=2e-4)
 
@@ -168,7 +166,7 @@ def test_run_clm_cli_llama_pp_smoke():
 
 def test_llama_pp_chunked_head_matches_dense():
     """pp × vocab_chunks on the untied lm_head (dv layout)."""
-    from jax import shard_map
+    from _sharded import run_sharded
     from jax.sharding import PartitionSpec as P
 
     from distributed_lion_tpu.models.llama_pipe import (
@@ -190,13 +188,11 @@ def test_llama_pp_chunked_head_matches_dense():
         loss, m = loss_fn(pp_params, toks, None)
         return m["loss"]
 
-    loss_pp = shard_map(
-        body, mesh=mesh,
-        in_specs=(llama_pipeline_param_specs(), P()),
-        out_specs=P(), check_vma=False,
-    )(pparams, tokens)
+    loss_pp = run_sharded(
+        body, mesh, (llama_pipeline_param_specs(), P()), P(),
+        pparams, tokens, check_vma=False)
     loss_seq, _ = clm_loss_and_metrics(
-        llama_apply(params, tokens, MODEL), tokens)
+        jax.jit(llama_apply, static_argnums=2)(params, tokens, MODEL), tokens)
     np.testing.assert_allclose(float(loss_pp), float(loss_seq),
                                rtol=2e-4, atol=2e-4)
 

@@ -15,6 +15,16 @@ from distributed_lion_tpu.models.lora import (
 from distributed_lion_tpu.ops.quant import quantize_tree
 
 
+# Forward passes run COMPILED, one program a shape (ISSUE 35): eagerly each is
+# a few hundred one-op programs.
+_apply = jax.jit(llama_apply, static_argnums=2)
+
+
+def _wrapped(cfg, base, lcfg):
+    return jax.jit(lora_apply_fn(lambda p, t: llama_apply(p, t, cfg), base,
+                                 lcfg))
+
+
 def _setup(quant=None):
     cfg = LlamaConfig.tiny()
     base = llama_init(jax.random.key(0), cfg)
@@ -37,10 +47,10 @@ def test_adapters_target_q_and_v():
 def test_fresh_adapters_are_identity():
     cfg, base, lcfg, adapters = _setup()
     toks = jnp.asarray(np.random.default_rng(0).integers(0, 256, (1, 8)), jnp.int32)
-    wrapped = lora_apply_fn(lambda p, t: llama_apply(p, t, cfg), base, lcfg)
+    wrapped = _wrapped(cfg, base, lcfg)
     np.testing.assert_allclose(
         np.asarray(wrapped(adapters, toks)),
-        np.asarray(llama_apply(base, toks, cfg)),
+        np.asarray(_apply(base, toks, cfg)),
         rtol=1e-5, atol=1e-5,
     )
 
@@ -50,11 +60,11 @@ def test_merge_matches_wrapped_apply():
     # give the adapters nonzero B so the delta is real
     adapters = jax.tree.map(lambda x: x + 0.01, adapters)
     toks = jnp.asarray(np.random.default_rng(1).integers(0, 256, (2, 8)), jnp.int32)
-    wrapped = lora_apply_fn(lambda p, t: llama_apply(p, t, cfg), base, lcfg)
+    wrapped = _wrapped(cfg, base, lcfg)
     merged = merge_lora(base, adapters, lcfg)
     np.testing.assert_allclose(
         np.asarray(wrapped(adapters, toks)),
-        np.asarray(llama_apply(merged, toks, cfg)),
+        np.asarray(_apply(merged, toks, cfg)),
         rtol=2e-2, atol=2e-2,  # bf16 compute tolerance
     )
 
@@ -87,11 +97,11 @@ def test_embedding_adapter_factored_matches_merged():
     adapters = lora_init(jax.random.key(1), base, lcfg)
     adapters = jax.tree.map(lambda x: x + 0.01, adapters)
     toks = jnp.asarray(np.random.default_rng(3).integers(0, 256, (2, 8)), jnp.int32)
-    wrapped = lora_apply_fn(lambda p, t: llama_apply(p, t, cfg), base, lcfg)
+    wrapped = _wrapped(cfg, base, lcfg)
     merged = merge_lora(base, adapters, lcfg)
     np.testing.assert_allclose(
         np.asarray(wrapped(adapters, toks)),
-        np.asarray(llama_apply(merged, toks, cfg)),
+        np.asarray(_apply(merged, toks, cfg)),
         rtol=2e-4, atol=2e-4,
     )
 
@@ -104,8 +114,9 @@ def test_embedding_adapter_gets_gradient():
     lcfg = LoraConfig(r=4, alpha=8, target_patterns=DPO_TARGET_PATTERNS)
     adapters = lora_init(jax.random.key(1), base, lcfg)
     toks = jnp.asarray(np.random.default_rng(4).integers(0, 256, (1, 8)), jnp.int32)
-    wrapped = lora_apply_fn(lambda p, t: llama_apply(p, t, cfg), base, lcfg)
-    g = jax.grad(lambda ad: wrapped(ad, toks).astype(jnp.float32).mean())(adapters)
+    wrapped = _wrapped(cfg, base, lcfg)
+    g = jax.jit(jax.grad(
+        lambda ad: wrapped(ad, toks).astype(jnp.float32).mean()))(adapters)
     # B=0 at init ⇒ signal arrives through wte's B via the gathered A rows
     assert np.abs(np.asarray(g["wte"]["B"])).sum() > 0
 
@@ -120,11 +131,10 @@ def test_adapter_dropout_train_vs_eval():
     adapters = lora_init(jax.random.key(1), base, lcfg)
     adapters = jax.tree.map(lambda x: x + 0.05, adapters)  # nonzero branch
     toks = jnp.asarray(np.random.default_rng(5).integers(0, 256, (1, 16)), jnp.int32)
-    wrapped = lora_apply_fn(lambda p, t: llama_apply(p, t, cfg), base, lcfg)
+    wrapped = _wrapped(cfg, base, lcfg)
     eval_out = wrapped(adapters, toks)
-    nodrop = lora_apply_fn(
-        lambda p, t: llama_apply(p, t, cfg), base,
-        LoraConfig(r=4, alpha=8, dropout=0.0))(adapters, toks)
+    nodrop = _wrapped(cfg, base, LoraConfig(r=4, alpha=8, dropout=0.0))(
+        adapters, toks)
     np.testing.assert_allclose(np.asarray(eval_out), np.asarray(nodrop),
                                rtol=1e-6, atol=1e-6)
     t1 = wrapped(adapters, toks, dropout_key=jax.random.key(2))
@@ -160,12 +170,12 @@ def test_embedding_adapter_peft_roundtrip(tmp_path):
 def test_quantized_base_trains_only_adapters():
     cfg, base, lcfg, adapters = _setup(quant="int8")
     toks = jnp.asarray(np.random.default_rng(2).integers(0, 256, (1, 8)), jnp.int32)
-    wrapped = lora_apply_fn(lambda p, t: llama_apply(p, t, cfg), base, lcfg)
+    wrapped = _wrapped(cfg, base, lcfg)
 
     def loss(ad):
         return wrapped(ad, toks).astype(jnp.float32).mean()
 
-    g = jax.grad(loss)(adapters)
+    g = jax.jit(jax.grad(loss))(adapters)
     # gradient exists for every adapter leaf and matches its shape
     for k, ab in g.items():
         assert ab["A"].shape == adapters[k]["A"].shape

@@ -42,9 +42,11 @@ def test_factored_matches_merged():
     )
     tokens = np.random.default_rng(0).integers(0, MODEL.vocab_size,
                                                size=(2, 16)).astype(np.int32)
-    factored = lora_apply_fn(
-        lambda p, t: llama_apply(p, t, MODEL), base, LORA)(adapters, tokens)
-    merged = llama_apply(merge_lora(base, adapters, LORA), tokens, MODEL)
+    # each as ONE compiled program (ISSUE 35), not op by op
+    factored = jax.jit(lora_apply_fn(
+        lambda p, t: llama_apply(p, t, MODEL), base, LORA))(adapters, tokens)
+    merged = jax.jit(llama_apply, static_argnums=2)(
+        merge_lora(base, adapters, LORA), tokens, MODEL)
     np.testing.assert_allclose(np.asarray(factored), np.asarray(merged),
                                rtol=2e-4, atol=2e-4)
 
@@ -273,7 +275,9 @@ def test_gpt2_lora_decode():
     eff = apply_adapters(base, adapters, cfg)
     tokens = np.random.default_rng(0).integers(0, model.vocab_size,
                                                size=(2, 8)).astype(np.int32)
-    full = gpt2_apply(eff, tokens, model)
-    dec, _ = gpt2_decode(eff, tokens, model, gpt2_init_cache(model, 2, 8), 0)
+    # each as ONE compiled program (ISSUE 35), not op by op
+    full = jax.jit(gpt2_apply, static_argnums=2)(eff, tokens, model)
+    dec, _ = jax.jit(gpt2_decode, static_argnums=2)(
+        eff, tokens, model, gpt2_init_cache(model, 2, 8), 0)
     np.testing.assert_allclose(np.asarray(dec), np.asarray(full),
                                rtol=1e-4, atol=1e-4)

@@ -7,12 +7,16 @@ import numpy as np
 from distributed_lion_tpu.models.gpt2 import GPT2Config, count_params, gpt2_apply, gpt2_init
 from distributed_lion_tpu.models.loss import clm_loss_and_metrics
 
+# the forward pass as ONE compiled program a shape (ISSUE 35): eagerly it is
+# a few hundred one-op programs
+apply = jax.jit(gpt2_apply, static_argnums=2)
+
 
 def test_forward_shapes_and_dtype():
     cfg = GPT2Config.tiny()
     params = gpt2_init(jax.random.key(0), cfg)
     tokens = jnp.zeros((2, 16), jnp.int32)
-    logits = gpt2_apply(params, tokens, cfg)
+    logits = apply(params, tokens, cfg)
     assert logits.shape == (2, 16, cfg.vocab_size)
     assert logits.dtype == jnp.float32  # f32 logits out of bf16 compute
 
@@ -25,8 +29,8 @@ def test_causality():
     toks = rng.integers(0, cfg.vocab_size, size=(1, 12)).astype(np.int32)
     toks2 = toks.copy()
     toks2[0, -1] = (toks2[0, -1] + 1) % cfg.vocab_size
-    l1 = gpt2_apply(params, jnp.asarray(toks), cfg)
-    l2 = gpt2_apply(params, jnp.asarray(toks2), cfg)
+    l1 = apply(params, jnp.asarray(toks), cfg)
+    l2 = apply(params, jnp.asarray(toks2), cfg)
     np.testing.assert_array_equal(np.asarray(l1[0, :-1]), np.asarray(l2[0, :-1]))
     assert not np.array_equal(np.asarray(l1[0, -1]), np.asarray(l2[0, -1]))
 
@@ -62,10 +66,10 @@ def test_dropout_changes_output_only_with_key():
     cfg = GPT2Config.tiny(dropout=0.5)
     params = gpt2_init(jax.random.key(0), cfg)
     toks = jnp.ones((1, 8), jnp.int32)
-    a = gpt2_apply(params, toks, cfg, dropout_key=jax.random.key(1))
-    b = gpt2_apply(params, toks, cfg, dropout_key=jax.random.key(2))
-    c = gpt2_apply(params, toks, cfg)  # deterministic (eval) path
-    d = gpt2_apply(params, toks, cfg)
+    a = apply(params, toks, cfg, dropout_key=jax.random.key(1))
+    b = apply(params, toks, cfg, dropout_key=jax.random.key(2))
+    c = apply(params, toks, cfg)  # deterministic (eval) path
+    d = apply(params, toks, cfg)
     assert not np.array_equal(np.asarray(a), np.asarray(b))
     np.testing.assert_array_equal(np.asarray(c), np.asarray(d))
 
@@ -91,6 +95,9 @@ def test_remat_policy_dots_matches_full():
             logits = gpt2_apply(p, toks, cfg)
             return jnp.mean(logits.astype(jnp.float32) ** 2)
 
+        # Eager on purpose (ISSUE 35): compiled whole, the two policies fuse
+        # differently around bf16 roundings and 357 of 4096 entries of one
+        # grad leaf leave the tolerance below (max abs 3.8e-6).
         return jax.value_and_grad(loss)(params)
 
     l_full, g_full = loss_for("full")

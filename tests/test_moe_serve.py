@@ -7,6 +7,7 @@ token-identical on the CPU mesh, NF4 expert banks), the composition pins
 ep, llama + ep, indivisible experts, MoE × draft:<k>), the engine's MoE
 routing stats, and the moe_serving evidence stage."""
 
+import functools
 import json
 import os
 
@@ -49,6 +50,16 @@ def _requests(vocab, n=4, max_new=8, lens=(3, 9, 5, 14, 2), seed=7):
             for i, L in enumerate(lens[:n])]
 
 
+# The model calls below run COMPILED, one program a shape: eagerly each is a
+# few hundred one-op programs (ISSUE 35).
+_ffn = jax.jit(functools.partial(moe_ffn, axis_name=None),
+               static_argnames=("capacity_factor", "capacity_override",
+                               "return_stats"))
+_dense = jax.jit(lambda p, t, cache, pos: gpt2_decode(p, t, MOE, cache, pos))
+_paged = jax.jit(lambda p, t, pages, tables, lens, valid=None:
+                 gpt2_decode_paged(p, t, MOE, pages, tables, lens, valid))
+
+
 def _engine(params, cfg=MOE, **kw):
     base = dict(max_seqs=4, block_size=4, max_blocks_per_seq=8)
     base.update(kw)
@@ -78,14 +89,14 @@ def test_pad_lanes_consume_zero_capacity_under_binding_cap():
     valid = np.zeros((16,), bool)
     valid[real_pos] = True
 
-    y_ref, _ = moe_ffn(params, x_real, axis_name=None, capacity_override=2)
-    y_pad, _ = moe_ffn(params, x_pad, axis_name=None, capacity_override=2,
-                       valid=jnp.asarray(valid))
+    y_ref, _ = _ffn(params, x_real, capacity_override=2)
+    y_pad, _ = _ffn(params, x_pad, capacity_override=2,
+                    valid=jnp.asarray(valid))
     np.testing.assert_array_equal(np.asarray(y_ref),
                                   np.asarray(y_pad)[real_pos])
     assert (np.asarray(y_pad)[~valid] == 0).all()
-    _, _, st = moe_ffn(params, x_pad, axis_name=None, capacity_override=2,
-                       valid=jnp.asarray(valid), return_stats=True)
+    _, _, st = _ffn(params, x_pad, capacity_override=2,
+                    valid=jnp.asarray(valid), return_stats=True)
     assert float(st["valid"]) == 10.0  # pads counted in NO column
     # the binding cap actually dropped real tokens (zero output rows) —
     # the equality pin is not vacuous: 10 tokens / 4 experts / cap 2
@@ -99,8 +110,8 @@ def test_all_valid_mask_is_bit_identical_to_no_mask():
     params = moe_init(jax.random.key(2), 4, 8, 16)
     x = jnp.asarray(np.random.default_rng(5).standard_normal((12, 8)),
                     jnp.float32)
-    y0, a0 = moe_ffn(params, x, axis_name=None)
-    y1, a1 = moe_ffn(params, x, axis_name=None, valid=jnp.ones((12,), bool))
+    y0, a0 = _ffn(params, x)
+    y1, a1 = _ffn(params, x, valid=jnp.ones((12,), bool))
     np.testing.assert_array_equal(np.asarray(y0), np.asarray(y1))
     assert float(a0) == float(a1)
 
@@ -115,8 +126,8 @@ def test_moe_routing_stats_against_capacity_budget():
     params["gate"] = jnp.zeros_like(params["gate"])
     x = jnp.asarray(np.random.default_rng(6).standard_normal((16, D)),
                     jnp.float32)
-    _, _, st = moe_ffn(params, x, axis_name=None, capacity_factor=1.0,
-                       capacity_override=16, return_stats=True)
+    _, _, st = _ffn(params, x, capacity_factor=1.0, capacity_override=16,
+                    return_stats=True)
     valid, kept, slots = (float(st[k]) for k in
                           ("valid", "kept", "capacity_slots"))
     assert valid == 16.0 and slots == 16.0  # budget = ceil(1.0*16/4) = 4
@@ -134,21 +145,19 @@ def test_paged_moe_decode_bit_identical_to_dense(moe_params):
         np.random.default_rng(0).integers(1, MOE.vocab_size, (B, L)),
         jnp.int32)
     cache = gpt2_init_cache(MOE, B, bs * nb_seq)
-    dl, cache = gpt2_decode(moe_params, toks, MOE, cache, 0)
+    dl, cache = _dense(moe_params, toks, cache, 0)
     pages = [{k: jnp.zeros((B * nb_seq, bs, MOE.n_head, MOE.head_dim),
                            MOE.compute_dtype) for k in ("k", "v")}
              for _ in range(MOE.n_layer)]
     tables = jnp.asarray([[2, 0, 1, 3], [5, 7, 4, 6]], jnp.int32)
-    pl, pages = gpt2_decode_paged(moe_params, toks, MOE, pages, tables,
-                                  jnp.zeros((B,), jnp.int32))
+    pl, pages = _paged(moe_params, toks, pages, tables,
+                       jnp.zeros((B,), jnp.int32))
     np.testing.assert_array_equal(np.asarray(dl), np.asarray(pl))
     t_cur = jnp.argmax(dl[:, -1], -1)
     lens = jnp.full((B,), L, jnp.int32)
     for i in range(5):
-        dl, cache = gpt2_decode(moe_params, t_cur[:, None], MOE, cache,
-                                L + i)
-        pl, pages = gpt2_decode_paged(moe_params, t_cur[:, None], MOE,
-                                      pages, tables, lens)
+        dl, cache = _dense(moe_params, t_cur[:, None], cache, L + i)
+        pl, pages = _paged(moe_params, t_cur[:, None], pages, tables, lens)
         np.testing.assert_array_equal(np.asarray(dl), np.asarray(pl))
         t_cur = jnp.argmax(dl[:, -1], -1)
         lens = lens + 1
@@ -172,16 +181,14 @@ def test_paged_moe_prefill_pad_tail_is_inert(moe_params):
 
     tables = jnp.asarray([[0, 1, 2, 3]], jnp.int32)
     zero = jnp.zeros((1,), jnp.int32)
-    ref, ref_pages = gpt2_decode_paged(moe_params, toks, MOE, pages(),
-                                       tables, zero)
+    ref, ref_pages = _paged(moe_params, toks, pages(), tables, zero)
     valid = (jnp.arange(P) < L)[None, :]
-    got, got_pages = gpt2_decode_paged(moe_params, padded, MOE, pages(),
-                                       tables, zero, valid)
+    got, got_pages = _paged(moe_params, padded, pages(), tables, zero, valid)
     np.testing.assert_array_equal(np.asarray(ref), np.asarray(got[:, :L]))
     nxt = jnp.argmax(ref[:, L - 1], -1)[:, None]
     lens = jnp.full((1,), L, jnp.int32)
-    a, _ = gpt2_decode_paged(moe_params, nxt, MOE, ref_pages, tables, lens)
-    b, _ = gpt2_decode_paged(moe_params, nxt, MOE, got_pages, tables, lens)
+    a, _ = _paged(moe_params, nxt, ref_pages, tables, lens)
+    b, _ = _paged(moe_params, nxt, got_pages, tables, lens)
     np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
@@ -196,8 +203,9 @@ def test_moe_engine_staggered_batched_matches_solo(moe_params, sampling):
     reqs = _requests(MOE.vocab_size)
     stag = _run(_engine(moe_params, **samp), reqs,
                 arrivals={0: 0, 1: 1, 2: 1, 3: 4})
+    alone = _engine(moe_params, **samp)  # one engine: programs compiled once
     for r in reqs:
-        solo = _run(_engine(moe_params, **samp), [r])
+        solo = _run(alone, [r])
         assert solo[r.req_id].tokens == stag[r.req_id].tokens, r.req_id
 
 

@@ -105,10 +105,13 @@ def test_moe_ep_forward_matches_ep1():
     # capacity as each (data, expert) shard saw), loss = token-weighted mean
     from distributed_lion_tpu.models.loss import clm_loss_and_metrics
 
-    losses = []
-    for i in range(0, rows, 2):
-        logits = gpt2_apply(params, tokens[i:i + 2], MODEL, return_aux=True)[0]
-        losses.append(float(clm_loss_and_metrics(logits, tokens[i:i + 2])[0]))
+    @jax.jit
+    def group_loss(params, pair):
+        logits = gpt2_apply(params, pair, MODEL, return_aux=True)[0]
+        return clm_loss_and_metrics(logits, pair)[0]
+
+    losses = [float(group_loss(params, tokens[i:i + 2]))
+              for i in range(0, rows, 2)]
     ref = float(np.mean(losses))
     np.testing.assert_allclose(got, ref, rtol=5e-4, atol=5e-4)
 
@@ -133,9 +136,12 @@ def test_moe_decode_matches_apply():
     params = gpt2_init(jax.random.key(2), model)
     tokens = np.random.default_rng(1).integers(
         0, model.vocab_size, size=(2, 12)).astype(np.int32)
-    full = gpt2_apply(params, tokens, model, return_aux=True)[0]
+    # each as ONE compiled program (ISSUE 35), not op by op
+    full = jax.jit(lambda p, t: gpt2_apply(p, t, model, return_aux=True)[0])(
+        params, tokens)
     cache = gpt2_init_cache(model, 2, 16)
-    dec, _ = gpt2_decode(params, tokens, model, cache, 0)
+    dec, _ = jax.jit(lambda p, t, c: gpt2_decode(p, t, model, c, 0))(
+        params, tokens, cache)
     np.testing.assert_allclose(np.asarray(dec), np.asarray(full),
                                rtol=2e-2, atol=2e-2)
 

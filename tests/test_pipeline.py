@@ -10,8 +10,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 from jax.sharding import Mesh, PartitionSpec as P
-from jax import shard_map
 
+from _sharded import run_sharded
 from distributed_lion_tpu.parallel.pipeline import (
     from_last_stage,
     from_microbatches,
@@ -54,9 +54,7 @@ def _run_pipeline(mesh, stacked, xm):
         local = jax.tree.map(lambda a: a[0], stage_params)  # [1, L/S,...] -> [L/S,...]
         return pipeline_apply(_layer_fn, local, xm, axis_name="pipe")
 
-    return shard_map(
-        body, mesh=mesh, in_specs=(P("pipe"), P()), out_specs=P("pipe")
-    )(stacked, xm)
+    return run_sharded(body, mesh, (P("pipe"), P()), P("pipe"), stacked, xm)
 
 
 def test_stack_unstack_roundtrip():
@@ -98,7 +96,7 @@ def test_from_last_stage_broadcasts(pipe_mesh):
         return from_last_stage(val, "pipe")[None]
 
     x = jnp.ones((3,))
-    out = shard_map(body, mesh=pipe_mesh, in_specs=(P(),), out_specs=P("pipe"))(x)
+    out = run_sharded(body, pipe_mesh, (P(),), P("pipe"), x)
     np.testing.assert_allclose(np.asarray(out), 7.0)  # every stage got it
 
 
@@ -118,9 +116,8 @@ def test_grads_match_sequential(pipe_mesh):
             loss = jnp.mean((from_microbatches(y) - target) ** 2)
             return loss[None]
 
-        return shard_map(
-            body, mesh=pipe_mesh, in_specs=(P("pipe"), P()), out_specs=P("pipe")
-        )(stacked, xm).mean()
+        return run_sharded(
+            body, pipe_mesh, (P("pipe"), P()), P("pipe"), stacked, xm).mean()
 
     def seq_loss(stacked, xm):
         layers_l = unstack_stage_params(stacked, N_LAYER)

@@ -67,7 +67,7 @@ def test_pp_forward_matches_sequential():
 
     from distributed_lion_tpu.models.loss import clm_loss_and_metrics
 
-    logits = gpt2_apply(params, tokens, MODEL)
+    logits = jax.jit(gpt2_apply, static_argnums=2)(params, tokens, MODEL)
     ref_loss, _ = clm_loss_and_metrics(logits, tokens)
 
     loss_fn = make_pipeline_loss(MODEL, n_micro=2)
@@ -196,8 +196,8 @@ def test_pp_chunked_head_matches_dense():
     params = gpt2_init(jax.random.key(0), MODEL)
     tokens = np.random.default_rng(0).integers(
         0, MODEL.vocab_size, size=(8, 32)).astype(np.int32)
-    ref_loss, _ = clm_loss_and_metrics(gpt2_apply(params, tokens, MODEL),
-                                       tokens)
+    ref_loss, _ = clm_loss_and_metrics(
+        jax.jit(gpt2_apply, static_argnums=2)(params, tokens, MODEL), tokens)
 
     loss_fn = make_pipeline_loss(MODEL, n_micro=2, vocab_chunks=4)
     pparams = pipeline_params(params, pp)
@@ -337,7 +337,7 @@ def test_sp_pipeline_oversized_total_sequence_fails_loudly():
         pipeline_param_specs,
         pipeline_params,
     )
-    from jax import shard_map
+    from _sharded import run_sharded
     from jax.sharding import PartitionSpec as P
 
     pp, sp = 2, 2
@@ -353,14 +353,10 @@ def test_sp_pipeline_oversized_total_sequence_fails_loudly():
     pparams = pipeline_params(params, pp)
     pspecs = pipeline_param_specs()
 
-    def run(pparams, tokens):
-        def body(p, t):
-            loss, _ = loss_fn(p, t, None)
-            return jax.lax.pmean(loss, "data")
-        return shard_map(
-            body, mesh=mesh, in_specs=(pspecs, P("data", "seq")),
-            out_specs=P(), check_vma=False,
-        )(pparams, tokens)
+    def body(p, t):
+        loss, _ = loss_fn(p, t, None)
+        return jax.lax.pmean(loss, "data")
 
     with pytest.raises(ValueError, match="exceeds n_ctx"):
-        jax.jit(run)(pparams, tokens)
+        run_sharded(body, mesh, (pspecs, P("data", "seq")), P(),
+                    pparams, tokens, check_vma=False)

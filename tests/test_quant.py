@@ -108,7 +108,7 @@ def test_sharded_dequant_matches_dense_slice():
     """shard_map over a column-sharded shaped QuantizedTensor: each rank's
     local dequant == the corresponding columns of the full dequant (the
     invariant TP's maybe_dequant relies on)."""
-    from jax import shard_map
+    from _sharded import run_sharded
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
     devs = jax.devices()[:2]
@@ -122,8 +122,7 @@ def test_sharded_dequant_matches_dense_slice():
     def local_dequant(q):
         return dequantize(q, jnp.float32)
 
-    out = shard_map(local_dequant, mesh=mesh, in_specs=spec,
-                    out_specs=spec)(qt_sharded)
+    out = run_sharded(local_dequant, mesh, spec, spec, qt_sharded)
     np.testing.assert_allclose(np.asarray(out),
                                np.asarray(dequantize(qt, jnp.float32)))
 

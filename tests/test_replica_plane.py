@@ -10,6 +10,7 @@ import importlib.util
 import json
 import os
 import time
+import types
 
 import jax
 import numpy as np
@@ -22,6 +23,7 @@ from distributed_lion_tpu.serve.engine import (
     ServeModel,
     ServingEngine,
 )
+from distributed_lion_tpu.serve import replica_plane
 from distributed_lion_tpu.serve.replica_plane import ServingFleet
 from distributed_lion_tpu.train import resilience
 
@@ -63,6 +65,20 @@ def _clean_serve_faults():
     resilience.inject_fault("serve", [])
     yield
     resilience.inject_fault("serve", [])
+
+
+_PLAIN = {}
+
+
+def _plain(reqs, arrivals=None):
+    """The never-disturbed run of ``reqs`` on one plain engine: what nine
+    tests compare a fleet's output with, computed once a workload
+    (tests/test_speculate.py's ``_plain_out`` idiom) and only read."""
+    key = (tuple((r.req_id, tuple(r.tokens), r.max_new_tokens, r.seed)
+                 for r in reqs), tuple(sorted((arrivals or {}).items())))
+    if key not in _PLAIN:
+        _PLAIN[key] = _factory()().run(_clone(reqs), arrivals)
+    return _PLAIN[key]
 
 
 def _fleet_run(specs, reqs, arrivals=None, replicas=2, eng_kw=None, **kw):
@@ -117,7 +133,7 @@ def test_recovery_record_resumes_token_identically():
     stream — the record is prompt + committed + seed and the pinned
     per-request keys do the rest."""
     reqs = _reqs()
-    base = _factory()().run(_clone(reqs))
+    base = _plain(reqs)
     for cut in (1, 2, 4):
         a = _factory()()
         for r in _clone(reqs):
@@ -207,7 +223,7 @@ def test_crash_migration_identity_speculative():
     the committed tokens on the survivor and the verify stream is the
     same pinned stream — outputs identical to the plain engine."""
     reqs = _reqs()
-    base = _factory()().run(_clone(reqs), dict(ARRIVALS))
+    base = _plain(reqs, dict(ARRIVALS))
     fleet, done = _fleet_run("replica_crash:0:3", reqs, dict(ARRIVALS),
                              eng_kw=dict(speculate="ngram:4"))
     for r in reqs:
@@ -222,7 +238,7 @@ def test_crash_mid_decode_loses_zero_accepted_tokens():
     committed history is at least as long as what was accepted)."""
     reqs = _reqs()
     fleet, done = _fleet_run("replica_crash:0:4", reqs, dict(ARRIVALS))
-    base = _factory()().run(_clone(reqs), dict(ARRIVALS))
+    base = _plain(reqs, dict(ARRIVALS))
     assert fleet.stats["migrations"] > 0
     lost = sum(max(len(base[r.req_id].tokens) - len(done[r.req_id].tokens),
                    0) for r in reqs)
@@ -273,7 +289,7 @@ def test_drain_stops_admission_and_finishes_residents():
     assert seen_draining
     assert fleet.lifecycle()[0] == "departed"
     assert "probe" in done  # served by the OTHER replica
-    base = _factory()().run(_clone(reqs), dict(ARRIVALS))
+    base = _plain(reqs, dict(ARRIVALS))
     for r in reqs:
         assert done[r.req_id].tokens == base[r.req_id].tokens
     # the drained replica's residents finished in place: nothing failed,
@@ -281,15 +297,30 @@ def test_drain_stops_admission_and_finishes_residents():
     assert fleet.stats["failed"] == 0 and fleet.stats["timeouts"] == 0
 
 
-def test_slow_replica_detected_and_routed_around():
+def test_slow_replica_detected_and_routed_around(monkeypatch):
+    # A counted clock: every reading costs 1 ms and the injected
+    # straggler's sleep advances it by what it asked for, so "four times
+    # the peers' median" is decided by the injected 40 ms and not by how
+    # busy the box is (the wall clock failed this under six workers).
+    clock = [0.0]
+
+    def now():
+        clock[0] += 1e-3
+        return clock[0]
+
+    def sleep(seconds):
+        clock[0] += seconds
+
+    monkeypatch.setattr(replica_plane, "time",
+                        types.SimpleNamespace(sleep=sleep))
     reqs = _reqs(n=8, max_new=8)
     arrivals = {i: i for i in range(len(reqs))}
     fleet, done = _fleet_run("slow_tick:0:40", reqs, arrivals,
-                             slow_min_ticks=3)
+                             slow_min_ticks=3, time_fn=now)
     assert fleet.stats["slow_detected"] >= 1
     r0, r1 = fleet.replicas
     assert r0.admissions < r1.admissions  # new work routed around
-    base = _factory()().run(_clone(reqs))
+    base = _plain(reqs)
     for r in reqs:  # outputs unaffected — slowness changes placement only
         assert done[r.req_id].tokens == base[r.req_id].tokens
 
@@ -321,7 +352,7 @@ def test_rejoin_serves_from_fresh_pool():
     # after probation the fresh engine's stats count post-rejoin work
     assert rep0.engine.stats["prefill_dispatches"] > 0
     assert fleet.lifecycle() == ["healthy", "healthy"]
-    base = _factory()().run(_clone(reqs))
+    base = _plain(reqs)
     for r in reqs:
         assert done[r.req_id].tokens == base[r.req_id].tokens
 
@@ -331,7 +362,7 @@ def test_retry_budget_exhaustion_fails_loudly():
     completes as ``failed`` with its partial output attached — never
     silent loss, never an infinite requeue loop."""
     reqs = _reqs()
-    base = _factory()().run(_clone(reqs))
+    base = _plain(reqs)
     fleet, done = _fleet_run(
         "replica_crash:0:2,replica_rejoin:0:4,replica_crash:1:3,"
         "replica_crash:0:7", reqs, max_retries=0)

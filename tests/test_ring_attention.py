@@ -7,6 +7,7 @@ import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
 
+from _sharded import run_sharded
 from distributed_lion_tpu.ops.attention import attention_xla
 from distributed_lion_tpu.parallel.mesh import SEQ_AXIS, make_mesh
 from distributed_lion_tpu.parallel.ring_attention import ring_attention, ulysses_attention
@@ -31,14 +32,9 @@ def test_matches_full_attention(impl):
     def f(q, k, v):
         return impl(q, k, v, SEQ_AXIS)
 
-    out = jax.jit(
-        jax.shard_map(
-            f, mesh=mesh,
-            in_specs=(P(None, None, SEQ_AXIS), P(None, None, SEQ_AXIS), P(None, None, SEQ_AXIS)),
-            out_specs=P(None, None, SEQ_AXIS),
-            check_vma=False,
-        )
-    )(q, k, v)
+    out = run_sharded(
+        f, mesh, (P(None, None, SEQ_AXIS),) * 3, P(None, None, SEQ_AXIS),
+        q, k, v, check_vma=False)
     np.testing.assert_allclose(np.asarray(out), np.asarray(expected), rtol=2e-4, atol=2e-5)
 
 
@@ -50,12 +46,9 @@ def test_ring_gradients_flow():
         def f(q, k, v):
             return ring_attention(q, k, v, SEQ_AXIS)
 
-        out = jax.shard_map(
-            f, mesh=mesh,
-            in_specs=(P(None, None, SEQ_AXIS),) * 3,
-            out_specs=P(None, None, SEQ_AXIS),
-            check_vma=False,
-        )(q, k, v)
+        out = run_sharded(
+            f, mesh, (P(None, None, SEQ_AXIS),) * 3, P(None, None, SEQ_AXIS),
+            q, k, v, check_vma=False)
         return (out.astype(jnp.float32) ** 2).sum()
 
     def loss_ref(q, k, v):
@@ -75,11 +68,6 @@ def test_ulysses_rejects_bad_head_count():
         return ulysses_attention(q, k, v, SEQ_AXIS)
 
     with pytest.raises(ValueError):
-        jax.jit(
-            jax.shard_map(
-                f, mesh=mesh,
-                in_specs=(P(None, None, SEQ_AXIS),) * 3,
-                out_specs=P(None, None, SEQ_AXIS),
-                check_vma=False,
-            )
-        )(q, k, v)
+        run_sharded(
+            f, mesh, (P(None, None, SEQ_AXIS),) * 3, P(None, None, SEQ_AXIS),
+            q, k, v, check_vma=False)

@@ -7,6 +7,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
+from _sharded import run_sharded
 from distributed_lion_tpu.data.sources import batch_iterator, synthetic_lm_dataset
 from distributed_lion_tpu.models.gpt2 import GPT2Config, gpt2_apply, gpt2_init
 from distributed_lion_tpu.parallel.mesh import DATA_AXIS, SEQ_AXIS, make_mesh
@@ -17,17 +18,15 @@ def test_sp_forward_matches_single_device():
     cfg = GPT2Config.tiny()
     params = gpt2_init(jax.random.key(0), cfg)
     toks = jnp.asarray(np.random.default_rng(0).integers(0, 256, (2, 64)), jnp.int32)
-    expected = gpt2_apply(params, toks, cfg)
+    expected = jax.jit(gpt2_apply, static_argnums=2)(params, toks, cfg)
 
     mesh = make_mesh(data=1, seq=4, devices=jax.devices()[:4])
 
     def f(p, t):
         return gpt2_apply(p, t, cfg, seq_axis=SEQ_AXIS)
 
-    out = jax.jit(
-        jax.shard_map(f, mesh=mesh, in_specs=(P(), P(None, SEQ_AXIS)),
-                      out_specs=P(None, SEQ_AXIS), check_vma=False)
-    )(params, toks)
+    out = run_sharded(f, mesh, (P(), P(None, SEQ_AXIS)), P(None, SEQ_AXIS),
+                      params, toks, check_vma=False)
     np.testing.assert_allclose(np.asarray(out), np.asarray(expected),
                                rtol=2e-2, atol=2e-2)
 
@@ -39,17 +38,15 @@ def test_llama_sp_forward_matches_single_device():
     cfg = LlamaConfig.tiny()
     params = llama_init(jax.random.key(1), cfg)
     toks = jnp.asarray(np.random.default_rng(1).integers(0, 256, (2, 64)), jnp.int32)
-    expected = llama_apply(params, toks, cfg)
+    expected = jax.jit(llama_apply, static_argnums=2)(params, toks, cfg)
 
     mesh = make_mesh(data=1, seq=4, devices=jax.devices()[:4])
 
     def f(p, t):
         return llama_apply(p, t, cfg, seq_axis=SEQ_AXIS)
 
-    out = jax.jit(
-        jax.shard_map(f, mesh=mesh, in_specs=(P(), P(None, SEQ_AXIS)),
-                      out_specs=P(None, SEQ_AXIS), check_vma=False)
-    )(params, toks)
+    out = run_sharded(f, mesh, (P(), P(None, SEQ_AXIS)), P(None, SEQ_AXIS),
+                      params, toks, check_vma=False)
     np.testing.assert_allclose(np.asarray(out), np.asarray(expected),
                                rtol=2e-2, atol=2e-2)
 
@@ -94,17 +91,15 @@ def test_ulysses_sp_forward_matches_single_device():
     cfg = GPT2Config.tiny(seq_impl="ulysses")
     params = gpt2_init(jax.random.key(2), cfg)
     toks = jnp.asarray(np.random.default_rng(2).integers(0, 256, (2, 64)), jnp.int32)
-    expected = gpt2_apply(params, toks, cfg)
+    expected = jax.jit(gpt2_apply, static_argnums=2)(params, toks, cfg)
 
     mesh = make_mesh(data=1, seq=4, devices=jax.devices()[:4])
 
     def f(p, t):
         return gpt2_apply(p, t, cfg, seq_axis=SEQ_AXIS)
 
-    out = jax.jit(
-        jax.shard_map(f, mesh=mesh, in_specs=(P(), P(None, SEQ_AXIS)),
-                      out_specs=P(None, SEQ_AXIS), check_vma=False)
-    )(params, toks)
+    out = run_sharded(f, mesh, (P(), P(None, SEQ_AXIS)), P(None, SEQ_AXIS),
+                      params, toks, check_vma=False)
     np.testing.assert_allclose(np.asarray(out), np.asarray(expected),
                                rtol=2e-2, atol=2e-2)
 

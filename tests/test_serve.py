@@ -30,6 +30,12 @@ from distributed_lion_tpu.serve.kv_cache import BlockTables
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
+# Model hooks called by hand run COMPILED, one program a shape (ISSUE 35):
+# eagerly a forward pass is a few hundred one-op programs. ``cfg`` is static.
+_compiled = {fn: jax.jit(fn, static_argnums=2) for fn in (
+    gpt2_decode, gpt2_decode_paged, llama_decode, llama_decode_paged)}
+
+
 def _tokens(vocab, b, t, seed=0):
     return jnp.asarray(
         np.random.default_rng(seed).integers(1, vocab, (b, t)), jnp.int32)
@@ -44,13 +50,13 @@ def test_paged_decode_bit_identical_to_dense(family):
     if family == "gpt2":
         cfg = GPT2Config.tiny()
         params = gpt2_init(jax.random.key(0), cfg)
-        dec, icache, decp, kv = gpt2_decode, gpt2_init_cache, \
-            gpt2_decode_paged, cfg.n_head
+        dec, icache, decp, kv = _compiled[gpt2_decode], gpt2_init_cache, \
+            _compiled[gpt2_decode_paged], cfg.n_head
     else:
         cfg = LlamaConfig.tiny()  # GQA: pages hold kv heads un-repeated
         params = llama_init(jax.random.key(0), cfg)
-        dec, icache, decp, kv = llama_decode, llama_init_cache, \
-            llama_decode_paged, cfg.n_kv_head
+        dec, icache, decp, kv = _compiled[llama_decode], llama_init_cache, \
+            _compiled[llama_decode_paged], cfg.n_kv_head
     B, L, bs, nb_seq = 2, 7, 4, 4          # both caches attend 16 slots
     toks = _tokens(cfg.vocab_size, B, L)
     cache = icache(cfg, B, bs * nb_seq)
@@ -96,10 +102,10 @@ def test_paged_prefill_valid_mask_drops_pad_tail():
 
     tables = jnp.asarray([[0, 1, 2, 3]], jnp.int32)
     zero = jnp.zeros((1,), jnp.int32)
-    ref, ref_pages = gpt2_decode_paged(params, toks, cfg, pages(), tables, zero)
+    decp = _compiled[gpt2_decode_paged]
+    ref, ref_pages = decp(params, toks, cfg, pages(), tables, zero)
     valid = (jnp.arange(P) < L)[None, :]
-    got, got_pages = gpt2_decode_paged(params, padded, cfg, pages(), tables,
-                                       zero, valid)
+    got, got_pages = decp(params, padded, cfg, pages(), tables, zero, valid)
     ref, got = np.asarray(ref), np.asarray(got[:, :L])
     assert ref.dtype == np.float32
     few_ulps = 4 * np.finfo(np.float32).eps * np.abs(ref).max()
@@ -120,9 +126,9 @@ def test_paged_prefill_valid_mask_drops_pad_tail():
     lens = jnp.full((1,), L, jnp.int32)
     # same shape, same program, SAME pages -> exact; and the decode step
     # over the padded prefill's pages agrees with the unpadded one's
-    a, _ = gpt2_decode_paged(params, nxt, cfg, ref_pages, tables, lens)
-    a2, _ = gpt2_decode_paged(params, nxt, cfg, ref_pages, tables, lens)
-    b, _ = gpt2_decode_paged(params, nxt, cfg, got_pages, tables, lens)
+    a, _ = decp(params, nxt, cfg, ref_pages, tables, lens)
+    a2, _ = decp(params, nxt, cfg, ref_pages, tables, lens)
+    b, _ = decp(params, nxt, cfg, got_pages, tables, lens)
     np.testing.assert_array_equal(np.asarray(a), np.asarray(a2))
     np.testing.assert_allclose(np.asarray(b), np.asarray(a), rtol=0,
                                atol=few_ulps)
@@ -646,8 +652,9 @@ def test_staggered_continuous_batching_matches_solo(sampling):
         [Request(r.req_id, list(r.tokens), r.max_new_tokens, r.seed)
          for r in reqs],
         arrivals={"r0": 0, "r1": 1, "r2": 1, "r3": 3, "r4": 5})
+    alone = _engine(params, cfg, **samp)  # one engine: programs compiled once
     for r in reqs:
-        solo = _engine(params, cfg, **samp).run(
+        solo = alone.run(
             [Request(r.req_id, list(r.tokens), r.max_new_tokens, r.seed)])
         assert batched[r.req_id].tokens == solo[r.req_id].tokens, r.req_id
         assert batched[r.req_id].reason == solo[r.req_id].reason
@@ -734,14 +741,13 @@ def test_moe_checkpoints_serve_through_the_paged_engine():
     pages = [{k: jnp.zeros((4, 4, cfg.n_head, cfg.head_dim),
                            cfg.compute_dtype) for k in ("k", "v")}
              for _ in range(cfg.n_layer)]
-    logits, _ = gpt2_decode_paged(params, jnp.ones((1, 4), jnp.int32), cfg,
-                                  pages,
-                                  jnp.asarray([[0, 1, 2, 3]], jnp.int32),
-                                  jnp.zeros((1,), jnp.int32))
+    logits, _ = _compiled[gpt2_decode_paged](
+        params, jnp.ones((1, 4), jnp.int32), cfg, pages,
+        jnp.asarray([[0, 1, 2, 3]], jnp.int32), jnp.zeros((1,), jnp.int32))
     assert np.isfinite(np.asarray(logits)).all()
-    logits, _ = gpt2_decode(params, jnp.ones((2, 4), jnp.int32), cfg,
-                            gpt2_init_cache(cfg, 2, 8), 0,
-                            jnp.asarray([0, 1], jnp.int32))
+    logits, _ = _compiled[gpt2_decode](
+        params, jnp.ones((2, 4), jnp.int32), cfg, gpt2_init_cache(cfg, 2, 8),
+        0, jnp.asarray([0, 1], jnp.int32))
     assert np.isfinite(np.asarray(logits)).all()
 
 

@@ -118,6 +118,7 @@ def test_injected_extra_psum_fails_naming_the_primitive():
     the primitive."""
     from jax.sharding import PartitionSpec as P
 
+    from _sharded import sharded
     from distributed_lion_tpu.parallel.mesh import EXPERT_AXIS
 
     cell = {"name": "moe_ep2_bf16", "moe": True, "ep": 2}
@@ -125,9 +126,9 @@ def test_injected_extra_psum_fails_naming_the_primitive():
     mcfg = serve_check._model_cfg(True)
     reg = eng._dispatches["decode"]
     orig = reg["jitted"]
-    leak_fn = jax.shard_map(
-        lambda x: jax.lax.psum(x, EXPERT_AXIS), mesh=eng._mesh,
-        in_specs=(P(),), out_specs=P(), check_vma=False)
+    leak_fn = sharded(
+        lambda x: jax.lax.psum(x, EXPERT_AXIS), eng._mesh, (P(),), P(),
+        check_vma=False)
 
     def bad(params, pages, *rest):
         (tok, st), pg = orig(params, pages, *rest)

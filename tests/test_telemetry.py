@@ -29,8 +29,16 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax import shard_map
 from jax.sharding import PartitionSpec as P
+
+from _sharded import (
+    assert_trees_equal,
+    lion_state_specs,
+    run_sharded,
+    sharded,
+    sharded_opt_step,
+    toy_problem,
+)
 
 from distributed_lion_tpu.ops.codec import wire_bytes_per_param
 from distributed_lion_tpu.optim import (
@@ -39,9 +47,7 @@ from distributed_lion_tpu.optim import (
     init_global_state,
     squeeze_worker_state,
 )
-from distributed_lion_tpu.optim.lion import LionState
 from distributed_lion_tpu.parallel import collectives
-from distributed_lion_tpu.parallel.mesh import make_mesh
 from distributed_lion_tpu.train import telemetry
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -50,23 +56,10 @@ N = 43  # ragged on purpose: with vote_every=4 the last rotation slot is
 # normalization must keep hist mass at exactly 1.0 through it
 
 
-@pytest.fixture(scope="module")
-def mesh8():
-    return make_mesh(data=8)
-
-
-def _toy():
-    params = {"w": jax.random.normal(jax.random.key(0), (40,)),
-              "b": jnp.zeros((3,))}
-    grads = {"w": jax.random.normal(jax.random.key(1), (8, 40)),
-             "b": jax.random.normal(jax.random.key(2), (8, 3))}
-    return params, grads
-
-
 def _run(mesh, telemetry_on, wire="sign_psum", buckets=1, ve=1, stoch=False,
          kern="xla", steps=5):
     """Drive opt.step under shard_map with the trainer's fold wiring."""
-    params, grads = _toy()
+    params, grads = toy_problem()
     opt = distributed_lion(
         0.01, weight_decay=0.01, wire=wire, vote_buckets=buckets,
         vote_every=ve, max_grad_norm=1.0 if stoch else None, kernel=kern,
@@ -74,39 +67,25 @@ def _run(mesh, telemetry_on, wire="sign_psum", buckets=1, ve=1, stoch=False,
     rng = jax.random.key(7) if stoch else None
     state = init_global_state(opt, params, 8, rng=rng)
     vh = telemetry.init_vote_health(N, ve) if telemetry_on else {}
-    p_spec = jax.tree.map(lambda _: P(), params)
-    st_spec = LionState(
-        count=P(), exp_avg=jax.tree.map(lambda _: P("data"), state.exp_avg),
-        rng=None if rng is None else P(), elected=P() if ve > 1 else None)
-    g_spec = jax.tree.map(lambda _: P("data"), grads)
+    st_spec = lion_state_specs(state)
     vh_spec = jax.tree.map(lambda _: P(), vh)
 
-    @jax.jit
-    def step(params, grads, state, vh):
-        def body(p, g, st, v):
-            st = squeeze_worker_state(st)
-            g = jax.tree.map(lambda x: x[0], g)
-            if telemetry_on:
-                p2, st2, frame = opt.step(p, g, st)
-                v = telemetry.fold(v, frame, "data", 8, N)
-            else:
-                p2, st2 = opt.step(p, g, st)
-            return p2, expand_worker_state(st2), v
+    def body(p, g, st, v):
+        st = squeeze_worker_state(st)
+        g = jax.tree.map(lambda x: x[0], g)
+        if telemetry_on:
+            p2, st2, frame = opt.step(p, g, st)
+            v = telemetry.fold(v, frame, "data", 8, N)
+        else:
+            p2, st2 = opt.step(p, g, st)
+        return p2, expand_worker_state(st2), v
 
-        return shard_map(
-            body, mesh=mesh, in_specs=(p_spec, g_spec, st_spec, vh_spec),
-            out_specs=(p_spec, st_spec, vh_spec), check_vma=False,
-        )(params, grads, state, vh)
-
+    step = sharded(body, mesh, (P(), P("data"), st_spec, vh_spec),
+                   (P(), st_spec, vh_spec), check_vma=False)
     p, st, v = params, state, vh
     for _ in range(steps):
         p, st, v = step(p, grads, st, v)
     return p, st, v
-
-
-def _eq(a, b):
-    jax.tree.map(lambda x, y: np.testing.assert_array_equal(
-        np.asarray(x), np.asarray(y)), a, b)
 
 
 # ----------------------------------------------------- observational contract
@@ -122,9 +101,9 @@ def test_vote_health_bucket_invariant_and_elections_unperturbed(
     p_off, st_off, _ = _run(mesh8, False, ve=ve, stoch=stoch)
     runs = {b: _run(mesh8, True, ve=ve, stoch=stoch, buckets=b)
             for b in (1, 4)}
-    _eq(runs[1][2], runs[4][2])                    # vh bitwise across B
-    _eq(p_off, runs[1][0])                         # params untouched
-    _eq(st_off.exp_avg, runs[1][1].exp_avg)        # momentum untouched
+    assert_trees_equal(runs[1][2], runs[4][2])  # vh bitwise across B
+    assert_trees_equal(p_off, runs[1][0])  # params untouched
+    assert_trees_equal(st_off.exp_avg, runs[1][1].exp_avg)  # momentum too
     d = telemetry.drain(runs[1][2], margin_exact=True)
     # sign_psum moves the exact tally: every voted coordinate lands in a
     # margin bin, so mass == 1 even through the zero-coordinate lazy slot
@@ -161,7 +140,7 @@ def test_proxy_wire_hist_zeroed_not_faked(mesh8):
     the election) still reports."""
     p_off, _, _ = _run(mesh8, False, wire="packed_a2a")
     p_on, _, vh = _run(mesh8, True, wire="packed_a2a")
-    _eq(p_off, p_on)
+    assert_trees_equal(p_off, p_on)
     d = telemetry.drain(vh, margin_exact=False)
     assert d["hist_mass"] == 0.0 and d["margin_exact"] == 0
     assert 0.0 < d["disagree_frac"] < 1.0
@@ -178,10 +157,10 @@ def test_pallas_telemetry_matches_xla_and_bucket_invariant(mesh8):
     hardware the kernel is opaque and the wobble disappears)."""
     _, _, v_x = _run(mesh8, True, kern="xla", buckets=1, steps=1)
     _, _, v_p = _run(mesh8, True, kern="pallas", buckets=3, steps=1)
-    _eq(v_x, v_p)
+    assert_trees_equal(v_x, v_p)
     r1 = _run(mesh8, True, kern="pallas", buckets=1)
     r3 = _run(mesh8, True, kern="pallas", buckets=3)
-    _eq(r1[2], r3[2])
+    assert_trees_equal(r1[2], r3[2])
     p_off, _, _ = _run(mesh8, False, kern="pallas", buckets=3)
     jax.tree.map(lambda a, b: np.testing.assert_allclose(
         np.asarray(a), np.asarray(b), atol=1e-5), p_off, r3[0])
@@ -218,28 +197,12 @@ def test_measured_wire_equals_analytic_exactly(mesh8, wire, ve, buckets):
     bytes-received accounting EXACTLY — per optimizer step, through lazy
     slicing and bucket splits, including hier's DCN leg. Abstract eval
     only: no compile, no execution."""
-    params, grads = _toy()
+    params, grads = toy_problem()
     opt = distributed_lion(0.01, wire=wire, vote_every=ve,
                            vote_buckets=buckets)
     state = init_global_state(opt, params, 8)
-    p_spec = jax.tree.map(lambda _: P(), params)
-    st_spec = LionState(
-        count=P(), exp_avg=jax.tree.map(lambda _: P("data"), state.exp_avg),
-        rng=None, elected=P() if ve > 1 else None)
-    g_spec = jax.tree.map(lambda _: P("data"), grads)
-
-    def step(params, grads, state):
-        def body(p, g, st):
-            st = squeeze_worker_state(st)
-            g = jax.tree.map(lambda x: x[0], g)
-            p2, st2 = opt.step(p, g, st)
-            return p2, expand_worker_state(st2)
-
-        return shard_map(body, mesh=mesh8, in_specs=(p_spec, g_spec, st_spec),
-                         out_specs=(p_spec, st_spec), check_vma=False,
-                         )(params, grads, state)
-
-    measured = telemetry.measure_step_wire(step, params, grads, state)
+    measured = telemetry.measure_step_wire(
+        sharded_opt_step(opt, mesh8, state), params, grads, state)
     acct = wire_bytes_per_param(N, 8, wire, vote_every=ve,
                                 vote_buckets=buckets)
     assert measured["bytes_per_step"] == acct["bytes_per_step"], (
@@ -258,13 +221,12 @@ def test_wire_tally_inert_outside_capture(mesh8):
         return collectives.majority_vote_bucketed(b[0], "data",
                                                   "sign_psum", 2)
 
-    out = shard_map(f, mesh=mesh8, in_specs=(P("data"),), out_specs=P(),
-                    check_vma=False)(jnp.tile(ballots, (8, 1)))
+    out = run_sharded(f, mesh8, (P("data"),), P(), jnp.tile(ballots, (8, 1)),
+                      check_vma=False)
     assert np.asarray(out).all()
     with collectives.WIRE_TALLY.capture() as entries:
-        jax.eval_shape(
-            lambda b: shard_map(f, mesh=mesh8, in_specs=(P("data"),),
-                                out_specs=P(), check_vma=False)(b),
+        jax.eval_shape(  # a jit of its own: the one above is traced already
+            sharded(f, mesh8, (P("data"),), P(), check_vma=False),
             jnp.tile(ballots, (8, 1)))
     assert len(entries) == 2  # one record per bucket collective
 

@@ -6,6 +6,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
+from _sharded import run_sharded
 from distributed_lion_tpu.data.sources import batch_iterator, synthetic_lm_dataset
 from distributed_lion_tpu.models.gpt2 import GPT2Config, gpt2_apply, gpt2_init
 from distributed_lion_tpu.parallel.mesh import TENSOR_AXIS, make_mesh
@@ -17,7 +18,7 @@ def test_tp_forward_matches_single_device():
     cfg = GPT2Config.tiny()
     params = gpt2_init(jax.random.key(0), cfg)
     toks = jnp.asarray(np.random.default_rng(0).integers(0, 256, (2, 16)), jnp.int32)
-    expected = gpt2_apply(params, toks, cfg)
+    expected = jax.jit(gpt2_apply, static_argnums=2)(params, toks, cfg)
 
     mesh = make_mesh(data=1, tensor=4, devices=jax.devices()[:4])
     specs = gpt2_param_specs(cfg)
@@ -25,10 +26,8 @@ def test_tp_forward_matches_single_device():
     def f(p, t):
         return gpt2_apply(p, t, cfg, tp_axis=TENSOR_AXIS)
 
-    out = jax.jit(
-        jax.shard_map(f, mesh=mesh, in_specs=(specs, P()), out_specs=P(),
+    out = run_sharded(f, mesh, (specs, P()), P(), params, toks,
                       check_vma=False)
-    )(params, toks)
     np.testing.assert_allclose(np.asarray(out), np.asarray(expected), rtol=2e-2, atol=2e-2)
 
 
@@ -68,7 +67,7 @@ def test_llama_tp_forward_matches_single_device():
     cfg = LlamaConfig.tiny()  # 4 heads, 2 kv heads → tp=2 divides both
     params = llama_init(jax.random.key(0), cfg)
     toks = jnp.asarray(np.random.default_rng(1).integers(0, 256, (2, 16)), jnp.int32)
-    expected = llama_apply(params, toks, cfg)
+    expected = jax.jit(llama_apply, static_argnums=2)(params, toks, cfg)
 
     mesh = make_mesh(data=1, tensor=2, devices=jax.devices()[:2])
     specs = llama_param_specs(cfg)
@@ -76,10 +75,8 @@ def test_llama_tp_forward_matches_single_device():
     def f(p, t):
         return llama_apply(p, t, cfg, tp_axis=TENSOR_AXIS)
 
-    out = jax.jit(
-        jax.shard_map(f, mesh=mesh, in_specs=(specs, P()), out_specs=P(),
+    out = run_sharded(f, mesh, (specs, P()), P(), params, toks,
                       check_vma=False)
-    )(params, toks)
     np.testing.assert_allclose(np.asarray(out), np.asarray(expected), rtol=2e-2, atol=2e-2)
 
 
@@ -95,16 +92,18 @@ def test_gpt2_lora_targets_stacked_qkv():
     assert ab["A"].shape == (64, 4) and ab["B"].shape == (4, 3, 64)
     # identity at init, merge consistent with wrapped apply after perturbation
     toks = jnp.asarray(np.random.default_rng(2).integers(0, 256, (1, 8)), jnp.int32)
-    wrapped = lora_apply_fn(lambda p, t: gpt2_apply(p, t, cfg), base, lcfg)
+    wrapped = jax.jit(lora_apply_fn(lambda p, t: gpt2_apply(p, t, cfg), base,
+                                    lcfg))
+    apply = jax.jit(gpt2_apply, static_argnums=2)
     np.testing.assert_allclose(
-        np.asarray(wrapped(adapters, toks)), np.asarray(gpt2_apply(base, toks, cfg)),
+        np.asarray(wrapped(adapters, toks)), np.asarray(apply(base, toks, cfg)),
         rtol=1e-5, atol=1e-5,
     )
     adapters = jax.tree.map(lambda x: x + 0.01, adapters)
     merged = merge_lora(base, adapters, lcfg)
     np.testing.assert_allclose(
         np.asarray(wrapped(adapters, toks)),
-        np.asarray(gpt2_apply(merged, toks, cfg)),
+        np.asarray(apply(merged, toks, cfg)),
         rtol=2e-2, atol=2e-2,
     )
 

@@ -12,9 +12,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
+from _sharded import run_sharded
 from distributed_lion_tpu.ops.xent import tp_vocab_xent
 
 TP = 4
@@ -35,10 +35,8 @@ def _sharded(hidden, head, labels):
     def body(h, hd, lab):
         return tp_vocab_xent(h, hd, lab, "tensor")
 
-    f = shard_map(body, mesh=_mesh(),
-                  in_specs=(P(), P(None, "tensor"), P()),
-                  out_specs=(P(), P()), check_vma=False)
-    return f(hidden, head, labels)
+    return run_sharded(body, _mesh(), (P(), P(None, "tensor"), P()),
+                       (P(), P()), hidden, head, labels, check_vma=False)
 
 
 def _data(n=37, d=16, v=64, seed=0):
@@ -77,10 +75,9 @@ def test_matches_dense_gradients():
         gh, ghd = jax.grad(loss, argnums=(0, 1))(h, hd)
         return gh, ghd  # gh complete+replicated; ghd this rank's shard
 
-    f = shard_map(body, mesh=_mesh(),
-                  in_specs=(P(), P(None, "tensor"), P()),
-                  out_specs=(P(), P(None, "tensor")), check_vma=False)
-    gh_s, ghd_s = f(hidden, head, labels)
+    gh_s, ghd_s = run_sharded(
+        body, _mesh(), (P(), P(None, "tensor"), P()),
+        (P(), P(None, "tensor")), hidden, head, labels, check_vma=False)
     gh_d, ghd_d = jax.grad(dense_loss, argnums=(0, 1))(hidden, head)
     np.testing.assert_allclose(np.asarray(gh_s), np.asarray(gh_d),
                                rtol=1e-4, atol=1e-5)
@@ -145,8 +142,8 @@ def test_vocab_parallel_embed_matches_dense():
     def body(w, t):
         return vocab_parallel_embed(w, t, "tensor")
 
-    out = shard_map(body, mesh=_mesh(), in_specs=(P("tensor"), P()),
-                    out_specs=P(), check_vma=False)(wte, tokens)
+    out = run_sharded(body, _mesh(), (P("tensor"), P()), P(), wte, tokens,
+                      check_vma=False)
     np.testing.assert_allclose(np.asarray(out), np.asarray(dense),
                                rtol=1e-6, atol=1e-6)
 

@@ -22,16 +22,11 @@ from jax.sharding import PartitionSpec as P
 from distributed_lion_tpu.analysis import trace_check
 from distributed_lion_tpu.models.gpt2 import GPT2Config
 from distributed_lion_tpu.parallel import collectives
-from distributed_lion_tpu.parallel.mesh import DATA_AXIS, make_mesh
+from distributed_lion_tpu.parallel.mesh import DATA_AXIS
 from distributed_lion_tpu.train.loop import TrainConfig, Trainer
 
 MODEL = GPT2Config.tiny(vocab_size=512, n_layer=2, n_head=4, d_model=128,
                         n_ctx=64)
-
-
-@pytest.fixture(scope="module")
-def mesh8():
-    return make_mesh(data=8, devices=jax.devices()[:8])
 
 
 def _trainer(mesh, **kw):

@@ -262,8 +262,9 @@ def test_remat_off_matches_remat_on():
         def loss(p, cfg):
             return jnp.mean(gpt2_apply(p, tokens, cfg) ** 2)
 
-        l_on, g_on = jax.value_and_grad(loss)(params, cfg_on)
-        l_off, g_off = jax.value_and_grad(loss)(params, cfg_off)
+        grad = jax.jit(jax.value_and_grad(loss), static_argnums=1)
+        l_on, g_on = grad(params, cfg_on)
+        l_off, g_off = grad(params, cfg_off)
         np.testing.assert_allclose(np.asarray(l_on), np.asarray(l_off),
                                    rtol=1e-6)
         for a, b in zip(jax.tree.leaves(g_on), jax.tree.leaves(g_off)):

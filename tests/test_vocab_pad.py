@@ -27,6 +27,11 @@ from distributed_lion_tpu.ops.xent import (
 
 V, PAD_M = 250, 64  # padded_vocab = 256
 
+# Forward passes run COMPILED, one program a shape (ISSUE 35): eagerly each is
+# a few hundred one-op programs.
+apply = jax.jit(gpt2_apply, static_argnums=2)
+decode = jax.jit(gpt2_decode, static_argnums=2)
+
 
 def _cfgs():
     plain = GPT2Config.tiny(vocab_size=V)
@@ -58,8 +63,8 @@ def test_apply_logits_exact_vs_unpadded():
     key = jax.random.key(7)
     p0, p1 = gpt2_init(key, plain), gpt2_init(key, padded)
     tok = jax.random.randint(jax.random.key(1), (2, 16), 0, V)
-    l0 = gpt2_apply(p0, tok, plain)
-    l1 = gpt2_apply(p1, tok, padded)
+    l0 = apply(p0, tok, plain)
+    l1 = apply(p1, tok, padded)
     assert l1.shape == l0.shape == (2, 16, V)
     np.testing.assert_allclose(np.asarray(l0), np.asarray(l1), atol=1e-5)
 
@@ -71,8 +76,8 @@ def test_pad_rows_do_not_leak_even_when_nonzero():
     p = gpt2_init(jax.random.key(7), padded)
     junk = p["wte"].at[V:].set(37.0)
     tok = jax.random.randint(jax.random.key(1), (2, 16), 0, V)
-    l_clean = gpt2_apply(p, tok, padded)
-    l_junk = gpt2_apply({**p, "wte": junk}, tok, padded)
+    l_clean = apply(p, tok, padded)
+    l_junk = apply({**p, "wte": junk}, tok, padded)
     np.testing.assert_array_equal(np.asarray(l_clean), np.asarray(l_junk))
     loss_c, _ = chunked_clm_loss_and_metrics(
         jax.random.normal(jax.random.key(2), (2, 16, padded.d_model)),
@@ -101,8 +106,8 @@ def test_chunked_xent_valid_v_matches_dense_and_zero_pad_grad():
 
     np.testing.assert_allclose(float(loss_pad(emb)), float(loss_dense(emb)),
                                rtol=1e-6)
-    g_pad = jax.grad(loss_pad)(emb)
-    g_dense = jax.grad(loss_dense)(emb)
+    g_pad = jax.jit(jax.grad(loss_pad))(emb)
+    g_dense = jax.jit(jax.grad(loss_dense))(emb)
     np.testing.assert_array_equal(np.asarray(g_pad[V:]), 0.0)
     np.testing.assert_allclose(np.asarray(g_pad[:V]), np.asarray(g_dense[:V]),
                                atol=1e-5)
@@ -127,9 +132,9 @@ def test_decode_matches_apply_with_padding():
     _, padded = _cfgs()
     p = gpt2_init(jax.random.key(7), padded)
     tok = jax.random.randint(jax.random.key(1), (1, 12), 0, V)
-    full = gpt2_apply(p, tok, padded)
+    full = apply(p, tok, padded)
     cache = gpt2_init_cache(padded, 1, 12)
-    dec, _ = gpt2_decode(p, tok, padded, cache, 0)
+    dec, _ = decode(p, tok, padded, cache, 0)
     assert dec.shape[-1] == V
     np.testing.assert_allclose(np.asarray(full), np.asarray(dec), atol=2e-4)
 

@@ -19,31 +19,26 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
+from _sharded import (
+    assert_trees_equal,
+    run_sharded,
+    sharded_opt_step,
+    toy_problem,
+)
 from distributed_lion_tpu.ops.codec import (
     bucket_alignment,
     bucket_bounds,
     wire_bytes_per_param,
 )
-from distributed_lion_tpu.optim import (
-    distributed_lion,
-    expand_worker_state,
-    init_global_state,
-    squeeze_worker_state,
-)
+from distributed_lion_tpu.optim import distributed_lion, init_global_state
 from distributed_lion_tpu.optim.distributed_lion import _bucket_windows
 from distributed_lion_tpu.optim.lion import LionState
 from distributed_lion_tpu.parallel import collectives
 from distributed_lion_tpu.parallel.mesh import make_mesh
 
 WIRES = ["sign_psum", "packed_allgather", "packed_a2a", "hier:4"]
-
-
-@pytest.fixture(scope="module")
-def mesh8():
-    return make_mesh(data=8)
 
 
 # --------------------------------------------------------------- bounds math
@@ -146,10 +141,8 @@ def test_majority_vote_bucketed_bit_identical(mesh8, wire):
             return collectives.majority_vote_bucketed(
                 b[0], "data", wire, vote_buckets)
 
-        return np.asarray(shard_map(
-            body, mesh=mesh8, in_specs=(P("data"),), out_specs=P(),
-            check_vma=False,
-        )(ballots))
+        return np.asarray(run_sharded(
+            body, mesh8, (P("data"),), P(), ballots, check_vma=False))
 
     # one bucketed config suffices: 5 buckets of the 1003-coordinate ballot
     # exercise interior + ragged-tail chunks; each extra config is a fresh
@@ -159,51 +152,14 @@ def test_majority_vote_bucketed_bit_identical(mesh8, wire):
 
 # ------------------------------------------------------ optimizer bit-parity
 def _run_steps(opt, params, grads_per_worker, n_steps, mesh, world,
-               rng=None, has_elected=False):
-    """Drive opt.step under shard_map for n_steps (test_vote_every idiom,
-    extended with stochastic rng support)."""
+               rng=None):
+    """Drive opt.step under shard_map for n_steps; grads_per_worker is a
+    [world, ...] stacked pytree reused every step."""
     state = init_global_state(opt, params, world, rng=rng)
-    p_spec = jax.tree.map(lambda _: P(), params)
-    st_spec = LionState(
-        count=P(),
-        exp_avg=jax.tree.map(lambda _: P("data"), state.exp_avg),
-        rng=None if rng is None else P(),
-        elected=P() if has_elected else None,
-    )
-    g_spec = jax.tree.map(lambda _: P("data"), grads_per_worker)
-
-    @jax.jit
-    def step(params, grads, state):
-        def body(p, g, st):
-            st = squeeze_worker_state(st)
-            g = jax.tree.map(lambda x: x[0], g)
-            p_new, st_new = opt.step(p, g, st)
-            return p_new, expand_worker_state(st_new)
-
-        return shard_map(
-            body, mesh=mesh, in_specs=(p_spec, g_spec, st_spec),
-            out_specs=(p_spec, st_spec), check_vma=False,
-        )(params, grads, state)
-
+    step = sharded_opt_step(opt, mesh, state)
     for _ in range(n_steps):
         params, state = step(params, grads_per_worker, state)
     return params, state
-
-
-def _toy_problem(world=8, n=40):
-    key = jax.random.key(0)
-    params = {"w": jax.random.normal(key, (n,)), "b": jnp.zeros((3,))}
-    grads = {
-        "w": jax.random.normal(jax.random.key(1), (world, n)),
-        "b": jax.random.normal(jax.random.key(2), (world, 3)),
-    }
-    return params, grads
-
-
-def _assert_trees_equal(a, b):
-    jax.tree.map(
-        lambda x, y: np.testing.assert_array_equal(np.asarray(x),
-                                                   np.asarray(y)), a, b)
 
 
 @pytest.mark.parametrize("wire", WIRES)
@@ -215,7 +171,7 @@ def test_bucketed_trajectory_bit_identical(mesh8, wire, stochastic,
     """The acceptance criterion: vote_buckets > 1 produces bit-identical
     params AND momentum to vote_buckets = 1 for every wire × binarization
     mode × vote cadence (the rotating 1/K slice votes bucket-wise too)."""
-    params, grads = _toy_problem()
+    params, grads = toy_problem()
     kw = dict(learning_rate=0.01, weight_decay=0.01, wire=wire,
               vote_every=vote_every,
               max_grad_norm=1.0 if stochastic else None)
@@ -225,9 +181,9 @@ def test_bucketed_trajectory_bit_identical(mesh8, wire, stochastic,
     for buckets in (1, 3):
         opt = distributed_lion(vote_buckets=buckets, **kw)
         runs[buckets] = _run_steps(opt, params, grads, steps, mesh8, 8,
-                                   rng=rng, has_elected=vote_every > 1)
-    _assert_trees_equal(runs[1][0], runs[3][0])
-    _assert_trees_equal(runs[1][1].exp_avg, runs[3][1].exp_avg)
+                                   rng=rng)
+    assert_trees_equal(runs[1][0], runs[3][0])
+    assert_trees_equal(runs[1][1].exp_avg, runs[3][1].exp_avg)
     if vote_every > 1:
         np.testing.assert_array_equal(np.asarray(runs[1][1].elected),
                                       np.asarray(runs[3][1].elected))
@@ -239,7 +195,7 @@ def test_pallas_bucketed_equals_xla_monolithic(mesh8, wire):
     must match the XLA path's monolithic vote bit-for-bit — the cross-check
     that the persistent flat-offset layout slices exactly the coordinates
     the flat concatenate used to."""
-    params, grads = _toy_problem(n=300)  # spans several (8,128) windows
+    params, grads = toy_problem(n=300)  # spans several (8,128) windows
     results = []
     for kern, buckets in (("pallas", 4), ("pallas", 1), ("xla", 1)):
         opt = distributed_lion(learning_rate=0.02, weight_decay=0.05,
@@ -247,7 +203,7 @@ def test_pallas_bucketed_equals_xla_monolithic(mesh8, wire):
         p, st = _run_steps(opt, params, grads, 3, mesh8, 8)
         results.append((p, st))
     for other in results[1:]:
-        _assert_trees_equal(results[0][0], other[0])
+        assert_trees_equal(results[0][0], other[0])
         jax.tree.map(
             lambda a, b: np.testing.assert_allclose(
                 np.asarray(a), np.asarray(b), rtol=1e-6),
@@ -259,29 +215,13 @@ def test_pallas_step_preserves_elected_cache(mesh8):
     — harmless only because the Pallas gate requires vote_every == 1. The
     invariant is 'state passes through', pinned by smuggling a cache into a
     state the Pallas path consumes."""
-    params, grads = _toy_problem(n=64)
+    params, grads = toy_problem(n=64)
     opt = distributed_lion(learning_rate=0.01, kernel="pallas",
                            vote_buckets=2)
     state = init_global_state(opt, params, 8)
     cache = jnp.arange(16, dtype=jnp.uint8)
     state = LionState(state.count, state.exp_avg, state.rng, cache)
-    p_spec = jax.tree.map(lambda _: P(), params)
-    st_spec = LionState(count=P(),
-                        exp_avg=jax.tree.map(lambda _: P("data"),
-                                             state.exp_avg),
-                        rng=None, elected=P())
-    g_spec = jax.tree.map(lambda _: P("data"), grads)
-
-    def body(p, g, st):
-        st = squeeze_worker_state(st)
-        g = jax.tree.map(lambda x: x[0], g)
-        p_new, st_new = opt.step(p, g, st)
-        return p_new, expand_worker_state(st_new)
-
-    _, new_state = jax.jit(shard_map(
-        body, mesh=mesh8, in_specs=(p_spec, g_spec, st_spec),
-        out_specs=(p_spec, st_spec), check_vma=False,
-    ))(params, grads, state)
+    _, new_state = sharded_opt_step(opt, mesh8, state)(params, grads, state)
     np.testing.assert_array_equal(np.asarray(new_state.elected),
                                   np.asarray(cache))
 

@@ -12,68 +12,25 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax import shard_map
-from jax.sharding import NamedSharding, PartitionSpec as P
 
+from _sharded import sharded_opt_step, toy_problem
 from distributed_lion_tpu.ops.codec import wire_bytes_per_param
-from distributed_lion_tpu.optim import (
-    distributed_lion,
-    expand_worker_state,
-    init_global_state,
-    squeeze_worker_state,
-)
-from distributed_lion_tpu.parallel.mesh import make_mesh
+from distributed_lion_tpu.optim import distributed_lion, init_global_state
 
 
 def _run_steps(opt, params, grads_per_worker, n_steps, mesh, world):
     """Drive opt.step under shard_map for n_steps; grads_per_worker is a
     [world, ...] stacked pytree reused every step."""
     state = init_global_state(opt, params, world)
-    p_spec = jax.tree.map(lambda _: P(), params)
-    st_spec = type(state)(
-        count=P(),
-        exp_avg=jax.tree.map(lambda _: P("data"), state.exp_avg),
-        rng=None,
-        elected=None if state.elected is None else P(),
-    )
-    g_spec = jax.tree.map(lambda _: P("data"), grads_per_worker)
-
-    @jax.jit
-    def step(params, grads, state):
-        def body(p, g, st):
-            st = squeeze_worker_state(st)
-            g = jax.tree.map(lambda x: x[0], g)
-            p_new, st_new = opt.step(p, g, st)
-            return p_new, expand_worker_state(st_new)
-
-        return shard_map(
-            body, mesh=mesh, in_specs=(p_spec, g_spec, st_spec),
-            out_specs=(p_spec, st_spec), check_vma=False,
-        )(params, grads, state)
-
+    step = sharded_opt_step(opt, mesh, state)
     for _ in range(n_steps):
         params, state = step(params, grads_per_worker, state)
     return params, state
 
 
-@pytest.fixture(scope="module")
-def mesh8():
-    return make_mesh(data=8)
-
-
-def _toy_problem(world=8, n=40):
-    key = jax.random.key(0)
-    params = {"w": jax.random.normal(key, (n,)), "b": jnp.zeros((3,))}
-    grads = {
-        "w": jax.random.normal(jax.random.key(1), (world, n)),
-        "b": jax.random.normal(jax.random.key(2), (world, 3)),
-    }
-    return params, grads
-
-
 @pytest.mark.parametrize("wire", ["sign_psum", "packed_a2a"])
 def test_vote_every_replicas_consistent(mesh8, wire):
-    params, grads = _toy_problem()
+    params, grads = toy_problem()
     opt = distributed_lion(learning_rate=0.01, wire=wire, vote_every=4)
     p, st = _run_steps(opt, params, grads, n_steps=6, mesh=mesh8, world=8)
     # params stay replicated: every device holds identical values
@@ -86,7 +43,7 @@ def test_vote_every_replicas_consistent(mesh8, wire):
 
 def test_vote_every_one_matches_plain(mesh8):
     """K=1 must be the plain voted optimizer bit-for-bit."""
-    params, grads = _toy_problem()
+    params, grads = toy_problem()
     p1, _ = _run_steps(distributed_lion(learning_rate=0.01), params, grads, 5, mesh8, 8)
     p2, _ = _run_steps(distributed_lion(learning_rate=0.01, vote_every=1),
                        params, grads, 5, mesh8, 8)
@@ -96,7 +53,7 @@ def test_vote_every_one_matches_plain(mesh8):
 def test_vote_every_cold_start_mask(mesh8):
     """During the first K-1 steps, not-yet-voted coordinates must not move
     (beyond weight decay, which is off here)."""
-    params, grads = _toy_problem(n=40)
+    params, grads = toy_problem(n=40)
     opt = distributed_lion(learning_rate=0.01, vote_every=4)
     p, _ = _run_steps(opt, params, grads, n_steps=1, mesh=mesh8, world=8)
     n = 40 + 3

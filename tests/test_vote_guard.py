@@ -31,9 +31,15 @@ import pytest
 
 import jax
 import jax.numpy as jnp
-from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
+from _sharded import (
+    assert_trees_equal,
+    run_sharded,
+    sharded,
+    sharded_opt_step,
+    toy_problem,
+)
 from distributed_lion_tpu.data.sources import (
     batch_iterator,
     synthetic_lm_dataset,
@@ -41,12 +47,9 @@ from distributed_lion_tpu.data.sources import (
 from distributed_lion_tpu.models.gpt2 import GPT2Config
 from distributed_lion_tpu.optim import (
     distributed_lion,
-    expand_worker_state,
     heal_worker_momentum,
     init_global_state,
-    squeeze_worker_state,
 )
-from distributed_lion_tpu.optim.lion import LionState
 from distributed_lion_tpu.parallel import collectives
 from distributed_lion_tpu.parallel.mesh import make_mesh
 from distributed_lion_tpu.train import resilience
@@ -54,11 +57,6 @@ from distributed_lion_tpu.train.loop import TrainConfig, Trainer
 from distributed_lion_tpu.train.vote_guard import VoteGuard
 
 WIRES = ["sign_psum", "packed_allgather", "packed_a2a", "hier:4"]
-
-
-@pytest.fixture(scope="module")
-def mesh8():
-    return make_mesh(data=8)
 
 
 @pytest.fixture(autouse=True)
@@ -105,17 +103,15 @@ def test_masked_election_matches_reference(mesh8, wire):
     n = 203
     rng = np.random.default_rng(3)
     ballots = rng.integers(0, 2, size=(8, n)).astype(bool)
+
+    def body(b, a):
+        return collectives.majority_vote(b[0], "data", wire, a)
+
+    elect = sharded(body, mesh8, (P("data"), P()), P(), check_vma=False)
     for sick in ([2, 5], [4, 5, 6, 7]):  # the 2nd kills hier group 1 of 2
         alive = np.ones(8, bool)
         alive[sick] = False
-
-        def body(b, a):
-            return collectives.majority_vote(b[0], "data", wire, a)
-
-        got = np.asarray(shard_map(
-            body, mesh=mesh8, in_specs=(P("data"), P()), out_specs=P(),
-            check_vma=False,
-        )(jnp.asarray(ballots), jnp.asarray(alive)))
+        got = np.asarray(elect(jnp.asarray(ballots), jnp.asarray(alive)))
         np.testing.assert_array_equal(
             got, _ref_masked_election(ballots, alive, wire), err_msg=wire)
 
@@ -129,75 +125,35 @@ def test_masked_all_healthy_bit_identical_collective(mesh8, wire):
     ballots = jnp.asarray(rng.integers(0, 2, size=(8, n)).astype(bool))
     alive = jnp.ones((8,), jnp.bool_)
 
-    def run(a, buckets):
-        def body(b):
+    def run(buckets, *mask):
+        def body(b, *mask):
             return collectives.majority_vote_bucketed(
-                b[0], "data", wire, buckets, a)
+                b[0], "data", wire, buckets, *mask)
 
-        return np.asarray(shard_map(
-            body, mesh=mesh8, in_specs=(P("data"),), out_specs=P(),
-            check_vma=False,
-        )(ballots))
+        return np.asarray(run_sharded(
+            body, mesh8, (P("data"),) + (P(),) * len(mask), P(),
+            ballots, *mask, check_vma=False))
 
-    np.testing.assert_array_equal(run(alive, 1), run(None, 1))
-    np.testing.assert_array_equal(run(alive, 4), run(None, 4))
+    np.testing.assert_array_equal(run(1, alive), run(1))
+    np.testing.assert_array_equal(run(4, alive), run(4))
 
 
 # --------------------------------------------------- optimizer bit-identity
-def _toy_problem(world=8, n=40, vary_steps=0):
-    key = jax.random.key(0)
-    params = {"w": jax.random.normal(key, (n,)), "b": jnp.zeros((3,))}
-    grads = {
-        "w": jax.random.normal(jax.random.key(1), (world, n)),
-        "b": jax.random.normal(jax.random.key(2), (world, 3)),
-    }
-    return params, grads
-
-
 def _run_steps(opt, params, grads_fn, n_steps, mesh, world, rng=None,
-               has_elected=False, guard_on=False, sick=None):
-    """Drive opt.step under shard_map (test_vote_buckets idiom, extended
-    with guard state and per-step grads via ``grads_fn(step)``)."""
+               sick=None):
+    """Drive opt.step under shard_map with per-step grads via
+    ``grads_fn(step)``; returns ``(params, state, guard frame or None)``."""
     state = init_global_state(opt, params, world, rng=rng)
     if sick is not None and state.health is not None:
         h = np.ones(world, bool)
         h[sick] = False
         state = state._replace(health=jnp.asarray(h))
-    p_spec = jax.tree.map(lambda _: P(), params)
-    st_spec = LionState(
-        count=P(),
-        exp_avg=jax.tree.map(lambda _: P("data"), state.exp_avg),
-        rng=None if rng is None else P(),
-        elected=P() if has_elected else None,
-        health=P() if guard_on else None,
-        prev_ballot=P("data") if guard_on else None,
-    )
-    g_spec = jax.tree.map(lambda _: P("data"), grads_fn(0))
-
-    @jax.jit
-    def step(params, grads, state):
-        def body(p, g, st):
-            st = squeeze_worker_state(st)
-            g = jax.tree.map(lambda x: x[0], g)
-            outs = opt.step(p, g, st)
-            p_new, st_new = outs[0], expand_worker_state(outs[1])
-            return p_new, st_new, (outs[-1] if guard_on else {})
-
-        return shard_map(
-            body, mesh=mesh, in_specs=(p_spec, g_spec, st_spec),
-            out_specs=(p_spec, st_spec, P()), check_vma=False,
-        )(params, grads, state)
-
-    gf = None
+    step = sharded_opt_step(opt, mesh, state,
+                            extras=int(state.health is not None))
+    frames = []
     for t in range(n_steps):
-        params, state, gf = step(params, grads_fn(t), state)
-    return params, state, gf
-
-
-def _assert_trees_equal(a, b):
-    jax.tree.map(
-        lambda x, y: np.testing.assert_array_equal(np.asarray(x),
-                                                   np.asarray(y)), a, b)
+        params, state, *frames = step(params, grads_fn(t), state)
+    return params, state, frames[0] if frames else None
 
 
 @pytest.mark.parametrize("wire", WIRES)
@@ -208,7 +164,7 @@ def test_guard_all_healthy_bit_identical(mesh8, wire, stochastic, buckets):
     """The acceptance criterion: 'enforce' with an all-healthy mask is
     bit-identical to guard 'off' in params AND momentum, across all four
     wires × vote_buckets {1, 4} × det/stoch (XLA path)."""
-    params, grads = _toy_problem()
+    params, grads = toy_problem()
     kw = dict(learning_rate=0.01, weight_decay=0.01, wire=wire,
               vote_buckets=buckets,
               max_grad_norm=1.0 if stochastic else None)
@@ -217,9 +173,9 @@ def test_guard_all_healthy_bit_identical(mesh8, wire, stochastic, buckets):
     for guard in ("off", "enforce"):
         opt = distributed_lion(guard=guard, **kw)
         runs[guard] = _run_steps(opt, params, lambda t: grads, 3, mesh8, 8,
-                                 rng=rng, guard_on=guard != "off")
-    _assert_trees_equal(runs["off"][0], runs["enforce"][0])
-    _assert_trees_equal(runs["off"][1].exp_avg, runs["enforce"][1].exp_avg)
+                                 rng=rng)
+    assert_trees_equal(runs["off"][0], runs["enforce"][0])
+    assert_trees_equal(runs["off"][1].exp_avg, runs["enforce"][1].exp_avg)
 
 
 @pytest.mark.parametrize("buckets", [1, 4])
@@ -227,29 +183,27 @@ def test_guard_all_healthy_bit_identical(mesh8, wire, stochastic, buckets):
 def test_guard_all_healthy_bit_identical_pallas(mesh8, wire, buckets):
     """Same contract on the Pallas window path (the mask zeroes the bucket
     ballot before it reaches the wire; kernels untouched)."""
-    params, grads = _toy_problem(n=300)
+    params, grads = toy_problem(n=300)
     runs = {}
     for guard in ("off", "enforce"):
         opt = distributed_lion(learning_rate=0.02, weight_decay=0.05,
                                wire=wire, kernel="pallas",
                                vote_buckets=buckets, guard=guard)
-        runs[guard] = _run_steps(opt, params, lambda t: grads, 3, mesh8, 8,
-                                 guard_on=guard != "off")
-    _assert_trees_equal(runs["off"][0], runs["enforce"][0])
-    _assert_trees_equal(runs["off"][1].exp_avg, runs["enforce"][1].exp_avg)
+        runs[guard] = _run_steps(opt, params, lambda t: grads, 3, mesh8, 8)
+    assert_trees_equal(runs["off"][0], runs["enforce"][0])
+    assert_trees_equal(runs["off"][1].exp_avg, runs["enforce"][1].exp_avg)
 
 
 def test_guard_lazy_vote_every_bit_identical(mesh8):
     """Guard × lazy refresh: the per-slot prev-ballot cache must not
     disturb the rotating-slice election (elected cache compared too)."""
-    params, grads = _toy_problem()
+    params, grads = toy_problem()
     runs = {}
     for guard in ("off", "enforce"):
         opt = distributed_lion(learning_rate=0.01, wire="sign_psum",
                                vote_every=4, guard=guard)
-        runs[guard] = _run_steps(opt, params, lambda t: grads, 5, mesh8, 8,
-                                 has_elected=True, guard_on=guard != "off")
-    _assert_trees_equal(runs["off"][0], runs["enforce"][0])
+        runs[guard] = _run_steps(opt, params, lambda t: grads, 5, mesh8, 8)
+    assert_trees_equal(runs["off"][0], runs["enforce"][0])
     np.testing.assert_array_equal(np.asarray(runs["off"][1].elected),
                                   np.asarray(runs["enforce"][1].elected))
 
@@ -259,12 +213,12 @@ def test_masked_optimizer_election_excludes_sick_worker(mesh8):
     elections must equal those of an election among workers 1..7 alone
     (verified against the numpy healthy-majority over the actual ballots:
     ballot = b1*m + (1-b1)*g > 0, m = 0 at the first step)."""
-    params, grads = _toy_problem()
+    params, grads = toy_problem()
     b1 = 0.9
     opt = distributed_lion(learning_rate=0.01, b1=b1, wire="sign_psum",
                            guard="enforce")
     p1, _, _ = _run_steps(opt, params, lambda t: grads, 1, mesh8, 8,
-                          guard_on=True, sick=[0])
+                          sick=[0])
     flat_g = np.concatenate([np.asarray(grads["w"]),
                              np.asarray(grads["b"])], axis=1)
     ballots = (1 - b1) * flat_g > 0  # m == 0 at step 0
@@ -304,10 +258,10 @@ def _varied_grads(world, n, t, poison=None, kind=None):
 def test_guard_frame_nonfinite_names_worker(mesh8):
     opt = distributed_lion(learning_rate=0.01, wire="sign_psum",
                            guard="observe")
-    params, _ = _toy_problem()
+    params, _ = toy_problem()
     _, _, gf = _run_steps(
         opt, params, lambda t: _varied_grads(8, 40, t, poison=3, kind="nan"),
-        2, mesh8, 8, guard_on=True)
+        2, mesh8, 8)
     nf = np.asarray(gf["nonfinite"])
     assert nf[3] > 0 and (nf[[i for i in range(8) if i != 3]] == 0).all()
 
@@ -318,11 +272,11 @@ def test_guard_frame_frozen_ballot_names_worker(mesh8):
     keep flipping bits."""
     opt = distributed_lion(learning_rate=0.01, wire="sign_psum",
                            guard="observe")
-    params, _ = _toy_problem()
+    params, _ = toy_problem()
     _, _, gf = _run_steps(
         opt, params, lambda t: _varied_grads(8, 40, t, poison=2,
                                              kind="zero"),
-        3, mesh8, 8, guard_on=True)
+        3, mesh8, 8)
     flips = np.asarray(gf["flips"])
     assert bool(np.asarray(gf["flip_valid"]))
     assert flips[2] == 0
@@ -333,14 +287,14 @@ def test_guard_enforce_sanitizes_momentum(mesh8):
     """enforce: nonfinite grads are zeroed out of the momentum update (the
     reference-lineage latent bug: one NaN batch used to poison exp_avg
     forever); observe keeps the raw semantics."""
-    params, _ = _toy_problem()
+    params, _ = toy_problem()
     for guard, finite in (("enforce", True), ("observe", False)):
         opt = distributed_lion(learning_rate=0.01, wire="sign_psum",
                                guard=guard)
         _, st, _ = _run_steps(
             opt, params,
             lambda t: _varied_grads(8, 40, t, poison=1, kind="nan"),
-            2, mesh8, 8, guard_on=True)
+            2, mesh8, 8)
         mom = np.asarray(st.exp_avg["w"])
         assert np.isfinite(mom).all() == finite
 
@@ -755,7 +709,7 @@ def test_sharded_step_wrapper_supports_guard(mesh8):
         shard_state,
     )
 
-    params, grads = _toy_problem()
+    params, grads = toy_problem()
     outs = {}
     for guard in ("off", "enforce"):
         opt = distributed_lion(learning_rate=0.01, guard=guard)
@@ -769,5 +723,5 @@ def test_sharded_step_wrapper_supports_guard(mesh8):
             outs[guard] = (p, st)
             assert np.asarray(gf["nonfinite"]).shape == (8,)
             assert np.asarray(st.health).all()
-    _assert_trees_equal(outs["off"][0], outs["enforce"][0])
-    _assert_trees_equal(outs["off"][1].exp_avg, outs["enforce"][1].exp_avg)
+    assert_trees_equal(outs["off"][0], outs["enforce"][0])
+    assert_trees_equal(outs["off"][1].exp_avg, outs["enforce"][1].exp_avg)

@@ -124,7 +124,7 @@ def test_chunked_seq_parallel_matches_dense_seq_loss():
     """chunked_clm_loss_seq_parallel == clm_loss_seq_parallel (values,
     metrics, AND grads) under a 4-way seq mesh — the long-context x
     huge-vocab composition (round 3)."""
-    from jax import shard_map
+    from _sharded import run_sharded
     from jax.sharding import Mesh, PartitionSpec as P
 
     from distributed_lion_tpu.models.llama import (
@@ -159,10 +159,9 @@ def test_chunked_seq_parallel_matches_dense_seq_loss():
             g = jax.lax.psum(g, "seq")
             return m["loss"], m["accuracy"], g
 
-        out = shard_map(
-            body, mesh=mesh, in_specs=(P(), P(None, "seq")),
-            out_specs=(P(), P(), P()), check_vma=False,
-        )(params, tokens)
+        out = run_sharded(
+            body, mesh, (P(), P(None, "seq")), (P(), P(), P()),
+            params, tokens, check_vma=False)
         return jax.tree.map(np.asarray, jax.device_get(out))
 
     loss_d, acc_d, g_d = run(dense)
