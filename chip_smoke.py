@@ -525,7 +525,10 @@ def phase_serve(out_dir: str) -> None:
     log(f"  decode_attn_kernel_ticks {stats['decode_attn_kernel_ticks']} of "
         f"{stats['decode_ticks']} decode ticks; kv_pages_read "
         f"{stats['kv_pages_read']} of kv_pages_table "
-        f"{stats['kv_pages_table']}")
+        f"{stats['kv_pages_table']}; run_ahead_ticks "
+        f"{stats['run_ahead_ticks']} of them, run_ahead_drains "
+        f"{stats['run_ahead_drains']}, run_ahead_discarded "
+        f"{stats['run_ahead_discarded']}")
     log(f"  run_serve: {len(records)} requests complete "
         f"({[r['reason'] for r in records]}), pool back to "
         f"{engine.tables.free_blocks}/{engine.tables.num_blocks} free, "

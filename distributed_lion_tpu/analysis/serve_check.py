@@ -200,18 +200,19 @@ def _example_rest(eng, kind: str, window: Optional[int] = None) -> tuple:
     i32, u32 = jnp.int32, jnp.uint32
     if kind == "decode":
         return (jnp.zeros((s_, w_), i32), jnp.zeros((s_,), i32),
-                jnp.zeros((s_,), i32), jnp.zeros((s_,), bool),
+                eng._prev, jnp.zeros((s_,), bool),
                 jnp.zeros((s_,), u32), jnp.zeros((s_,), i32))
     if kind == "prefill":
         toks = jnp.zeros((1, int(window)), i32)
+        # the last two: the vector the decode tick reads its last tokens
+        # from, and the slot whose entry the first token is written over
         if eng._ep_batch:
             g = eng.tables.groups
             return (jnp.zeros((g, w_), i32), toks, jnp.zeros((g,), i32),
-                    jnp.zeros((g,), i32), u32(0), i32(0))
+                    jnp.zeros((g,), i32), u32(0), i32(0), eng._prev,
+                    jnp.zeros((g,), i32))
         return (jnp.zeros((1, w_), i32), toks, jnp.zeros((1,), i32),
-                i32(0), u32(0), i32(0)) + (
-            # a window family's prefill names the slot whose ring it fills
-            (jnp.zeros((1,), i32),) if eng._slotted else ())
+                i32(0), u32(0), i32(0), eng._prev, jnp.zeros((1,), i32))
     if kind == "verify":
         return (jnp.zeros((s_, w_), i32), jnp.zeros((s_,), i32),
                 jnp.zeros((s_, int(window)), i32), jnp.zeros((s_,), i32),

@@ -4,9 +4,31 @@ The serving counterpart of ``train/loop.py`` (ROADMAP item 4): requests
 join a rolling batch on arrival, leave on EOS/length/overflow, and every
 tick is ONE device dispatch — either a bucketed prefill or a decode step
 over all active slots. The host's only per-tick work is table math
-(serve/kv_cache.py) and reading back the tick's sampled tokens as one
+(serve/kv_cache.py) and reading back a dispatch's sampled tokens as one
 array; there is no per-token host sync inside a tick (graft-check DLT001
 pins the forbidden shape, tests/fixtures/analysis/serve/).
+
+**The host runs one tick ahead of its reads.** A decode tick's last
+tokens never come from the host: every dispatch returns one int32 vector
+(the slots' tokens, counters behind them), the next dispatch takes that
+vector as its operand ``prev`` while it is still on the device, and a
+prefill writes its sampled first token over its slot's entry. So
+``step()`` t enqueues the decode tick of t and only then reads tick t-1's
+vector (and this tick's prefills'): the device runs tick t while the host
+commits t-1 and builds t+1, and a tick costs max(device, host) where it
+cost their sum. What the operands need besides is host arithmetic that
+does not wait for a token: lengths and sample indices advance at
+dispatch. An end the host can foresee (the budget) retires the slot AT
+dispatch — row and pages freed, the replacement admitted next tick, the
+Completion emitted when the last token is read, one ``step()`` later
+(``timing["delivery_lag_ticks"]``); an end it cannot foresee (EOS) costs
+the one row already enqueued, which is dropped (``run_ahead_discarded``).
+A decision that needs unread tokens reads them first, a ``serve/drain``
+span with its reason: an overflow eviction, a resident's deadline,
+``export_records``. ``has_work()`` stays true until the last read is
+made. A speculator's accept / reject shapes every next tick, so an engine
+with one reads in order; nothing else does (``stats["run_ahead_ticks"]``
+of ``decode_ticks``; the ``[setup] decode:`` line says which).
 
 Scheduling (the vLLM recipe, simplified to two tick kinds):
 
@@ -123,23 +145,30 @@ Every tick is one tree::
 
     serve/tick            tick=<n>                       the whole step()
       serve/expire                                       deadline sweep
+        serve/drain       reason=deadline                a resident is late
       serve/admit         pending, prefills              admission + table math
         serve/cow         copies
-        serve/prefill     req_id, prompt_len, padded..   one per admitted request
-          serve/token_read                               int(np.asarray(tok))
-        serve/evict       req_id, slot, reason
+        serve/prefill     req_id, prompt_len, padded..   one per admitted request: enqueue only
+        serve/evict       req_id, slot, reason=length    budget of 1: retired at dispatch
       serve/decode_tick   batch
-        serve/decode_build                               grow, CoW, six operands, tables
-          serve/cow, serve/evict (overflow)
+        serve/decode_build                               grow, CoW, operands, tables
+          serve/cow
+          serve/drain     reason=overflow                then serve/evict
         serve/decode_dispatch                            the jitted call: enqueue only
-        serve/token_read                                 the ONE blocking np.asarray(toks)
-        serve/commit      batch                          per-slot bookkeeping, evictions
-          serve/evict
+          serve/evict     reason=length                  budget ends with this token
+        serve/token_read  of=decode, tick=<n-1>          the blocking np.asarray(vec)
+        serve/commit      batch                          per-row bookkeeping, completions
+          serve/evict     reason=eos
+        serve/token_read  of=prefill, tick=<n>           one pair per prefill admitted
+        serve/commit
       serve/metrics                                      only with ServeConfig.metrics
 
 (under ``speculate`` the decode tick is ``serve/draft``, ``serve/verify``
-with its own ``serve/token_read``, and ``serve/commit``). A tick's span
-less the ``serve/token_read`` under it is what the host did itself.
+with its own ``serve/token_read``, and ``serve/commit``, and a prefill's
+``serve/token_read`` and ``serve/commit`` follow it under ``serve/admit``).
+A tick's span less the ``serve/token_read`` under it is what the host did
+itself; a ``serve/token_read`` is where it waits for the device, and with
+the next tick already enqueued the device does not wait for it.
 Construction is timed by always-on ``setup/place_weights``,
 ``setup/init_pages`` and ``setup/build_dispatches`` spans and one
 ``[setup]`` line; the compile ledger (utils/compile_cache) names each
@@ -369,9 +398,33 @@ class RecoveryRecord:
 class _Slot:
     req: Request
     budget: int          # max new tokens for this request
-    cache_len: int       # tokens whose k/v are in the pages
-    last_tok: int        # newest sampled token (not yet in the cache)
-    gen: List[int] = dataclasses.field(default_factory=list)
+    cache_len: int       # tokens whose k/v are in the pages once every
+    #                      dispatch enqueued so far has run
+    last_tok: int = 0    # newest token the host has READ: the speculative
+    #                      tick's window starts from it; the plain tick
+    #                      takes its last token on the device (``prev``)
+    gen: List[int] = dataclasses.field(default_factory=list)  # tokens read
+    unread: int = 0      # tokens dispatched whose read is outstanding (a
+    #                      prefill's first, one decode tick's)
+    done: bool = False   # its Completion is out; a row of it still
+    #                      unread is dropped (an EOS the host could not
+    #                      foresee)
+
+
+@dataclasses.dataclass
+class _Unread:
+    """A dispatch whose output vector the host has not read yet: the
+    tokens of ``rows`` (``(slot, _Slot)``: token ``vec[slot]``) and the
+    counters that ride behind them. It holds the ``_Slot`` objects
+    themselves, because a slot whose end the host foresaw at dispatch has
+    left ``engine.slots`` (and its pages) by the time its last token is
+    read."""
+
+    kind: str            # "prefill" | "decode"
+    tick: int            # the tick whose dispatch made these tokens
+    vec: Any             # device int32 [max_seqs + counters]
+    st: Any              # capacity-routed MoE load scalars, or {}
+    rows: List[tuple]
 
 
 def dispatch_signature(operands) -> tuple:
@@ -945,7 +998,13 @@ class ServingEngine:
                       # the pages their rows' lengths need against the
                       # tables' whole width, which the gather path reads
                       model.kernel_stat: 0, "kv_pages_read": 0,
-                      "kv_pages_table": 0}
+                      "kv_pages_table": 0,
+                      # decode ticks enqueued while a read was outstanding,
+                      # reads forced before their turn (``serve/drain``
+                      # names why), and rows dropped because their slot had
+                      # sampled EOS in the tick before
+                      "run_ahead_ticks": 0, "run_ahead_drains": 0,
+                      "run_ahead_discarded": 0}
         from distributed_lion_tpu.ops.attention import paged_kernel_applies
 
         paged = next(i for i in range(model.n_layer)
@@ -1027,8 +1086,15 @@ class ServingEngine:
         overlap = self._ep_overlap
         slotted = self._slotted
 
-        def decode_tick(params, pages, tables, lens, last, act, seeds,
+        def decode_tick(params, pages, tables, lens, prev, act, seeds,
                         counts):
+            # prev: the output vector of the dispatch before this one,
+            # still on the device (the last decode tick's tokens, with the
+            # first token of every prefill since written over its slot's
+            # entry, and counters behind them). A row's last token is read
+            # from it here, so the host can enqueue this tick before it
+            # has read that one.
+            last = prev[:lens.shape[0]]
             # act [S] bool: the engine's valid-lane mask for the tick —
             # inactive (sentinel) slots are dead lanes for expert routing
             # and for the scatter (which their sentinel rows drop anyway).
@@ -1064,9 +1130,13 @@ class ServingEngine:
                         st), pages
 
         def prefill(params, pages, tables, toks, start, length, seed, count,
-                    *slot):
-            # ``slot`` ([1] int32): a window or state family's one operand
-            # more
+                    prev, where):
+            # ``where`` ([1] int32): the slot being admitted, which is also
+            # what a window or state family's layers find their ring or
+            # state by. The sampled first token is written over that entry
+            # of ``prev`` (see decode_tick) and the prefill's own counters
+            # over the tail, so what comes back is the decode tick's next
+            # operand and the host's one read both.
             # toks [1, P] — the prompt SUFFIX not covered by shared prefix
             # pages, scattered at absolute positions start..start+P-1
             # (start == 0 without prefix sharing: the whole prompt).
@@ -1094,14 +1164,21 @@ class ServingEngine:
                                                   if stats_axis else None),
                                      **({"logit_index": at}
                                         if model.last_logit else {}),
-                                     **({"slots": slot[0]}
+                                     **({"slots": where}
                                         if slotted else {}))
             logits, pages = out[0], out[1]
             st = out[2] if moe_stats else {}
             last = logits[0, 0] if model.last_logit else \
                 jax.lax.dynamic_index_in_dim(logits[0], at, 0, keepdims=False)
             tok = _sample_rows(last[None], seed[None], count[None], *samp)
-            return ride(tok, st), pages
+            vec, st = ride(tok, st)
+            # under ep_batch ``prev`` is this shard's slots and ``where``
+            # lies past them on every shard but the owner's: dropped
+            tail = prev.shape[0] - vec.shape[0] + 1 + jnp.arange(
+                vec.shape[0] - 1)
+            at = jnp.concatenate([where, tail.astype(where.dtype)])
+            return (prev.at[at].set(vec.astype(prev.dtype), mode="drop"),
+                    st), pages
 
         def cow_copy(pages, src, dst):
             from distributed_lion_tpu.ops.attention import paged_copy_pages
@@ -1120,13 +1197,13 @@ class ServingEngine:
                 rest_specs=(tab, bsp, bsp, bsp, bsp, bsp),
                 out_spec=(bsp, rep), name="decode")
             self._prefill = self._jit_paged(
-                prefill, n_rest=6,
-                rest_specs=(tab, rep, bsp, bsp, rep, rep),
+                prefill, n_rest=8,
+                rest_specs=(tab, rep, bsp, bsp, rep, rep, bsp, bsp),
                 out_spec=(bsp, rep), name="prefill")
         else:
             self._decode_tick = self._jit_paged(decode_tick, n_rest=6,
                                                 name="decode")
-            self._prefill = self._jit_paged(prefill, n_rest=6 + slotted,
+            self._prefill = self._jit_paged(prefill, n_rest=8,
                                             name="prefill")
         self._cow = self._jit_cow(cow_copy)
 
@@ -1136,9 +1213,23 @@ class ServingEngine:
 
             self._speculator = build_speculator(self, cfg.speculate,
                                                 draft_model)
+        self._unread: deque = deque()  # dispatches not yet read, in order
+        self._carry: List[Completion] = []  # completed outside a step()
+        n_prev = cfg.max_seqs + len(self._moe_counters)
+        if self._mesh is None:
+            self._prev = jnp.zeros((n_prev,), jnp.int32)
+        else:   # laid out as the dispatches return it
+            self._prev = jax.device_put(
+                np.zeros((n_prev,), np.int32), NamedSharding(
+                    self._mesh, P(EXPERT_AXIS) if cfg.ep_batch else P()))
         setup.lap("setup/build_dispatches")  # jit wrappers; each program
         # compiles at its first tick, where the compile ledger names it
         setup.emit(stderr=True)  # stdout is run_serve's response stream
+        journal.emit(
+            "[setup] decode: " + (
+                "run-ahead 1 tick (device-fed last token)"
+                if self._run_ahead else "in order (speculation)"),
+            stderr=True)
 
     # ------------------------------------------------------- TP dispatch
     def _register_dispatch(self, name: Optional[str], jitted, inner,
@@ -1299,28 +1390,46 @@ class ServingEngine:
             self.metrics.on_submit(req.req_id)
         self.pending.append(req)
 
-    def _finish_timing(self, req_id, status: str) -> Dict[str, Any]:
+    def _finish_timing(self, req_id, status: str,
+                       tick: Optional[int] = None) -> Dict[str, Any]:
         """Retire the request's clocks into a timing dict (fed through
         the metrics plane when armed, which adds wall ``ttft_ms``) and
         journal the terminal ``serve_finish`` event — the per-request
-        record run_analyze --serve builds waterfalls from."""
-        timing = self.times.finished(req_id, self.stats["ticks"])
+        record run_analyze --serve builds waterfalls from. ``tick`` is the
+        tick whose dispatch made the last token; the host may have read it
+        a tick later, and ``delivery_lag_ticks`` (0 or 1) says so."""
+        now = self.stats["ticks"]
+        tick = now if tick is None else tick
+        timing = self.times.finished(req_id, tick)
+        timing["delivery_lag_ticks"] = now - tick
         if self.metrics is not None:
             timing = self.metrics.on_finish(req_id, timing, status,
-                                            tick=self.stats["ticks"])
+                                            tick=now)
         journal.active().event("serve_finish", req_id=str(req_id),
                                reason=status, **timing)
         return timing
 
+    @property
+    def _run_ahead(self) -> bool:
+        """Tick t+1's decode dispatch is enqueued before the host reads
+        tick t's tokens, unless each tick's outcome shapes the next
+        tick's operands (a speculator's accept / reject)."""
+        return self._speculator is None
+
     def has_work(self) -> bool:
-        return bool(self.pending) or any(s is not None for s in self.slots)
+        """Also true while a dispatch is unread or a completion is held
+        for the next ``step()``: every driver loop drains through it."""
+        return bool(self.pending or self._unread or self._carry) \
+            or any(s is not None for s in self.slots)
 
     def export_records(self) -> List[RecoveryRecord]:
         """Snapshot every unfinished request (resident slots + the pending
-        queue) as :class:`RecoveryRecord`s — pure host-side table/list
-        reads, no device sync. The fleet copies these OUT of the replica
-        each tick so a crash recovers from the shadow, never from the
-        dead engine."""
+        queue) as :class:`RecoveryRecord`s. The fleet copies these OUT of
+        the replica each tick so a crash recovers from the shadow, never
+        from the dead engine. A record holds every token made: an unread
+        dispatch is read first (a ``serve/drain``), and what that read
+        completes is returned by the next ``step()``."""
+        self._drain("export_records", self._carry)
         recs = []
         for s in self.slots:
             if s is None:
@@ -1340,7 +1449,10 @@ class ServingEngine:
         a NEW engine can warm-start its page pool by re-prefilling each
         shared chain once instead of cold prefilling it per request.
         Empty without ``prefix_cache`` (nothing shared, nothing to save).
-        Host-side dict walks only — no device sync."""
+        Host-side dict walks only — no device sync, and no drain: a chain
+        is registered at admission from the request's history (prompt and
+        committed tokens), never from a token a dispatch has yet to
+        deliver."""
         if not self._prefix_caches:
             return []
         seen = set()
@@ -1367,7 +1479,9 @@ class ServingEngine:
 
         bt = self.tables
         if not self._ep_batch:
-            return jnp.asarray(bt.tables)
+            # a copy: the host goes on to free and grow rows while the
+            # dispatch that reads this is still in flight
+            return jnp.asarray(bt.tables.copy())
         base = (np.arange(bt.max_seqs, dtype=np.int32)
                 // bt.slots_per_group) * bt.blocks_per_group
         local = np.where(bt.tables == bt.sentinel, bt.blocks_per_group,
@@ -1456,12 +1570,15 @@ class ServingEngine:
 
     # -------------------------------------------------------------- ticks
     def _dispatch_prefill(self, req: Request, slot: int, covered: int,
-                          suffix: List[int], padded: int) -> int:
-        """Ship ONE admitted request's prefill and return its sampled
-        first token. All device-array construction for the dispatch
-        happens here, at the dispatch boundary — the admission loop's
-        body stays numpy/table math (graft-check DLT010 pins that
-        shape), and the readback is ONE host sync per prefill."""
+                          suffix: List[int], padded: int):
+        """Enqueue ONE admitted request's prefill and return its output
+        vector (not read) and MoE load scalars. The program writes the
+        sampled first token over ``prev[slot]``, so the decode tick
+        enqueued next reads it on the device; the host reads the vector
+        when its turn comes (:meth:`_read`). All device-array construction
+        for the dispatch happens here, at the dispatch boundary — the
+        admission loop's body stays numpy/table math (graft-check DLT010
+        pins that shape)."""
         import jax.numpy as jnp
 
         toks = np.zeros((1, padded), np.int32)
@@ -1470,11 +1587,11 @@ class ServingEngine:
         g = bt.group_of(slot)
         if self._ep_batch:
             # only the OWNER group's shard gets the real table row
-            # (LOCAL ids) and the true length — the other shards see
-            # all-sentinel + length 0 (every lane invalid): their
+            # (LOCAL ids), the true length and a place in its share of
+            # ``prev`` — the other shards see all-sentinel + length 0
+            # (every lane invalid) and a place past their slots: their
             # scatters drop, their lanes consume zero expert capacity,
-            # their sampled lane is never read (the token output is
-            # expert-sharded [ep])
+            # their sampled lane is written nowhere
             tab = np.full((bt.groups, bt.max_blocks_per_seq),
                           bt.blocks_per_group, np.int32)
             row = bt.tables[slot]
@@ -1485,33 +1602,38 @@ class ServingEngine:
             start_h[g] = covered
             len_h = np.zeros((bt.groups,), np.int32)
             len_h[g] = len(suffix)
+            where_h = np.full((bt.groups,), bt.slots_per_group, np.int32)
+            where_h[g] = slot - g * bt.slots_per_group
             tab_dev = jnp.asarray(tab)
             start_dev = jnp.asarray(start_h)
             len_dev = jnp.asarray(len_h)
+            where_dev = jnp.asarray(where_h)
         else:
-            tab_dev = jnp.asarray(bt.tables[slot:slot + 1])
+            # a copy: the row grows and is freed while this is in flight
+            tab_dev = jnp.asarray(bt.tables[slot:slot + 1].copy())
             start_dev = jnp.full((1,), covered, jnp.int32)
             len_dev = jnp.int32(len(suffix))
+            where_dev = jnp.full((1,), slot, jnp.int32)
         # the sample index resumes at len(committed): the key for this
         # draw is fold_in(key(seed), len(committed)) — the exact key the
         # pre-migration engine would use next
         rest = (tab_dev, jnp.asarray(toks), start_dev, len_dev,
-                jnp.uint32(req.seed), jnp.int32(len(req.committed)))
-        if self._slotted:     # whose ring or state the layers write
-            rest += (jnp.full((1,), slot, jnp.int32),)
+                jnp.uint32(req.seed), jnp.int32(len(req.committed)),
+                self._prev, where_dev)
         self._guard("prefill", rest)
-        (tok, st), self.pages = self._prefill(self.params, self.pages,
+        (vec, st), self.pages = self._prefill(self.params, self.pages,
                                               *rest)
-        # ONE host sync per prefill dispatch (the owner group's lane
-        # under ep_batch; the only lane otherwise)
-        with journal.span("serve/token_read"):
-            tok = np.asarray(tok).reshape(-1)
-            first = int(tok[g if self._ep_batch else 0])
-        self._absorb_moe_stats(st)
-        self._absorb_counters(tok[1:], "moe_prefill_")
-        return first
+        return vec, st
 
-    def _admit(self, completions: List[Completion]) -> None:
+    def _enqueued(self, kind: str, vec, st, rows: List[tuple]) -> None:
+        """A dispatch is on the device's queue: its output vector is the
+        next dispatch's ``prev``, its copy to the host starts now, and its
+        read waits its turn (:meth:`_read`)."""
+        self._prev = vec
+        vec.copy_to_host_async()
+        self._unread.append(_Unread(kind, self.stats["ticks"], vec, st, rows))
+
+    def _admit(self, completions: List[Completion]) -> int:
         budget = self.cfg.prefill_cap_tokens
         admitted = 0
         while self.pending:
@@ -1572,11 +1694,10 @@ class ServingEngine:
             with journal.span("serve/prefill", req_id=str(req.req_id),
                            prompt_len=L, padded=P, slot=slot,
                            shared=covered, resumed=len(req.committed)):
-                first = self._dispatch_prefill(req, slot, covered,
-                                               suffix, P)
+                vec, st = self._dispatch_prefill(req, slot, covered,
+                                                 suffix, P)
             budget -= P
             admitted += 1
-            self.stats["prefill_dispatches"] += 1
             self.stats["prefill_tokens"] += len(suffix)
             self.stats["padded_prefill_tokens"] += P
             if self.model.state_layers:
@@ -1591,21 +1712,59 @@ class ServingEngine:
                     self.stats["prefix_hits"] += 1
                     self.stats["shared_tokens"] += covered
                 self._prefix_for(slot).register(slot, hist)
-            slot_state = _Slot(req=req, cache_len=L, last_tok=first,
-                               budget=(req.max_new_tokens
-                                       or self.cfg.max_new_tokens))
-            slot_state.gen = list(req.committed) + [first]
-            self.slots[slot] = slot_state
-            self.times.first_token(req.req_id, self.stats["ticks"])
-            if self.metrics is not None:
-                self.metrics.on_first_token(req.req_id)
+            s = _Slot(req=req, cache_len=L, gen=list(req.committed),
+                      unread=1, budget=(req.max_new_tokens
+                                        or self.cfg.max_new_tokens))
+            self.slots[slot] = s
+            self._enqueued("prefill", vec, st, [(slot, s)])
             if self._speculator is not None:
                 self._speculator.on_admit(slot, hist, len(req.committed))
-            self._maybe_finish(slot, completions)
+            if not self._run_ahead:
+                # in order: the speculative tick drafts from the token
+                self._read_unread(completions)
+            else:
+                self._retire_if_spent(slot, s)
+        return admitted
+
+    def _release(self, slot: int, s: _Slot, reason: str) -> None:
+        """Hand ``slot``'s table row and page refs back and empty the
+        slot (``serve/evict``). Dispatches enqueued so far still see the
+        row as it was (each took its own copy), and the device runs them
+        in order before any prefill that reuses the pages."""
+        with journal.span(
+                "serve/evict", req_id=str(s.req.req_id), slot=slot,
+                reason=reason, n_generated=(
+                    min(len(s.gen) + s.unread, s.budget)
+                    if reason == "length" else len(s.gen))):
+            # refcount-honest accounting: evicting a sharer whose pages
+            # all outlive it (prefix cache / other slots) frees ZERO
+            # physical pages — freed_pages records what really returned
+            freed = self.tables.free_slot(slot)
+            self.stats["freed_pages"] += freed
+            self.slots[slot] = None
+            self.stats["evictions"] += 1
+            if reason == "timeout":
+                self.stats["timeouts"] += 1
+            if self._speculator is not None:
+                self._speculator.on_evict(slot)
+
+    def _retire_if_spent(self, slot: int, s: _Slot) -> None:
+        """An end the host can foresee: the tokens dispatched for ``s``
+        reach its budget, so it is not dispatched again and its slot and
+        pages are free for the next admission. Its Completion waits for
+        the read of its last token."""
+        if len(s.gen) + s.unread >= s.budget:
+            self._release(slot, s, "length")
 
     def _maybe_finish(self, slot: int, completions: List[Completion],
-                      overflow: bool = False, timeout: bool = False) -> None:
-        s = self.slots[slot]
+                      overflow: bool = False, timeout: bool = False,
+                      s: Optional[_Slot] = None,
+                      tick: Optional[int] = None) -> None:
+        """End ``s`` (default: the slot's resident) if it has a reason to
+        end, on the tokens the host has read: release the slot unless it
+        was retired at dispatch, and emit the Completion. ``tick``: the
+        tick whose dispatch made its last token (default: this one)."""
+        s = self.slots[slot] if s is None else s
         reason = None
         if overflow:
             reason = "overflow"
@@ -1618,107 +1777,173 @@ class ServingEngine:
             reason = "length"
         if reason is None:
             return
-        with journal.span("serve/evict", req_id=str(s.req.req_id),
-                          slot=slot, reason=reason, n_generated=len(s.gen)):
-            # refcount-honest accounting: evicting a sharer whose pages
-            # all outlive it (prefix cache / other slots) frees ZERO
-            # physical pages — freed_pages records what really returned
-            freed = self.tables.free_slot(slot)
-            self.stats["freed_pages"] += freed
-            self.slots[slot] = None
-            self.stats["evictions"] += 1
-            if reason == "timeout":
-                self.stats["timeouts"] += 1
-            if self._speculator is not None:
-                self._speculator.on_evict(slot)
+        # overflow and timeout are decided on a drained engine; an EOS may
+        # leave one row in flight, which its read drops
+        assert not s.unread or reason == "eos", (reason, s.unread)
+        if self.slots[slot] is s:
+            self._release(slot, s, reason)
+        s.done = True
         self._deadline_at.pop(s.req.req_id, None)
         completions.append(Completion(
             s.req.req_id, len(s.req.tokens), list(s.gen), reason,
-            timing=self._finish_timing(s.req.req_id, reason)))
+            timing=self._finish_timing(s.req.req_id, reason, tick)))
 
-    def _decode_operands(self, active: List[int],
-                         completions: List[Completion]):
-        """The decode tick's host half (``serve/decode_build``): grow the
-        tables for the tick's ONE write per active slot (CoW'ing a shared
+    def _read(self, u: _Unread, completions: List[Completion]) -> None:
+        """Read one dispatch's output vector — the host's ONE sync with
+        the device for that dispatch, blocking until it has run — and
+        commit its rows: the one routine through which a token reaches
+        ``gen``, ``decode_tokens`` / ``prefill_dispatches`` count it and a
+        request can end by EOS or length."""
+        span = journal.span
+        with span("serve/token_read", of=u.kind, tick=u.tick):
+            vec = np.asarray(u.vec)
+        with span("serve/commit", batch=len(u.rows)):
+            first = u.kind == "prefill"
+            self._absorb_moe_stats(u.st)
+            self._absorb_counters(vec[self.cfg.max_seqs:],
+                                  "moe_prefill_" if first else "moe_")
+            for i, s in u.rows:
+                s.unread -= 1
+                if s.done:
+                    # it sampled EOS in the tick before; this row was
+                    # already enqueued. Never appended, never counted.
+                    self.stats["run_ahead_discarded"] += 1
+                    continue
+                s.last_tok = int(vec[i])
+                s.gen.append(s.last_tok)
+                if first:
+                    self.stats["prefill_dispatches"] += 1
+                    self.times.first_token(s.req.req_id, u.tick)
+                    if self.metrics is not None:
+                        self.metrics.on_first_token(s.req.req_id)
+                else:
+                    self.stats["decode_tokens"] += 1
+                self._maybe_finish(i, completions, s=s, tick=u.tick)
+
+    def _read_unread(self, completions: List[Completion],
+                     keep: int = 0) -> None:
+        """Read the unread dispatches, oldest first, down to the newest
+        ``keep``."""
+        while len(self._unread) > keep:
+            self._read(self._unread.popleft(), completions)
+
+    def _drain(self, why: str, completions: List[Completion]) -> None:
+        """Read every unread dispatch NOW, before its turn: a decision
+        that needs the tokens is about to be made (``why``: overflow,
+        deadline, export_records). No-op with nothing unread."""
+        if not self._unread:
+            return
+        self.stats["run_ahead_drains"] += 1
+        with journal.span("serve/drain", reason=why,
+                          dispatches=len(self._unread)):
+            self._read_unread(completions)
+
+    def _reserve_write(self, slot: int, s: _Slot,
+                       cow_pairs: List[tuple]) -> bool:
+        """Pages for the tick's ONE write of ``s`` (CoW'ing a shared
         boundary page first — the first decode write after a cache-hit
-        admit is the canonical divergent write), then build the six
-        operands. A slot the pool can't serve even after reclaim is
-        evicted as overflow (truncated output) and leaves ``active``, so
-        the rest of the batch keeps moving. Returns None when no slot is
-        left."""
+        admit is the canonical divergent write)."""
+        return (self._grow(slot, s.cache_len + 1)
+                and self._cow_if_shared(slot, s.cache_len, cow_pairs))
+
+    def _decode_operands(self, completions: List[Completion]):
+        """The decode tick's host half (``serve/decode_build``): reserve
+        every resident's write, then build the operands. None of it
+        waits for a token: ``lens`` and ``counts`` advance at dispatch,
+        and the rows' last tokens are ``prev``, on the device. A slot the
+        pool can't serve even after reclaim is evicted as overflow — after
+        a drain, since its truncated output holds every token made and the
+        unread tick may free a slot's pages by EOS — and the rest of the
+        batch keeps moving. Returns ``(operands, rows)``, or None when no
+        slot is left."""
         import jax.numpy as jnp
 
         cow_pairs: List[tuple] = []
-        for i in list(active):
-            s = self.slots[i]
-            if not (self._grow(i, s.cache_len + 1)
-                    and self._cow_if_shared(i, s.cache_len, cow_pairs)):
+        for i, s in enumerate(self.slots):
+            if s is None or self._reserve_write(i, s, cow_pairs):
+                continue
+            # (the copies queued so far first: the drain may free their
+            # pages, and a page minted twice must not be copied into twice
+            # by one dispatch)
+            self._flush_cow(cow_pairs)
+            del cow_pairs[:]
+            self._drain("overflow", completions)
+            if self.slots[i] is s and not self._reserve_write(
+                    i, s, cow_pairs):
                 self._maybe_finish(i, completions, overflow=True)
-                active.remove(i)
-        if not active:
+        rows = [(i, s) for i, s in enumerate(self.slots) if s is not None]
+        if not rows:
             return None
         self._flush_cow(cow_pairs)
         S = self.cfg.max_seqs
         lens = np.zeros((S,), np.int32)
-        last = np.zeros((S,), np.int32)
         act = np.zeros((S,), bool)
         seeds = np.zeros((S,), np.uint32)
         counts = np.zeros((S,), np.int32)
-        for i in active:
-            s = self.slots[i]
+        for i, s in rows:
             lens[i] = s.cache_len
-            last[i] = s.last_tok
             act[i] = True
             seeds[i] = s.req.seed
-            counts[i] = len(s.gen)  # index of the token being sampled
-        return (self._device_tables(), jnp.asarray(lens),
-                jnp.asarray(last), jnp.asarray(act),
-                jnp.asarray(seeds), jnp.asarray(counts))
+            # index of the token being sampled
+            counts[i] = len(s.gen) + s.unread
+        return (self._device_tables(), jnp.asarray(lens), self._prev,
+                jnp.asarray(act), jnp.asarray(seeds),
+                jnp.asarray(counts)), rows
 
     def _decode(self, completions: List[Completion]) -> None:
-        active = [i for i, s in enumerate(self.slots) if s is not None]
-        if not active:
+        """Enqueue this tick's decode dispatch, THEN read what was
+        enqueued before it (the last tick's tokens, this tick's prefills):
+        the device runs tick t while the host commits tick t-1 and builds
+        tick t+1."""
+        residents = sum(s is not None for s in self.slots)
+        if not (residents or self._unread):
             return
         span = journal.span
-        with span("serve/decode_tick", batch=len(active)):
+        with span("serve/decode_tick", batch=residents):
             with span("serve/decode_build"):
-                rest = self._decode_operands(active, completions)
-            if rest is None:
-                return
-            with span("serve/decode_dispatch"):  # enqueue only
-                self._guard("decode", rest)
-                (toks, st), self.pages = self._decode_tick(
-                    self.params, self.pages, *rest)
-            with span("serve/token_read"):
-                toks = np.asarray(toks)  # ONE host sync for the whole batch
-            with span("serve/commit", batch=len(active)):
-                self._absorb_moe_stats(st)
-                self._absorb_counters(toks[self.cfg.max_seqs:])
-                self.stats["decode_ticks"] += 1
-                self.stats["decode_tokens"] += len(active)
-                self.stats[self.model.kernel_stat] += self._decode_kernel
-                self.stats["kv_pages_table"] += (
-                    self.cfg.max_seqs * self.cfg.max_blocks_per_seq)
-                if self._windowed:
-                    self.stats["window_kernel_ticks"] += self._decode_kernel
-                if self.model.state_layers:
-                    self.stats["state_rows_stepped"] += (
-                        len(active) * len(self.model.state_layers))
-                for i in active:
-                    s = self.slots[i]
-                    s.cache_len += 1
-                    self.stats["kv_pages_read"] += self.tables.blocks_for(
-                        s.cache_len)
-                    s.last_tok = int(toks[i])
-                    s.gen.append(int(toks[i]))
-                    self._maybe_finish(i, completions)
+                built = self._decode_operands(completions)
+            if built is not None:
+                rest, rows = built
+                with span("serve/decode_dispatch"):  # enqueue only
+                    self._guard("decode", rest)
+                    self.stats["run_ahead_ticks"] += bool(self._unread)
+                    (vec, st), self.pages = self._decode_tick(
+                        self.params, self.pages, *rest)
+                    self._enqueued("decode", vec, st, rows)
+                    self._count_dispatched(rows)
+            # all but the dispatch just enqueued (a speculator's tick is
+            # not this method: it reads its own dispatch in order)
+            self._read_unread(completions, keep=int(built is not None))
+
+    def _count_dispatched(self, rows: List[tuple]) -> None:
+        """What the enqueued decode tick does on the device, counted as it
+        is enqueued (the rooflines divide device time by these), and the
+        rows' own clocks: one more cached position, one more unread
+        token, and the slot's end if the host can foresee it."""
+        self.stats["decode_ticks"] += 1
+        self.stats[self.model.kernel_stat] += self._decode_kernel
+        self.stats["kv_pages_table"] += (
+            self.cfg.max_seqs * self.cfg.max_blocks_per_seq)
+        if self._windowed:
+            self.stats["window_kernel_ticks"] += self._decode_kernel
+        if self.model.state_layers:
+            self.stats["state_rows_stepped"] += (
+                len(rows) * len(self.model.state_layers))
+        for i, s in rows:
+            s.cache_len += 1
+            s.unread += 1
+            self.stats["kv_pages_read"] += self.tables.blocks_for(
+                s.cache_len)
+            self._retire_if_spent(i, s)
 
     def _expire_deadlines(self, completions: List[Completion]) -> None:
         """Evict every request past its wall-clock deadline with the
         honest ``timeout`` status (partial output attached) — checked at
         the tick boundary BEFORE admit/decode, so an expired pending
         request never pays a prefill and an expired resident never pays
-        another dispatch. Host-side clock reads only."""
+        another dispatch. A resident's partial output holds every token
+        made: an unread dispatch is read first (which may end it some
+        other way). Host-side clock reads only."""
         if not self._deadline_at:
             return
         now = self._now()
@@ -1740,30 +1965,33 @@ class ServingEngine:
             else:
                 keep.append(req)
         self.pending = keep
-        for i, s in enumerate(self.slots):
-            if s is None:
-                continue
-            at = self._deadline_at.get(s.req.req_id)
-            if at is not None and now >= at:
+        late = [i for i, s in enumerate(self.slots) if s is not None
+                and now >= self._deadline_at.get(s.req.req_id, np.inf)]
+        if late:
+            self._drain("deadline", completions)
+        for i in late:
+            if self.slots[i] is not None:
                 self._maybe_finish(i, completions, timeout=True)
 
     def step(self) -> List[Completion]:
         """One engine tick: expire deadlines, admit/prefill under the
-        fairness cap, then one decode dispatch over the rolling batch.
-        Returns the requests that finished this tick."""
-        completions: List[Completion] = []
+        fairness cap, enqueue one decode dispatch over the rolling batch,
+        then read and commit what was enqueued before it. Returns the
+        requests whose last token the host read this tick: one made by
+        tick t's decode dispatch is read, and its request returned, by
+        ``step()`` t+1 (``timing["delivery_lag_ticks"]``), unless a
+        speculator keeps the engine in order."""
+        completions, self._carry = self._carry, []
         self.stats["ticks"] += 1
         span = journal.span
         with span("serve/tick", tick=self.stats["ticks"]):
             with span("serve/expire"):
                 self._expire_deadlines(completions)
             with span("serve/admit", pending=len(self.pending)) as admit:
-                before = self.stats["prefill_dispatches"]
-                self._admit(completions)
-                admit.set(prefills=self.stats["prefill_dispatches"] - before)
+                admit.set(prefills=self._admit(completions))
             if self.metrics is not None:
-                # per-token decode interval = the decode dispatch's wall
-                # time over however many tokens it committed (1/slot
+                # per-token decode interval = the tick's wall time from
+                # here over however many tokens it committed (1/slot
                 # plain, up to k+1/slot speculative) — host clock reads
                 # only, the dispatch itself is untouched
                 t0 = self._now()

@@ -156,6 +156,10 @@ def test_moe_stats_bit_equal_under_sharding(moe_params, ep):
     _run(e1, reqs)
     for k in ("moe_valid_tokens", "moe_kept_tokens", "moe_capacity_slots"):
         assert e0.stats[k] == e1.stats[k], (k, e0.stats, e1.stats)
+    # batch-sharded, the token vector is fed back expert-sharded as the
+    # dispatches return it: no gather, so this layout runs ahead too
+    assert e1._run_ahead and 0 < e1.stats["run_ahead_ticks"] \
+        == e0.stats["run_ahead_ticks"]
 
 
 def test_ep_batch_refusals(moe_params):

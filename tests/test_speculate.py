@@ -228,6 +228,9 @@ def test_partial_accept_rollback_matches_token_by_token():
     done = []
     while plain.has_work():
         done += plain.step()
+        # the plain engine runs a tick ahead of its reads: read the tick's
+        # tokens (a drain), so the snapshot is the in-order state
+        plain.export_records()
         s = plain.slots[0]
         if s is not None:
             snaps[len(s.gen)] = (_alloc_state(plain.tables), s.cache_len,

@@ -215,6 +215,10 @@ def test_tp_prefix_cache_composes():
     for r in reqs:
         assert got[r.req_id].tokens == base[r.req_id].tokens, r.req_id
     assert eng.stats["prefix_hits"] > 0
+    # the sharded tick runs ahead of its reads like the plain one (the
+    # token vector is replicated over the tensor axis: fed back as it is)
+    assert eng._run_ahead and eng.stats["run_ahead_ticks"] > 0
+    assert eng.stats["run_ahead_drains"] == 0
 
 
 def test_tp_one_decode_dispatch_per_tick():
