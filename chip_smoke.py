@@ -1111,6 +1111,293 @@ def kda_at_size() -> None:
         f"{rows * 2 * H * d * d * 4 / ms / 1e6:.0f} GB/s of state")
 
 
+def phase_serve_sparse() -> None:
+    """The block-sparse family (models/minicpm_sala) at a small lane-aligned
+    size through the constructors ``run_serve --model_family minicpm_sala``
+    calls, with a ``dense_len`` of 256 so that its rows select: the decode
+    tick holds ``lightning_step`` once a Lightning layer and ``paged_attn``
+    once a ``minicpm4`` layer, no dispatch copies a state leaf, a page leaf
+    or the compressed keys; slots are admitted twice; and the S = 1 kernel
+    path through pages, lists and states gives the logits of one prefill
+    window (masked tiles, chunked form) on the tokens it served, in float32
+    at the highest matmul precision (``phase_serve_state`` says why). Before
+    it, selection and the recurrence at the published shape
+    (:func:`sparse_at_size`, :func:`lightning_at_size`)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from distributed_lion_tpu.models.minicpm_sala import (
+        MiniCPMSalaConfig, minicpm_sala_decode_paged, minicpm_sala_init,
+    )
+    from distributed_lion_tpu.ops.sparse_select import SparseConfig
+    from distributed_lion_tpu.serve.engine import (
+        Request, ServeConfig, ServeModel, ServingEngine,
+    )
+    from distributed_lion_tpu.serve.kv_cache import init_page_leaves
+
+    cfg = MiniCPMSalaConfig.tiny(
+        vocab_size=1024, d_model=256, n_head=32, n_kv_head=2, head_dim=128,
+        d_ff=512, sparse=SparseConfig(32, 16, 64, 128, 4, 1, 256),
+        dim_model_base=64)
+    params = minicpm_sala_init(jax.random.key(37), cfg)
+    block, max_blocks, n_seq = 16, 40, 4
+    model = ServeModel.for_minicpm_sala(params, cfg)
+    engine = ServingEngine(model, ServeConfig(
+        max_seqs=n_seq, block_size=block, max_blocks_per_seq=max_blocks,
+        moe_stats=True, prefill_cap_tokens=512))
+    rng = np.random.default_rng(37)
+    prompts = [rng.integers(0, cfg.vocab_size, n).tolist()
+               for n in (300, 512, 411, 270, 140, 333)]
+    done = engine.run([Request(req_id=i, tokens=p,
+                               max_new_tokens=SERVE_NEW_TOKENS)
+                       for i, p in enumerate(prompts)])
+    stats = engine.stats
+    check(all(done[i].reason == "length" for i in range(len(prompts))), done)
+    assert_donated(engine)
+    assert_pool_in_place(engine, kernels=("lightning_step", "paged_attn"))
+    states = len(cfg.lightning_layers)
+    check(stats["state_rows_stepped"] == stats["decode_tokens"] * states,
+          stats)
+    check(stats["state_resets"] == len(prompts) > n_seq, stats)
+    check(stats["decode_attn_kernel_ticks"] == stats["decode_ticks"] > 0,
+          stats)
+    check(stats["sparse_rows"] > 0 and stats["dense_rows"] > 0, stats)
+    walked = stats["kv_pages_read"] * cfg.n_kv_head * len(cfg.sparse_layers)
+    check(0 < stats["kv_pages_selected"] < walked, stats)
+    log(f"  state_rows_stepped {stats['state_rows_stepped']} (= decode "
+        f"tokens x {states} Lightning layers), state_resets "
+        f"{stats['state_resets']} over {n_seq} slots; sparse_rows "
+        f"{stats['sparse_rows']}, dense_rows {stats['dense_rows']}, "
+        f"kv_pages_selected {stats['kv_pages_selected']} of {walked} a "
+        f"walk of every page would hand attention, ck_rows_written "
+        f"{stats['ck_rows_written']}")
+
+    sparse_at_size()
+    lightning_at_size()
+    tables = jnp.arange(n_seq * max_blocks, dtype=jnp.int32)[::-1].reshape(
+        n_seq, max_blocks)
+    slots = jnp.arange(n_seq, dtype=jnp.int32)
+    f32 = jnp.float32
+    cfg32 = dataclasses.replace(cfg, param_dtype=f32, compute_dtype=f32)
+    params32 = jax.tree.map(lambda x: x.astype(f32), params)
+    with jax.default_matmul_precision("highest"):
+        worst = teacher_forced_gap(
+            lambda t, pg, pos, valid: minicpm_sala_decode_paged(
+                params32, t, cfg32, pg, tables, slots, pos, valid),
+            lambda: init_page_leaves(
+                cfg.n_layer, n_seq * max_blocks, block, model.page_leaves,
+                f32, state=(cfg.lightning_layers, n_seq,
+                            model.state_leaves)),
+            prompts[:n_seq], [done[i].tokens for i in range(n_seq)], block)
+    log(f"  kernel path through pages, lists and states vs one prefill "
+        f"window in float32 at the highest matmul precision, logits "
+        f"teacher-forced on the served tokens: max |diff| {worst:.5f} "
+        f"(tol {LOGIT_TOL})")
+    check(worst <= LOGIT_TOL, f"sparse decode logits off by {worst}")
+
+
+def sparse_at_size() -> None:
+    """Selection and attention over the selected pages at the published
+    shape (32 query heads, 2 kv heads of 128, pages of 16, blocks of 64, the
+    64 best of 256 at 16,384 positions), with moderately loud keys planted
+    in three blocks a kv head, other blocks for each head, outside the first
+    block and the local window: the compacted lists must hold the planted
+    blocks' pages (their own head's, not the other's), the kernel's output
+    over the lists must be the masked dense attention's and must carry the
+    planted values, a walk of every page (``nosel``) must differ from it by
+    far more than the tolerance, and the prefill's masked tiles must give
+    the decode's answer at the same position; timed."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from distributed_lion_tpu.ops import sparse_select as ss
+    from distributed_lion_tpu.ops.attention import (
+        paged_decode_attention, paged_scatter_kv,
+    )
+
+    sp = ss.SparseConfig()
+    H, KV, hd, bs, S = 32, 2, 128, 16, 16384
+    nb = S // bs
+    planted = ((37, 101, 150), (11, 99, 170))
+    ks = jax.random.split(jax.random.key(3737), 6)
+
+    def normed(x):
+        return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True))
+
+    centre = normed(jax.random.normal(ks[0], (KV, hd)))
+    q = normed(jnp.repeat(centre, H // KV, 0)[None, :, None]
+               + 0.5 * jax.random.normal(ks[1], (1, H, S, hd)))
+    k = normed(jax.random.normal(ks[2], (1, S, KV, hd)))
+    v = jax.random.normal(ks[3], (1, S, KV, hd))
+    mark = jax.random.normal(ks[4], (KV, hd)) * 4
+    for g, blocks in enumerate(planted):
+        for b in blocks:
+            at = slice(64 * b, 64 * b + 64)
+            k = k.at[0, at, g].set(0.5 * centre[g])
+            v = v.at[0, at, g].set(mark[g])
+    q, k, v = (x.astype(jnp.bfloat16) for x in (q, k, v))
+    tables = jax.random.permutation(ks[5], nb).astype(jnp.int32)[None]
+    zero = jnp.zeros((1,), jnp.int32)
+    pool = jnp.zeros((nb, bs, 1, KV * hd), jnp.bfloat16)
+    k_pages = paged_scatter_kv(pool, tables, zero, k)
+    v_pages = paged_scatter_kv(pool, tables, zero, v)
+    rows, whole = ss.prefill_compressed(k, jnp.asarray([S]), sp)
+    ck = ss.scatter_compressed(jnp.zeros((nb, 1, 1, KV * hd), jnp.bfloat16),
+                               tables, rows, whole)
+    pos = jnp.asarray([S - 1])
+    q1 = q[:, :, -1]
+
+    @jax.jit
+    def decode(q1, k_pages, v_pages, ck):
+        lists, held, sparse = ss.decode_page_lists(
+            q1, ck, tables, pos, jnp.asarray([True]), sp, KV, bs)
+        out = ss.sparse_decode_attention(q1, k_pages, v_pages, lists, held,
+                                         KV)
+        return lists, held, out
+
+    lists, held, out = decode(q1, k_pages, v_pages, ck)
+    check(bool((held == 63 * 64 + 64).all()), f"lists hold {held}")
+    table = np.asarray(tables[0])
+    for g in range(KV):
+        mine = set(np.asarray(lists[0, g]).tolist())
+        for b in planted[g]:
+            check(set(table[4 * b:4 * b + 4]) <= mine,
+                  f"kv head {g}'s list lacks its planted block {b}")
+        other = [b for b in planted[1 - g]
+                 if set(table[4 * b:4 * b + 4]) <= mine]
+        check(len(other) < 3, f"kv head {g}'s list is the other head's")
+        check(set(table[:4]) <= mine and set(table[-128:]) <= mine,
+              "the first block or the local window is missing")
+
+    f32 = jnp.float32
+    kept = ss.kept_blocks(q1.reshape(1, KV, H // KV, hd),
+                          rows[0].reshape(-1, KV, hd), pos, sp, nb // 4)[0]
+    mask = jnp.repeat(kept, 64, -1)                         # [KV, S]
+    sim = jnp.einsum("grd,tgd->grt", q1[0].reshape(KV, H // KV, hd).astype(f32),
+                     k[0].astype(f32)) / np.sqrt(hd)
+    dense = jax.nn.softmax(sim, -1)
+    masked = jax.nn.softmax(jnp.where(mask[:, None], sim, -jnp.inf), -1)
+    want = jnp.einsum("grt,tgd->grd", masked, v[0].astype(f32)).reshape(H, hd)
+    every = jnp.einsum("grt,tgd->grd", dense, v[0].astype(f32)).reshape(H, hd)
+    err = float(jnp.abs(out[0].astype(f32) - want).max())
+    apart = float(jnp.abs(every - want).max())
+    carried = float((out[0].astype(f32).reshape(KV, H // KV, hd)
+                     * mark[:, None]).sum(-1).min() / (mark * mark).sum(-1).max())
+    walk = paged_decode_attention(q1[:, :, None], k_pages, v_pages, tables,
+                                  pos, kv_heads=KV)[0, :, 0].astype(f32)
+    walked = float(jnp.abs(walk - want).max())
+    # probabilities go to the value product in bfloat16: 2^-8 of outputs
+    # that reach |mark|
+    tol = 0.01 * max(float(jnp.abs(want).max()), 1.0)
+    t0, n = time.time(), 20
+    for _ in range(n):
+        got = decode(q1, k_pages, v_pages, ck)[2]
+    jax.block_until_ready(got)
+    log(f"  selection at 16,384 positions, 1 row: lists hold the planted "
+        f"blocks of each kv head; kernel over the lists vs masked dense "
+        f"{err:.4f}; a walk of every page lies {apart:.3f} away; planted "
+        f"values carry {carried:.2f} of the output; "
+        f"{1e3 * (time.time() - t0) / n:.2f} ms a call; the kernel's own "
+        f"walk of every page lies {walked:.3f} away (tol {tol:.3f})")
+    check(err <= tol, f"attention over the lists off by {err}")
+    check(apart > 5 * tol,
+          f"a walk of every page is only {apart} from the selected one")
+    check(carried > 0.2, f"the planted values carry {carried} of the output")
+    check(walked > 5 * tol,
+          "the program that walks every page passes the selected check")
+
+    tiles = jax.jit(lambda q, k, v, rows: ss.sparse_prefill_attention(
+        q, k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3), rows, sp))
+    got = tiles(q, k, v, rows)
+    last = float(jnp.abs(got[0, :, -1].astype(f32) - want).max())
+    jax.block_until_ready(got)
+    t0 = time.time()
+    jax.block_until_ready(tiles(q, k, v, rows))
+    check(last <= tol, f"prefill's last query off by {last}")
+    log(f"  prefill attention of one minicpm4 layer at 16,384 (dense to "
+        f"8,192 by the tiled kernel, masked tiles past it): last query vs "
+        f"masked dense {last:.4f}; {1e3 * (time.time() - t0):.1f} ms a layer")
+
+
+def lightning_at_size() -> None:
+    """The Lightning recurrence at the published shape (32 heads of 128 x
+    128, the slopes 2^-0.25 .. 2^-8): 16,384 positions in chunks by the
+    ``lightning_chunk`` kernel, a row cut at 12,345, against the
+    token-by-token scan; then the ``lightning_step`` kernel over 64 slots
+    with dead slots among them against the plain step, the dead slots'
+    states bit for bit untouched; both timed."""
+    import jax
+    import jax.numpy as jnp
+
+    from distributed_lion_tpu.models.minicpm_sala import lightning_slopes
+    from distributed_lion_tpu.ops import lightning, pallas_lightning
+
+    H, d, T, B = 32, 128, 16384, 64
+    ks = jax.random.split(jax.random.key(373), 5)
+    q, k, v = (jax.random.normal(ks[i], (1, T, H, d)).astype(jnp.bfloat16)
+               for i in range(3))
+    slope = lightning_slopes(H)
+    lengths = jnp.asarray([12345])
+    zero = jnp.zeros((1, H, d, d), jnp.float32)
+
+    @jax.jit
+    def scan(q, k, v):
+        def step(S, x):
+            q, k, v, t = x
+            o, S = lightning.lightning_step_xla(
+                S, q, k, v, jnp.exp(-slope), t < lengths)
+            return S, o
+        S, o = jax.lax.scan(step, zero, tuple(
+            jnp.moveaxis(x, 1, 0) for x in (q, k, v)) + (jnp.arange(T),))
+        return jnp.moveaxis(o, 0, 1), S
+
+    check(pallas_lightning.chunk_kernel_takes(zero.shape, zero.dtype),
+          "the chunk kernel refuses the published shape")
+    chunked = jax.jit(lightning.lightning_chunked)
+    o2, s2 = scan(q, k, v)
+    o1, s1 = chunked(q, k, v, slope, lengths, zero)
+    keep = (jnp.arange(T) < lengths[0])[None, :, None, None]
+    scale = float(jnp.abs(o2).max())
+    err = max(float(jnp.abs((o1 - o2) * keep).max()),
+              float(jnp.abs(s1 - s2).max()))
+    check(bool(jnp.isfinite(o1).all()), "lightning_chunk not finite")
+    check(err <= 2e-3 * max(scale, 1.0),
+          f"lightning_chunk vs scan: {err} of {scale}")
+    t0, n = time.time(), 5
+    for _ in range(n):
+        o1 = chunked(q, k, v, slope, lengths, zero)[0]
+    jax.block_until_ready(o1)
+    log(f"  lightning_chunk kernel, {T} positions cut at 12,345: max |diff| "
+        f"to the scan {err:.2e} (outputs to {scale:.1f}); "
+        f"{1e3 * (time.time() - t0) / n:.2f} ms a layer's worth")
+
+    state = jax.random.normal(ks[3], (B, H, d, d))
+    q1, k1, v1 = (x[0, :B].astype(jnp.float32) for x in (q, k, v))
+    live = (jnp.arange(B) % 7 != 3) & (jnp.arange(B) > 1)
+    lam = jnp.exp(-slope)
+    o_ref, s_ref = jax.jit(lightning.lightning_step_xla)(
+        state, q1, k1, v1, lam, live)
+    step = jax.jit(pallas_lightning.lightning_step, donate_argnums=(0,))
+    o_k, s_k = step(state + 0, q1, k1, v1, lam, live)
+    err = max(float(jnp.abs(o_k - o_ref).max()),
+              float(jnp.abs(s_k - s_ref).max()))
+    check(err <= 1e-3, f"lightning_step kernel vs plain step: {err}")
+    check(bool((s_k[~live] == state[~live]).all()),
+          "lightning_step wrote a dead slot's state")
+    s_k = jax.block_until_ready(step(s_k, q1, k1, v1, lam, live)[1])
+    t0, n = time.time(), 20
+    for _ in range(n):
+        s_k = step(s_k, q1, k1, v1, lam, live)[1]
+    jax.block_until_ready(s_k)
+    ms = 1e3 * (time.time() - t0) / n
+    rows = int(live.sum())
+    log(f"  lightning_step kernel, {rows} live of {B} slots: max |diff| "
+        f"{err:.2e}, dead slots untouched; {ms:.3f} ms a call = "
+        f"{rows * 2 * H * d * d * 4 / ms / 1e6:.0f} GB/s of state")
+
+
 def _replica_check(tree, what: str) -> int:
     """Every leaf fully replicated over distinct devices and its replicas
     bit-identical (exact). Returns the device count seen."""
@@ -1314,7 +1601,7 @@ def main() -> int:
     ap.add_argument("--only", default="",
                     help="run this one phase (kernels, train, serve, "
                          "serve_latent, serve_window, serve_state, "
-                         "multichip) and no "
+                         "serve_sparse, multichip) and no "
                          "other")
     args = ap.parse_args()
 
@@ -1346,7 +1633,8 @@ def main() -> int:
                ("serve", lambda: phase_serve(out_dir)),
                ("serve_latent", phase_serve_latent),
                ("serve_window", phase_serve_window),
-               ("serve_state", phase_serve_state)]
+               ("serve_state", phase_serve_state),
+               ("serve_sparse", phase_serve_sparse)]
               if args.chips == 1 else
               [("multichip", lambda: phase_multichip(work))])
     if args.only:

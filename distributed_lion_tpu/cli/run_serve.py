@@ -194,7 +194,9 @@ def build_engine_factory(gen_args, serve_args: "ServeArguments"):
         return {"gpt2": ServeModel.for_gpt2, "llama": ServeModel.for_llama,
                 "joyai": ServeModel.for_joyai,
                 "laguna": ServeModel.for_laguna,
-                "ling": ServeModel.for_ling}[gen_args.model_family](p, c)
+                "ling": ServeModel.for_ling,
+                "minicpm_sala": ServeModel.for_minicpm_sala,
+                }[gen_args.model_family](p, c)
 
     if serve_args.speculate:
         # pure-config refusals BEFORE any checkpoint loads — a spec error

@@ -507,7 +507,8 @@ class ServeModel:
                  moe_counters: tuple = (), last_logit: bool = False,
                  shardable: bool = True, window: int = 0,
                  window_layers: tuple = (), state_layers: tuple = (),
-                 state_leaves: Optional[Dict[str, tuple]] = None):
+                 state_leaves: Optional[Dict[str, tuple]] = None,
+                 setup_note: str = ""):
         self.family = family
         self.cfg = cfg
         self.params = params
@@ -548,6 +549,9 @@ class ServeModel:
         # them too
         self.state_layers = tuple(state_layers)
         self.state_leaves = dict(state_leaves or {})
+        # the family's own ``[setup]`` line, printed at engine build with
+        # ``{slots}`` and ``{state_gb}`` filled in
+        self.setup_note = setup_note
         # the model's position budget (gpt2: learned wpe rows; llama's
         # rope extrapolates but n_ctx is still the trained horizon) — the
         # engine refuses a page geometry that would silently alias/exceed
@@ -693,6 +697,44 @@ class ServeModel:
                          cfg.compute_dtype)})
 
 
+    @staticmethod
+    def for_minicpm_sala(params: Any, cfg: Any) -> "ServeModel":
+        """MiniCPM-SALA (models/minicpm_sala): block-sparse attention
+        layers whose keys and values live in pages with one compressed key a
+        page beside them (``ck``: a page's id and lifetime, one row a stride), among
+        Lightning linear-attention layers that keep a float32 state a
+        slot."""
+        import jax.numpy as jnp
+
+        from distributed_lion_tpu.models.minicpm_sala import (
+            SALA_COUNTERS,
+            minicpm_sala_decode_paged,
+        )
+
+        def decode(p, toks, pages, tables, pos, valid=None, tp_axis=None,
+                   ep_axis=None, return_moe_stats=False, stats_axis=None,
+                   stats_lanes=None, logit_index=None, slots=None):
+            # the engine refuses tp / ep for this family at build
+            assert tp_axis is None and ep_axis is None and stats_axis is None
+            return minicpm_sala_decode_paged(
+                p, toks, cfg, pages, tables, slots, pos, valid,
+                return_moe_stats, logit_index)
+
+        H, KV, hd, sp = cfg.n_head, cfg.n_kv_head, cfg.head_dim, cfg.sparse
+        return ServeModel(
+            "minicpm_sala", cfg, params, decode, cfg.n_layer, KV, hd,
+            cfg.compute_dtype, max_positions=cfg.n_ctx,
+            page_leaves={"k": (KV, hd), "v": (KV, hd), "ck": (KV, hd, sp.kernel_stride)},
+            moe_counters=SALA_COUNTERS, last_logit=True, shardable=False,
+            state_layers=cfg.lightning_layers,
+            state_leaves={"state": ((H, hd, hd), jnp.float32)},
+            setup_note=(
+                f"sparse: top {sp.topk} of blocks of {sp.block_size}, dense "
+                f"to {sp.dense_len}, ck a page; lightning: "
+                f"{len(cfg.lightning_layers)} state layers x {{slots}} "
+                "slots, {state_gb:.2f} GB"))
+
+
 def weight_bytes(params: Any) -> int:
     """Actual storage bytes of a (possibly quantized) weight tree —
     QuantizedTensor leaves count packed codes + absmax scales, dense
@@ -821,7 +863,16 @@ class ServingEngine:
                   "prefix leaves behind, and a state has no pages to share",
                   "a rejected draft cannot be rolled back out of a state that "
                   "has already decayed and been written",
-                  "the state leaves have no sharding spec", no_exchange))):
+                  "the state leaves have no sharding spec", no_exchange)),
+                (tuple(n for n, hw in model.page_leaves.items()
+                       if len(hw) > 2),
+                 "keeps a compressed key a page beside its keys and values",
+                 ("a shared page's compressed key would be shared with it, "
+                  "and nothing copies it on write yet",
+                  "a rejected draft's position may have closed a window "
+                  "whose compressed key is already written",
+                  "the compressed-key leaf has no sharding spec",
+                  no_exchange))):
             for (on, flag), why in zip(flags, whys):
                 if on and layers:
                     raise ValueError(
@@ -1225,6 +1276,11 @@ class ServingEngine:
         setup.lap("setup/build_dispatches")  # jit wrappers; each program
         # compiles at its first tick, where the compile ledger names it
         setup.emit(stderr=True)  # stdout is run_serve's response stream
+        if model.setup_note:
+            journal.emit("[setup] " + model.setup_note.format(
+                slots=cfg.max_seqs,
+                state_gb=self.stats.get("state_bytes", 0) / 1e9),
+                stderr=True)
         journal.emit(
             "[setup] decode: " + (
                 "run-ahead 1 tick (device-fed last token)"

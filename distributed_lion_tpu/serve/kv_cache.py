@@ -80,12 +80,21 @@ def init_page_leaves(n_layer: int, num_blocks: int, block_size: int,
     * R + R - 1`` for good, whatever ``num_blocks`` is: two lifetimes in
     one pool list. ``state = (layers, max_seqs, leaves)``: those layers carry
     a recurrent state and hold :func:`init_state_leaves` instead, a third
-    kind in the same list. Allocated once at engine start; ticks update it
-    in place (donated)."""
+    kind in the same list. A leaf described by three numbers, ``(heads,
+    width, stride)``, holds one row for every ``stride`` positions of a page
+    instead of one a position: the fourth kind, a page's compressed keys
+    (``ops/sparse_select``: one row a page where ``block_size`` is the
+    stride), which have a page's id and lifetime, so they are allocated,
+    freed and counted with the page and never apart from it. Allocated once
+    at engine start; ticks update it in place (donated)."""
     import jax.numpy as jnp
 
-    def leaf(blocks, heads, width):
-        return jnp.zeros((blocks, block_size, groups,
+    def leaf(blocks, heads, width, stride=1):
+        if block_size % stride:
+            raise ValueError(
+                f"a leaf with a row every {stride} positions needs pages of "
+                f"whole strides, got block_size {block_size}")
+        return jnp.zeros((blocks, block_size // stride, groups,
                           pool_row_width(heads // groups, width)), dtype)
 
     ring_layers, ring_blocks = ring

@@ -37,7 +37,11 @@ SCOPES = ("embed", "attn", "mlp", "head", "xent", "paged_attn",
           "lion_stats", "mla/q", "mla/kv_latent", "mla_attn",
           "mla_paged_attn", "moe/route", "moe/sort", "moe/experts",
           "moe/shared", "moe/combine", "moe_gmm", "attn/qkv", "attn/rope",
-          "attn/gate", "window_attn", "full_attn")
+          "attn/gate", "window_attn", "full_attn", "kda/conv", "kda/gate",
+          "kda/step", "kda/chunk", "kda/out_norm", "kda_step", "kda_chunk",
+          "lightning/step", "lightning/chunk", "lightning/out_norm",
+          "lightning_step", "lightning_chunk", "sparse/compress",
+          "sparse/select", "sparse_attn", "dense_attn")
 NO_SCOPE = "(no scope)"
 _FIND = [(s, re.compile(r"(?<=[(/])%s(?=[)/])" % re.escape(s)))
          for s in SCOPES]

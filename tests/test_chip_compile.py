@@ -1189,3 +1189,116 @@ def test_vote_step_compiles_on_2x2_mesh_with_auto_wire(topo):
     # phase 1 of the packed wire; the compiler is free to rewrite phase 2's
     # all_gather (it becomes dynamic-update-slice + all-reduce on v5e)
     assert "all-to-all" in text
+
+
+@pytest.mark.parametrize("kind", ["decode_tick", "prefill_16384"])
+def test_minicpm_sala_serving_programs_at_the_published_shapes(
+        one_chip, kind, monkeypatch):
+    """MiniCPM-SALA as ``serve.minicpm-sala.backlog-16k`` runs it (the cut
+    configuration file: layers 9-16, the whole vocabulary; 64 slots, 81,920
+    pages of keys, values and one compressed key each in two ``minicpm4``
+    layers, 0.81 GB of float32 state in six Lightning layers), donated. The
+    decode tick holds ``lightning_step`` once a Lightning layer and
+    ``paged_attn`` once a ``minicpm4`` layer (over the compacted lists of
+    128 (row, kv head) pairs), steps the state and writes the pages IN PLACE
+    (no copy of 0.81 or 2.77 GB), and selects in XLA. The 16,384-token
+    prefill (the one bucket) runs ``lightning_chunk`` once a Lightning layer,
+    the dense half of a ``minicpm4`` layer through ``flash_gqa_fwd`` at
+    8,192, the selected half a tile of queries at a time: no ``[32, 16384,
+    16384]`` buffer outside a fused body, one position's logits, and it fits
+    the chip beside 9.2 GB of weights and cache."""
+    from distributed_lion_tpu.analysis.serve_check import pool_leaf_copies
+    from distributed_lion_tpu.models.minicpm_sala import (
+        SALA_COUNTERS, MiniCPMSalaConfig, minicpm_sala_decode_paged,
+        minicpm_sala_init,
+    )
+    from distributed_lion_tpu.serve.engine import ServeModel
+    from distributed_lion_tpu.serve.kv_cache import init_page_leaves
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    cfg = MiniCPMSalaConfig.named(os.path.join(
+        root, "benchmark", "configs", "minicpm-sala.json"))
+    block, per_seq, slots, pool = 16, 1280, 64, 81920
+    b, s_len = (slots, 1) if kind == "decode_tick" \
+        else (1, int(kind.split("_")[1]))
+
+    def place(tree):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=one_chip), tree)
+
+    model = ServeModel.for_minicpm_sala(None, cfg)
+    pages = place(jax.eval_shape(lambda: init_page_leaves(
+        cfg.n_layer, pool, block, model.page_leaves, cfg.compute_dtype,
+        state=(cfg.lightning_layers, slots, model.state_leaves))))
+    assert [sorted(p) for p in pages] == [["ck", "k", "v"]] \
+        + [["state"]] * 6 + [["ck", "k", "v"]]
+    assert pages[1]["state"].shape == (slots, 32, 128, 128)
+    assert pages[0]["k"].shape == (pool, block, 1, 256)
+    assert pages[0]["ck"].shape == (pool, 1, 1, 256)
+    params = place(jax.eval_shape(
+        lambda: minicpm_sala_init(jax.random.key(0), cfg)))
+    n_params = sum(x.size for x in jax.tree.leaves(params)
+                   if x.dtype == jnp.bfloat16)
+    assert round(n_params / 1e5) == 28205                     # 5.64 GB
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+    def fn(params, pages, toks, tables, owned, pos):
+        valid = jnp.arange(s_len)[None, :] < jnp.maximum(pos[:, None], 1)
+        logits, pages, st = minicpm_sala_decode_paged(
+            params, toks, cfg, pages, tables, owned,
+            pos if kind == "decode_tick" else jnp.zeros_like(pos), valid,
+            True, None if kind == "decode_tick" else pos[0])
+        tail = jnp.stack([st[k] for k in SALA_COUNTERS])
+        return (jnp.argmax(logits[:, -1], -1), tail), pages
+
+    t0 = time.monotonic()
+    compiled = jax.jit(fn, donate_argnums=(1,)).lower(
+        params, pages, i32(b, s_len), i32(b, per_seq), i32(b),
+        i32(b)).compile()
+    secs = time.monotonic() - t0
+    text = compiled.as_text()
+    assert "input_output_alias" in text
+    for leaf in (pages[1]["state"], pages[0]["k"], pages[0]["ck"]):
+        assert not pool_leaf_copies(text, leaf)
+    held = re.sub(r"(?ms)^%?fused_computation[^\n]*\{\n.*?^\}\n", "", text)
+    assert "fusion(" in held and len(held) < len(text)
+    for m in re.finditer(r"= (\w+)\[([\d,]+)\]", held):
+        dims = [int(d) for d in m[2].split(",")]
+        size = 1
+        for d in dims:
+            size *= d
+        # [heads, S, S] scores, whole or a kv head's group of them
+        assert size < 16 * 16384 * 16384, m[0]
+    decode = kind == "decode_tick"
+    for name, n in (("lightning_step", 6 * decode),
+                    ("lightning_chunk", 6 * (not decode)),
+                    ("paged_attn", 2 * decode),
+                    ("flash_gqa_fwd", 2 * (not decode))):
+        calls = re.findall(r"%%%s(?:\.\d+)? = [^\n]*custom-call" % name, text)
+        assert len(calls) == n, (name, len(calls))
+    for scope in ("attn/gate", "lightning/out_norm", "sparse/compress",
+                  "sparse/select", "sparse_attn",
+                  "lightning/step" if decode else "lightning/chunk") \
+            + (() if decode else ("dense_attn",)):
+        assert re.search(r'op_name="[^"]*/%s/' % scope, text), scope
+    mem = compiled.memory_analysis()
+    live = mem.argument_size_in_bytes + mem.temp_size_in_bytes \
+        + mem.output_size_in_bytes - mem.alias_size_in_bytes
+    print(f"[minicpm_sala {kind}] compiled in {secs:.0f} s: arguments "
+          f"{mem.argument_size_in_bytes / 1e9:.2f} GB, temporaries "
+          f"{mem.temp_size_in_bytes / 1e9:.2f} GB, aliased "
+          f"{mem.alias_size_in_bytes / 1e9:.2f} GB, live {live / 1e9:.2f} GB")
+    assert 9.1e9 < mem.argument_size_in_bytes < 9.4e9
+    assert mem.alias_size_in_bytes > 3.5e9            # state and pool
+    assert live < 15.75 * 2 ** 30 - 0.5e9, live               # the chip's HBM
+    if decode:
+        # a copy of the state leaves would be 0.81 GB of temporaries, of a
+        # layer's keys or values 0.67 GB
+        assert mem.temp_size_in_bytes < 0.4e9, mem.temp_size_in_bytes
+    else:
+        assert not re.search(r"f32\[1,%d,73448\]" % s_len, text)
+        assert mem.temp_size_in_bytes < 5.0e9
+        assert '"estimated_cycles":"9223372036854775807"' not in text
