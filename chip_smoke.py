@@ -217,6 +217,54 @@ def phase_kernels() -> None:
             f"{bad_p} params / {bad_m} momenta differ from ops/lion_math")
         del p_k, m_k, p_r, m_r, got, ref, u, gm, g32, m32, m
 
+    # the leaf-shaped entries against the flat ones at wte's shape, in the
+    # windows leaf_layout cuts under four buckets (a later first block, the
+    # ragged end of 50257 rows): the same arithmetic read where the leaf
+    # lies, so ballots, parameters and momenta bit for bit
+    from distributed_lion_tpu.ops.codec import bucket_bounds
+
+    R, C = 50257, 768
+    pieces = pallas_lion.leaf_layout(
+        [(R, C)], bucket_bounds(R * C, 4, world, "packed_a2a")).pieces
+    check(len(pieces) > 4 and pieces[-1].r1 == R,
+          f"leaf_layout cut wte into {pieces}")
+    m = jax.random.normal(km, (R * C,), jnp.float32) * 0.5
+    pw, gw, tw = p[:R * C], g[:R * C], total[:R * C]
+    flat_b = jax.jit(lambda g, m: pallas_lion.fused_ballots(g, m, b1))(gw, m)
+    flat_p, flat_m = jax.jit(lambda p, g, m, t: pallas_lion.fused_apply(
+        p, g, m, t, lr, wd, b2))(pw, gw, m, tw)
+
+    def where_it_lies(p2, g2, m2, t2):
+        tiles = lambda x, pc: x[pc.r0:pc.r1].reshape(  # noqa: E731
+            pc.r1 - pc.r0, C // 128, 128).transpose(1, 0, 2)
+        ballots = [pallas_lion.leaf_ballots(
+            g2, m2, b1, rows=(pc.r0, pc.r1), block=pc.block) for pc in pieces]
+        for pc in pieces:
+            p2, m2 = pallas_lion.leaf_apply(
+                p2, g2, m2, tiles(t2, pc).astype(jnp.int8), lr, wd, b2,
+                rows=(pc.r0, pc.r1), block=pc.block)
+        return jnp.concatenate([b.transpose(1, 0, 2).reshape(-1)
+                                for b in ballots]), p2, m2
+
+    leaf_k = jax.jit(where_it_lies, donate_argnums=(0, 2))
+    args = (pw.reshape(R, C), gw.reshape(R, C), m.reshape(R, C),
+            tw.reshape(R, C))
+    leaf_k = leaf_k.lower(*args).compile()
+    text = leaf_k.as_text()
+    check(text.count("tpu_custom_call") == 2 * len(pieces)
+          and not re.search(r"= f32\[50257,768\]\S* copy\(", text),
+          "the leaf-shaped kernels do not take wte where it lies")
+    leaf_b, leaf_p, leaf_m = leaf_k(*args)
+    for name, got, want in (("ballots", leaf_b, flat_b),
+                            ("params", leaf_p.reshape(-1), flat_p),
+                            ("momenta", leaf_m.reshape(-1), flat_m)):
+        check(bool(jnp.all(got == want)),
+              f"leaf-shaped {name} differ from the flat kernels'")
+    log(f"  leaf_ballots / leaf_apply [{R}, {C}] in {len(pieces)} windows: "
+        f"{2 * len(pieces)} Mosaic calls in place, equal to the flat kernels "
+        f"bit for bit")
+    del m, pw, gw, tw, flat_b, flat_p, flat_m, leaf_b, leaf_p, leaf_m, args
+
     ballots = jnp.where(g > 0, 1, -1).astype(jnp.int8)
     stats_k = jax.jit(lambda b, t: pallas_lion.bucket_vote_stats(
         b, t, world, telemetry.NBINS))

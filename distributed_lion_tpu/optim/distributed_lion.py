@@ -82,28 +82,6 @@ def _split_votes(flat, like_tree):
     return jax.tree.unflatten(treedef, out)
 
 
-def _bucket_windows(bounds, sizes):
-    """Static window decomposition of the persistent flat-offset layout.
-
-    ``bounds`` are contiguous flat-coordinate buckets (codec.bucket_bounds);
-    ``sizes`` the leaf sizes in ``jax.tree.leaves`` order. Returns, per
-    bucket, the ``(leaf_idx, leaf_start, length, bucket_offset)`` windows
-    tiling it — all Python ints at trace time, so the bucket loop unrolls
-    into a fixed dataflow graph with no dynamic indexing."""
-    out = []
-    leaf, loff = 0, 0  # running cursor over the flat coordinate space
-    for _, size in bounds:
-        ws, done = [], 0
-        while done < size:
-            while sizes[leaf] == loff:  # also skips zero-size leaves
-                leaf, loff = leaf + 1, 0
-            take = min(sizes[leaf] - loff, size - done)
-            ws.append((leaf, loff, take, done))
-            done, loff = done + take, loff + take
-        out.append(ws)
-    return out
-
-
 def _guard_ballot_len(n: int, vote_every: int) -> int:
     """uint8 bytes of the guard's previous-ballot state: the elected-cache
     per-slot layout under lazy refresh (so the refreshed slot's bytes line
@@ -367,22 +345,31 @@ def distributed_lion(
         }
 
     def _step_pallas(params, grads, state: LionState, guard_nf=None):
-        """Fused-kernel fast path: per-window VMEM kernels + the bucketed,
-        software-pipelined vote wire. ``guard_nf`` is the pre-sanitize
-        nonfinite count ``step`` measured (the guard's NaN signal must see
-        the raw gradients; enforce mode zeroes them before this path).
+        """Fused-kernel fast path: VMEM kernels over every leaf where it
+        lies + the bucketed, software-pipelined vote wire. ``guard_nf`` is
+        the pre-sanitize nonfinite count ``step`` measured (the guard's NaN
+        signal must see the raw gradients; enforce mode zeroes them before
+        this path).
 
-        The pytree is addressed through a persistent flat-offset layout —
-        leaf offsets are Python ints fixed at trace time — and the kernels
-        slice shared per-leaf flat views (``reshape(-1)``), so the step no
-        longer materializes full flat copies of params/grads/momentum via a
-        per-step triple ``jnp.concatenate`` (three full HBM round-trips at
-        f32 width on the old path). The only cross-leaf buffers built are
-        the per-bucket int8 ballot chunks — the wire payload itself.
+        Between the gradient and the new parameters no float32 array of a
+        leaf's size is relaid, sliced, padded, joined or reshaped: a leaf
+        of whole 128-lane rows is handed to the kernels as it is
+        (``pallas_lion.leaf_ballots`` / ``leaf_apply``, a window of whole
+        rows a call, ``p'`` and ``m'`` written into their own operands) and
+        the rest — biases, LayerNorm, LoRA factors: what
+        ``pallas_lion.takes_leaf_in_place`` turns down by shape — is
+        concatenated into ONE small flat vector for one ballot and one
+        apply call. What crosses between the kernels is int8 and bits: the
+        pieces' ballot tiles joined into a bucket's vector, in an order the
+        step owns (``pallas_lion.leaf_layout``; the bucket SIZES are
+        ``codec.bucket_bounds``', so the wire's bytes are what they were),
+        and the verdict cut back out of it at one byte a coordinate.
 
         Pipeline order: compute + send bucket k's ballots, then run bucket
         k−1's fused apply while k is on the wire; XLA's async collectives
-        turn that dataflow into interconnect/VMEM overlap. ``grads`` arrive
+        turn that dataflow into interconnect/VMEM overlap. A piece is
+        applied with the last bucket that elects a part of it (the one
+        block a bucket boundary falls in waits for two). ``grads`` arrive
         already cast to the momentum dtype (hoisted once in ``step``).
         """
         from distributed_lion_tpu.ops import pallas_lion
@@ -391,9 +378,6 @@ def distributed_lion(
         p_leaves, treedef = jax.tree.flatten(params)
         m_leaves = treedef.flatten_up_to(state.exp_avg)
         g_leaves = treedef.flatten_up_to(grads)
-        p_f = [p.reshape(-1) for p in p_leaves]
-        g_f = [g.reshape(-1) for g in g_leaves]
-        m_f = [m.reshape(-1) for m in m_leaves]
         sizes = [p.size for p in p_leaves]
         n = sum(sizes)
         w = collectives.axis_size(axis_name)
@@ -413,77 +397,143 @@ def distributed_lion(
                     jnp.zeros((), jnp.float32), 0),)
             return out
         alive = state.health if enforce else None
-        windows = _bucket_windows(bounds, sizes)
-        pieces: list[list] = [[] for _ in sizes]  # per-leaf, in flat order
+        layout = pallas_lion.leaf_layout([p.shape for p in p_leaves], bounds,
+                                         row_block)
 
-        def _bucket_ballots(k):
-            parts = [
-                pallas_lion.fused_ballots_window(
-                    g_f[li], m_f[li], b1, start=ls, length=ln,
-                    interpret=interpret, row_block=row_block)
-                for li, ls, ln, _ in windows[k]
-            ]
+        def _join(parts):
             return parts[0] if len(parts) == 1 else jnp.concatenate(parts)
 
-        def _bucket_apply(k, total):
-            for li, ls, ln, boff in windows[k]:
-                pieces[li].append(pallas_lion.fused_apply_window(
-                    p_f[li], g_f[li], m_f[li], total, lr, weight_decay, b2,
-                    start=ls, length=ln, total_offset=boff,
-                    interpret=interpret, row_block=row_block))
+        # the newest (p, m) of every leaf: an apply call writes its window
+        # into its operands, so whoever reads the leaf next reads its result
+        # (the rows a call leaves alone are the same bits in both)
+        new_p = {i: pallas_lion.rows_view(p_leaves[i]) for i in layout.in_place}
+        new_m = {i: pallas_lion.rows_view(m_leaves[i]) for i in layout.in_place}
+        g_rows = {i: pallas_lion.rows_view(g_leaves[i]) for i in layout.in_place}
+        pool = [_join([ls[i].reshape(-1) for i in layout.pooled])
+                if layout.pooled else None
+                for ls in (p_leaves, g_leaves, m_leaves)]
+        ballots_of: dict = {}  # piece -> its int8 ballots, in its own order
 
-        totals = []
+        def _piece_ballots(pi):
+            if pi not in ballots_of:
+                pc = layout.pieces[pi]
+                if pc.leaf < 0:
+                    ballots_of[pi] = pallas_lion.fused_ballots(
+                        pool[1], pool[2], b1, interpret=interpret,
+                        row_block=row_block)
+                else:
+                    ballots_of[pi] = pallas_lion.leaf_ballots(
+                        g_rows[pc.leaf], new_m[pc.leaf], b1,
+                        rows=(pc.r0, pc.r1), block=pc.block,
+                        interpret=interpret).reshape(-1)
+            return ballots_of[pi]
+
+        def _cut(vec, lo, hi):
+            return vec if (lo, hi) == (0, vec.shape[0]) else lax.slice(
+                vec, (lo,), (hi,))
+
+        def _bucket_ballots(k):
+            return _join([_cut(_piece_ballots(pi), lo, hi)
+                          for pi, lo, hi in layout.buckets[k]])
+
+        verdict_of: dict = {}  # piece -> its int8 election, same order
+
+        def _bucket_apply(k):
+            for pi in layout.apply_at[k]:
+                pc = layout.pieces[pi]
+                verdict = verdict_of[pi] = _join(
+                    [_cut(verdicts[b], off, off + ln)
+                     for b, off, ln in layout.verdicts[pi]])
+                if pc.leaf < 0:
+                    pool[0], pool[2] = pallas_lion.fused_apply(
+                        pool[0], pool[1], pool[2], verdict, lr,
+                        weight_decay, b2, interpret=interpret,
+                        row_block=row_block)
+                    continue
+                li = pc.leaf
+                new_p[li], new_m[li] = pallas_lion.leaf_apply(
+                    new_p[li], g_rows[li], new_m[li],
+                    verdict.reshape(-1, pc.r1 - pc.r0, pallas_lion.LANES),
+                    lr, weight_decay, b2, rows=(pc.r0, pc.r1),
+                    block=pc.block, interpret=interpret)
+
+        verdicts = []  # per bucket: the election at one byte a coordinate
         # telemetry rides the bucket pipeline: each bucket's stats kernel
         # (margin bincount + local-ballot disagreement, pallas_lion.
-        # bucket_vote_stats) consumes ballots/totals already resident in
-        # VMEM, and packing the per-bucket elections concatenates to the
-        # full packed vector because bucket boundaries are byte-aligned.
-        # Purely observational — the vote/apply dataflow is untouched.
+        # bucket_vote_stats) consumes the bucket's ballots and totals, both
+        # sums over coordinates that no order can move. Purely
+        # observational — the vote/apply dataflow is untouched.
         hist_acc = jnp.zeros((_vt.NBINS,), jnp.int32) if telemetry else None
         dis_acc = jnp.zeros((), jnp.int32) if telemetry else None
-        packed_parts: list = []
-        # guard accumulators: the packed LOCAL ballot (flip detection) and
-        # the local-vs-elected disagreement count, folded per bucket from
-        # arrays the pipeline already has in registers/VMEM. The mask is
-        # applied to the bucket ballot BEFORE the collective (inside
-        # vote_total — a quarantined worker's int8 ballots become zeros on
-        # the wire), never to the guard's own observation of them.
-        guard_packed: list = []
+        # guard accumulator: the local-vs-elected disagreement count, folded
+        # per bucket. The mask is applied to the bucket ballot BEFORE the
+        # collective (inside vote_total — a quarantined worker's int8
+        # ballots become zeros on the wire), never to the guard's own
+        # observation of them.
         guard_dis = jnp.zeros((), jnp.int32) if guard_on else None
         for k in range(len(bounds)):
             ballots = _bucket_ballots(k)
-            totals.append(collectives.vote_total(
-                ballots > 0, axis_name, wire, alive, state.count))
+            total = collectives.vote_total(
+                ballots > 0, axis_name, wire, alive, state.count)
+            # only the sign is applied: int8 tallies go on as they are,
+            # wider ones (and the packed wires' ±1 proxies) as 0 / 1
+            verdicts.append(total if total.dtype == jnp.int8
+                            else (total > 0).astype(jnp.int8))
             if telemetry:
                 h, d = pallas_lion.bucket_vote_stats(
-                    ballots, totals[k], w, _vt.NBINS, interpret=interpret,
+                    ballots, total, w, _vt.NBINS, interpret=interpret,
                     row_block=row_block)
                 hist_acc, dis_acc = hist_acc + h, dis_acc + d
-                packed_parts.append(pack_signs(totals[k] > 0))
             if guard_on:
-                guard_packed.append(pack_signs(ballots > 0))
                 guard_dis = guard_dis + jnp.sum(
-                    ((ballots > 0) != (totals[k] > 0)).astype(jnp.int32))
+                    ((ballots > 0) != (total > 0)).astype(jnp.int32))
             if k:  # apply k−1 while bucket k's collective is in flight
-                _bucket_apply(k - 1, totals[k - 1])
-        _bucket_apply(len(bounds) - 1, totals[-1])
+                _bucket_apply(k - 1)
+        _bucket_apply(len(bounds) - 1)
 
-        def _join(parts, leaf, idx):
-            if not parts:  # zero-size leaf: nothing was windowed onto it
-                return jnp.zeros(leaf.shape, leaf.dtype)
-            flat = (parts[0][idx] if len(parts) == 1
-                    else jnp.concatenate([p[idx] for p in parts]))
-            return flat.reshape(leaf.shape)
+        pool_at, off = {}, 0  # pooled leaf -> its span of the pool
+        for i in layout.pooled:
+            pool_at[i], off = (off, off + sizes[i]), off + sizes[i]
+        pool_piece = len(layout.pieces) - 1  # the pool, where there is one
 
-        new_p = [_join(ws, p, 0) for ws, p in zip(pieces, p_leaves)]
-        new_m = [_join(ws, m, 1) for ws, m in zip(pieces, m_leaves)]
+        def _from_pool(vec, i, shape):
+            return _cut(vec, *pool_at[i]).reshape(shape)
+
+        def _leaf_out(i, leaf, rows_of, pooled_vec):
+            if i in rows_of:
+                return pallas_lion.from_rows_view(rows_of[i], leaf.shape)
+            if i in pool_at:
+                return _from_pool(pooled_vec, i, leaf.shape)
+            return leaf  # zero-size leaf: nothing was voted onto it
+
+        out_p = [_leaf_out(i, p, new_p, pool[0])
+                 for i, p in enumerate(p_leaves)]
+        out_m = [_leaf_out(i, m, new_m, pool[2])
+                 for i, m in enumerate(m_leaves)]
+
+        def _flat_order(of_piece):
+            """Per-piece int8 vectors, in the step's order -> one [n]
+            vector in flat coordinate order: what checkpoints
+            (``prev_ballot``) and the host (the frame's ``elected``) read.
+            A transpose of bytes, paid by guard and telemetry runs alone."""
+            per_leaf: dict = {i: [] for i in layout.in_place}
+            for pi, pc in enumerate(layout.pieces):
+                if pc.leaf >= 0:
+                    tiles = of_piece[pi].reshape(
+                        -1, pc.r1 - pc.r0, pallas_lion.LANES)
+                    per_leaf[pc.leaf].append(tiles.transpose(1, 0, 2).reshape(
+                        -1, p_leaves[pc.leaf].shape[-1]))
+            return _join([
+                pallas_lion.from_rows_view(
+                    _join(per_leaf[i]), p_leaves[i].shape).reshape(-1)
+                if i in per_leaf else
+                _from_pool(of_piece[pool_piece], i, (-1,))
+                for i in range(len(sizes)) if sizes[i]])
+
         new_prev = state.prev_ballot
         gframe = None
         if guard_on:
-            # bucket boundaries are byte-aligned for every wire, so the
-            # per-bucket packed ballots concatenate to the full vector
-            packed_now = (guard_packed[0] if len(guard_packed) == 1
-                          else jnp.concatenate(guard_packed))
+            packed_now = pack_signs(_flat_order(ballots_of) > 0)
             gframe = _guard_frame(
                 w, guard_nf,
                 _ballot_flips(packed_now, state.prev_ballot),
@@ -491,12 +541,12 @@ def distributed_lion(
                 guard_dis.astype(jnp.float32) / n, n)
             new_prev = packed_now
         out = (
-            jax.tree.unflatten(treedef, new_p),
+            jax.tree.unflatten(treedef, out_p),
             # this path is gated to vote_every == 1 and dcn_depth == 0,
             # where the elected-sign cache and the DCN ring are None — but
             # the invariant is "state passes through", not "they may be
             # dropped": a future un-gating must not silently lose either
-            LionState(state.count + 1, jax.tree.unflatten(treedef, new_m),
+            LionState(state.count + 1, jax.tree.unflatten(treedef, out_m),
                       state.rng, state.elected, state.health, new_prev,
                       state.dcn_ring),
         )
@@ -505,8 +555,7 @@ def distributed_lion(
         frame = {
             "margin_hist": (hist_acc if wire_has_tally
                             else jnp.zeros((_vt.NBINS,), jnp.int32)),
-            "elected": (packed_parts[0] if len(packed_parts) == 1
-                        else jnp.concatenate(packed_parts)),
+            "elected": pack_signs(_flat_order(verdict_of) > 0),
             "disagree": dis_acc,
             "voted": jnp.asarray(n, jnp.int32),
             "valid": jnp.asarray(n, jnp.int32),

@@ -43,6 +43,7 @@ from distributed_lion_tpu.models.gpt2 import (
 from distributed_lion_tpu.models.loss import clm_loss_and_metrics
 from distributed_lion_tpu.ops import xent as xent_ops
 from distributed_lion_tpu.ops.codec import (
+    bucket_bounds,
     vote_chunk_elems,
     wire_bytes_per_param,
     wire_codec,
@@ -567,6 +568,27 @@ def resolve_auto_comm(cfg: TrainConfig, mesh, n_params: int,
                                vote_buckets=vb)
 
 
+def _lion_layout_line(cfg: TrainConfig, mesh, params, param_specs) -> str:
+    """The ``[setup] lion:`` line: how the Lion kernels take this tree, from
+    the shapes a worker holds (``ops/pallas_lion.leaf_layout``: leaves read
+    where they lie, leaves pooled through the flat path, kernel calls a
+    step). A fact of the shapes: the step runs those calls where
+    ``optim.distributed_lion`` takes its Pallas path (a TPU, every
+    coordinate voted every step); the XLA path votes in flat order and runs
+    none."""
+    from distributed_lion_tpu.ops import pallas_lion
+
+    leaves, treedef = jax.tree.flatten(params)
+    specs = ([P()] * len(leaves) if param_specs is None
+             else treedef.flatten_up_to(param_specs))
+    shapes = [NamedSharding(mesh, s).shard_shape(p.shape)
+              for p, s in zip(leaves, specs)]
+    bounds = bucket_bounds(sum(math.prod(s) for s in shapes),
+                           cfg.vote_buckets or 1, data_axis_size(mesh),
+                           cfg.wire)
+    return pallas_lion.leaf_layout(shapes, bounds).line()
+
+
 def make_optimizer(cfg: TrainConfig) -> FunctionalOptimizer:
     """The reference's optimizer wiring (run_clm.py:580-585): ``--lion`` →
     Lion(lr, wd) else AdamW(wd=0.1 hardcoded); both under a cosine-warmup
@@ -779,6 +801,7 @@ class Trainer:
         if cfg.lion:
             emit(f"[setup] vote: {cfg.wire} x{cfg.vote_buckets} buckets, "
                  f"{wire_codec(cfg.wire)}")
+            emit(_lion_layout_line(cfg, mesh, params, param_specs))
         if cfg.zero1:
             shape = dict(mesh.shape)
             for ax in (TENSOR_AXIS, SEQ_AXIS):

@@ -57,8 +57,13 @@ MEMORY_SHARE = 0.72
 # world > 1. Measured, not derived: cell 4's peak under `full`, 5.45 GB
 # (ledger, PR 30), less its 1.5 GB of live state and the 1.39 GB of
 # temporaries the same step compiles to without a vote (described v5e,
-# PR 31), over 124.4 M parameters.
-VOTE_BYTES_PER_PARAM = 21
+# PR 31), over 124.4 M parameters: 21. Since PR 38 the Lion kernels take
+# every leaf where it lies and the step no longer stages flat float32
+# copies of parameters, gradients and momentum beside the ballots: cell 4's
+# peak fell 5.627 -> 5.447 GB at the same rung (my chip runs, PR 38), 1.45
+# bytes a parameter, so 19.6, held at 20 (what is left: a bucket's int8
+# ballots and verdict, the packed wire's buffers, the unpacked tallies).
+VOTE_BYTES_PER_PARAM = 20
 
 
 def _itemsize(dtype) -> int:

@@ -82,3 +82,20 @@ def toy_problem(world=8, n=40):
 def assert_trees_equal(a, b):
     jax.tree.map(lambda x, y: np.testing.assert_array_equal(
         np.asarray(x), np.asarray(y)), a, b)
+
+
+def leafy_problem(world=8, dtype=jnp.float32):
+    """``(params, stacked_grads)`` whose leaves take both of the Pallas
+    path's routes (``ops/pallas_lion.takes_leaf_in_place``): matrices of
+    whole 128-lane rows at C = 128 / 768 / 2304 (an odd row count, fewer
+    rows than a row block, the fused qkv's 3-D form) beside what the flat
+    path pools (1-D, a last dimension of 7, under a tile, zero-size)."""
+    shapes = {"emb": (209, 768), "fc": (40, 2304), "proj": (96, 128),
+              "qkv": (32, 3, 128), "bias": (130,), "lora": (33, 7),
+              "under_a_tile": (8, 128), "empty": (0,)}
+    keys = jax.random.split(jax.random.key(5), 2 * len(shapes))
+    params = {k: jax.random.normal(keys[2 * i], s).astype(dtype)
+              for i, (k, s) in enumerate(shapes.items())}
+    grads = {k: jax.random.normal(keys[2 * i + 1], (world,) + s).astype(dtype)
+             for i, (k, s) in enumerate(shapes.items())}
+    return params, grads

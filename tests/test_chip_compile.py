@@ -59,6 +59,24 @@ def _named_custom_call(text, name):
                      r'custom_call_target="tpu_custom_call"' % name, text)
 
 
+_LAYOUT_OPS = ("copy", "slice", "pad", "concatenate", "reshape", "transpose")
+
+
+def _big_f32_layout_ops(text, least=2 ** 20):
+    """Instructions of compiled HLO text, in any computation (a fusion's
+    body included), that copy, slice, pad, concatenate, reshape or transpose
+    into a float32 result of ``least`` elements or more: what XLA does to a
+    leaf that a Mosaic call could not take where it lay. (The asynchronous
+    ``copy-start`` / ``slice-start`` pairs are the compiler moving a buffer
+    between memories, not a relayout, and are other opcodes.)"""
+    found = []
+    for m in re.finditer(r"^\s*(?:ROOT )?%?[\w.\-]+ = f32\[([\d,]+)\]\S* ("
+                         + "|".join(_LAYOUT_OPS) + r")\(.*$", text, re.M):
+        if np.prod([int(d) for d in m.group(1).split(",")]) >= least:
+            found.append(m.group(0).strip()[:200])
+    return found
+
+
 def _compile(fn, *args):
     """Compile for the described chip; returns (text, seconds)."""
     t0 = time.monotonic()
@@ -94,28 +112,31 @@ def test_lion_kernel_compiles_at_124m(one_chip, kernel, mom):
 
 
 def test_lion_kernels_compile_on_odd_window(one_chip):
-    """A bucket window whose start and length are not tile multiples."""
+    """The leaf-shaped entries over a window that starts at a later row
+    block and ends, off a block and off a tile, with its 50257-row leaf."""
     from distributed_lion_tpu.ops import pallas_lion
 
-    n, start, length = 38_597_376, 1_000_003, 9_437_187
+    shape, rows, block = (50257, 768), (49664, 50257), 512
 
     def s(dt):
-        return jax.ShapeDtypeStruct((n,), dt, sharding=one_chip)
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
 
     def fn(p, g, m):
-        b = pallas_lion.fused_ballots_window(g, m, 0.9, start=start,
-                                             length=length)
-        tot = b.astype(jnp.int32)
-        h, d = pallas_lion.bucket_vote_stats(b, tot, 1, 8)
-        return pallas_lion.fused_apply_window(
-            p, g, m, tot, 1e-4, 0.1, 0.99, start=start, length=length), h, d
+        b = pallas_lion.leaf_ballots(g, m, 0.9, rows=rows, block=block)
+        h, d = pallas_lion.bucket_vote_stats(b.reshape(-1), b.reshape(-1),
+                                             1, 8)
+        return pallas_lion.leaf_apply(p, g, m, b, 1e-4, 0.1, 0.99,
+                                      rows=rows, block=block), h, d
 
-    text, _ = _compile(fn, s("float32"), s("float32"), s("float32"))
+    text = jax.jit(fn, donate_argnums=(0, 2)).lower(
+        s("float32"), s("float32"), s("float32")).compile().as_text()
     assert text.count("tpu_custom_call") >= 3
     # each Mosaic custom-call's instruction is named after its kernel, which
     # is the name a device trace shows (not ``fn.<n>``)
     for kernel in ("lion_ballot", "lion_stats", "lion_apply"):
         assert _named_custom_call(text, kernel), kernel
+    # the window is written into the leaf itself: no copy of it is made
+    assert not _big_f32_layout_ops(text)
 
 
 def test_sign_codec_compile_time_is_flat_in_n(one_chip):
@@ -1189,6 +1210,71 @@ def test_vote_step_compiles_on_2x2_mesh_with_auto_wire(topo):
     # phase 1 of the packed wire; the compiler is free to rewrite phase 2's
     # all_gather (it becomes dynamic-update-slice + all-reduce on v5e)
     assert "all-to-all" in text
+
+
+def test_vote_step_takes_gpt2_leaves_where_they_lie(topo):
+    """The optimizer's step for the described 2x2 at GPT-2's leaf shapes
+    (``wte`` split by bucket boundaries, the fused qkv's 3-D form, a bias):
+    between the gradient and the new parameters XLA relays, slices, pads,
+    joins or reshapes no float32 array of 2^20 elements, the kernels keep
+    the names ``lion_ms.train`` reads them by, and there are as many Mosaic
+    calls as the trainer's ``[setup] lion:`` line says.
+
+    One relayout is not the step's to remove and is allowed by name: a
+    worker's momentum is stored stacked, ``[W, 50257, 768]``, and the chip
+    keeps a ``[1, R, C]`` shard whose ``R`` is off the sublane tile in a
+    row-linear layout (``{2,0,1:T(1,128)}``), so ``exp_avg['wte']`` (and
+    here the stacked gradient, which a trainer computes in place) is
+    copied into row tiles on the way in and back on the way out."""
+    from distributed_lion_tpu.ops import pallas_lion
+    from distributed_lion_tpu.ops.codec import bucket_bounds
+    from distributed_lion_tpu.optim import distributed_lion, init_global_state
+    from distributed_lion_tpu.optim.sharded import make_sharded_step
+
+    mesh = Mesh(np.array(topo.devices).reshape(4), ("data",))
+    shapes = {"fc": (768, 3072), "fc_b": (3072,), "qkv": (768, 3, 768),
+              "qkv_b": (3, 768), "wte": (50257, 768)}
+    repl, split = NamedSharding(mesh, P()), NamedSharding(mesh, P("data"))
+    params = {k: jax.ShapeDtypeStruct(s, jnp.float32, sharding=repl)
+              for k, s in shapes.items()}
+    grads = {k: jax.ShapeDtypeStruct((4,) + s, jnp.float32, sharding=split)
+             for k, s in shapes.items()}
+    real = pallas_lion.pallas_available
+    pallas_lion.pallas_available = lambda: True
+    try:
+        opt = distributed_lion(learning_rate=1e-4, weight_decay=0.1,
+                               wire="packed_a2a", vote_buckets=4,
+                               kernel="auto")
+    finally:
+        pallas_lion.pallas_available = real
+    state = jax.eval_shape(lambda: init_global_state(
+        opt, {k: jnp.zeros(s, jnp.float32) for k, s in shapes.items()},
+        world=4))
+    state = state._replace(
+        count=jax.ShapeDtypeStruct((), jnp.int32, sharding=repl),
+        exp_avg={k: jax.ShapeDtypeStruct(m.shape, m.dtype, sharding=split)
+                 for k, m in state.exp_avg.items()})
+    # parameters and state donated, as the Trainer's train step donates them
+    text = jax.jit(make_sharded_step(opt, mesh), donate_argnums=(0, 2)).lower(
+        params, grads, state).compile().as_text()
+
+    leaves = [shapes[k] for k in sorted(shapes)]
+    n = sum(int(np.prod(s)) for s in leaves)
+    layout = pallas_lion.leaf_layout(leaves,
+                                     bucket_bounds(n, 4, 4, "packed_a2a"))
+    assert layout.line() == (
+        "[setup] lion: 3 leaves in place (100.0% of coordinates), 2 through "
+        "the flat path, 22 kernel calls a step")
+    # bucket boundaries fall inside wte: a block of it waits for two buckets
+    assert any(len({b for b, _, _ in v}) > 1 for v in layout.verdicts)
+    assert text.count('custom_call_target="tpu_custom_call"') == layout.calls
+    assert _named_custom_call(text, "lion_ballot")
+    assert _named_custom_call(text, "lion_apply")
+    ops = _big_f32_layout_ops(text)
+    stacked = [op for op in ops if " = f32[1,50257,768]{" in op]
+    assert [op for op in ops if op not in stacked] == []
+    # momentum in and out, and this harness's stacked gradient in
+    assert len(stacked) <= 3 and all(" copy(" in op for op in stacked)
 
 
 @pytest.mark.parametrize("kind", ["decode_tick", "prefill_16384"])

@@ -90,7 +90,10 @@ def _train(mesh, cfg, steps=3, seed=4):
 def test_bit_identity_journal_on_vs_off(mesh8, tmp_path, kern, buckets):
     """The acceptance pin: elections/params/losses are BIT-identical with
     the journal on vs off, for vote_buckets {1,4} x XLA/Pallas — the
-    journal records host wall time only and can never move an election."""
+    journal records host wall time only and can never move an election.
+    (The Pallas cases take tiny's two ``[64, 256]`` fc weights where they
+    lie and pool the other 26 leaves: ``[setup] lion: 2 leaves in
+    place``.)"""
     runs = {}
     for on in (False, True):
         cfg = _tiny_cfg(kernel=kern, vote_buckets=buckets, journal=on,

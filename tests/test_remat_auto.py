@@ -272,3 +272,8 @@ def test_an_explicit_policy_is_not_a_resolution(capsys):
     # the other set-up line a Lion trainer prints: the wire auto resolved
     # to and which of ops/codec's two bit orders its bytes are in
     assert "[setup] vote: packed_a2a x1 buckets, planar codec" in out
+    # and how the Lion kernels take this tree (ops/pallas_lion.leaf_layout;
+    # GPT-2 124M's and a LoRA tree's lines: tests/test_pallas_lion.py): at
+    # d_model 64 only the two [64, 256] fc weights have whole 128-lane rows
+    assert ("[setup] lion: 2 leaves in place (26.3% of coordinates), 26 "
+            "through the flat path, 6 kernel calls a step") in out

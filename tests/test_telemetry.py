@@ -33,6 +33,7 @@ from jax.sharding import PartitionSpec as P
 
 from _sharded import (
     assert_trees_equal,
+    leafy_problem,
     lion_state_specs,
     run_sharded,
     sharded,
@@ -57,13 +58,14 @@ N = 43  # ragged on purpose: with vote_every=4 the last rotation slot is
 
 
 def _run(mesh, telemetry_on, wire="sign_psum", buckets=1, ve=1, stoch=False,
-         kern="xla", steps=5):
+         kern="xla", steps=5, problem=toy_problem, row_block=0):
     """Drive opt.step under shard_map with the trainer's fold wiring."""
-    params, grads = toy_problem()
+    params, grads = problem()
+    N = sum(p.size for p in jax.tree.leaves(params))
     opt = distributed_lion(
         0.01, weight_decay=0.01, wire=wire, vote_buckets=buckets,
         vote_every=ve, max_grad_norm=1.0 if stoch else None, kernel=kern,
-        telemetry=telemetry_on)
+        row_block=row_block, telemetry=telemetry_on)
     rng = jax.random.key(7) if stoch else None
     state = init_global_state(opt, params, 8, rng=rng)
     vh = telemetry.init_vote_health(N, ve) if telemetry_on else {}
@@ -164,6 +166,20 @@ def test_pallas_telemetry_matches_xla_and_bucket_invariant(mesh8):
     p_off, _, _ = _run(mesh8, False, kern="pallas", buckets=3)
     jax.tree.map(lambda a, b: np.testing.assert_allclose(
         np.asarray(a), np.asarray(b), atol=1e-5), p_off, r3[0])
+
+
+@pytest.mark.parametrize("wire", ["sign_psum", "packed_a2a"])
+def test_leaf_shaped_telemetry_matches_xla(mesh8, wire):
+    """Leaves taken where they lie vote in an order of the step's own; what
+    the host reads does not show it: the accumulator (the packed ``elected``
+    against the step before, the margins, the disagreements) is BITWISE the
+    XLA path's after two steps, with a leaf split across buckets."""
+    runs = [_run(mesh8, True, wire=wire, kern=kern, buckets=buckets, steps=2,
+                 problem=leafy_problem, row_block=32)
+            for kern, buckets in (("xla", 1), ("pallas", 4))]
+    assert_trees_equal(runs[0][2], runs[1][2])
+    assert_trees_equal(runs[0][0], runs[1][0])
+    assert_trees_equal(runs[0][1].exp_avg, runs[1][1].exp_avg)
 
 
 def test_bucket_vote_stats_kernel_matches_reference():

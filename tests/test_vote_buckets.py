@@ -10,9 +10,10 @@ bytes move, never what is elected or how many bytes ship —
   CPU mesh;
 - the summed per-bucket byte accounting equals the unbucketed totals exactly
   (and stays zero at world=1, commit 3d77603);
-- the Pallas window path (offset-window kernels over shared per-leaf flat
-  buffers) matches the XLA path and preserves the elected-sign cache through
-  ``_step_pallas`` (the state-pass-through invariant).
+- the Pallas path (kernels over every leaf where it lies, the rest pooled:
+  its layout's own tests are in tests/test_pallas_lion.py) matches the XLA
+  path and preserves the elected-sign cache through ``_step_pallas`` (the
+  state-pass-through invariant).
 """
 
 import jax
@@ -33,7 +34,6 @@ from distributed_lion_tpu.ops.codec import (
     wire_bytes_per_param,
 )
 from distributed_lion_tpu.optim import distributed_lion, init_global_state
-from distributed_lion_tpu.optim.distributed_lion import _bucket_windows
 from distributed_lion_tpu.optim.lion import LionState
 from distributed_lion_tpu.parallel import collectives
 from distributed_lion_tpu.parallel.mesh import make_mesh
@@ -56,25 +56,6 @@ def test_bucket_bounds_tile_exactly(wire, n, buckets):
             assert size % align == 0
         off += size
     assert off == n
-
-
-def test_bucket_windows_tile_leaves():
-    """The optimizer's static window decomposition must tile every bucket
-    with per-leaf windows in flat order, skipping zero-size leaves."""
-    sizes = [5, 0, 11, 3]
-    bounds = [(0, 8), (8, 8), (16, 3)]
-    windows = _bucket_windows(bounds, sizes)
-    flat = 0
-    for (start, size), ws in zip(bounds, windows):
-        boff = 0
-        for leaf, loff, take, w_boff in ws:
-            assert sizes[leaf] > 0 and take > 0
-            assert w_boff == boff
-            assert sum(sizes[:leaf]) + loff == flat
-            flat += take
-            boff += take
-        assert boff == size
-    assert flat == sum(sizes)
 
 
 # ----------------------------------------------------------- byte accounting
@@ -191,11 +172,10 @@ def test_bucketed_trajectory_bit_identical(mesh8, wire, stochastic,
 
 @pytest.mark.parametrize("wire", ["sign_psum", "packed_a2a"])
 def test_pallas_bucketed_equals_xla_monolithic(mesh8, wire):
-    """The Pallas window path (offset-window kernels, bucket pipeline)
-    must match the XLA path's monolithic vote bit-for-bit — the cross-check
-    that the persistent flat-offset layout slices exactly the coordinates
-    the flat concatenate used to."""
-    params, grads = toy_problem(n=300)  # spans several (8,128) windows
+    """The Pallas path's bucket pipeline must match the XLA path's
+    monolithic vote bit-for-bit, here on leaves that all take the flat
+    path (one pooled vector cut by every bucket boundary)."""
+    params, grads = toy_problem(n=300)
     results = []
     for kern, buckets in (("pallas", 4), ("pallas", 1), ("xla", 1)):
         opt = distributed_lion(learning_rate=0.02, weight_decay=0.05,

@@ -35,6 +35,7 @@ from jax.sharding import PartitionSpec as P
 
 from _sharded import (
     assert_trees_equal,
+    leafy_problem,
     run_sharded,
     sharded,
     sharded_opt_step,
@@ -181,7 +182,7 @@ def test_guard_all_healthy_bit_identical(mesh8, wire, stochastic, buckets):
 @pytest.mark.parametrize("buckets", [1, 4])
 @pytest.mark.parametrize("wire", ["sign_psum", "packed_a2a"])
 def test_guard_all_healthy_bit_identical_pallas(mesh8, wire, buckets):
-    """Same contract on the Pallas window path (the mask zeroes the bucket
+    """Same contract on the Pallas path (the mask zeroes the bucket
     ballot before it reaches the wire; kernels untouched)."""
     params, grads = toy_problem(n=300)
     runs = {}
@@ -192,6 +193,26 @@ def test_guard_all_healthy_bit_identical_pallas(mesh8, wire, buckets):
         runs[guard] = _run_steps(opt, params, lambda t: grads, 3, mesh8, 8)
     assert_trees_equal(runs["off"][0], runs["enforce"][0])
     assert_trees_equal(runs["off"][1].exp_avg, runs["enforce"][1].exp_avg)
+
+
+@pytest.mark.parametrize("buckets", [1, 4])
+@pytest.mark.parametrize("wire", ["sign_psum", "packed_a2a"])
+def test_guard_leaf_shaped_matches_xla(mesh8, wire, buckets):
+    """Enforce over leaves taken where they lie (and pooled ones beside
+    them): parameters, momenta, the checkpointed ``prev_ballot`` (flat
+    coordinate order, whatever order the step votes in) and the guard
+    frame are the XLA path's bit for bit."""
+    params, grads = leafy_problem()
+    runs = []
+    for kern, b, rb in (("xla", 1, 0), ("pallas", buckets, 32)):
+        opt = distributed_lion(learning_rate=0.02, weight_decay=0.05,
+                               wire=wire, kernel=kern, vote_buckets=b,
+                               row_block=rb, guard="enforce")
+        runs.append(_run_steps(opt, params, lambda t: grads, 2, mesh8, 8))
+    assert_trees_equal(runs[0][0], runs[1][0])
+    assert_trees_equal(runs[0][1].exp_avg, runs[1][1].exp_avg)
+    assert_trees_equal(runs[0][1].prev_ballot, runs[1][1].prev_ballot)
+    assert_trees_equal(runs[0][2], runs[1][2])
 
 
 def test_guard_lazy_vote_every_bit_identical(mesh8):
