@@ -1446,6 +1446,96 @@ def lightning_at_size() -> None:
         f"{rows * 2 * H * d * d * 4 / ms / 1e6:.0f} GB/s of state")
 
 
+def phase_serve_mhc() -> None:
+    """The hyper-connection mix at the published shape (4 streams of 3,584,
+    bfloat16): the kernels ``mhc_pre`` / ``mhc_post`` against ``ops/mhc``'s
+    ``jax.numpy`` forms over a 4,096-row prefill and a 64-row decode tick
+    with rows without a token among them, the mixing matrices' distance
+    from doubly stochastic (under 0.1 with the seeded weights' dominant
+    diagonal, under 1e-3 where the matrix mixes), the stream rewritten in
+    place, all four calls timed against the bytes the mix needs."""
+    import jax
+    import jax.numpy as jnp
+
+    from distributed_lion_tpu.ops import mhc, pallas_mhc
+
+    cfg = mhc.MixConfig()
+    n, d = cfg.n, 3584
+    f32, bf = jnp.float32, jnp.bfloat16
+    ks = jax.random.split(jax.random.key(391), 6)
+    a = jnp.ones((3,), f32)
+
+    def mix(key, std, diag):
+        k1, k2 = jax.random.split(key)
+        phi = jax.random.normal(k1, (n * d, cfg.width)) * std
+        b = jax.random.normal(k2, (cfg.width,)) * 0.5
+        return (mhc.pack_phi(phi, cfg),
+                b.at[2 * n:].add(diag * jnp.eye(n).reshape(-1)))
+
+    packed, b = mix(ks[0], 0.02, 4.0)         # the seeded weights' law
+    flat_phi, flat_b = mix(ks[1], 0.002, 0.0)  # no entry dominates
+    pre_x = jax.jit(lambda X, p, b: mhc.mhc_pre_xla(X, p, a, b, cfg))
+    post_x = jax.jit(lambda X, y, c, v: mhc.mhc_post_xla(X, y, c, v, cfg))
+    pre_k = jax.jit(lambda X, p, b: pallas_mhc.mhc_pre(X, p, a, b, cfg))
+    post_k = jax.jit(lambda X, y, c, v: pallas_mhc.mhc_post(X, y, c, v, cfg),
+                     donate_argnums=(0,))
+    for rows in (4096, 64):
+        X = jax.random.normal(ks[2], (rows, n * d)).astype(bf)
+        y = jax.random.normal(ks[3], (rows, d)).astype(bf)
+        valid = jnp.arange(rows) % 7 != 3
+        check(pallas_mhc.kernel_takes(X.shape, X.dtype, cfg),
+              "the kernels refuse the published shape")
+        u0, c0 = pre_x(X, packed, b)
+        u1, c1 = pre_k(X, packed, b)
+        err_c = float(jnp.abs(c0 - c1).max())
+        err_u = float(jnp.abs(u0.astype(f32) - u1.astype(f32)).max())
+        top_u = float(jnp.abs(u0.astype(f32)).max())
+        check(bool(jnp.isfinite(c1).all()), "mhc_pre coefficients not finite")
+        check(err_c <= 1e-4, f"mhc_pre coefficients vs ops/mhc: {err_c}")
+        check(err_u <= top_u * 2.0 ** -6, f"mhc_pre u vs ops/mhc: {err_u}")
+        seeded = float(mhc.mhc_defect(c1, valid, cfg))
+        mixed = float(mhc.mhc_defect(pre_k(X, flat_phi, flat_b)[1],
+                                     valid, cfg))
+        check(seeded <= 0.1, f"Hres {seeded} from doubly stochastic")
+        check(mixed <= 1e-3, f"a mixing Hres {mixed} from doubly stochastic")
+        o0 = post_x(X, y, c1, valid)
+        o1 = post_k(X + 0, y, c1, valid)
+        err_o = float(jnp.abs(o0.astype(f32) - o1.astype(f32)).max())
+        top_o = float(jnp.abs(o0.astype(f32)).max())
+        check(err_o <= top_o * 2.0 ** -6, f"mhc_post vs ops/mhc: {err_o}")
+        check(bool((o1[~valid] == X[~valid]).all()),
+              "mhc_post wrote a row without a token")
+        text = post_k.lower(X, y, c1, valid).compile().as_text()
+        check("input_output_alias" in text and not re.search(
+            r"= bf16\[%d,%d\]\S* copy\(" % (rows, n * d), text),
+            "mhc_post copies the stream")
+        times = {}
+        for name, fn in (("pre kernel", lambda: pre_k(X, packed, b)[0]),
+                         ("pre xla", lambda: pre_x(X, packed, b)[0])):
+            jax.block_until_ready(fn())
+            t0, reps = time.time(), 20
+            for _ in range(reps):
+                out = fn()
+            jax.block_until_ready(out)
+            times[name] = 1e3 * (time.time() - t0) / reps
+        for name, fn in (("post kernel", post_k), ("post xla", post_x)):
+            S = jax.block_until_ready(fn(X + 0, y, c1, valid))
+            t0, reps = time.time(), 20
+            for _ in range(reps):
+                S = fn(S, y, c1, valid)
+            jax.block_until_ready(S)
+            times[name] = 1e3 * (time.time() - t0) / reps
+        need_pre, need_post = rows * (n + 1) * d * 2, rows * (2 * n + 1) * d * 2
+        log(f"  mhc at [{rows}, {n} x {d}]: coefficients to {err_c:.1e}, u "
+            f"to {err_u:.3f} of {top_u:.1f}, stream to {err_o:.3f} of "
+            f"{top_o:.1f}; Hres {seeded:.4f} from doubly stochastic seeded, "
+            f"{mixed:.1e} mixing; pre {times['pre kernel']:.3f} ms = "
+            f"{need_pre / times['pre kernel'] / 1e6:.0f} GB/s (xla "
+            f"{times['pre xla']:.3f}), post {times['post kernel']:.3f} ms = "
+            f"{need_post / times['post kernel'] / 1e6:.0f} GB/s (xla "
+            f"{times['post xla']:.3f})")
+
+
 def _replica_check(tree, what: str) -> int:
     """Every leaf fully replicated over distinct devices and its replicas
     bit-identical (exact). Returns the device count seen."""
@@ -1649,7 +1739,7 @@ def main() -> int:
     ap.add_argument("--only", default="",
                     help="run this one phase (kernels, train, serve, "
                          "serve_latent, serve_window, serve_state, "
-                         "serve_sparse, multichip) and no "
+                         "serve_sparse, serve_mhc, multichip) and no "
                          "other")
     args = ap.parse_args()
 
@@ -1682,7 +1772,8 @@ def main() -> int:
                ("serve_latent", phase_serve_latent),
                ("serve_window", phase_serve_window),
                ("serve_state", phase_serve_state),
-               ("serve_sparse", phase_serve_sparse)]
+               ("serve_sparse", phase_serve_sparse),
+               ("serve_mhc", phase_serve_mhc)]
               if args.chips == 1 else
               [("multichip", lambda: phase_multichip(work))])
     if args.only:

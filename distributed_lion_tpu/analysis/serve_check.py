@@ -397,6 +397,31 @@ def check_cell(cell: Dict[str, Any]) -> dict:
     return report
 
 
+# ------------------------------------------------------ engine counters
+# ``mhc_res_defect_max`` (models/xing: the largest |rowsum - 1| or
+# |colsum - 1| of a hyper-connection mixing matrix over a dispatch, x 1e6).
+# 20 Sinkhorn steps leave up to 0.064 on a matrix with a dominant diagonal
+# (the seeded weights' 4 I: the iteration's rate is the square of the
+# limit's second singular value, some 0.9), 10 steps leave 0.15 and one
+# step 1.8 (400,000 matrices drawn as the seeded weights draw them): 0.1
+# stands between the configured iteration and one cut short or missing a
+# normalisation.
+MHC_DEFECT_LIMIT = 100_000
+
+
+def check_counters(stats: Dict[str, Any]) -> List[str]:
+    """Findings over an engine's counters after a workload (``[]`` = ok;
+    an engine whose family keeps none of them has nothing to find)."""
+    found = []
+    defect = stats.get("mhc_res_defect_max")
+    if defect is not None and defect > MHC_DEFECT_LIMIT:
+        found.append(
+            f"mhc_res_defect_max {defect} > {MHC_DEFECT_LIMIT}: a mixing "
+            f"matrix is {defect / 1e6:.3f} from doubly stochastic (the "
+            "Sinkhorn iteration was cut short or lost a normalisation)")
+    return found
+
+
 # ------------------------------------------------------- compile budget
 def _mixed_workload(vocab: int) -> list:
     """Prompt lengths spanning every page bucket (1->4, 3->4, 7->8,
@@ -421,9 +446,11 @@ def check_compile_budget(cell: Dict[str, Any]) -> dict:
     budget = eng.compile_budget()
     over = {k: [v, budget.get(k, 0)] for k, v in counts.items()
             if v > budget.get(k, 0)}
-    ok = not over and counts.get("prefill", 0) > 0
+    findings = check_counters(eng.stats)
+    ok = not over and counts.get("prefill", 0) > 0 and not findings
     return {"cell": cell["name"], "ok": bool(ok), "counts": counts,
-            "budget": budget, "over_budget": over}
+            "budget": budget, "over_budget": over,
+            "counter_findings": findings}
 
 
 # --------------------------------------------------------------- driver

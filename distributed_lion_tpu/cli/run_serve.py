@@ -196,6 +196,7 @@ def build_engine_factory(gen_args, serve_args: "ServeArguments"):
                 "laguna": ServeModel.for_laguna,
                 "ling": ServeModel.for_ling,
                 "minicpm_sala": ServeModel.for_minicpm_sala,
+                "xing": ServeModel.for_xing,
                 }[gen_args.model_family](p, c)
 
     if serve_args.speculate:

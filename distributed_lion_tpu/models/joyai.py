@@ -192,12 +192,15 @@ def joyai_init(key: jax.Array, cfg: JoyAIConfig) -> dict:
     return params
 
 
-def _mla_block(x, p, cfg: JoyAIConfig, c, tables, pos, cos, sin, valid):
+def _mla_block(x, p, cfg: JoyAIConfig, c, tables, pos, cos, sin, valid,
+               scale=None):
     """Latent attention over the paged cache: scatter the new tokens'
     latent rows, then attend (the module note says which path). Returns
     (output ``[B, S, d]``, the layer's updated page leaf). ``cfg`` is read
     for its head count, widths and ``rms_eps`` alone, so another family's
-    configuration with the same names serves (``models/ling``). Two leaves of
+    configuration with the same names serves (``models/ling``,
+    ``models/xing``). ``scale``: the softmax scale, both paths' (default ``1 /
+    sqrt(nope + rope)``; a YaRN family's carries its ``mscale``). Two leaves of
     ``p`` are optional: ``wq`` in place of ``wq_a`` / ``q_norm`` / ``wq_b``
     (a query with no low-rank step), and ``wg``, a per-head output gate
     before ``wo`` (``models/laguna.head_gate``)."""
@@ -212,7 +215,8 @@ def _mla_block(x, p, cfg: JoyAIConfig, c, tables, pos, cos, sin, valid):
     B, S, _ = x.shape
     H, r = cfg.n_head, cfg.kv_lora_rank
     dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
-    scale = 1.0 / math.sqrt(dn + dr)
+    if scale is None:
+        scale = 1.0 / math.sqrt(dn + dr)
     with jax.named_scope("mla/q"):
         if "wq" in p:
             q = _matmul(x, p["wq"])

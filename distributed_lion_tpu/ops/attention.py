@@ -450,20 +450,38 @@ def mla_decode_attention(q_abs, kv_pages, tables, pos, *, scale: float):
     return mla_paged_attn(q_abs, kv_pages, tables, lengths, scale=scale)
 
 
+def query_chunk(B: int, H: int, S: int, T: int) -> int:
+    """Queries a step of :func:`chunked_causal_attention`: 256 while their
+    float32 scores (B x H x 256 x T) stay within ``SCORE_BYTES``; past
+    that the largest power of two whose scores stay within a quarter of it.
+    The chip's compiler tiles the chunk's softmax fusion ever worse as its
+    scores grow (:func:`banded_causal_attention`'s note), and past
+    ``SCORE_BYTES`` staying just within it is not enough: 32 heads of 192 /
+    128 over 4,096 keys, a layer, took 16.2 ms at 256 queries a chunk (128
+    MB of scores), 9.8 at 128, 6.0 at 64 and 4.5 at 32 (16 MB), where the
+    same heads over 2,048 keys took 1.1 ms at any of them (PERF.md, PR 39).
+    Every shape that fitted keeps its 256."""
+    chunk = 256
+    if B * H * chunk * T * 4 > SCORE_BYTES:
+        while chunk > 8 and B * H * chunk * T * 4 > SCORE_BYTES // 4:
+            chunk //= 2
+    return min(chunk, S)
+
+
 @jax.named_scope("mla_attn")
-def chunked_causal_attention(q, k, v, pos, *, scale: float,
-                             chunk: int = 256):
+def chunked_causal_attention(q, k, v, pos, *, scale: float, chunk=None):
     """Masked-softmax attention of S new tokens a row over T cached
-    positions, the queries taken ``chunk`` at a time so that no
-    ``[H, S, T]`` float32 scores are held (32 heads x 2,048 x 3,072 would be
-    0.8 GB; a chunk of 256 is 0.1 GB). q [B, H, S, dk]; k [B, H, T, dk];
+    positions, the queries taken ``chunk`` at a time (default:
+    :func:`query_chunk`) so that no ``[H, S, T]`` float32 scores are held
+    (32 heads x 2,048 x 3,072 would be 0.8 GB; a chunk of 256 is 0.1 GB).
+    q [B, H, S, dk]; k [B, H, T, dk];
     v [B, H, T, dv] (dv may differ from dk); query s of row b sits at
     position ``pos[b] + s`` and sees positions ``<=`` its own. Returns
     [B, H, S, dv] in q's dtype. The arithmetic is
     :func:`paged_decode_attention`'s gather path, chunk by chunk."""
     B, H, S, _ = q.shape
     T = k.shape[2]
-    chunk = min(chunk, S)
+    chunk = query_chunk(B, H, S, T) if chunk is None else min(chunk, S)
     assert S % chunk == 0, (S, chunk)
     t_idx = jnp.arange(T)[None, None, :]
 

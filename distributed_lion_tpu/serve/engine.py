@@ -637,6 +637,36 @@ class ServeModel:
             last_logit=True, shardable=False)
 
     @staticmethod
+    def for_xing(params: Any, cfg: Any) -> "ServeModel":
+        """Xing4.0 (models/xing): JoyAI's latent page leaf and expert
+        layer round a residual of ``hc_mult`` mixed streams, which never
+        reaches the cache (``ops/mhc``); the mix's counters ride behind the
+        expert layer's."""
+        from distributed_lion_tpu.models.xing import (
+            XING_COUNTERS,
+            xing_decode_paged,
+        )
+
+        def decode(p, toks, pages, tables, pos, valid=None, tp_axis=None,
+                   ep_axis=None, return_moe_stats=False, stats_axis=None,
+                   stats_lanes=None, logit_index=None):
+            # the engine refuses tp / ep for this family at build
+            assert tp_axis is None and ep_axis is None and stats_axis is None
+            return xing_decode_paged(p, toks, cfg, pages, tables, pos,
+                                     valid, return_moe_stats, logit_index)
+
+        return ServeModel(
+            "xing", cfg, params, decode, cfg.n_layer, 1, cfg.latent_dim,
+            cfg.compute_dtype, max_positions=cfg.n_ctx,
+            page_leaves={"kv": (1, cfg.latent_dim)},
+            kernel_stat="mla_kernel_ticks", moe_counters=XING_COUNTERS,
+            last_logit=True, shardable=False,
+            setup_note=(
+                f"residual: {cfg.hc_mult} mixed streams of {cfg.d_model} "
+                f"(mHC, {cfg.hc_sinkhorn_iters} Sinkhorn steps a token a "
+                f"sublayer), {2 * cfg.n_layer} sublayers"))
+
+    @staticmethod
     def for_laguna(params: Any, cfg: Any) -> "ServeModel":
         """Laguna (models/laguna): window and full GQA layers with their
         own head counts over one pool list, a bounded ring a slot for the
@@ -2092,6 +2122,11 @@ class ServingEngine:
                 self.stats["prefill_dispatches"], 1)
             g["prefix_hit_rate"] = hits / disp
             g["cow_copies"] = self.stats["cow_copies"]
+        if "mhc_rows" in self.stats:
+            # models/xing's residual mix: rows x sublayers mixed so far, and
+            # how far the worst mixing matrix was from doubly stochastic
+            g["mhc_rows"] = self.stats["mhc_rows"]
+            g["mhc_res_defect"] = self.stats["mhc_res_defect_max"] / 1e6
         if "spec_proposed" in self.stats:
             g["spec_accept_rate"] = (
                 self.stats["spec_accepted"]

@@ -32,7 +32,8 @@ Three layers:
     unconditionally. ``ServeMetrics`` is the opt-in plane on top: wall
     clocks (TTFT ms, per-token decode ms), the sketches, live gauges
     (queue depth, page-pool occupancy, active slots, speculative
-    accept rate, prefix-hit/CoW counts, evictions), drained at a tick
+    accept rate, prefix-hit/CoW counts, evictions, a hyper-connected
+    family's ``mhc_rows`` and ``mhc_res_defect``), drained at a tick
     cadence into ``serve_metrics`` journal events (train/journal.py —
     strict JSON, ``allow_nan=False``).
 

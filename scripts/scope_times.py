@@ -41,7 +41,8 @@ SCOPES = ("embed", "attn", "mlp", "head", "xent", "paged_attn",
           "kda/step", "kda/chunk", "kda/out_norm", "kda_step", "kda_chunk",
           "lightning/step", "lightning/chunk", "lightning/out_norm",
           "lightning_step", "lightning_chunk", "sparse/compress",
-          "sparse/select", "sparse_attn", "dense_attn")
+          "sparse/select", "sparse_attn", "dense_attn", "mhc/pre",
+          "mhc/post", "mhc/read_out", "mhc_pre", "mhc_post")
 NO_SCOPE = "(no scope)"
 _FIND = [(s, re.compile(r"(?<=[(/])%s(?=[)/])" % re.escape(s)))
          for s in SCOPES]

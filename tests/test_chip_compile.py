@@ -1388,3 +1388,106 @@ def test_minicpm_sala_serving_programs_at_the_published_shapes(
         assert not re.search(r"f32\[1,%d,73448\]" % s_len, text)
         assert mem.temp_size_in_bytes < 5.0e9
         assert '"estimated_cycles":"9223372036854775807"' not in text
+
+
+@pytest.mark.parametrize("kind", ["decode_tick", "prefill_4096"])
+def test_mhc_and_latent_serving_programs_at_the_published_shapes(
+        one_chip, kind, monkeypatch, capsys):
+    """Xing4.0-29B-A4B as ``serve.xing4.0-29b-a4b.backlog-4k-in`` runs it
+    (the cut configuration file: one dense and five expert layers, all 64
+    experts of each, the whole vocabulary; 64 slots, 18,432 latent pages),
+    donated. Both programs hold the mix's two kernels once a sublayer
+    (``mhc_pre``, ``mhc_post``: 12 each) and ``moe_gmm``; the decode tick
+    holds ``mla_paged_attn`` once a layer; none copies the latent pool or an
+    expert bank, and NO buffer of the stream's size is a copy, a relayout
+    or a float32 image of it: the stream is ``[rows, 4 x 3584]`` bfloat16,
+    rewritten in place by ``mhc_post``. The 4,096-token prefill keeps one
+    position's logits and fits the chip beside 11.9 GB of weights and
+    cache; live bytes are printed."""
+    from distributed_lion_tpu.analysis.serve_check import pool_leaf_copies
+    from distributed_lion_tpu.models.xing import (
+        XING_COUNTERS, XingConfig, xing_decode_paged, xing_init,
+    )
+    from distributed_lion_tpu.serve.engine import ServeModel
+    from distributed_lion_tpu.serve.kv_cache import init_page_leaves
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    cfg = XingConfig.named(os.path.join(
+        root, "benchmark", "configs", "xing4.0-29b-a4b.json"))
+    block, per_seq, slots, pool = 16, 288, 64, 18432
+    decode = kind == "decode_tick"
+    b, s_len = (slots, 1) if decode else (1, int(kind.split("_")[1]))
+
+    def place(tree):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=one_chip), tree)
+
+    model = ServeModel.for_xing(None, cfg)
+    pages = place(jax.eval_shape(lambda: init_page_leaves(
+        cfg.n_layer, pool, block, model.page_leaves, cfg.compute_dtype)))
+    leaf = pages[0]["kv"]
+    assert leaf.shape == (pool, block, 1, 640) and len(pages) == 6
+    params = place(jax.eval_shape(lambda: xing_init(jax.random.key(0), cfg)))
+    matrices = sum(x.size for x in jax.tree.leaves(params)
+                   if x.ndim > 1 and x.shape[-1] != 128)
+    assert round(matrices / 1e5) == 47885                     # 9.58 GB
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+    def fn(params, pages, toks, tables, pos):
+        valid = jnp.arange(s_len)[None, :] < jnp.maximum(pos[:, None], 1)
+        logits, pages, st = xing_decode_paged(
+            params, toks, cfg, pages, tables,
+            pos if decode else jnp.zeros_like(pos), valid, True,
+            None if decode else pos[0])
+        tail = jnp.stack([st[k] for k in XING_COUNTERS])
+        return (jnp.argmax(logits[:, -1], -1), tail), pages
+
+    t0 = time.monotonic()
+    compiled = jax.jit(fn, donate_argnums=(1,)).lower(
+        params, pages, i32(b, s_len), i32(b, per_seq), i32(b)).compile()
+    secs = time.monotonic() - t0
+    text = compiled.as_text()
+    assert "input_output_alias" in text
+    assert not pool_leaf_copies(text, leaf)
+    bank = jax.ShapeDtypeStruct((cfg.n_experts, cfg.d_model, cfg.moe_d_ff),
+                                jnp.bfloat16)
+    assert not pool_leaf_copies(text, bank)
+    for name in ("mhc_pre", "mhc_post"):
+        calls = re.findall(r"%%%s(?:\.\d+)? = [^\n]*custom-call" % name, text)
+        assert len(calls) == 2 * cfg.n_layer == 12, (name, len(calls))
+    assert _named_custom_call(text, "moe_gmm")
+    calls = re.findall(r"%mla_paged_attn(?:\.\d+)? = [^\n]*custom-call",
+                       text)
+    assert len(calls) == cfg.n_layer * decode
+    # the stream: no buffer of its size is a copy, a relayout or a float32
+    # image of it (a ``[rows, 4, 3584]`` buffer is the expert layer's
+    # combine over top_k = 4 picks, not the stream)
+    rows, wide = b * s_len, cfg.hc_mult * cfg.d_model
+    held = re.sub(r"(?ms)^%?fused_computation[^\n]*\{\n.*?^\}\n", "", text)
+    assert not re.search(r"= f32\[%d,%d\]" % (rows, wide), held)
+    assert not re.search(r"= bf16\[%d,%d\]\S* (copy|transpose)\("
+                         % (rows, wide), held)
+    for scope in ("mhc/pre", "mhc/post", "mhc/read_out", "mla/q",
+                  "mla/kv_latent", "moe/route", "moe/sort", "moe/experts",
+                  "moe/shared", "moe/combine"):
+        assert re.search(r'op_name="[^"]*/%s/' % scope, text), scope
+    mem = compiled.memory_analysis()
+    live = mem.argument_size_in_bytes + mem.temp_size_in_bytes \
+        + mem.output_size_in_bytes - mem.alias_size_in_bytes
+    with capsys.disabled():
+        print(f"\n[mhc_and_latent] {kind}: compiled in {secs:.0f} s; "
+              f"arguments {mem.argument_size_in_bytes / 1e9:.2f} GB, "
+              f"temporaries {mem.temp_size_in_bytes / 1e9:.2f} GB, live "
+              f"{live / 1e9:.2f} GB")
+    assert 11.8e9 < mem.argument_size_in_bytes < 12.0e9
+    assert mem.alias_size_in_bytes > 2.2e9                    # the pool
+    assert live < 15.75 * 2 ** 30 - 0.5e9, live               # the chip's HBM
+    if decode:
+        assert mem.temp_size_in_bytes < 0.2e9, mem.temp_size_in_bytes
+    else:
+        assert not re.search(r"f32\[1,%d,131072\]" % s_len, text)
+        assert mem.temp_size_in_bytes < 2.5e9, mem.temp_size_in_bytes
+        assert '"estimated_cycles":"9223372036854775807"' not in text

@@ -337,6 +337,8 @@ def _scopes(text):
     ("jit(prefill)/moe/experts/jit(moe_gmm)/moe_gmm/pallas_call:", "moe_gmm"),
     ("jit(prefill)/moe/sort/jit(argsort)/sort:", "moe/sort"),
     ("jit(prefill)/moe/shared/mlp/dot_general:", "mlp"),
+    ("jit(prefill)/mhc/pre/jit(mhc_pre)/pallas_call:", "mhc_pre"),
+    ("jit(decode_tick)/mhc/post/concatenate:", "mhc/post"),
     ("pages[45]['k']:", "(no scope)"),
     ("jit(train_step)/headroom/attnx/add:", "(no scope)"),
     ("", "(no scope)"),
