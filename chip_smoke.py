@@ -576,7 +576,9 @@ def phase_serve(out_dir: str) -> None:
         f"{stats['kv_pages_table']}; run_ahead_ticks "
         f"{stats['run_ahead_ticks']} of them, run_ahead_drains "
         f"{stats['run_ahead_drains']}, run_ahead_discarded "
-        f"{stats['run_ahead_discarded']}")
+        f"{stats['run_ahead_discarded']}; prefill_fresh_dispatches "
+        f"{stats['prefill_fresh_dispatches']} of "
+        f"{stats['prefill_dispatches']} prefills")
     log(f"  run_serve: {len(records)} requests complete "
         f"({[r['reason'] for r in records]}), pool back to "
         f"{engine.tables.free_blocks}/{engine.tables.num_blocks} free, "

@@ -750,18 +750,26 @@ def gpt2_decode(params: dict, tokens: jnp.ndarray, cfg: GPT2Config, cache: list,
 
 @jax.named_scope("attn")
 def _paged_attention_block(x, p, cfg: GPT2Config, c, tables, pos, valid,
-                           tp_axis=None):
+                           tp_axis=None, fresh=False):
     """The paged twin of :func:`_decode_attention`: scatter the new k/v
     into block-table pages, attend over the gathered history
     (ops.attention.paged_decode_attention — same masked-softmax chain as
     the dense path, so greedy decode is bit-identical when T matches).
+    ``fresh`` (static): the window's first token is at position 0, so the
+    keys it attends to are the ones it has just projected; where
+    ``ops.attention.fresh_kernel_applies`` it attends over q, k, v as they
+    lie, token-major, through the tiled forward kernel, and the pages are
+    written and never gathered.
     With ``tp_axis`` (inside shard_map — the TP serving engine): qkv is
     column-parallel (this rank holds H/tp heads and the page pool's
     matching kv-head shard), the scatter/gather/attend chain is entirely
     shard-local, and only the row-parallel output projection crosses the
     tensor axis (one psum; bias added after the reduction, once)."""
     from distributed_lion_tpu.ops.attention import (
+        fresh_causal_attention,
+        fresh_kernel_applies,
         paged_decode_attention,
+        paged_scatter_fresh,
         paged_scatter_kv,
     )
 
@@ -770,11 +778,20 @@ def _paged_attention_block(x, p, cfg: GPT2Config, c, tables, pos, valid,
     H, hd = cfg.n_head // tp, cfg.head_dim
     qkv = _qkv_project(x, p["qkv"]) + p["qkv_b"].astype(x.dtype)
     q, k, v = (qkv[:, :, i].reshape(B, S, H, hd) for i in range(3))
-    k_pages = paged_scatter_kv(c["k"], tables, pos, k.astype(c["k"].dtype), valid)
-    v_pages = paged_scatter_kv(c["v"], tables, pos, v.astype(c["v"].dtype), valid)
-    out = paged_decode_attention(q.transpose(0, 2, 1, 3), k_pages, v_pages,
-                                 tables, pos, kv_heads=H)
-    out = out.transpose(0, 2, 1, 3).reshape(B, S, H * hd)
+    if fresh and fresh_kernel_applies(S, H, H, hd, q.dtype):
+        k_pages = paged_scatter_fresh(c["k"], tables, k.astype(c["k"].dtype),
+                                      valid)
+        v_pages = paged_scatter_fresh(c["v"], tables, v.astype(c["v"].dtype),
+                                      valid)
+        out = fresh_causal_attention(q, k, v)
+    else:
+        k_pages = paged_scatter_kv(c["k"], tables, pos,
+                                   k.astype(c["k"].dtype), valid)
+        v_pages = paged_scatter_kv(c["v"], tables, pos,
+                                   v.astype(c["v"].dtype), valid)
+        out = paged_decode_attention(q.transpose(0, 2, 1, 3), k_pages,
+                                     v_pages, tables, pos, kv_heads=H)
+        out = out.transpose(0, 2, 1, 3).reshape(B, S, H * hd)
     out = _proj(out, p["proj"])
     if tp_axis is not None:
         out = reduce_from_tp_region(out, tp_axis)
@@ -786,7 +803,7 @@ def gpt2_decode_paged(params: dict, tokens: jnp.ndarray, cfg: GPT2Config,
                       pages: list, tables: jnp.ndarray, pos: jnp.ndarray,
                       valid=None, tp_axis=None, ep_axis=None,
                       return_moe_stats=False, stats_axis=None,
-                      stats_lanes=None):
+                      stats_lanes=None, fresh=False):
     """Block-table decode (the serving engine's model hook): ``tokens``
     [B, S] where row b's tokens sit at absolute positions
     ``pos[b] .. pos[b]+S-1`` of its own sequence; ``pages`` is the
@@ -798,6 +815,11 @@ def gpt2_decode_paged(params: dict, tokens: jnp.ndarray, cfg: GPT2Config,
     [B, S, vocab] f32, updated pages). Positions are PER ROW, so one call
     serves prefill (S = padded prompt, pos = 0) and the rolling decode
     tick (S = 1, pos = per-slot lengths) — one jitted program each.
+    ``fresh`` (static; the engine knows it at dispatch): every row's
+    ``pos`` is 0, so no query sees a page this call did not write, and the
+    blocks may attend over their own fresh keys
+    (:func:`_paged_attention_block`). A window behind a shared prefix, a
+    speculative verify and a drafter's mirror leave it False.
     With ``tp_axis`` (inside shard_map — the TP serving engine, ISSUE 13)
     attention/MLP weights and the page pool's kv-head axis are expected
     pre-sharded per ``parallel.tensor_parallel.gpt2_param_specs``;
@@ -835,7 +857,8 @@ def gpt2_decode_paged(params: dict, tokens: jnp.ndarray, cfg: GPT2Config,
     new_pages = []
     for p, c in zip(params["blocks"], pages):
         a, c = _paged_attention_block(_layer_norm(x, p["ln_1"]), p["attn"],
-                                      cfg, c, tables, pos, valid, tp_axis)
+                                      cfg, c, tables, pos, valid, tp_axis,
+                                      fresh)
         x = _decode_mlp(x + a, p, cfg, tp_axis, valid, ep_axis, stats,
                         stats_axis, stats_lanes)
         new_pages.append(c)

@@ -327,19 +327,26 @@ def llama_decode(params: dict, tokens: jnp.ndarray, cfg: LlamaConfig, cache: lis
 
 @jax.named_scope("attn")
 def _paged_attention_block(x, p, cfg: LlamaConfig, c, tables, pos, cos, sin,
-                           valid, tp_axis=None):
+                           valid, tp_axis=None, fresh=False):
     """The paged twin of :func:`_decode_attention` (serve/kv_cache layout):
     scatter the roped new k (and v) into block-table pages, attend over
     the gathered history via ops.attention.paged_decode_attention — the
     same masked-softmax chain, so greedy decode is bit-identical to the
-    dense cache whenever the attended length matches. With ``tp_axis``
+    dense cache whenever the attended length matches. ``fresh`` (static):
+    the window starts at position 0 and, where
+    ``ops.attention.fresh_kernel_applies`` (heads of 128), attends over its
+    own roped q, k and v through the tiled forward kernel; the pages are
+    written and never gathered. With ``tp_axis``
     (the TP serving engine) wq/wk/wv are column-parallel — this rank holds
     n_head/tp query and n_kv_head/tp kv heads and the page pool's matching
     kv-head shard — the scatter/gather/attend chain is shard-local (GQA
     repeat preserved: H/tp over KV/tp), and wo is row-parallel with one
     psum over the tensor axis."""
     from distributed_lion_tpu.ops.attention import (
+        fresh_causal_attention,
+        fresh_kernel_applies,
         paged_decode_attention,
+        paged_scatter_fresh,
         paged_scatter_kv,
     )
 
@@ -351,11 +358,20 @@ def _paged_attention_block(x, p, cfg: LlamaConfig, c, tables, pos, cos, sin,
     v = _matmul(x, p["wv"]).reshape(B, S, KV, hd)
     q = apply_rope(q, cos, sin)
     k = apply_rope(k, cos, sin).transpose(0, 2, 1, 3)  # back to [B, S, KV, hd]
-    k_pages = paged_scatter_kv(c["k"], tables, pos, k.astype(c["k"].dtype), valid)
-    v_pages = paged_scatter_kv(c["v"], tables, pos, v.astype(c["v"].dtype), valid)
-    out = paged_decode_attention(q, k_pages, v_pages, tables, pos,
-                                 kv_heads=KV)
-    out = out.transpose(0, 2, 1, 3).reshape(B, S, H * hd)
+    if fresh and fresh_kernel_applies(S, H, KV, hd, q.dtype):
+        k_pages = paged_scatter_fresh(c["k"], tables, k.astype(c["k"].dtype),
+                                      valid)
+        v_pages = paged_scatter_fresh(c["v"], tables, v.astype(c["v"].dtype),
+                                      valid)
+        out = fresh_causal_attention(q.transpose(0, 2, 1, 3), k, v)
+    else:
+        k_pages = paged_scatter_kv(c["k"], tables, pos,
+                                   k.astype(c["k"].dtype), valid)
+        v_pages = paged_scatter_kv(c["v"], tables, pos,
+                                   v.astype(c["v"].dtype), valid)
+        out = paged_decode_attention(q, k_pages, v_pages, tables, pos,
+                                     kv_heads=KV)
+        out = out.transpose(0, 2, 1, 3).reshape(B, S, H * hd)
     out = _matmul(out, p["wo"])
     if tp_axis is not None:
         out = reduce_from_tp_region(out, tp_axis)
@@ -364,7 +380,7 @@ def _paged_attention_block(x, p, cfg: LlamaConfig, c, tables, pos, cos, sin,
 
 def llama_decode_paged(params: dict, tokens: jnp.ndarray, cfg: LlamaConfig,
                        pages: list, tables: jnp.ndarray, pos: jnp.ndarray,
-                       valid=None, tp_axis=None):
+                       valid=None, tp_axis=None, fresh=False):
     """Block-table decode (the serving engine's model hook): row b's
     ``tokens`` [B, S] sit at positions ``pos[b] .. pos[b]+S-1`` of its own
     sequence (rotary angles gathered per row); ``pages`` is the per-layer
@@ -373,9 +389,10 @@ def llama_decode_paged(params: dict, tokens: jnp.ndarray, cfg: LlamaConfig,
     kv heads un-repeated, like the dense cache). Returns (logits
     [B, S, vocab] f32, updated pages). One jitted program serves both the
     bucketed prefill (S = padded prompt, ``valid`` masks the tail) and the
-    rolling decode tick (S = 1, pos = per-slot lengths). With ``tp_axis``
-    (inside shard_map — the TP serving engine, ISSUE 13) attention/MLP
-    weights and the pool's kv-head axis are pre-sharded per
+    rolling decode tick (S = 1, pos = per-slot lengths). ``fresh`` (static)
+    says every row's ``pos`` is 0 (``models/gpt2.gpt2_decode_paged``). With
+    ``tp_axis`` (inside shard_map — the TP serving engine, ISSUE 13)
+    attention/MLP weights and the pool's kv-head axis are pre-sharded per
     ``parallel.tensor_parallel.llama_param_specs``; wte/lm_head stay
     replicated, so logits are identical on every tensor rank."""
     B, S = tokens.shape
@@ -391,7 +408,7 @@ def llama_decode_paged(params: dict, tokens: jnp.ndarray, cfg: LlamaConfig,
     for p, c in zip(params["blocks"], pages):
         a, c = _paged_attention_block(_rms_norm(x, p["ln_attn"], cfg.rms_eps),
                                       p["attn"], cfg, c, tables, pos, cos, sin,
-                                      valid, tp_axis)
+                                      valid, tp_axis, fresh)
         x = x + a
         x = x + _mlp(_rms_norm(x, p["ln_mlp"], cfg.rms_eps), p["mlp"], tp_axis)
         new_pages.append(c)

@@ -92,13 +92,27 @@ def attention_flash(q, k, v, *, causal: bool = True):
 # one head a group (tests and tools build it; on the chip XLA keeps such a
 # leaf with num_blocks minor-most and every dispatch re-lays it out).
 #
-# Which calls take the Mosaic kernel (ops/pallas_paged_attn) is decided
-# from what the call shows, in :func:`paged_kernel_applies`: one query
-# token a row, no ``start``, a TPU backend, a pool the kernel takes as it
-# lies. Every other call (S > 1: bucketed prefill, speculative verify;
-# left-padded batches; the CPU) takes the gather path below, which is the
-# reference the kernel is tested against and stays bit-identical to the
-# dense cache.
+# Which calls take which path is decided from what the call shows:
+#
+# - one query token a row, no ``start``, a TPU backend, a pool the kernel
+#   takes as it lies (:func:`paged_kernel_applies`): the Mosaic kernel
+#   ``paged_attn`` (ops/pallas_paged_attn) over the pool in place: the
+#   decode tick.
+# - S > 1 whose first token is at position 0 (the dispatch says so: the
+#   engine's ``fresh``), a TPU backend, S whole blocks of 128 rows up to
+#   8,192, heads of 128 or ungrouped heads of 64
+#   (:func:`fresh_kernel_applies`): the keys a prefill attends to are the
+#   ones it has just projected, so it attends over q, k, v token-major
+#   through the tiled forward kernel ``flash_gqa_fwd``
+#   (:func:`fresh_causal_attention`), and the pool is only written, a page
+#   at a time (:func:`paged_scatter_fresh`). GPT-2's
+#   and Llama's prefills; the families with their own hooks take
+#   :func:`banded_causal_attention` or :func:`chunked_causal_attention`.
+# - every other call (a prefill behind a shared prefix, the speculative
+#   verify window and the drafter's mirror, whose queries see pages they did
+#   not write; left-padded batches; a bucket off a multiple of 128; the
+#   CPU): the gather path below, which is the reference both kernels are
+#   tested against and stays bit-identical to the dense cache.
 
 
 @jax.named_scope("paged_scatter")
@@ -262,6 +276,77 @@ def paged_decode_attention(q, k_pages, v_pages, tables, pos,
     return out.astype(q.dtype)
 
 
+# --------------------------------------- a prefill over its own fresh keys
+def fresh_kernel_applies(n_new: int, n_head: int, kv_heads: int,
+                         head_dim: int, dtype) -> bool:
+    """True when :func:`fresh_causal_attention` takes a window of ``n_new``
+    tokens from position 0 (the rule is in the layout note above). The
+    serving engine asks the same question, bucket by bucket, to decide
+    which prefills it dispatches as fresh and to count them."""
+    from distributed_lion_tpu.ops.pallas_flash_attn import gqa_kernel_takes
+
+    return (n_new > 1 and jax.default_backend() == "tpu"
+            and n_head % kv_heads == 0
+            and gqa_kernel_takes(n_new, head_dim, dtype, n_head // kv_heads))
+
+
+@jax.jit        # a model's blocks are a Python loop: traced once a shape
+@jax.named_scope("paged_scatter")
+def paged_scatter_fresh(pages, tables, new, valid=None):
+    """:func:`paged_scatter_kv` for S fresh tokens at positions ``0 ..
+    S-1``, a page at a time: the same cells get the same values and every
+    other cell keeps what it held. ``valid`` [B, S] marks a right-padded
+    prompt's real tokens (a prefix of each row; None: all S).
+
+    The chip's scatter costs some 170 ns an index whatever it moves (a
+    1,024-token prefill of GPT-2 XL spent 15.7 of its 40.5 ms writing
+    98,304 rows of 3.3 KB one by one: my chip run, PR 40), and from
+    position 0 token t lies in row ``t % block_size`` of the row's page
+    ``t // block_size``: the pages a prompt fills are written whole (one
+    index a page), the page it ends in row by row, the pages past it not
+    at all."""
+    B, S = new.shape[:2]
+    NB, bs, G, W = pages.shape
+    if S % bs:                       # a window that is not whole pages
+        return paged_scatter_kv(pages, tables, jnp.zeros((B,), jnp.int32),
+                                new, valid)
+    n = S // bs
+    length = (jnp.full((B,), S, jnp.int32) if valid is None
+              else valid.sum(axis=1).astype(jnp.int32))
+    full = length // bs                                   # whole pages a row
+    ids = jnp.where(jnp.arange(n)[None, :] < full[:, None], tables[:, :n], NB)
+    whole = new.reshape(B * n, bs, G, -1)
+    whole = jnp.pad(whole, ((0, 0), (0, 0), (0, 0), (0, W - whole.shape[-1])))
+    pages = pages.at[ids.reshape(-1)].set(whole, mode="drop",
+                                          unique_indices=False)
+    # the page the prompt ends in (none where it ends on a page's edge)
+    first = jnp.minimum(full, n - 1) * bs
+    last = jax.vmap(lambda x, at: jax.lax.dynamic_slice_in_dim(x, at, bs, 0))(
+        new, first)
+    at = first[:, None] + jnp.arange(bs)[None, :]
+    return paged_scatter_kv(
+        pages, tables, first, last,
+        (at >= (full * bs)[:, None]) & (at < length[:, None]))
+
+
+def fresh_causal_attention(q, k, v):
+    """Causal self-attention of S fresh tokens at positions ``0 .. S-1``,
+    token-major as the projections write them: q [B, S, H, hd]; k, v
+    [B, S, KV, hd], head h reading kv head ``h // (H // KV)``. Returns
+    [B, S, H * hd] in q's dtype, as the output projection reads it: no
+    transpose either side of the kernel (``pallas_flash_attn.flash_gqa_fwd``;
+    a caller asks :func:`fresh_kernel_applies` first). Float32 scores,
+    running max and sum, the probabilities cast to v's dtype unnormalised
+    before ``P v``, where the gather path casts them normalised: one
+    rounding apart. Rows past a right-padded prompt's end attend to pad
+    keys and are the caller's to discard, as on the gather path."""
+    from distributed_lion_tpu.ops.pallas_flash_attn import flash_gqa_fwd
+
+    B, S, H, _ = q.shape
+    return flash_gqa_fwd(q.reshape(B, S, -1), k.reshape(B, S, -1),
+                         v.reshape(B, S, -1), H)
+
+
 # ------------------------------------------------ window layers: the ring
 # A layer whose queries see only the last ``window`` positions keeps a
 # bounded span a slot, whatever the sequence's length: ``R`` pages
@@ -354,8 +439,9 @@ def banded_causal_attention(q, k, v, *, window=None):
     of :func:`attention_xla`.
 
     Two paths, chosen from what the call shows. Without a band, on a TPU,
-    where ``pallas_flash_attn.gqa_kernel_takes`` (heads of 128, S a
-    multiple of 128 up to 8,192): the repo's tiled forward kernel
+    where ``pallas_flash_attn.gqa_kernel_takes`` (heads of 128, or of 64
+    with one kv head a query head; S a multiple of 128 up to 8,192): the
+    repo's tiled forward kernel
     ``flash_gqa_fwd``, token-major, which visits no block above the
     diagonal: 48 heads over 8,192 keys, a layer, 7.2 ms on a v5e with the
     transposes either side, 2.1 over 4,096, 0.7 over 2,048. Every other
@@ -378,7 +464,7 @@ def banded_causal_attention(q, k, v, *, window=None):
             flash_gqa_fwd, gqa_kernel_takes,
         )
 
-        if gqa_kernel_takes(S, hd, q.dtype):
+        if gqa_kernel_takes(S, hd, q.dtype, H // KV):
             # no band: the tiled kernel, token-major, which skips every
             # block above the diagonal (module note of pallas_flash_attn)
             def rows(x):

@@ -54,12 +54,25 @@ Names on the device: ``flash_attention_fwd`` and ``flash_mha_bwd``
 (``name=`` and the innermost ``jax.named_scope``, as ``ops/pallas_lion``
 names its kernels).
 
-A serving prefill with grouped queries (``flash_gqa_fwd``, on the device
-under that name): the forward kernel alone over separate token-major q, k
-and v, heads of 128, a query head's k and v block picked by ``h // rep``.
-``ops/attention.banded_causal_attention`` sends a full layer's prefill here
-(``models/laguna``: 48 query heads over 8 kv heads, 8,192 keys in 7.2 ms
-with the transposes either side; my chip run, PR 30).
+A serving prefill from position 0 (``flash_gqa_fwd``, on the device under
+that name): the forward kernel alone over separate token-major q, k and v
+as the projections write them. Two shapes, one kernel body:
+
+- heads of 128, grouped queries: a lane block is a query head and its k
+  and v block is picked by ``h // rep``.
+  ``ops/attention.banded_causal_attention`` sends a full layer's prefill
+  here (``models/laguna``: 48 query heads over 8 kv heads, 8,192 keys in
+  7.2 ms with the transposes either side; my chip run, PR 30), and
+  ``ops/attention.fresh_causal_attention`` a Llama prefill.
+- heads of 64, one kv head a query head: a lane block is two heads, as in
+  training; an odd head count (GPT-2 XL's 25) is padded with one head of
+  zero lanes (1,600 -> 1,664), whose output is zero and is sliced off.
+  ``ops/attention.fresh_causal_attention`` sends GPT-2's prefill here
+  (PR 40).
+
+The wrapper is jitted, as every kernel wrapper of the repo is: a model
+whose blocks are a Python loop lowers the kernel once a shape, not once a
+layer.
 """
 
 from __future__ import annotations
@@ -219,49 +232,62 @@ def _fwd(qkv, n_head: int, interpret: bool):
         )(qkv, qkv, qkv)
 
 
-def gqa_kernel_takes(T: int, head_dim: int, dtype) -> bool:
-    """Whether :func:`flash_gqa_fwd` takes these operands: a head is one
-    lane block, whole blocks of rows, k and v of a kv head inside VMEM."""
-    return head_dim == LANES and kernel_takes(T, 1, head_dim, dtype)
+def gqa_kernel_takes(T: int, head_dim: int, dtype, rep: int = 1) -> bool:
+    """Whether :func:`flash_gqa_fwd` takes these operands: a head of 128
+    is one lane block (any ``rep`` query heads a kv head), two heads of 64
+    share one (``rep`` 1 only: a lane block's k and v are its own two
+    heads'), whole blocks of rows, k and v of a lane block inside VMEM."""
+    return ((head_dim == LANES or (head_dim == 64 and rep == 1))
+            and kernel_takes(T, 1, LANES, dtype))
 
 
+@functools.partial(jax.jit, static_argnames=("n_head", "interpret"))
 def flash_gqa_fwd(q, k, v, n_head: int, interpret: bool = False):
-    """The forward kernel alone over grouped queries, for a prefill (no
-    gradient): q ``[B, T, n_head * 128]``, k and v ``[B, T, KV * 128]``,
-    all token-major as a projection writes them, head h reading kv head
-    ``h // (n_head // KV)``; ``[B, T, n_head * 128]`` in q's dtype. The
-    grid walks the query heads and a kv head's keys and values stay in
-    VMEM under all of its query heads (their index map does not move), so
+    """The forward kernel alone, for a prefill from position 0 (no
+    gradient): q ``[B, T, n_head * head_dim]``, k and v ``[B, T, KV *
+    head_dim]``, all token-major as a projection writes them, head h
+    reading kv head ``h // (n_head // KV)``; ``[B, T, n_head * head_dim]``
+    in q's dtype (:func:`gqa_kernel_takes` says which shapes). The grid
+    walks q's lane blocks (a head of 128, or two of 64 with ``KV ==
+    n_head``; an odd count of those is padded with one head of zero lanes
+    and its output sliced off) and a lane block's keys and values stay in
+    VMEM under all of its query blocks (their index map does not move), so
     nothing is repeated in HBM. Causal from position 0; on the device the
     kernel is ``flash_gqa_fwd``."""
     B, T, width = q.shape
-    rep = n_head // (k.shape[2] // LANES)
+    hd = width // n_head
+    pad = -width % LANES          # heads of 128: always 0
+    if pad:
+        q, k, v = (jnp.pad(x, ((0, 0), (0, 0), (0, pad))) for x in (q, k, v))
+    nj, hpb = (width + pad) // LANES, LANES // hd
+    rep = nj // (k.shape[2] // LANES)
     blk, nq = block_for(T), T // block_for(T)
     kv_spec = pl.BlockSpec((None, T, LANES), lambda b, j, i: (b, 0, j // rep))
     with jax.named_scope("flash_gqa_fwd"):
-        return pl.pallas_call(
-            functools.partial(_fwd_kernel, scale=1.0 / math.sqrt(LANES),
-                              head_dim=LANES),
-            grid=(B, n_head, nq),
+        out = pl.pallas_call(
+            functools.partial(_fwd_kernel, scale=1.0 / math.sqrt(hd),
+                              head_dim=hd),
+            grid=(B, nj, nq),
             in_specs=[pl.BlockSpec((None, blk, LANES),
                                    lambda b, j, i: (b, i, j)),
                       kv_spec, kv_spec],
             out_specs=[
                 pl.BlockSpec((None, blk, LANES), lambda b, j, i: (b, i, j)),
-                pl.BlockSpec((None, None, None, 1, blk),
+                pl.BlockSpec((None, None, None, hpb, blk),
                              lambda b, j, i: (b, j, i, 0, 0)),
             ],
             out_shape=[
-                jax.ShapeDtypeStruct((B, T, width), q.dtype),
-                jax.ShapeDtypeStruct((B, n_head, nq, 1, blk), jnp.float32),
+                jax.ShapeDtypeStruct((B, T, width + pad), q.dtype),
+                jax.ShapeDtypeStruct((B, nj, nq, hpb, blk), jnp.float32),
             ],
-            scratch_shapes=[pltpu.VMEM((1, blk, LANES), jnp.float32)],
+            scratch_shapes=[pltpu.VMEM((hpb, blk, LANES), jnp.float32)],
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel", "arbitrary"),
                 vmem_limit_bytes=_vmem_limit(T, q.dtype.itemsize, 2, 0)),
             interpret=interpret,
             name="flash_gqa_fwd",
         )(q, k, v)[0]
+    return out[..., :width] if pad else out
 
 
 # ---------------------------------------------------------------- backward

@@ -39,7 +39,17 @@ Scheduling (the vLLM recipe, simplified to two tick kinds):
 - **prefill** — one dispatch per admitted request at a power-of-two
   bucketed length (a handful of compiles total, never per-prompt), tail
   masked via the scatter's ``valid`` lanes; samples the request's first
-  token inside the same dispatch.
+  token inside the same dispatch. The host knows at dispatch whether the
+  prefill starts at position 0 (no shared prefix before it) and says so
+  to a hook that takes it (``ServeModel.fresh_prefill``: GPT-2 and Llama)
+  as the STATIC argument ``fresh``: in a bucket the tiled forward kernel
+  takes (``ops/attention.fresh_kernel_applies``: on a TPU, whole blocks of
+  128 rows) the program then attends over the keys it has just projected
+  and only writes its pages; a prefill behind a shared prefix, the
+  speculative verify and the drafter's mirror see pages they did not
+  write and keep the gather path. ``stats["prefill_fresh_dispatches"]``
+  of ``prefill_dispatches`` took the fresh path, and the ``[setup]
+  prefill:`` line says which buckets can.
 - **decode tick** — one dispatch advancing EVERY active slot one token:
   block-table decode (``*_decode_paged``) + per-slot sampling. Per-slot
   PRNG keys are ``fold_in(key(request.seed), generated_index)`` — a
@@ -180,6 +190,7 @@ built, and the retrace guard names it when it counts a retrace.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import time
 from collections import deque
 from typing import Any, Callable, Dict, List, Optional
@@ -425,6 +436,7 @@ class _Unread:
     vec: Any             # device int32 [max_seqs + counters]
     st: Any              # capacity-routed MoE load scalars, or {}
     rows: List[tuple]
+    fresh: bool = False  # a prefill that attended over its own fresh keys
 
 
 def dispatch_signature(operands) -> tuple:
@@ -432,8 +444,11 @@ def dispatch_signature(operands) -> tuple:
     operand — pure attribute reads (never values, never a device sync),
     so observing a dispatch costs nanoseconds on the common tick. Python
     scalars hash by type name (a scalar operand's jnp conversion always
-    lands the same weak dtype for the same Python type)."""
+    lands the same weak dtype for the same Python type); a bool is a
+    STATIC argument of its dispatch (the prefill's ``fresh``) and its value
+    picks the program, so it counts by value."""
     return tuple(
+        ((), f"static {a}") if isinstance(a, bool) else
         (tuple(getattr(a, "shape", ())),
          str(getattr(a, "dtype", type(a).__name__)))
         for a in operands)
@@ -508,7 +523,7 @@ class ServeModel:
                  shardable: bool = True, window: int = 0,
                  window_layers: tuple = (), state_layers: tuple = (),
                  state_leaves: Optional[Dict[str, tuple]] = None,
-                 setup_note: str = ""):
+                 setup_note: str = "", fresh_prefill: bool = False):
         self.family = family
         self.cfg = cfg
         self.params = params
@@ -535,6 +550,12 @@ class ServeModel:
         # position ``[B, 1, V]``: a 2,048-token prefill over a 129,280-row
         # head would otherwise hold 1 GB of float32 logits to read one row
         self.last_logit = last_logit
+        # the hook takes ``fresh`` (static): the engine says at dispatch
+        # that a prefill starts at position 0, and the hook may then attend
+        # over the keys it has just projected instead of gathering them back
+        # out of the pool (ops/attention.fresh_causal_attention). A family
+        # whose hook already reads S > 1 as "from 0" has no use for it
+        self.fresh_prefill = fresh_prefill
         # False: no tensor / expert sharding and no quantized weights here
         self.shardable = shardable
         # layers that see only the last ``window`` positions keep a bounded
@@ -583,15 +604,15 @@ class ServeModel:
 
         def decode(p, toks, pages, tables, pos, valid=None, tp_axis=None,
                    ep_axis=None, return_moe_stats=False, stats_axis=None,
-                   stats_lanes=None):
+                   stats_lanes=None, fresh=False):
             return gpt2_decode_paged(p, toks, cfg, pages, tables, pos,
                                      valid, tp_axis, ep_axis,
                                      return_moe_stats, stats_axis,
-                                     stats_lanes)
+                                     stats_lanes, fresh)
 
         return ServeModel("gpt2", cfg, params, decode, cfg.n_layer,
                           cfg.n_head, cfg.head_dim, cfg.compute_dtype,
-                          max_positions=cfg.n_ctx)
+                          max_positions=cfg.n_ctx, fresh_prefill=True)
 
     @staticmethod
     def for_llama(params: Any, cfg: Any) -> "ServeModel":
@@ -599,17 +620,17 @@ class ServeModel:
 
         def decode(p, toks, pages, tables, pos, valid=None, tp_axis=None,
                    ep_axis=None, return_moe_stats=False, stats_axis=None,
-                   stats_lanes=None):
+                   stats_lanes=None, fresh=False):
             # llama has no MoE blocks; the engine refuses --serve_ep for
             # it at build, so these can never be set here
             assert ep_axis is None and not return_moe_stats
             assert stats_axis is None and stats_lanes is None
             return llama_decode_paged(p, toks, cfg, pages, tables, pos,
-                                      valid, tp_axis)
+                                      valid, tp_axis, fresh)
 
         return ServeModel("llama", cfg, params, decode, cfg.n_layer,
                           cfg.n_kv_head, cfg.head_dim, cfg.compute_dtype,
-                          max_positions=cfg.n_ctx)
+                          max_positions=cfg.n_ctx, fresh_prefill=True)
 
     @staticmethod
     def for_joyai(params: Any, cfg: Any) -> "ServeModel":
@@ -1070,6 +1091,11 @@ class ServingEngine:
         # deadline_s, or an inherited stamp from a pre-migration submit)
         self._deadline_at: Dict[Any, float] = {}
         self.stats = {"ticks": 0, "decode_ticks": 0, "prefill_dispatches": 0,
+                      # of them, those that started at position 0 in a
+                      # bucket the tiled forward kernel takes: they attended
+                      # over their own fresh keys and gathered no page
+                      # (every such prefill on a TPU, none on the CPU)
+                      "prefill_fresh_dispatches": 0,
                       "decode_tokens": 0, "prefill_tokens": 0,
                       "padded_prefill_tokens": 0, "evictions": 0,
                       "freed_pages": 0, "timeouts": 0, "resumed_requests": 0,
@@ -1094,6 +1120,16 @@ class ServingEngine:
         self._decode_kernel = paged_kernel_applies(
             1, (nb // groups, bs, 1, width),  # one shard's share of the pool
             model.cache_dtype)
+        # the prefill buckets whose program attends over fresh keys when
+        # the host says the prefill starts at 0 (one shard's heads)
+        from distributed_lion_tpu.ops.attention import fresh_kernel_applies
+
+        shards = max(cfg.tp, 1)
+        self._fresh_buckets = frozenset(
+            b for b in self._buckets() if model.fresh_prefill
+            and fresh_kernel_applies(
+                b, model.cfg.n_head // shards, model.kv_heads // shards,
+                model.head_dim, model.cache_dtype))
         if self._windowed:
             # ticks whose walk over the ring ran the kernel. The pages
             # those walks were handed, ``kv_window_pages_read`` (ONE window
@@ -1211,7 +1247,11 @@ class ServingEngine:
                         st), pages
 
         def prefill(params, pages, tables, toks, start, length, seed, count,
-                    prev, where):
+                    prev, where, fresh=False):
+            # ``fresh`` (static, so a bucket may compile two programs under
+            # ``prefix_cache``): ``start`` is 0 and the bucket is one of
+            # ``_fresh_buckets``, which the host knows at dispatch; the
+            # hook then attends over the keys it has just projected.
             # ``where`` ([1] int32): the slot being admitted, which is also
             # what a window or state family's layers find their ring or
             # state by. The sampled first token is written over that entry
@@ -1246,7 +1286,8 @@ class ServingEngine:
                                      **({"logit_index": at}
                                         if model.last_logit else {}),
                                      **({"slots": where}
-                                        if slotted else {}))
+                                        if slotted else {}),
+                                     **({"fresh": True} if fresh else {}))
             logits, pages = out[0], out[1]
             st = out[2] if moe_stats else {}
             last = logits[0, 0] if model.last_logit else \
@@ -1280,12 +1321,13 @@ class ServingEngine:
             self._prefill = self._jit_paged(
                 prefill, n_rest=8,
                 rest_specs=(tab, rep, bsp, bsp, rep, rep, bsp, bsp),
-                out_spec=(bsp, rep), name="prefill")
+                out_spec=(bsp, rep), name="prefill", static=("fresh",))
         else:
             self._decode_tick = self._jit_paged(decode_tick, n_rest=6,
                                                 name="decode")
             self._prefill = self._jit_paged(prefill, n_rest=8,
-                                            name="prefill")
+                                            name="prefill",
+                                            static=("fresh",))
         self._cow = self._jit_cow(cow_copy)
 
         self._speculator = None
@@ -1316,6 +1358,12 @@ class ServingEngine:
                 "run-ahead 1 tick (device-fed last token)"
                 if self._run_ahead else "in order (speculation)"),
             stderr=True)
+        if model.fresh_prefill:
+            fb = sorted(self._fresh_buckets)
+            journal.emit("[setup] prefill: " + (
+                f"fresh keys, flash_gqa_fwd x{model.n_layer} (from position "
+                f"0 in buckets {fb[0]}-{fb[-1]}; every other prefill gathers)"
+                if fb else "gather"), stderr=True)
 
     # ------------------------------------------------------- TP dispatch
     def _register_dispatch(self, name: Optional[str], jitted, inner,
@@ -1349,18 +1397,28 @@ class ServingEngine:
         """Max legal distinct lowerings per dispatch kind: decode /
         verify / cow are ONE fixed-shape program each; prefill gets one
         per power-of-two page bucket (serve/kv_cache.bucket_tokens — the
-        O(log max) claim made countable). The draft-model mirror's own
+        O(log max) claim made countable), and under ``prefix_cache`` one
+        more for each bucket whose prefill from position 0 takes the
+        fresh-keys path (``_fresh_buckets``). The draft-model mirror's own
         prefill buckets identically."""
-        cap = self.cfg.block_size * self.cfg.max_blocks_per_seq
-        buckets = {bucket_tokens(n, self.cfg.block_size,
-                                 self.cfg.max_blocks_per_seq)
-                   for n in range(1, cap + 1)}
-        budget = {"decode": 1, "cow": 1, "prefill": len(buckets)}
+        buckets = self._buckets()
+        # a bucket whose prefill from position 0 attends over fresh keys
+        # compiles a second program where a prefill can also start behind
+        # a shared prefix
+        both = len(self._fresh_buckets) if self.cfg.prefix_cache else 0
+        budget = {"decode": 1, "cow": 1, "prefill": len(buckets) + both}
         if self.cfg.speculate:
             budget["verify"] = 1
             budget["draft_prefill"] = len(buckets)
             budget["draft_step"] = 1
         return budget
+
+    def _buckets(self) -> set:
+        """Every padded length a prefill can have."""
+        cap = self.cfg.block_size * self.cfg.max_blocks_per_seq
+        return {bucket_tokens(n, self.cfg.block_size,
+                              self.cfg.max_blocks_per_seq)
+                for n in range(1, cap + 1)}
 
     def _program_of(self, kind: str) -> str:
         """A dispatch kind's name in the compile ledger: the name of the
@@ -1376,7 +1434,7 @@ class ServingEngine:
             self._retrace_guard.observe(kind, operands)
 
     def _jit_paged(self, fn, n_rest: int, rest_specs=None, out_spec=None,
-                   name: Optional[str] = None):
+                   name: Optional[str] = None, static: tuple = ()):
         """jit a dispatch ``fn(params, pages, *rest) -> (out, pages)``;
         under TP the body is shard_map'd over the serving mesh — params
         and pages sharded per their spec trees, every host-built operand
@@ -1389,12 +1447,16 @@ class ServingEngine:
         ``P(EXPERT_AXIS)``) and ``out_spec`` (the spec-prefix for the
         first output, e.g. ``(P(EXPERT_AXIS), P())`` for
         expert-sharded sampled tokens + replicated psummed stats);
-        speculative verify reuses the same hooks (serve/speculate.py)."""
+        speculative verify reuses the same hooks (serve/speculate.py).
+        ``static`` names keyword arguments of ``fn`` that pick the program
+        (the prefill's ``fresh``); the registered ``inner`` is the program
+        of their defaults."""
         import jax
 
         donate = (1,) if jax.default_backend() != "cpu" else ()
         if self._mesh is None:
-            jitted = jax.jit(fn, donate_argnums=donate)
+            jitted = jax.jit(fn, donate_argnums=donate,
+                             static_argnames=static)
             self._register_dispatch(name, jitted, fn, donate, None, None)
             return jitted
         from jax.sharding import PartitionSpec as P
@@ -1404,12 +1466,23 @@ class ServingEngine:
             rest_specs = (rep,) * n_rest
         if out_spec is None:
             out_spec = rep
-        body = jax.shard_map(
-            fn, mesh=self._mesh,
-            in_specs=(self._param_specs, self._pages_spec)
-            + tuple(rest_specs),
-            out_specs=(out_spec, self._pages_spec), check_vma=False)
-        jitted = jax.jit(body, donate_argnums=donate)
+
+        def sharded(fn):
+            return jax.shard_map(
+                fn, mesh=self._mesh,
+                in_specs=(self._param_specs, self._pages_spec)
+                + tuple(rest_specs),
+                out_specs=(out_spec, self._pages_spec), check_vma=False)
+
+        body = sharded(fn)
+        if static:
+            @functools.wraps(fn)
+            def program(*operands, **chosen):
+                return sharded(functools.partial(fn, **chosen))(*operands)
+        else:
+            program = body
+        jitted = jax.jit(program, donate_argnums=donate,
+                         static_argnames=static)
         self._register_dispatch(name, jitted, body, donate,
                                 tuple(rest_specs), out_spec)
         return jitted
@@ -1658,7 +1731,8 @@ class ServingEngine:
     def _dispatch_prefill(self, req: Request, slot: int, covered: int,
                           suffix: List[int], padded: int):
         """Enqueue ONE admitted request's prefill and return its output
-        vector (not read) and MoE load scalars. The program writes the
+        vector (not read), its MoE load scalars and whether it was
+        dispatched as ``fresh``. The program writes the
         sampled first token over ``prev[slot]``, so the decode tick
         enqueued next reads it on the device; the host reads the vector
         when its turn comes (:meth:`_read`). All device-array construction
@@ -1706,18 +1780,22 @@ class ServingEngine:
         rest = (tab_dev, jnp.asarray(toks), start_dev, len_dev,
                 jnp.uint32(req.seed), jnp.int32(len(req.committed)),
                 self._prev, where_dev)
-        self._guard("prefill", rest)
+        # from position 0 no query sees a page this dispatch did not write
+        fresh = covered == 0 and padded in self._fresh_buckets
+        self._guard("prefill", rest + (fresh,))
         (vec, st), self.pages = self._prefill(self.params, self.pages,
-                                              *rest)
-        return vec, st
+                                              *rest, fresh=fresh)
+        return vec, st, fresh
 
-    def _enqueued(self, kind: str, vec, st, rows: List[tuple]) -> None:
+    def _enqueued(self, kind: str, vec, st, rows: List[tuple],
+                  fresh: bool = False) -> None:
         """A dispatch is on the device's queue: its output vector is the
         next dispatch's ``prev``, its copy to the host starts now, and its
         read waits its turn (:meth:`_read`)."""
         self._prev = vec
         vec.copy_to_host_async()
-        self._unread.append(_Unread(kind, self.stats["ticks"], vec, st, rows))
+        self._unread.append(_Unread(kind, self.stats["ticks"], vec, st, rows,
+                                    fresh))
 
     def _admit(self, completions: List[Completion]) -> int:
         budget = self.cfg.prefill_cap_tokens
@@ -1780,8 +1858,8 @@ class ServingEngine:
             with journal.span("serve/prefill", req_id=str(req.req_id),
                            prompt_len=L, padded=P, slot=slot,
                            shared=covered, resumed=len(req.committed)):
-                vec, st = self._dispatch_prefill(req, slot, covered,
-                                                 suffix, P)
+                vec, st, fresh = self._dispatch_prefill(req, slot, covered,
+                                                        suffix, P)
             budget -= P
             admitted += 1
             self.stats["prefill_tokens"] += len(suffix)
@@ -1802,7 +1880,7 @@ class ServingEngine:
                       unread=1, budget=(req.max_new_tokens
                                         or self.cfg.max_new_tokens))
             self.slots[slot] = s
-            self._enqueued("prefill", vec, st, [(slot, s)])
+            self._enqueued("prefill", vec, st, [(slot, s)], fresh)
             if self._speculator is not None:
                 self._speculator.on_admit(slot, hist, len(req.committed))
             if not self._run_ahead:
@@ -1899,6 +1977,7 @@ class ServingEngine:
                 s.gen.append(s.last_tok)
                 if first:
                     self.stats["prefill_dispatches"] += 1
+                    self.stats["prefill_fresh_dispatches"] += u.fresh
                     self.times.first_token(s.req.req_id, u.tick)
                     if self.metrics is not None:
                         self.metrics.on_first_token(s.req.req_id)

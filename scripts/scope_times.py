@@ -42,7 +42,8 @@ SCOPES = ("embed", "attn", "mlp", "head", "xent", "paged_attn",
           "lightning/step", "lightning/chunk", "lightning/out_norm",
           "lightning_step", "lightning_chunk", "sparse/compress",
           "sparse/select", "sparse_attn", "dense_attn", "mhc/pre",
-          "mhc/post", "mhc/read_out", "mhc_pre", "mhc_post")
+          "mhc/post", "mhc/read_out", "mhc_pre", "mhc_post",
+          "flash_gqa_fwd")
 NO_SCOPE = "(no scope)"
 _FIND = [(s, re.compile(r"(?<=[(/])%s(?=[)/])" % re.escape(s)))
          for s in SCOPES]

@@ -671,6 +671,68 @@ def test_serving_programs_read_the_pool_in_place(one_chip, kind, monkeypatch):
         assert re.search(r'op_name="[^"]*/paged_gather/', text)
 
 
+@pytest.mark.parametrize("bucket", [1024, 512])
+def test_gpt2_xl_prefill_from_0_attends_over_fresh_keys(one_chip, bucket,
+                                                         monkeypatch):
+    """Cell 2's prefill at both of its buckets, GPT-2 XL's 48 layers of 25
+    heads of 64 over the pool as the engine lays it out, donated, told that
+    it starts at position 0: every layer attends through ``flash_gqa_fwd``
+    (lowered ONCE: the blocks are a Python loop and the wrapper is jitted),
+    no float32 scores of the table's width exist, and the pool is written
+    (a page at a time: ``paged_scatter_fresh``) and never gathered or
+    copied."""
+    from distributed_lion_tpu.analysis.serve_check import pool_leaf_copies
+    from distributed_lion_tpu.models.gpt2 import (
+        GPT2Config, gpt2_decode_paged, gpt2_init,
+    )
+    from distributed_lion_tpu.serve.kv_cache import init_pages
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = GPT2Config(vocab_size=50257, n_layer=48, n_head=25, d_model=1600,
+                     n_ctx=1024)
+    block, per_seq = 16, 64
+
+    def place(tree):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=one_chip), tree)
+
+    params = place(jax.eval_shape(lambda: jax.tree.map(
+        lambda x: x.astype(jnp.bfloat16),       # the cell's weights: 3.1 GB
+        gpt2_init(jax.random.key(0), cfg))))
+    pages = place(jax.eval_shape(lambda: init_pages(
+        cfg.n_layer, 32 * per_seq, block, cfg.n_head, cfg.head_dim,
+        cfg.compute_dtype)))
+    assert pages[0]["k"].shape == (2048, 16, 1, 1664)
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+    def fn(params, pages, toks, tables, pos, length):
+        valid = jnp.arange(bucket)[None, :] < length
+        logits, pages = gpt2_decode_paged(params, toks, cfg, pages, tables,
+                                          pos, valid, fresh=True)
+        return jnp.argmax(logits[0, length - 1], -1), pages
+
+    t0 = time.monotonic()
+    lowered = jax.jit(fn, donate_argnums=(1,)).lower(
+        params, pages, i32(1, bucket), i32(1, per_seq), i32(1), i32())
+    assert lowered.as_text().count("tpu_custom_call") == 1
+    text = lowered.compile().as_text()
+    assert time.monotonic() - t0 < 240
+    calls = re.findall(r"%flash_gqa_fwd(?:\.\d+)? = [^\n]*custom-call", text)
+    assert len(calls) == cfg.n_layer
+    assert "input_output_alias" in text
+    assert not pool_leaf_copies(text, pages[0]["k"])
+    assert f"f32[1,25,{bucket},1024]" not in text
+    assert not re.search(r'op_name="[^"]*/paged_(gather|attn)/', text)
+    assert re.search(r'op_name="[^"]*/paged_scatter/', text)
+    # k and v of every layer are written as whole [16, 1664] pages
+    assert len(re.findall(r" scatter\([^\n]*update_window_dims=\{1,2\}",
+                          text)) == 2 * cfg.n_layer
+    # what the pool meets is the scatter alone: no gather reads 2,048 pages
+    assert not re.search(r"gather\([^\n]*bf16\[2048,16,1,1664\]", text)
+
+
 def test_mla_kernel_compiles_at_published_widths(one_chip):
     """``mla_paged_attn`` at JoyAI-LLM-Flash's widths and the cell's pool:
     32 absorbed queries of 640 lanes (576 of them the latent row) a

@@ -121,6 +121,70 @@ def test_block_is_chosen_from_T(T, block):
     assert F.block_for(T) == block
 
 
+# ------------------------------------- the forward alone, for a prefill
+def _gqa_inputs(B, T, H, KV, hd, dtype=jnp.float32, seed=0):
+    keys = jax.random.split(jax.random.key(seed), 3)
+    return [jax.random.normal(k, (B, T, n * hd), dtype)
+            for k, n in zip(keys, (H, KV, KV))]
+
+
+@pytest.mark.parametrize("T", [128, 256])
+@pytest.mark.parametrize("H", [4, 5], ids=["even", "odd"])
+def test_prefill_forward_takes_heads_of_64(H, T):
+    """``flash_gqa_fwd`` over two heads of 64 a lane block, one kv head a
+    query head; an odd count is padded with a head of zero lanes whose
+    output is sliced off (GPT-2 XL's 25 heads: 1,600 -> 1,664 lanes)."""
+    q, k, v = _gqa_inputs(2, T, H, H, 64, seed=H)
+    got = F.flash_gqa_fwd(q, k, v, H, interpret=True)
+    want = A.attention_xla(_heads(q, H), _heads(k, H), _heads(v, H))
+    assert got.shape == q.shape and got.dtype == q.dtype
+    np.testing.assert_allclose(got, want.transpose(0, 2, 1, 3).reshape(
+        q.shape), atol=2e-6)
+    assert F.gqa_kernel_takes(T, 64, q.dtype, 1)
+    assert not F.gqa_kernel_takes(T, 64, q.dtype, 2)   # grouped heads of 64
+    assert not F.gqa_kernel_takes(T + 64, 64, q.dtype, 1)
+    assert F.gqa_kernel_takes(T, 128, jnp.bfloat16, 6)
+    assert not F.gqa_kernel_takes(T, 128, jnp.float16, 6)
+
+
+def _gqa_fwd_of_pr30(q, k, v, n_head):
+    """The call ``flash_gqa_fwd`` made until PR 40, heads of 128 only."""
+    import functools
+    import math
+
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    B, T, width = q.shape
+    rep = n_head // (k.shape[2] // 128)
+    blk, nq = F.block_for(T), T // F.block_for(T)
+    kv_spec = pl.BlockSpec((None, T, 128), lambda b, j, i: (b, 0, j // rep))
+    return pl.pallas_call(
+        functools.partial(F._fwd_kernel, scale=1.0 / math.sqrt(128),
+                          head_dim=128),
+        grid=(B, n_head, nq),
+        in_specs=[pl.BlockSpec((None, blk, 128), lambda b, j, i: (b, i, j)),
+                  kv_spec, kv_spec],
+        out_specs=[pl.BlockSpec((None, blk, 128), lambda b, j, i: (b, i, j)),
+                   pl.BlockSpec((None, None, None, 1, blk),
+                                lambda b, j, i: (b, j, i, 0, 0))],
+        out_shape=[jax.ShapeDtypeStruct((B, T, width), q.dtype),
+                   jax.ShapeDtypeStruct((B, n_head, nq, 1, blk),
+                                        jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((1, blk, 128), jnp.float32)],
+        interpret=True)(q, k, v)[0]
+
+
+@pytest.mark.parametrize("T,H,KV,dtype", [(256, 4, 2, jnp.float32),
+                                          (128, 3, 1, jnp.bfloat16)],
+                         ids=["f32-rep2", "bf16-rep3"])
+def test_prefill_forward_at_heads_of_128_is_the_call_it_was(T, H, KV, dtype):
+    q, k, v = _gqa_inputs(1, T, H, KV, 128, dtype, seed=T)
+    np.testing.assert_array_equal(
+        np.asarray(F.flash_gqa_fwd(q, k, v, H, interpret=True), np.float32),
+        np.asarray(_gqa_fwd_of_pr30(q, k, v, H), np.float32))
+
+
 # ------------------------------------------------- the token-major entry
 @pytest.fixture
 def spy(monkeypatch):

@@ -1038,3 +1038,162 @@ def test_speculative_stage_rejects_bad_artifacts(tmp_path):
     # the untouched artifact still passes from the tmp copy
     p.write_text(json.dumps(good))
     assert ce.speculative_ok(str(p))
+
+# ------------------------------- a prefill over its own fresh keys (PR 40)
+def _fresh_model(family):
+    """Heads the tiled forward kernel takes: three of 64 (an odd count:
+    a head of zero lanes beside them) and two of 128 over one kv head."""
+    if family == "gpt2":
+        cfg = GPT2Config.tiny(n_head=3, d_model=192, n_ctx=256,
+                              compute_dtype=jnp.float32)
+        return ServeModel.for_gpt2(gpt2_init(jax.random.key(0), cfg), cfg)
+    cfg = LlamaConfig.tiny(n_head=2, n_kv_head=1, d_model=256, n_ctx=256,
+                           compute_dtype=jnp.float32)
+    return ServeModel.for_llama(llama_init(jax.random.key(0), cfg), cfg)
+
+
+@pytest.fixture
+def on_a_tpu(monkeypatch):
+    """The TPU's choice for a prefill on the CPU: ``flash_gqa_fwd`` in
+    interpret mode, its calls recorded (one a layer a traced program). The
+    decode tick keeps the gather path: only the prefill is under test."""
+    from distributed_lion_tpu.ops import attention as attn_ops
+    from distributed_lion_tpu.ops import pallas_flash_attn
+
+    calls = []
+    real = pallas_flash_attn.flash_gqa_fwd
+
+    def kernel(*a, **kw):
+        calls.append(a[0].shape)
+        return real(*a, interpret=True, **kw)
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(pallas_flash_attn, "flash_gqa_fwd", kernel)
+    monkeypatch.setattr(attn_ops, "paged_kernel_applies",
+                        lambda *a, **k: False)
+    return calls
+
+
+def _prompts(vocab, lens, seed=5):
+    rng = np.random.default_rng(seed)
+    return [list(map(int, rng.integers(1, vocab, n))) for n in lens]
+
+
+@pytest.mark.parametrize("shape", ["engine_pool", "head_major_pool",
+                                   "window_off_whole_pages"])
+def test_fresh_scatter_writes_the_cells_the_row_scatter_writes(shape):
+    """``paged_scatter_fresh`` (whole pages, then the page a prompt ends in
+    row by row) against ``paged_scatter_kv`` from position 0, bytewise,
+    over a pool full of stale rows: prompts that end inside a page, on a
+    page's edge, inside the first page, at the window's end, an empty lane,
+    and a row whose table is all sentinel."""
+    from distributed_lion_tpu.ops.attention import (
+        paged_scatter_fresh, paged_scatter_kv,
+    )
+
+    rng = np.random.default_rng(3)
+    KV, hd, nb, per = 3, 8, 40, 6
+    bs, S = (5, 32) if shape == "window_off_whole_pages" else (8, 32)
+    G, W = (KV, hd) if shape == "head_major_pool" else (1, 128)
+    pages = jnp.asarray(rng.standard_normal((nb, bs, G, W)), jnp.float32)
+    tables = rng.permutation(nb)[:6 * per].reshape(6, per).astype(np.int32)
+    tables[5] = nb                                   # never allocated
+    tables[1, 3:] = nb                               # pages for 24 tokens
+    lengths = np.asarray([13, 24, 3, 32, 0, 9])
+    new = jnp.asarray(rng.standard_normal((6, S, KV, hd)), jnp.float32)
+    valid = jnp.arange(S)[None, :] < jnp.asarray(lengths)[:, None]
+    tables = jnp.asarray(tables)
+    for mask in (valid, None):
+        want = paged_scatter_kv(pages, tables, jnp.zeros((6,), jnp.int32),
+                                new, mask)
+        got = jax.jit(paged_scatter_fresh)(pages, tables, new, mask)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    assert not np.array_equal(np.asarray(want), np.asarray(pages))
+
+
+@pytest.mark.parametrize("family", ["gpt2", "llama"])
+def test_prefill_from_0_attends_over_its_fresh_keys(family, request):
+    """A prefill that starts at position 0, in a bucket the kernel takes,
+    never gathers: the tokens are the gather path's, and so are the pages
+    (the first layer's bit for bit: the same projections scattered to the
+    same cells; a deeper layer's within the kernel's rounding of the
+    layer before)."""
+    model = _fresh_model(family)
+    scfg = ServeConfig(max_seqs=2, block_size=64, max_blocks_per_seq=2,
+                       temperature=0.0)
+
+    def run():
+        eng = ServingEngine(model, scfg)
+        out = eng.run([Request(f"r{i}", toks, 5, seed=i) for i, toks in
+                       enumerate(_prompts(model.cfg.vocab_size, (100, 70)))])
+        return eng, out
+
+    gather, want = run()
+    assert gather.stats["prefill_fresh_dispatches"] == 0     # the CPU
+    assert gather._fresh_buckets == frozenset()
+    calls = request.getfixturevalue("on_a_tpu")
+    fresh, got = run()
+    assert calls == [(1, 128, model.cfg.n_head * model.head_dim)] \
+        * model.n_layer                    # ONE program, the 128 bucket's
+    assert fresh._fresh_buckets == {128}
+    assert fresh.stats["prefill_fresh_dispatches"] == 2 \
+        == fresh.stats["prefill_dispatches"]
+    for rid in want:
+        assert got[rid].tokens == want[rid].tokens, rid
+    for layer, (a, b) in enumerate(zip(fresh.pages, gather.pages)):
+        for leaf in ("k", "v"):
+            if layer == 0:
+                np.testing.assert_array_equal(a[leaf], b[leaf])
+            else:
+                np.testing.assert_allclose(a[leaf], b[leaf], atol=1e-5)
+
+
+def test_only_a_prefill_from_0_takes_the_fresh_path(on_a_tpu, capsys):
+    """A prefill behind a shared prefix sees pages it did not write and
+    keeps the gather path, so under ``prefix_cache`` a bucket the kernel
+    takes may compile two programs: the budget says so, the run reaches it
+    and the retrace guard stays silent. The counter counts the prefills
+    that attended over fresh keys: from 0, in a bucket the kernel takes."""
+    model = _fresh_model("gpt2")
+    eng = ServingEngine(model, ServeConfig(
+        max_seqs=1, block_size=64, max_blocks_per_seq=4, num_blocks=16,
+        temperature=0.0, prefix_cache=True, retrace_guard="error"))
+    assert "[setup] prefill: fresh keys, flash_gqa_fwd x2 (from position 0 " \
+        "in buckets 128-256; every other prefill gathers)" \
+        in capsys.readouterr().err
+    assert eng._fresh_buckets == {128, 256}
+    assert eng.compile_budget()["prefill"] == 3 + 2
+    a, b, c, d, e = _prompts(model.cfg.vocab_size, (100, 200, 60, 100, 136))
+    prompts = [c[:40],        # from 0, bucket 64: off a multiple of 128
+               a, b,          # from 0, buckets 128 and 256: fresh
+               a[:64] + c,    # behind a's first page, bucket 64
+               a[:64] + d,    # ... bucket 128
+               b[:64] + e]    # behind b's first page, bucket 256
+    for i, toks in enumerate(prompts):     # one at a time: a's pages are
+        eng.run([Request(f"r{i}", toks, 2, seed=i)])      # cached by then
+    st = eng.stats
+    assert st["prefix_hits"] == 3 and st["shared_tokens"] == 3 * 64
+    assert st["prefill_dispatches"] == 6
+    assert st["prefill_fresh_dispatches"] == 2
+    assert eng.compile_counts()["prefill"] == eng.compile_budget()["prefill"]
+    assert st["serve_retraces"] == 0
+    assert sorted(s[1] for s in on_a_tpu) == [128, 128, 256, 256]
+
+
+def test_a_speculative_verify_keeps_the_gather_path(on_a_tpu):
+    model = _fresh_model("gpt2")
+    eng = ServingEngine(model, ServeConfig(
+        max_seqs=1, block_size=64, max_blocks_per_seq=4, temperature=0.0,
+        speculate="ngram:2"))
+    phrase = _prompts(model.cfg.vocab_size, (10,))[0]
+    eng.run([Request("r", phrase * 10, 8, seed=0)])
+    assert eng.stats["spec_rounds"] > 0
+    assert eng.stats["prefill_fresh_dispatches"] == 1
+    # the prefill's two layers, and nothing from the verify window
+    assert on_a_tpu == [(1, 128, 192)] * 2
+
+
+def test_a_gather_engine_says_so(capsys):
+    ServingEngine(_fresh_model("llama"), ServeConfig(
+        max_seqs=1, block_size=64, max_blocks_per_seq=2))
+    assert "[setup] prefill: gather\n" in capsys.readouterr().err

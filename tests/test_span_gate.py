@@ -339,6 +339,9 @@ def _scopes(text):
     ("jit(prefill)/moe/shared/mlp/dot_general:", "mlp"),
     ("jit(prefill)/mhc/pre/jit(mhc_pre)/pallas_call:", "mhc_pre"),
     ("jit(decode_tick)/mhc/post/concatenate:", "mhc/post"),
+    ("jit(prefill)/attn/jit(flash_gqa_fwd)/jit(_pad)/pad:", "flash_gqa_fwd"),
+    ("jit(prefill)/attn/jit(flash_gqa_fwd)/flash_gqa_fwd/pallas_call:",
+     "flash_gqa_fwd"),
     ("pages[45]['k']:", "(no scope)"),
     ("jit(train_step)/headroom/attnx/add:", "(no scope)"),
     ("", "(no scope)"),
