@@ -1215,18 +1215,27 @@ def phase_serve_sparse() -> None:
     check(stats["sparse_rows"] > 0 and stats["dense_rows"] > 0, stats)
     walked = stats["kv_pages_read"] * cfg.n_kv_head * len(cfg.sparse_layers)
     check(0 < stats["kv_pages_selected"] < walked, stats)
+    # a copy a block of four pages a leaf (2 a page by the page: 0.5 / 2.0;
+    # a list's last block is partly filled, so a little over a half)
+    run = engine.tables.run_pages
+    ratio = stats["kv_copies"] / stats["kv_pages_selected"]
+    check(run == cfg.sparse.block_size // block == 4, run)
+    check(0.5 <= ratio < 0.7, f"kv_copies / kv_pages_selected {ratio}")
     log(f"  state_rows_stepped {stats['state_rows_stepped']} (= decode "
         f"tokens x {states} Lightning layers), state_resets "
         f"{stats['state_resets']} over {n_seq} slots; sparse_rows "
         f"{stats['sparse_rows']}, dense_rows {stats['dense_rows']}, "
         f"kv_pages_selected {stats['kv_pages_selected']} of {walked} a "
-        f"walk of every page would hand attention, ck_rows_written "
-        f"{stats['ck_rows_written']}")
+        f"walk of every page would hand attention, kv_copies "
+        f"{stats['kv_copies']} ({ratio:.3f} a selected page; pages minted "
+        f"in runs of {run}), ck_rows_written {stats['ck_rows_written']}")
 
     sparse_at_size()
+    sparse_walk_at_size()
     lightning_at_size()
-    tables = jnp.arange(n_seq * max_blocks, dtype=jnp.int32)[::-1].reshape(
-        n_seq, max_blocks)
+    # a block's four pages an aligned run, as the engine mints them
+    heads = jnp.arange(n_seq * max_blocks // run, dtype=jnp.int32)[::-1] * run
+    tables = (heads[:, None] + jnp.arange(run)).reshape(n_seq, max_blocks)
     slots = jnp.arange(n_seq, dtype=jnp.int32)
     f32 = jnp.float32
     cfg32 = dataclasses.replace(cfg, param_dtype=f32, compute_dtype=f32)
@@ -1288,7 +1297,9 @@ def sparse_at_size() -> None:
             k = k.at[0, at, g].set(0.5 * centre[g])
             v = v.at[0, at, g].set(mark[g])
     q, k, v = (x.astype(jnp.bfloat16) for x in (q, k, v))
-    tables = jax.random.permutation(ks[5], nb).astype(jnp.int32)[None]
+    # a block's four pages an aligned run, the runs scattered
+    tables = (jax.random.permutation(ks[5], nb // 4).astype(jnp.int32)[:, None]
+              * 4 + jnp.arange(4)).reshape(1, nb)
     zero = jnp.zeros((1,), jnp.int32)
     pool = jnp.zeros((nb, bs, 1, KV * hd), jnp.bfloat16)
     k_pages = paged_scatter_kv(pool, tables, zero, k)
@@ -1304,21 +1315,21 @@ def sparse_at_size() -> None:
         lists, held, sparse = ss.decode_page_lists(
             q1, ck, tables, pos, jnp.asarray([True]), sp, KV, bs)
         out = ss.sparse_decode_attention(q1, k_pages, v_pages, lists, held,
-                                         KV)
+                                         KV, sp.block_size // bs)
         return lists, held, out
 
     lists, held, out = decode(q1, k_pages, v_pages, ck)
     check(bool((held == 63 * 64 + 64).all()), f"lists hold {held}")
-    table = np.asarray(tables[0])
+    check(lists.shape == (1, KV, 128), lists.shape)   # dense_len / 64 wide
+    runs = np.asarray(tables[0, ::4]) // 4       # a block's run of the pool
     for g in range(KV):
         mine = set(np.asarray(lists[0, g]).tolist())
         for b in planted[g]:
-            check(set(table[4 * b:4 * b + 4]) <= mine,
+            check(runs[b] in mine,
                   f"kv head {g}'s list lacks its planted block {b}")
-        other = [b for b in planted[1 - g]
-                 if set(table[4 * b:4 * b + 4]) <= mine]
+        other = [b for b in planted[1 - g] if runs[b] in mine]
         check(len(other) < 3, f"kv head {g}'s list is the other head's")
-        check(set(table[:4]) <= mine and set(table[-128:]) <= mine,
+        check(runs[0] in mine and set(runs[-32:]) <= mine,
               "the first block or the local window is missing")
 
     f32 = jnp.float32
@@ -1369,6 +1380,76 @@ def sparse_at_size() -> None:
     log(f"  prefill attention of one minicpm4 layer at 16,384 (dense to "
         f"8,192 by the tiled kernel, masked tiles past it): last query vs "
         f"masked dense {last:.4f}; {1e3 * (time.time() - t0):.1f} ms a layer")
+
+
+def sparse_walk_at_size() -> None:
+    """``paged_attn`` alone at the shape of cell 8's decode tick (64 rows x 2
+    kv heads = 128 lists of the 64 best blocks of 64 positions at 16-20k
+    contexts, 32 query heads of 128, a pool of 81,920 pages of 16 x 256
+    lanes), under both views of the same pool: lists of 253-256 PAGES of 16,
+    the pages strewn over the pool one by one (what single-page minting
+    leaves after the first minutes) and then the same blocks as aligned
+    runs of four; and lists of 64 RUNS over the pool viewed ``[20480, 64, 1,
+    256]``. The last two read the same bytes and must agree bit for bit.
+    Timed, with the copies each starts."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from distributed_lion_tpu.ops.pallas_paged_attn import paged_attn
+
+    B, H, KV, hd, bs, r, K = 64, 32, 2, 128, 16, 4, 64
+    NB, per = 81920, 1280
+    rng = np.random.default_rng(41)
+    ks = jax.random.split(jax.random.key(4141), 3)
+    k_pages, v_pages = (
+        jax.random.normal(ks[i], (NB, bs, 1, KV * hd), jnp.bfloat16)
+        for i in range(2))
+    q = jax.random.normal(ks[2], (B * KV, H, hd), jnp.bfloat16)
+    pos = rng.integers(16384, 20480, B)               # the tick's positions
+    own = pos // 64
+    blocks = np.empty((B, KV, K), np.int64)
+    for b in range(B):
+        for g in range(KV):        # first block, the local 32, 31 of the rest
+            far = rng.choice(np.arange(1, own[b] - 31), K - 33, replace=False)
+            blocks[b, g] = np.sort(np.concatenate(
+                [[0], far, np.arange(own[b] - 31, own[b] + 1)]))
+    held = np.repeat(((K - 1) * 64 + pos % 64 + 1)[:, None], KV, 1)
+    lengths = jnp.asarray(held.reshape(-1), jnp.int32)
+    run_tables = (rng.permutation(NB // r)[:, None] * r
+                  + np.arange(r)).reshape(B, per)      # aligned runs
+    page_tables = rng.permutation(NB).reshape(B, per)  # a page at a time
+    at = (blocks[..., None] * r + np.arange(r)).reshape(B, KV, K * r)
+    rows = np.arange(B)[:, None, None]
+    walks = {
+        "pages of 16, strewn": (k_pages, v_pages, page_tables[rows, at]),
+        "pages of 16, in runs": (k_pages, v_pages, run_tables[rows, at]),
+        "runs of 64": (k_pages.reshape(NB // r, r * bs, 1, -1),
+                       v_pages.reshape(NB // r, r * bs, 1, -1),
+                       run_tables[rows, blocks * r] // r)}
+    outs = {}
+    for name, (kp, vp, lists) in walks.items():
+        lists = jnp.asarray(lists.reshape(B * KV, -1), jnp.int32)
+        page = kp.shape[1]
+        copies = 2 * int((-(-held // page)).sum())
+        call = jax.jit(lambda q, kp, vp, lists: paged_attn(
+            q, kp, vp, lists, lengths, kv_heads=KV))
+        outs[name] = jax.block_until_ready(call(q, kp, vp, lists))
+        t0, n = time.time(), 50
+        for _ in range(n):
+            got = call(q, kp, vp, lists)
+        jax.block_until_ready(got)
+        ms = 1e3 * (time.time() - t0) / n
+        moved = copies * page * KV * hd * 2
+        log(f"  paged_attn alone, 128 lists of 64 blocks, {name}: lists of "
+            f"{lists.shape[1]}, kv_copies {copies} of "
+            f"{page * KV * hd * 2} B a layer; {ms:.3f} ms a call = "
+            f"{1e6 * ms / copies:.1f} ns a copy, {moved / ms / 1e6:.0f} GB/s "
+            f"moved")
+    check(bool((outs["runs of 64"] == outs["pages of 16, in runs"]).all()),
+          "the walk by runs is not bit for bit the walk by pages")
+    check(bool(jnp.isfinite(outs["runs of 64"].astype(jnp.float32)).all()),
+          "the walk by runs is not finite")
 
 
 def lightning_at_size() -> None:

@@ -19,7 +19,9 @@ pairs). No biases. Two mixers (``mixer_types``):
   prompt's TRUE length, a decode tick the one its position closes. A query
   past ``dense_len`` attends the ``topk`` blocks it selects, a set a kv
   head; one at or under it, every position. Decode (S = 1) runs
-  ``paged_attn`` over each (row, kv head)'s compacted page list.
+  ``paged_attn`` over each (row, kv head)'s compacted list of blocks, a
+  block being an aligned run of pages (the engine's tables are minted so:
+  ``ServeModel.page_run``).
 - **``lightning-attn``: linear attention with a constant decay a head**
   (``ops/lightning``): q, k, v of ``n_head`` heads, q and k RMS-normed a
   head with a gain, RoPE over the whole head, ``S <- lambda_h S + k v^T``,
@@ -78,10 +80,12 @@ SPARSE, LIGHTNING = "minicpm4", "lightning-attn"
 
 # what a dispatch counts under ``return_moe_stats`` (int32, summed over the
 # ``minicpm4`` layers; the name is the engine's, there are no experts): the
-# (page, kv head) pairs the decode lists hand attention, the live decode rows
+# (page, kv head) pairs the decode lists hand attention, the copies the walk
+# over them starts (a list's entries x the k and the v leaf: 2 a pair where
+# an entry is a page, 0.5 where it is a block of four), the live decode rows
 # past and at or under ``dense_len``, and the compressed keys written
-SALA_COUNTERS = ("kv_pages_selected", "sparse_rows", "dense_rows",
-                 "ck_rows_written")
+SALA_COUNTERS = ("kv_pages_selected", "kv_copies", "sparse_rows",
+                 "dense_rows", "ck_rows_written")
 
 
 def lightning_slopes(n_head: int) -> jnp.ndarray:
@@ -275,10 +279,12 @@ def _sparse_block(u, p, cfg: MiniCPMSalaConfig, c, tables, pos, lengths,
         # one program holds rows on both sides of dense_len; the kernel
         # inside either region reads ``paged_attn``
         with jax.named_scope("sparse_attn"):
-            out = sparse_decode_attention(q[:, 0], k_pages, v_pages, lists,
-                                          held, KV)[:, None]
+            out = sparse_decode_attention(
+                q[:, 0], k_pages, v_pages, lists, held, KV,
+                sp.block_size // bs)[:, None]
         count.update(
             kv_pages_selected=((held + bs - 1) // bs).sum().astype(jnp.int32),
+            kv_copies=2 * (-(-held // sp.block_size)).sum().astype(jnp.int32),
             sparse_rows=(live & sparse).sum().astype(jnp.int32),
             dense_rows=(live & ~sparse).sum().astype(jnp.int32),
             ck_rows_written=closed.sum().astype(jnp.int32))

@@ -5,11 +5,12 @@ is never gathered: the block tables and each row's token count ride in as
 scalar-prefetched operands, and row ``b`` walks its own
 ``ceil(length / block_size)`` pages only, copying each page (all its kv
 heads, one contiguous ``[block_size, W]`` slab) from HBM into a
-double-buffered VMEM block of ``PAGES_PER_BLOCK`` pages while the block
+double-buffered VMEM block of :func:`pages_per_block` pages while the block
 before it is computed. Online softmax in float32 (running max, sum and
-accumulator), operands in the cache dtype, probabilities cast to the value
-dtype before the value contraction: the gather path's arithmetic
-(``ops/attention.paged_decode_attention``), blockwise.
+accumulator) over ``PART_TOKENS`` positions at a time, operands in the cache
+dtype, probabilities cast to the value dtype before the value contraction:
+the gather path's arithmetic (``ops/attention.paged_decode_attention``),
+blockwise.
 
 Heads without a head loop. A page row holds its kv heads side by side
 (``W = KV * hd`` lanes, padded to 128: ``serve/kv_cache.pool_row_width``),
@@ -22,6 +23,21 @@ head's lanes: each score is exactly its own head's dot product) and
 ``hd`` lanes. GQA is the same thing with ``kv(h) = h // (H // KV)``: no
 ``jnp.repeat``. The MXU does H x W where H x hd would do; at one query a
 head the page's weight tile is loaded either way.
+
+A page is whatever ``block_size`` the operand has. A caller whose table
+names aligned runs of ``r`` pages (``serve/kv_cache``'s runs;
+``ops/sparse_select``'s block lists) hands the same pool viewed as
+``[num_blocks / r, r * block_size, 1, W]`` and run ids for page ids, and
+starts a quarter of the copies at ``r = 4``. Two sizes are kept apart. What
+is COPIED ahead is a block of 16 pages whatever their length (up to 1,024
+positions): the copies in flight have to cover the DMA's latency, and 16
+pages of 8 KB do not (cell 8's walk alone read 2.27 ms a layer by the page;
+by runs of 32 KB 1.41 ms with 4 a block and 0.92 with 16: PERF.md section
+6, PR 41). What the softmax
+TAKES at a time is 256 positions whatever the page: a block of pages of 16
+is one part as it always was, a block of runs of 64 is four, so the walk by
+runs updates max, sum and accumulator over the same partitions in the same
+order and its output is bit for bit the walk by pages.
 
 A window layer's walk (``starts``, optional): the caller hands the pages of
 a row's window in logical order (a bounded ring, rotated:
@@ -49,12 +65,34 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 LANES = 128
-PAGES_PER_BLOCK = 16  # pages a compute block: 256 tokens at block_size 16;
+PAGES_PER_BLOCK = 16  # pages a block, copied while the one before computes:
 # k and v, two slots each, are 4 x 16 x 53 KB = 3.4 MB of VMEM at GPT-2 XL,
 # 8.4 MB at 32 heads of 128. On the chip at cell 2's shapes 4 / 8 / 16 / 32
-# pages read 329 / 253 / 247 / 248 us a call (my chip run, PR 24)
+# pages read 329 / 253 / 247 / 248 us a call (my chip run, PR 24); at cell
+# 8's, runs of 64 rows x 256 lanes, 4 / 8 / 16 / 32 read 1.406 / 1.005 /
+# 0.917 / 0.872 ms (my chip run, PR 41): what is in flight while a block
+# computes has to cover the DMA's latency, some 1 MB a leaf here
+BLOCK_TOKENS = 1024   # ... but no more positions a block than this
+PART_TOKENS = 256     # positions the online softmax takes at a time: 16 pages
+# of 16, one block; of a block of larger pages, a part (so the walk by runs
+# is bit for bit the walk by pages of 16; a block of runs taken whole read
+# 0.751 ms where four parts read 0.917: the price of that)
 Q_ROWS = 16          # query heads pad to whole bf16 sublane tiles
 MASKED = -1e30       # the gather path's mask value
+
+
+def pages_per_block(block_size: int) -> int:
+    """Pages a block holds: ``PAGES_PER_BLOCK``, or as many as
+    ``BLOCK_TOKENS`` positions fill where the pages are long."""
+    return min(PAGES_PER_BLOCK, max(BLOCK_TOKENS // block_size, 1))
+
+
+def parts_per_block(block_size: int) -> int:
+    """Parts of ``PART_TOKENS`` positions (whole pages) the softmax takes a
+    block in; 1 where a block holds no more, or no whole number of them."""
+    pages = pages_per_block(block_size)
+    part = max(PART_TOKENS // block_size, 1)
+    return 1 if pages % part else pages // part
 
 
 def kernel_takes(pool_shape, dtype) -> bool:
@@ -69,14 +107,15 @@ def kernel_takes(pool_shape, dtype) -> bool:
 
 
 def _kernel(lens_ref, tables_ref, *refs, scale: float, table_width: int,
-            windowed: bool):
+            windowed: bool, parts: int):
     if windowed:
         starts_ref, *refs = refs
     q_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf, sems, acc_ref, ahead_ref = refs
     b = pl.program_id(0)
     last_row = pl.num_programs(0) - 1
     n_slots, pages, bs, width = k_buf.shape
-    tokens = pages * bs
+    part = pages // parts              # pages the softmax takes at a time
+    tokens = part * bs
 
     def pages_of(row):
         return (lens_ref[row] + bs - 1) // bs
@@ -126,7 +165,6 @@ def _kernel(lens_ref, tables_ref, *refs, scale: float, table_width: int,
         block_copies(b, n_pages, 0, 0)
 
     def body(blk, carry):
-        m_prev, l_prev = carry
         slot = (first_slot + blk) % n_slots
         other = (slot + 1) % n_slots
 
@@ -140,21 +178,34 @@ def _kernel(lens_ref, tables_ref, *refs, scale: float, table_width: int,
             ahead_ref[0] = other + 1
 
         block_copies(b, n_pages, blk, slot, wait=True)
-        k = k_buf[slot].reshape(tokens, width)
-        s = jax.lax.dot_general(q_ref[...], k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        t_idx = blk * tokens + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        keep = t_idx < length
-        if windowed:   # rows of the walk's first page before the window
-            keep = jnp.logical_and(keep, t_idx >= starts_ref[b])
-        s = jnp.where(keep, s, MASKED)
-        m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new)
-        v = v_buf[slot].reshape(tokens, width)
-        acc_ref[...] = alpha * acc_ref[...] + jnp.dot(
-            p.astype(v.dtype), v, preferred_element_type=jnp.float32)
-        return m_new, alpha * l_prev + p.sum(axis=1, keepdims=True)
+        for j in range(parts):
+            # a part past the row's end is all masked: its probabilities
+            # are 0 and it leaves the running max, sum and accumulator be
+            m_prev, l_prev = carry
+
+            def rows_of(buf):
+                got = buf[slot] if parts == 1 \
+                    else buf[slot, j * part:(j + 1) * part]
+                return got.reshape(tokens, width)
+
+            k = rows_of(k_buf)
+            s = jax.lax.dot_general(
+                q_ref[...], k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale
+            first = blk * tokens if parts == 1 else (blk * parts + j) * tokens
+            t_idx = first + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+            keep = t_idx < length
+            if windowed:   # rows of the walk's first page before the window
+                keep = jnp.logical_and(keep, t_idx >= starts_ref[b])
+            s = jnp.where(keep, s, MASKED)
+            m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            p = jnp.exp(s - m_new)
+            v = rows_of(v_buf)
+            acc_ref[...] = alpha * acc_ref[...] + jnp.dot(
+                p.astype(v.dtype), v, preferred_element_type=jnp.float32)
+            carry = m_new, alpha * l_prev + p.sum(axis=1, keepdims=True)
+        return carry
 
     rows = q_ref.shape[0]
     _, l = jax.lax.fori_loop(
@@ -203,6 +254,7 @@ def paged_attn(q, k_pages, v_pages, tables, lengths, starts=None, *,
     nb = tables.shape[1]
     q_bd = _spread_heads(q, kv_heads, W)
     rows = q_bd.shape[1]
+    pages = pages_per_block(bs)
     row_spec = pl.BlockSpec((None, rows, W), lambda b, *_: (b, 0, 0))
     scalars = (lengths.astype(jnp.int32),
                tables.reshape(-1).astype(jnp.int32))
@@ -212,7 +264,8 @@ def paged_attn(q, k_pages, v_pages, tables, lengths, starts=None, *,
         o_bd = pl.pallas_call(
             functools.partial(_kernel, scale=1.0 / math.sqrt(hd),
                               table_width=nb,
-                              windowed=starts is not None),
+                              windowed=starts is not None,
+                              parts=parts_per_block(bs)),
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=len(scalars),
                 grid=(B,),
@@ -221,8 +274,8 @@ def paged_attn(q, k_pages, v_pages, tables, lengths, starts=None, *,
                           pl.BlockSpec(memory_space=pl.ANY)],
                 out_specs=row_spec,
                 scratch_shapes=[
-                    pltpu.VMEM((2, PAGES_PER_BLOCK, bs, W), k_pages.dtype),
-                    pltpu.VMEM((2, PAGES_PER_BLOCK, bs, W), v_pages.dtype),
+                    pltpu.VMEM((2, pages, bs, W), k_pages.dtype),
+                    pltpu.VMEM((2, pages, bs, W), v_pages.dtype),
                     pltpu.SemaphoreType.DMA((2, 2)),
                     pltpu.VMEM((rows, W), jnp.float32),
                     pltpu.SMEM((1,), jnp.int32),

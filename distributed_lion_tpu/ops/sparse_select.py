@@ -24,13 +24,18 @@ block_size`` up to the query's own are forced (+inf); the best ``topk`` are
 kept, ties to the lower index, a set a kv head. A query at ``p + 1 <=
 dense_len`` sees every position.
 
-**Decode** (:func:`sparse_decode_attention`): the selected blocks' pages of
-each (row, kv head) as one compacted list, ascending, the query's own partly
+**Decode** (:func:`sparse_decode_attention`): the selected blocks of each
+(row, kv head) as one compacted list, ascending, the query's own partly
 filled block last, and ``lengths`` the positions the list holds; the layer
 has no RoPE, so attention over that list IS ``ops/pallas_paged_attn``'s walk
 (``paged_decode_attention``, kernel or gather path) with the list for a
-table. A row at or under ``dense_len`` gets its own table's head as the list
-of both kv heads: one program holds both kinds of row.
+table. A block's pages are an aligned run of the pool (the engine mints them
+so for this family: ``serve/kv_cache``), so the list names runs and the
+walk reads the pool as ``[runs, block_size, 1, W]``: a block is one copy of
+32 KB where its four pages were four of 8 KB, and the kernel was bound by
+how many copies it starts (PERF.md section 6, PR 41). A row at or under
+``dense_len`` gets its own table's blocks as the list of both kv heads: one
+program holds both kinds of row.
 
 **Prefill** (:func:`sparse_prefill_attention`): the positions up to
 ``dense_len`` through ``banded_causal_attention`` (the tiled kernel on a
@@ -227,12 +232,16 @@ def gather_compressed(ck, tables):
 @jax.named_scope("sparse/select")
 def decode_page_lists(q, ck, tables, pos, live, sp: SparseConfig,
                       kv_heads: int, page: int):
-    """The compacted page list of every (row, kv head) of a decode tick.
-    ``q [B, H, hd]`` at positions ``pos [B]`` (keys and compressed keys
-    already written); ``tables [B, nb]`` over pages of ``page`` positions.
-    Returns (``lists [B, KV, Wd]`` page ids, the sentinel ``num_blocks`` past
-    a list's end; ``lengths [B, KV]`` the positions each list holds, 0 on a
-    dead row; ``sparse [B]`` bool: the row is past ``dense_len``)."""
+    """The compacted list of every (row, kv head) of a decode tick, by
+    BLOCKS: a block's ``r = block_size / page`` pages are an aligned run of
+    the pool (``serve/kv_cache``'s runs, which the engine mints for this
+    family), so a block is named by its run, ``first page // r``, and a list
+    is a quarter as long as its pages at ``r = 4``. ``q [B, H, hd]`` at
+    positions ``pos [B]`` (keys and compressed keys already written);
+    ``tables [B, nb]`` over pages of ``page`` positions. Returns (``lists [B,
+    KV, Wd]`` run ids, ``num_blocks // r`` past a list's end; ``lengths [B,
+    KV]`` the positions each list holds, 0 on a dead row; ``sparse [B]``
+    bool: the row is past ``dense_len``)."""
     NB = ck.shape[0]
     B, H, hd = q.shape
     nb = tables.shape[1]
@@ -250,35 +259,48 @@ def decode_page_lists(q, ck, tables, pos, live, sp: SparseConfig,
     )(q, rows, pos)
     idx, count = idx[:, 0], count[:, 0]                  # [B, KV, K], [B, KV]
     K = idx.shape[-1]
-    width = min(nb, max(K * r, sp.dense_len // page))
-    at = (idx[..., None] * r + jnp.arange(r)).reshape(B, kv_heads, K * r)
+    heads = tables[:, ::r]     # each block's first page: [B, ceil(nb / r)]
+    width = min(heads.shape[1], max(K, sp.dense_len // sp.block_size))
     picked = jnp.take_along_axis(
-        jnp.broadcast_to(tables[:, None], (B, kv_heads, nb)),
-        jnp.minimum(at, nb - 1), axis=2)
-    picked = jnp.where(at < n_blocks * r, picked, NB)
-    picked = jnp.pad(picked, ((0, 0), (0, 0), (0, width - K * r)),
+        jnp.broadcast_to(heads[:, None], (B, kv_heads, heads.shape[1])),
+        jnp.minimum(idx, heads.shape[1] - 1), axis=2)
+    picked = jnp.where(idx < n_blocks, picked, NB)
+    picked = jnp.pad(picked, ((0, 0), (0, 0), (0, width - K)),
                      constant_values=NB)
     sparse = pos + 1 > sp.dense_len
     # the last kept block is the query's own (the local window forces it)
     held = (count - 1) * sp.block_size + (pos % sp.block_size)[:, None] + 1
     lists = jnp.where(sparse[:, None, None], picked,
-                      tables[:, None, :width])
+                      heads[:, None, :width]) // r
     lengths = jnp.where(sparse[:, None], held, (pos + 1)[:, None])
     return lists, jnp.where(live[:, None], lengths, 0), sparse
 
 
+def by_runs(pages, r: int):
+    """A pool leaf ``[num_blocks, page, 1, W]`` as runs of ``r`` pages,
+    ``[num_blocks // r, r * page, 1, W]``: row-major, so a bitcast (pages
+    past the last whole run, which the allocator never hands out, are cut
+    off: a copy, and only where the pool is not whole runs)."""
+    NB, page = pages.shape[:2]
+    if NB % r:
+        pages = pages[:NB // r * r]
+    return pages.reshape((NB // r, r * page) + pages.shape[2:])
+
+
 def sparse_decode_attention(q, k_pages, v_pages, lists, lengths,
-                            kv_heads: int):
+                            kv_heads: int, run_pages: int = 1):
     """One query token a row over each (row, kv head)'s own list. ``q [B,
-    H, hd]``; ``lists [B, KV, Wd]``; ``lengths [B, KV]``. Returns ``[B, H,
-    hd]`` in q's dtype. Each list is walked with every head of the row (the
-    page rows hold both kv heads' lanes) and its own kv head's ``H / KV``
-    heads are kept."""
+    H, hd]``; ``lists [B, KV, Wd]`` naming runs of ``run_pages`` pages
+    (:func:`decode_page_lists`); ``lengths [B, KV]``. Returns ``[B, H, hd]``
+    in q's dtype. Each list is walked with every head of the row (the page
+    rows hold both kv heads' lanes) and its own kv head's ``H / KV`` heads
+    are kept. The walk is ``paged_decode_attention``'s over the pool viewed
+    :func:`by_runs`: a run is its page, one copy of the kernel's."""
     B, H, hd = q.shape
     KV = kv_heads
     q2 = jnp.broadcast_to(q[:, None], (B, KV, H, hd)).reshape(B * KV, H, 1, hd)
     out = paged_decode_attention(
-        q2, k_pages, v_pages,
+        q2, by_runs(k_pages, run_pages), by_runs(v_pages, run_pages),
         lists.reshape(B * KV, -1), lengths.reshape(-1) - 1, kv_heads=KV)
     out = out[:, :, 0].reshape(B, KV, KV, H // KV, hd)
     own = jnp.arange(KV)

@@ -523,7 +523,8 @@ class ServeModel:
                  shardable: bool = True, window: int = 0,
                  window_layers: tuple = (), state_layers: tuple = (),
                  state_leaves: Optional[Dict[str, tuple]] = None,
-                 setup_note: str = "", fresh_prefill: bool = False):
+                 setup_note: str = "", fresh_prefill: bool = False,
+                 page_run: int = 0):
         self.family = family
         self.cfg = cfg
         self.params = params
@@ -570,6 +571,13 @@ class ServeModel:
         # them too
         self.state_layers = tuple(state_layers)
         self.state_leaves = dict(state_leaves or {})
+        # positions the family's reader copies as one (a selection block):
+        # the engine mints the pages that hold them as an aligned run of
+        # the pool, ``page_run // block_size`` pages
+        # (serve/kv_cache.BlockTables ``run_pages``), which is what makes
+        # them one contiguous slab of every leaf. 0: by the page. From the
+        # family's own config, never a user's knob
+        self.page_run = page_run
         # the family's own ``[setup]`` line, printed at engine build with
         # ``{slots}`` and ``{state_gb}`` filled in
         self.setup_note = setup_note
@@ -779,6 +787,7 @@ class ServeModel:
             moe_counters=SALA_COUNTERS, last_logit=True, shardable=False,
             state_layers=cfg.lightning_layers,
             state_leaves={"state": ((H, hd, hd), jnp.float32)},
+            page_run=sp.block_size,
             setup_note=(
                 f"sparse: top {sp.topk} of blocks of {sp.block_size}, dense "
                 f"to {sp.dense_len}, ck a page; lightning: "
@@ -1047,9 +1056,13 @@ class ServingEngine:
         self.params = params
         setup.lap("setup/place_weights")  # quantize, mesh, shard
 
-        self.tables = BlockTables(cfg.resolved_num_blocks(), cfg.block_size,
-                                  cfg.max_seqs, cfg.max_blocks_per_seq,
-                                  groups=groups)
+        # the family's run in pages (a page that does not divide it is the
+        # family's reader's to refuse, in its own words)
+        run, rest = divmod(model.page_run, cfg.block_size)
+        self.tables = BlockTables(
+            cfg.resolved_num_blocks(), cfg.block_size, cfg.max_seqs,
+            cfg.max_blocks_per_seq, groups=groups,
+            run_pages=1 if rest else max(run, 1))
         # window layers: a ring of fixed pages a slot, owned for good and
         # never counted against num_blocks (admission sees full-layer
         # pages only); the dispatches name a row's ring by its slot id
@@ -1353,6 +1366,13 @@ class ServingEngine:
                 slots=cfg.max_seqs,
                 state_gb=self.stats.get("state_bytes", 0) / 1e9),
                 stderr=True)
+        if self.tables.run_pages > 1:
+            journal.emit(
+                f"[setup] pages: minted in aligned runs of "
+                f"{self.tables.run_pages} ({model.page_run} positions, one "
+                f"copy of the decode walk); {self.tables.unused_blocks} of "
+                f"{self.tables.num_blocks} pages lie in no whole run and "
+                "stay unused", stderr=True)
         journal.emit(
             "[setup] decode: " + (
                 "run-ahead 1 tick (device-fed last token)"

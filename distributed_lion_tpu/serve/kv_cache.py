@@ -47,6 +47,19 @@ a ref>1 page is forbidden; the engine first calls :meth:`BlockTables.cow`
 copy rides ``ops.attention.paged_copy_pages``) so the first divergent
 write targets a private copy — content-identical up to the written
 suffix, bit-identity preserved by construction.
+
+Aligned runs (``BlockTables(run_pages=r)``): a family whose reader walks
+blocks of ``r`` pages (``ops/sparse_select``: a selected block of 64
+positions over pages of 16) gets its pages in runs, so that a block is one
+contiguous ``[r * block_size, W]`` slab of every leaf and one copy of the
+decode kernel's. The free lists then hold run HEADS (multiples of ``r``), a
+slot that crosses a run boundary takes one whole run and grows into it page
+by page, and ``free_slot`` gives whole runs back. At every moment, for
+every slot and every ``j``, the owned entries among ``tables[slot, r j : r
+j + r]`` are consecutive ids starting at a multiple of ``r``: whatever
+order slots grow and leave in. Sharing, copy-on-write and ``shrink`` work
+by the page and are not built for runs (such a family serves without the
+prefix cache and without speculation: serve/engine's refusal table).
 """
 
 from __future__ import annotations
@@ -155,14 +168,22 @@ class BlockTables:
     the "allocation is host-side table math, never a recompile" half of
     the paged design, and it must stay importable without jax for the
     bench's capacity planning.
+
+    ``run_pages`` (the module note's aligned runs; 1 = a run is a page,
+    the historical allocator pop for pop): everything stays counted in
+    pages (``num_blocks``, ``owned``, ``refs``, ``free_blocks``,
+    ``pages_allocated``, what ``free_slot`` returns); only the free lists
+    hold runs, and ``unused_blocks`` says how many pages of the pool no
+    whole aligned run covers.
     """
 
     def __init__(self, num_blocks: int, block_size: int, max_seqs: int,
-                 max_blocks_per_seq: int, groups: int = 1):
-        if num_blocks < 1 or block_size < 1:
+                 max_blocks_per_seq: int, groups: int = 1,
+                 run_pages: int = 1):
+        if num_blocks < 1 or block_size < 1 or run_pages < 1:
             raise ValueError(
                 f"need positive pool dims, got num_blocks={num_blocks} "
-                f"block_size={block_size}")
+                f"block_size={block_size} run_pages={run_pages}")
         if groups < 1 or num_blocks % groups or max_seqs % groups:
             raise ValueError(
                 f"groups={groups} must divide num_blocks={num_blocks} and "
@@ -180,13 +201,17 @@ class BlockTables:
         self.groups = int(groups)
         self.blocks_per_group = self.num_blocks // self.groups
         self.slots_per_group = self.max_seqs // self.groups
-        # per-group LIFO free lists: recently-freed pages are re-used
-        # first, which keeps the working set of the pool small and
-        # cache-warm. groups=1 is bit-identical to the historical single
-        # list (same pop/append order).
+        self.run_pages = r = int(run_pages)
+        # per-group LIFO free lists of run heads (pages, where a run is a
+        # page): recently-freed pages are re-used first, which keeps the
+        # working set of the pool small and cache-warm. groups=1 is
+        # bit-identical to the historical single list (same pop/append
+        # order). A group's runs are the whole aligned ones inside its span.
         bpg = self.blocks_per_group
-        self._free = [list(range((g + 1) * bpg - 1, g * bpg - 1, -1))
+        self._free = [list(range((g + 1) * bpg // r * r - r,
+                                 -(-g * bpg // r) * r - 1, -r))
                       for g in range(self.groups)]
+        self.unused_blocks = self.num_blocks - self.free_blocks
         self.tables = np.full((max_seqs, max_blocks_per_seq), self.sentinel,
                               np.int32)
         self.owned = np.zeros((max_seqs,), np.int32)
@@ -200,7 +225,8 @@ class BlockTables:
     # ------------------------------------------------------------ capacity
     @property
     def free_blocks(self) -> int:
-        return sum(len(f) for f in self._free)
+        """Pages no slot holds or has reserved (whole free runs)."""
+        return sum(len(f) for f in self._free) * self.run_pages
 
     def group_of(self, slot: int) -> int:
         """The pool group ``slot`` allocates from (its device shard under
@@ -213,7 +239,7 @@ class BlockTables:
         return int(group) * self.blocks_per_group
 
     def free_blocks_in(self, group: int) -> int:
-        return len(self._free[group])
+        return len(self._free[group]) * self.run_pages
 
     @property
     def max_tokens_per_seq(self) -> int:
@@ -228,13 +254,18 @@ class BlockTables:
         need = self.blocks_for(n_tokens)
         if need > self.max_blocks_per_seq:
             return False
-        return need - int(self.owned[slot]) <= len(
+        # the runs the slot's pages lie in, now and then: the last one it
+        # holds has room for ``-owned % run_pages`` pages more
+        r = self.run_pages
+        return -(-need // r) - -(-int(self.owned[slot]) // r) <= len(
             self._free[self.group_of(slot)])
 
     # ---------------------------------------------------------- alloc/free
-    def _mint(self, group: int = 0) -> int:
-        """Pop a fresh page off ``group``'s free list at ref 1 (counted)."""
-        p = self._free[group].pop()
+    def _mint(self, group: int = 0, after: Optional[int] = None) -> int:
+        """A fresh page at ref 1 (counted): the head of a run popped off
+        ``group``'s free list, or the page after ``after`` inside the run
+        its slot already took."""
+        p = self._free[group].pop() if after is None else after + 1
         assert self.refs[p] == 0, f"page {p} on the free list with refs"
         self.refs[p] = 1
         self.pages_allocated += 1
@@ -242,14 +273,24 @@ class BlockTables:
 
     def _release(self, page: int) -> int:
         """Drop one ref; the page returns to its group's LIFO free list
-        only at ref 0. Returns 1 when the page was physically freed."""
+        only at ref 0 (with runs: its run does, when the head goes; the
+        slot that frees the head frees the rest in the same call). Returns
+        1 when the page was physically freed."""
         page = int(page)
         assert self.refs[page] > 0, f"double free of page {page}"
         self.refs[page] -= 1
         if self.refs[page] == 0:
-            self._free[page // self.blocks_per_group].append(page)
+            if page % self.run_pages == 0:
+                self._free[page // self.blocks_per_group].append(page)
             return 1
         return 0
+
+    def _pages_only(self, what: str) -> None:
+        if self.run_pages > 1:
+            raise NotImplementedError(
+                f"BlockTables.{what} works by the page and this pool is "
+                f"minted in runs of {self.run_pages}: sharing and rollback "
+                "by the run are not built (the module note)")
 
     def grow(self, slot: int, n_tokens: int) -> bool:
         """Ensure ``slot``'s table covers ``n_tokens`` total cache
@@ -267,7 +308,11 @@ class BlockTables:
             return True
         g = self.group_of(slot)
         for i in range(have, need):
-            self.tables[slot, i] = self._mint(g)
+            # crossing a run boundary takes one whole run; inside a run the
+            # next page is the one after the last
+            self.tables[slot, i] = self._mint(
+                g, None if i % self.run_pages == 0
+                else int(self.tables[slot, i - 1]))
         self.owned[slot] = need
         return True
 
@@ -285,6 +330,7 @@ class BlockTables:
         pins it). SHARED tail pages (refs > 1 — a rollback over a shared
         prefix) only drop this slot's ref: the physical page survives for
         its other holders. Returns the count of pages physically freed."""
+        self._pages_only("shrink")
         need = self.blocks_for(n_tokens)
         have = int(self.owned[slot])
         if need >= have:
@@ -323,6 +369,7 @@ class BlockTables:
         """Point an EMPTY slot's leading table entries at already-owned
         pages (a prefix-cache hit), taking one ref per page. ``grow`` then
         extends the row with fresh private pages as usual."""
+        self._pages_only("share")
         if int(self.owned[slot]) != 0:
             raise ValueError(
                 f"share() needs an empty slot, slot {slot} owns "
@@ -360,6 +407,7 @@ class BlockTables:
         ``ops.attention.paged_copy_pages`` before any write lands).
         Returns ``(src_page, dst_page)`` — or None when the pool is dry
         (caller falls back to reclaim/overflow, nothing changed)."""
+        self._pages_only("cow")
         idx = pos // self.block_size
         src = int(self.tables[slot, idx])
         assert self.refs[src] > 1, \
@@ -375,6 +423,7 @@ class BlockTables:
     # ----------------------------------------------- cache-side ref plumbing
     def add_ref(self, page: int) -> None:
         """One more holder of ``page`` (the PrefixCache's registration)."""
+        self._pages_only("add_ref")
         assert self.refs[page] > 0, f"ref on unowned page {page}"
         self.refs[page] += 1
 
@@ -385,7 +434,9 @@ class BlockTables:
 
     @property
     def physical_pages(self) -> int:
-        """Pages currently holding data (refs > 0)."""
+        """Pages currently holding data (refs > 0; with runs, also the
+        pages of a slot's last run it has yet to grow into, and the
+        ``unused_blocks``)."""
         return self.num_blocks - self.free_blocks
 
 

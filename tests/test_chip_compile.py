@@ -1348,8 +1348,11 @@ def test_minicpm_sala_serving_programs_at_the_published_shapes(
     layers, 0.81 GB of float32 state in six Lightning layers), donated. The
     decode tick holds ``lightning_step`` once a Lightning layer and
     ``paged_attn`` once a ``minicpm4`` layer (over the compacted lists of
-    128 (row, kv head) pairs), steps the state and writes the pages IN PLACE
-    (no copy of 0.81 or 2.77 GB), and selects in XLA. The 16,384-token
+    128 (row, kv head) pairs, a selected block of 64 positions one entry:
+    the kernel's k and v operands are the pool leaves seen as 20,480 runs of
+    64 rows, a bitcast), steps the state and writes the pages IN PLACE
+    (no copy of 0.81 or 2.77 GB, nor of a leaf under either view: a copy is
+    matched by its size), and selects in XLA. The 16,384-token
     prefill (the one bucket) runs ``lightning_chunk`` once a Lightning layer,
     the dense half of a ``minicpm4`` layer through ``flash_gqa_fwd`` at
     8,192, the selected half a tile of queries at a time: no ``[32, 16384,
@@ -1421,6 +1424,17 @@ def test_minicpm_sala_serving_programs_at_the_published_shapes(
         # [heads, S, S] scores, whole or a kv head's group of them
         assert size < 16 * 16384 * 16384, m[0]
     decode = kind == "decode_tick"
+    if decode:
+        # the walk's operands: lists of 128 runs (dense_len / 64) a (row, kv
+        # head), and both leaves by runs of four pages
+        for call in re.findall(r"%paged_attn(?:\.\d+)? = [^\n]*custom-call"
+                               r"\(([^\n]*?)\), custom_call_target", text):
+            made = [re.search(r"%s = (\S+) (\w+)\(" % re.escape(name), text)
+                    for name in call.split(", ")]
+            assert [m[1].split("{")[0] for m in made] == [
+                "s32[128]", "s32[16384]", "bf16[128,32,256]",
+                "bf16[20480,64,256]", "bf16[20480,64,256]"], made
+            assert [m[2] for m in made[3:]] == ["bitcast", "bitcast"], made
     for name, n in (("lightning_step", 6 * decode),
                     ("lightning_chunk", 6 * (not decode)),
                     ("paged_attn", 2 * decode),

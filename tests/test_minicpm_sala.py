@@ -58,14 +58,22 @@ def model():
     return weights, family.to_program(weights), cfg
 
 
+def run_tables(n_seq, per_seq, run, order=None):
+    """Tables as the engine mints them for this family: a block's ``run``
+    pages an aligned run of the pool, the runs themselves in ``order``
+    (None: descending)."""
+    runs = n_seq * per_seq // run
+    heads = (np.arange(runs)[::-1] if order is None else order) * run
+    return jnp.asarray((heads[:, None] + np.arange(run)).reshape(
+        n_seq, per_seq), jnp.int32)
+
+
 def pool(cfg, n_seq, block=BLOCK, per_seq=PER_SEQ):
     m = ServeModel.for_minicpm_sala(None, cfg)
     pages = init_page_leaves(
         cfg.n_layer, n_seq * per_seq, block, m.page_leaves, jnp.float32,
         state=(cfg.lightning_layers, n_seq, m.state_leaves))
-    tables = jnp.arange(n_seq * per_seq, dtype=jnp.int32)[::-1].reshape(
-        n_seq, per_seq)
-    return pages, tables
+    return pages, run_tables(n_seq, per_seq, cfg.sparse.block_size // block)
 
 
 def hooks_of(model):
@@ -146,6 +154,9 @@ def test_a_tick_holds_rows_on_both_sides_of_dense_len(served):
     # filled; the dense row walks ceil(41 / 2) = 21 pages a kv head
     sparse_pages = 2 * 2 * (3 * 4 + 1) + 2 * 2 * (3 * 4 + 3)
     assert first["kv_pages_selected"] == sparse_pages + 2 * 2 * 21
+    # a copy a block a leaf: 4 blocks a sparse list, ceil(41 / 8) = 6 the
+    # dense row's; by the page it would be 2 x kv_pages_selected = 392
+    assert first["kv_copies"] == 2 * (2 * 2 * (4 + 4) + 2 * 2 * 6)
 
 
 def test_selection_matters_to_the_logits(model, served):
@@ -398,14 +409,15 @@ def test_selection_by_hand():
 
 def test_the_compacted_lists_attention_is_masked_dense_attention():
     """Rows at positions 39 (sparse), 12 (dense: ``dense_len`` 16) and a dead
-    lane over pages of 2: ``paged_decode_attention`` over each (row, kv
-    head)'s list against a softmax over the kept blocks' positions taken by
-    hand, a different set a kv head."""
+    lane over pages of 2, a block's four pages an aligned run and the runs
+    scattered: ``paged_decode_attention`` over each (row, kv head)'s list of
+    runs against a softmax over the kept blocks' positions taken by hand, a
+    different set a kv head."""
     rng = np.random.default_rng(5)
     B, H, KV, hd, nb = 3, 4, 2, 4, 24
     k_pages = jnp.asarray(rng.normal(size=(B * nb, 2, 1, 128)), jnp.float32)
     v_pages = jnp.asarray(rng.normal(size=(B * nb, 2, 1, 128)), jnp.float32)
-    tables = jnp.asarray(rng.permutation(B * nb).reshape(B, nb), jnp.int32)
+    tables = run_tables(B, nb, 4, rng.permutation(B * nb // 4))
     pos = jnp.asarray([39, 12, 0])
     live = jnp.asarray([True, True, False])
     q = jnp.asarray(rng.normal(size=(B, H, hd)), jnp.float32)
@@ -417,13 +429,13 @@ def test_the_compacted_lists_attention_is_masked_dense_attention():
         q, jnp.asarray(ck_rows), tables, pos, live, SP, KV, 2)
     assert sparse.tolist() == [True, False, False]
     assert held.tolist() == [[24, 24], [13, 13], [0, 0]]
-    assert lists.shape == (B, KV, 12)          # max(3 blocks x 4, 16 / 2)
-    assert lists[0, 0, :12].tolist() == row0[[0, 1, 2, 3, 4, 5, 6, 7,
-                                              16, 17, 18, 19]].tolist()
-    assert lists[0, 1, :8].tolist() == row0[[0, 1, 2, 3, 12, 13, 14,
-                                             15]].tolist()
+    assert lists.shape == (B, KV, 3)           # max(3 blocks, 16 / 8)
+    assert lists[0, 0].tolist() == (row0[[0, 4, 16]] // 4).tolist()
+    assert lists[0, 1].tolist() == (row0[[0, 12, 16]] // 4).tolist()
+    assert lists[1, 0].tolist() == (np.asarray(tables[1])[[0, 4, 8]]
+                                    // 4).tolist()
     out = sparse_select.sparse_decode_attention(q, k_pages, v_pages, lists,
-                                                held, KV)
+                                                held, KV, 4)
     for b, blocks in ((0, ([0, 1, 4], [0, 3, 4])), (1, ([0, 1], [0, 1]))):
         p = int(pos[b])
         k_row = np.asarray(k_pages)[np.asarray(tables[b])].reshape(-1, 128)
@@ -521,6 +533,109 @@ def test_a_page_admitted_twice_reads_nothing_of_its_last_owner(model, batched):
     assert stats["sparse_rows"] == 2 * (8 + 11 + 6) and not stats["dense_rows"]
     assert stats["state_rows_stepped"] == 2 * (8 + 11 + 6)
     assert stats["state_bytes"] == 2 * 2 * 4 * 16 * 16 * 4
+
+
+def page_lists_of_pr40(q, ck, tables, pos, live, sp, kv_heads, page):
+    """``ops/sparse_select.decode_page_lists`` of the parent commit
+    (4baec4a): a list names PAGES, four table entries a kept block, read
+    wherever they point."""
+    NB = ck.shape[0]
+    B, H, hd = q.shape
+    nb = tables.shape[1]
+    r = sp.block_size // page
+    n_blocks = nb // r
+    rows = sparse_select.gather_compressed(ck, tables)[..., :kv_heads * hd]
+    idx, count = jax.vmap(
+        lambda qb, cb, pb: sparse_select.selected_blocks(
+            qb.reshape(1, kv_heads, H // kv_heads, hd),
+            cb.reshape(-1, kv_heads, hd), pb[None], sp, n_blocks)
+    )(q, rows, pos)
+    idx, count = idx[:, 0], count[:, 0]
+    K = idx.shape[-1]
+    width = min(nb, max(K * r, sp.dense_len // page))
+    at = (idx[..., None] * r + jnp.arange(r)).reshape(B, kv_heads, K * r)
+    picked = jnp.take_along_axis(
+        jnp.broadcast_to(tables[:, None], (B, kv_heads, nb)),
+        jnp.minimum(at, nb - 1), axis=2)
+    picked = jnp.where(at < n_blocks * r, picked, NB)
+    picked = jnp.pad(picked, ((0, 0), (0, 0), (0, width - K * r)),
+                     constant_values=NB)
+    sparse = pos + 1 > sp.dense_len
+    held = (count - 1) * sp.block_size + (pos % sp.block_size)[:, None] + 1
+    lists = jnp.where(sparse[:, None, None], picked,
+                      tables[:, None, :width])
+    lengths = jnp.where(sparse[:, None], held, (pos + 1)[:, None])
+    return lists, jnp.where(live[:, None], lengths, 0), sparse
+
+
+@pytest.mark.parametrize("n_slots", [2, 3])
+def test_slots_that_leave_out_of_order_and_return_read_whole_blocks(
+        model, n_slots, monkeypatch):
+    """Seven requests through two or three slots, outputs of 5 to 40 tokens
+    over prompts on both sides of ``dense_len``: slots grow a page every
+    second tick in turn, leave in an order that is not their admission's and
+    are admitted again into what the leavers gave back. The engine mints this
+    family's pages in aligned runs of four (``ServeModel.page_run``: every
+    table holds whole runs whenever a slot leaves), so the walk by blocks
+    over the pool viewed by runs serves the tokens of the walk by pages (the
+    parent commit's lists: four table entries a block, read wherever they
+    point); ``kv_pages_selected`` counts what it counted, and ``kv_copies``
+    a copy a block a leaf where the walk by pages starts two a page."""
+    rng = np.random.default_rng(41)
+    shapes = [(100, 30), (40, 9), (70, 5), (128, 12), (50, 40), (90, 7),
+              (66, 21)]
+    reqs = [Request(req_id=i, tokens=rng.integers(0, 256, n).tolist(),
+                    max_new_tokens=m, seed=0)
+            for i, (n, m) in enumerate(shapes)]
+
+    def serve(page_run=8):
+        served = ServeModel.for_minicpm_sala(model[1], model[2])
+        assert served.page_run == 8          # the family's block, its own
+        served.page_run = page_run
+        eng = ServingEngine(served, ServeConfig(
+            max_seqs=n_slots, block_size=BLOCK, max_blocks_per_seq=PER_SEQ,
+            prefill_cap_tokens=128, moe_stats=True))
+        bt, left = eng.tables, []
+        assert bt.run_pages == max(page_run // BLOCK, 1)
+        assert bt.unused_blocks == 0
+        if not page_run:
+            out = eng.run(reqs)
+            return [out[r.req_id].tokens for r in reqs]
+        free_slot = bt.free_slot
+
+        def leaving(slot):
+            for s in range(n_slots):
+                n = int(bt.owned[s])
+                for j in range(0, n, 4):
+                    run = bt.tables[s, j:min(j + 4, n)]
+                    assert run[0] % 4 == 0 and (np.diff(run) == 1).all()
+            left.append((slot, int(bt.tables[slot, 0])))
+            return free_slot(slot)
+
+        bt.free_slot = leaving
+        out = eng.run(reqs)
+        return [out[r.req_id].tokens for r in reqs], dict(eng.stats), left
+
+    by_runs, stats, left = serve()
+    assert len(left) == len(reqs)
+    # a slot's second tenant starts in a run its first did not start in,
+    # and slots do not leave in the order they came
+    assert len({head for _, head in left}) > n_slots
+    assert [s for s, _ in left[:n_slots]] != list(range(n_slots))
+    with monkeypatch.context() as m:
+        m.setattr(sala, "decode_page_lists", page_lists_of_pr40)
+        m.setattr(sala, "sparse_decode_attention", lambda *a:
+                  sparse_select.sparse_decode_attention(*a[:6]))
+        by_pages, stats_pages, left_pages = serve()
+    assert by_runs == by_pages and left == left_pages
+    assert [len(t) for t in by_runs] == [m for _, m in shapes]
+    assert stats["kv_pages_selected"] == stats_pages["kv_pages_selected"] > 0
+    # whole blocks but each list's last: between a half and 0.6
+    assert 0.5 <= stats["kv_copies"] / stats["kv_pages_selected"] < 0.6
+    assert stats["sparse_rows"] > 0 and stats["dense_rows"] > 0
+    # neither half alone: blocks read as runs out of pages minted one by one
+    if n_slots == 3:
+        assert serve(page_run=0) != by_runs
 
 
 def test_compressed_keys_are_not_counted_against_the_pool(model):

@@ -171,3 +171,81 @@ def test_engine_counts_pages_read_and_no_kernel_ticks_on_the_cpu():
     # tick j attends the prompt, the j tokens before it and its own
     assert st["kv_pages_read"] == sum(
         math.ceil((L + j + 1) / 4) for j in range(new - 1))
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+def test_block_lists_over_the_run_view_are_the_page_walk_bit_for_bit(dtype):
+    """A family whose reader walks blocks of 64 positions gets its pages in
+    aligned runs of four (``BlockTables(run_pages=4)``) and hands the kernel
+    the pool viewed ``[NB / 4, 64, 1, W]`` with a run id a block: the output
+    is BIT FOR BIT the walk over the same blocks' four table entries each by
+    pages of 16 (16 runs are copied ahead where 16 pages were, but the
+    softmax takes 256 positions at a time either way), with a quarter of
+    the list. Rows: blocks kept out of a long row, more than one block of 16
+    runs, the last run partly filled; a dead row; a row under ``dense_len``
+    (its table's own blocks, a partly filled page last); a row every page of
+    which was minted a page at a time between its neighbours', after a slot
+    had left and its runs had been taken again."""
+    from distributed_lion_tpu.ops.pallas_paged_attn import (
+        pages_per_block, parts_per_block,
+    )
+    from distributed_lion_tpu.serve.kv_cache import BlockTables
+
+    assert (pages_per_block(BS), parts_per_block(BS)) == (PAGES_PER_BLOCK, 1)
+    assert (pages_per_block(64), parts_per_block(64)) == (PAGES_PER_BLOCK, 4)
+    assert (pages_per_block(512), parts_per_block(512)) == (2, 2)
+    assert (pages_per_block(48), parts_per_block(48)) == (16, 1)
+    rng = np.random.default_rng(41)
+    r, H, KV, hd, per = 4, 8, 2, 128, 80
+    bt = BlockTables(num_blocks=4 * per + 8, block_size=BS, max_seqs=4,
+                     max_blocks_per_seq=per, run_pages=r)
+    assert bt.grow(3, 9 * BS) and bt.grow(0, 79 * BS)
+    bt.free_slot(3)                    # its runs come back first, descending
+    for tokens in range(BS, 23 * BS + 1, BS):      # a page at a time, in turn
+        for slot, upto in ((1, 6), (3, 23)):
+            if tokens <= upto * BS:
+                assert bt.grow(slot, tokens)
+    tables = np.asarray(bt.tables)
+    assert (np.diff(tables[3, :8]) == 1).sum() == 6      # runs, scattered:
+    assert len(set(tables[3, :23:r] // r)) == 6          # not one long run
+    # the slot that left gave back runs 0, 4, 8: taken again last first
+    assert (tables[1, 0], tables[3, 0], tables[1, 4]) == (8, 4, 0)
+    k_pages, v_pages = _pool(rng, bt.num_blocks, KV, hd, dtype)
+    assert not (tables[:, :per] == bt.num_blocks - 1).any()   # the poison
+
+    # (row's slot, blocks kept ascending, positions the list holds)
+    rows = [(0, [0, 2] + list(range(4, 20)), 17 * 64 + 37),
+            (2, [], 0),
+            (1, [0, 1], 5 * BS + 3),
+            (3, [0, 1, 3, 4, 5], 4 * 64 + 2 * BS + 9)]
+    width = 20
+    runs = np.full((len(rows), width), bt.num_blocks // r, np.int32)
+    pages = np.full((len(rows), width * r), bt.num_blocks, np.int32)
+    for i, (slot, kept, _) in enumerate(rows):
+        for j, b in enumerate(kept):
+            runs[i, j] = tables[slot, r * b] // r
+            pages[i, r * j:r * j + r] = tables[slot, r * b:r * b + r]
+    lengths = jnp.asarray([n for _, _, n in rows], jnp.int32)
+    q = jnp.asarray(rng.standard_normal((len(rows), H, hd)), dtype)
+
+    def view(x):
+        return x.reshape(x.shape[0] // r, r * BS, 1, x.shape[-1])
+
+    by_page = paged_attn(q, k_pages, v_pages, jnp.asarray(pages), lengths,
+                         kv_heads=KV, interpret=True)
+    by_run = paged_attn(q, view(k_pages), view(v_pages), jnp.asarray(runs),
+                        lengths, kv_heads=KV, interpret=True)
+    assert np.isfinite(np.asarray(by_run, np.float32)).all()
+    assert np.abs(np.asarray(by_run, np.float32)[[0, 2, 3]]).min() > 0
+    np.testing.assert_array_equal(np.asarray(by_run, np.float32)[1], 0.0)
+    np.testing.assert_array_equal(np.asarray(by_run, np.float32),
+                                  np.asarray(by_page, np.float32))
+    # and both are the gather path's attention over those positions
+    want = A.paged_decode_attention(q[:, :, None], view(k_pages),
+                                    view(v_pages), jnp.asarray(runs),
+                                    lengths - 1, kv_heads=KV)[:, :, 0]
+    tol = 2e-2 if dtype == jnp.bfloat16 else 1e-5
+    np.testing.assert_allclose(np.asarray(by_run, np.float32)[[0, 2, 3]],
+                               np.asarray(want, np.float32)[[0, 2, 3]],
+                               atol=tol, rtol=tol)

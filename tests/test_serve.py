@@ -359,6 +359,108 @@ def test_block_tables_refcount_fuzz_vs_reference():
     assert bt.free_blocks == bt.num_blocks
 
 
+# (run_pages, num_blocks, groups, seed): 98 and 100 pages leave a tail that
+# no whole aligned run covers; two groups split the pool into spans whose
+# edges need not be run edges; runs of 1 are the historical allocator
+RUN_CASES = [(4, 96, 1, 0), (4, 96, 1, 1), (4, 98, 1, 2), (2, 64, 2, 3),
+             (4, 100, 2, 4), (8, 128, 1, 5), (1, 40, 1, 6), (1, 40, 2, 7)]
+
+
+@pytest.mark.parametrize("r,num_blocks,groups,seed", RUN_CASES)
+def test_block_tables_mint_aligned_runs(r, num_blocks, groups, seed):
+    """``BlockTables(run_pages=r)``: a seeded fuzz of ``grow`` (a page or
+    two at a time, the slots in turn, as decoding slots grow) and
+    ``free_slot`` (in scrambled order, the freed slots admitted again into
+    the descending free lists) over 8 slots. After EVERY call: the owned
+    entries among ``tables[slot, r j : r j + r]`` are consecutive ids from a
+    multiple of ``r``, inside the slot's group; ``can_grow`` says what
+    ``grow`` then does and a refused ``grow`` changes nothing; ``owned``,
+    ``refs``, the free lists and ``unused_blocks`` add up to the pool; and
+    the tables are, pop for pop, those of a LIFO list of run heads written
+    out here apart from the class (at ``r`` 1: the allocator as it always
+    was). Sharing, copy-on-write and rollback work by the page and raise."""
+    rng = np.random.default_rng(seed)
+    n_slots, per = 8, 11                    # a table no multiple of 4 or 8
+    bt = BlockTables(num_blocks, 4, n_slots, per, groups=groups,
+                     run_pages=r)
+    bpg, spg = num_blocks // groups, n_slots // groups
+    model_free = [[h for h in range(num_blocks - 1, -1, -1)
+                   if h % r == 0 and g * bpg <= h and h + r <= (g + 1) * bpg]
+                  for g in range(groups)]
+    model = [[] for _ in range(n_slots)]
+    whole = sum(len(f) for f in model_free) * r
+    assert bt.unused_blocks == num_blocks - whole
+    assert bt.unused_blocks == {98: 2, 100: 4}.get(num_blocks, 0)
+    assert bt.free_blocks == whole
+
+    def check():
+        held = 0
+        for s in range(n_slots):
+            n, row = int(bt.owned[s]), bt.tables[s]
+            assert row[:n].tolist() == model[s]
+            assert (row[n:] == bt.sentinel).all()
+            for j in range(0, n, r):
+                run = row[j:min(j + r, n)]
+                assert run[0] % r == 0, (s, j, run)
+                assert (np.diff(run) == 1).all(), (s, j, run)
+                assert run[0] // bpg == s // spg == (run[0] + r - 1) // bpg
+            held += -(-n // r) * r
+        assert [list(f) for f in bt._free] == model_free
+        heads = [h for f in bt._free for h in f]
+        assert len(set(heads)) == len(heads)
+        assert all(not bt.refs[h:h + r].any() for h in heads)
+        owned = sorted(p for m in model for p in m)
+        assert np.flatnonzero(bt.refs).tolist() == owned
+        assert int(bt.refs.sum()) == int(bt.owned.sum()) == len(owned)
+        assert bt.free_blocks + held + bt.unused_blocks == num_blocks
+        assert bt.free_blocks == sum(bt.free_blocks_in(g)
+                                     for g in range(groups))
+
+    minted = 0
+    for step in range(900):
+        s = int(rng.integers(0, n_slots))
+        if rng.random() < 0.12 or bt.owned[s] == per:
+            freed = bt.free_slot(s)                 # frees in any order
+            assert freed == len(model[s])
+            model_free[s // spg].extend(model[s][::r])
+            model[s] = []
+        else:
+            tokens = int(bt.owned[s]) * 4 + int(rng.integers(1, 9))
+            need = min(-(-tokens // 4), per + 1)
+            take = -(-need // r) - -(-len(model[s]) // r)
+            can = need <= per and take <= len(model_free[s // spg])
+            assert bt.can_grow(s, tokens) == can
+            before = (bt.tables.copy(), bt.owned.copy(), bt.refs.copy())
+            assert bt.grow(s, tokens) == can
+            if can:
+                for i in range(len(model[s]), need):
+                    model[s].append(model_free[s // spg].pop() if i % r == 0
+                                    else model[s][-1] + 1)
+                    minted += 1
+            else:
+                for a, b in zip(before, (bt.tables, bt.owned, bt.refs)):
+                    np.testing.assert_array_equal(a, b)
+        check()
+    assert bt.pages_allocated == minted > 300
+    for s in rng.permutation(n_slots):
+        bt.free_slot(int(s))
+    assert bt.free_blocks == whole and not bt.refs.any()
+
+    assert bt.grow(0, 6)
+    calls = {"share": lambda: bt.share(1, [int(bt.tables[0, 0])]),
+             "cow": lambda: bt.cow(0, 0), "shrink": lambda: bt.shrink(0, 1),
+             "add_ref": lambda: bt.add_ref(int(bt.tables[0, 0]))}
+    for name, call in calls.items():
+        if r > 1:
+            with pytest.raises(NotImplementedError, match=f"{name}.*runs of"):
+                call()
+    if r == 1:
+        calls["share"](), calls["add_ref"]()
+        assert bt.refs[bt.tables[0, 0]] == 3 and bt.shrink(0, 1) == 1
+    with pytest.raises(ValueError, match="run_pages=0"):
+        BlockTables(num_blocks, 4, n_slots, per, run_pages=0)
+
+
 def test_paged_copy_then_scatter_matches_scatter_after_deep_copy():
     """The CoW device primitive: copying a page with paged_copy_pages and
     then multi-token-scattering into the copy is bit-identical to a host
