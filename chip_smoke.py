@@ -328,7 +328,7 @@ def phase_kernels() -> None:
                   f"flash_qkv off by {err}")
 
     # the loss head's kernel pair (ops/pallas_xent) through the entry the
-    # trainer's dense branch calls, at the train step's microbatch against
+    # trainer's loss builder calls, at the train step's microbatch against
     # the published head as it lies ([4 x 1023 labelled rows, 768] x
     # [50257, 768]), vs the einsum + clm_loss_and_metrics it replaces:
     # loss, accuracy and both gradients
@@ -339,7 +339,7 @@ def phase_kernels() -> None:
     hidden = jax.random.normal(kh, (4, 1024, 768), jnp.bfloat16)
     head = jax.random.normal(kw, (VOCAB, 768), jnp.float32) * 0.05
     tokens = jax.random.randint(kt, (4, 1024), 0, VOCAB)
-    check(xent_ops.fused_kernel_applies(768, jnp.bfloat16),
+    check(xent_ops.head_path("vd", 768, jnp.bfloat16) == "fused",
           "the loss head does not take ops/pallas_xent at d 768, bf16")
 
     def dense(h, w, t):
@@ -350,7 +350,7 @@ def phase_kernels() -> None:
     def run(fn):
         return jax.jit(jax.value_and_grad(fn, argnums=(0, 1), has_aux=True))
 
-    fused = run(xent_ops.tied_head_clm_loss_and_metrics)
+    fused = run(lambda h, w, t: xent_ops.clm_head_loss(h, w, t, layout="vd"))
     names = mosaic_kernels(fused.lower(hidden, head, tokens).as_text())
     check(names == ["fused_xent_bwd", "fused_xent_fwd"],
           f"the loss head lowered to {names}")

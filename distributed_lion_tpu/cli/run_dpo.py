@@ -209,6 +209,7 @@ def main(argv=None):
                                        dropout_key=dropout_key)
             return llama_apply(effective, tokens, model_cfg, tp_axis=TENSOR_AXIS)
 
+        loss_spec = None  # the frozen variant honours no head flag
         loss_fn = make_dpo_loss_fn_frozen(
             policy_apply=policy_apply,
             ref_apply=lambda frozen, t: llama_apply(frozen["ref"], t, model_cfg,
@@ -230,7 +231,7 @@ def main(argv=None):
             ref_fwd = lambda t: llama_apply(ref_params, t, model_cfg,
                                             seq_axis=SEQ_AXIS)  # noqa: E731
         policy_apply_lora = lora_apply_fn(base_fwd, base_params, lora_cfg)
-        loss_fn = make_dpo_loss_fn(
+        loss_fn, loss_spec = make_dpo_loss_fn(
             policy_apply=policy_apply_lora,
             ref_apply=ref_fwd,
             beta=script_args.beta,
@@ -246,7 +247,7 @@ def main(argv=None):
             base_fwd = lambda p, t: llama_apply(p, t, model_cfg)  # noqa: E731
             ref_fwd = lambda t: llama_apply(ref_params, t, model_cfg)  # noqa: E731
         policy_apply_lora = lora_apply_fn(base_fwd, base_params, lora_cfg)
-        loss_fn = make_dpo_loss_fn(
+        loss_fn, loss_spec = make_dpo_loss_fn(
             policy_apply=policy_apply_lora,
             ref_apply=ref_fwd,
             beta=script_args.beta,
@@ -275,17 +276,11 @@ def main(argv=None):
     print(f"[run_dpo] {len(train_data['chosen'])} train / {n_valid} eval pairs "
           f"(after length filtering)")
 
-    batch_spec = None
-    if sp > 1:
-        from jax.sharding import PartitionSpec as P
-
-        from distributed_lion_tpu.parallel.mesh import DATA_AXIS, SEQ_AXIS
-
-        batch_spec = P(DATA_AXIS, SEQ_AXIS)  # every [B, T] leaf token-sharded
     trainer = Trainer(train_cfg, mesh, apply_fn=None, params=adapters,
-                      loss_fn=loss_fn, param_specs=adapter_specs,
+                      loss_fn=loss_fn, loss_spec=loss_spec,
+                      param_specs=adapter_specs,
                       frozen_params=frozen_params, frozen_specs=frozen_specs,
-                      batch_spec=batch_spec, remat_decision=remat_decision)
+                      remat_decision=remat_decision)
     it = dpo_batch_iterator(train_data, trainer.global_train_batch(), seed=train_cfg.seed)
     try:
         trainer.train(it, eval_blocks=eval_data)

@@ -64,32 +64,6 @@ class SFTArguments:
     # (LlamaForCausalLM.from_pretrained-loadable, models/hf_export)
 
 
-def _sp_head_loss(effective, batch, model_cfg, train_cfg, tp_axis=None):
-    """Seq-parallel SFT loss over the (possibly adapted/quantized) effective
-    params — ONE dispatch point for the dense vs chunked-vocab head under
-    ``--seq_parallel``, with or without a tensor axis. ``--vocab_chunks``
-    streams the lm_head per shard (ops/xent.chunked_clm_loss_seq_parallel:
-    the [B, T/sp, V] logits never materialize and the shard-boundary label
-    ppermute is shared with the dense path's protocol)."""
-    from distributed_lion_tpu.models.llama import llama_apply, llama_hidden
-    from distributed_lion_tpu.models.loss import clm_loss_seq_parallel
-    from distributed_lion_tpu.parallel.mesh import SEQ_AXIS
-
-    if train_cfg.vocab_chunks > 0:
-        from distributed_lion_tpu.ops.quant import maybe_dequant
-        from distributed_lion_tpu.ops.xent import chunked_clm_loss_seq_parallel
-
-        hidden = llama_hidden(effective, batch, model_cfg,
-                              tp_axis=tp_axis, seq_axis=SEQ_AXIS)
-        emb = maybe_dequant(effective["lm_head"], model_cfg.compute_dtype)
-        return chunked_clm_loss_seq_parallel(
-            hidden, emb, batch, train_cfg.vocab_chunks, SEQ_AXIS,
-            emb_layout="dv")
-    logits = llama_apply(effective, batch, model_cfg,
-                         tp_axis=tp_axis, seq_axis=SEQ_AXIS)
-    return clm_loss_seq_parallel(logits, batch, SEQ_AXIS)
-
-
 def main(argv=None):
     from distributed_lion_tpu.utils.argparsing import parse_dataclasses
 
@@ -107,7 +81,7 @@ def main(argv=None):
         )
 
     import jax
-    import jax.numpy as jnp
+    from jax.sharding import PartitionSpec as P
 
     from distributed_lion_tpu.cli.run_clm import build_mesh
     from distributed_lion_tpu.data.sft import (
@@ -117,15 +91,29 @@ def main(argv=None):
         synthetic_qa_pairs,
     )
     from distributed_lion_tpu.data.tokenizer import load_tokenizer
-    from distributed_lion_tpu.models.llama import LlamaConfig, llama_apply, llama_init
+    from distributed_lion_tpu.models.llama import (
+        LlamaConfig,
+        llama_hidden,
+        llama_init,
+    )
     from distributed_lion_tpu.models.lora import (
         LoraConfig,
         apply_adapters,
         lora_init,
         merge_lora,
     )
-    from distributed_lion_tpu.ops.quant import quantize_tree
-    from distributed_lion_tpu.train.loop import Trainer
+    from distributed_lion_tpu.ops import xent as xent_ops
+    from distributed_lion_tpu.ops.quant import maybe_dequant, quantize_tree
+    from distributed_lion_tpu.parallel.mesh import (
+        DATA_AXIS,
+        SEQ_AXIS,
+        TENSOR_AXIS,
+    )
+    from distributed_lion_tpu.train.loop import (
+        LossSpec,
+        Trainer,
+        apply_remat_policy,
+    )
     from distributed_lion_tpu.utils.serialization import save_pytree
 
     sp = train_cfg.seq_parallel
@@ -208,46 +196,43 @@ def main(argv=None):
     n_adapter = sum(int(np.prod(a.shape)) for a in jax.tree.leaves(adapters))
     print(f"[run_sft] LoRA adapters: {len(adapters)} sites, {n_adapter/1e3:.1f}k trainable params")
 
-    from distributed_lion_tpu.models.loss import clm_loss_and_metrics
-    from distributed_lion_tpu.train.loop import apply_remat_policy
-
     model_cfg, remat_decision = apply_remat_policy(
         train_cfg, model_cfg, mesh, adapters, frozen=base_params)
-
-    def _split_batch(batch):
-        # packed: plain [B, T] token array; non-packed: {"tokens", "mask"}
-        if isinstance(batch, dict):
-            return batch["tokens"], batch["mask"]
-        return batch, None
-
-    def _head_loss(effective, tokens, mask, tp_axis=None):
-        """Dense or chunked-vocab CLM loss over the (possibly adapted)
-        effective params — --vocab_chunks streams the lm_head projection
-        (ops/xent) so the [B,T,V] logits are never materialized (V is 32k
-        for Llama-2, 128k for Llama-3-class configs)."""
-        if train_cfg.vocab_chunks > 0:
-            from distributed_lion_tpu.models.llama import llama_hidden
-            from distributed_lion_tpu.ops.quant import maybe_dequant
-            from distributed_lion_tpu.ops.xent import chunked_clm_loss_and_metrics
-
-            hidden = llama_hidden(effective, tokens, model_cfg, tp_axis=tp_axis)
-            # lm_head stays in its [d, V] matmul layout — ops/xent slices
-            # columns per chunk, no transposed copy of the head
-            emb = maybe_dequant(effective["lm_head"], model_cfg.compute_dtype)
-            return chunked_clm_loss_and_metrics(
-                hidden, emb, tokens, train_cfg.vocab_chunks, mask,
-                emb_layout="dv")
-        logits = llama_apply(effective, tokens, model_cfg, tp_axis=tp_axis)
-        return clm_loss_and_metrics(logits, tokens, mask)
-
     tp = train_cfg.tensor_parallel
+    tp_axis = TENSOR_AXIS if tp > 1 else None
+    seq_axis = SEQ_AXIS if sp > 1 else None
+
+    def _loss(effective, batch):
+        """CLM loss over the (possibly adapted/quantized) effective params.
+        ``--vocab_chunks`` streams the lm_head, left in its [d, V] matmul
+        layout, so the [B, T, V] logits never materialize (V is 32k for
+        Llama-2, 128k for Llama-3-class configs); under ``--seq_parallel``
+        the batch is this shard's contiguous token chunk [B, T/sp] and the
+        boundary labels ride a ppermute. Which head runs is
+        ops/xent.head_path's to say."""
+        # packed: plain [B, T] token array; non-packed: {"tokens", "mask"}
+        tokens, mask = ((batch["tokens"], batch["mask"])
+                        if isinstance(batch, dict) else (batch, None))
+        hidden = llama_hidden(effective, tokens, model_cfg, tp_axis=tp_axis,
+                              seq_axis=seq_axis)
+        return xent_ops.clm_head_loss(
+            hidden, maybe_dequant(effective["lm_head"], hidden.dtype), tokens,
+            layout="dv", loss_mask=mask, chunks=train_cfg.vocab_chunks,
+            seq_axis=seq_axis)
+
+    # tp x sp is long-context QLoRA SFT: base weights sharded over 'tensor',
+    # packed rows' tokens over 'seq' (ring attention), one vote world over
+    # 'data'; the train loop psums grads over the seq axis
+    loss_spec = LossSpec(
+        vocab_chunks=True,
+        batch_spec=P(DATA_AXIS, SEQ_AXIS) if sp > 1 else None)
     if tp > 1:
         # frozen base sharded over the tensor axis, threaded through the
         # train step as a live argument; adapters shard with their targets
         # (models/lora.lora_adapter_specs), replicated factors get the
-        # copy_to_tp_region gradient boundary inside apply_adapters.
+        # copy_to_tp_region gradient boundary inside apply_adapters (the
+        # f/g custom-vjp pair keeps per-tensor-rank adapter grads exact).
         from distributed_lion_tpu.models.lora import lora_adapter_specs
-        from distributed_lion_tpu.parallel.mesh import TENSOR_AXIS
         from distributed_lion_tpu.parallel.tensor_parallel import (
             llama_param_specs,
             validate_tp,
@@ -261,74 +246,27 @@ def main(argv=None):
             from distributed_lion_tpu.ops.quant import validate_quant_tp
 
             validate_quant_tp(base_params, base_specs, tp, TENSOR_AXIS)
-        adapter_specs = lora_adapter_specs(adapters, base_specs, TENSOR_AXIS)
 
-        if sp > 1:
-            # tp x sp: long-context QLoRA SFT — base weights sharded over
-            # 'tensor', packed rows' tokens sharded over 'seq' (ring
-            # attention), one vote world over 'data'. Gradients: the f/g
-            # custom-vjp pair keeps per-tensor-rank adapter grads exact,
-            # and the train loop psums grads over the seq axis.
-            from jax.sharding import PartitionSpec as P
+        def loss_fn(params, frozen, batch, dropout_key):
+            return _loss(apply_adapters(frozen, params, lora_cfg,
+                                        tp_axis=TENSOR_AXIS,
+                                        base_specs=base_specs,
+                                        dropout_key=dropout_key), batch)
 
-            from distributed_lion_tpu.parallel.mesh import DATA_AXIS, SEQ_AXIS
-
-            def loss_fn(params, frozen, batch, dropout_key):
-                effective = apply_adapters(frozen, params, lora_cfg,
-                                           tp_axis=TENSOR_AXIS,
-                                           base_specs=base_specs,
-                                           dropout_key=dropout_key)
-                return _sp_head_loss(effective, batch, model_cfg, train_cfg,
-                                     tp_axis=TENSOR_AXIS)
-
-            loss_fn._vocab_chunked = True
-            trainer = Trainer(train_cfg, mesh, apply_fn=None, params=adapters,
-                              remat_decision=remat_decision,
-                              param_specs=adapter_specs, loss_fn=loss_fn,
-                              frozen_params=base_params,
-                              frozen_specs=base_specs,
-                              batch_spec=P(DATA_AXIS, SEQ_AXIS))
-        else:
-            def loss_fn(params, frozen, batch, dropout_key):
-                tokens, mask = _split_batch(batch)
-                effective = apply_adapters(frozen, params, lora_cfg,
-                                           tp_axis=TENSOR_AXIS,
-                                           base_specs=base_specs,
-                                           dropout_key=dropout_key)
-                return _head_loss(effective, tokens, mask, tp_axis=TENSOR_AXIS)
-
-            loss_fn._vocab_chunked = True
-            trainer = Trainer(train_cfg, mesh, apply_fn=None, params=adapters,
-                              remat_decision=remat_decision,
-                              param_specs=adapter_specs, loss_fn=loss_fn,
-                              frozen_params=base_params, frozen_specs=base_specs)
-    elif sp > 1:
-        from jax.sharding import PartitionSpec as P
-
-        from distributed_lion_tpu.parallel.mesh import DATA_AXIS, SEQ_AXIS
-
-        def loss_fn(params, batch, dropout_key):
-            # batch is this shard's contiguous token chunk [B, T/sp]
-            effective = apply_adapters(base_params, params, lora_cfg,
-                                       dropout_key=dropout_key)
-            return _sp_head_loss(effective, batch, model_cfg, train_cfg)
-
-        loss_fn._vocab_chunked = True
-        trainer = Trainer(train_cfg, mesh, apply_fn=None, params=adapters,
-                              remat_decision=remat_decision,
-                          loss_fn=loss_fn,
-                          batch_spec=P(DATA_AXIS, SEQ_AXIS))
+        trainer = Trainer(
+            train_cfg, mesh, apply_fn=None, params=adapters,
+            remat_decision=remat_decision, loss_fn=loss_fn,
+            loss_spec=loss_spec,
+            param_specs=lora_adapter_specs(adapters, base_specs, TENSOR_AXIS),
+            frozen_params=base_params, frozen_specs=base_specs)
     else:
         def loss_fn(params, batch, dropout_key):
-            tokens, mask = _split_batch(batch)
-            effective = apply_adapters(base_params, params, lora_cfg,
-                                       dropout_key=dropout_key)
-            return _head_loss(effective, tokens, mask)
+            return _loss(apply_adapters(base_params, params, lora_cfg,
+                                        dropout_key=dropout_key), batch)
 
-        loss_fn._vocab_chunked = True
         trainer = Trainer(train_cfg, mesh, apply_fn=None, params=adapters,
-                              remat_decision=remat_decision,
-                          loss_fn=loss_fn)
+                          remat_decision=remat_decision, loss_fn=loss_fn,
+                          loss_spec=loss_spec)
 
     if script_args.packing:
         def batches():

@@ -26,7 +26,6 @@ from __future__ import annotations
 from typing import Optional
 
 import jax
-import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
@@ -36,7 +35,11 @@ from distributed_lion_tpu.models.gpt2 import (
     _block_remat_for,
     _layer_norm,
 )
-from distributed_lion_tpu.models.loss import clm_loss_and_metrics
+from distributed_lion_tpu.models.loss import (
+    pipelined_loss,
+    pipelined_seq_parallel_loss,
+)
+from distributed_lion_tpu.ops import xent as xent_ops
 from distributed_lion_tpu.parallel.mesh import PIPE_AXIS
 from distributed_lion_tpu.parallel.pipeline import (
     pipeline_apply,
@@ -179,16 +182,14 @@ def make_pipeline_loss(model_cfg: GPT2Config, n_micro: int,
         if seq_axis is not None:
             # sp × pp scaffold (collective hoisting + grad contract) lives
             # in models/loss.pipelined_seq_parallel_loss, shared with
-            # llama_pipe; only the family head is defined here.
-            from distributed_lion_tpu.models.loss import (
-                pipelined_seq_parallel_loss,
-            )
-            from distributed_lion_tpu.ops.xent import masked_local_nll
-
+            # llama_pipe; only the family head is defined here. It is
+            # ops/xent.masked_local_nll and not the entry (clm_head_loss),
+            # whose sequence arms psum and ppermute: this runs under
+            # lax.cond, where XLA aborts on a collective.
             def head_partials(acc, labels, mask):
                 h = _layer_norm(acc.reshape((B, T, x.shape[-1])),
                                 params["ln_f"])
-                return masked_local_nll(
+                return xent_ops.masked_local_nll(
                     h, params["wte"], labels, mask, vocab_chunks,
                     valid_v=model_cfg.vocab_size)
 
@@ -196,59 +197,13 @@ def make_pipeline_loss(model_cfg: GPT2Config, n_micro: int,
                 head_partials, acc, tokens, seq_axis, axis_name)
 
         def head_loss(acc):
-            h = acc.reshape((B, T, x.shape[-1]))
-            h = _layer_norm(h, params["ln_f"])
-            if vocab_chunks > 0:
-                from distributed_lion_tpu.ops.xent import (
-                    chunked_clm_loss_and_metrics,
-                )
+            h = _layer_norm(acc.reshape((B, T, x.shape[-1])), params["ln_f"])
+            # padded-vocab layout (models/gpt2 vocab_pad_multiple): valid_v
+            # drops the alignment columns, same as gpt2_apply
+            return xent_ops.clm_head_loss(
+                h, params["wte"], tokens, layout="vd",
+                valid_v=model_cfg.vocab_size, chunks=vocab_chunks)
 
-                return chunked_clm_loss_and_metrics(
-                    h, params["wte"], tokens, vocab_chunks,
-                    valid_v=model_cfg.vocab_size)
-            logits = jnp.einsum(
-                "btd,vd->btv", h, params["wte"].astype(h.dtype),
-                preferred_element_type=jnp.float32,
-            )
-            # padded-vocab layout (models/gpt2 vocab_pad_multiple): drop the
-            # alignment columns before the loss, same as gpt2_apply
-            return clm_loss_and_metrics(logits[..., : model_cfg.vocab_size],
-                                        tokens)
-
-        def skip_loss(acc):
-            z = jnp.float32(0)
-            return z, {"loss": z, "accuracy": z, "n_tokens": z}
-
-        # only the last stage saw real activations; lax.cond skips the
-        # (expensive) vocab projection + loss on the other stages entirely —
-        # XLA executes just the taken branch — and the psum then both
-        # broadcasts the value and routes zero cotangent to the skip branch
-        stage = lax.axis_index(axis_name)
-        last = lax.psum(1, axis_name) - 1
-        loss_local, metrics = lax.cond(stage == last, head_loss, skip_loss, acc)
-        loss = lax.psum(loss_local, axis_name)
-        metrics = {k: lax.psum(v, axis_name) for k, v in metrics.items()}
-        return loss, metrics
+        return pipelined_loss(head_loss, acc, axis_name)
 
     return loss_fn
-
-
-def validate_pipeline(model_cfg: GPT2Config, cfg, pp: int, n_micro: int) -> None:
-    """Config-time guards for ``--pipeline_parallel``."""
-    if model_cfg.n_layer % pp:
-        raise ValueError(f"n_layer {model_cfg.n_layer} not divisible by "
-                         f"pipeline stages {pp}")
-    if model_cfg.dropout > 0.0:
-        raise ValueError("dropout is unsupported under pipeline parallelism "
-                         "(per-microbatch keys would need schedule-aware "
-                         "plumbing); set --dropout 0")
-    if cfg.per_device_train_batch_size % n_micro:
-        raise ValueError(
-            f"per_device_train_batch_size {cfg.per_device_train_batch_size} "
-            f"not divisible by pipeline_microbatches {n_micro}"
-        )
-    if cfg.per_device_eval_batch_size % n_micro:
-        raise ValueError(
-            f"per_device_eval_batch_size {cfg.per_device_eval_batch_size} "
-            f"not divisible by pipeline_microbatches {n_micro}"
-        )

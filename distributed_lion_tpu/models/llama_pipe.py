@@ -19,7 +19,6 @@ loop psums over the pipe axis.
 from __future__ import annotations
 
 import jax
-import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
@@ -30,7 +29,11 @@ from distributed_lion_tpu.models.llama import (
     _rms_norm,
     rope_angles,
 )
-from distributed_lion_tpu.models.loss import clm_loss_and_metrics
+from distributed_lion_tpu.models.loss import (
+    pipelined_loss,
+    pipelined_seq_parallel_loss,
+)
+from distributed_lion_tpu.ops import xent as xent_ops
 from distributed_lion_tpu.parallel.mesh import PIPE_AXIS
 from distributed_lion_tpu.parallel.pipeline import (
     pipeline_apply,
@@ -140,16 +143,12 @@ def make_llama_pipeline_loss(model_cfg: LlamaConfig, n_micro: int,
 
         if seq_axis is not None:
             # sp × pp scaffold (collective hoisting + grad contract) shared
-            # with gpt2_pipe: models/loss.pipelined_seq_parallel_loss.
-            from distributed_lion_tpu.models.loss import (
-                pipelined_seq_parallel_loss,
-            )
-            from distributed_lion_tpu.ops.xent import masked_local_nll
-
+            # with gpt2_pipe: models/loss.pipelined_seq_parallel_loss, and
+            # masked_local_nll under its lax.cond for gpt2_pipe's reason.
             def head_partials(acc, labels, mask):
                 h = _rms_norm(acc.reshape((B, T, x.shape[-1])),
                               params["ln_f"], model_cfg.rms_eps)
-                return masked_local_nll(
+                return xent_ops.masked_local_nll(
                     h, params["lm_head"], labels, mask, vocab_chunks,
                     emb_layout="dv")
 
@@ -157,52 +156,11 @@ def make_llama_pipeline_loss(model_cfg: LlamaConfig, n_micro: int,
                 head_partials, acc, tokens, seq_axis, axis_name)
 
         def head_loss(acc):
-            h = acc.reshape((B, T, x.shape[-1]))
-            h = _rms_norm(h, params["ln_f"], model_cfg.rms_eps)
-            if vocab_chunks > 0:
-                from distributed_lion_tpu.ops.xent import (
-                    chunked_clm_loss_and_metrics,
-                )
+            h = _rms_norm(acc.reshape((B, T, x.shape[-1])), params["ln_f"],
+                          model_cfg.rms_eps)
+            return xent_ops.clm_head_loss(h, params["lm_head"], tokens,
+                                          layout="dv", chunks=vocab_chunks)
 
-                return chunked_clm_loss_and_metrics(
-                    h, params["lm_head"], tokens, vocab_chunks,
-                    emb_layout="dv")
-            logits = jnp.einsum(
-                "btd,dv->btv", h, params["lm_head"].astype(h.dtype),
-                preferred_element_type=jnp.float32,
-            )
-            return clm_loss_and_metrics(logits, tokens)
-
-        def skip_loss(acc):
-            z = jnp.float32(0)
-            return z, {"loss": z, "accuracy": z, "n_tokens": z}
-
-        # only the last stage saw real activations (see gpt2_pipe: cond
-        # skips the vocab projection elsewhere; the psum broadcasts the
-        # value and routes zero cotangent into the skip branch)
-        stage = lax.axis_index(axis_name)
-        last = lax.psum(1, axis_name) - 1
-        loss_local, metrics = lax.cond(stage == last, head_loss, skip_loss, acc)
-        loss = lax.psum(loss_local, axis_name)
-        metrics = {k: lax.psum(v, axis_name) for k, v in metrics.items()}
-        return loss, metrics
+        return pipelined_loss(head_loss, acc, axis_name)
 
     return loss_fn
-
-
-def validate_llama_pipeline(model_cfg: LlamaConfig, cfg, pp: int,
-                            n_micro: int) -> None:
-    """Config-time guards for ``--pipeline_parallel`` on the Llama family."""
-    if model_cfg.n_layer % pp:
-        raise ValueError(f"n_layer {model_cfg.n_layer} not divisible by "
-                         f"pipeline stages {pp}")
-    if cfg.per_device_train_batch_size % n_micro:
-        raise ValueError(
-            f"per_device_train_batch_size {cfg.per_device_train_batch_size} "
-            f"not divisible by pipeline_microbatches {n_micro}"
-        )
-    if cfg.per_device_eval_batch_size % n_micro:
-        raise ValueError(
-            f"per_device_eval_batch_size {cfg.per_device_eval_batch_size} "
-            f"not divisible by pipeline_microbatches {n_micro}"
-        )

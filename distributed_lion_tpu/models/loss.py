@@ -163,6 +163,25 @@ def clm_loss_seq_parallel(
     }
 
 
+def pipelined_loss(head_loss, acc, pipe_axis: str):
+    """The pp loss tail of gpt2_pipe and llama_pipe: only the last stage
+    saw real activations, so ``lax.cond`` runs ``head_loss(acc) -> (loss,
+    metrics)`` there alone — XLA executes just the taken branch, and the
+    (expensive) vocab projection is skipped on every other stage — and
+    the psum then both broadcasts the value and routes zero cotangent
+    into the skip branch."""
+    def skip_loss(acc):
+        z = jnp.float32(0)
+        return z, {"loss": z, "accuracy": z, "n_tokens": z}
+
+    stage = jax.lax.axis_index(pipe_axis)
+    last = jax.lax.psum(1, pipe_axis) - 1
+    loss_local, metrics = jax.lax.cond(stage == last, head_loss, skip_loss,
+                                       acc)
+    return jax.lax.psum(loss_local, pipe_axis), {
+        k: jax.lax.psum(v, pipe_axis) for k, v in metrics.items()}
+
+
 def pipelined_seq_parallel_loss(head_partials, acc, tokens, seq_axis: str,
                                 pipe_axis: str):
     """The sp × pp loss scaffold, shared by gpt2_pipe and llama_pipe so the

@@ -14,16 +14,30 @@ drops to [N, V/chunks] — and backward recomputes each chunk's logits from
 Exact same math as ``log_softmax`` + gather (pinned to the dense path by
 tests/test_xent.py, gradients included); only the schedule differs.
 
-The dense path's loss head (no vocab chunks, no vocab or sequence axis) is
-:func:`tied_head_clm_loss_and_metrics`: on a TPU, for a ``[V, d]`` head
-under bfloat16 hidden states with ``d`` a multiple of 128, it is the Mosaic
-kernel pair of ``ops/pallas_xent`` (no ``[B, T, V]`` logits in HBM, forward
-or backward); everywhere else the einsum and
-``models/loss.clm_loss_and_metrics``, bit for bit what ``gpt2_apply`` and
-the default loss compute. No option chooses. What it resolved to is
-recorded once a shape at trace time: an ``xent_resolved`` event in the run
-journal and a ``[setup] cross-entropy: ...`` line after the trainer's
-first dispatch (``train/journal.resolved``), as ``ops/attention`` does.
+Where the trainer's loss head is decided: :func:`clm_head_loss` is the one
+entry from final hidden states and a head matrix to a CLM loss, and
+:func:`head_path` the one rule, a function of what a call shows (never an
+option beyond the ``vocab_chunks`` / ``tp_vocab`` a user already sets):
+
+- ``tp_vocab``: a vocab axis. Each rank's shard of the head, Megatron's
+  vocab-parallel cross entropy (:func:`tp_vocab_xent`).
+- ``seq``: a sequence axis. The shard's logits and
+  ``models/loss.clm_loss_seq_parallel``.
+- ``seq_chunked``: a sequence axis and chunks
+  (:func:`chunked_clm_loss_seq_parallel`).
+- ``chunked``: chunks. :func:`chunked_softmax_xent`, a vocab chunk at a time.
+- ``fused``: a ``[V, d]`` head whole on a TPU under bfloat16 hidden states
+  with ``d`` a multiple of 128. The Mosaic kernel pair of
+  ``ops/pallas_xent``: no ``[B, T, V]`` logits in HBM, forward or backward.
+- ``dense``: everything else. The einsum the family's ``*_apply`` forms and
+  ``models/loss.clm_loss_and_metrics``, bit for bit.
+
+Both trainers' builder (``train/loop``), both pipelined losses and SFT call
+the entry; ``train/remat`` asks the rule which head a step will hold. What
+``fused`` resolved to is recorded once a shape at trace time: an
+``xent_resolved`` event in the run journal and a ``[setup] cross-entropy:
+...`` line after the trainer's first dispatch (``train/journal.resolved``),
+as ``ops/attention`` does.
 """
 
 from __future__ import annotations
@@ -239,8 +253,8 @@ def chunked_clm_loss_seq_parallel(
     valid_v: int = 0,
 ) -> tuple[jnp.ndarray, dict]:
     """Chunked-vocab CE under sequence parallelism (inside shard_map) —
-    the composition of :func:`chunked_clm_loss_and_metrics` (no [B, T, V]
-    logits materialized) with models/loss.clm_loss_seq_parallel's
+    the composition of :func:`chunked_softmax_xent` (no [B, T, V] logits
+    materialized) with models/loss.clm_loss_seq_parallel's
     shard-boundary protocol (each device holds a contiguous [B, T_local]
     token chunk; its last position's label arrives from the next shard via
     one [B, 1] ppermute; only the final shard's final position is masked).
@@ -295,11 +309,7 @@ def masked_local_nll(
             hidden.reshape(b * t, d), head, flat_labels, n_chunks,
             emb_layout, valid_v)
     else:
-        eq = "btd,vd->btv" if emb_layout == "vd" else "btd,dv->btv"
-        logits = jnp.einsum(eq, hidden, head.astype(hidden.dtype),
-                            preferred_element_type=jnp.float32)
-        if valid_v > 0:
-            logits = logits[..., :valid_v]
+        logits = _head_logits(hidden, head, emb_layout, valid_v)
         logp = jax.nn.log_softmax(logits.reshape(b * t, -1), axis=-1)
         nll = -jnp.take_along_axis(logp, flat_labels[:, None], 1)[:, 0]
         correct = logp.argmax(-1) == flat_labels
@@ -307,47 +317,9 @@ def masked_local_nll(
     return (nll * fm).sum(), (correct.astype(jnp.float32) * fm).sum()
 
 
-def tp_vocab_clm_loss_and_metrics(
-    hidden: jnp.ndarray,
-    head_shard: jnp.ndarray,
-    tokens: jnp.ndarray,
-    axis_name: str,
-    loss_mask: jnp.ndarray | None = None,
-    valid_v: int = 0,
-) -> tuple[jnp.ndarray, dict]:
-    """Shift-by-one CLM loss over a vocab-sharded head — the
-    tensor-parallel twin of :func:`chunked_clm_loss_and_metrics`, same
-    return contract. ``valid_v`` masks a padded head's alignment columns."""
-    return _shifted_clm_metrics(
-        lambda h, lab: tp_vocab_xent(h, head_shard, lab, axis_name, valid_v),
-        hidden, tokens, loss_mask)
-
-
-def chunked_clm_loss_and_metrics(
-    hidden: jnp.ndarray,
-    emb: jnp.ndarray,
-    tokens: jnp.ndarray,
-    n_chunks: int = 8,
-    loss_mask: jnp.ndarray | None = None,
-    emb_layout: str = "vd",
-    valid_v: int = 0,
-) -> tuple[jnp.ndarray, dict]:
-    """Shift-by-one CLM loss from FINAL HIDDEN STATES (not logits) — the
-    chunked twin of models/loss.clm_loss_and_metrics, same return contract.
-
-    ``hidden`` [B, T, d]; positions 0..T-2 predict tokens 1..T-1. ``emb``
-    is the head in either layout (see :func:`chunked_softmax_xent`);
-    ``valid_v`` masks MXU-alignment pad columns of a padded head.
-    """
-    return _shifted_clm_metrics(
-        lambda h, lab: chunked_softmax_xent(h, emb, lab, n_chunks, emb_layout,
-                                            valid_v),
-        hidden, tokens, loss_mask)
-
-
 def fused_kernel_applies(d: int, dtype) -> bool:
-    """True when :func:`tied_head_clm_loss_and_metrics` takes the kernel
-    pair for such hidden states (the rule is in the module doc)."""
+    """True when the kernel pair takes such hidden states (``fused`` in the
+    module doc's table); :func:`head_path` is its one caller."""
     from distributed_lion_tpu.ops.pallas_xent import kernel_takes
 
     return jax.default_backend() == "tpu" and kernel_takes(d, dtype)
@@ -385,29 +357,98 @@ def _fused_clm_loss_and_metrics(hidden, head, tokens, loss_mask, valid_v,
     return _masked_mean_metrics(nll, pred == labels, mask)
 
 
-def tied_head_clm_loss_and_metrics(
+def _head_logits(hidden, head, layout, valid_v):
+    """The float32 logits as the family's ``*_apply`` forms them: the head
+    as it lies (``vd``: ``[V, d]``, ``dv``: ``[d, V]``), a padded head's
+    alignment columns sliced off."""
+    eq = "btd,vd->btv" if layout == "vd" else "btd,dv->btv"
+    with jax.named_scope("head"):
+        logits = jnp.einsum(eq, hidden, head.astype(hidden.dtype),
+                            preferred_element_type=jnp.float32)
+    return logits[..., :valid_v] if valid_v > 0 else logits
+
+
+def head_path(layout: str, d: int, dtype, *, chunks: int = 0,
+              vocab_axis: str | None = None,
+              seq_axis: str | None = None) -> str:
+    """Which loss head :func:`clm_head_loss` runs for a head lying as
+    ``layout`` under hidden states of width ``d`` and ``dtype``: one of
+    ``fused | dense | chunked | tp_vocab | seq | seq_chunked`` (the module
+    doc's table). Loud on the two combinations that are not wired."""
+    if layout not in ("vd", "dv"):
+        raise ValueError(f"layout must be 'vd' or 'dv', got {layout!r}")
+    if vocab_axis and chunks > 0:
+        raise NotImplementedError(
+            "--tp_vocab and --vocab_chunks are alternative head strategies "
+            "(vocab sharded across ranks vs streamed in chunks); pick one")
+    if vocab_axis and seq_axis:
+        raise NotImplementedError(
+            "--tp_vocab under --seq_parallel is not wired; pick one")
+    if vocab_axis:
+        return "tp_vocab"
+    if seq_axis:
+        return "seq_chunked" if chunks > 0 else "seq"
+    if chunks > 0:
+        return "chunked"
+    if layout == "vd" and fused_kernel_applies(d, dtype):
+        return "fused"
+    return "dense"
+
+
+def clm_head_loss(
     hidden: jnp.ndarray,
     head: jnp.ndarray,
     tokens: jnp.ndarray,
+    *,
+    layout: str,
     loss_mask: jnp.ndarray | None = None,
     valid_v: int = 0,
+    chunks: int = 0,
+    vocab_axis: str | None = None,
+    seq_axis: str | None = None,
 ) -> tuple[jnp.ndarray, dict]:
     """Shift-by-one CLM loss from FINAL HIDDEN STATES ``[B, T, d]`` and the
-    tied head as it lies (``[V, d]``, whole on this device): the dense
-    path's loss head, with the return contract of
+    head as it lies (``layout`` ``vd``: ``[V, d]``, a tied embedding;
+    ``dv``: ``[d, V]``, an lm_head), with the return contract of
     models/loss.clm_loss_and_metrics (a masked position gives no loss and
-    no gradient). ``valid_v`` marks a padded head's alignment rows. Where
-    :func:`fused_kernel_applies` the logits stay in VMEM; elsewhere they
-    are the einsum ``gpt2_apply`` makes, sliced to ``valid_v``."""
-    from distributed_lion_tpu.models.loss import clm_loss_and_metrics
+    no gradient). ``valid_v`` marks a padded head's alignment columns,
+    ``chunks`` streams the vocabulary, ``vocab_axis`` names the mesh axis
+    the head's vocabulary is sharded over (``head`` is then this rank's
+    shard) and ``seq_axis`` the one the tokens are (``tokens`` this shard's
+    chunk; the loss then differentiates as ``local_nll_sum /
+    global_token_count``, as models/loss.clm_loss_seq_parallel says).
+    :func:`head_path` picks the implementation."""
+    from distributed_lion_tpu.models.loss import (
+        clm_loss_and_metrics,
+        clm_loss_seq_parallel,
+    )
 
-    if fused_kernel_applies(hidden.shape[-1], hidden.dtype):
+    path = head_path(layout, hidden.shape[-1], hidden.dtype, chunks=chunks,
+                     vocab_axis=vocab_axis, seq_axis=seq_axis)
+    if path in ("seq", "seq_chunked") and loss_mask is not None:
+        raise NotImplementedError(
+            "a loss mask under a sequence axis is not wired (the shard "
+            "boundary's label protocol carries none)")
+    if path == "tp_vocab":
+        # a [V/tp, d] shard of a tied embedding is the head's [d, V/tp]
+        # column slice transposed
+        shard = head.T if layout == "vd" else head
+        return _shifted_clm_metrics(
+            lambda h, lab: tp_vocab_xent(h, shard, lab, vocab_axis, valid_v),
+            hidden, tokens, loss_mask)
+    if path == "seq_chunked":
+        return chunked_clm_loss_seq_parallel(
+            hidden, head, tokens, chunks, seq_axis, layout, valid_v)
+    if path == "seq":
+        return clm_loss_seq_parallel(
+            _head_logits(hidden, head, layout, valid_v), tokens, seq_axis)
+    if path == "chunked":
+        return _shifted_clm_metrics(
+            lambda h, lab: chunked_softmax_xent(h, head, lab, chunks, layout,
+                                                valid_v),
+            hidden, tokens, loss_mask)
+    if path == "fused":
         return _fused_clm_loss_and_metrics(hidden, head, tokens, loss_mask,
                                            valid_v)
-    with jax.named_scope("head"):
-        logits = jnp.einsum(
-            "btd,vd->btv", hidden, head.astype(hidden.dtype),
-            preferred_element_type=jnp.float32)
-    if valid_v > 0:
-        logits = logits[..., :valid_v]
-    return clm_loss_and_metrics(logits, tokens, loss_mask)
+    return clm_loss_and_metrics(
+        _head_logits(hidden, head, layout, valid_v), tokens, loss_mask)

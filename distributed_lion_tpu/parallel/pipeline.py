@@ -137,3 +137,25 @@ def to_microbatches(x: jnp.ndarray, n_micro: int) -> jnp.ndarray:
 def from_microbatches(x: jnp.ndarray) -> jnp.ndarray:
     """Inverse of :func:`to_microbatches`."""
     return x.reshape((x.shape[0] * x.shape[1],) + x.shape[2:])
+
+
+def validate_pipeline(model_cfg, cfg, pp: int, n_micro: int) -> None:
+    """Config-time guards for ``--pipeline_parallel``, either family (a
+    Llama config has no dropout to refuse)."""
+    if model_cfg.n_layer % pp:
+        raise ValueError(f"n_layer {model_cfg.n_layer} not divisible by "
+                         f"pipeline stages {pp}")
+    if getattr(model_cfg, "dropout", 0.0) > 0.0:
+        raise ValueError("dropout is unsupported under pipeline parallelism "
+                         "(per-microbatch keys would need schedule-aware "
+                         "plumbing); set --dropout 0")
+    if cfg.per_device_train_batch_size % n_micro:
+        raise ValueError(
+            f"per_device_train_batch_size {cfg.per_device_train_batch_size} "
+            f"not divisible by pipeline_microbatches {n_micro}"
+        )
+    if cfg.per_device_eval_batch_size % n_micro:
+        raise ValueError(
+            f"per_device_eval_batch_size {cfg.per_device_eval_batch_size} "
+            f"not divisible by pipeline_microbatches {n_micro}"
+        )

@@ -19,6 +19,10 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
+
+from distributed_lion_tpu.parallel.mesh import DATA_AXIS
+from distributed_lion_tpu.train.loop import LossSpec
 
 
 def sequence_logprob(logits: jnp.ndarray, tokens: jnp.ndarray,
@@ -131,9 +135,12 @@ def make_dpo_loss_fn(
     seq_axis: str | None = None,
     vocab_chunks: int = 0,
     emb_layout: str = "dv",
-) -> Callable:
-    """Build ``loss_fn(params, batch, dropout_key) -> (loss, metrics)`` for
-    the Trainer. ``policy_apply(params, tokens)`` and ``ref_apply(tokens)``
+) -> tuple[Callable, LossSpec]:
+    """Build ``loss_fn(params, batch, dropout_key) -> (loss, metrics)`` and
+    its ``LossSpec`` (``vocab_chunks`` is honoured when given here; under
+    ``seq_axis`` every [B, T] leaf of the batch is token-sharded) for the
+    Trainer.
+    ``policy_apply(params, tokens)`` and ``ref_apply(tokens)``
     (ref params are frozen/closed-over, mirroring the reference's separate
     4-bit ref model, dpo_llama2.py:146-152). With ``seq_axis``, the batch
     leaves are token-sharded chunks and the apply fns are expected to run
@@ -198,8 +205,9 @@ def make_dpo_loss_fn(
         }
         return loss, metrics
 
-    loss_fn._vocab_chunked = vocab_chunks > 0  # Trainer guard handshake
-    return loss_fn
+    return loss_fn, LossSpec(
+        vocab_chunks=vocab_chunks > 0,
+        batch_spec=P(DATA_AXIS, seq_axis) if seq_axis else None)
 
 
 def make_dpo_loss_fn_frozen(
@@ -221,7 +229,7 @@ def make_dpo_loss_fn_frozen(
                    policy_apply(p, frozen, t, dropout_key=dropout_key))
         else:
             pol = lambda p, t: policy_apply(p, frozen, t)  # noqa: E731
-        inner = make_dpo_loss_fn(pol, lambda t: ref_apply(frozen, t), beta)
+        inner, _ = make_dpo_loss_fn(pol, lambda t: ref_apply(frozen, t), beta)
         return inner(params, batch, dropout_key)
 
     return loss_fn

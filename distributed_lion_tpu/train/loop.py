@@ -423,19 +423,130 @@ def apply_remat_policy(cfg: "TrainConfig", model_cfg, mesh, params, *,
     return remat.with_rung(model_cfg, decision.rung), decision
 
 
-def gpt2_dense_loss(model_cfg: GPT2Config, tp_axis: Optional[str] = None):
-    """The loss of ``Trainer.for_gpt2``'s dense branch (no MoE, no vocab or
-    sequence axis, no vocab chunks): the backbone's hidden states and the
-    tied head as it lies go to ``ops/xent``'s loss head, which keeps the
-    logits out of HBM where its kernels apply and computes what
-    ``gpt2_apply`` + ``clm_loss_and_metrics`` compute everywhere else."""
-    def loss_fn(params, batch, dropout_key):
-        hidden, _ = gpt2_hidden(params, batch, model_cfg,
-                                dropout_key=dropout_key, tp_axis=tp_axis)
-        return xent_ops.tied_head_clm_loss_and_metrics(
-            hidden, params["wte"], batch, valid_v=model_cfg.vocab_size)
+@dataclasses.dataclass(frozen=True)
+class LossSpec:
+    """What a built loss tells the :class:`Trainer` beside the function:
+    which of ``TrainConfig``'s head flags it honours (``parse_dataclasses``
+    exposes every field on every CLI, so a loss that would ignore a set
+    flag is refused, not run), how its batch is sharded (None: rows over
+    ``data``), and the shape of one MoE balance tally where it feeds the
+    ``--ep_dcn_pipeline`` ring (None: no ring). The default is a bare
+    callable's: it honours nothing."""
 
-    return loss_fn
+    vocab_chunks: bool = False
+    tp_vocab: bool = False
+    batch_spec: Optional[P] = None
+    moe_tally_shape: Optional[tuple] = None
+
+
+def _clm_head_loss(cfg: "TrainConfig", mesh, model_cfg, hidden_fn: Callable,
+                   head_fn: Callable, layout: str, *, head_cols: int,
+                   valid_v: int = 0):
+    """``(loss_fn, LossSpec)`` of a dense family's CLM training, from the
+    two things a family hands over — ``hidden_fn(params, batch,
+    dropout_key, tp_axis=, seq_axis=, vocab_axis=) -> [B, T, d]`` and where
+    its head lies (``head_fn(params)``, ``layout``, its ``head_cols``
+    vocabulary entries of which ``valid_v`` are real) — and from
+    ``cfg.vocab_chunks``, ``cfg.tp_vocab`` and the mesh's axes. Which head
+    runs is ``ops/xent.head_path``'s to say, asked here once so that what
+    it refuses is refused at construction."""
+    shape = dict(mesh.shape)
+    tp, sp = shape.get(TENSOR_AXIS, 1), shape.get(SEQ_AXIS, 1)
+    if cfg.tp_vocab and tp <= 1:
+        raise ValueError("--tp_vocab needs --tensor_parallel > 1 (it shards "
+                         "the head's vocabulary over the tensor axis)")
+    tp_axis = TENSOR_AXIS if tp > 1 else None
+    seq_axis = SEQ_AXIS if sp > 1 else None
+    vocab_axis = TENSOR_AXIS if cfg.tp_vocab else None
+    xent_ops.head_path(layout, model_cfg.d_model, model_cfg.compute_dtype,
+                       chunks=cfg.vocab_chunks, vocab_axis=vocab_axis,
+                       seq_axis=seq_axis)
+    if cfg.tp_vocab and head_cols % tp:
+        raise ValueError(
+            f"--tp_vocab: the head's {head_cols} vocabulary entries are not "
+            f"divisible by tensor axis {tp} (models/gpt2's "
+            "vocab_pad_multiple pads a ragged vocab so it shards evenly)")
+    if sp > 1:
+        validate_seq_block(cfg, model_cfg, sp)
+
+    def loss_fn(params, batch, dropout_key):
+        hidden = hidden_fn(params, batch, dropout_key, tp_axis=tp_axis,
+                           seq_axis=seq_axis, vocab_axis=vocab_axis)
+        return xent_ops.clm_head_loss(
+            hidden, head_fn(params), batch, layout=layout, valid_v=valid_v,
+            chunks=cfg.vocab_chunks, vocab_axis=vocab_axis, seq_axis=seq_axis)
+
+    return loss_fn, LossSpec(
+        vocab_chunks=True, tp_vocab=True,
+        # rows over data, tokens over seq
+        batch_spec=P(DATA_AXIS, SEQ_AXIS) if sp > 1 else None)
+
+
+def gpt2_clm_loss(cfg: "TrainConfig", mesh, model_cfg: GPT2Config):
+    """:func:`_clm_head_loss` for dense GPT-2 (what ``Trainer.for_gpt2``
+    steps at both training cells): ``gpt2_hidden`` and the tied embedding
+    as it lies, ``[padded_vocab, d]``."""
+    def hidden_fn(params, batch, dropout_key, **axes):
+        # under a vocab axis params["wte"] is this rank's [V/tp, d]
+        # vocab-row slice: VocabParallelEmbedding on the way in, its
+        # transpose as the tied vocab-parallel head on the way out
+        return gpt2_hidden(params, batch, model_cfg,
+                           dropout_key=dropout_key, **axes)[0]
+
+    return _clm_head_loss(
+        cfg, mesh, model_cfg, hidden_fn, lambda params: params["wte"], "vd",
+        head_cols=model_cfg.padded_vocab, valid_v=model_cfg.vocab_size)
+
+
+def _resolve_comm(cfg: "TrainConfig", mesh, params):
+    """``cfg`` with its ``auto`` comm fields resolved for a model of these
+    ``params`` on ``mesh``, their count, and the vote wire's byte account
+    (the factories' banner)."""
+    n = count_params(params)
+    shape = dict(mesh.shape)
+    cfg = resolve_auto_comm(
+        cfg, mesh, n,
+        # tp/pp/expert all shard params; only dp(/sp) keeps them
+        # replicated, the precondition for the lazy elected-sign cache
+        params_replicated=all(
+            shape.get(ax, 1) == 1
+            for ax in (TENSOR_AXIS, PIPE_AXIS, EXPERT_AXIS)))
+    return cfg, n, wire_bytes_per_param(
+        n, data_axis_size(mesh), cfg.wire, vote_every=cfg.vote_every,
+        accum_steps=cfg.gradient_accumulation_steps,
+        vote_buckets=cfg.vote_buckets or 1)
+
+
+def _pipelined_trainer(cfg: "TrainConfig", mesh, model_cfg, family: str,
+                       remat_decision, make_loss, stage_params, stage_specs):
+    """The :class:`Trainer` of either family under ``--pipeline_parallel``
+    (models/gpt2_pipe, models/llama_pipe: ``make_loss`` and the stage
+    layout are the family's). The pipelined loss streams
+    ``cfg.vocab_chunks`` at the last stage and carries a replicated head:
+    its ``LossSpec`` says so, and ``--tp_vocab`` is refused on it."""
+    from distributed_lion_tpu.parallel.pipeline import validate_pipeline
+    from distributed_lion_tpu.parallel.tensor_parallel import validate_tp
+
+    shape = dict(mesh.shape)
+    tp, sp, pp = (shape.get(ax, 1) for ax in (TENSOR_AXIS, SEQ_AXIS,
+                                              PIPE_AXIS))
+    if tp > 1:
+        validate_tp(model_cfg, tp, family)
+    if sp > 1:
+        validate_seq_block(cfg, model_cfg, sp)
+    n_micro = cfg.pipeline_microbatches or pp
+    validate_pipeline(model_cfg, cfg, pp, n_micro)
+    loss_fn = make_loss(model_cfg, n_micro,
+                        tp_axis=TENSOR_AXIS if tp > 1 else None,
+                        vocab_chunks=cfg.vocab_chunks,
+                        seq_axis=SEQ_AXIS if sp > 1 else None)
+    return Trainer(
+        cfg, mesh, None, stage_params, loss_fn=loss_fn,
+        loss_spec=LossSpec(
+            vocab_chunks=True,
+            batch_spec=P(DATA_AXIS, SEQ_AXIS) if sp > 1 else None),
+        param_specs=stage_specs(tensor=tp > 1),
+        remat_decision=remat_decision)
 
 
 def validate_seq_block(cfg: "TrainConfig", model_cfg, sp: int) -> None:
@@ -723,10 +834,9 @@ class Trainer:
         mesh,
         apply_fn: Callable,
         params: Any,
-        loss_mask_fn: Optional[Callable] = None,
         loss_fn: Optional[Callable] = None,
+        loss_spec: Optional[LossSpec] = None,
         param_specs: Any = None,
-        batch_spec: Optional[P] = None,
         frozen_params: Any = None,
         frozen_specs: Any = None,
         remat_decision: Any = None,
@@ -734,7 +844,9 @@ class Trainer:
         """``loss_fn(params, batch, dropout_key) -> (loss, metrics)`` may
         replace the default CLM loss; ``batch`` is then any pytree whose
         leaves carry a leading global-batch axis (e.g. DPO's
-        chosen/rejected pairs). ``param_specs`` is an optional PartitionSpec
+        chosen/rejected pairs); ``loss_spec`` is the :class:`LossSpec` its
+        builder returned beside it (None: a bare callable's, which honours
+        no flag). ``param_specs`` is an optional PartitionSpec
         pytree (parallel.tensor_parallel) for tensor-parallel params;
         default replicated.
 
@@ -831,28 +943,28 @@ class Trainer:
                 "will NOT move. Use f32 param_dtype (bf16 compute_dtype "
                 "keeps the matmul speed) unless this is a throughput bench."
             )
-        if (cfg.vocab_chunks > 0 and loss_fn is not None
-                and not getattr(loss_fn, "_vocab_chunked", False)):
-            # vocab_chunks is consumed by losses that opt in (for_gpt2's
-            # dense path, run_sft's SFT losses, run_dpo's chunked scoring —
-            # marked _vocab_chunked); any other caller-supplied loss would
-            # silently ignore the CLI-auto-exposed flag.
+        spec = loss_spec or LossSpec()
+        if cfg.vocab_chunks > 0 and not spec.vocab_chunks:
+            # parse_dataclasses exposes every TrainConfig field on every
+            # CLI: a loss whose builder did not say it honours the flag
+            # (the MoE branch's, a bare callable, the default over
+            # apply_fn) would silently ignore it
             raise NotImplementedError(
                 "--vocab_chunks is not wired into this entry point's loss "
                 "function (supported: run_clm's dp/tp/sp/pp paths, run_sft, "
                 "run_dpo)"
             )
-        if cfg.tp_vocab and not getattr(loss_fn, "_tp_vocab", False):
-            # same silent-ignore trap as vocab_chunks: the flag is
-            # CLI-auto-exposed everywhere but only the dense dp x tp losses
-            # of for_gpt2/for_llama consume it (parse_dataclasses exposes
-            # every TrainConfig field)
+        if cfg.tp_vocab and not spec.tp_vocab:
+            # the same trap: only the dense dp x tp losses of
+            # for_gpt2/for_llama shard the head's vocabulary (the pipelined
+            # and the MoE losses carry a replicated head)
             raise NotImplementedError(
                 "--tp_vocab is wired for run_clm's dense dp x tp paths "
                 "(gpt2 and llama families) only; this entry point's loss "
                 "would silently ignore it"
             )
-        self.batch_spec = batch_spec if batch_spec is not None else P(DATA_AXIS)
+        self.batch_spec = (spec.batch_spec if spec.batch_spec is not None
+                           else P(DATA_AXIS))
         # number of ways batch ROWS (dim 0) are sharded: data alone normally;
         # data x expert under expert parallelism (tokens ride both axes)
         dim0 = self.batch_spec[0] if len(self.batch_spec) else None
@@ -984,19 +1096,18 @@ class Trainer:
                 # [n_moe_blocks, E+1] tally slot per in-flight step, stacked
                 # per data worker like the momenta. Created HERE, not by
                 # init_global_state — the tally shape is model config,
-                # which the optimizer never sees; the loss the MoE trainer
-                # built stamps it on itself (_moe_tally_shape).
-                tshape = getattr(loss_fn, "_moe_tally_shape", None)
-                if tshape is None:
+                # which the optimizer never sees; the MoE trainer's loss
+                # says it in its LossSpec.
+                if spec.moe_tally_shape is None:
                     raise ValueError(
                         f"--ep_dcn_pipeline {cfg.ep_dcn_pipeline} > 0 "
                         "needs the MoE trainer's loss (make_trainer with "
-                        "--moe_experts), which stamps the balance-tally "
-                        "shape the ring is sized from; this loss carries "
-                        "none")
+                        "--moe_experts), whose LossSpec gives the "
+                        "balance-tally shape the ring is sized from; this "
+                        "loss gives none")
                 state = state._replace(moe_ring=jnp.zeros(
-                    (self.world, cfg.ep_dcn_pipeline) + tuple(tshape),
-                    jnp.float32))
+                    (self.world, cfg.ep_dcn_pipeline)
+                    + tuple(spec.moe_tally_shape), jnp.float32))
             self.state = jax.device_put(
                 state,
                 LionState(
@@ -1065,10 +1176,10 @@ class Trainer:
         if loss_fn is None:
             def loss_fn(params, batch, dropout_key):
                 logits = self.apply_fn(params, batch, dropout_key)
-                mask = loss_mask_fn(batch) if loss_mask_fn else None
-                return clm_loss_and_metrics(logits, batch, mask)
+                return clm_loss_and_metrics(logits, batch)
 
         self.loss_fn = loss_fn
+        self.loss_spec = spec
         self._train_step_core = self._build_train_step_core()
         # the accumulator (arg 2) is NOT donated: its zero-initialized
         # scalar counters can alias one device buffer, which XLA rejects as
@@ -1383,7 +1494,7 @@ class Trainer:
         # tally (read from LionState.moe_ring pre-scan) and returns this
         # step's fresh local tallies on the metrics dict under the
         # reserved 'moe_tallies' key (popped in-trace below, never logged)
-        ring_on = getattr(self.loss_fn, "_wants_moe_balance", False)
+        ring_on = self.loss_spec.moe_tally_shape is not None
 
         @partial(
             jax.shard_map,
@@ -2451,7 +2562,6 @@ class Trainer:
         """``initial_params`` (e.g. an HF checkpoint imported via
         models/hf_import) replaces the random init — the reference's
         finetune-from-pretrained path (run_clm.py:425-444)."""
-        from distributed_lion_tpu.parallel.mesh import TENSOR_AXIS
         from distributed_lion_tpu.parallel.tensor_parallel import (
             gpt2_param_specs,
             validate_tp,
@@ -2461,20 +2571,7 @@ class Trainer:
                   gpt2_init(jax.random.key(seed if seed is not None else cfg.seed), model_cfg))
         model_cfg, remat_decision = apply_remat_policy(cfg, model_cfg, mesh,
                                                        params)
-        n = count_params(params)
-        shape = dict(mesh.shape)
-        cfg = resolve_auto_comm(
-            cfg, mesh, n,
-            # tp/pp/expert all shard params; only dp(/sp) keeps them
-            # replicated, the precondition for the lazy elected-sign cache
-            params_replicated=all(
-                shape.get(ax, 1) == 1
-                for ax in (TENSOR_AXIS, PIPE_AXIS, EXPERT_AXIS)),
-        )
-        acct = wire_bytes_per_param(n, data_axis_size(mesh), cfg.wire,
-                                    vote_every=cfg.vote_every,
-                                    accum_steps=cfg.gradient_accumulation_steps,
-                                    vote_buckets=cfg.vote_buckets or 1)
+        cfg, n, acct = _resolve_comm(cfg, mesh, params)
         tp = mesh.shape[TENSOR_AXIS]
         emit(
             f"[trainer] GPT-2 {n/1e6:.1f}M params | world={data_axis_size(mesh)} "
@@ -2490,17 +2587,11 @@ class Trainer:
                if "dcn_bits_per_param" in acct else "")
         )
         pp = dict(mesh.shape).get(PIPE_AXIS, 1)
-        if cfg.vocab_chunks > 0 and model_cfg.moe_experts > 0:
-            raise NotImplementedError(
-                "--vocab_chunks is wired for the dense dp/tp/sp/pp paths "
-                "(the MoE branch carries its own loss function); drop one"
-            )
         if pp > 1:
             from distributed_lion_tpu.models.gpt2_pipe import (
                 make_pipeline_loss,
                 pipeline_param_specs,
                 pipeline_params,
-                validate_pipeline,
             )
 
             if dict(mesh.shape).get(EXPERT_AXIS, 1) > 1:
@@ -2514,34 +2605,10 @@ class Trainer:
                     "MoE blocks under pipeline parallelism are not wired "
                     "(mixed dense/MoE stage structures); drop one of the two"
                 )
-            if cfg.tp_vocab:
-                raise NotImplementedError(
-                    "--tp_vocab under --pipeline_parallel is not wired (the "
-                    "pipeline loss carries its own replicated head); drop one"
-                )
-            if tp > 1:
-                validate_tp(model_cfg, tp, "gpt2")
-            sp_pipe = dict(mesh.shape).get(SEQ_AXIS, 1)
-            if sp_pipe > 1:
-                validate_seq_block(cfg, model_cfg, sp_pipe)
-            n_micro = cfg.pipeline_microbatches or pp
-            validate_pipeline(model_cfg, cfg, pp, n_micro)
-            loss_fn = make_pipeline_loss(
-                model_cfg, n_micro,
-                tp_axis=TENSOR_AXIS if tp > 1 else None,
-                vocab_chunks=cfg.vocab_chunks,
-                seq_axis=SEQ_AXIS if sp_pipe > 1 else None)
-            if cfg.vocab_chunks > 0:
-                loss_fn._vocab_chunked = True  # consumed; don't trip the guard
-            return Trainer(
-                cfg, mesh,
-                apply_fn=None,
-                params=pipeline_params(params, pp),
-                param_specs=pipeline_param_specs(tensor=tp > 1),
-                loss_fn=loss_fn,
-                batch_spec=(P(DATA_AXIS, SEQ_AXIS) if sp_pipe > 1 else None),
-                remat_decision=remat_decision,
-            )
+            return _pipelined_trainer(
+                cfg, mesh, model_cfg, "gpt2", remat_decision,
+                make_pipeline_loss, pipeline_params(params, pp),
+                pipeline_param_specs)
 
         ep = dict(mesh.shape).get(EXPERT_AXIS, 1)
         if ep > 1 and model_cfg.moe_experts == 0:
@@ -2557,10 +2624,7 @@ class Trainer:
                 "Drop the flag or add --moe_experts")
         if model_cfg.moe_experts > 0:
             from distributed_lion_tpu.models.gpt2 import gpt2_moe_param_specs
-            from distributed_lion_tpu.models.loss import (
-                clm_loss_and_metrics,
-                clm_loss_sharded_rows,
-            )
+            from distributed_lion_tpu.models.loss import clm_loss_sharded_rows
 
             if dict(mesh.shape).get(SEQ_AXIS, 1) > 1:
                 raise NotImplementedError(
@@ -2571,11 +2635,6 @@ class Trainer:
                 raise ValueError(
                     f"moe_experts {model_cfg.moe_experts} not divisible by "
                     f"expert axis {ep}"
-                )
-            if cfg.tp_vocab:
-                raise NotImplementedError(
-                    "--tp_vocab on the MoE path is not wired (the MoE loss "
-                    "uses the replicated tied head); drop one"
                 )
             if tp > 1:
                 validate_tp(model_cfg, tp, "gpt2")
@@ -2603,46 +2662,31 @@ class Trainer:
                                   moe_balance_axis=balance_axis,
                                   return_moe_tallies=return_tallies)
 
-            if ep > 1:
-                def moe_loss(params, batch, dropout_key, moe_balance=None):
-                    if moe_balance is None:
-                        logits, aux = moe_apply(params, batch, dropout_key)
-                        tallies = None
-                    else:
-                        logits, aux, tallies = moe_apply(
-                            params, batch, dropout_key, moe_balance, True)
+            def moe_loss(params, batch, dropout_key, moe_balance=None):
+                fed = moe_balance is not None
+                logits, aux, *tallies = moe_apply(params, batch, dropout_key,
+                                                  moe_balance, fed)
+                if ep > 1:
                     loss, metrics = clm_loss_sharded_rows(
                         logits, batch, EXPERT_AXIS, aux=aux)
-                    if tallies is not None:
-                        metrics["moe_tallies"] = tallies
-                    return loss, metrics
-
-                moe_batch_spec = P((DATA_AXIS, EXPERT_AXIS))
-            else:
-                def moe_loss(params, batch, dropout_key, moe_balance=None):
-                    if moe_balance is None:
-                        logits, aux = moe_apply(params, batch, dropout_key)
-                        tallies = None
-                    else:
-                        logits, aux, tallies = moe_apply(
-                            params, batch, dropout_key, moe_balance, True)
+                else:
                     loss, metrics = clm_loss_and_metrics(logits, batch)
                     metrics["aux_loss"] = aux
-                    if tallies is not None:
-                        metrics["moe_tallies"] = tallies
-                    return loss + 0.01 * aux, metrics
+                    loss = loss + 0.01 * aux
+                if fed:
+                    metrics["moe_tallies"] = tallies[0]
+                return loss, metrics
 
-                moe_batch_spec = None
+            moe_batch_spec = P((DATA_AXIS, EXPERT_AXIS)) if ep > 1 else None
+            tally_shape = None
             if (ep_depth or 0) > 0:
                 from distributed_lion_tpu.models.gpt2 import is_moe_block
                 n_moe = sum(1 for i in range(model_cfg.n_layer)
                             if is_moe_block(model_cfg, i))
-                # consumed by Trainer.__init__ (ring sizing) and the step
-                # core (ring read/feed/write); the tally row is per-expert
-                # token counts + the lane count in the last entry
-                moe_loss._wants_moe_balance = True
-                moe_loss._moe_tally_shape = (n_moe,
-                                             model_cfg.moe_experts + 1)
+                # read by Trainer.__init__ (ring sizing) and the step core
+                # (ring read/feed/write); the tally row is per-expert token
+                # counts + the lane count in the last entry
+                tally_shape = (n_moe, model_cfg.moe_experts + 1)
             n_active = count_params(params) - sum(
                 p.size for b in params["blocks"] if "moe" in b
                 for p in jax.tree.leaves(b["moe"])
@@ -2652,116 +2696,27 @@ class Trainer:
                   f"experts every {model_cfg.moe_every} blocks | ep={ep}")
             return Trainer(cfg, mesh, apply_fn=None, params=params,
                            param_specs=moe_specs, loss_fn=moe_loss,
-                           batch_spec=moe_batch_spec,
+                           loss_spec=LossSpec(batch_spec=moe_batch_spec,
+                                              moe_tally_shape=tally_shape),
                            remat_decision=remat_decision)
 
-        if cfg.tp_vocab and tp <= 1:
-            raise ValueError("--tp_vocab needs --tensor_parallel > 1 (it "
-                             "shards the tied embedding over the tensor axis)")
-        if cfg.tp_vocab and cfg.vocab_chunks > 0:
-            raise NotImplementedError(
-                "--tp_vocab and --vocab_chunks are alternative head "
-                "strategies; pick one"
-            )
-        if cfg.tp_vocab and dict(mesh.shape).get(SEQ_AXIS, 1) > 1:
-            raise NotImplementedError(
-                "--tp_vocab under --seq_parallel is not wired; pick one"
-            )
         param_specs = None
-        tp_axis = None
         if tp > 1:
             validate_tp(model_cfg, tp, "gpt2")
-            if cfg.tp_vocab and model_cfg.padded_vocab % tp:
-                raise ValueError(
-                    f"--tp_vocab: embedding rows {model_cfg.padded_vocab} not "
-                    f"divisible by tensor axis {tp}; vocab_pad_multiple "
-                    f"(models/gpt2) pads a ragged vocab so it shards evenly"
-                )
             param_specs = gpt2_param_specs(model_cfg,
                                            vocab_parallel=cfg.tp_vocab)
-            tp_axis = TENSOR_AXIS
+        if dict(mesh.shape).get(SEQ_AXIS, 1) > 1 and model_cfg.dropout > 0.0:
+            emit(
+                "[trainer] WARNING: attention-probability dropout is "
+                "disabled under sequence parallelism (scores never exist "
+                "in one place on the ring path); residual/embedding "
+                "dropout still applies — semantics differ from "
+                "replicated training at the same dropout rate"
+            )
 
-        sp = dict(mesh.shape).get(SEQ_AXIS, 1)
-        seq_axis = SEQ_AXIS if sp > 1 else None
-        batch_spec = None
-        loss_fn = None
-        if seq_axis:
-            validate_seq_block(cfg, model_cfg, sp)
-            if model_cfg.dropout > 0.0:
-                emit(
-                    "[trainer] WARNING: attention-probability dropout is "
-                    "disabled under sequence parallelism (scores never exist "
-                    "in one place on the ring path); residual/embedding "
-                    "dropout still applies — semantics differ from "
-                    "replicated training at the same dropout rate"
-                )
-            batch_spec = P(DATA_AXIS, SEQ_AXIS)  # rows over data, tokens over seq
-            from distributed_lion_tpu.models.loss import clm_loss_seq_parallel
-
-            if cfg.vocab_chunks > 0:
-                # long-context x chunked-vocab: stream the tied head over
-                # vocab chunks per shard (ops/xent) — the [B, T/sp, V]
-                # logits never materialize either
-                from distributed_lion_tpu.models.gpt2 import gpt2_hidden
-                from distributed_lion_tpu.ops.xent import (
-                    chunked_clm_loss_seq_parallel,
-                )
-
-                def loss_fn(params, batch, dropout_key):
-                    hidden, _ = gpt2_hidden(params, batch, model_cfg,
-                                            dropout_key=dropout_key,
-                                            tp_axis=tp_axis,
-                                            seq_axis=SEQ_AXIS)
-                    return chunked_clm_loss_seq_parallel(
-                        hidden, params["wte"], batch, cfg.vocab_chunks,
-                        SEQ_AXIS, valid_v=model_cfg.vocab_size)
-
-                loss_fn._vocab_chunked = True
-            else:
-                def loss_fn(params, batch, dropout_key):
-                    logits = apply_fn(params, batch, dropout_key)
-                    return clm_loss_seq_parallel(logits, batch, SEQ_AXIS)
-
-        def apply_fn(params, tokens, dropout_key):
-            return gpt2_apply(params, tokens, model_cfg, dropout_key=dropout_key,
-                              tp_axis=tp_axis, seq_axis=seq_axis)
-
-        if cfg.tp_vocab and loss_fn is None:
-            from distributed_lion_tpu.models.gpt2 import gpt2_hidden
-            from distributed_lion_tpu.ops.xent import tp_vocab_clm_loss_and_metrics
-
-            def loss_fn(params, batch, dropout_key):
-                # params["wte"] is this rank's [V/tp, d] vocab-row slice:
-                # VocabParallelEmbedding on the way in, its transpose as the
-                # tied vocab-parallel head on the way out
-                hidden, _ = gpt2_hidden(params, batch, model_cfg,
-                                        dropout_key=dropout_key,
-                                        tp_axis=tp_axis,
-                                        vocab_axis=TENSOR_AXIS)
-                return tp_vocab_clm_loss_and_metrics(
-                    hidden, params["wte"].T, batch, TENSOR_AXIS,
-                    valid_v=model_cfg.vocab_size)
-
-            loss_fn._tp_vocab = True  # consumed; don't trip the guard
-
-        elif cfg.vocab_chunks > 0 and loss_fn is None:
-            from distributed_lion_tpu.models.gpt2 import gpt2_hidden
-            from distributed_lion_tpu.ops.xent import chunked_clm_loss_and_metrics
-
-            def loss_fn(params, batch, dropout_key):
-                hidden, _ = gpt2_hidden(params, batch, model_cfg,
-                                        dropout_key=dropout_key, tp_axis=tp_axis)
-                return chunked_clm_loss_and_metrics(
-                    hidden, params["wte"], batch, cfg.vocab_chunks,
-                    valid_v=model_cfg.vocab_size)
-
-            loss_fn._vocab_chunked = True  # consumed; don't trip the guard
-
-        elif loss_fn is None:
-            loss_fn = gpt2_dense_loss(model_cfg, tp_axis)
-
-        return Trainer(cfg, mesh, apply_fn, params, param_specs=param_specs,
-                       loss_fn=loss_fn, batch_spec=batch_spec,
+        loss_fn, loss_spec = gpt2_clm_loss(cfg, mesh, model_cfg)
+        return Trainer(cfg, mesh, None, params, param_specs=param_specs,
+                       loss_fn=loss_fn, loss_spec=loss_spec,
                        remat_decision=remat_decision)
 
     @staticmethod
@@ -2773,12 +2728,8 @@ class Trainer:
         imported checkpoint too. Composes with dp, tensor (dp×tp), sequence
         (dp×sp) and pipeline (dp×pp, models/llama_pipe) parallelism; the
         expert axis is GPT-2-MoE-only."""
-        from distributed_lion_tpu.models.llama import (
-            llama_apply,
-            llama_hidden,
-            llama_init,
-        )
-        from distributed_lion_tpu.models.loss import clm_loss_seq_parallel
+        from distributed_lion_tpu.models.llama import llama_hidden, llama_init
+        from distributed_lion_tpu.ops.quant import maybe_dequant
         from distributed_lion_tpu.parallel.tensor_parallel import (
             llama_param_specs,
             validate_tp,
@@ -2794,17 +2745,7 @@ class Trainer:
                              model_cfg))
         model_cfg, remat_decision = apply_remat_policy(cfg, model_cfg, mesh,
                                                        params)
-        n = count_params(params)
-        shape = dict(mesh.shape)
-        cfg = resolve_auto_comm(
-            cfg, mesh, n,
-            params_replicated=all(
-                shape.get(ax, 1) == 1 for ax in (TENSOR_AXIS, PIPE_AXIS)),
-        )
-        acct = wire_bytes_per_param(n, data_axis_size(mesh), cfg.wire,
-                                    vote_every=cfg.vote_every,
-                                    accum_steps=cfg.gradient_accumulation_steps,
-                                    vote_buckets=cfg.vote_buckets or 1)
+        cfg, n, acct = _resolve_comm(cfg, mesh, params)
         tp = mesh.shape[TENSOR_AXIS]
         pp = dict(mesh.shape).get(PIPE_AXIS, 1)
         emit(
@@ -2822,120 +2763,33 @@ class Trainer:
                 llama_pipeline_param_specs,
                 llama_pipeline_params,
                 make_llama_pipeline_loss,
-                validate_llama_pipeline,
             )
 
-            if cfg.tp_vocab:
-                raise NotImplementedError(
-                    "--tp_vocab under --pipeline_parallel is not wired (the "
-                    "pipeline loss carries its own replicated head); drop one"
-                )
-            if tp > 1:
-                validate_tp(model_cfg, tp, "llama")
-            sp_pipe = dict(mesh.shape).get(SEQ_AXIS, 1)
-            if sp_pipe > 1:
-                validate_seq_block(cfg, model_cfg, sp_pipe)
-            n_micro = cfg.pipeline_microbatches or pp
-            validate_llama_pipeline(model_cfg, cfg, pp, n_micro)
-            loss_fn = make_llama_pipeline_loss(
-                model_cfg, n_micro,
-                tp_axis=TENSOR_AXIS if tp > 1 else None,
-                vocab_chunks=cfg.vocab_chunks,
-                seq_axis=SEQ_AXIS if sp_pipe > 1 else None)
-            if cfg.vocab_chunks > 0:
-                loss_fn._vocab_chunked = True  # consumed; don't trip the guard
-            return Trainer(
-                cfg, mesh,
-                apply_fn=None,
-                params=llama_pipeline_params(params, pp),
-                param_specs=llama_pipeline_param_specs(tensor=tp > 1),
-                loss_fn=loss_fn,
-                batch_spec=(P(DATA_AXIS, SEQ_AXIS) if sp_pipe > 1 else None),
-                remat_decision=remat_decision,
-            )
-        if cfg.tp_vocab and tp <= 1:
-            raise ValueError("--tp_vocab needs --tensor_parallel > 1 (it "
-                             "shards the lm_head over the tensor axis)")
-        if cfg.tp_vocab and cfg.vocab_chunks > 0:
-            raise NotImplementedError(
-                "--tp_vocab and --vocab_chunks are alternative head "
-                "strategies (vocab sharded across ranks vs streamed in "
-                "chunks); pick one"
-            )
+            return _pipelined_trainer(
+                cfg, mesh, model_cfg, "llama", remat_decision,
+                make_llama_pipeline_loss, llama_pipeline_params(params, pp),
+                llama_pipeline_param_specs)
         param_specs = None
-        tp_axis = None
         if tp > 1:
             validate_tp(model_cfg, tp, "llama")
-            if cfg.tp_vocab and model_cfg.vocab_size % tp:
-                raise ValueError(
-                    f"--tp_vocab: vocab {model_cfg.vocab_size} not divisible "
-                    f"by tensor axis {tp}"
-                )
             param_specs = llama_param_specs(model_cfg,
                                             vocab_parallel=cfg.tp_vocab)
-            tp_axis = TENSOR_AXIS
 
-        sp = dict(mesh.shape).get(SEQ_AXIS, 1)
-        seq_axis = SEQ_AXIS if sp > 1 else None
-        batch_spec = None
-        loss_fn = None
-        if seq_axis and cfg.tp_vocab:
-            raise NotImplementedError(
-                "--tp_vocab under --seq_parallel is not wired; pick one"
-            )
-        if seq_axis:
-            validate_seq_block(cfg, model_cfg, sp)
-            batch_spec = P(DATA_AXIS, SEQ_AXIS)
+        def hidden_fn(params, batch, dropout_key, *, tp_axis, seq_axis,
+                      vocab_axis):
+            # our Llama (like HF's) has no dropout, and under a vocab axis
+            # only the lm_head is sharded ([d, V/tp] column slices): the
+            # embedding stays whole
+            return llama_hidden(params, batch, model_cfg, tp_axis=tp_axis,
+                                seq_axis=seq_axis)
 
-            if cfg.vocab_chunks > 0:
-                # long-context x huge-vocab: stream the lm_head per shard
-                # (ops/xent chunked CE + the shard-boundary label ppermute)
-                from distributed_lion_tpu.ops.xent import (
-                    chunked_clm_loss_seq_parallel,
-                )
-
-                def loss_fn(params, batch, dropout_key):
-                    hidden = llama_hidden(params, batch, model_cfg,
-                                          tp_axis=tp_axis, seq_axis=SEQ_AXIS)
-                    return chunked_clm_loss_seq_parallel(
-                        hidden, params["lm_head"], batch, cfg.vocab_chunks,
-                        SEQ_AXIS, emb_layout="dv")
-
-                loss_fn._vocab_chunked = True
-            else:
-                def loss_fn(params, batch, dropout_key):
-                    logits = llama_apply(params, batch, model_cfg,
-                                         tp_axis=tp_axis, seq_axis=SEQ_AXIS)
-                    return clm_loss_seq_parallel(logits, batch, SEQ_AXIS)
-
-        def apply_fn(params, tokens, dropout_key):
-            del dropout_key  # our Llama (like HF's) has no dropout
-            return llama_apply(params, tokens, model_cfg, tp_axis=tp_axis)
-
-        if cfg.tp_vocab and loss_fn is None:
-            from distributed_lion_tpu.ops.xent import tp_vocab_clm_loss_and_metrics
-
-            def loss_fn(params, batch, dropout_key):
-                hidden = llama_hidden(params, batch, model_cfg, tp_axis=tp_axis)
-                # params["lm_head"] is this rank's [d, V/tp] column slice
-                return tp_vocab_clm_loss_and_metrics(
-                    hidden, params["lm_head"], batch, TENSOR_AXIS)
-
-            loss_fn._tp_vocab = True  # consumed; don't trip the guard
-
-        elif cfg.vocab_chunks > 0 and loss_fn is None:
-            from distributed_lion_tpu.ops.xent import chunked_clm_loss_and_metrics
-
-            def loss_fn(params, batch, dropout_key):
-                hidden = llama_hidden(params, batch, model_cfg, tp_axis=tp_axis)
-                return chunked_clm_loss_and_metrics(
-                    hidden, params["lm_head"], batch, cfg.vocab_chunks,
-                    None, emb_layout="dv")
-
-            loss_fn._vocab_chunked = True  # consumed; don't trip the guard
-
-        return Trainer(cfg, mesh, apply_fn, params, param_specs=param_specs,
-                       loss_fn=loss_fn, batch_spec=batch_spec,
+        loss_fn, loss_spec = _clm_head_loss(
+            cfg, mesh, model_cfg, hidden_fn,
+            lambda params: maybe_dequant(params["lm_head"],
+                                         model_cfg.compute_dtype),
+            "dv", head_cols=model_cfg.vocab_size)
+        return Trainer(cfg, mesh, None, params, param_specs=param_specs,
+                       loss_fn=loss_fn, loss_spec=loss_spec,
                        remat_decision=remat_decision)
 
 

@@ -115,7 +115,7 @@ def block_saved_bytes(model_cfg, rows: int, seq: int, *, tp: int = 1,
 def head_bytes(model_cfg, rows: int, seq: int, *, fused: bool,
                vocab_shards: int = 1, vocab_chunks: int = 0) -> int:
     """The loss head's workspace, from ``ops/xent``'s shapes. The fused
-    kernel pair (``fused=True``: ``ops/xent.fused_kernel_applies``) keeps
+    kernel pair (``fused=True``: ``ops/xent.head_path`` said ``fused``) keeps
     the logits in VMEM and writes two float32 partial gradients of the head
     ``[V, d]`` beside the hidden states and their cotangent; every other
     head holds the float32 logits and their cotangent ``[rows, seq, V]``,
@@ -233,9 +233,10 @@ def resolve_for(cfg, model_cfg, mesh, params, *, bytes_limit: Optional[int],
         attn = "kernel"
     else:
         attn = "library" if attn_ops.library_kernel_applies(seq) else "xla"
-    fused = (gpt2 and tp == 1 and not cfg.vocab_chunks
-             and xent_ops.fused_kernel_applies(model_cfg.d_model,
-                                               model_cfg.compute_dtype))
+    fused = xent_ops.head_path(
+        "vd" if gpt2 else "dv", model_cfg.d_model, model_cfg.compute_dtype,
+        chunks=cfg.vocab_chunks,
+        vocab_axis=TENSOR_AXIS if cfg.tp_vocab else None) == "fused"
     # a tensor axis splits the blocks' matrices; what stays whole
     # (embeddings, norms) is small beside them, and counting it split too
     # errs by less than the share's room

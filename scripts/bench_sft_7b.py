@@ -108,10 +108,10 @@ def run(quant: str = "nf4", batch_per_dev: int = 1, accum: int = 4,
     adapters = lora_init(jax.random.key(1), base, lora_cfg)
     n_adapter = sum(int(np.prod(a.shape)) for a in jax.tree.leaves(adapters))
 
-    from distributed_lion_tpu.models.llama import llama_apply, llama_hidden
-    from distributed_lion_tpu.models.loss import clm_loss_and_metrics
+    from distributed_lion_tpu.models.llama import llama_hidden
     from distributed_lion_tpu.ops.quant import maybe_dequant
-    from distributed_lion_tpu.ops.xent import chunked_clm_loss_and_metrics
+    from distributed_lion_tpu.ops.xent import clm_head_loss
+    from distributed_lion_tpu.train.loop import LossSpec
 
     # the frozen base rides the Trainer's frozen_params slot (replicated
     # device_put + a (params, frozen, batch, key) loss) instead of a
@@ -122,17 +122,13 @@ def run(quant: str = "nf4", batch_per_dev: int = 1, accum: int = 4,
     # codes ship once and compile stays shape-only
     def loss_fn(params, frozen, batch, dropout_key):
         effective = apply_adapters(frozen, params, lora_cfg)
-        if vocab_chunks > 0:
-            hidden = llama_hidden(effective, batch, model_cfg)
-            emb = maybe_dequant(effective["lm_head"], model_cfg.compute_dtype)
-            return chunked_clm_loss_and_metrics(
-                hidden, emb, batch, vocab_chunks, None, emb_layout="dv")
-        logits = llama_apply(effective, batch, model_cfg)
-        return clm_loss_and_metrics(logits, batch, None)
+        hidden = llama_hidden(effective, batch, model_cfg)
+        return clm_head_loss(
+            hidden, maybe_dequant(effective["lm_head"], hidden.dtype), batch,
+            layout="dv", chunks=vocab_chunks)
 
-    loss_fn._vocab_chunked = True
     trainer = Trainer(cfg, mesh, apply_fn=None, params=adapters, loss_fn=loss_fn,
-                      frozen_params=base)
+                      loss_spec=LossSpec(vocab_chunks=True), frozen_params=base)
     gb = trainer.global_train_batch()
     tokens_per_step = gb * seq_len
 

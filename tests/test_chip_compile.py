@@ -456,14 +456,16 @@ def test_fused_xent_kernels_compile(one_chip, rows, valid_v):
 
 
 @pytest.fixture(scope="module")
-def train_step_hlo(one_chip):
-    """Loss and gradients of the trainer's dense branch
-    (``train/loop.gpt2_dense_loss``: what ``Trainer.for_gpt2`` steps at both
+def train_step_hlo(topo, one_chip):
+    """Loss and gradients of the trainer's dense GPT-2 loss
+    (``train/loop.gpt2_clm_loss``: what ``Trainer.for_gpt2`` steps at both
     training cells) for a GPT-2 of the published widths and vocabulary cut
     to two layers, at a cell's microbatch, compiled for the described chip
     with every ``auto`` resolved as a TPU backend resolves it."""
     from distributed_lion_tpu.models import gpt2
-    from distributed_lion_tpu.train.loop import gpt2_dense_loss
+    from distributed_lion_tpu.train.loop import TrainConfig, gpt2_clm_loss
+
+    mesh = Mesh(np.array(topo.devices[:1]), ("data",))
 
     texts = {}
 
@@ -476,7 +478,7 @@ def train_step_hlo(one_chip):
                 jax.eval_shape(lambda: gpt2.gpt2_init(jax.random.key(0), cfg)))
             tokens = jax.ShapeDtypeStruct((B, 1024), jnp.int32,
                                           sharding=one_chip)
-            loss = gpt2_dense_loss(cfg)
+            loss, _ = gpt2_clm_loss(TrainConfig(), mesh, cfg)
             real = jax.default_backend
             jax.default_backend = lambda: "tpu"
             try:
@@ -531,7 +533,7 @@ def test_loss_head_compiles_under_the_workers_shard_map(topo, monkeypatch):
 
     def worker(h, w, t):
         dh, dw = jax.grad(
-            lambda h, w: xent_ops.tied_head_clm_loss_and_metrics(h, w, t)[0],
+            lambda h, w: xent_ops.clm_head_loss(h, w, t, layout="vd")[0],
             argnums=(0, 1))(h, w)
         return dh, dw[None]                     # a worker's own gradient
 

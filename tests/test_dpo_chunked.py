@@ -74,25 +74,26 @@ def _loss_fns(model_cfg, base, lcfg, vocab_chunks):
     """(dense, chunked) DPO loss fns over the same frozen base."""
     pol_dense = lora_apply_fn(
         lambda p, t: llama_apply(p, t, model_cfg), base, lcfg)
-    dense = make_dpo_loss_fn(
+    dense, dense_spec = make_dpo_loss_fn(
         policy_apply=pol_dense,
         ref_apply=lambda t: llama_apply(base, t, model_cfg), beta=0.1)
+    assert not dense_spec.vocab_chunks   # nothing to honour the flag with
 
     def hidden_head(p, t):
         return llama_hidden(p, t, model_cfg), p["lm_head"]
 
     pol_chunked = lora_apply_fn(hidden_head, base, lcfg)
-    chunked = make_dpo_loss_fn(
+    chunked, spec = make_dpo_loss_fn(
         policy_apply=pol_chunked,
         ref_apply=lambda t: hidden_head(base, t), beta=0.1,
         vocab_chunks=vocab_chunks)
+    assert spec.vocab_chunks is True     # what the Trainer's guard reads
     return dense, chunked
 
 
 def test_dpo_loss_and_grads_match_dense():
     model_cfg, base, lcfg, adapters = _pieces()
     dense, chunked = _loss_fns(model_cfg, base, lcfg, vocab_chunks=4)
-    assert getattr(chunked, "_vocab_chunked") is True
     batch = jax.tree.map(jnp.asarray,
                          _rand_batch(np.random.default_rng(1), 2, 32,
                                      model_cfg.vocab_size))
@@ -127,7 +128,7 @@ def _train(mesh, sp, vocab_chunks, steps=6):
         def fwd(p, t):
             return llama_apply(p, t, model_cfg, **kw)
         ref_fwd = lambda t: fwd(base, t)  # noqa: E731
-    loss_fn = make_dpo_loss_fn(
+    loss_fn, loss_spec = make_dpo_loss_fn(
         policy_apply=lora_apply_fn(fwd, base, lcfg), ref_apply=ref_fwd,
         beta=0.1, seq_axis=seq_axis, vocab_chunks=vocab_chunks)
 
@@ -138,9 +139,10 @@ def _train(mesh, sp, vocab_chunks, steps=6):
         eval_steps=1000, save_steps=1000, seed=0,
         vocab_chunks=vocab_chunks,
     )
-    spec = P(DATA_AXIS, SEQ_AXIS) if sp > 1 else None
+    assert loss_spec.batch_spec == (P(DATA_AXIS, SEQ_AXIS) if sp > 1
+                                    else None)
     trainer = Trainer(cfg, mesh, apply_fn=None, params=adapters,
-                      loss_fn=loss_fn, batch_spec=spec)
+                      loss_fn=loss_fn, loss_spec=loss_spec)
     rng = np.random.default_rng(2)
     batches = [_rand_batch(rng, trainer.global_train_batch(), 64,
                            LlamaConfig.tiny().vocab_size)

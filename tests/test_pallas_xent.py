@@ -1,8 +1,9 @@
 """The loss head's kernel pair (ops/pallas_xent), through Pallas interpret
 mode at small sizes, against ``clm_loss_and_metrics`` on dense
 float32-accumulated logits: loss, accuracy, n_tokens and both gradients;
-and the rule by which ``ops/xent.tied_head_clm_loss_and_metrics`` takes the
-kernels, from what a call shows (never an option)."""
+and the rule (``ops/xent.head_path``) by which the entry
+(``ops/xent.clm_head_loss``) takes the kernels or another head, from what a
+call shows (never an option), which ``train/remat`` asks and does not copy."""
 
 import jax
 import jax.numpy as jnp
@@ -185,7 +186,77 @@ def test_more_rows_than_one_group_holds(monkeypatch):
 def test_kernel_applies_from_what_a_call_shows(monkeypatch, backend, d, dtype,
                                                takes):
     monkeypatch.setattr(jax, "default_backend", lambda: backend)
-    assert X.fused_kernel_applies(d, dtype) is takes
+    assert X.head_path("vd", d, dtype) == ("fused" if takes else "dense")
+
+
+_CELL = ("vd", 768, jnp.bfloat16)           # both training cells' head
+_REFUSED = {"chunks": "--tp_vocab and --vocab_chunks are alternative head "
+                      "strategies",
+            "seq_axis": "--tp_vocab under --seq_parallel is not wired"}
+
+
+@pytest.mark.parametrize("head,kw,path", [
+    (_CELL, {}, "fused"),
+    (("dv", 768, jnp.bfloat16), {}, "dense"),      # an lm_head lies [d, V]
+    (_CELL, dict(chunks=4), "chunked"),
+    (("dv", 4096, jnp.bfloat16), dict(chunks=8), "chunked"),
+    (_CELL, dict(vocab_axis="tensor"), "tp_vocab"),
+    (("dv", 64, jnp.float32), dict(vocab_axis="tensor"), "tp_vocab"),
+    (_CELL, dict(seq_axis="seq"), "seq"),
+    (("dv", 64, jnp.float32), dict(seq_axis="seq"), "seq"),
+    (_CELL, dict(seq_axis="seq", chunks=4), "seq_chunked"),
+    (_CELL, dict(vocab_axis="tensor", chunks=4), _REFUSED["chunks"]),
+    (("dv", 64, jnp.float32), dict(vocab_axis="tensor", seq_axis="seq"),
+     _REFUSED["seq_axis"]),
+], ids=lambda v: v if isinstance(v, str) and " " not in v else None)
+def test_the_rule_from_what_a_call_shows(monkeypatch, head, kw, path):
+    """Every outcome of ``head_path`` on a backend that says "tpu" (the
+    kernels are the last thing asked: an axis or chunks come first), and
+    the two combinations it refuses, by the words the CLIs' tests match."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    if path in _REFUSED.values():
+        with pytest.raises(NotImplementedError, match=path):
+            X.head_path(*head, **kw)
+    else:
+        assert X.head_path(*head, **kw) == path
+    with pytest.raises(ValueError, match="layout"):
+        X.head_path("dd", *head[1:], **kw)
+
+
+def test_the_checkpoint_resolver_asks_the_rule(monkeypatch):
+    """``remat.resolve_for`` sizes the loss head from ``head_path``'s
+    answer: make the rule say ``dense`` at cell 1's shapes and every
+    rung's predicted peak grows by the float32 logits and their cotangent
+    less the kernels' two partial head gradients."""
+    from distributed_lion_tpu.models.gpt2 import GPT2Config, gpt2_init
+    from distributed_lion_tpu.parallel.mesh import make_mesh
+    from distributed_lion_tpu.train import remat
+    from distributed_lion_tpu.train.loop import TrainConfig
+
+    model = GPT2Config.gpt2_124m(dropout=0.0)
+    cfg = TrainConfig(lion=True, per_device_train_batch_size=20,
+                      block_size=1024)
+    shapes = jax.eval_shape(lambda: gpt2_init(jax.random.key(0), model))
+    mesh = make_mesh(data=1, devices=jax.devices()[:1])
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    def peaks():
+        return remat.resolve_for(cfg, model, mesh, shapes,
+                                 bytes_limit=16 * 2 ** 30).predicted
+
+    asked = []
+    real = X.head_path
+    monkeypatch.setattr(
+        X, "head_path", lambda *a, **k: asked.append((a, k)) or real(*a, **k))
+    fused = peaks()
+    assert asked == [(_CELL, dict(chunks=0, vocab_axis=None))]
+    monkeypatch.setattr(X, "head_path", lambda *a, **k: "dense")
+    dense = peaks()
+    grown = (remat.head_bytes(model, 20, 1024, fused=False)
+             - remat.head_bytes(model, 20, 1024, fused=True))
+    assert grown > 3 * 2 ** 30                  # two [20, 1024, 50304] f32
+    assert {r: dense[r] - fused[r] for r in fused} == dict.fromkeys(fused,
+                                                                    grown)
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
@@ -202,7 +273,10 @@ def test_on_the_cpu_the_entry_is_the_dense_path_bit_for_bit(dtype, valid_v):
             has_aux=True))(hidden, head)
         return [loss, m["accuracy"], m["n_tokens"], *g]
 
-    for a, b in zip(both(X.tied_head_clm_loss_and_metrics), both(_dense)):
+    def entry(h, w, t, m, v):
+        return X.clm_head_loss(h, w, t, layout="vd", loss_mask=m, valid_v=v)
+
+    for a, b in zip(both(entry), both(_dense)):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
@@ -222,8 +296,8 @@ def test_on_a_tpu_backend_the_entry_takes_the_kernels_and_says_so(monkeypatch,
     j = journal.Journal(str(tmp_path))
     journal.install(j)
     try:
-        loss, m = X.tied_head_clm_loss_and_metrics(hidden, head, tokens)
-        X.tied_head_clm_loss_and_metrics(hidden, head, tokens)  # said once
+        loss, m = X.clm_head_loss(hidden, head, tokens, layout="vd")
+        X.clm_head_loss(hidden, head, tokens, layout="vd")  # said once
         events = [r for r in j.records() if r.get("name") == "xent_resolved"]
     finally:
         journal.uninstall(j)
@@ -243,16 +317,17 @@ def test_on_a_tpu_backend_the_entry_takes_the_kernels_and_says_so(monkeypatch,
 
 
 def _trainer(monkeypatch, mesh_kw, **cfg_kw):
-    """``Trainer.for_gpt2`` at the tiny preset with the dense branch's loss
-    builder and the kernel entry replaced by recorders."""
+    """``Trainer.for_gpt2`` at the tiny preset with the rule's answers
+    recorded and the kernel path replaced by a failure."""
     from distributed_lion_tpu.models.gpt2 import GPT2Config
     from distributed_lion_tpu.parallel.mesh import make_mesh
     from distributed_lion_tpu.train import loop
 
     calls = []
-    real = loop.gpt2_dense_loss
-    monkeypatch.setattr(loop, "gpt2_dense_loss",
-                        lambda *a: calls.append("dense") or real(*a))
+    real = X.head_path
+    monkeypatch.setattr(
+        X, "head_path",
+        lambda *a, **k: calls.append(real(*a, **k)) or calls[-1])
     monkeypatch.setattr(
         X, "_fused_clm_loss_and_metrics",
         lambda *a, **k: pytest.fail("the kernel path was reached"))
@@ -265,32 +340,33 @@ def _trainer(monkeypatch, mesh_kw, **cfg_kw):
     return t, calls
 
 
-@pytest.mark.parametrize("mesh_kw,cfg_kw", [
-    (dict(data=2, tensor=2), dict(tp_vocab=True)),
-    (dict(data=2, seq=2), dict()),
-    (dict(data=2), dict(vocab_chunks=4)),
-    (dict(data=2, seq=2), dict(vocab_chunks=4)),
+@pytest.mark.parametrize("mesh_kw,cfg_kw,path", [
+    (dict(data=2, tensor=2), dict(tp_vocab=True), "tp_vocab"),
+    (dict(data=2, seq=2), dict(), "seq"),
+    (dict(data=2), dict(vocab_chunks=4), "chunked"),
+    (dict(data=2, seq=2), dict(vocab_chunks=4), "seq_chunked"),
 ], ids=["tp_vocab", "seq_axis", "vocab_chunks", "seq_axis+vocab_chunks"])
-def test_other_head_strategies_never_reach_the_entry(monkeypatch, mesh_kw,
-                                                     cfg_kw):
+def test_other_head_strategies_never_reach_the_kernels(monkeypatch, mesh_kw,
+                                                       cfg_kw, path):
     """``tp_vocab``, a sequence axis and ``vocab_chunks > 0`` keep their own
-    losses: the dense branch's builder is not called, and one train step
-    on a backend that says "tpu" never enters the kernel path."""
+    heads behind the entry: the rule names them at construction, and one
+    train step on a backend that says "tpu" never enters the kernel path."""
     from distributed_lion_tpu.data.sources import (
         batch_iterator,
         synthetic_lm_dataset,
     )
 
     t, calls = _trainer(monkeypatch, mesh_kw, **cfg_kw)
-    assert calls == []
+    assert calls == [path]
     blocks = synthetic_lm_dataset(64, 32, 256)
     monkeypatch.setattr(X, "fused_kernel_applies", lambda *a: True)
     t.train(batch_iterator(blocks, t.global_train_batch(), seed=1),
             max_steps=1)
+    assert set(calls) == {path}          # and at every trace of the step
     t.close()
 
 
 def test_the_dense_branch_builds_its_loss_from_the_entry(monkeypatch):
     t, calls = _trainer(monkeypatch, dict(data=2))
-    assert calls == ["dense"]
+    assert calls == ["dense"]                   # the CPU's answer
     t.close()

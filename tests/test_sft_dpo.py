@@ -94,12 +94,12 @@ def test_dpo_loss_zero_at_init_and_direction():
         "rejected_mask": jnp.ones((1, 3), bool),
     }
     ref = apply_const(0.0)
-    loss_fn_same = make_dpo_loss_fn(lambda p, t: ref(t), ref, beta=0.1)
+    loss_fn_same, _ = make_dpo_loss_fn(lambda p, t: ref(t), ref, beta=0.1)
     loss0, m0 = loss_fn_same(None, batch, None)
     np.testing.assert_allclose(float(loss0), np.log(2), rtol=1e-5)
 
     pol = apply_const(1.0)  # policy now prefers token 1 (the chosen one)
-    loss_fn_better = make_dpo_loss_fn(lambda p, t: pol(t), ref, beta=0.1)
+    loss_fn_better, _ = make_dpo_loss_fn(lambda p, t: pol(t), ref, beta=0.1)
     loss1, m1 = loss_fn_better(None, batch, None)
     assert float(loss1) < float(loss0)
     assert float(m1["reward_margin"]) > 0

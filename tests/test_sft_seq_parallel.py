@@ -19,7 +19,7 @@ from distributed_lion_tpu.models.loss import (
     clm_loss_seq_parallel,
 )
 from distributed_lion_tpu.parallel.mesh import DATA_AXIS, SEQ_AXIS, make_mesh
-from distributed_lion_tpu.train.loop import TrainConfig, Trainer
+from distributed_lion_tpu.train.loop import LossSpec, TrainConfig, Trainer
 
 
 def _cfg(**kw):
@@ -51,7 +51,8 @@ def _train(mesh, sp, steps=8):
             return clm_loss_seq_parallel(logits, batch, SEQ_AXIS)
 
         trainer = Trainer(cfg, mesh, apply_fn=None, params=adapters,
-                          loss_fn=loss_fn, batch_spec=P(DATA_AXIS, SEQ_AXIS))
+                          loss_fn=loss_fn,
+                          loss_spec=LossSpec(batch_spec=P(DATA_AXIS, SEQ_AXIS)))
     else:
         def loss_fn(params, batch, dropout_key):
             effective = apply_adapters(base, params, lcfg)
@@ -118,7 +119,7 @@ def test_sft_tp_sp_trajectory_matches_pure_dp():
                       apply_fn=None, params=adapters2,
                       param_specs=adapter_specs, loss_fn=tpsp_loss,
                       frozen_params=base, frozen_specs=base_specs,
-                      batch_spec=P(DATA_AXIS, SEQ_AXIS))
+                      loss_spec=LossSpec(batch_spec=P(DATA_AXIS, SEQ_AXIS)))
 
     rng = np.random.default_rng(7)
     steps = 6
@@ -198,15 +199,14 @@ def _train_dpo(mesh, sp, steps=6):
     pol = lora_apply_fn(
         lambda p, t: llama_apply(p, t, model_cfg, seq_axis=seq_axis),
         base, lcfg)
-    loss_fn = make_dpo_loss_fn(
+    loss_fn, loss_spec = make_dpo_loss_fn(
         policy_apply=pol,
         ref_apply=lambda t: llama_apply(base, t, model_cfg, seq_axis=seq_axis),
         beta=0.1, seq_axis=seq_axis,
     )
     cfg = _cfg(learning_rate=1e-3)
-    spec = P(DATA_AXIS, SEQ_AXIS) if sp > 1 else None
     trainer = Trainer(cfg, mesh, apply_fn=None, params=adapters,
-                      loss_fn=loss_fn, batch_spec=spec)
+                      loss_fn=loss_fn, loss_spec=loss_spec)
     model_cfg_vocab = model_cfg.vocab_size
     batches = _dpo_batches(steps, trainer.global_train_batch(), 64,
                            model_cfg_vocab)

@@ -20,10 +20,7 @@ from distributed_lion_tpu.models.gpt2 import (
     gpt2_init_cache,
 )
 from distributed_lion_tpu.models.loss import clm_loss_and_metrics
-from distributed_lion_tpu.ops.xent import (
-    chunked_clm_loss_and_metrics,
-    chunked_softmax_xent,
-)
+from distributed_lion_tpu.ops.xent import chunked_softmax_xent, clm_head_loss
 
 V, PAD_M = 250, 64  # padded_vocab = 256
 
@@ -79,12 +76,12 @@ def test_pad_rows_do_not_leak_even_when_nonzero():
     l_clean = apply(p, tok, padded)
     l_junk = apply({**p, "wte": junk}, tok, padded)
     np.testing.assert_array_equal(np.asarray(l_clean), np.asarray(l_junk))
-    loss_c, _ = chunked_clm_loss_and_metrics(
+    loss_c, _ = clm_head_loss(
         jax.random.normal(jax.random.key(2), (2, 16, padded.d_model)),
-        junk, tok, n_chunks=4, valid_v=V)
-    loss_u, _ = chunked_clm_loss_and_metrics(
+        junk, tok, layout="vd", chunks=4, valid_v=V)
+    loss_u, _ = clm_head_loss(
         jax.random.normal(jax.random.key(2), (2, 16, padded.d_model)),
-        junk[:V], tok, n_chunks=4)
+        junk[:V], tok, layout="vd", chunks=4)
     np.testing.assert_allclose(float(loss_c), float(loss_u), atol=1e-6)
 
 
