@@ -378,6 +378,158 @@ def phase_kernels() -> None:
 
 
 # ------------------------------------------------------------------- train
+# ---------------------------------------------------------------- train_moe
+def phase_train_moe() -> None:
+    """The trained expert-and-window block at the published widths
+    (models/mellum: hidden 2,304, 32 query heads over 4 kv heads of 128,
+    experts of 896, 16 of 64 held, top 8) on 8,192-token rows: the grouped
+    product's gradient in the ``moe_gmm`` kernels against the autodiff of
+    ``lax.ragged_dot``, the ``flash_gqa`` pair against the plain banded
+    softmax with the window's edge held to the position, and a block's
+    loss and gradients through the kernels in the lowered program."""
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+
+    from distributed_lion_tpu.models.mellum import (
+        MellumConfig,
+        mellum_hidden,
+        mellum_init,
+    )
+    from distributed_lion_tpu.ops import pallas_flash_attn as flash
+    from distributed_lion_tpu.parallel.expert import grouped_matmul
+
+    # -- the grouped product and its two gradient products, a held range
+    # with a tail (three quarters of the rows), an empty group, one of 1
+    d, f, held, rows = 2304, 896, 16, 8 * 8192
+    sizes = jnp.array([2048, 0, 1, 4095] + [850] * 12, jnp.int32)
+    n = int(sizes.sum())
+    ks = jax.random.split(jax.random.key(43), 8)
+    lhs = jax.random.normal(ks[0], (rows, d), jnp.bfloat16)
+    rhs = (jax.random.normal(ks[1], (held, d, f)) * 0.02).astype(jnp.bfloat16)
+    dy = jax.random.normal(ks[2], (rows, f), jnp.bfloat16)
+    live = (jnp.arange(rows) < n)[:, None]
+
+    def through(product):
+        def loss(lhs, rhs):
+            out = product(lhs, rhs).astype(jnp.float32)
+            return jnp.where(live, out * dy.astype(jnp.float32), 0).sum()
+
+        return jax.jit(jax.grad(loss, (0, 1)))
+
+    mine = through(lambda a, b: grouped_matmul(a, b, sizes, True))
+    text = mine.lower(lhs, rhs).as_text()
+    check({"moe_gmm", "moe_gmm_drhs"} <= set(mosaic_kernels(text)),
+          f"the gradient's kernels: {mosaic_kernels(text)}")
+    want = through(lambda a, b: lax.ragged_dot(
+        a, b, sizes, preferred_element_type=jnp.float32).astype(a.dtype))
+    got = mine(lhs, rhs)
+    # rows past the last group: zero here, whatever the forward left there
+    # (`ragged_dot`'s own gradient leaves them undefined on a TPU: compared
+    # over the groups' rows only)
+    check(float(jnp.abs(got[0][n:].astype(jnp.float32)).max()) == 0,
+          "dlhs is not zero in the rows past the last group")
+    for name, a, b in zip(("dlhs", "drhs"), got, want(lhs, rhs)):
+        a, b = a.astype(jnp.float32), b.astype(jnp.float32)
+        if name == "dlhs":
+            a, b = a[:n], b[:n]
+        gap = float(jnp.abs(a - b).max() / jnp.abs(b).max())
+        log(f"train_moe {name} against ragged_dot's autodiff: {gap:.2e} of "
+            "the largest entry")
+        check(gap < 2e-2, f"{name} gap {gap}")
+        check(bool(jnp.isfinite(a).all()), f"{name} is not finite")
+
+    # -- the attention pair at 32 / 4 heads of 128 over 8,192 positions
+    T, H, KV, window = 8192, 32, 4, 1024
+    q = jax.random.normal(ks[3], (1, T, H * 128), jnp.bfloat16)
+    k = jax.random.normal(ks[4], (1, T, KV * 128), jnp.bfloat16)
+    v = jax.random.normal(ks[5], (1, T, KV * 128), jnp.bfloat16)
+    w = jax.random.normal(ks[6], (1, T, H * 128), jnp.bfloat16)
+
+    def plain(q, k, v, window):
+        """Rows 4,096 .. 4,607 of the masked softmax, float32 (a block of
+        queries: 32 heads x 512 x 8,192 scores)."""
+        rows_ = slice(4096, 4608)
+        qh = q[0, rows_].reshape(512, KV, H // KV, 128).astype(jnp.float32)
+        kh = k[0].reshape(T, KV, 128).astype(jnp.float32)
+        vh = v[0].reshape(T, KV, 128).astype(jnp.float32)
+        s = jnp.einsum("sgrd,tgd->grst", qh, kh,
+                       precision=lax.Precision.HIGHEST) / 128 ** 0.5
+        i = jnp.arange(4096, 4608)[:, None]
+        j = jnp.arange(T)[None, :]
+        seen = (j <= i) & ((i - j < window) if window else True)
+        p = jax.nn.softmax(jnp.where(seen, s, -jnp.inf), -1)
+        return jnp.einsum("grst,tgd->sgrd", p, vh,
+                          precision=lax.Precision.HIGHEST).reshape(512, -1)
+
+    for win in (window, 0):
+        def mine_loss(q, k, v):
+            out = flash.flash_gqa(q, k, v, H, win)
+            return (out[0, 4096:4608].astype(jnp.float32)
+                    * w[0, 4096:4608].astype(jnp.float32)).sum(), out
+
+        def plain_loss(q, k, v):
+            out = plain(q, k, v, win)
+            return (out * w[0, 4096:4608].astype(jnp.float32)).sum(), out
+
+        a = jax.jit(jax.value_and_grad(mine_loss, (0, 1, 2), has_aux=True))
+        b = jax.jit(jax.value_and_grad(plain_loss, (0, 1, 2), has_aux=True))
+        kernels = set(mosaic_kernels(a.lower(q, k, v).as_text()))
+        check({"flash_gqa_lse", "flash_gqa_dq", "flash_gqa_dkv"} <= kernels,
+              f"the attention pair's kernels: {kernels}")
+        (_, out_a), grads_a = a(q, k, v)
+        (_, out_b), grads_b = b(q, k, v)
+        gap = float(jnp.abs(out_a[0, 4096:4608].astype(jnp.float32)
+                            - out_b).max())
+        log(f"train_moe flash_gqa window {win}: output gap {gap:.2e}")
+        check(gap < 3e-2, f"flash_gqa window {win} output gap {gap}")
+        for name, ga, gb in zip("qkv", grads_a, grads_b):
+            ga, gb = ga.astype(jnp.float32), gb.astype(jnp.float32)
+            gap = float(jnp.abs(ga - gb).max() / jnp.abs(gb).max())
+            log(f"train_moe flash_gqa window {win}: d{name} gap {gap:.2e} "
+                "of the largest entry")
+            check(gap < 4e-2, f"flash_gqa window {win} d{name} gap {gap}")
+    # a loud key at j0: read by the query at j0 + 1023, not by j0 + 1024
+    j0 = 3000
+    e = jnp.zeros((128,), jnp.bfloat16).at[0].set(12.0)   # score 12.7
+    seen = jax.jit(lambda: flash.flash_gqa(
+        jnp.tile(e, (1, T, H)), jnp.zeros((1, T, KV * 128), jnp.bfloat16
+                                          ).at[0, j0, :128].set(e),
+        jnp.zeros((1, T, KV * 128), jnp.bfloat16).at[0, j0, :128].set(1.0),
+        H, window))()[0, :, 0].astype(jnp.float32)
+    check(float(seen[j0 - 1]) == 0 and float(seen[j0]) > 0.9
+          and float(seen[j0 + window - 1]) > 0.9
+          and float(seen[j0 + window]) == 0,
+          f"the window's edge: {seen[j0 - 1]}, {seen[j0]}, "
+          f"{seen[j0 + window - 1]}, {seen[j0 + window]}")
+    log("train_moe window edge: key 3000 read by queries 3000 .. 4023 only")
+
+    # -- one window and one full block, loss and gradients in one program
+    cfg = MellumConfig(vocab_size=1024, n_layer=2, windowed=(True, False),
+                       held=(0, 16), remat_policy="full")
+    params = jax.jit(lambda key: mellum_init(key, cfg))(ks[7])
+    tokens = jax.random.randint(ks[7], (1, T), 0, 1024)
+
+    def loss(params, tokens):
+        hidden, counters = mellum_hidden(params, tokens, cfg)
+        return hidden.astype(jnp.float32).mean(), counters
+
+    step = jax.jit(jax.value_and_grad(loss, has_aux=True))
+    kernels = set(mosaic_kernels(step.lower(params, tokens).as_text()))
+    check({"moe_gmm", "moe_gmm_drhs", "flash_gqa_lse", "flash_gqa_dq",
+           "flash_gqa_dkv"} <= kernels, f"the block's kernels: {kernels}")
+    (value, counters), grads = step(params, tokens)
+    flat = jnp.concatenate([g.reshape(-1) for g in jax.tree.leaves(grads)])
+    check(bool(jnp.isfinite(flat).all()) and bool(jnp.isfinite(value)),
+          "a gradient of the block is not finite")
+    check(int(counters["moe_routed"]) == 2 * T * 8, counters)
+    share = int(counters["moe_assignments"]) / int(counters["moe_routed"])
+    log(f"train_moe block: kernels {sorted(kernels)}, held share "
+        f"{share:.3f} of the picks, {int(counters['moe_experts_hit'])} "
+        "experts hit in 2 layers")
+    check(0.15 < share < 0.35, f"held share {share}")
+
+
 def _metrics_rows(out_dir: str) -> list:
     with open(os.path.join(out_dir, "metrics.jsonl")) as f:
         return [json.loads(line) for line in f if line.strip()]
@@ -1820,7 +1972,7 @@ def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--chips", type=int, default=1, choices=(1, 4))
     ap.add_argument("--only", default="",
-                    help="run this one phase (kernels, train, serve, "
+                    help="run this one phase (kernels, train, train_moe, serve, "
                          "serve_latent, serve_window, serve_state, "
                          "serve_sparse, serve_mhc, multichip) and no "
                          "other")
@@ -1851,6 +2003,7 @@ def main() -> int:
     out_dir = os.path.join(work, "train")
     phases = ([("kernels", phase_kernels),
                ("train", lambda: phase_train(out_dir)),
+               ("train_moe", phase_train_moe),
                ("serve", lambda: phase_serve(out_dir)),
                ("serve_latent", phase_serve_latent),
                ("serve_window", phase_serve_window),

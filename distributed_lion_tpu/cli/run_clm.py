@@ -47,11 +47,13 @@ class ModelArguments:
     """run_clm.py ModelArguments (:89-166) — the subset that configures a
     from-scratch model rather than an HF hub download."""
 
-    model_family: str = "gpt2"  # gpt2 | llama — the reference's run_clm is
-    # architecture-agnostic (AutoModelForCausalLM, run_clm.py:425-444);
-    # llama composes with dp x tp x sp (pipe/expert/MoE are GPT-2-only)
+    model_family: str = "gpt2"  # gpt2 | llama | mellum — the reference's
+    # run_clm is architecture-agnostic (AutoModelForCausalLM,
+    # run_clm.py:425-444); llama composes with dp x tp x sp (pipe/expert/MoE
+    # are GPT-2-only), mellum (models/mellum) trains over the data axis
     model_name: str = "gpt2_124m"  # gpt2: gpt2_124m | gpt2_small | tiny;
-    # llama: llama2_7b | llama3_8b | tiny
+    # llama: llama2_7b | llama3_8b | tiny; mellum: tiny | a config.json of
+    # the published keys (benchmark/configs/mellum2-12b-a2.5b.json)
     model_path: Optional[str] = None  # local HF checkpoint (save_pretrained
     # dir / .safetensors / .bin / .npz) → finetune from pretrained weights,
     # the reference's from_pretrained path (run_clm.py:425-444). Overrides
@@ -306,8 +308,21 @@ def main(argv=None):
         moe_capacity_factor=model_args.moe_capacity_factor,
         vocab_pad_multiple=model_args.vocab_pad_multiple,
     )
-    if family not in ("gpt2", "llama"):
+    if family not in ("gpt2", "llama", "mellum"):
         raise ValueError(f"unknown model family {family!r}")
+    if family == "mellum":
+        ignored = [flag for flag, on in (
+            ("--model_path", model_args.model_path),
+            ("--hf_export", model_args.hf_export),
+            ("--moe_experts", model_args.moe_experts > 0),
+            ("--vocab_pad_multiple", model_args.vocab_pad_multiple),
+            ("--vocab_size", model_args.vocab_size),
+            ("--dropout > 0", (model_args.dropout or 0.0) > 0.0)) if on]
+        if ignored:
+            raise ValueError(
+                f"--model_family mellum takes its architecture from "
+                f"--model_name (tiny | a config.json) and has no dropout; "
+                f"it does not take {ignored}")
     if family == "llama" and (
         model_args.moe_experts > 0 or train_cfg.expert_parallel > 1
     ):
@@ -353,6 +368,14 @@ def main(argv=None):
             model_cfg = dataclasses.replace(
                 model_cfg, vocab_pad_multiple=model_args.vocab_pad_multiple)
             initial_params["wte"] = pad_wte(initial_params["wte"], model_cfg)
+    elif family == "mellum":
+        from distributed_lion_tpu.models.mellum import MellumConfig
+
+        kw = {k: common[k] for k in ("param_dtype", "compute_dtype", "remat",
+                                     "remat_policy")}
+        model_cfg = (MellumConfig.tiny(**kw)
+                     if model_args.model_name == "tiny" else
+                     MellumConfig.from_file(model_args.model_name, **kw))
     elif family == "llama":
         from distributed_lion_tpu.models.llama import LlamaConfig
 
@@ -394,7 +417,8 @@ def main(argv=None):
         print(f"[run_clm] capping block_size {train_cfg.block_size} -> n_ctx {model_cfg.n_ctx}")
         train_cfg.block_size = model_cfg.n_ctx
 
-    factory = Trainer.for_llama if family == "llama" else Trainer.for_gpt2
+    factory = {"llama": Trainer.for_llama, "mellum": Trainer.for_mellum,
+               "gpt2": Trainer.for_gpt2}[family]
     trainer = factory(train_cfg, mesh, model_cfg, initial_params=initial_params)
     if train_cfg.telemetry:
         # name the regime the vote-health records will be in: only the

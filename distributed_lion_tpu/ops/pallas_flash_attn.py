@@ -141,18 +141,27 @@ def _rows(i, blk):
 
 
 # ----------------------------------------------------------------- forward
+def _band(qi, blk: int, window: int):
+    """Of the kv blocks below q block ``qi``'s diagonal, those its band
+    reaches: ``lo`` the first with a visible key, ``inner`` the first with
+    every key visible to every row (blocks ``lo .. inner - 1`` cross the
+    band's lower edge). Query i sees keys ``i - window + 1 .. i``."""
+    lo = jnp.maximum(qi * blk - (window - 1), 0) // blk
+    return lo, jnp.clip(qi - (window // blk - 1), lo, qi)
+
+
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, *,
-                scale: float, head_dim: int):
+                scale: float, head_dim: int, window: int = 0):
     blk = q_ref.shape[0]
     qi = pl.program_id(2)
     masks = _head_masks(head_dim)
     q = q_ref[...]
     q_heads = [_own(mask, q) for mask in masks]
 
-    def block(ki, stats, diagonal):
+    def block(ki, stats, diagonal, edge=False):
         """One kv block for every head of the lane block (the heads side by
         side in one loop body: one's matmuls run under the other's
-        softmax)."""
+        softmax). ``edge``: the block crosses the band's lower edge."""
         k, v = k_ref[_rows(ki, blk), :], v_ref[_rows(ki, blk), :]
         out = []
         for h, (q_h, (m_prev, l_prev)) in enumerate(zip(q_heads, stats)):
@@ -162,6 +171,13 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, *,
                 row = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
                 col = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
                 s = jnp.where(col <= row, s, MASKED)
+                if 0 < window < blk:
+                    s = jnp.where(row - col < window, s, MASKED)
+            if edge:
+                row = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+                col = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+                s = jnp.where(row - col < window - (qi - ki) * blk, s,
+                              MASKED)
             m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
             alpha = jnp.exp(m_prev - m_new)
             p = jnp.exp(s - m_new)
@@ -171,10 +187,16 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, *,
         return tuple(out)
 
     acc_ref[...] = jnp.zeros_like(acc_ref)
+    stats = tuple((jnp.full((blk, 1), MASKED, jnp.float32),
+                   jnp.zeros((blk, 1), jnp.float32)) for _ in masks)
+    first = 0
+    if window:
+        # blocks wholly below the band are never visited
+        lo, first = _band(qi, blk, window)
+        stats = jax.lax.fori_loop(
+            lo, first, lambda ki, stats: block(ki, stats, False, True), stats)
     stats = jax.lax.fori_loop(
-        0, qi, lambda ki, stats: block(ki, stats, False),
-        tuple((jnp.full((blk, 1), MASKED, jnp.float32),
-               jnp.zeros((blk, 1), jnp.float32)) for _ in masks))
+        first, qi, lambda ki, stats: block(ki, stats, False), stats)
     stats = block(qi, stats, True)
     o_ref[...] = _merge(masks, [acc_ref[h] / l for h, (_, l) in
                                 enumerate(stats)]).astype(o_ref.dtype)
@@ -423,3 +445,236 @@ def _flash_qkv_bwd(n_head, interpret, res, do):
 
 
 flash_qkv.defvjp(_flash_qkv_fwd, _flash_qkv_bwd)
+
+
+# ------------------------------------------- grouped queries, with a window
+# The trainer's attention where q, k and v are projected apart (heads of
+# 128, ``rep`` query heads a kv head) and a layer may see a window:
+# ``flash_gqa`` below. Forward: ``_fwd_kernel`` as the prefill runs it
+# (``flash_gqa_fwd``'s grid and index maps), with the statistics kept and
+# the band's bounds. Backward: two kernels over the same transposed scores
+# as ``_bwd_kernel``, split because a kv head's eight query heads do not fit
+# VMEM side by side at T = 8,192: ``flash_gqa_dq`` walks a q block's band of
+# kv blocks, ``flash_gqa_dkv`` a kv block's band of q blocks, one query head
+# a grid step, and writes that head's share of dk and dv (the shares of a
+# kv head's ``rep`` query heads are summed outside, ``[B, T, H x 128]`` once
+# each). k and v are read through ``h // rep`` and never repeated in HBM.
+# ``di = sum(o * do)`` is one fused pass outside, laid out like the
+# statistics. Blocks wholly outside the band are never visited; only the
+# diagonal block and the blocks that cross the band's lower edge are masked.
+
+def _bwd_block(k, v, q, do, lse, di, scale, mask):
+    """One (kv block, q block) pair on transposed scores: ``p^T`` and
+    ``ds^T`` ``[kv rows, q rows]``; ``mask(row, col)`` or None."""
+    s_t = jax.lax.dot_general(k, q, _NT, preferred_element_type=jnp.float32)
+    p_t = jnp.exp(s_t * scale - lse)
+    if mask is not None:
+        row = jax.lax.broadcasted_iota(jnp.int32, p_t.shape, 0)
+        col = jax.lax.broadcasted_iota(jnp.int32, p_t.shape, 1)
+        p_t = jnp.where(mask(row, col), p_t, 0.0)
+    dp_t = jax.lax.dot_general(v, do, _NT, preferred_element_type=jnp.float32)
+    return p_t, ((dp_t - di) * p_t * scale).astype(q.dtype)
+
+
+def _pair_masks(blk: int, window: int):
+    """The masks of the diagonal pair and of a pair ``gap`` blocks apart
+    that crosses the band's edge, on transposed scores (row = key)."""
+    def diagonal(row, col):
+        seen = row <= col
+        return seen & (col - row < window) if 0 < window < blk else seen
+
+    def edge(gap):
+        return lambda row, col: col - row < window - gap * blk
+
+    return diagonal, edge
+
+
+def _gqa_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref, dq_ref,
+                   acc_ref, *, scale: float, window: int):
+    blk = q_ref.shape[0]
+    qi = pl.program_id(2)
+    q, do, lse, di = q_ref[...], do_ref[...], lse_ref[...], di_ref[...]
+    diagonal, edge = _pair_masks(blk, window)
+
+    def pair(ki, mask):
+        k, v = k_ref[_rows(ki, blk), :], v_ref[_rows(ki, blk), :]
+        _, ds_t = _bwd_block(k, v, q, do, lse, di, scale, mask)
+        acc_ref[...] += jax.lax.dot_general(
+            ds_t, k, _TN, preferred_element_type=jnp.float32)
+
+    def loop(lo, hi, mask_of):
+        jax.lax.fori_loop(lo, hi,
+                          lambda ki, _: pair(ki, mask_of(ki)) or 0, 0)
+
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+    first = 0
+    if window:
+        lo, first = _band(qi, blk, window)
+        loop(lo, first, lambda ki: edge(qi - ki))
+    loop(first, qi, lambda ki: None)
+    pair(qi, diagonal)
+    dq_ref[...] = acc_ref[...].astype(dq_ref.dtype)
+
+
+def _gqa_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref, dk_ref,
+                    dv_ref, acc_ref, *, scale: float, window: int):
+    blk = k_ref.shape[0]
+    nq = lse_ref.shape[0]
+    ki = pl.program_id(2)
+    k, v = k_ref[...], v_ref[...]
+    diagonal, edge = _pair_masks(blk, window)
+
+    def pair(qi, mask):
+        q, do = q_ref[_rows(qi, blk), :], do_ref[_rows(qi, blk), :]
+        p_t, ds_t = _bwd_block(k, v, q, do, lse_ref[qi], di_ref[qi], scale,
+                               mask)
+        acc_ref[1] += jnp.dot(p_t.astype(do.dtype), do,
+                              preferred_element_type=jnp.float32)
+        acc_ref[0] += jnp.dot(ds_t, q, preferred_element_type=jnp.float32)
+
+    def loop(lo, hi, mask_of):
+        jax.lax.fori_loop(lo, hi,
+                          lambda qi, _: pair(qi, mask_of(qi)) or 0, 0)
+
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+    pair(ki, diagonal)
+    if window:
+        # the last q block with a row that sees this block's last key, and
+        # the last whose every row sees its every key
+        hi = jnp.minimum((ki * blk + blk + window - 2) // blk, nq - 1)
+        inner = jnp.clip(ki + window // blk - 1, ki, hi)
+        loop(ki + 1, inner + 1, lambda qi: None)
+        loop(inner + 1, hi + 1, lambda qi: edge(qi - ki))
+    else:
+        loop(ki + 1, nq, lambda qi: None)
+    dk_ref[...] = acc_ref[0].astype(dk_ref.dtype)
+    dv_ref[...] = acc_ref[1].astype(dv_ref.dtype)
+
+
+def gqa_train_kernel_takes(T: int, head_dim: int, dtype) -> bool:
+    """Whether :func:`flash_gqa` takes these operands: heads of 128 (one
+    lane block a head, any ``rep``), whole blocks of rows, a lane block's
+    whole-T operands inside VMEM."""
+    return head_dim == LANES and kernel_takes(T, 1, LANES, dtype)
+
+
+def _gqa_geometry(q, k, n_head: int):
+    B, T, width = q.shape
+    assert width == n_head * LANES and k.shape[2] % LANES == 0, (q.shape,
+                                                                 k.shape)
+    blk = block_for(T)
+    return B, T, blk, T // blk, n_head // (k.shape[2] // LANES)
+
+
+def _gqa_fwd(q, k, v, n_head: int, window: int, interpret: bool):
+    B, T, blk, nq, rep = _gqa_geometry(q, k, n_head)
+    kv_spec = pl.BlockSpec((None, T, LANES), lambda b, j, i: (b, 0, j // rep))
+    with jax.named_scope("flash_gqa_lse"):
+        return pl.pallas_call(
+            functools.partial(_fwd_kernel, scale=1.0 / math.sqrt(LANES),
+                              head_dim=LANES, window=window),
+            grid=(B, n_head, nq),
+            in_specs=[pl.BlockSpec((None, blk, LANES),
+                                   lambda b, j, i: (b, i, j)),
+                      kv_spec, kv_spec],
+            out_specs=[
+                pl.BlockSpec((None, blk, LANES), lambda b, j, i: (b, i, j)),
+                pl.BlockSpec((None, None, None, 1, blk),
+                             lambda b, j, i: (b, j, i, 0, 0)),
+            ],
+            out_shape=[
+                jax.ShapeDtypeStruct(q.shape, q.dtype),
+                jax.ShapeDtypeStruct((B, n_head, nq, 1, blk), jnp.float32),
+            ],
+            scratch_shapes=[pltpu.VMEM((1, blk, LANES), jnp.float32)],
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "parallel", "arbitrary"),
+                vmem_limit_bytes=_vmem_limit(T, q.dtype.itemsize, 2, 0)),
+            interpret=interpret,
+            name="flash_gqa_lse",
+        )(q, k, v)
+
+
+def _gqa_bwd(q, k, v, o, lse, do, n_head: int, window: int, interpret: bool):
+    B, T, blk, nq, rep = _gqa_geometry(q, k, n_head)
+    scale = 1.0 / math.sqrt(LANES)
+    with jax.named_scope("flash_gqa_di"):
+        di = (o.astype(jnp.float32) * do.astype(jnp.float32)).reshape(
+            B, nq, blk, n_head, LANES).sum(-1)
+        di = di.transpose(0, 3, 1, 2)[:, :, :, None, :]   # like lse
+    params = pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "arbitrary"),
+        vmem_limit_bytes=_vmem_limit(T, q.dtype.itemsize, 2, 0))
+
+    def block(b, j, i):
+        return (b, i, j)
+
+    def kv_block(b, j, i):
+        return (b, i, j // rep)
+
+    def stat_block(b, j, i):
+        return (b, j, i, 0, 0)
+
+    tile = pl.BlockSpec((None, blk, LANES), block)
+    whole = pl.BlockSpec((None, T, LANES), lambda b, j, i: (b, 0, j))
+    whole_kv = pl.BlockSpec((None, T, LANES),
+                            lambda b, j, i: (b, 0, j // rep))
+    stat = pl.BlockSpec((None, None, None, 1, blk), stat_block)
+    stats = pl.BlockSpec((None, None, nq, 1, blk),
+                         lambda b, j, i: (b, j, 0, 0, 0))
+    with jax.named_scope("flash_gqa_dq"):
+        dq = pl.pallas_call(
+            functools.partial(_gqa_dq_kernel, scale=scale, window=window),
+            grid=(B, n_head, nq),
+            in_specs=[tile, whole_kv, whole_kv, tile, stat, stat],
+            out_specs=tile,
+            out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+            scratch_shapes=[pltpu.VMEM((blk, LANES), jnp.float32)],
+            compiler_params=params, interpret=interpret,
+            name="flash_gqa_dq",
+        )(q, k, v, do, lse, di)
+    with jax.named_scope("flash_gqa_dkv"):
+        dk, dv = pl.pallas_call(
+            functools.partial(_gqa_dkv_kernel, scale=scale, window=window),
+            grid=(B, n_head, nq),
+            in_specs=[whole, pl.BlockSpec((None, blk, LANES), kv_block),
+                      pl.BlockSpec((None, blk, LANES), kv_block), whole,
+                      stats, stats],
+            out_specs=[tile, tile],
+            out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype)] * 2,
+            scratch_shapes=[pltpu.VMEM((2, blk, LANES), jnp.float32)],
+            compiler_params=params, interpret=interpret,
+            name="flash_gqa_dkv",
+        )(q, k, v, do, lse, di)
+
+    def grouped(x):
+        """A kv head's gradient: its ``rep`` query heads' shares."""
+        x = x.reshape(B, T, n_head // rep, rep, LANES).astype(jnp.float32)
+        return x.sum(3).reshape(k.shape).astype(k.dtype)
+
+    return dq, grouped(dk), grouped(dv)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def flash_gqa(q, k, v, n_head: int, window: int = 0,
+              interpret: bool = False):
+    """Causal attention of q ``[B, T, n_head * 128]`` over k, v ``[B, T,
+    KV * 128]``, all token-major as the projections write them, query head
+    h reading kv head ``h // (n_head // KV)``; ``window`` > 0: query i sees
+    keys ``i - window + 1 .. i`` only (0: every earlier key). ``[B, T,
+    n_head * 128]`` in q's dtype, scores scaled by ``1 / sqrt(128)``;
+    differentiable. :func:`gqa_train_kernel_takes` says which shapes. On
+    the device: ``flash_gqa_lse``, ``flash_gqa_dq``, ``flash_gqa_dkv``."""
+    return _gqa_fwd(q, k, v, n_head, window, interpret)[0]
+
+
+def _flash_gqa_fwd(q, k, v, n_head, window, interpret):
+    o, lse = _gqa_fwd(q, k, v, n_head, window, interpret)
+    return o, (q, k, v, o, lse)
+
+
+def _flash_gqa_bwd(n_head, window, interpret, res, do):
+    return _gqa_bwd(*res, do, n_head, window, interpret)
+
+
+flash_gqa.defvjp(_flash_gqa_fwd, _flash_gqa_bwd)

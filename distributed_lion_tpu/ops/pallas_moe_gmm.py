@@ -25,6 +25,16 @@ holds 128 of the 256 experts its tokens pick) says ``tail=True``: the grid
 steps past the visits the groups need keep the last one's blocks and skip
 the matmul: a 4,096-token slice's call over ``[3072, 1024]`` banks 2.80 ->
 2.12 ms, the groups' rows bit for bit (my chip run, PR 30).
+
+The gradient (``parallel/expert.grouped_matmul``'s ``custom_vjp``) is two
+more grouped products. ``dlhs = dy rhs^T`` is this same kernel against the
+banks transposed. ``drhs[g] = lhs_g^T dy_g`` is ``moe_gmm_drhs``, megablox's
+``tgmm`` schedule under the same differences: visits of ``TILE_M_DRHS`` rows
+(the float32 accumulator is a whole ``[K, tn]`` block that every visit
+reads and writes, so a visit has to be deep enough to pay for it), rows of
+other groups zeroed in the tile, one float32 accumulation a group, stored
+when the group's last visit is done; a group with no row gets one visit
+that stores zeros.
 """
 
 from __future__ import annotations
@@ -39,6 +49,8 @@ from jax.experimental.pallas import tpu as pltpu
 LANES = 128
 TILE_M = 128            # rows a visit: one pass of the 128 x 128 MXU
 BLOCK_BYTES = 4 << 20   # most of rhs one visit holds (double-buffered)
+TILE_M_DRHS = 512       # rows a visit of the weight gradient contracts over
+_TN = (((0,), (0,)), ((), ()))   # a^T @ b
 
 
 def kernel_takes(k: int, n: int) -> bool:
@@ -133,3 +145,93 @@ def moe_gmm(lhs, rhs, group_sizes, *, tail: bool = False,
             name="moe_gmm",
         )(*scalars, lhs, rhs)
     return out[:m] if pad else out
+
+
+def _drhs_kernel(visits_ref, offsets_ref, group_ref, tile_ref, lhs_ref,
+                 dy_ref, out_ref, acc_ref):
+    """One visit of ``drhs``: the tile's rows that belong to the visit's
+    group, transposed, against their cotangent rows. Grid steps past the
+    ``visits`` the groups need (tiles of rows past the last group) keep the
+    last visit's blocks and do nothing."""
+    i, last = pl.program_id(1), pl.num_programs(1) - 1
+    real = i < visits_ref[0]
+    at = jnp.minimum(i, visits_ref[0] - 1)
+    group = group_ref[at]
+    first = jnp.logical_or(i == 0, jnp.logical_and(
+        real, group_ref[jnp.maximum(at - 1, 0)] != group))
+    final = jnp.logical_or(
+        i == last, group_ref[jnp.minimum(i + 1, visits_ref[0] - 1)] != group)
+
+    @pl.when(first)
+    def _():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    @pl.when(real)
+    def _():
+        lhs = lhs_ref[...]
+        rows = tile_ref[at] * lhs.shape[0] + jax.lax.broadcasted_iota(
+            jnp.int32, lhs.shape, 0)
+        mine = jnp.logical_and(rows >= offsets_ref[group],
+                               rows < offsets_ref[group + 1])
+        acc_ref[...] += jax.lax.dot_general(
+            jnp.where(mine, lhs, jnp.zeros_like(lhs)), dy_ref[...], _TN,
+            preferred_element_type=jnp.float32)
+
+    @pl.when(final)
+    def _():
+        out_ref[...] = acc_ref[...].astype(out_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def moe_gmm_drhs(lhs, dy, group_sizes, *, interpret: bool = False):
+    """``out[g] = lhs_g^T @ dy_g`` over the rows of group g: the gradient of
+    :func:`moe_gmm` in its banks, ``[n_groups, K, N]`` in lhs's dtype,
+    accumulated in float32 a group. lhs ``[M, K]`` and dy ``[M, N]`` sorted
+    by group; rows past the last group add nothing."""
+    from jax.experimental.pallas.ops.tpu.megablox.gmm import (
+        make_group_metadata,
+    )
+
+    m, k = lhs.shape
+    n, n_groups = dy.shape[1], group_sizes.shape[0]
+    tm = min(TILE_M_DRHS, -(-m // 16) * 16)
+    pad = -m % tm
+    if pad:
+        lhs = jnp.pad(lhs, ((0, pad), (0, 0)))
+        dy = jnp.pad(dy, ((0, pad), (0, 0)))
+    tn = _tile_n(k, n, 4)
+    (offsets, group_ids, tile_ids), visits = make_group_metadata(
+        group_sizes=group_sizes.astype(jnp.int32), m=m + pad, tm=tm,
+        start_group=jnp.int32(0), num_nonzero_groups=n_groups,
+        visit_empty_groups=True)
+    scalars = (jnp.reshape(visits, (1,)).astype(jnp.int32), offsets,
+               group_ids, tile_ids)
+
+    def at(i, s):
+        return jnp.minimum(i, s[0][0] - 1)
+
+    size = lhs.dtype.itemsize
+    vmem = (k * tn * (4 + 2 * size) + 2 * tm * (k + tn) * size
+            + 2 * tm * k * size + (8 << 20))
+    with jax.named_scope("moe_gmm_drhs"):
+        return pl.pallas_call(
+            _drhs_kernel,
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=len(scalars),
+                grid=(n // tn, group_ids.shape[0]),
+                in_specs=[
+                    pl.BlockSpec((tm, k),
+                                 lambda j, i, *s: (s[3][at(i, s)], 0)),
+                    pl.BlockSpec((tm, tn),
+                                 lambda j, i, *s: (s[3][at(i, s)], j)),
+                ],
+                out_specs=pl.BlockSpec(
+                    (None, k, tn), lambda j, i, *s: (s[2][at(i, s)], 0, j)),
+                scratch_shapes=[pltpu.VMEM((k, tn), jnp.float32)]),
+            out_shape=jax.ShapeDtypeStruct((n_groups, k, n), lhs.dtype),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "arbitrary"),
+                vmem_limit_bytes=max(vmem, 32 << 20)),
+            interpret=interpret,
+            name="moe_gmm_drhs",
+        )(*scalars, lhs, dy)

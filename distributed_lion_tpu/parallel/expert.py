@@ -16,6 +16,7 @@ load-balancing auxiliary loss. All arithmetic is batched einsums over
 
 from __future__ import annotations
 
+import functools
 import math
 from typing import Optional
 
@@ -362,21 +363,122 @@ def sigmoid_topk_route(x, router, bias, top_k: int, scale: float,
         return idx, w / (w.sum(-1, keepdims=True) + 1e-20) * scale
 
 
+def softmax_topk_route(x, router, top_k: int):
+    """``p = softmax(float32(x) router^T)`` over all ``E`` outputs; the
+    ``top_k`` largest; weights ``p[idx] / sum p[idx]`` (``norm_topk_prob``).
+    Float32 throughout, like :func:`sigmoid_topk_route`; no bias and no
+    scale. x [N, D], router [E, D] -> idx [N, k] int32, w [N, k] float32
+    (differentiable in x and router through the weights)."""
+    with jax.named_scope("moe/route"):
+        p = jax.nn.softmax(jnp.einsum(
+            "nd,ed->ne", x.astype(jnp.float32), router.astype(jnp.float32),
+            precision=lax.Precision.HIGHEST), axis=-1)
+        w, idx = lax.top_k(p, top_k)
+        return idx, w / w.sum(-1, keepdims=True)
+
+
+def _gmm_kernels(k: int, n: int):
+    """``ops/pallas_moe_gmm`` where its kernels take these widths on this
+    backend, else None."""
+    from distributed_lion_tpu.ops import pallas_moe_gmm
+
+    if jax.default_backend() == "tpu" and pallas_moe_gmm.kernel_takes(k, n):
+        return pallas_moe_gmm
+    return None
+
+
+def _grouped_product(lhs, rhs, group_sizes, tail):
+    kernels = _gmm_kernels(lhs.shape[1], rhs.shape[2])
+    if kernels:
+        return kernels.moe_gmm(lhs, rhs, group_sizes, tail=tail)
+    return lax.ragged_dot(lhs, rhs, group_sizes.astype(jnp.int32),
+                          preferred_element_type=jnp.float32
+                          ).astype(lhs.dtype)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
 def grouped_matmul(lhs, rhs, group_sizes, tail: bool = False):
     """Rows of ``lhs [M, K]`` sorted by group against ``rhs [E, K, N]``:
     the Mosaic kernel ``moe_gmm`` on a TPU where the widths are whole lane
     tiles, ``jax.lax.ragged_dot`` elsewhere (the CPU; the tests hold the
     two together). Chosen from what the call shows, like
     ``ops/attention.paged_kernel_applies``: no flag. Rows past the last
-    group are undefined on the kernel's path."""
-    from distributed_lion_tpu.ops import pallas_moe_gmm
+    group are undefined on the kernel's path.
 
-    if jax.default_backend() == "tpu" and pallas_moe_gmm.kernel_takes(
-            lhs.shape[1], rhs.shape[2]):
-        return pallas_moe_gmm.moe_gmm(lhs, rhs, group_sizes, tail=tail)
-    return lax.ragged_dot(lhs, rhs, group_sizes.astype(jnp.int32),
-                          preferred_element_type=jnp.float32
-                          ).astype(lhs.dtype)
+    Its gradient is two more grouped products, on the same path as the
+    forward: ``dlhs = dy rhs^T`` (this product against the banks
+    transposed; zero in the rows past the last group, whatever ``dy``
+    holds there) and ``drhs[g] = lhs_g^T dy_g`` accumulated in float32
+    (``moe_gmm_drhs`` on a TPU; off it a ``ragged_dot`` whose ragged
+    dimension is the contraction)."""
+    return _grouped_product(lhs, rhs, group_sizes, tail)
+
+
+def _grouped_matmul_fwd(lhs, rhs, group_sizes, tail):
+    return _grouped_product(lhs, rhs, group_sizes, tail), \
+        (lhs, rhs, group_sizes)
+
+
+def _grouped_matmul_bwd(tail, res, dy):
+    lhs, rhs, group_sizes = res
+    dy = dy.astype(lhs.dtype)
+    dlhs = _grouped_product(dy, jnp.swapaxes(rhs, 1, 2), group_sizes, tail)
+    grouped = jnp.arange(lhs.shape[0]) < group_sizes.sum()
+    dlhs = jnp.where(grouped[:, None], dlhs, 0)
+    kernels = _gmm_kernels(lhs.shape[1], dy.shape[1])
+    if kernels:
+        drhs = kernels.moe_gmm_drhs(lhs, dy, group_sizes)
+    else:
+        drhs = lax.ragged_dot_general(
+            lhs, dy, group_sizes.astype(jnp.int32),
+            lax.RaggedDotDimensionNumbers(
+                dot_dimension_numbers=(((0,), (0,)), ((), ())),
+                lhs_ragged_dimensions=[0], rhs_group_dimensions=[]),
+            preferred_element_type=jnp.float32)
+    return dlhs, drhs.astype(rhs.dtype), None
+
+
+grouped_matmul.defvjp(_grouped_matmul_fwd, _grouped_matmul_bwd)
+
+
+@jax.custom_vjp
+def _sorted_rows(x, order):
+    """``x[order // k]``: every token's row once a pick, in the sorted
+    order (``k = len(order) / len(x)``). Its transpose as autodiff writes
+    it is a scatter-add over rows that repeat; ``order`` is a permutation,
+    so it is a gather by the inverse and a sum of ``k`` rows."""
+    return x[order // (order.shape[0] // x.shape[0])]
+
+
+def _sorted_rows_fwd(x, order):
+    return _sorted_rows(x, order), (order, x.shape[0])
+
+
+def _sorted_rows_bwd(res, g):
+    order, n = res
+    dx = g[jnp.argsort(order)].reshape(n, -1, g.shape[1])
+    return dx.astype(jnp.float32).sum(1).astype(g.dtype), None
+
+
+_sorted_rows.defvjp(_sorted_rows_fwd, _sorted_rows_bwd)
+
+
+@jax.custom_vjp
+def _unsorted_rows(y, order, back):
+    """``y[back]``: the sorted rows back in pick order; the transpose of a
+    permutation is the gather by its inverse."""
+    return y[back]
+
+
+def _unsorted_rows_fwd(y, order, back):
+    return y[back], order
+
+
+def _unsorted_rows_bwd(order, g):
+    return g[order], None, None
+
+
+_unsorted_rows.defvjp(_unsorted_rows_fwd, _unsorted_rows_bwd)
 
 
 def _swiglu_limited(gate, up, limit: float):
@@ -398,7 +500,13 @@ def moe_dropless_ffn(params, x, *, top_k: int, scale: float, valid=None,
     ``params``: ``router [E, D]``, ``bias [E]`` (float32), the banks
     ``w_gate`` / ``w_up`` ``[E, D, F]`` and ``w_down [E, F, D]``, and
     ``shared`` (``w_gate``, ``w_up``, ``w_down`` of one expert every token
-    takes). ``valid`` (optional ``[N]`` bool): pad and inactive lanes sort
+    takes). What the tree holds says which layer it is: with a ``bias`` the
+    route is :func:`sigmoid_topk_route`, without one
+    :func:`softmax_topk_route` (``scale`` and ``route_groups`` are the
+    sigmoid route's); without ``shared`` no shared expert is added. The
+    layer is differentiable (the trainer's expert layer): the sort and its
+    inverse transpose as gathers, the grouped products through
+    :func:`grouped_matmul`'s own gradient. ``valid`` (optional ``[N]`` bool): pad and inactive lanes sort
     behind every group, so no expert runs them, and give zero rows.
 
     ``held = (first, count)``: the layer is told which experts it holds
@@ -430,8 +538,11 @@ def moe_dropless_ffn(params, x, *, top_k: int, scale: float, valid=None,
     n, d = x.shape
     n_experts = params["router"].shape[0]
     groups = n_experts if held is None else held[1]
-    idx, w = sigmoid_topk_route(x, params["router"], params["bias"], top_k,
-                                scale, route_groups)
+    if "bias" in params:
+        idx, w = sigmoid_topk_route(x, params["router"], params["bias"],
+                                    top_k, scale, route_groups)
+    else:
+        idx, w = softmax_topk_route(x, params["router"], top_k)
     with jax.named_scope("moe/sort"):
         flat = idx.reshape(-1)
         if held is not None:
@@ -444,7 +555,7 @@ def moe_dropless_ffn(params, x, *, top_k: int, scale: float, valid=None,
         order = jnp.argsort(flat)            # stable: ties keep token order
         ends = jnp.searchsorted(flat[order], jnp.arange(groups + 1))
         sizes = jnp.diff(ends).astype(jnp.int32)       # [E] rows an expert
-        rows = x[order // top_k]                       # [N k, D], by expert
+        rows = _sorted_rows(x, order)                  # [N k, D], by expert
     with jax.named_scope("moe/experts"):
         # a held range leaves the picks held elsewhere past the last group
         tail = held is not None
@@ -454,7 +565,7 @@ def moe_dropless_ffn(params, x, *, top_k: int, scale: float, valid=None,
         y = grouped_matmul(h, params["w_down"], sizes, tail)
     with jax.named_scope("moe/combine"):
         back = jnp.argsort(order)            # each token's k rows, in order
-        y = y[back].reshape(n, top_k, d)
+        y = _unsorted_rows(y, order, back).reshape(n, top_k, d)
         if held is not None:
             # rows past the last group are undefined: picks held elsewhere
             # and lanes with no token alike
@@ -462,13 +573,15 @@ def moe_dropless_ffn(params, x, *, top_k: int, scale: float, valid=None,
         elif valid is not None:
             y = jnp.where(valid[:, None, None], y, 0)
         out = jnp.einsum("nkd,nk->nd", y.astype(jnp.float32), w)
-    with jax.named_scope("moe/shared"):
-        from distributed_lion_tpu.models.llama import _matmul, _mlp
+    if "shared" in params:
+        with jax.named_scope("moe/shared"):
+            from distributed_lion_tpu.models.llama import _matmul, _mlp
 
-        sh = params["shared"]
-        out = out + (_mlp(x, sh) if limits[1] <= 0 else _matmul(
-            _swiglu_limited(_matmul(x, sh["w_gate"]), _matmul(x, sh["w_up"]),
-                            limits[1]), sh["w_down"]))
+            sh = params["shared"]
+            out = out + (_mlp(x, sh) if limits[1] <= 0 else _matmul(
+                _swiglu_limited(_matmul(x, sh["w_gate"]),
+                                _matmul(x, sh["w_up"]), limits[1]),
+                sh["w_down"]))
     out = out.astype(x.dtype)
     if valid is not None:
         out = jnp.where(valid[:, None], out, 0)

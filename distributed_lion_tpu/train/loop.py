@@ -236,6 +236,10 @@ class TrainConfig:
     block_size: int = 1024
     seed: int = 42
     logging_steps: int = 50
+    logging_first_step: bool = False  # also log after this loop's first
+    # dispatch (HF TrainingArguments.logging_first_step): the interval's
+    # first device drain, and the few scalar programs the first log
+    # compiles (2.5 s on a TPU), then fall on step 1 and not inside the run
     eval_steps: int = 1000
     eval_iters: int = 20
     save_steps: int = 1000
@@ -430,13 +434,16 @@ class LossSpec:
     exposes every field on every CLI, so a loss that would ignore a set
     flag is refused, not run), how its batch is sharded (None: rows over
     ``data``), and the shape of one MoE balance tally where it feeds the
-    ``--ep_dcn_pipeline`` ring (None: no ring). The default is a bare
+    ``--ep_dcn_pipeline`` ring (None: no ring), and which of its metrics
+    are counts (``sum_metrics``: summed over a step's microbatches and
+    workers where every other metric is averaged). The default is a bare
     callable's: it honours nothing."""
 
     vocab_chunks: bool = False
     tp_vocab: bool = False
     batch_spec: Optional[P] = None
     moe_tally_shape: Optional[tuple] = None
+    sum_metrics: tuple = ()
 
 
 def _clm_head_loss(cfg: "TrainConfig", mesh, model_cfg, hidden_fn: Callable,
@@ -1495,6 +1502,7 @@ class Trainer:
         # step's fresh local tallies on the metrics dict under the
         # reserved 'moe_tallies' key (popped in-trace below, never logged)
         ring_on = self.loss_spec.moe_tally_shape is not None
+        counts = self.loss_spec.sum_metrics
 
         @partial(
             jax.shard_map,
@@ -1673,7 +1681,10 @@ class Trainer:
                 # re-attach this step's launch here, re-stacked [1, ...]
                 new_state = new_state._replace(moe_ring=new_moe_ring[None])
 
-            mean_metrics = {k: lax.pmean(v.mean(), DATA_AXIS) for k, v in metrics.items()}
+            mean_metrics = {
+                k: (lax.psum(v.sum(), DATA_AXIS) if k in counts
+                    else lax.pmean(v.mean(), DATA_AXIS))
+                for k, v in metrics.items()}
             if gnorm is not None:
                 mean_metrics["grad_norm"] = gnorm
             if gframe is not None:
@@ -1771,6 +1782,7 @@ class Trainer:
                     next(train_iter)
             self._resume_skip_batches = 0
         t_last, s_last = time.time(), self.step_count
+        log_first = cfg.logging_first_step
         chunk_spec = NamedSharding(self.mesh, P(None, *self.batch_spec))
         jr = self.journal  # journal.NULL when --journal is off: its events
         # are no-ops. Spans go through journal.span, which is the shared
@@ -1865,7 +1877,9 @@ class Trainer:
 
             # boundary tests are "crossed a multiple of N during this
             # dispatch" so chunked advances never skip a log/eval/save
-            if self.step_count % cfg.logging_steps < advanced or self.step_count == total:
+            if (self.step_count % cfg.logging_steps < advanced or log_first
+                    or self.step_count == total):
+                log_first = False
                 # the ONE device drain the loop already pays per log
                 # interval (the host-float below blocks on it either way)
                 # made explicit, so a listener sees device-bound time as a
@@ -2790,6 +2804,65 @@ class Trainer:
             "dv", head_cols=model_cfg.vocab_size)
         return Trainer(cfg, mesh, None, params, param_specs=param_specs,
                        loss_fn=loss_fn, loss_spec=loss_spec,
+                       remat_decision=remat_decision)
+
+
+    @staticmethod
+    def for_mellum(cfg: TrainConfig, mesh, model_cfg,
+                   seed: Optional[int] = None, initial_params: Any = None):
+        """Full-parameter CLM training of ``models/mellum`` (window and
+        full GQA layers, a dropless top-k expert layer in every block,
+        an untied head) over the data axis: every device holds the tree
+        ``mellum_init`` makes, which with ``model_cfg.held`` is one chip's
+        share of a layer spread over several. The exchange between those
+        chips is not run, so tensor, sequence, pipeline and expert axes are
+        refused. The step's metrics carry the expert layers' counters
+        (``MELLUM_COUNTERS``), summed over layers, microbatches and
+        workers."""
+        from distributed_lion_tpu.models.mellum import (
+            MELLUM_COUNTERS,
+            mellum_hidden,
+            mellum_init,
+            mellum_param_specs,
+        )
+
+        wrong = [a for a in (TENSOR_AXIS, SEQ_AXIS, PIPE_AXIS, EXPERT_AXIS)
+                 if dict(mesh.shape).get(a, 1) > 1]
+        if wrong:
+            raise NotImplementedError(
+                f"--model_family mellum trains over the data axis; the mesh "
+                f"has {wrong} (the exchange between the chips that share a "
+                "layer's experts is not run: ROADMAP.md)")
+        if cfg.tp_vocab or cfg.ep_dcn_pipeline is not None:
+            raise ValueError("--tp_vocab and --ep_dcn_pipeline need axes "
+                             "--model_family mellum does not train over")
+        params = (initial_params if initial_params is not None else
+                  mellum_init(jax.random.key(seed if seed is not None
+                                             else cfg.seed), model_cfg))
+        model_cfg, remat_decision = apply_remat_policy(cfg, model_cfg, mesh,
+                                                       params)
+        cfg, n, acct = _resolve_comm(cfg, mesh, params)
+        held = model_cfg.held or (0, model_cfg.n_experts)
+        emit(f"[trainer] Mellum {n/1e6:.1f}M params | "
+             f"world={data_axis_size(mesh)} | experts {held[0]}-"
+             f"{held[0] + held[1] - 1} of {model_cfg.n_experts} held, top "
+             f"{model_cfg.top_k}, dropless | vote wire={cfg.wire}: "
+             f"{acct['bits_per_param']:.2f} bits/param/step")
+        xent_ops.head_path("vd", model_cfg.d_model, model_cfg.compute_dtype,
+                           chunks=cfg.vocab_chunks)
+
+        def loss_fn(params, batch, dropout_key):
+            hidden, counters = mellum_hidden(params, batch, model_cfg)
+            loss, metrics = xent_ops.clm_head_loss(
+                hidden, params["lm_head"], batch, layout="vd",
+                chunks=cfg.vocab_chunks)
+            return loss, {**metrics, **counters}
+
+        return Trainer(cfg, mesh, None, params,
+                       param_specs=mellum_param_specs(model_cfg),
+                       loss_fn=loss_fn,
+                       loss_spec=LossSpec(vocab_chunks=True,
+                                          sum_metrics=MELLUM_COUNTERS),
                        remat_decision=remat_decision)
 
 

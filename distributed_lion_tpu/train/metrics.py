@@ -19,6 +19,19 @@ from typing import Optional
 from distributed_lion_tpu.train.journal import emit
 
 
+# The newest record each prefix logged, in this process: what a reader that
+# holds no Trainer (the benchmark's per-layer readers, after the run) takes
+# the step's drained counters from. One dict assignment a log interval.
+_LAST: dict = {}
+
+
+def last_logged(prefix: str = "train") -> Optional[dict]:
+    """The metrics of the newest ``MetricsLogger.log(step, metrics,
+    prefix)`` of this process, under their own names (``step`` added), or
+    None before the first."""
+    return _LAST.get(prefix)
+
+
 class MetricsLogger:
     def __init__(self, output_dir: Optional[str] = None, run_name: str = "run",
                  use_wandb: bool = False):
@@ -41,6 +54,7 @@ class MetricsLogger:
         self._t0 = time.time()
 
     def log(self, step: int, metrics: dict, prefix: str = "train") -> None:
+        _LAST[prefix] = {"step": step, **metrics}
         record = {"step": step, "elapsed_s": round(time.time() - self._t0, 3)}
         sep = "/" if prefix else ""
         record.update({f"{prefix}{sep}{k}": _scalar(v) for k, v in metrics.items()})

@@ -12,6 +12,11 @@ rungs of this module, cheapest backward first:
   backward runs the block's whole forward again (a quarter of the blocks'
   time at GPT-2 124M, PERF.md section 5).
 
+Three blocks are counted, told apart by what their configuration holds: a
+fused-qkv GELU block (no ``d_ff``), a GQA SwiGLU block (``d_ff``) and a GQA
+block whose FFN is a dropless top-k expert layer (``top_k``,
+:func:`expert_block_saved_bytes`).
+
 :func:`resolve` takes the first rung whose predicted peak is at most
 ``MEMORY_SHARE`` of the device's ``bytes_limit``. The prediction is a
 closed-form count, no compile and no trial run: what one block keeps for
@@ -110,6 +115,41 @@ def block_saved_bytes(model_cfg, rows: int, seq: int, *, tp: int = 1,
     none = u * (2.0 + (qkv + 1.0 + mlp) / tp) + stat
     dots = u * (2.0 + (qkv + mlp) / tp)
     return {"none": int(none), "dots": int(dots), "full": int(u)}
+
+
+def expert_block_saved_bytes(model_cfg, rows: int, seq: int) -> dict:
+    """:func:`block_saved_bytes` for a block of grouped-query attention
+    (separate q, k, v of ``head_dim`` lanes a head, q and the attention's
+    output ``n_head x head_dim`` wide, the repo's kernel: one float32
+    statistic a row and head) and a dropless ``top_k`` expert layer
+    (``parallel/expert.moe_dropless_ffn``), in the same unit ``U``. The
+    expert layer keeps, a pick and not a token: the sorted rows
+    ``[tokens x top_k, d]``, the gate and up products ``[tokens x top_k,
+    moe_d_ff]`` each and the expert outputs back in pick order (the
+    combine's gradient in the routing weights reads them); the grouped
+    products are kernels with their own gradient, so ``dots`` keeps of the
+    expert layer nothing but the router's float32 logits. A held range
+    changes none of it: the picks held elsewhere still take their rows."""
+    size = _itemsize(model_cfg.compute_dtype)
+    d, k = model_cfg.d_model, model_cfg.top_k
+    u = rows * seq * d * size
+    q = model_cfg.n_head * model_cfg.head_dim / d
+    kv = 2.0 * model_cfg.n_kv_head * model_cfg.head_dim / d
+    stat = 4 * rows * model_cfg.n_head * seq
+    logits = 4 * rows * seq * model_cfg.n_experts
+    picks = k * (2.0 + 2.0 * model_cfg.moe_d_ff / d)
+    none = u * (2.0 + 2.0 * q + kv + picks) + stat + logits
+    dots = u * (2.0 + q + kv) + logits
+    return {"none": int(none), "dots": int(dots), "full": int(u)}
+
+
+def expert_backward_bytes(model_cfg, rows: int, seq: int) -> int:
+    """What the expert layer's backward holds at once beside what its
+    forward kept, under every rung: the cotangents of the outputs in pick
+    order and sorted, and of the sorted rows, ``[tokens x top_k, d]``
+    each."""
+    return 3 * model_cfg.top_k * rows * seq * model_cfg.d_model \
+        * _itemsize(model_cfg.compute_dtype)
 
 
 def head_bytes(model_cfg, rows: int, seq: int, *, fused: bool,
@@ -219,13 +259,23 @@ def resolve_for(cfg, model_cfg, mesh, params, *, bytes_limit: Optional[int],
     tp = shape.get(TENSOR_AXIS, 1)
     rows = cfg.per_device_train_batch_size * rows_per_sample
     seq = cfg.block_size
-    gpt2 = not hasattr(model_cfg, "d_ff")
+    experts = hasattr(model_cfg, "top_k")
+    gpt2 = not experts and not hasattr(model_cfg, "d_ff")
     # which attention and which loss head a TPU's `auto` takes at these
     # shapes (ops/attention, ops/xent: their own rules, asked, not copied):
     # GPT-2's fused projection reaches the repo's kernel, the head-major
     # entry the library's; attention dropout and `attn_impl='xla'`
     # materialize the scores
-    if model_cfg.attn_impl != "auto" or getattr(model_cfg, "dropout", 0.0) > 0:
+    if experts:
+        from distributed_lion_tpu.ops import pallas_flash_attn
+
+        if not pallas_flash_attn.gqa_train_kernel_takes(
+                seq, model_cfg.head_dim, model_cfg.compute_dtype):
+            return resolve({}, 0, 0, bytes_limit,
+                           "grouped-query attention off the kernel pair")
+        attn = "kernel"
+    elif model_cfg.attn_impl != "auto" \
+            or getattr(model_cfg, "dropout", 0.0) > 0:
         attn = "xla"
     elif gpt2 and attn_ops.qkv_kernel_applies(
             seq, model_cfg.n_head // tp, model_cfg.head_dim,
@@ -234,7 +284,8 @@ def resolve_for(cfg, model_cfg, mesh, params, *, bytes_limit: Optional[int],
     else:
         attn = "library" if attn_ops.library_kernel_applies(seq) else "xla"
     fused = xent_ops.head_path(
-        "vd" if gpt2 else "dv", model_cfg.d_model, model_cfg.compute_dtype,
+        "vd" if gpt2 or experts else "dv", model_cfg.d_model,
+        model_cfg.compute_dtype,
         chunks=cfg.vocab_chunks,
         vocab_axis=TENSOR_AXIS if cfg.tp_vocab else None) == "fused"
     # a tensor axis splits the blocks' matrices; what stays whole
@@ -257,7 +308,11 @@ def resolve_for(cfg, model_cfg, mesh, params, *, bytes_limit: Optional[int],
                         vocab_chunks=cfg.vocab_chunks),
         world=data_axis_size(mesh) if cfg.lion else 1,
         frozen_bytes=frozen_bytes)
-    saved = block_saved_bytes(model_cfg, rows, seq, tp=tp, attn=attn)
+    if experts:
+        saved = expert_block_saved_bytes(model_cfg, rows, seq)
+        fixed += expert_backward_bytes(model_cfg, rows, seq)
+    else:
+        saved = block_saved_bytes(model_cfg, rows, seq, tp=tp, attn=attn)
     return resolve(saved, model_cfg.n_layer, fixed, bytes_limit)
 
 

@@ -43,7 +43,9 @@ SCOPES = ("embed", "attn", "mlp", "head", "xent", "paged_attn",
           "lightning_step", "lightning_chunk", "sparse/compress",
           "sparse/select", "sparse_attn", "dense_attn", "mhc/pre",
           "mhc/post", "mhc/read_out", "mhc_pre", "mhc_post",
-          "flash_gqa_fwd")
+          "flash_gqa_fwd", "attn/window", "attn/full", "moe",
+          "moe_gmm_drhs", "flash_gqa_lse", "flash_gqa_di", "flash_gqa_dq",
+          "flash_gqa_dkv")
 NO_SCOPE = "(no scope)"
 _FIND = [(s, re.compile(r"(?<=[(/])%s(?=[)/])" % re.escape(s)))
          for s in SCOPES]

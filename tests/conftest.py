@@ -32,6 +32,8 @@ def mesh4():
 def pytest_configure(config):
     config.addinivalue_line(
         "markers", "slow: left out of the tier-1 run (-m 'not slow')")
+    # the workers order the files themselves (``_file_order`` below)
+    config.option.loadscopereorder = False
 
 
 # ``tests/benchmark/test_bm_laguna.py`` pins the TAIL of ``BENCHMARK.json`` as
@@ -48,7 +50,30 @@ PINNED_TO_AN_OLDER_MANIFEST = {
 }
 
 
+# ``--dist loadfile`` hands files to its workers in the order of their NUMBER
+# of cases, most first (xdist 3.8, ``--loadscope-reorder``), so a file of six
+# cases that takes 196 core-seconds starts last and the run waits for it
+# alone: 45-70 s of a wall that is at its limit (PERF.md section 7, x). The
+# workers keep that order, which counts most passes early, and these start
+# before it. The file named may not be edited (the benchmark's ``paths``).
+STARTED_FIRST = ("tests/benchmark/test_bm_control.py",)
+
+
+def _file_order(items):
+    """xdist's own order of files, after the ones named above."""
+    cases = {}
+    for item in items:
+        name = item.nodeid.split("::", 1)[0]
+        cases[name] = cases.get(name, 0) + 1
+    items.sort(key=lambda item: (
+        item.nodeid.split("::", 1)[0] not in STARTED_FIRST,
+        -cases[item.nodeid.split("::", 1)[0]]))
+
+
+@pytest.hookimpl(trylast=True)   # after ``-m 'not slow'`` has deselected
 def pytest_collection_modifyitems(config, items):
+    if hasattr(config, "workerinput"):
+        _file_order(items)
     for item in items:
         why = PINNED_TO_AN_OLDER_MANIFEST.get(item.nodeid)
         if why:

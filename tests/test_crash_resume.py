@@ -75,8 +75,13 @@ def _assert_resumed_matches(tmp_path, mesh, model, blocks, **kw):
         np.testing.assert_array_equal(got_ring, ref_ring)
 
 
-@pytest.mark.parametrize("stoch", [False, True], ids=["det", "stoch"])
-@pytest.mark.parametrize("buckets", [1, 4])
+# tier-1 runs the diagonal of the 2 x 2 (each value of each axis once); the
+# other two are `slow`: 63 core-seconds (ROADMAP D9)
+@pytest.mark.parametrize("buckets,stoch", [
+    pytest.param(1, False, id="1-det"),
+    pytest.param(1, True, id="1-stoch", marks=pytest.mark.slow),
+    pytest.param(4, False, id="4-det", marks=pytest.mark.slow),
+    pytest.param(4, True, id="4-stoch")])
 def test_crash_resume_bit_identical(tmp_path, buckets, stoch):
     mesh = make_mesh(data=8)
     model = GPT2Config.tiny()

@@ -11,6 +11,7 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 from jax.sharding import PartitionSpec as P
 
 from distributed_lion_tpu.models.llama import LlamaConfig, llama_apply, llama_init
@@ -232,6 +233,7 @@ def test_dpo_tp_trains():
     trainer.close()
 
 
+@pytest.mark.slow   # 131 core-seconds over 7B-wide constants (ROADMAP D9 c)
 def test_lora_7b_widths_smoke():
     """Factored LoRA at Llama-2-7B widths (d=4096, d_ff=11008, vocab 32000;
     depth scaled to 2 layers): one SFT train step runs and is finite. Pins
