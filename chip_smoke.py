@@ -379,6 +379,79 @@ def phase_kernels() -> None:
 
 # ------------------------------------------------------------------- train
 # ---------------------------------------------------------------- train_moe
+def expert_combine_at_size(n: int = 2 * 8192, d: int = 2304,
+                           mean: int = 32283) -> None:
+    """The combine of a layer that holds a range of its experts, at cell
+    10's widths (16,384 tokens x 8 picks of 2,304 bfloat16) with 0, 32,283
+    (the cell's mean) and all 131,072 rows in groups: its transpose bounded
+    by the count (``parallel/expert._combine_held``) against autodiff's
+    through the plain gather, mask and einsum. The forward is the plain
+    program's own, so the outputs are equal; ``dy`` is equal in the rows
+    below the count; ``dw`` is within float32 rounding. Each transpose's
+    time is printed (the least of five; called alone the bounded one first
+    copies ``y``, 0.6 GB, over which it writes ``dy``: the step's ``y`` is
+    its own to overwrite)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from distributed_lion_tpu.parallel import expert
+
+    k, held = 8, 16
+    m = n * k
+    ks = jax.random.split(jax.random.key(44), 3)
+    y = jax.random.normal(ks[0], (m, d), jnp.bfloat16)
+    w = jax.random.uniform(ks[1], (n, k), jnp.float32)
+    dout = jax.random.normal(ks[2], (n, d), jnp.float32)
+
+    def clock(fn, *args):
+        out = jax.block_until_ready(fn(*args))
+        times = []
+        for _ in range(5):
+            t0 = time.perf_counter()
+            jax.block_until_ready(fn(*args))
+            times.append(time.perf_counter() - t0)
+        return out, min(times) * 1e3
+
+    def plain(y, w, order, back, flat):
+        rows = expert._unsorted_rows(y, order, back).reshape(n, k, d)
+        rows = jnp.where((flat < held).reshape(n, k, 1), rows, 0)
+        return jnp.einsum("nkd,nk->nd", rows.astype(jnp.float32), w)
+
+    def bounded(y, w, order, back, flat):
+        return expert._combine_held(y, w, order, back, flat, held)
+
+    def both(fn):
+        def run(y, w, order, back, flat, dout):
+            out, transpose = jax.vjp(
+                lambda y, w: fn(y, w, order, back, flat), y, w)
+            return (out,) + transpose(dout)
+        return jax.jit(run)
+
+    for count in (0, mean, m):
+        rng = np.random.default_rng(count)
+        flat = np.full(m, held, np.int32)
+        flat[rng.choice(m, count, replace=False)] = rng.integers(
+            0, held, count)
+        order = jnp.asarray(np.argsort(flat, kind="stable"), jnp.int32)
+        back = jnp.argsort(order).astype(jnp.int32)
+        flat = jnp.asarray(flat)
+        (out, dy, dw), t_mine = clock(both(bounded), y, w, order, back, flat,
+                                      dout)
+        (out_want, dy_want, dw_want), t_plain = clock(
+            both(plain), y, w, order, back, flat, dout)
+        at = f"{count} of {m} rows in groups"
+        check(bool((out == out_want).all()), f"the combine differs ({at})")
+        live = (jnp.arange(m) < count)[:, None]
+        check(bool(jnp.where(live, dy == dy_want, True).all()),
+              f"the combine's dy differs below the count ({at})")
+        gap = float(jnp.abs(dw - dw_want).max())
+        check(gap <= 1e-5 * max(float(jnp.abs(dw_want).max()), 1.0),
+              f"the combine's dw gap {gap} ({at})")
+        log(f"train_moe combine, {at}: forward and transpose {t_mine:.3f} ms "
+            f"bounded, {t_plain:.3f} plain; dw gap {gap:.2e}")
+
+
 def phase_train_moe() -> None:
     """The trained expert-and-window block at the published widths
     (models/mellum: hidden 2,304, 32 query heads over 4 kv heads of 128,
@@ -438,6 +511,9 @@ def phase_train_moe() -> None:
             "the largest entry")
         check(gap < 2e-2, f"{name} gap {gap}")
         check(bool(jnp.isfinite(a).all()), f"{name} is not finite")
+
+    # -- the combine's transpose, bounded by the rows in groups
+    expert_combine_at_size()
 
     # -- the attention pair at 32 / 4 heads of 128 over 8,192 positions
     T, H, KV, window = 8192, 32, 4, 1024

@@ -401,6 +401,8 @@ def laguna_decode_paged(params: dict, tokens: jnp.ndarray, cfg: LagunaConfig,
         if return_moe_stats:
             y, st = y
             for name in st:
+                if name not in counters:     # one this family does not keep
+                    continue
                 join = jnp.maximum if name.endswith("_max") else jnp.add
                 counters[name] = join(counters[name],
                                       st[name].astype(jnp.int32))

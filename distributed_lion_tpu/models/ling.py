@@ -390,6 +390,8 @@ def ling_decode_paged(params: dict, tokens: jnp.ndarray, cfg: LingConfig,
         if return_moe_stats:
             y, st = y
             for name in st:
+                if name not in counters:     # one this family does not keep
+                    continue
                 join = jnp.maximum if name.endswith("_max") else jnp.add
                 counters[name] = join(counters[name],
                                       st[name].astype(jnp.int32))

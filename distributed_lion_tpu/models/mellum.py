@@ -55,7 +55,7 @@ from distributed_lion_tpu.parallel.expert import (
     moe_dropless_ffn,
 )
 
-MELLUM_COUNTERS = MOE_COUNTERS + ("moe_routed",)
+MELLUM_COUNTERS = MOE_COUNTERS + ("moe_routed", "moe_rows_moved")
 
 
 @dataclasses.dataclass(frozen=True)

@@ -481,6 +481,87 @@ def _unsorted_rows_bwd(order, g):
 _unsorted_rows.defvjp(_unsorted_rows_fwd, _unsorted_rows_bwd)
 
 
+# Rows a trip of the combine's bounded transpose takes. A multiple of the
+# grouped kernels' deepest tile of rows (``pallas_moe_gmm.TILE_M_DRHS``), so
+# every tile a kernel visits lies inside the chunks that were written; above
+# the decode ticks' picks (640 and 1,024 a tick at the serving cells).
+ROW_CHUNK = 2048
+
+
+def _chunks_below(count):
+    """Whole chunks of ``ROW_CHUNK`` sorted rows that hold a row below
+    ``count``, and never none: a grouped kernel visits a tile even for an
+    empty group, and what it reads there must be finite."""
+    return jnp.maximum((count + ROW_CHUNK - 1) // ROW_CHUNK, 1)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def _combine_held(y, w, order, back, flat, groups: int):
+    """The combine of a layer that holds a range of its experts: the sorted
+    rows ``y [N k, D]`` back in pick order (``y[back]``), the picks held
+    elsewhere and the lanes with no token masked (``flat [N k]``, a pick's
+    group, is ``groups`` for those: their rows are undefined), and summed a
+    token with the routing weights ``w [N, k]`` in float32. As the plain
+    program writes it, so a forward pass is the plain program's.
+
+    Its transpose is bounded by the rows in groups. Autodiff would build the
+    ``[N, k, D]`` product of the cotangent and the weights, mask it, gather
+    all ``N k`` rows of it into sorted order, and gather ``y`` by ``back``
+    once more for the weights' gradient: two of the layer's five row moves a
+    step, three quarters of it rows nothing reads where a quarter of the
+    experts is held. Here a loop takes the chunks of ``ROW_CHUNK`` sorted
+    rows that hold a row of a group (its trip count is the count of live
+    picks, read on the device) and no other: ``dy[j] = dout[order[j] // k]
+    w_j`` gathered from the ``[N, D]`` cotangent, cast as the product was,
+    and written over ``y``'s chunk, whose rows the same trip has just read
+    for ``dw_j = sum_d dout[.., d] y[j, d]``. No second ``[N k, D]`` buffer
+    is held. Rows of ``dy`` past the chunks taken are ``y``'s, undefined
+    there; ``grouped_matmul``'s gradient reads none of them."""
+    n, k = w.shape
+    rows = _unsorted_rows(y, order, back).reshape(n, k, -1)
+    rows = jnp.where((flat < groups).reshape(n, k, 1), rows, 0)
+    return jnp.einsum("nkd,nk->nd", rows.astype(jnp.float32), w)
+
+
+def _combine_held_fwd(y, w, order, back, flat, groups):
+    return _combine_held(y, w, order, back, flat, groups), \
+        (y, w, order, back, flat < groups)
+
+
+def _combine_held_bwd(groups, res, dout):
+    y, w, order, back, live = res
+    k = w.shape[1]
+    m = y.shape[0]
+    flat_w = w.reshape(-1)
+
+    def trip(i, carry):
+        # ``rows`` holds y where no trip has been and dy where one has (the
+        # rows before ``first`` of a last, part chunk were the trip's
+        # before and are left as they are)
+        rows, dscale = carry
+        first = i * ROW_CHUNK
+        start = jnp.minimum(first, m - ROW_CHUNK)
+        fresh = start + jnp.arange(ROW_CHUNK) >= first
+        picks = lax.dynamic_slice_in_dim(order, start, ROW_CHUNK)
+        g = dout[picks // k]                              # [chunk, D] float32
+        was = lax.dynamic_slice_in_dim(rows, start, ROW_CHUNK)
+        dy = (g * flat_w[picks][:, None]).astype(y.dtype)
+        rows = lax.dynamic_update_slice_in_dim(
+            rows, jnp.where(fresh[:, None], dy, was), start, 0)
+        ds = jnp.where(fresh, (g * was.astype(jnp.float32)).sum(-1),
+                       lax.dynamic_slice_in_dim(dscale, start, ROW_CHUNK))
+        return rows, lax.dynamic_update_slice_in_dim(dscale, ds, start, 0)
+
+    dy, dscale = lax.fori_loop(
+        0, _chunks_below(live.sum().astype(jnp.int32)), trip,
+        (y, jnp.zeros((m,), jnp.float32)))
+    dw = jnp.where(live, dscale[back], 0).reshape(w.shape)
+    return dy, dw.astype(w.dtype), None, None, None
+
+
+_combine_held.defvjp(_combine_held_fwd, _combine_held_bwd)
+
+
 def _swiglu_limited(gate, up, limit: float):
     """``silu(gate) * up``; with a ``limit`` > 0 the gate is clamped from
     above and the up-projection to ``[-limit, limit]`` first (a per-layer
@@ -519,17 +600,28 @@ def moe_dropless_ffn(params, x, *, top_k: int, scale: float, valid=None,
     every expert is held.
 
     The layer takes all of ``x`` in one pass, so the banks are read once
-    a call whatever its length. What bounds a call is the sorted rows and
-    the rows gathered back (bfloat16 ``[N top_k, D]``: 0.5 GB each for an
-    8,192-token prefill at top 10 of 3,072); the combine's float32
+    a call whatever its length. What bounds a call's memory is the sorted
+    rows and the rows gathered back (bfloat16 ``[N top_k, D]``: 0.5 GB each
+    for an 8,192-token prefill at top 10 of 3,072); the combine's float32
     ``[N, top_k, D]`` lives inside one fusion and is never held
     (tests/test_chip_compile.py looks for it among the buffers).
 
+    Row traffic: a forward pass moves every pick's row twice (the sort's
+    gather, the combine's), held or not; so does the sort's transpose. The
+    combine's transpose is bounded by the rows in groups where the call
+    says rows lie past the last group (``held``) and has a chunk of picks
+    or more (``N top_k >= ROW_CHUNK``, a static shape): :func:`_combine_held`
+    takes the whole chunks of sorted rows that hold a row of a group and no
+    other, and holds no second ``[N top_k, D]`` buffer. Every other call's
+    transpose is autodiff's, and every forward program is the one it was.
+
     ``return_counters``: also a dict of int32 scalars over the valid lanes
-    (``MOE_COUNTERS`` and ``moe_routed``): rows computed here (tokens x
-    top_k where every expert is held: none is ever dropped), distinct
-    experts hit, the most rows at one expert, and the picks made, held or
-    not (tokens x top_k).
+    (``MOE_COUNTERS``, ``moe_routed`` and ``moe_rows_moved``): rows
+    computed here (tokens x top_k where every expert is held: none is ever
+    dropped), distinct experts hit, the most rows at one expert, the picks
+    made, held or not (tokens x top_k), and the sorted rows the combine's
+    transpose takes for this call (the whole chunks that hold a row of a
+    group; ``N top_k``, pad lanes included, where it is not bounded).
 
     ``route_groups``: group-limited selection
     (:func:`sigmoid_topk_route`'s ``groups``).
@@ -543,6 +635,9 @@ def moe_dropless_ffn(params, x, *, top_k: int, scale: float, valid=None,
                                     top_k, scale, route_groups)
     else:
         idx, w = softmax_topk_route(x, params["router"], top_k)
+    # rows lie past the last group and the call has a chunk of picks or more:
+    # the combine's transpose takes the rows in groups alone
+    bounded = held is not None and n * top_k >= ROW_CHUNK
     with jax.named_scope("moe/sort"):
         flat = idx.reshape(-1)
         if held is not None:
@@ -565,14 +660,17 @@ def moe_dropless_ffn(params, x, *, top_k: int, scale: float, valid=None,
         y = grouped_matmul(h, params["w_down"], sizes, tail)
     with jax.named_scope("moe/combine"):
         back = jnp.argsort(order)            # each token's k rows, in order
-        y = _unsorted_rows(y, order, back).reshape(n, top_k, d)
-        if held is not None:
+        if bounded:
             # rows past the last group are undefined: picks held elsewhere
             # and lanes with no token alike
-            y = jnp.where((flat < groups).reshape(n, top_k, 1), y, 0)
-        elif valid is not None:
-            y = jnp.where(valid[:, None, None], y, 0)
-        out = jnp.einsum("nkd,nk->nd", y.astype(jnp.float32), w)
+            out = _combine_held(y, w, order, back, flat, groups)
+        else:
+            y = _unsorted_rows(y, order, back).reshape(n, top_k, d)
+            if held is not None:
+                y = jnp.where((flat < groups).reshape(n, top_k, 1), y, 0)
+            elif valid is not None:
+                y = jnp.where(valid[:, None, None], y, 0)
+            out = jnp.einsum("nkd,nk->nd", y.astype(jnp.float32), w)
     if "shared" in params:
         with jax.named_scope("moe/shared"):
             from distributed_lion_tpu.models.llama import _matmul, _mlp
@@ -588,7 +686,10 @@ def moe_dropless_ffn(params, x, *, top_k: int, scale: float, valid=None,
     if not return_counters:
         return out
     lanes = n if valid is None else valid.sum()
+    moved = jnp.minimum(_chunks_below(sizes.sum()) * ROW_CHUNK, n * top_k) \
+        if bounded else n * top_k
     return out, {"moe_assignments": sizes.sum(),
                  "moe_experts_hit": (sizes > 0).sum().astype(jnp.int32),
                  "moe_load_max": sizes.max(),
-                 "moe_routed": jnp.asarray(lanes * top_k, jnp.int32)}
+                 "moe_routed": jnp.asarray(lanes * top_k, jnp.int32),
+                 "moe_rows_moved": jnp.asarray(moved, jnp.int32)}

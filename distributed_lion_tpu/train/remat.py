@@ -125,11 +125,15 @@ def expert_block_saved_bytes(model_cfg, rows: int, seq: int) -> dict:
     (``parallel/expert.moe_dropless_ffn``), in the same unit ``U``. The
     expert layer keeps, a pick and not a token: the sorted rows
     ``[tokens x top_k, d]``, the gate and up products ``[tokens x top_k,
-    moe_d_ff]`` each and the expert outputs back in pick order (the
-    combine's gradient in the routing weights reads them); the grouped
-    products are kernels with their own gradient, so ``dots`` keeps of the
-    expert layer nothing but the router's float32 logits. A held range
-    changes none of it: the picks held elsewhere still take their rows."""
+    moe_d_ff]`` each and the expert outputs (back in pick order, or as they
+    lie sorted where the combine's transpose is bounded by the rows in
+    groups: the combine's gradient in the routing weights reads them); the
+    grouped products are kernels with their own gradient, so ``dots`` keeps
+    of the expert layer nothing but the router's float32 logits. A held
+    range changes none of the buffers: the picks held elsewhere still have
+    their rows in each. What it changes is the traffic of the combine's
+    transpose, which takes the rows in groups alone
+    (``parallel/expert._combine_held``)."""
     size = _itemsize(model_cfg.compute_dtype)
     d, k = model_cfg.d_model, model_cfg.top_k
     u = rows * seq * d * size
@@ -147,7 +151,10 @@ def expert_backward_bytes(model_cfg, rows: int, seq: int) -> int:
     """What the expert layer's backward holds at once beside what its
     forward kept, under every rung: the cotangents of the outputs in pick
     order and sorted, and of the sorted rows, ``[tokens x top_k, d]``
-    each."""
+    each. The plain program's count: a combine whose transpose is bounded
+    by the rows in groups gathers it from the ``[tokens, d]`` cotangent,
+    never builds the one in pick order and writes the sorted one over the
+    outputs, so a held range of a chunk of picks or more runs under this."""
     return 3 * model_cfg.top_k * rows * seq * model_cfg.d_model \
         * _itemsize(model_cfg.compute_dtype)
 

@@ -804,6 +804,51 @@ def test_moe_gmm_kernel_told_of_a_tail_compiles(one_chip, m, k, n):
     assert not pool_leaf_copies(text, bank)
 
 
+def test_trained_expert_layer_bounds_the_combines_transpose_at_cell_10_widths(
+        one_chip, monkeypatch):
+    """``moe_dropless_ffn`` and its gradient as cell 10 runs a microbatch
+    (16,384 tokens, top 8 of 64, 16 held, 2,304 wide, experts of 896,
+    bfloat16): the combine's transpose is a loop over chunks of sorted rows
+    (its trip count is read on the device) that writes ``dy`` over ``y``
+    where it lies, the grouped products stay the kernels, and the program
+    holds no more under the `full` rung than the plain one (no cotangent in
+    pick order, no second gather of the rows back)."""
+    from distributed_lion_tpu.parallel import expert
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    n, k, d, f = 16384, 8, 2304, 896
+
+    def on_chip(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = {"router": on_chip((64, d), jnp.float32),
+              "w_gate": on_chip((16, d, f)), "w_up": on_chip((16, d, f)),
+              "w_down": on_chip((16, f, d))}
+
+    def loss(params, x):     # under the `full` rung, as the cell runs it
+        y, counters = jax.checkpoint(lambda params, x: expert.moe_dropless_ffn(
+            params, x, top_k=k, scale=1.0, held=(0, 16),
+            return_counters=True))(params, x)
+        return y.astype(jnp.float32).sum(), counters
+
+    def compiled():
+        return jax.jit(jax.value_and_grad(loss, (0, 1), has_aux=True)).lower(
+            params, on_chip((n, d))).compile()
+
+    mine = compiled()
+    text = mine.as_text()
+    for kernel in ("moe_gmm", "moe_gmm_drhs"):
+        assert _named_custom_call(text, kernel), kernel
+    assert re.search(r"moe/combine\)*/while", text)
+    assert re.search(r"bf16\[%d,%d\]\S* dynamic-update-slice\(" % (n * k, d),
+                     text)
+    monkeypatch.setattr(expert, "ROW_CHUNK", n * k + 1)   # the plain program
+    plain = compiled()
+    assert not re.search(r"moe/combine\)*/while", plain.as_text())
+    assert mine.memory_analysis().temp_size_in_bytes \
+        <= plain.memory_analysis().temp_size_in_bytes
+
+
 def test_train_blocks_compile_under_the_workers_shard_map(topo, monkeypatch):
     """Cell 4's path: the same two blocks, plain as its ``auto`` leaves
     them, inside a ``shard_map`` over the four chips' ``data`` axis, 4

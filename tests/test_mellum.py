@@ -46,20 +46,23 @@ def _rows(step, rows, block, vocab):
 
 
 # ------------------------------------------------ trainer against reference
-@pytest.mark.parametrize("dtype,loss_tol,grad_tol", [
-    ("float32", 2e-6, 2e-4), ("bfloat16", 2e-3, 0.25)])
+@pytest.mark.parametrize("dtype,loss_tol,grad_tol,block", [
+    ("float32", 2e-6, 2e-4, 32), ("bfloat16", 2e-3, 0.25, 32),
+    ("float32", 2e-6, 2e-4, 512)])
 def test_trainer_follows_the_reference_for_two_steps(family, dtype, loss_tol,
-                                                     grad_tol):
+                                                     grad_tol, block):
     """Loss and every leaf's gradient of the first two optimizer steps
     (2 x 2 rows of 32 tokens, Lion at W = 1), the gradient read from the
     momentum as the benchmark's driver reads it. float32 compute: tight;
-    bfloat16 compute: within a stated band of the float32 reference."""
+    bfloat16 compute: within a stated band of the float32 reference. Rows
+    of 512 tokens make a microbatch one chunk of picks (2 x 512 x 2), so the
+    combine's transpose is the one bounded by the rows in groups."""
     cfg, ref = family.TINY, family.reference
     b2, lr, wd = 0.99, 1e-3, 0.1
     tcfg = TrainConfig(lion=True, async_grad=True, learning_rate=lr,
                        weight_decay=wd, warmup_steps=0, max_steps=50,
                        per_device_train_batch_size=2,
-                       gradient_accumulation_steps=2, block_size=32,
+                       gradient_accumulation_steps=2, block_size=block,
                        logging_steps=1000, eval_steps=1000, save_steps=1000,
                        seed=0)
     mesh = make_mesh(data=1, devices=jax.devices()[:1])
@@ -80,7 +83,7 @@ def test_trainer_follows_the_reference_for_two_steps(family, dtype, loss_tol,
     m_prev = jax.tree.map(jnp.zeros_like, trainer.state.exp_avg)
     key = jax.random.key(1)
     for s in range(2):
-        rows = _rows(s, 4, 32, cfg["vocab_size"])
+        rows = _rows(s, 4, block, cfg["vocab_size"])
         trainer.params, trainer.state, _, metrics = trainer._train_step(
             trainer.params, trainer.state, trainer.vote_health,
             trainer._frozen_arg(), jnp.asarray(rows), key)
@@ -97,9 +100,12 @@ def test_trainer_follows_the_reference_for_two_steps(family, dtype, loss_tol,
                 / max(norms[k], median)
             assert gap <= grad_tol, (s, k, gap)
         if s == 0:
-            assert float(metrics["moe_routed"]) == 4 * 32 * 4 * 2
-            assert 0 < float(metrics["moe_assignments"]) \
-                < float(metrics["moe_routed"])
+            routed = 4 * block * 4 * 2
+            assert float(metrics["moe_routed"]) == routed
+            assert 0 < float(metrics["moe_assignments"]) < routed
+            # 128 picks a call: under one chunk, not bounded; 2,048: the one
+            # chunk that holds the rows in groups, which is all of them
+            assert float(metrics["moe_rows_moved"]) == routed
         w, momenta = step_fn(w, momenta, [g], ref.cosine_warmup_lr(
             s, lr, 0, 50))
     trainer.close()
@@ -117,7 +123,8 @@ def test_forward_matches_the_reference_and_counts_its_picks(family):
     np.testing.assert_allclose(mine, want, atol=2e-5)
     assert model_cfg.held == (0, 4) and model_cfg.n_experts == 8
     assert set(MELLUM_COUNTERS) == {"moe_assignments", "moe_experts_hit",
-                                    "moe_load_max", "moe_routed"}
+                                    "moe_load_max", "moe_routed",
+                                    "moe_rows_moved"}
 
 
 # ------------------------------------------------- the grouped product's vjp
@@ -329,6 +336,7 @@ def test_run_clm_names_the_family_with_a_file(family, tmp_path, capsys):
         == ["step=1", "step=3"]
     assert "[trainer] Mellum" in out and "experts 0-3 of 8 held" in out
     assert "[setup] remat: full" in out and "train/moe_routed=" in out
+    assert "train/moe_rows_moved=" in out
     with pytest.raises(ValueError, match="does not take"):
         run_clm.main(["--model_family", "mellum", "--model_name", "tiny",
                       "--moe_experts", "2"])
