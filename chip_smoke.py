@@ -379,6 +379,19 @@ def phase_kernels() -> None:
 
 # ------------------------------------------------------------------- train
 # ---------------------------------------------------------------- train_moe
+def clock(fn, *args):
+    """``(fn(*args), least milliseconds of five more calls)``."""
+    import jax
+
+    out = jax.block_until_ready(fn(*args))
+    times = []
+    for _ in range(5):
+        t0 = time.perf_counter()
+        jax.block_until_ready(fn(*args))
+        times.append(time.perf_counter() - t0)
+    return out, min(times) * 1e3
+
+
 def expert_combine_at_size(n: int = 2 * 8192, d: int = 2304,
                            mean: int = 32283) -> None:
     """The combine of a layer that holds a range of its experts, at cell
@@ -403,15 +416,6 @@ def expert_combine_at_size(n: int = 2 * 8192, d: int = 2304,
     y = jax.random.normal(ks[0], (m, d), jnp.bfloat16)
     w = jax.random.uniform(ks[1], (n, k), jnp.float32)
     dout = jax.random.normal(ks[2], (n, d), jnp.float32)
-
-    def clock(fn, *args):
-        out = jax.block_until_ready(fn(*args))
-        times = []
-        for _ in range(5):
-            t0 = time.perf_counter()
-            jax.block_until_ready(fn(*args))
-            times.append(time.perf_counter() - t0)
-        return out, min(times) * 1e3
 
     def plain(y, w, order, back, flat):
         rows = expert._unsorted_rows(y, order, back).reshape(n, k, d)
@@ -450,6 +454,69 @@ def expert_combine_at_size(n: int = 2 * 8192, d: int = 2304,
               f"the combine's dw gap {gap} ({at})")
         log(f"train_moe combine, {at}: forward and transpose {t_mine:.3f} ms "
             f"bounded, {t_plain:.3f} plain; dw gap {gap:.2e}")
+
+
+def qk_rope_at_size(T: int = 8192, B: int = 2) -> None:
+    """q's and k's head norm and rotation at cell 10's shapes (a microbatch
+    of 2 x 8,192 rows, 32 and 4 heads of 128, bfloat16, YaRN's table): the
+    kernel pair ``qk_rope_fwd`` / ``qk_rope_bwd`` (``ops/pallas_qk_rope``)
+    against the plain expression it stands for
+    (``models/mellum.head_norm_rope``: ``apply_rope_half(_rms_norm(...))``
+    over ``[B T, n, 1, 128]``), both
+    held to that expression in float32: the kernel rounds once and must be
+    no further from it than the plain bfloat16 path, which rounds twice.
+    Each one's time is printed, forward alone and forward with both
+    gradients (the least of five)."""
+    import jax
+    import jax.numpy as jnp
+
+    from distributed_lion_tpu.models.mellum import MellumConfig, head_norm_rope
+    from distributed_lion_tpu.ops.pallas_qk_rope import qk_norm_rope
+
+    cos, sin = MellumConfig().rope_full.angles(jnp.arange(T))
+
+    def plain(y, scale):
+        return head_norm_rope(y, scale, cos, sin, 1e-6)
+
+    def fused(y, scale):
+        return qk_norm_rope(y, scale, cos, sin, 1e-6)
+
+    def gap(a, b):
+        a, b = a.astype(jnp.float32), b.astype(jnp.float32)
+        return float(jnp.abs(a - b).max() / jnp.abs(b).max())
+
+    for n in (32, 4):
+        ks = jax.random.split(jax.random.key(45 + n), 3)
+        y = 3 * jax.random.normal(ks[0], (B, T, n * 128), jnp.bfloat16)
+        scale = 1 + 0.1 * jax.random.normal(ks[1], (128,), jnp.float32)
+        w = jax.random.normal(ks[2], (B, T, n * 128), jnp.bfloat16)
+
+        def both(fn):
+            return jax.jit(jax.value_and_grad(
+                lambda y, scale: (fn(y, scale).astype(jnp.float32)
+                                  * w.astype(jnp.float32)).sum(), (0, 1)))
+
+        kernels = set(mosaic_kernels(both(fused).lower(y, scale).as_text()))
+        check({"qk_rope_fwd", "qk_rope_bwd"} <= kernels,
+              f"the norm and rotation's kernels: {kernels}")
+        exact = (plain(y.astype(jnp.float32), scale),
+                 *both(plain)(y.astype(jnp.float32), scale)[1])
+        at = f"[{B * T}, {n * 128}]"
+        gaps = {}
+        for name, fn in (("kernel", fused), ("plain", plain)):
+            out, t_fwd = clock(jax.jit(fn), y, scale)
+            (_, (dx, dscale)), t_both = clock(both(fn), y, scale)
+            gaps[name] = [gap(a, b) for a, b in zip((out, dx, dscale), exact)]
+            log(f"train_moe qk_rope {at} {name}: forward {t_fwd:.3f} ms, "
+                f"with dx and dscale {t_both:.3f} ms; against float32 "
+                "output {:.2e}, dx {:.2e}, dscale {:.2e} of the largest "
+                "entry".format(*gaps[name]))
+        # one bfloat16 rounding of the largest entry, and no further from
+        # float32 than the expression that rounds twice
+        check(max(gaps["kernel"]) < 4e-3
+              and all(a <= b + 1e-4 for a, b in zip(gaps["kernel"],
+                                                    gaps["plain"])),
+              f"qk_rope {at} gaps {gaps}")
 
 
 def phase_train_moe() -> None:
@@ -514,6 +581,9 @@ def phase_train_moe() -> None:
 
     # -- the combine's transpose, bounded by the rows in groups
     expert_combine_at_size()
+
+    # -- q's and k's head norm and rotation where the projection wrote them
+    qk_rope_at_size()
 
     # -- the attention pair at 32 / 4 heads of 128 over 8,192 positions
     T, H, KV, window = 8192, 32, 4, 1024
@@ -593,7 +663,8 @@ def phase_train_moe() -> None:
     step = jax.jit(jax.value_and_grad(loss, has_aux=True))
     kernels = set(mosaic_kernels(step.lower(params, tokens).as_text()))
     check({"moe_gmm", "moe_gmm_drhs", "flash_gqa_lse", "flash_gqa_dq",
-           "flash_gqa_dkv"} <= kernels, f"the block's kernels: {kernels}")
+           "flash_gqa_dkv", "qk_rope_fwd", "qk_rope_bwd"} <= kernels,
+          f"the block's kernels: {kernels}")
     (value, counters), grads = step(params, tokens)
     flat = jnp.concatenate([g.reshape(-1) for g in jax.tree.leaves(grads)])
     check(bool(jnp.isfinite(flat).all()) and bool(jnp.isfinite(value)),

@@ -185,37 +185,50 @@ def mellum_param_specs(cfg: MellumConfig) -> dict:
     return jax.tree.map(lambda _: P(), shapes)
 
 
+def head_norm_rope(y, scale, cos, sin, eps):
+    """Each head of ``y [B, T, n * hd]`` RMS-normed over its ``hd`` lanes
+    with the weight ``scale [hd]`` and rotated by ``cos``, ``sin`` ``[T, rot /
+    2]``: the plain expression, which ``ops/pallas_qk_rope.qk_norm_rope``
+    stands for on a TPU (same arguments) and is held to."""
+    B, T, width = y.shape
+    hd = scale.shape[0]
+    c, s = (jnp.broadcast_to(t, (B,) + t.shape).reshape(B * T, 1, -1)
+            for t in (cos, sin))
+    y = _rms_norm(y.reshape(B * T, width // hd, 1, hd), {"scale": scale}, eps)
+    return apply_rope_half(y, c, s).reshape(B, T, width)
+
+
 def _attention(x, p, cfg: MellumConfig, windowed: bool):
-    """x [B, T, d] -> [B, T, d]; token-major throughout on the kernel's
-    path (no head-major copy of q, k, v or the output)."""
+    """x [B, T, d] -> [B, T, d]; token-major throughout on the kernels'
+    path (no head-major copy of q, k, v or the output, and q and k normed
+    and rotated where the projection wrote them)."""
     from distributed_lion_tpu.ops import pallas_flash_attn as flash
+    from distributed_lion_tpu.ops import pallas_qk_rope
     from distributed_lion_tpu.ops.attention import banded_causal_attention
 
     B, T, _ = x.shape
-    H, KV, hd = cfg.n_head, cfg.n_kv_head, cfg.head_dim
+    H, hd = cfg.n_head, cfg.head_dim
     rope = cfg.rope_window if windowed else cfg.rope_full
     window = cfg.window if windowed and cfg.window < T else 0
-    cos, sin = (jnp.broadcast_to(t, (B,) + t.shape).reshape(B * T, 1, -1)
-                for t in rope.angles(jnp.arange(T)))
+    cos, sin = rope.angles(jnp.arange(T))
+    on_tpu = jax.default_backend() == "tpu"
+    fused = on_tpu and pallas_qk_rope.qk_rope_takes(
+        T, hd, rope.rotary_dim, x.dtype)
+    norm_rope = pallas_qk_rope.qk_norm_rope if fused else head_norm_rope
 
-    def heads(w, norm, n):
-        """Project, RMS-norm each head's lanes, rotate: [B, T, n, hd]."""
-        y = _rms_norm(_matmul(x, w).reshape(B * T, n, 1, hd), norm,
-                      cfg.rms_eps)
-        return apply_rope_half(y, cos, sin).reshape(B, T, n, hd)
+    def heads(w, norm):
+        """Project, RMS-norm each head's lanes, rotate: [B, T, n * hd]."""
+        return norm_rope(_matmul(x, w), norm["scale"], cos, sin, cfg.rms_eps)
 
-    q = heads(p["wq"], p["q_norm"], H)
-    k = heads(p["wk"], p["k_norm"], KV)
+    q = heads(p["wq"], p["q_norm"])
+    k = heads(p["wk"], p["k_norm"])
     v = _matmul(x, p["wv"])
-    if jax.default_backend() == "tpu" and flash.gqa_train_kernel_takes(
-            T, hd, q.dtype):
-        out = flash.flash_gqa(q.reshape(B, T, H * hd),
-                              k.reshape(B, T, KV * hd), v, H, window)
+    if on_tpu and flash.gqa_train_kernel_takes(T, hd, q.dtype):
+        out = flash.flash_gqa(q, k, v, H, window)
     else:
-        out = banded_causal_attention(
-            q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
-            v.reshape(B, T, KV, hd).transpose(0, 2, 1, 3),
-            window=window or None)
+        q, k, v = (t.reshape(B, T, -1, hd).transpose(0, 2, 1, 3)
+                   for t in (q, k, v))
+        out = banded_causal_attention(q, k, v, window=window or None)
         out = out.transpose(0, 2, 1, 3).reshape(B, T, H * hd)
     return _matmul(out, p["wo"])
 

@@ -45,7 +45,7 @@ SCOPES = ("embed", "attn", "mlp", "head", "xent", "paged_attn",
           "mhc/post", "mhc/read_out", "mhc_pre", "mhc_post",
           "flash_gqa_fwd", "attn/window", "attn/full", "moe",
           "moe_gmm_drhs", "flash_gqa_lse", "flash_gqa_di", "flash_gqa_dq",
-          "flash_gqa_dkv")
+          "flash_gqa_dkv", "qk_rope_fwd", "qk_rope_bwd")
 NO_SCOPE = "(no scope)"
 _FIND = [(s, re.compile(r"(?<=[(/])%s(?=[)/])" % re.escape(s)))
          for s in SCOPES]

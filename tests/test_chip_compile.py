@@ -14,6 +14,7 @@ off around them (an entry compiled for a described device cannot be read
 back without a chip).
 """
 
+import dataclasses
 import os
 import re
 import time
@@ -847,6 +848,72 @@ def test_trained_expert_layer_bounds_the_combines_transpose_at_cell_10_widths(
     assert not re.search(r"moe/combine\)*/while", plain.as_text())
     assert mine.memory_analysis().temp_size_in_bytes \
         <= plain.memory_analysis().temp_size_in_bytes
+
+
+@pytest.mark.parametrize("width", [4096, 512], ids=["q", "k"])
+def test_qk_rope_kernels_compile_at_cell_10_widths(one_chip, width):
+    """``qk_rope_fwd`` / ``qk_rope_bwd`` (ops/pallas_qk_rope) over a
+    microbatch of 2 x 8,192 rows of 32 and of 4 heads, bfloat16."""
+    from distributed_lion_tpu.ops.pallas_qk_rope import qk_norm_rope
+
+    def on_chip(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def both(y, scale, cos, sin, w):
+        out, back = jax.vjp(
+            lambda y, scale: qk_norm_rope(y, scale, cos, sin, 1e-6), y, scale)
+        return (out,) + back(w)
+
+    y = on_chip((2, 8192, width), jnp.bfloat16)
+    text, secs = _compile(both, y, on_chip((128,)), on_chip((8192, 64)),
+                          on_chip((8192, 64)), y)
+    assert secs < 30
+    for kernel in ("qk_rope_fwd", "qk_rope_bwd"):
+        assert _named_custom_call(text, kernel), kernel
+
+
+def test_mellum_window_layer_norms_and_rotates_where_the_projection_wrote(
+        one_chip, monkeypatch):
+    """The gradient of one window layer at cell 10's size (``x +
+    _attention(_rms_norm(x))`` under ``jax.checkpoint``, 2 x 8,192 rows of
+    2,304, bfloat16): q and k go from their projections to ``flash_gqa``
+    through the one kernel a direction, so nothing takes the 4-D head shape
+    whose tiling the compiler lays over (head, lane) (``[16384,32,1,128]``,
+    ``[16384,4,1,128]``: every crossing was a relayout of 134 MB) and no
+    ``reshape`` of q's size is a real copy."""
+    from distributed_lion_tpu.models import mellum
+    from distributed_lion_tpu.models.llama import _rms_norm
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = mellum.MellumConfig()
+
+    def on_chip(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+
+    p = jax.tree.map(on_chip, jax.eval_shape(
+        lambda: mellum.mellum_init(jax.random.key(0), dataclasses.replace(
+            cfg, n_layer=1, vocab_size=128))["blocks"][0]))
+
+    @jax.checkpoint
+    def layer(x, p):
+        return x + mellum._attention(
+            _rms_norm(x, p["ln_attn"], cfg.rms_eps), p["attn"], cfg, True)
+
+    x = jax.ShapeDtypeStruct((2, 8192, cfg.d_model), jnp.bfloat16,
+                             sharding=one_chip)
+    text, secs = _compile(jax.grad(
+        lambda x, p: layer(x, p).astype(jnp.float32).sum(), (0, 1)), x, p)
+    assert secs < 40
+    for kernel in ("qk_rope_fwd", "qk_rope_bwd", "flash_gqa_lse",
+                   "flash_gqa_dq", "flash_gqa_dkv"):
+        assert _named_custom_call(text, kernel), kernel
+    assert not re.search(r"= \w+\[16384,(32|4),1,", text)
+    real_reshapes = [
+        m.group(0)[:160] for m in re.finditer(
+            r"^\s*(?:ROOT )?%?[\w.\-]+ = \w+\[([\d,]+)\]\S* reshape\(.*$",
+            text, re.M)
+        if np.prod([int(d) for d in m.group(1).split(",")]) >= 2 ** 25]
+    assert not real_reshapes, real_reshapes      # a bitcast is another opcode
 
 
 def test_train_blocks_compile_under_the_workers_shard_map(topo, monkeypatch):
