@@ -1828,6 +1828,389 @@ def lightning_at_size() -> None:
         f"{rows * 2 * H * d * d * 4 / ms / 1e6:.0f} GB/s of state")
 
 
+def phase_serve_dsa() -> None:
+    """The learned-indexer family (models/dots3) at a small lane-aligned
+    size through the constructors ``run_serve --model_family dots3`` calls,
+    with an ``index_topk`` of 128 so that its rows select: the decode tick
+    holds ``dsa_index`` and ``dsa_attn`` once a full layer and
+    ``window_mla_attn`` once a sliding layer, no dispatch copies a page leaf,
+    an index-key leaf or a ring; slots are admitted twice; and the S = 1
+    kernel path through pages, index keys and rings gives the logits of one
+    prefill window (the chunked walk under the mask) on the tokens it
+    served, in float32 at the highest matmul precision. Then the kernels
+    against ``ops/dsa.py`` at the published shapes (:func:`dsa_at_size`),
+    the latent ring held to the position (:func:`latent_ring_at_size`) and
+    the 12,288-token prefill's two attentions, timed
+    (:func:`dsa_prefill_at_size`)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from distributed_lion_tpu.models.dots3 import (
+        Dots3Config, Latent, dots3_decode_paged, dots3_init, lora_rescale,
+    )
+    from distributed_lion_tpu.ops.attention import ring_pages
+    from distributed_lion_tpu.serve.engine import (
+        Request, ServeConfig, ServeModel, ServingEngine,
+    )
+    from distributed_lion_tpu.serve.kv_cache import init_page_leaves
+
+    cfg = Dots3Config.tiny(
+        vocab_size=1024, d_model=256,
+        full=Latent(8, 128, 128, 64, 64, 64, 8e7, 1e-5,
+                    (lora_rescale(256, 128), lora_rescale(256, 128))),
+        swa=Latent(4, 128, 256, 64, 64, 64, 5e4, 1e-5,
+                   (lora_rescale(256, 128), lora_rescale(256, 256))),
+        window=33, index_n_heads=4, index_head_dim=128, index_topk=128,
+        d_ff=512, moe_d_ff=128, held=(0, 4), page_run=64)
+    params = dots3_init(jax.random.key(46), cfg)
+    block, max_blocks, n_seq = 16, 64, 4   # every bucket a power of two
+    model = ServeModel.for_dots3(params, cfg)
+    engine = ServingEngine(model, ServeConfig(
+        max_seqs=n_seq, block_size=block, max_blocks_per_seq=max_blocks,
+        moe_stats=True, prefill_cap_tokens=512))
+    rng = np.random.default_rng(46)
+    prompts = [rng.integers(0, cfg.vocab_size, n).tolist()
+               for n in (300, 500, 411, 270, 140, 333)]   # 500 + 8 served: a window of 512
+    done = engine.run([Request(req_id=i, tokens=p,
+                               max_new_tokens=SERVE_NEW_TOKENS)
+                       for i, p in enumerate(prompts)])
+    stats = engine.stats
+    check(all(done[i].reason == "length" for i in range(len(prompts))), done)
+    assert_donated(engine)
+    assert_pool_in_place(engine, kernels=("dsa_index", "dsa_attn",
+                                          "window_mla_attn"))
+    check(stats["mla_kernel_ticks"] == stats["decode_ticks"] > 0, stats)
+    check(stats["window_kernel_ticks"] == stats["decode_ticks"], stats)
+    fulls = len(cfg.full_layers)
+    check(stats["dsa_rows"] == stats["decode_tokens"] * fulls, stats)
+    check(0 < stats["dsa_keys_kept"] < stats["dsa_keys_visible"],
+          stats)
+    check(stats["dsa_keys_kept"]
+          == stats["dsa_rows"] * cfg.index_topk, stats)
+    run = engine.tables.run_pages
+    check(run == cfg.page_run // block == 4, run)
+    log(f"  dsa_rows {stats['dsa_rows']} (= decode tokens x {fulls} full "
+        f"layers), keys kept {stats['dsa_keys_kept']} of "
+        f"{stats['dsa_keys_visible']} visible (top {cfg.index_topk}); "
+        f"window pages read {stats['kv_window_pages_read']} (a ring of "
+        f"{ring_pages(cfg.window, block)}); pages minted in runs of {run}")
+
+    dsa_at_size()
+    latent_ring_at_size()
+    dsa_prefill_at_size()
+    # a run's four pages aligned, as the engine mints them
+    heads = jnp.arange(n_seq * max_blocks // run, dtype=jnp.int32)[::-1] * run
+    tables = (heads[:, None] + jnp.arange(run)).reshape(n_seq, max_blocks)
+    slots = jnp.arange(n_seq, dtype=jnp.int32)
+    f32 = jnp.float32
+    cfg32 = dataclasses.replace(cfg, param_dtype=f32, compute_dtype=f32)
+    params32 = jax.tree.map(lambda x: x.astype(f32), params)
+    ring = n_seq * ring_pages(cfg.window, block)
+    with jax.default_matmul_precision("highest"):
+        worst = teacher_forced_gap(
+            lambda t, pg, pos, valid: dots3_decode_paged(
+                params32, t, cfg32, pg, tables, slots, pos, valid),
+            lambda: init_page_leaves(
+                cfg.n_layer, n_seq * max_blocks, block, model.page_leaves,
+                f32, ring=(cfg.window_layers, ring, model.window_leaves)),
+            prompts[:n_seq], [done[i].tokens for i in range(n_seq)], block)
+    log(f"  kernel path through pages, index keys and rings vs one prefill "
+        f"window in float32 at the highest matmul precision, logits "
+        f"teacher-forced on the served tokens: max |diff| {worst:.5f} "
+        f"(tol {LOGIT_TOL})")
+    check(worst <= LOGIT_TOL, f"dsa decode logits off by {worst}")
+
+
+def dsa_at_size() -> None:
+    """The decode tick's indexer and kept-set attention at the PUBLISHED
+    shape (64 index heads of 128, 128 heads over latent rows of 576 values in
+    640 lanes, pages of 16 minted in runs of 4, the 2,048 best kept) over 64
+    rows of 2,047 to 16,383 cached positions: ``dsa_index`` against the
+    gathered rows through ``ops/dsa.index_scores``; ``kept_positions``
+    against ``lax.top_k``'s set, row for row; ``dsa_attn`` against a float32
+    softmax over the kept rows alone. Each is timed, and beside them the
+    same walk with no mask and the whole sort."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from distributed_lion_tpu.ops import dsa
+    from distributed_lion_tpu.ops.attention import (
+        mla_decode_attention, paged_gather_kv,
+    )
+
+    B, Hi, di, H, W, r_dim = 64, 64, 128, 128, 640, 576
+    bs, nb, topk, run = 16, 1024, 2048, 64
+    NB = B * nb
+    rng = np.random.default_rng(4646)
+    ends = rng.integers(8192, 16384, B)
+    ends[:5] = [2046, 2047, 2048, 12287, 16383]
+    pos = jnp.asarray(ends, jnp.int32)
+    heads = rng.permutation(NB // 4)[:B * nb // 4].reshape(B, nb // 4) * 4
+    tables = jnp.asarray(
+        (heads[:, :, None] + np.arange(4)).reshape(B, nb), jnp.int32)
+    key = jax.random.key(4646)
+    k1, k2, k3, k4, k5 = jax.random.split(key, 5)
+    ik = jax.random.normal(k1, (NB, bs, 1, di), jnp.bfloat16)
+    kv = jnp.pad(jax.random.normal(k2, (NB, bs, 1, r_dim), jnp.bfloat16),
+                 ((0, 0), (0, 0), (0, 0), (0, W - r_dim)))
+    qi = (1.4 * jax.random.normal(k3, (B, Hi, di))).astype(jnp.bfloat16)
+    wi = jax.random.normal(k4, (B, Hi), jnp.float32) / math.sqrt(Hi * di)
+    q_abs = jnp.pad(
+        (0.6 * jax.random.normal(k5, (B, H, r_dim))).astype(jnp.bfloat16),
+        ((0, 0), (0, 0), (0, W - r_dim)))
+    scale = 1.0 / math.sqrt(192)
+    T = nb * bs
+    visible = jnp.arange(T)[None, :] <= pos[:, None]
+
+    # the big arrays go in as operands: a closure would bake 1.6 GB of
+    # constants into every program
+    index = jax.jit(lambda ik: dsa.decode_index_scores(
+        qi, wi, ik, tables, pos, page_run=run))
+    plain = jax.jit(lambda ik: dsa.index_scores(
+        qi[:, None], wi[:, None],
+        paged_gather_kv(ik, tables)[:, :, 0])[:, 0])
+    got, index_ms = clock(index, ik)
+    want, plain_ms = clock(plain, ik)
+    big = float(jnp.abs(jnp.where(visible, want, 0)).max())
+    off = float(jnp.abs(jnp.where(visible, got - want, 0)).max()) / big
+    log(f"  dsa_index at {B} rows x {Hi} heads of {di} over "
+        f"{int((pos + 1).sum())} index keys by runs of {run}: {index_ms:.3f} "
+        f"ms a layer ({float((pos + 1).sum()) * 256 / index_ms / 1e6:.0f} "
+        f"GB/s of index keys); gathered through XLA {plain_ms:.3f} ms; worst "
+        f"|diff| / max {off:.2e}")
+    check(off <= 1e-5, f"dsa_index off by {off}")
+    del ik, want
+
+    select = jax.jit(lambda s: dsa.kept_positions(s, visible, topk))
+    keep, select_ms = clock(select, got)
+
+    def by_sort(s):
+        idx = jax.lax.top_k(jnp.where(visible, s, -jnp.inf), topk)[1]
+        hit = jnp.zeros((B, T), bool).at[
+            jnp.arange(B)[:, None], idx].set(True)
+        return hit & visible
+
+    sort, sort_ms = clock(jax.jit(by_sort), got)
+    n_kept = np.asarray(keep.sum(1))
+    check(n_kept.tolist() == np.minimum(ends + 1, topk).tolist(), n_kept)
+    check(bool((keep == sort).all()),
+          f"kept_positions differs from the sort's set in "
+          f"{int((keep != sort).sum())} places")
+    log(f"  kept_positions, the {topk} best of 2,047-16,383 a row, {B} rows: "
+        f"{select_ms:.3f} ms a layer, the sort's set row for row "
+        f"(lax.top_k and a scatter: {sort_ms:.3f} ms)")
+
+    attn = jax.jit(lambda kv, m: dsa.kept_decode_attention(
+        q_abs, kv, tables, pos, m, scale=scale, page_run=run))
+    walk = jax.jit(lambda kv: mla_decode_attention(
+        q_abs, kv, tables, pos, scale=scale))
+
+    def reference(kv, m):
+        """A float32 softmax over the rows ``m`` leaves, eight rows of the
+        batch at a time (all 64 would hold 2.7 GB of float32 rows)."""
+        def some(args):
+            q, tab, mm = args
+            rows = paged_gather_kv(kv, tab)[:, :, 0].astype(jnp.float32)
+            s = jnp.einsum("bhw,btw->bht", q.astype(jnp.float32), rows,
+                           precision="highest") * scale
+            p = jax.nn.softmax(jnp.where(mm[:, None], s, -jnp.inf), -1)
+            return jnp.einsum("bht,btw->bhw", p, rows, precision="highest")
+
+        def parts(x):
+            return x.reshape((B // 8, 8) + x.shape[1:])
+
+        return jax.lax.map(some, (parts(q_abs), parts(tables), parts(m))
+                           ).reshape(B, H, W)
+
+    out, attn_ms = clock(attn, kv, keep)
+    _, walk_ms = clock(walk, kv)
+    reference = jax.jit(reference)
+    ref = reference(kv, keep)
+    err = float(jnp.abs(out.astype(jnp.float32) - ref).max()
+                / jnp.abs(ref).max())
+    every = reference(kv, visible)
+    moved = float(jnp.abs(every - ref).max() / jnp.abs(ref).max())
+    kept_bytes = float(keep.sum()) * 1280
+    log(f"  dsa_attn at {H} heads over rows of {W} lanes, "
+        f"{int(keep.sum())} kept of {int(visible.sum())} visible: "
+        f"{attn_ms:.3f} ms a layer ({kept_bytes / attn_ms / 1e6:.0f} GB/s of "
+        f"kept rows; the same walk with no mask {walk_ms:.3f} ms); worst "
+        f"|diff| / max against a float32 softmax over the kept rows "
+        f"{err:.5f} (tol 0.02; attending every visible row instead moves it "
+        f"by {moved:.3f})")
+    check(err <= 0.02, f"dsa_attn off by {err}")
+    check(moved > 0.1, f"the check does not see a walk with no mask: {moved}")
+
+
+def latent_ring_at_size() -> None:
+    """A sliding layer's decode walk at the PUBLISHED shape (64 heads over
+    latent rows of 1,088 values in 1,152 lanes, window 513, pages of 16: a
+    ring of 34), held to the position as :func:`ring_edges_at_size` holds the
+    key-and-value ring: a loud row just inside each edge of every row's
+    window, at ``pos - 512`` and at ``pos``, and one just outside at ``pos -
+    513`` (each the loud direction plus its own noise: a latent row is key
+    and value both, so three equal rows would average to the same output
+    whether two or three are seen). The reference is a float32 softmax over the last 513 rows of the
+    plain sequence. Then the check itself is checked: the same walk told the
+    window is 512 or 514, or over rings of 32 pages, must fail it (513
+    positions touch 33 pages of 16 at most: ``ring_pages`` keeps one to
+    spare, so it is a ring one short of what a window FILLS that fails)."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    from distributed_lion_tpu.ops import attention as ops
+
+    H, W, width, window, bs = 64, 1152, 1088, 513, 16
+    R = ops.ring_pages(window, bs)
+    ends = [511, 512, 513, 528, 543, 544, 1040, R * bs * 2 - 1, R * bs * 2,
+            12287, 16383]
+    B, T = len(ends), max(ends) + 1
+    scale = 1.0 / 16.0
+    rng = np.random.default_rng(4647)
+    loud = rng.standard_normal((B, width)).astype(np.float32)
+    loud *= 14.6 / np.linalg.norm(loud, axis=-1, keepdims=True)
+    rows = rng.standard_normal((B, T, width)).astype(np.float32)
+    for b, p in enumerate(ends):
+        for at in (p - window, p - window + 1, p):
+            if at >= 0:      # as loud as each other, and each its own row:
+                # a latent row is key AND value, so equal rows would hide
+                # one more or one fewer of them
+                rows[b, at] += 1.5 * loud[b]
+    q = loud[:, None] + 0.5 * rng.standard_normal(
+        (B, H, width)).astype(np.float32)
+    q = jnp.pad(jnp.asarray(q, jnp.bfloat16),
+                ((0, 0), (0, 0), (0, W - width)))
+    rows = jnp.asarray(rows, jnp.bfloat16)
+    pos = jnp.asarray(ends, jnp.int32)
+    slots = jnp.arange(B, dtype=jnp.int32)[::-1]
+
+    def walk(window):
+        leaf = jnp.zeros((B * ops.ring_pages(window, bs), bs, 1, W),
+                         jnp.bfloat16)
+        leaf = ops.ring_scatter_kv(leaf, slots, jnp.zeros_like(pos),
+                                   rows[:, :, None], pos + 1, window=window)
+        out, read = ops.ring_mla_decode_attention(
+            q, leaf, slots, pos, window=window, scale=scale)
+        return np.asarray(out[..., :width], np.float32), np.asarray(read)
+
+    want = np.zeros((B, H, width), np.float32)
+    rf, qf = np.asarray(rows, np.float32), np.asarray(q, np.float32)
+    for b, p in enumerate(ends):
+        lo = max(p - window + 1, 0)
+        sc = qf[b, :, :width] @ rf[b, lo:p + 1].T * scale
+        w = np.exp(sc - sc.max(1, keepdims=True))
+        want[b] = (w / w.sum(1, keepdims=True)) @ rf[b, lo:p + 1]
+
+    def off(got):
+        return float(np.abs(got - want).max() / np.abs(want).max())
+
+    got, read = walk(window)
+    sound = off(got)
+    check(read.tolist() == [p // bs - max(p - window + 1, 0) // bs + 1
+                            for p in ends] and read.max() <= R, read)
+    early, late = off(walk(window + 1)[0]), off(walk(window - 1)[0])
+    whole, ops.ring_pages = ops.ring_pages, lambda w, b: -(-w // b) - 1
+    try:       # a ring one page short of the pages a window fills
+        short = off(walk(window)[0])
+    finally:
+        ops.ring_pages = whole
+    log(f"  latent ring walk at 64 heads x 1,152 lanes, window 513, {B} rows "
+        f"ending at {ends}: worst |diff| / max {sound:.5f} (tol 0.02; pages "
+        f"handed {read.tolist()}); told 514: {early:.3f}, told 512: "
+        f"{late:.3f}, a ring of 32 pages: {short:.3f} (each must exceed 0.1)")
+    check(sound <= 0.02, f"latent ring walk off by {sound}")
+    check(min(early, late, short) > 0.1,
+          f"the ring check does not see a wrong window: {early} {late} "
+          f"{short}")
+
+
+def dsa_prefill_at_size() -> None:
+    """The 12,288-token prefill's two attentions at the published shapes,
+    timed: a full layer's under the indexer's mask
+    (``ops/dsa.dsa_prefill_attention``: 128 heads of 192 / 128, 64 index
+    heads of 128, the 2,048 best a query) through the tiled kernel
+    ``dsa_prefill`` and through the XLA walk every other call takes, one
+    against the other and the kernel's last 128 queries against a float32
+    softmax over ``lax.top_k``'s set; and a sliding layer's banded walk (64
+    heads of 256 / 128, window 513)."""
+    import jax
+    import jax.numpy as jnp
+
+    from distributed_lion_tpu.ops import dsa, pallas_dsa
+    from distributed_lion_tpu.ops.attention import banded_causal_attention
+
+    S, H, dk, dv, Hi, di, topk = 12288, 128, 192, 128, 64, 128, 2048
+    keys = jax.random.split(jax.random.key(4648), 6)
+    bf = jnp.bfloat16
+    q = (0.5 * jax.random.normal(keys[0], (1, H, S, dk))).astype(bf)
+    k = jax.random.normal(keys[1], (1, H, S, dk), bf)
+    v = jax.random.normal(keys[2], (1, H, S, dv), bf)
+    qi = (1.4 * jax.random.normal(keys[3], (1, S, Hi, di))).astype(bf)
+    wi = jax.random.normal(keys[4], (1, S, Hi)) / math.sqrt(Hi * di)
+    ki = jax.random.normal(keys[5], (1, S, di), bf)
+    scale = 1.0 / math.sqrt(dk)
+    lengths = jnp.full((1,), S, jnp.int32)
+
+    def attend(q, k, v, qi, wi, ki):
+        return dsa.dsa_prefill_attention(q, k, v, qi, wi, ki, lengths,
+                                         topk=topk, scale=scale)
+
+    check(pallas_dsa.prefill_takes(S, dv), "the kernel refuses the shape")
+    (out, counts), full_ms = clock(jax.jit(attend), q, k, v, qi, wi, ki)
+    takes, pallas_dsa.prefill_takes = pallas_dsa.prefill_takes, \
+        lambda *a: False
+    try:       # the walk every other call takes, at the same shape (a
+        # function of its own: jit would hand back the kernel's program)
+        (walk, _), walk_ms = clock(jax.jit(lambda *a: attend(*a)),
+                                   q, k, v, qi, wi, ki)
+    finally:
+        pallas_dsa.prefill_takes = takes
+    apart = float(jnp.abs(out.astype(jnp.float32) - walk.astype(jnp.float32)
+                          ).max() / jnp.abs(walk.astype(jnp.float32)).max())
+    del walk
+    tail = 128
+
+    def reference(q, k, v, qi, wi, ki):
+        sc = dsa.index_scores(qi[:, -tail:], wi[:, -tail:], ki)[0]
+        seen = jnp.arange(S)[None, :] <= (S - tail + jnp.arange(tail))[:, None]
+        idx = jax.lax.top_k(jnp.where(seen, sc, -jnp.inf), topk)[1]
+        keep = jnp.zeros((tail, S), bool).at[
+            jnp.arange(tail)[:, None], idx].set(True)
+        s = jnp.einsum("hsd,htd->hst", q[0, :, -tail:].astype(jnp.float32),
+                       k[0].astype(jnp.float32), precision="highest") * scale
+        p = jax.nn.softmax(jnp.where(keep[None], s, -jnp.inf), -1)
+        return jnp.einsum("hst,htd->hsd", p, v[0].astype(jnp.float32),
+                          precision="highest")
+
+    ref = jax.jit(reference)(q, k, v, qi, wi, ki)
+    err = float(jnp.abs(out[0, :, -tail:].astype(jnp.float32) - ref).max()
+                / jnp.abs(ref).max())
+    kept = int(counts["dsa_keys_kept"])
+    want = sum(min(t + 1, topk) for t in range(S))
+    log(f"  full layer's prefill at {S} positions, {H} heads of {dk} / {dv} "
+        f"under the indexer's mask: {full_ms:.1f} ms a layer through "
+        f"dsa_prefill, {walk_ms:.1f} ms through the XLA walk (worst |diff| / "
+        f"max between them {apart:.5f}); kept {kept} of "
+        f"{int(counts['dsa_keys_visible'])} visible; last {tail} queries "
+        f"against a float32 softmax over lax.top_k's set: worst |diff| / "
+        f"max {err:.5f} (tol 0.03)")
+    check(kept == want, f"the prefill kept {kept} keys, not {want}")
+    check(err <= 0.03, f"dsa prefill off by {err}")
+    check(apart <= 0.03, f"kernel and walk apart by {apart}")
+    del out, ref, q, k, v
+    H2, dk2 = 64, 256
+    q2 = (0.5 * jax.random.normal(keys[0], (1, H2, S, dk2))).astype(bf)
+    k2 = jax.random.normal(keys[1], (1, H2, S, dk2), bf)
+    v2 = jax.random.normal(keys[2], (1, H2, S, dv), bf)
+    banded = jax.jit(lambda q, k, v: banded_causal_attention(
+        q, k, v, window=513))
+    _, band_ms = clock(banded, q2, k2, v2)
+    log(f"  sliding layer's prefill at {S} positions, {H2} heads of {dk2} / "
+        f"{dv}, window 513, banded: {band_ms:.1f} ms a layer")
+
+
 def phase_serve_mhc() -> None:
     """The hyper-connection mix at the published shape (4 streams of 3,584,
     bfloat16): the kernels ``mhc_pre`` / ``mhc_post`` against ``ops/mhc``'s
@@ -2121,7 +2504,7 @@ def main() -> int:
     ap.add_argument("--only", default="",
                     help="run this one phase (kernels, train, train_moe, serve, "
                          "serve_latent, serve_window, serve_state, "
-                         "serve_sparse, serve_mhc, multichip) and no "
+                         "serve_sparse, serve_mhc, serve_dsa, multichip) and no "
                          "other")
     args = ap.parse_args()
 
@@ -2156,7 +2539,8 @@ def main() -> int:
                ("serve_window", phase_serve_window),
                ("serve_state", phase_serve_state),
                ("serve_sparse", phase_serve_sparse),
-               ("serve_mhc", phase_serve_mhc)]
+               ("serve_mhc", phase_serve_mhc),
+               ("serve_dsa", phase_serve_dsa)]
               if args.chips == 1 else
               [("multichip", lambda: phase_multichip(work))])
     if args.only:

@@ -255,12 +255,8 @@ def pool_leaf_copies(hlo_text: str, leaf) -> List[str]:
 
 
 def _prefill_buckets(scfg) -> List[int]:
-    from distributed_lion_tpu.serve.kv_cache import bucket_tokens
-
     cap = scfg.block_size * scfg.max_blocks_per_seq
-    return sorted({bucket_tokens(n, scfg.block_size,
-                                 scfg.max_blocks_per_seq)
-                   for n in range(1, cap + 1)})
+    return sorted({scfg.bucket(n) for n in range(1, cap + 1)})
 
 
 # ------------------------------------------------------------ the checks

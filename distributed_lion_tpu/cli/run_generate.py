@@ -23,7 +23,7 @@ class GenerateArguments:
     model_path: Optional[str] = None  # .npz from utils.serialization, or an
     # HF save_pretrained directory (hf_export/--merged_output output, family
     # auto-detected); unset → random init (smoke mode)
-    model_family: str = "gpt2"  # gpt2 | llama | joyai, laguna, ling, minicpm_sala, xing (run_serve only)
+    model_family: str = "gpt2"  # gpt2 | llama | dots3, joyai, laguna, ling, minicpm_sala, xing (run_serve only)
     model_name: str = "tiny"    # gpt2: gpt2_124m | tiny; llama: llama2_7b | llama3_8b | tiny;
     # joyai, laguna, ling: tiny | the path of a JSON file with the published
     # config.json keys
@@ -101,7 +101,8 @@ def check_checkpoint(args: GenerateArguments):
 
 # families with no dense-cache decode (module and ``<family>_init`` by the
 # family's name): their configuration class
-PAGED_ONLY = {"joyai": "JoyAIConfig", "laguna": "LagunaConfig",
+PAGED_ONLY = {"dots3": "Dots3Config", "joyai": "JoyAIConfig",
+              "laguna": "LagunaConfig",
               "ling": "LingConfig", "minicpm_sala": "MiniCPMSalaConfig",
               "xing": "XingConfig"}
 

@@ -55,6 +55,8 @@ class ServeArguments:
     max_blocks_per_seq: int = 8
     num_blocks: int = 0              # 0 = auto (max_seqs * max_blocks_per_seq)
     prefill_cap_tokens: int = 512
+    prefill_top_bucket: int = 0      # the largest prefill bucket, where the
+    # longest prompt lies between two powers of two (ServeConfig's)
     quant: str = "none"              # none | nf4 | int8 (ops/quant)
     quant_block: Optional[int] = None  # quant block override; shrink so
     # every --serve_tp-sharded last dim splits (ops/quant.validate_quant_tp
@@ -193,6 +195,7 @@ def build_engine_factory(gen_args, serve_args: "ServeArguments"):
     def as_serve_model(p, c):
         return {"gpt2": ServeModel.for_gpt2, "llama": ServeModel.for_llama,
                 "joyai": ServeModel.for_joyai,
+                "dots3": ServeModel.for_dots3,
                 "laguna": ServeModel.for_laguna,
                 "ling": ServeModel.for_ling,
                 "minicpm_sala": ServeModel.for_minicpm_sala,
@@ -229,6 +232,7 @@ def build_engine_factory(gen_args, serve_args: "ServeArguments"):
         max_blocks_per_seq=serve_args.max_blocks_per_seq,
         num_blocks=serve_args.num_blocks,
         prefill_cap_tokens=serve_args.prefill_cap_tokens,
+        prefill_top_bucket=serve_args.prefill_top_bucket,
         max_new_tokens=gen_args.max_new_tokens,
         temperature=gen_args.temperature, top_k=gen_args.top_k,
         top_p=gen_args.top_p, quant=serve_args.quant,

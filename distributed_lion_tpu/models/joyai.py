@@ -28,6 +28,15 @@ Per layer, ``h = x + Attn(RMSNorm(x))``, ``y = h + FFN(RMSNorm(h))``:
   a chunk of queries at a time. A prefill whose rows all start at 0 (no
   shared prefix) gathers only the pages its own tokens fill.
 
+``_mla_block`` also serves a family whose layers differ in geometry and in
+WHERE THEIR KEYS COME FROM (``models/dots3``): it takes the layer kind's own
+view of the configuration, a ``rescale`` of the two latents (1 here) and a
+``keys`` function that writes the new rows where its cache keeps them and
+attends its own key set: the last ``window`` positions out of a ring of
+latent rows a slot, or the set a learned indexer kept, whose keys lie in a
+page leaf of their own (``ik``) beside the latent rows. ``None``, every
+family but that one, is the two paths above.
+
 Multi-token prediction (``num_nextn_predict_layers``) is not instantiated:
 the main model's logits do not depend on it, and the public inference code
 drops it unless a speculative decoder asks (ROADMAP Reach, M6). Training
@@ -192,8 +201,40 @@ def joyai_init(key: jax.Array, cfg: JoyAIConfig) -> dict:
     return params
 
 
+def absorb_query(q_nope, q_rope, w_kvb, width: int):
+    """One query a row in the latent row's own layout: ``[q_nope W_kvb^K[h]
+    | q_rope | 0]`` ``[B, H, width]`` (q_nope, q_rope ``[B, H, 1, .]``;
+    ``w_kvb [r, H, dn + dv]``), so that every head attends the cached row
+    itself."""
+    dn = q_nope.shape[-1]
+    q_lat = jnp.einsum("bhn,rhn->bhr", q_nope[:, :, 0], w_kvb[..., :dn],
+                       preferred_element_type=jnp.float32)
+    q_abs = jnp.concatenate([q_lat.astype(q_nope.dtype), q_rope[:, :, 0]], -1)
+    return jnp.pad(q_abs, ((0, 0), (0, 0), (0, width - q_abs.shape[-1])))
+
+
+def expand_output(o_lat, w_kvb, dn: int):
+    """The absorbed kernel's ``sum p * row`` ``[B, H, width]`` through the
+    value up-projection: ``[B, H, dv]`` in its dtype."""
+    out = jnp.einsum("bhr,rhv->bhv", o_lat[..., :w_kvb.shape[0]],
+                     w_kvb[..., dn:], preferred_element_type=jnp.float32)
+    return out.astype(o_lat.dtype)
+
+
+def expand_rows(rows, w_kvb, dn: int):
+    """Latent rows ``[B, T, r + dr]`` as every head's keys ``[B, H, T, dn +
+    dr]`` (``[k_nope | the one k_rope]``) and values ``[B, H, T, dv]``."""
+    r = w_kvb.shape[0]
+    kvx = jnp.einsum("btr,rhm->bhtm", rows[..., :r], w_kvb,
+                     preferred_element_type=jnp.float32
+                     ).astype(rows.dtype)                     # [B, H, T, .]
+    k_r = jnp.broadcast_to(rows[:, None, :, r:],
+                           kvx.shape[:3] + (rows.shape[-1] - r,))
+    return jnp.concatenate([kvx[..., :dn], k_r], -1), kvx[..., dn:]
+
+
 def _mla_block(x, p, cfg: JoyAIConfig, c, tables, pos, cos, sin, valid,
-               scale=None):
+               scale=None, rescale=(1.0, 1.0), keys=None):
     """Latent attention over the paged cache: scatter the new tokens'
     latent rows, then attend (the module note says which path). Returns
     (output ``[B, S, d]``, the layer's updated page leaf). ``cfg`` is read
@@ -203,7 +244,20 @@ def _mla_block(x, p, cfg: JoyAIConfig, c, tables, pos, cos, sin, valid,
     sqrt(nope + rope)``; a YaRN family's carries its ``mscale``). Two leaves of
     ``p`` are optional: ``wq`` in place of ``wq_a`` / ``q_norm`` / ``wq_b``
     (a query with no low-rank step), and ``wg``, a per-head output gate
-    before ``wo`` (``models/laguna.head_gate``)."""
+    before ``wo`` (``models/laguna.head_gate``).
+
+    ``rescale = (s_q, s_kv)``: factors on the query latent and on the
+    key-value latent after their norms (the cached row holds the scaled
+    ``c_kv``); 1 for every family but ``models/dots3``.
+
+    ``keys``: WHERE THE KEYS COME FROM. None: every position up to the
+    query's, from the pages under ``tables`` (the two paths of the module
+    note). Else a function ``keys(q_nope, q_rope, row, c_q, w_kvb, scale)
+    -> (out [B, S, H * dv], the layer's updated leaves)`` that writes the
+    new rows ``row [B, S, 1, r + dr]`` where its cache keeps them and
+    attends its own key set: the last ``window`` positions out of a ring a
+    slot, or the set a learned indexer kept (``models/dots3``'s two; ``c_q``
+    is the scaled query latent an indexer reads)."""
     from distributed_lion_tpu.ops.attention import (
         chunked_causal_attention,
         mla_decode_attention,
@@ -217,11 +271,15 @@ def _mla_block(x, p, cfg: JoyAIConfig, c, tables, pos, cos, sin, valid,
     dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
     if scale is None:
         scale = 1.0 / math.sqrt(dn + dr)
+    s_q, s_kv = rescale
+    c_q = None
     with jax.named_scope("mla/q"):
         if "wq" in p:
             q = _matmul(x, p["wq"])
         else:
             c_q = _rms_norm(_matmul(x, p["wq_a"]), p["q_norm"], cfg.rms_eps)
+            if s_q != 1.0:
+                c_q = (c_q * s_q).astype(x.dtype)
             q = _matmul(c_q, p["wq_b"])
         q = q.reshape(B, S, H, dn + dr)
         q = q.transpose(0, 2, 1, 3)                       # [B, H, S, dn+dr]
@@ -229,35 +287,27 @@ def _mla_block(x, p, cfg: JoyAIConfig, c, tables, pos, cos, sin, valid,
     with jax.named_scope("mla/kv_latent"):
         kv = _matmul(x, p["wkv_a"])                       # [B, S, r + dr]
         c_kv = _rms_norm(kv[..., :r], p["kv_norm"], cfg.rms_eps)
+        if s_kv != 1.0:
+            c_kv = (c_kv * s_kv).astype(x.dtype)
         k_rope = apply_rope(kv[:, None, :, r:], cos, sin)[:, 0]
         row = jnp.concatenate([c_kv, k_rope], -1)[:, :, None, :]
-        pool = paged_scatter_kv(c["kv"], tables, pos,
-                                row.astype(c["kv"].dtype), valid)
+        if keys is None:
+            pool = paged_scatter_kv(c["kv"], tables, pos,
+                                    row.astype(c["kv"].dtype), valid)
     w_kvb = p["wkv_b"].reshape(r, H, dn + dv)             # heads of [k | v]
-    if paged_kernel_applies(S, pool.shape, pool.dtype):
+    if keys is not None:
+        out, leaves = keys(q_nope, q_rope, row, c_q, w_kvb, scale)
+    elif paged_kernel_applies(S, pool.shape, pool.dtype):
         # absorbed: every head's query against the latent row itself
-        width = pool.shape[-1]
-        q_lat = jnp.einsum("bhn,rhn->bhr", q_nope[:, :, 0], w_kvb[..., :dn],
-                           preferred_element_type=jnp.float32)
-        q_abs = jnp.concatenate([q_lat.astype(x.dtype), q_rope[:, :, 0]], -1)
-        q_abs = jnp.pad(q_abs, ((0, 0), (0, 0), (0, width - r - dr)))
-        o_lat = mla_decode_attention(q_abs, pool, tables, pos,
-                                     scale=scale)[..., :r]
-        out = jnp.einsum("bhr,rhv->bhv", o_lat, w_kvb[..., dn:],
-                         preferred_element_type=jnp.float32)
-        out = out.astype(x.dtype).reshape(B, 1, H * dv)
+        q_abs = absorb_query(q_nope, q_rope, w_kvb, pool.shape[-1])
+        o_lat = mla_decode_attention(q_abs, pool, tables, pos, scale=scale)
+        out = expand_output(o_lat, w_kvb, dn).reshape(B, 1, H * dv)
     else:
         def attend(tab):
             rows = paged_gather_kv(pool, tab)[:, :, 0, :r + dr]  # [B, T, .]
-            kvx = jnp.einsum("btr,rhm->bhtm", rows[..., :r], w_kvb,
-                             preferred_element_type=jnp.float32
-                             ).astype(x.dtype)                # [B, H, T, .]
-            k_r = jnp.broadcast_to(rows[:, None, :, r:],
-                                   kvx.shape[:3] + (dr,))
-            k = jnp.concatenate([kvx[..., :dn], k_r], -1)
+            k, v = expand_rows(rows, w_kvb, dn)
             q_full = jnp.concatenate([q_nope, q_rope], -1)
-            return chunked_causal_attention(q_full, k, kvx[..., dn:], pos,
-                                            scale=scale)
+            return chunked_causal_attention(q_full, k, v, pos, scale=scale)
 
         bs, own = pool.shape[1], S // pool.shape[1]
         if S > 1 and S % bs == 0 and own < tables.shape[1]:
@@ -269,12 +319,14 @@ def _mla_block(x, p, cfg: JoyAIConfig, c, tables, pos, cos, sin, valid,
         else:
             out = attend(tables)
         out = out.transpose(0, 2, 1, 3).reshape(B, S, H * dv)
+    if keys is None:
+        leaves = {"kv": pool}
     if "wg" in p:
         from distributed_lion_tpu.models.laguna import gate_heads, head_gate
 
         out = gate_heads(out.reshape(B, S, H, dv), head_gate(x, p["wg"]),
                          x.dtype)
-    return _matmul(out, p["wo"]), {"kv": pool}
+    return _matmul(out, p["wo"]), leaves
 
 
 def joyai_decode_paged(params: dict, tokens: jnp.ndarray, cfg: JoyAIConfig,

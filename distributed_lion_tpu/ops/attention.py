@@ -197,6 +197,14 @@ def paged_gather_kv(pages: jnp.ndarray, tables: jnp.ndarray) -> jnp.ndarray:
     return got.reshape((B, nb * bs) + pages.shape[2:])
 
 
+def walk_lengths(tables, pos, num_blocks: int, block_size: int):
+    """Positions row b's decode walk covers: ``pos + 1``, bounded by the
+    row's count of real table entries (an inactive slot's table is all
+    sentinel: 0)."""
+    return jnp.minimum(pos + 1,
+                       jnp.sum(tables < num_blocks, axis=1) * block_size)
+
+
 def paged_kernel_applies(n_new, pool_shape, pool_dtype, start=None) -> bool:
     """True when :func:`paged_decode_attention` takes the Mosaic kernel
     for a call with ``n_new`` query tokens a row over such a pool (the rule
@@ -249,8 +257,8 @@ def paged_decode_attention(q, k_pages, v_pages, tables, pos,
     if paged_kernel_applies(S, k_pages.shape, k_pages.dtype, start):
         from distributed_lion_tpu.ops.pallas_paged_attn import paged_attn
 
-        lengths = jnp.minimum(pos + 1, jnp.sum(tables < NB, axis=1) * bs)
-        return paged_attn(q[:, :, 0], k_pages, v_pages, tables, lengths,
+        return paged_attn(q[:, :, 0], k_pages, v_pages, tables,
+                          walk_lengths(tables, pos, NB, bs),
                           kv_heads=KV)[:, :, None]
 
     def history(pages):  # [B, T, G, W] -> [B, KV, T, hd], pad lanes dropped
@@ -393,6 +401,24 @@ def ring_scatter_kv(pages, slots, pos, new, lengths, *, window: int):
                             keep.reshape(-1, 1))
 
 
+def ring_walk(slots, pos, block_size: int, *, window: int, active=None):
+    """A window layer's decode walk over slot ``slots[b]``'s ring, row b at
+    position ``pos[b]`` (already scattered): (``walk [B, R]`` the ring's page
+    ids in logical order from the window's first page on, ``length [B]`` the
+    positions of the walk up to the query's own, ``start [B]`` the leading
+    rows of the first page that lie before the window, ``read [B]`` the
+    pages of the walk either path is handed). ``active`` (optional [B]
+    bool): a lane with no sequence walks nothing."""
+    R = ring_pages(window, block_size)
+    length = pos + 1 if active is None else jnp.where(active, pos + 1, 0)
+    first = jnp.maximum(length - window, 0)
+    page0 = first // block_size
+    walk = slots[:, None] * R + (page0[:, None] + jnp.arange(R)[None, :]) % R
+    rel_len = length - page0 * block_size
+    read = ((rel_len + block_size - 1) // block_size).astype(jnp.int32)
+    return walk, rel_len, first - page0 * block_size, read
+
+
 @jax.named_scope("paged_attn")
 def ring_decode_attention(q, k_pages, v_pages, slots, pos, *, window: int,
                           active=None, kv_heads=None):
@@ -402,18 +428,13 @@ def ring_decode_attention(q, k_pages, v_pages, slots, pos, *, window: int,
     slots [B] the slot each row owns; ``active`` (optional [B] bool): a
     lane with no sequence reads nothing. The Mosaic kernel where
     :func:`paged_kernel_applies`, else the gather path, both over the
-    ring's pages in logical order from the window's first page on. Returns
+    ring's pages in logical order from the window's first page on
+    (:func:`ring_walk`). Returns
     (out [B, H, 1, hd], pages [B] int32: the pages of the walk that either
     path is handed, ``ceil(length / block_size)`` of the walk's own
     length, which is what the kernel reads)."""
-    bs = k_pages.shape[1]
-    R = ring_pages(window, bs)
-    length = pos + 1 if active is None else jnp.where(active, pos + 1, 0)
-    first = jnp.maximum(length - window, 0)
-    page0 = first // bs
-    walk = slots[:, None] * R + (page0[:, None] + jnp.arange(R)[None, :]) % R
-    rel_len, rel_start = length - page0 * bs, first - page0 * bs
-    read = ((rel_len + bs - 1) // bs).astype(jnp.int32)
+    walk, rel_len, rel_start, read = ring_walk(
+        slots, pos, k_pages.shape[1], window=window, active=active)
     KV = kv_heads or k_pages.shape[2] * (k_pages.shape[3] // q.shape[-1])
     if paged_kernel_applies(q.shape[2], k_pages.shape, k_pages.dtype):
         from distributed_lion_tpu.ops.pallas_paged_attn import paged_attn
@@ -433,9 +454,10 @@ def banded_causal_attention(q, k, v, *, window=None):
     prefill from the start of a sequence), grouped queries, with no
     ``[H, S, S]`` float32 scores held (72 heads x 8,192 x 8,192 would be
     19 GB). q [B, H, S, hd]; k, v [B, KV, S, hd], head h reading kv head
-    ``h // (H // KV)`` with no repeat. ``window``: query i sees keys
+    ``h // (H // KV)`` with no repeat (v's heads may be narrower than
+    k's: latent attention's are). ``window``: query i sees keys
     ``i - window + 1 .. i`` only; None: every earlier key. Returns
-    [B, H, S, hd] in q's dtype; float32 scores and softmax, the arithmetic
+    [B, H, S, v's width] in q's dtype; float32 scores and softmax, the arithmetic
     of :func:`attention_xla`.
 
     Two paths, chosen from what the call shows. Without a band, on a TPU,
@@ -506,7 +528,7 @@ def banded_causal_attention(q, k, v, *, window=None):
         out = jax.lax.map(one, (jnp.moveaxis(qg, 3, 0),
                                 jnp.arange(n) * chunk))
         out = jnp.moveaxis(out, 0, 3)                  # [B,KV,rep,n,c,hd]
-    return out.reshape(B, H, S, hd)
+    return out.reshape(B, H, S, v.shape[-1])   # values may be narrower
 
 
 # ---------------------------------------------------------- latent (MLA)
@@ -518,6 +540,27 @@ def banded_causal_attention(q, k, v, *, window=None):
 # :func:`mla_decode_attention`, one page read for scores and values; every
 # other call (S > 1, the CPU) gathers the rows, expands keys and values and
 # runs :func:`chunked_causal_attention`, which takes keys wider than values.
+
+
+@jax.named_scope("window_mla")
+def ring_mla_decode_attention(q_abs, kv_pages, slots, pos, *, window: int,
+                              scale: float, active=None):
+    """:func:`mla_decode_attention` over a RING of latent rows: a window
+    layer of latent attention keeps ``[c_kv | k_rope]`` rows in
+    :func:`ring_pages` pages a slot (the ring note above: the leaf is a pool
+    leaf like any other, written by :func:`ring_scatter_kv`), and the decode
+    tick hands the kernel the ring in logical order with the rows before
+    the window as ``starts`` (name ``window_mla_attn``; a caller asks
+    :func:`paged_kernel_applies` first, and attends the gathered walk
+    itself elsewhere). q_abs [B, H, W]. Returns (``[B, H, W]``, the pages
+    of the walk ``[B]`` int32)."""
+    from distributed_lion_tpu.ops.pallas_mla_attn import mla_paged_attn
+
+    walk, rel_len, rel_start, read = ring_walk(
+        slots, pos, kv_pages.shape[1], window=window, active=active)
+    out = mla_paged_attn(q_abs, kv_pages, walk, rel_len, starts=rel_start,
+                         scale=scale, name="window_mla_attn")
+    return out, read
 
 
 @jax.named_scope("mla_attn")
@@ -532,8 +575,8 @@ def mla_decode_attention(q_abs, kv_pages, tables, pos, *, scale: float):
     from distributed_lion_tpu.ops.pallas_mla_attn import mla_paged_attn
 
     NB, bs = kv_pages.shape[:2]
-    lengths = jnp.minimum(pos + 1, jnp.sum(tables < NB, axis=1) * bs)
-    return mla_paged_attn(q_abs, kv_pages, tables, lengths, scale=scale)
+    return mla_paged_attn(q_abs, kv_pages, tables,
+                          walk_lengths(tables, pos, NB, bs), scale=scale)
 
 
 def query_chunk(B: int, H: int, S: int, T: int) -> int:

@@ -17,7 +17,21 @@ softmax in float32, probabilities cast to the cache dtype before the value
 matmul. The output is ``sum p * row`` over all W lanes (one matmul, no
 lane slicing in the kernel); the caller keeps the value lanes.
 
-Name on the device: ``mla_paged_attn``.
+Two optional operands, each a walk of its own under its own name on the
+device; without them the program is the one it was before they existed:
+
+- ``keep`` ``[B, T]`` bool: the positions row b attends among those its walk
+  covers (a learned indexer's choice: ``ops/dsa.kept_positions``); the walk
+  reads every page and masks. Name ``dsa_attn``.
+- ``starts`` ``[B]``: the leading rows of the walk's first page that lie
+  before a window (a ring of latent rows handed in logical order:
+  ``ops/attention.ring_mla_decode_attention``), masked as
+  ``ops/pallas_paged_attn`` masks them. Name ``window_mla_attn``.
+
+Under either a masked probability is written as 0 outright, so a block with
+no position to attend leaves sum and accumulator as they were.
+
+Name on the device: ``mla_paged_attn`` (``name=`` says otherwise).
 """
 
 from __future__ import annotations
@@ -36,9 +50,14 @@ from distributed_lion_tpu.ops.pallas_paged_attn import (
 )
 
 
-def _kernel(lens_ref, tables_ref, q_ref, kv_hbm, o_ref,
-            kv_buf, sems, acc_ref, ahead_ref, *, scale: float,
-            table_width: int):
+def _kernel(lens_ref, tables_ref, *refs, scale: float, table_width: int,
+            windowed: bool, kept: bool):
+    if windowed:
+        starts_ref, *refs = refs
+    q_ref, *refs = refs
+    if kept:
+        keep_ref, *refs = refs
+    kv_hbm, o_ref, kv_buf, sems, acc_ref, ahead_ref = refs
     b = pl.program_id(0)
     last_row = pl.num_programs(0) - 1
     n_slots, pages, bs, width = kv_buf.shape
@@ -103,10 +122,19 @@ def _kernel(lens_ref, tables_ref, q_ref, kv_hbm, o_ref,
         s = jax.lax.dot_general(q_ref[...], kv, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
         t_idx = blk * tokens + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(t_idx < length, s, MASKED)
+        seen = t_idx < length
+        if windowed:   # rows of the walk's first page before the window
+            seen = jnp.logical_and(seen, t_idx >= starts_ref[b])
+        if kept:       # the positions the row's indexer kept
+            seen = jnp.logical_and(
+                seen, keep_ref[:, pl.ds(pl.multiple_of(blk * tokens, tokens),
+                                        tokens)] > 0)
+        s = jnp.where(seen, s, MASKED)
         m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
         p = jnp.exp(s - m_new)
+        if windowed or kept:   # a block may hold no position to attend
+            p = jnp.where(seen, p, 0.0)
         acc_ref[...] = alpha * acc_ref[...] + jnp.dot(
             p.astype(kv.dtype), kv, preferred_element_type=jnp.float32)
         return m_new, alpha * l_prev + p.sum(axis=1, keepdims=True)
@@ -119,28 +147,48 @@ def _kernel(lens_ref, tables_ref, q_ref, kv_hbm, o_ref,
     o_ref[...] = (acc_ref[...] / jnp.where(l > 0, l, 1.0)).astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
+@functools.partial(jax.jit, static_argnames=("scale", "interpret", "name"))
 def mla_paged_attn(q, kv_pages, tables, lengths, *, scale: float,
-                   interpret: bool = False):
+                   keep=None, starts=None, interpret: bool = False,
+                   name: str = "mla_paged_attn"):
     """q [B, H, W] — the absorbed queries, laid out like a latent row
     (``[q_nope W_k | q_rope | 0]``); kv_pages ``[num_blocks, block_size, 1,
     W]`` (``pallas_paged_attn.kernel_takes`` says which pools); tables
     [B, nb] int32; lengths [B] int32 — tokens row b attends (0 = read
-    nothing, return zeros). Returns ``softmax(scale * q . row) @ row``
-    [B, H, W] in q's dtype: the caller keeps the value lanes."""
+    nothing, return zeros). ``keep`` (optional [B, T] bool, T at least the
+    positions the longest walk covers) and ``starts`` (optional [B] int32,
+    each below ``block_size``): the module note. Returns ``softmax(scale * q
+    . row) @ row`` [B, H, W] in q's dtype: the caller keeps the value
+    lanes."""
     B, H, W = q.shape
     NB, bs = kv_pages.shape[:2]
     nb = tables.shape[1]
     q = jnp.pad(q, ((0, 0), (0, -H % Q_ROWS), (0, 0)))
     rows = q.shape[1]
     row_spec = pl.BlockSpec((None, rows, W), lambda b, *_: (b, 0, 0))
-    with jax.named_scope("mla_paged_attn"):
+    scalars = (lengths.astype(jnp.int32),
+               tables.reshape(-1).astype(jnp.int32))
+    if starts is not None:
+        scalars += (starts.astype(jnp.int32),)
+    operands, in_specs = [q], [row_spec]
+    if keep is not None:
+        # whole blocks of the walk: a block's slice never leaves the operand
+        tokens = PAGES_PER_BLOCK * bs
+        width = -(-max(nb * bs, keep.shape[1]) // tokens) * tokens
+        keep = jnp.pad(keep.astype(jnp.int32),
+                       ((0, 0), (0, width - keep.shape[1])))[:, None]
+        operands.append(keep)
+        in_specs.append(pl.BlockSpec((None, 1, width),
+                                     lambda b, *_: (b, 0, 0)))
+    with jax.named_scope(name):
         out = pl.pallas_call(
-            functools.partial(_kernel, scale=scale, table_width=nb),
+            functools.partial(_kernel, scale=scale, table_width=nb,
+                              windowed=starts is not None,
+                              kept=keep is not None),
             grid_spec=pltpu.PrefetchScalarGridSpec(
-                num_scalar_prefetch=2,
+                num_scalar_prefetch=len(scalars),
                 grid=(B,),
-                in_specs=[row_spec, pl.BlockSpec(memory_space=pl.ANY)],
+                in_specs=in_specs + [pl.BlockSpec(memory_space=pl.ANY)],
                 out_specs=row_spec,
                 scratch_shapes=[
                     pltpu.VMEM((2, PAGES_PER_BLOCK, bs, W), kv_pages.dtype),
@@ -154,7 +202,6 @@ def mla_paged_attn(q, kv_pages, tables, lengths, *, scale: float,
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("arbitrary",)),
             interpret=interpret,
-            name="mla_paged_attn",
-        )(lengths.astype(jnp.int32), tables.reshape(-1).astype(jnp.int32),
-          q, kv_pages.reshape(NB, bs, W))
+            name=name,
+        )(*scalars, *operands, kv_pages.reshape(NB, bs, W))
     return out[:, :H]

@@ -223,6 +223,10 @@ class ServeConfig:
     #                              single over-cap prompt still admits
     #                              when the tick has admitted nothing —
     #                              caps must not livelock)
+    prefill_top_bucket: int = 0  # the largest prefill bucket where the
+    #                              longest prompt served lies between two
+    #                              powers of two (whole pages; 0 = none:
+    #                              serve/kv_cache.bucket_tokens ``top``)
     max_new_tokens: int = 64     # per-request default budget
     temperature: float = 0.0     # 0 = greedy; sampling knobs are engine-
     top_k: Optional[int] = None  # static (one compiled tick), seeds are
@@ -321,6 +325,13 @@ class ServeConfig:
 
     def resolved_num_blocks(self) -> int:
         return self.num_blocks or self.max_seqs * self.max_blocks_per_seq
+
+    def bucket(self, n: int) -> int:
+        """The padded length of an ``n``-token prefill: THE bucketing rule
+        of this configuration (the engine's prefill, the draft mirror's and
+        ``analysis/serve_check``'s compile budget all ask here)."""
+        return bucket_tokens(n, self.block_size, self.max_blocks_per_seq,
+                             self.prefill_top_bucket)
 
 
 @dataclasses.dataclass
@@ -524,7 +535,9 @@ class ServeModel:
                  window_layers: tuple = (), state_layers: tuple = (),
                  state_leaves: Optional[Dict[str, tuple]] = None,
                  setup_note: str = "", fresh_prefill: bool = False,
-                 page_run: int = 0):
+                 page_run: int = 0,
+                 window_leaves: Optional[Dict[str, tuple]] = None,
+                 index_leaves: tuple = ()):
         self.family = family
         self.cfg = cfg
         self.params = params
@@ -565,6 +578,12 @@ class ServeModel:
         # is all a ring's page ids are made of
         self.window = window
         self.window_layers = tuple(window_layers)
+        # what a window layer's ring holds where that differs from
+        # ``page_leaves`` (latent rows of the window layers' own width)
+        self.window_leaves = dict(window_leaves or {})
+        # the page leaves that hold a learned indexer's keys, a row a
+        # position beside the rows they index (ops/dsa)
+        self.index_leaves = tuple(index_leaves)
         # layers that carry a recurrent state a slot instead of a cache by
         # position: ``{leaf: (shape, dtype)}`` a slot
         # (serve/kv_cache.init_state_leaves); the hook takes ``slots`` for
@@ -720,6 +739,45 @@ class ServeModel:
             cfg.head_dim, cfg.compute_dtype, max_positions=cfg.n_ctx,
             moe_counters=LAGUNA_COUNTERS, last_logit=True, shardable=False,
             window=cfg.window, window_layers=cfg.window_layers)
+
+    @staticmethod
+    def for_dots3(params: Any, cfg: Any) -> "ServeModel":
+        """dots3-note (models/dots3): latent attention of two geometries in
+        one pool list. A full layer's latent rows live in pages with an
+        index key a position beside them (leaf ``ik``: the learned
+        indexer's, ``ops/dsa``), minted in aligned runs of the decode
+        walk's copy; a sliding layer's latent rows, of another width, in a
+        bounded ring a slot; dropless experts told which they hold."""
+        from distributed_lion_tpu.models.dots3 import (
+            DOTS3_COUNTERS,
+            dots3_decode_paged,
+        )
+
+        def decode(p, toks, pages, tables, pos, valid=None, tp_axis=None,
+                   ep_axis=None, return_moe_stats=False, stats_axis=None,
+                   stats_lanes=None, logit_index=None, slots=None):
+            # the engine refuses tp / ep for this family at build
+            assert tp_axis is None and ep_axis is None and stats_axis is None
+            return dots3_decode_paged(
+                p, toks, cfg, pages, tables, slots, pos, valid,
+                return_moe_stats, logit_index)
+
+        return ServeModel(
+            "dots3", cfg, params, decode, cfg.n_layer, 1,
+            cfg.full.latent_dim, cfg.compute_dtype, max_positions=cfg.n_ctx,
+            page_leaves={"kv": (1, cfg.full.latent_dim),
+                         "ik": (1, cfg.index_head_dim)},
+            kernel_stat="mla_kernel_ticks", moe_counters=DOTS3_COUNTERS,
+            last_logit=True, shardable=False, window=cfg.window,
+            window_layers=cfg.window_layers,
+            window_leaves={"kv": (1, cfg.swa.latent_dim)},
+            index_leaves=("ik",), page_run=cfg.page_run,
+            setup_note=(
+                f"indexer: top {cfg.index_topk} positions a query, "
+                f"{cfg.index_n_heads} heads of {cfg.index_head_dim}, ik a "
+                f"position in {len(cfg.full_layers)} full layers; latent "
+                f"rings of {cfg.window} in {len(cfg.window_layers)} sliding "
+                "layers x {slots} slots"))
 
     @staticmethod
     def for_ling(params: Any, cfg: Any) -> "ServeModel":
@@ -895,6 +953,10 @@ class ServingEngine:
         params = model.params
         if cfg.quant not in ("none", "nf4", "int8"):
             raise ValueError(f"unknown quant mode {cfg.quant!r}")
+        if cfg.prefill_top_bucket % cfg.block_size:
+            raise ValueError(
+                f"prefill_top_bucket {cfg.prefill_top_bucket} is not whole "
+                f"pages of {cfg.block_size}")
         if cfg.retrace_guard not in ("off", "warn", "error"):
             raise ValueError(
                 f"unknown retrace_guard mode {cfg.retrace_guard!r} "
@@ -907,6 +969,18 @@ class ServingEngine:
         no_exchange = ("its expert layer is told the one range it holds; "
                        "the exchange between ranges is not built")
         for layers, keeps, whys in (
+                (model.index_leaves,
+                 f"keeps an index key a position (leaf "
+                 f"{'/'.join(model.index_leaves)!r}) beside its latent pages "
+                 f"(leaf 'kv') and a ring of {model.window} latent rows a "
+                 "slot for its window layers",
+                 ("a shared page's index keys would be shared with it and "
+                  "nothing copies them on write yet, and a shared prefix's "
+                  "pages say nothing of the window layers' rings",
+                  "a rejected draft cannot be rolled back out of a ring that "
+                  "has overwritten its oldest page",
+                  "neither the index-key leaf nor the ring leaves have a "
+                  "sharding spec", no_exchange)),
                 (model.window_layers,
                  f"keeps a ring of {model.window} positions a slot for its "
                  "window layers",
@@ -1074,8 +1148,11 @@ class ServingEngine:
         self.pages = init_page_leaves(
             model.n_layer, cfg.resolved_num_blocks(), cfg.block_size,
             model.page_leaves, model.cache_dtype, groups=max(cfg.tp, 1),
+            # (a family whose rings hold leaves of their own says which;
+            # every other family's call is the one it was)
             ring=(model.window_layers, cfg.max_seqs * ring_pages(
-                model.window, cfg.block_size) if self._windowed else 0),
+                model.window, cfg.block_size) if self._windowed else 0)
+            + ((model.window_leaves,) if model.window_leaves else ()),
             # state layers: slot-indexed leaves in that layer's place,
             # never counted against num_blocks either
             state=(model.state_layers, cfg.max_seqs, model.state_leaves))
@@ -1436,9 +1513,7 @@ class ServingEngine:
     def _buckets(self) -> set:
         """Every padded length a prefill can have."""
         cap = self.cfg.block_size * self.cfg.max_blocks_per_seq
-        return {bucket_tokens(n, self.cfg.block_size,
-                              self.cfg.max_blocks_per_seq)
-                for n in range(1, cap + 1)}
+        return {self.cfg.bucket(n) for n in range(1, cap + 1)}
 
     def _program_of(self, kind: str) -> str:
         """A dispatch kind's name in the compile ledger: the name of the
@@ -1641,8 +1716,7 @@ class ServingEngine:
         return [list(k) for k in sorted(seen, key=lambda k: (len(k), k))]
 
     def _bucket(self, n: int) -> int:
-        return bucket_tokens(n, self.cfg.block_size,
-                             self.cfg.max_blocks_per_seq)
+        return self.cfg.bucket(n)
 
     def _prefix_for(self, slot: int) -> PrefixCache:
         """The prefix cache serving ``slot``'s pool group (the one cache

@@ -91,15 +91,29 @@ def init_page_leaves(n_layer: int, num_blocks: int, block_size: int,
     (window attention) hold ``blocks`` pages instead, ``max_seqs`` rings of
     ``ops/attention.ring_pages`` each, slot ``s`` owning pages ``s * R .. s
     * R + R - 1`` for good, whatever ``num_blocks`` is: two lifetimes in
-    one pool list. ``state = (layers, max_seqs, leaves)``: those layers carry
+    one pool list; ``ring = (layers, blocks, ring_leaves)`` gives those
+    layers leaves of their own (``models/dots3``: a sliding layer's ring
+    holds latent rows of another width than a full layer's pages, and no
+    index key). ``state = (layers, max_seqs, leaves)``: those layers carry
     a recurrent state and hold :func:`init_state_leaves` instead, a third
     kind in the same list. A leaf described by three numbers, ``(heads,
     width, stride)``, holds one row for every ``stride`` positions of a page
     instead of one a position: the fourth kind, a page's compressed keys
     (``ops/sparse_select``: one row a page where ``block_size`` is the
     stride), which have a page's id and lifetime, so they are allocated,
-    freed and counted with the page and never apart from it. Allocated once
-    at engine start; ticks update it in place (donated)."""
+    freed and counted with the page and never apart from it. The fifth
+    kind needs nothing of this function: a learned indexer's key a position
+    (``ops/dsa``, leaf ``ik`` beside the latent rows ``kv``) is one more
+    ``(heads, width)`` leaf, a row a position under the same tables.
+
+    Which leaves follow the block tables and which a slot: every leaf of a
+    layer outside ``ring`` and ``state`` is paged, ``num_blocks`` pages
+    found through a row's table (keys and values, latent rows, compressed
+    keys, index keys: allocated, freed and counted together, a page id
+    naming the same page in each); a ``ring`` layer's leaves and a ``state``
+    layer's are found from the slot id alone and never counted against
+    ``num_blocks``. Allocated once at engine start; ticks update it in
+    place (donated)."""
     import jax.numpy as jnp
 
     def leaf(blocks, heads, width, stride=1):
@@ -110,11 +124,13 @@ def init_page_leaves(n_layer: int, num_blocks: int, block_size: int,
         return jnp.zeros((blocks, block_size // stride, groups,
                           pool_row_width(heads // groups, width)), dtype)
 
-    ring_layers, ring_blocks = ring
+    ring_layers, ring_blocks, *own = ring
+    ring_leaves = own[0] if own and own[0] else leaves
     state_layers, max_seqs, state_leaves = state
     return [init_state_leaves(max_seqs, state_leaves) if i in state_layers
-            else {name: leaf(ring_blocks if i in ring_layers else num_blocks,
-                             *hw) for name, hw in leaves.items()}
+            else {name: leaf(ring_blocks, *hw)
+                  for name, hw in ring_leaves.items()} if i in ring_layers
+            else {name: leaf(num_blocks, *hw) for name, hw in leaves.items()}
             for i in range(n_layer)]
 
 
@@ -144,10 +160,15 @@ def init_pages(n_layer: int, num_blocks: int, block_size: int,
                             groups)
 
 
-def bucket_tokens(n: int, block_size: int, max_blocks_per_seq: int) -> int:
+def bucket_tokens(n: int, block_size: int, max_blocks_per_seq: int,
+                  top: int = 0) -> int:
     """Padded prefill length for an ``n``-token prompt: power-of-two
     pages, so prompt-length variety costs O(log(max)) compiles, not one
-    per length. The ONE bucketing rule — the serving engine's prefill and
+    per length. ``top`` (``ServeConfig.prefill_top_bucket``; whole pages):
+    a prompt of up to ``top`` tokens pads no further than ``top``, for a
+    deployment whose longest prompt lies between two powers of two (12,288:
+    a 16,384 bucket would compute a third more and hold a third more
+    temporaries); a longer prompt pads as if ``top`` were not given. The ONE bucketing rule — the serving engine's prefill and
     the draft-model mirror's prefill (serve/speculate.py) must pad
     identically or the mirror desyncs. For MoE checkpoints the bucket
     also sizes the no-drop expert dispatch buffer ([E, bucket, D] per MoE
@@ -156,7 +177,8 @@ def bucket_tokens(n: int, block_size: int, max_blocks_per_seq: int) -> int:
     blocks = 1
     while blocks * block_size < n:
         blocks *= 2
-    return min(blocks, max_blocks_per_seq) * block_size
+    padded = min(blocks, max_blocks_per_seq) * block_size
+    return top if n <= top < padded else padded
 
 
 class BlockTables:

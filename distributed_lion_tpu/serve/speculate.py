@@ -291,10 +291,7 @@ class DraftModelDrafter:
     def _bucket(self, n: int) -> int:
         # the engine's exact bucketing rule — the mirror must pad like
         # the target or the two prefills land k/v at different positions
-        from distributed_lion_tpu.serve.kv_cache import bucket_tokens
-
-        return bucket_tokens(n, self.cfg.block_size,
-                             self.cfg.max_blocks_per_seq)
+        return self.cfg.bucket(n)
 
     def _go_dead(self, slot: int) -> None:
         # a dead slot decodes plain until evicted — hand its mirror pages

@@ -45,17 +45,22 @@ SCOPES = ("embed", "attn", "mlp", "head", "xent", "paged_attn",
           "mhc/post", "mhc/read_out", "mhc_pre", "mhc_post",
           "flash_gqa_fwd", "attn/window", "attn/full", "moe",
           "moe_gmm_drhs", "flash_gqa_lse", "flash_gqa_di", "flash_gqa_dq",
-          "flash_gqa_dkv", "qk_rope_fwd", "qk_rope_bwd")
+          "flash_gqa_dkv", "qk_rope_fwd", "qk_rope_bwd", "dsa/index",
+          "dsa/select", "dsa/attn", "window_mla", "dsa_index", "dsa_attn",
+          "window_mla_attn", "dsa_prefill")
 NO_SCOPE = "(no scope)"
 _FIND = [(s, re.compile(r"(?<=[(/])%s(?=[)/])" % re.escape(s)))
          for s in SCOPES]
 
 
 def scope_of(op_name: str) -> str:
-    """The innermost of ``SCOPES`` in a device op's framework name."""
+    """The innermost of ``SCOPES`` in a device op's framework name: the one
+    that ends last, and of two that end together the longer (``dsa/attn``,
+    not the ``attn`` it ends in)."""
     path = "/" + (op_name or "")
-    found = [(m.start(), s) for s, pat in _FIND for m in pat.finditer(path)]
-    return max(found)[1] if found else NO_SCOPE
+    found = [(m.end(), len(s), s) for s, pat in _FIND
+             for m in pat.finditer(path)]
+    return max(found)[2] if found else NO_SCOPE
 
 
 def by_scope(hlo_stats: dict) -> dict:

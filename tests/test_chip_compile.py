@@ -1681,3 +1681,122 @@ def test_mhc_and_latent_serving_programs_at_the_published_shapes(
         assert not re.search(r"f32\[1,%d,131072\]" % s_len, text)
         assert mem.temp_size_in_bytes < 2.5e9, mem.temp_size_in_bytes
         assert '"estimated_cycles":"9223372036854775807"' not in text
+
+
+@pytest.mark.parametrize("kind", ["decode_tick", "prefill_12288"])
+def test_dots3_serving_programs_at_the_published_shapes(
+        one_chip, kind, monkeypatch):
+    """dots3-note as ``serve.dots3-note-prev.backlog-12k`` runs it (the cut
+    configuration file: the leading dense full layer and one period sliding
+    x 3, full; 32 of 256 experts, an eighth of the vocabulary; 64 slots,
+    65,536 pages of latent rows and index keys in two full layers, three
+    rings of 34 pages a slot), donated. The decode tick holds ``dsa_index``
+    and ``dsa_attn`` once a full layer (their page operands the pool leaves
+    seen as 16,384 runs of 64 rows, a bitcast) and ``window_mla_attn`` once a
+    sliding layer, writes pages and rings IN PLACE and selects in XLA. The
+    12,288-token prefill (the top bucket) takes its masks a chunk of queries
+    at a time and runs the tiled kernel ``dsa_prefill`` once a full layer: no
+    ``[128, 12288, 12288]`` buffer outside a fused body, one position's
+    logits, and it fits the chip beside 11.6 GB of weights and cache. Prints
+    both programs' live bytes."""
+    from distributed_lion_tpu.analysis.serve_check import pool_leaf_copies
+    from distributed_lion_tpu.models.dots3 import (
+        DOTS3_COUNTERS, Dots3Config, dots3_decode_paged, dots3_init,
+    )
+    from distributed_lion_tpu.ops.attention import ring_pages
+    from distributed_lion_tpu.serve.engine import ServeModel
+    from distributed_lion_tpu.serve.kv_cache import init_page_leaves
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    cfg = Dots3Config.named(os.path.join(
+        root, "benchmark", "configs", "dots3-note-prev.json"))
+    assert cfg.held == (0, 32) and cfg.windowed == (
+        False, True, True, True, False)
+    block, per_seq, slots, pool = 16, 1024, 64, 65536
+    b, s_len = (slots, 1) if kind == "decode_tick" \
+        else (1, int(kind.split("_")[1]))
+
+    def place(tree):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=one_chip), tree)
+
+    model = ServeModel.for_dots3(None, cfg)
+    ring = slots * ring_pages(cfg.window, block)
+    pages = place(jax.eval_shape(lambda: init_page_leaves(
+        cfg.n_layer, pool, block, model.page_leaves, cfg.compute_dtype,
+        ring=(cfg.window_layers, ring, model.window_leaves))))
+    assert [sorted(p) for p in pages] == [["ik", "kv"]] + [["kv"]] * 3 \
+        + [["ik", "kv"]]
+    assert pages[0]["kv"].shape == (pool, block, 1, 640)
+    assert pages[0]["ik"].shape == (pool, block, 1, 128)
+    assert pages[1]["kv"].shape == (slots * 34, block, 1, 1152)
+    params = place(jax.eval_shape(lambda: dots3_init(jax.random.key(0), cfg)))
+    n_params = sum(x.size for x in jax.tree.leaves(params))
+    assert round(n_params / 1e6) == 4087                       # 8.17 GB
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+    def fn(params, pages, toks, tables, owned, pos):
+        valid = jnp.arange(s_len)[None, :] < jnp.maximum(pos[:, None], 1)
+        logits, pages, st = dots3_decode_paged(
+            params, toks, cfg, pages, tables, owned,
+            pos if kind == "decode_tick" else jnp.zeros_like(pos), valid,
+            True, None if kind == "decode_tick" else pos[0])
+        tail = jnp.stack([st[k] for k in DOTS3_COUNTERS])
+        return (jnp.argmax(logits[:, -1], -1), tail), pages
+
+    t0 = time.monotonic()
+    compiled = jax.jit(fn, donate_argnums=(1,)).lower(
+        params, pages, i32(b, s_len), i32(b, per_seq), i32(b),
+        i32(b)).compile()
+    secs = time.monotonic() - t0
+    text = compiled.as_text()
+    assert "input_output_alias" in text
+    for leaf in (pages[0]["kv"], pages[0]["ik"], pages[1]["kv"]):
+        assert not pool_leaf_copies(text, leaf)
+    held = re.sub(r"(?ms)^%?fused_computation[^\n]*\{\n.*?^\}\n", "", text)
+    assert "fusion(" in held and len(held) < len(text)
+    for m in re.finditer(r"= (\w+)\[([\d,]+)\]", held):
+        size = 1
+        for d in m[2].split(","):
+            size *= int(d)
+        assert size < 16 * 12288 * 12288, m[0]
+    decode = kind == "decode_tick"
+    for name, n in (("dsa_index", 2 * decode), ("dsa_attn", 2 * decode),
+                    ("window_mla_attn", 3 * decode),
+                    ("dsa_prefill", 2 * (not decode)),
+                    ("mla_paged_attn", 0)):
+        calls = re.findall(r"%%%s(?:\.\d+)? = [^\n]*custom-call" % name, text)
+        assert len(calls) == n, (name, len(calls))
+    if decode:
+        # the full layers' walks by runs of four pages: both leaves a bitcast
+        for name, lanes in (("dsa_index", 128), ("dsa_attn", 640)):
+            for call in re.findall(
+                    r"%%%s(?:\.\d+)? = [^\n]*custom-call\(([^\n]*?)\), "
+                    r"custom_call_target" % name, text):
+                last = call.split(", ")[-1]
+                made = re.search(r"%s = (\S+) (\w+)\(" % re.escape(last), text)
+                assert made[1].split("{")[0] == "bf16[16384,64,%d]" % lanes
+                assert made[2] == "bitcast", made[0]
+    assert len(re.findall(r"%moe_gmm(?:\.\d+)? = [^\n]*custom-call", text)) \
+        >= 4
+    for scope in ("attn/gate", "dsa/index", "dsa/select", "dsa/attn",
+                  "window_mla", "mla/q", "mla/kv_latent"):
+        assert re.search(r'op_name="[^"]*/%s[/"]' % scope, text), scope
+    mem = compiled.memory_analysis()
+    live = mem.argument_size_in_bytes + mem.temp_size_in_bytes \
+        + mem.output_size_in_bytes - mem.alias_size_in_bytes
+    print(f"[dots3 {kind}] compiled in {secs:.0f} s: arguments "
+          f"{mem.argument_size_in_bytes / 1e9:.2f} GB, temporaries "
+          f"{mem.temp_size_in_bytes / 1e9:.2f} GB, aliased "
+          f"{mem.alias_size_in_bytes / 1e9:.2f} GB, live {live / 1e9:.2f} GB")
+    assert 11.5e9 < mem.argument_size_in_bytes < 11.8e9
+    assert mem.alias_size_in_bytes > 3.4e9            # pages and rings
+    assert live < 15.75 * 2 ** 30 - 0.5e9, live               # the chip's HBM
+    if decode:
+        assert mem.temp_size_in_bytes < 0.5e9, mem.temp_size_in_bytes
+    else:
+        assert not re.search(r"f32\[1,%d,19008\]" % s_len, text)
+        assert '"estimated_cycles":"9223372036854775807"' not in text

@@ -342,6 +342,19 @@ def _scopes(text):
     ("jit(prefill)/attn/jit(flash_gqa_fwd)/jit(_pad)/pad:", "flash_gqa_fwd"),
     ("jit(prefill)/attn/jit(flash_gqa_fwd)/flash_gqa_fwd/pallas_call:",
      "flash_gqa_fwd"),
+    ("jit(decode_tick)/dsa/index/jit(dsa_index)/dsa_index/pallas_call:",
+     "dsa_index"),
+    ("jit(decode_tick)/dsa/select/while/body/reduce_sum:", "dsa/select"),
+    ("jit(decode_tick)/dsa/attn/jit(mla_paged_attn)/dsa_attn/pallas_call:",
+     "dsa_attn"),
+    ("jit(decode_tick)/window_mla/jit(mla_paged_attn)/window_mla_attn/"
+     "pallas_call:", "window_mla_attn"),
+    ("jit(decode_tick)/window_mla/paged_scatter/scatter:", "paged_scatter"),
+    ("jit(prefill)/while/body/closed_call/dsa/attn/while/body/closed_call/"
+     "exp:", "dsa/attn"),
+    ("jit(prefill)/while/body/closed_call/dsa/index/dot_general:",
+     "dsa/index"),
+    ("jit(prefill)/window_mla/mla_attn/dot_general:", "mla_attn"),
     ("pages[45]['k']:", "(no scope)"),
     ("jit(train_step)/headroom/attnx/add:", "(no scope)"),
     ("", "(no scope)"),
