@@ -1073,6 +1073,79 @@ def phase_serve_latent() -> None:
         f"teacher-forced on the served tokens: max |diff| {worst:.5f} "
         f"(tol {LOGIT_TOL})")
     check(worst <= LOGIT_TOL, f"latent decode logits off by {worst}")
+    latent_prefill_at_size()
+
+
+def latent_prefill_at_size() -> None:
+    """A latent family's prefill attention from position 0 at the published
+    shape (32 heads of 192 / 128, rank 512, YaRN scale 0.14468: Xing4.0 and,
+    but for the scale, JoyAI), at 4,096 and 2,048 positions: the tiled
+    kernel ``latent_prefill`` (``ops/attention.latent_fresh_attention``,
+    rows of 3/4 of the window as the backlog cells' prompts average, and
+    whole) against the chunked XLA walk over the expanded rows every other
+    call takes, each timed alone from the same q, rows and ``w_kvb`` to the
+    token-major output, and both against a float32 softmax on the last 256
+    queries."""
+    import functools
+
+    import jax
+    import jax.numpy as jnp
+
+    from distributed_lion_tpu.models.joyai import expand_rows
+    from distributed_lion_tpu.ops.attention import (
+        chunked_causal_attention, latent_fresh_applies,
+        latent_fresh_attention,
+    )
+
+    H, r, dn, dr, dv, scale, tail = 32, 512, 128, 64, 128, 0.14468, 256
+    bf, f32 = jnp.bfloat16, jnp.float32
+    for S in (4096, 2048):
+        ks = jax.random.split(jax.random.key(47 + S), 3)
+        q = (2.0 * jax.random.normal(ks[0], (1, H, S, dn + dr))).astype(bf)
+        row = jax.random.normal(ks[1], (1, S, r + dr), bf)
+        w = (jax.random.normal(ks[2], (r, H, dn + dv)) / r ** 0.5).astype(bf)
+        pos = jnp.zeros((1,), jnp.int32)
+        some = jnp.arange(S)[None, :] < 3 * S // 4
+
+        def walk(q, row, w):
+            k, v = expand_rows(row, w, dn)
+            out = chunked_causal_attention(q, k, v, pos, scale=scale)
+            return out.transpose(0, 2, 1, 3).reshape(1, S, H * dv)
+
+        def reference(q, row, w):
+            k, v = expand_rows(row, w, dn)
+            s = jnp.einsum("hsd,htd->hst", q[0, :, -tail:].astype(f32),
+                           k[0].astype(f32), precision="highest") * scale
+            seen = jnp.arange(S)[None, :] <= (S - tail
+                                              + jnp.arange(tail))[:, None]
+            p = jax.nn.softmax(jnp.where(seen[None], s, -jnp.inf), -1)
+            out = jnp.einsum("hst,htd->hsd", p, v[0].astype(f32),
+                             precision="highest")
+            return out.transpose(1, 0, 2).reshape(tail, H * dv)
+
+        kernel = functools.partial(latent_fresh_attention, scale=scale)
+        out, full_ms = clock(jax.jit(kernel), q, row, w)
+        part, part_ms = clock(jax.jit(kernel), q, row, w, some)
+        ref_walk, walk_ms = clock(jax.jit(walk), q, row, w)
+        ref = jax.jit(reference)(q, row, w)
+        top = float(jnp.abs(ref).max())
+        err = float(jnp.abs(out[0, -tail:].astype(f32) - ref).max()) / top
+        err_walk = float(jnp.abs(ref_walk[0, -tail:].astype(f32)
+                                 - ref).max()) / top
+        apart = float(jnp.abs(part[:, :3 * S // 4].astype(f32)
+                              - out[:, :3 * S // 4].astype(f32)).max())
+        past = -(-3 * S // 4 // 512) * 512   # the first tile wholly past it
+        log(f"  latent prefill at {S} positions, {H} heads of {dn + dr} / "
+            f"{dv}: {full_ms:.2f} ms a layer through latent_prefill "
+            f"({part_ms:.2f} with rows of {3 * S // 4}), {walk_ms:.2f} ms "
+            f"through the chunked XLA walk; last {tail} queries against a "
+            f"float32 softmax: worst |diff| / max {err:.5f} the kernel, "
+            f"{err_walk:.5f} the walk (tol 0.02); the rule takes it: "
+            f"{latent_fresh_applies(S, dn, dv)}")
+        check(err <= 0.02, f"latent_prefill off by {err}")
+        check(apart == 0.0, f"a row's length moved its real queries: {apart}")
+        check(not bool(part[:, past:].any()),
+              "a tile past the row's length is not zero")
 
 
 def phase_serve_window() -> None:

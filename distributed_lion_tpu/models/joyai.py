@@ -26,7 +26,11 @@ Per layer, ``h = x + Attn(RMSNorm(x))``, ``y = h + FFN(RMSNorm(h))``:
   other call (S > 1: prefill, verify, prefix hits; the CPU) gathers the
   rows, expands keys (width nope + rope) and values (width v) and attends
   a chunk of queries at a time. A prefill whose rows all start at 0 (no
-  shared prefix) gathers only the pages its own tokens fill.
+  shared prefix) gathers only the pages its own tokens fill; told so at
+  dispatch (the engine's static ``fresh``) and in a bucket of 2,048 tokens
+  or more on a TPU, it gathers nothing and attends the rows it has just
+  computed through the tiled kernel ``latent_prefill``
+  (``ops/attention.latent_fresh_attention``).
 
 ``_mla_block`` also serves a family whose layers differ in geometry and in
 WHERE THEIR KEYS COME FROM (``models/dots3``): it takes the layer kind's own
@@ -234,7 +238,7 @@ def expand_rows(rows, w_kvb, dn: int):
 
 
 def _mla_block(x, p, cfg: JoyAIConfig, c, tables, pos, cos, sin, valid,
-               scale=None, rescale=(1.0, 1.0), keys=None):
+               scale=None, rescale=(1.0, 1.0), keys=None, fresh=False):
     """Latent attention over the paged cache: scatter the new tokens'
     latent rows, then attend (the module note says which path). Returns
     (output ``[B, S, d]``, the layer's updated page leaf). ``cfg`` is read
@@ -257,9 +261,16 @@ def _mla_block(x, p, cfg: JoyAIConfig, c, tables, pos, cos, sin, valid,
     new rows ``row [B, S, 1, r + dr]`` where its cache keeps them and
     attends its own key set: the last ``window`` positions out of a ring a
     slot, or the set a learned indexer kept (``models/dots3``'s two; ``c_q``
-    is the scaled query latent an indexer reads)."""
+    is the scaled query latent an indexer reads).
+
+    ``fresh`` (static): the dispatch says every row starts at position 0
+    (the engine's ``fresh``). In a bucket ``ops/attention.
+    latent_fresh_applies`` takes, the block then attends the rows it has
+    just computed through the tiled kernel and gathers nothing."""
     from distributed_lion_tpu.ops.attention import (
         chunked_causal_attention,
+        latent_fresh_applies,
+        latent_fresh_attention,
         mla_decode_attention,
         paged_gather_kv,
         paged_kernel_applies,
@@ -302,6 +313,10 @@ def _mla_block(x, p, cfg: JoyAIConfig, c, tables, pos, cos, sin, valid,
         q_abs = absorb_query(q_nope, q_rope, w_kvb, pool.shape[-1])
         o_lat = mla_decode_attention(q_abs, pool, tables, pos, scale=scale)
         out = expand_output(o_lat, w_kvb, dn).reshape(B, 1, H * dv)
+    elif fresh and latent_fresh_applies(S, dn, dv):
+        out = latent_fresh_attention(
+            jnp.concatenate([q_nope, q_rope], -1), row[:, :, 0], w_kvb,
+            valid, scale=scale)
     else:
         def attend(tab):
             rows = paged_gather_kv(pool, tab)[:, :, 0, :r + dr]  # [B, T, .]
@@ -332,9 +347,10 @@ def _mla_block(x, p, cfg: JoyAIConfig, c, tables, pos, cos, sin, valid,
 def joyai_decode_paged(params: dict, tokens: jnp.ndarray, cfg: JoyAIConfig,
                        pages: list, tables: jnp.ndarray, pos: jnp.ndarray,
                        valid=None, return_moe_stats: bool = False,
-                       logit_index=None):
+                       logit_index=None, fresh: bool = False):
     """Block-table decode (the serving engine's model hook, as
-    ``llama_decode_paged``): row b's ``tokens`` [B, S] sit at positions
+    ``llama_decode_paged``, ``fresh`` included: ``_mla_block``'s): row b's
+    ``tokens`` [B, S] sit at positions
     ``pos[b] .. pos[b]+S-1``; ``pages`` is the per-layer ``{"kv"}`` latent
     pool. Returns (logits float32, updated pages[, counters]): logits
     ``[B, S, vocab]``, or ``[B, 1, vocab]`` of position ``logit_index``
@@ -358,7 +374,7 @@ def joyai_decode_paged(params: dict, tokens: jnp.ndarray, cfg: JoyAIConfig,
     new_pages = []
     for p, c in zip(params["blocks"], pages):
         a, c = _mla_block(_rms_norm(x, p["ln_attn"], cfg.rms_eps), p["attn"],
-                          cfg, c, tables, pos, cos, sin, valid)
+                          cfg, c, tables, pos, cos, sin, valid, fresh=fresh)
         new_pages.append(c)
         x = x + a
         h = _rms_norm(x, p["ln_mlp"], cfg.rms_eps)

@@ -339,9 +339,11 @@ def _kda_block(u, p, cfg: LingConfig, c, slots, lengths, lanes):
 def ling_decode_paged(params: dict, tokens: jnp.ndarray, cfg: LingConfig,
                       pages: list, tables: jnp.ndarray, slots: jnp.ndarray,
                       pos: jnp.ndarray, valid=None,
-                      return_moe_stats: bool = False, logit_index=None):
+                      return_moe_stats: bool = False, logit_index=None,
+                      fresh: bool = False):
     """Block-table decode (the serving engine's model hook, as
-    ``laguna_decode_paged``): row b's ``tokens`` [B, S] sit at positions
+    ``laguna_decode_paged``; ``fresh`` is ``models/joyai._mla_block``'s):
+    row b's ``tokens`` [B, S] sit at positions
     ``pos[b] .. pos[b]+S-1``; ``pages`` is the per-layer cache list, an MLA
     layer's ``{"kv"}`` latent pool under ``tables`` [B, nb] and a KDA
     layer's ``{"state", "conv"}`` a slot (the module note). S = 1 is the
@@ -373,7 +375,7 @@ def ling_decode_paged(params: dict, tokens: jnp.ndarray, cfg: LingConfig,
             a, c = _kda_block(u, p["kda"], cfg, c, slots, lengths, lanes)
         else:
             a, c = _mla_block(u, p["attn"], cfg, c, tables, pos, cos, sin,
-                              valid)
+                              valid, fresh=fresh)
         new_pages.append(c)
         x = x + a
         h = _rms_norm(x, p["ln_mlp"], cfg.rms_eps)

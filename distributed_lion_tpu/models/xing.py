@@ -243,9 +243,9 @@ def xing_init(key: jax.Array, cfg: XingConfig) -> dict:
 def xing_decode_paged(params: dict, tokens: jnp.ndarray, cfg: XingConfig,
                       pages: list, tables: jnp.ndarray, pos: jnp.ndarray,
                       valid=None, return_moe_stats: bool = False,
-                      logit_index=None):
+                      logit_index=None, fresh: bool = False):
     """Block-table decode (the serving engine's model hook, as
-    ``joyai_decode_paged``): row b's ``tokens`` [B, S] sit at positions
+    ``joyai_decode_paged``, ``fresh`` included): row b's ``tokens`` [B, S] sit at positions
     ``pos[b] .. pos[b]+S-1``; ``pages`` is the per-layer ``{"kv"}`` latent
     pool. Returns (logits float32, updated pages[, counters]): logits ``[B,
     S, vocab]``, or ``[B, 1, vocab]`` of position ``logit_index`` when given.
@@ -278,7 +278,7 @@ def xing_decode_paged(params: dict, tokens: jnp.ndarray, cfg: XingConfig,
     for p, c in zip(params["blocks"], pages):
         h, coef = read(X, p["hc_attn"], p["ln_attn"])
         a, c = _mla_block(h, p["attn"], cfg, c, tables, pos, cos, sin, valid,
-                          scale=cfg.softmax_scale)
+                          scale=cfg.softmax_scale, fresh=fresh)
         new_pages.append(c)
         X = mhc_post(X, a.reshape(B * S, d), coef, lanes, mix)
         h, coef = read(X, p["hc_mlp"], p["ln_mlp"])

@@ -355,6 +355,58 @@ def fresh_causal_attention(q, k, v):
                          v.reshape(B, S, -1), H)
 
 
+# A latent family's prefill from position 0 (:func:`latent_fresh_applies`):
+# the rows it attends are the ones it has just computed, so it expands keys
+# and values from those, token-major as the up-projection writes them, and
+# attends through the tiled kernel ``latent_prefill`` (ops/pallas_dsa: the
+# indexer's prefill kernel without its mask). Nothing is gathered and no
+# ``[H, chunk, T]`` float32 scores reach HBM; the pages are written as on
+# every other path. Smaller buckets, a prefill behind a shared prefix, the
+# verify window and the CPU keep :func:`chunked_causal_attention`.
+LATENT_FRESH_MIN = 2048     # positions from which the kernel was faster
+
+
+def latent_fresh_applies(n_new: int, dn: int, dv: int) -> bool:
+    """True when :func:`latent_fresh_attention` takes a window of ``n_new``
+    tokens from position 0 of heads ``dn`` (+ rope) / ``dv``: a TPU, whole
+    key tiles, heads of one lane tile, and a window at or over the size where
+    the kernel was measured faster than the chunked walk (32 heads of 192 /
+    128 alone on the chip: 3.1 against 5.2 ms a layer at 4,096 positions, 1.4
+    against 1.8 at 2,048; 1,024 is one key tile and was not tried: PERF.md,
+    PR 47).
+    The serving engine asks the same question, bucket by bucket
+    (``ServeModel.fresh_prefill``)."""
+    from distributed_lion_tpu.ops.pallas_dsa import latent_prefill_takes
+
+    return (jax.default_backend() == "tpu" and n_new >= LATENT_FRESH_MIN
+            and latent_prefill_takes(n_new, dn, dv))
+
+
+@jax.named_scope("mla_attn")
+def latent_fresh_attention(q, row, w_kvb, valid=None, *, scale: float):
+    """Causal self-attention of S fresh tokens at positions ``0 .. S - 1``
+    over their own latent rows: q ``[B, H, S, dn + dr]`` (roped); ``row [B,
+    S, r + dr]`` the rows ``[c_kv | k_rope]`` the block has just computed
+    (and scatters for the decode ticks); ``w_kvb [r, H, dn + dv]``; ``valid``
+    [B, S] or [1, S] marks a right-padded prompt's real tokens (None: all).
+    Returns ``[B, S, H * dv]`` in q's dtype, token-major as the output
+    projection reads it. The arithmetic is :func:`chunked_causal_attention`'s
+    over ``models/joyai.expand_rows``: float32 scores and sums, the
+    probabilities cast before the value product (unnormalised here: one
+    rounding apart). A caller asks :func:`latent_fresh_applies` first. Rows
+    past a prompt's end are the caller's to discard (whole tiles of them
+    come back zero, uncomputed)."""
+    from distributed_lion_tpu.ops.pallas_dsa import latent_prefill
+
+    B, _, S, _ = q.shape
+    r = w_kvb.shape[0]
+    kv = jnp.einsum("bsr,rm->bsm", row[..., :r], w_kvb.reshape(r, -1),
+                    preferred_element_type=jnp.float32).astype(row.dtype)
+    lengths = (jnp.full((B,), S, jnp.int32) if valid is None else
+               jnp.broadcast_to(valid, (B, S)).sum(axis=1, dtype=jnp.int32))
+    return latent_prefill(q, kv, row[..., r:], lengths, scale=scale)
+
+
 # ------------------------------------------------ window layers: the ring
 # A layer whose queries see only the last ``window`` positions keeps a
 # bounded span a slot, whatever the sequence's length: ``R`` pages
@@ -539,7 +591,8 @@ def banded_causal_attention(q, k, v, *, window=None):
 # value up-projections into the query and the output and runs
 # :func:`mla_decode_attention`, one page read for scores and values; every
 # other call (S > 1, the CPU) gathers the rows, expands keys and values and
-# runs :func:`chunked_causal_attention`, which takes keys wider than values.
+# runs :func:`chunked_causal_attention`, which takes keys wider than values,
+# but for a long prefill from position 0 (:func:`latent_fresh_attention`).
 
 
 @jax.named_scope("window_mla")
@@ -589,7 +642,10 @@ def query_chunk(B: int, H: int, S: int, T: int) -> int:
     128 over 4,096 keys, a layer, took 16.2 ms at 256 queries a chunk (128
     MB of scores), 9.8 at 128, 6.0 at 64 and 4.5 at 32 (16 MB), where the
     same heads over 2,048 keys took 1.1 ms at any of them (PERF.md, PR 39).
-    Every shape that fitted keeps its 256."""
+    Every shape that fitted keeps its 256. Since PR 47 that 4,096-key walk is
+    what a prefill behind a shared prefix and the verify window take: a
+    prefill from position 0 of ``LATENT_FRESH_MIN`` tokens attends through the tiled
+    kernel (:func:`latent_fresh_attention`) and no chunk is cut for it."""
     chunk = 256
     if B * H * chunk * T * 4 > SCORE_BYTES:
         while chunk > 8 and B * H * chunk * T * 4 > SCORE_BYTES // 4:

@@ -29,7 +29,15 @@ clamped to the last one the query tile can see) nor computed. What the XLA
 walk (``ops/dsa``) writes to HBM and reads back a step, ``[heads, queries,
 keys]`` float32 scores five times over, never leaves the chip's VMEM here.
 
-Names on the device: ``dsa_index``, ``dsa_prefill``.
+The same tiles with no mask (:func:`latent_prefill`): every latent family's
+prefill from position 0 (``ops/attention.latent_fresh_attention``). The
+causal bound comes from two iotas on the tiles the diagonal crosses and
+from nothing on the tiles under it; keys and values are read where the
+up-projection wrote them (a token's row of every head's ``[k_nope | v]``),
+the one ``k_rope`` a position holds is an operand of its own, the output is
+token-major, and a query tile wholly past its row's length is zeros.
+
+Names on the device: ``dsa_index``, ``dsa_prefill``, ``latent_prefill``.
 """
 
 from __future__ import annotations
@@ -173,10 +181,25 @@ def prefill_takes(S: int, dv: int) -> bool:
     return S % BLOCK_K == 0 and dv % 128 == 0
 
 
-def _prefill_kernel(q_ref, k_ref, v_ref, keep_ref, o_ref, m_ref, l_ref,
-                    acc_ref, *, scale: float):
-    i, j = pl.program_id(1), pl.program_id(2)
-    bq, bk = keep_ref.shape
+def _prefill_kernel(*refs, scale: float, masked: bool):
+    """One tile of a prefill's online softmax, in its two forms. Under a
+    mask (:func:`dsa_prefill`): ``q, k [., dk]``, ``v``, the mask's tile.
+    Without one (:func:`latent_prefill`): the rows' lengths (scalars), ``q
+    [., dn + dr]``, every head's own ``k_nope [., dn]``, the ONE ``k_rope
+    [., dr]`` the heads share (a second product into the same scores) and
+    ``v``; the causal bound then comes from two iotas, on the tiles the
+    diagonal crosses alone, and a query tile wholly past its row's length
+    writes zeros."""
+    if masked:
+        q_ref, k_ref, v_ref, keep_ref, o_ref, m_ref, l_ref, acc_ref = refs
+        i, j = pl.program_id(1), pl.program_id(2)
+        live = True
+    else:
+        len_ref, q_ref, k_ref, kr_ref, v_ref, o_ref, m_ref, l_ref, acc_ref \
+            = refs
+        i, j = pl.program_id(2), pl.program_id(3)
+        live = i * q_ref.shape[0] < len_ref[pl.program_id(0)]
+    bq, bk = q_ref.shape[0], k_ref.shape[0]
     last = (i * bq + bq - 1) // bk     # the last key tile this one can see
 
     @pl.when(j == 0)
@@ -185,28 +208,72 @@ def _prefill_kernel(q_ref, k_ref, v_ref, keep_ref, o_ref, m_ref, l_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    @pl.when(j <= last)
-    def _():
-        s = jax.lax.dot_general(q_ref[...], k_ref[...],
-                                (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        seen = keep_ref[...].astype(jnp.int32) != 0
-        s = jnp.where(seen, s, MASKED)
+    def scores():
+        dims = (((1,), (1,)), ((), ()))
+        if masked:
+            return jax.lax.dot_general(q_ref[...], k_ref[...], dims,
+                                       preferred_element_type=jnp.float32)
+        dn = k_ref.shape[1]
+        return jax.lax.dot_general(
+            q_ref[:, :dn], k_ref[...], dims,
+            preferred_element_type=jnp.float32) + jax.lax.dot_general(
+            q_ref[:, dn:], kr_ref[...], dims,
+            preferred_element_type=jnp.float32)
+
+    def tile(seen):
+        s = scores() * scale
+        if seen is not None:
+            s = jnp.where(seen, s, MASKED)
         m_prev = m_ref[...]
         m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
-        p = jnp.where(seen, jnp.exp(s - m_new), 0.0)   # a tile may keep none
+        p = jnp.exp(s - m_new)
+        if masked:                     # a tile of the mask may keep none
+            p = jnp.where(seen, p, 0.0)
         l_ref[...] = alpha * l_ref[...] + p.sum(axis=1, keepdims=True)
         acc_ref[...] = alpha * acc_ref[...] + jnp.dot(
             p.astype(v_ref.dtype), v_ref[...],
             preferred_element_type=jnp.float32)
         m_ref[...] = m_new
 
+    if masked:
+        @pl.when(j <= last)
+        def _():
+            tile(keep_ref[...].astype(jnp.int32) != 0)
+    else:
+        # key tiles wholly at or under the tile's first query: no bound
+        clear = (i * bq + 1) // bk
+
+        @pl.when(jnp.logical_and(live, j < clear))
+        def _():
+            tile(None)
+
+        @pl.when(jnp.logical_and(live, jnp.logical_and(j >= clear,
+                                                       j <= last)))
+        def _():       # every row sees a key of such a tile: its own, or
+            # the tile's first
+            rows = i * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
+            cols = j * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
+            tile(cols <= rows)
+
     @pl.when(j == last)
     def _():
-        l = l_ref[...]
+        l = l_ref[...]                 # 0 where nothing ran: zeros out
         o_ref[...] = (acc_ref[...] / jnp.where(l > 0, l, 1.0)
                       ).astype(o_ref.dtype)
+
+
+def _last_seen(i, j, bq: int, bk: int):
+    """The key tile a grid step copies: a tile past the diagonal is the
+    last one the query tile sees, again (so nothing is copied)."""
+    return jnp.minimum(j, (i * bq + bq - 1) // bk)
+
+
+def _scratch(bq: int, dv: int) -> list:
+    """A query tile's running max, sum and accumulator."""
+    return [pltpu.VMEM((bq, 1), jnp.float32),
+            pltpu.VMEM((bq, 1), jnp.float32),
+            pltpu.VMEM((bq, dv), jnp.float32)]
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "interpret"))
@@ -220,13 +287,10 @@ def dsa_prefill(q, k, v, keep, *, scale: float, interpret: bool = False):
     H, S, dk = q.shape
     dv = v.shape[-1]
     bq, bk = min(BLOCK_Q, S), min(BLOCK_K, S)
-
-    def seen(i, j):                  # a tile past the diagonal is the last
-        return jnp.minimum(j, (i * bq + bq - 1) // bk)   # one seen, again
-
+    seen = functools.partial(_last_seen, bq=bq, bk=bk)
     with jax.named_scope("dsa_prefill"):
         return pl.pallas_call(
-            functools.partial(_prefill_kernel, scale=scale),
+            functools.partial(_prefill_kernel, scale=scale, masked=True),
             grid=(H, S // bq, S // bk),
             in_specs=[
                 pl.BlockSpec((None, bq, dk), lambda h, i, j: (h, i, 0)),
@@ -236,12 +300,69 @@ def dsa_prefill(q, k, v, keep, *, scale: float, interpret: bool = False):
                              lambda h, i, j: (h, seen(i, j), 0)),
                 pl.BlockSpec((bq, bk), lambda h, i, j: (i, seen(i, j)))],
             out_specs=pl.BlockSpec((None, bq, dv), lambda h, i, j: (h, i, 0)),
-            scratch_shapes=[pltpu.VMEM((bq, 1), jnp.float32),
-                            pltpu.VMEM((bq, 1), jnp.float32),
-                            pltpu.VMEM((bq, dv), jnp.float32)],
+            scratch_shapes=_scratch(bq, dv),
             out_shape=jax.ShapeDtypeStruct((H, S, dv), q.dtype),
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel", "arbitrary")),
             interpret=interpret,
             name="dsa_prefill",
         )(q, k, v, keep)
+
+
+# --------------------------------------------- the same tiles with no mask
+def latent_prefill_takes(S: int, dn: int, dv: int) -> bool:
+    """Whether :func:`latent_prefill` takes ``S`` positions of heads ``dn``
+    (the keys' own part) / ``dv``: whole key tiles, and one lane tile each,
+    so that a head's ``[k_nope | v]`` are two blocks of the row the
+    up-projection wrote."""
+    return S % BLOCK_K == 0 and dn == dv == 128
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
+def latent_prefill(q, kv, k_rope, lengths, *, scale: float,
+                   interpret: bool = False):
+    """Causal self-attention of S fresh tokens at positions ``0 .. S - 1``
+    of a latent family, expanded form, every operand as its projection wrote
+    it: q ``[B, H, S, dn + dr]``; kv ``[B, S, H * (dn + dv)]``, a token's row
+    of every head's ``[k_nope | v]`` (``c_kv W_kvb``, token-major: a head's
+    keys and values are two lane blocks of it); k_rope ``[B, S, dr]``, the
+    one roped key all heads share; ``lengths [B]`` int32, the real tokens of
+    each row. :func:`latent_prefill_takes` says which shapes. Returns
+    ``softmax(scale [q_nope | q_rope] [k_nope | k_rope]^T | causal) v``
+    token-major ``[B, S, H * dv]`` in q's dtype (what the output projection
+    reads): float32 scores and sums, the probabilities cast to v's dtype
+    before the value product. Queries in a tile wholly past ``lengths[b]``
+    come back zero (nothing is computed for them); the others past it attend
+    pad keys and are the caller's to discard."""
+    B, H, S, dk = q.shape
+    dr = k_rope.shape[-1]
+    dn = dv = dk - dr
+    bq, bk = min(BLOCK_Q, S), min(BLOCK_K, S)
+
+    def seen(b, i, j, lens):           # a dead query tile copies tile 0
+        return jnp.where(i * bq < lens[b], _last_seen(i, j, bq, bk), 0)
+
+    with jax.named_scope("latent_prefill"):
+        return pl.pallas_call(
+            functools.partial(_prefill_kernel, scale=scale, masked=False),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=1,
+                grid=(B, H, S // bq, S // bk),
+                in_specs=[
+                    pl.BlockSpec((None, None, bq, dk),
+                                 lambda b, h, i, j, lens: (b, h, i, 0)),
+                    pl.BlockSpec((None, bk, dn), lambda b, h, i, j, lens:
+                                 (b, seen(b, i, j, lens), 2 * h)),
+                    pl.BlockSpec((None, bk, dr), lambda b, h, i, j, lens:
+                                 (b, seen(b, i, j, lens), 0)),
+                    pl.BlockSpec((None, bk, dv), lambda b, h, i, j, lens:
+                                 (b, seen(b, i, j, lens), 2 * h + 1))],
+                out_specs=pl.BlockSpec((None, bq, dv),
+                                       lambda b, h, i, j, lens: (b, i, h)),
+                scratch_shapes=_scratch(bq, dv)),
+            out_shape=jax.ShapeDtypeStruct((B, S, H * dv), q.dtype),
+            compiler_params=pltpu.CompilerParams(dimension_semantics=(
+                "parallel", "parallel", "parallel", "arbitrary")),
+            interpret=interpret,
+            name="latent_prefill",
+        )(lengths.astype(jnp.int32), q, kv, k_rope, kv)

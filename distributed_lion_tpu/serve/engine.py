@@ -41,10 +41,13 @@ Scheduling (the vLLM recipe, simplified to two tick kinds):
   masked via the scatter's ``valid`` lanes; samples the request's first
   token inside the same dispatch. The host knows at dispatch whether the
   prefill starts at position 0 (no shared prefix before it) and says so
-  to a hook that takes it (``ServeModel.fresh_prefill``: GPT-2 and Llama)
-  as the STATIC argument ``fresh``: in a bucket the tiled forward kernel
-  takes (``ops/attention.fresh_kernel_applies``: on a TPU, whole blocks of
-  128 rows) the program then attends over the keys it has just projected
+  to a hook that takes it (``ServeModel.fresh_prefill``: GPT-2, Llama and
+  the latent families) as the STATIC argument ``fresh``: in a bucket the
+  family's tiled kernel takes (the rule ``fresh_prefill`` carries: on a
+  TPU, whole blocks of 128 rows for ``ops/attention.fresh_kernel_applies``;
+  ``LATENT_FRESH_MIN`` tokens or more for a latent family's
+  ``latent_fresh_applies``)
+  the program then attends over the keys it has just projected
   and only writes its pages; a prefill behind a shared prefix, the
   speculative verify and the drafter's mirror see pages they did not
   write and keep the gather path. ``stats["prefill_fresh_dispatches"]``
@@ -510,6 +513,27 @@ class _RetraceGuard:
         warnings.warn(msg, RuntimeWarning, stacklevel=3)
 
 
+def _dense_fresh(cfg, kv_heads: int) -> tuple:
+    """``ServeModel.fresh_prefill`` of GPT-2 and Llama: the tiled forward
+    kernel's name and its rule for one shard's heads."""
+    from distributed_lion_tpu.ops.attention import fresh_kernel_applies
+
+    return "flash_gqa_fwd", lambda bucket, shards: fresh_kernel_applies(
+        bucket, cfg.n_head // shards, kv_heads // shards, cfg.head_dim,
+        cfg.compute_dtype)
+
+
+def _latent_fresh(cfg) -> tuple:
+    """``ServeModel.fresh_prefill`` of a family whose layers are
+    ``models/joyai._mla_block`` with no ``keys`` of their own: the kernel's
+    name and the block's own rule, from the head widths alone (the engine
+    shards no such family)."""
+    from distributed_lion_tpu.ops.attention import latent_fresh_applies
+
+    return "latent_prefill", lambda bucket, shards: latent_fresh_applies(
+        bucket, cfg.qk_nope_head_dim, cfg.v_head_dim)
+
+
 class ServeModel:
     """Family adapter: the paged decode hook + cache geometry the engine
     needs, built from a (params, config) pair. ``decode_paged(params,
@@ -534,7 +558,8 @@ class ServeModel:
                  shardable: bool = True, window: int = 0,
                  window_layers: tuple = (), state_layers: tuple = (),
                  state_leaves: Optional[Dict[str, tuple]] = None,
-                 setup_note: str = "", fresh_prefill: bool = False,
+                 setup_note: str = "",
+                 fresh_prefill: Optional[tuple] = None,
                  page_run: int = 0,
                  window_leaves: Optional[Dict[str, tuple]] = None,
                  index_leaves: tuple = ()):
@@ -569,6 +594,8 @@ class ServeModel:
         # over the keys it has just projected instead of gathering them back
         # out of the pool (ops/attention.fresh_causal_attention). A family
         # whose hook already reads S > 1 as "from 0" has no use for it
+        # (None). Else ``(the kernel's name, rule(bucket, tensor shards) ->
+        # bool)``: which buckets' prefills from 0 take it
         self.fresh_prefill = fresh_prefill
         # False: no tensor / expert sharding and no quantized weights here
         self.shardable = shardable
@@ -639,7 +666,8 @@ class ServeModel:
 
         return ServeModel("gpt2", cfg, params, decode, cfg.n_layer,
                           cfg.n_head, cfg.head_dim, cfg.compute_dtype,
-                          max_positions=cfg.n_ctx, fresh_prefill=True)
+                          max_positions=cfg.n_ctx,
+                          fresh_prefill=_dense_fresh(cfg, cfg.n_head))
 
     @staticmethod
     def for_llama(params: Any, cfg: Any) -> "ServeModel":
@@ -657,7 +685,8 @@ class ServeModel:
 
         return ServeModel("llama", cfg, params, decode, cfg.n_layer,
                           cfg.n_kv_head, cfg.head_dim, cfg.compute_dtype,
-                          max_positions=cfg.n_ctx, fresh_prefill=True)
+                          max_positions=cfg.n_ctx,
+                          fresh_prefill=_dense_fresh(cfg, cfg.n_kv_head))
 
     @staticmethod
     def for_joyai(params: Any, cfg: Any) -> "ServeModel":
@@ -671,18 +700,20 @@ class ServeModel:
 
         def decode(p, toks, pages, tables, pos, valid=None, tp_axis=None,
                    ep_axis=None, return_moe_stats=False, stats_axis=None,
-                   stats_lanes=None, logit_index=None):
+                   stats_lanes=None, logit_index=None, fresh=False):
             # the engine refuses tp / ep for this family at build
             assert tp_axis is None and ep_axis is None and stats_axis is None
             return joyai_decode_paged(p, toks, cfg, pages, tables, pos,
-                                      valid, return_moe_stats, logit_index)
+                                      valid, return_moe_stats, logit_index,
+                                      fresh)
 
         return ServeModel(
             "joyai", cfg, params, decode, cfg.n_layer, 1, cfg.latent_dim,
             cfg.compute_dtype, max_positions=cfg.n_ctx,
             page_leaves={"kv": (1, cfg.latent_dim)},
             kernel_stat="mla_kernel_ticks", moe_counters=MOE_COUNTERS,
-            last_logit=True, shardable=False)
+            last_logit=True, shardable=False,
+            fresh_prefill=_latent_fresh(cfg))
 
     @staticmethod
     def for_xing(params: Any, cfg: Any) -> "ServeModel":
@@ -697,11 +728,12 @@ class ServeModel:
 
         def decode(p, toks, pages, tables, pos, valid=None, tp_axis=None,
                    ep_axis=None, return_moe_stats=False, stats_axis=None,
-                   stats_lanes=None, logit_index=None):
+                   stats_lanes=None, logit_index=None, fresh=False):
             # the engine refuses tp / ep for this family at build
             assert tp_axis is None and ep_axis is None and stats_axis is None
             return xing_decode_paged(p, toks, cfg, pages, tables, pos,
-                                     valid, return_moe_stats, logit_index)
+                                     valid, return_moe_stats, logit_index,
+                                     fresh)
 
         return ServeModel(
             "xing", cfg, params, decode, cfg.n_layer, 1, cfg.latent_dim,
@@ -709,6 +741,7 @@ class ServeModel:
             page_leaves={"kv": (1, cfg.latent_dim)},
             kernel_stat="mla_kernel_ticks", moe_counters=XING_COUNTERS,
             last_logit=True, shardable=False,
+            fresh_prefill=_latent_fresh(cfg),
             setup_note=(
                 f"residual: {cfg.hc_mult} mixed streams of {cfg.d_model} "
                 f"(mHC, {cfg.hc_sinkhorn_iters} Sinkhorn steps a token a "
@@ -794,12 +827,13 @@ class ServeModel:
 
         def decode(p, toks, pages, tables, pos, valid=None, tp_axis=None,
                    ep_axis=None, return_moe_stats=False, stats_axis=None,
-                   stats_lanes=None, logit_index=None, slots=None):
+                   stats_lanes=None, logit_index=None, slots=None,
+                   fresh=False):
             # the engine refuses tp / ep for this family at build
             assert tp_axis is None and ep_axis is None and stats_axis is None
             return ling_decode_paged(
                 p, toks, cfg, pages, tables, slots, pos, valid,
-                return_moe_stats, logit_index)
+                return_moe_stats, logit_index, fresh)
 
         H, hd = cfg.n_head, cfg.head_dim
         return ServeModel(
@@ -808,6 +842,7 @@ class ServeModel:
             page_leaves={"kv": (1, cfg.latent_dim)},
             kernel_stat="mla_kernel_ticks", moe_counters=LING_COUNTERS,
             last_logit=True, shardable=False, state_layers=cfg.kda_layers,
+            fresh_prefill=_latent_fresh(cfg),
             state_leaves={
                 "state": ((H, hd, hd), jnp.float32),
                 "conv": ((cfg.conv_width - 1, cfg.conv_channels),
@@ -1212,14 +1247,9 @@ class ServingEngine:
             model.cache_dtype)
         # the prefill buckets whose program attends over fresh keys when
         # the host says the prefill starts at 0 (one shard's heads)
-        from distributed_lion_tpu.ops.attention import fresh_kernel_applies
-
-        shards = max(cfg.tp, 1)
         self._fresh_buckets = frozenset(
             b for b in self._buckets() if model.fresh_prefill
-            and fresh_kernel_applies(
-                b, model.cfg.n_head // shards, model.kv_heads // shards,
-                model.head_dim, model.cache_dtype))
+            and model.fresh_prefill[1](b, max(cfg.tp, 1)))
         if self._windowed:
             # ticks whose walk over the ring ran the kernel. The pages
             # those walks were handed, ``kv_window_pages_read`` (ONE window
@@ -1457,8 +1487,9 @@ class ServingEngine:
             stderr=True)
         if model.fresh_prefill:
             fb = sorted(self._fresh_buckets)
+            paged = model.n_layer - len(model.state_layers)
             journal.emit("[setup] prefill: " + (
-                f"fresh keys, flash_gqa_fwd x{model.n_layer} (from position "
+                f"fresh keys, {model.fresh_prefill[0]} x{paged} (from position "
                 f"0 in buckets {fb[0]}-{fb[-1]}; every other prefill gathers)"
                 if fb else "gather"), stderr=True)
 

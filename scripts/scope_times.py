@@ -47,7 +47,7 @@ SCOPES = ("embed", "attn", "mlp", "head", "xent", "paged_attn",
           "moe_gmm_drhs", "flash_gqa_lse", "flash_gqa_di", "flash_gqa_dq",
           "flash_gqa_dkv", "qk_rope_fwd", "qk_rope_bwd", "dsa/index",
           "dsa/select", "dsa/attn", "window_mla", "dsa_index", "dsa_attn",
-          "window_mla_attn", "dsa_prefill")
+          "window_mla_attn", "dsa_prefill", "latent_prefill")
 NO_SCOPE = "(no scope)"
 _FIND = [(s, re.compile(r"(?<=[(/])%s(?=[)/])" % re.escape(s)))
          for s in SCOPES]

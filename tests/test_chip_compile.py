@@ -1580,7 +1580,8 @@ def test_minicpm_sala_serving_programs_at_the_published_shapes(
         assert '"estimated_cycles":"9223372036854775807"' not in text
 
 
-@pytest.mark.parametrize("kind", ["decode_tick", "prefill_4096"])
+@pytest.mark.parametrize("kind", ["decode_tick", "prefill_4096",
+                                  "prefill_4096_fresh"])
 def test_mhc_and_latent_serving_programs_at_the_published_shapes(
         one_chip, kind, monkeypatch, capsys):
     """Xing4.0-29B-A4B as ``serve.xing4.0-29b-a4b.backlog-4k-in`` runs it
@@ -1593,7 +1594,11 @@ def test_mhc_and_latent_serving_programs_at_the_published_shapes(
     or a float32 image of it: the stream is ``[rows, 4 x 3584]`` bfloat16,
     rewritten in place by ``mhc_post``. The 4,096-token prefill keeps one
     position's logits and fits the chip beside 11.9 GB of weights and
-    cache; live bytes are printed."""
+    cache; live bytes are printed. Dispatched ``fresh`` (from position 0, as
+    every prefill of the cell is) it attends its own rows through
+    ``latent_prefill`` once a layer, gathers no page and holds no ``[32,
+    chunk, 4096]`` float32 scores; past 0 it keeps the gather and the
+    chunked walk."""
     from distributed_lion_tpu.analysis.serve_check import pool_leaf_copies
     from distributed_lion_tpu.models.xing import (
         XING_COUNTERS, XingConfig, xing_decode_paged, xing_init,
@@ -1606,7 +1611,7 @@ def test_mhc_and_latent_serving_programs_at_the_published_shapes(
     cfg = XingConfig.named(os.path.join(
         root, "benchmark", "configs", "xing4.0-29b-a4b.json"))
     block, per_seq, slots, pool = 16, 288, 64, 18432
-    decode = kind == "decode_tick"
+    decode, fresh = kind == "decode_tick", kind.endswith("_fresh")
     b, s_len = (slots, 1) if decode else (1, int(kind.split("_")[1]))
 
     def place(tree):
@@ -1631,7 +1636,7 @@ def test_mhc_and_latent_serving_programs_at_the_published_shapes(
         logits, pages, st = xing_decode_paged(
             params, toks, cfg, pages, tables,
             pos if decode else jnp.zeros_like(pos), valid, True,
-            None if decode else pos[0])
+            None if decode else pos[0], fresh)
         tail = jnp.stack([st[k] for k in XING_COUNTERS])
         return (jnp.argmax(logits[:, -1], -1), tail), pages
 
@@ -1652,6 +1657,14 @@ def test_mhc_and_latent_serving_programs_at_the_published_shapes(
     calls = re.findall(r"%mla_paged_attn(?:\.\d+)? = [^\n]*custom-call",
                        text)
     assert len(calls) == cfg.n_layer * decode
+    calls = re.findall(r"%latent_prefill(?:\.\d+)? = [^\n]*custom-call", text)
+    assert len(calls) == cfg.n_layer * fresh
+    if not decode:     # the walk's scores and its gather go with ``fresh``
+        from distributed_lion_tpu.ops.attention import query_chunk
+
+        scores = "f32[32,%d,4096]" % query_chunk(1, cfg.n_head, s_len, s_len)
+        assert (scores in text) != fresh, scores
+        assert bool(re.search(r'op_name="[^"]*/paged_gather/', text)) != fresh
     # the stream: no buffer of its size is a copy, a relayout or a float32
     # image of it (a ``[rows, 4, 3584]`` buffer is the expert layer's
     # combine over top_k = 4 picks, not the stream)
