@@ -1,0 +1,10 @@
+"""Device idle time in the traced window, ms a tick: the part under a
+`serve/token_read` span and no collection: the device idle WHILE the host
+waits for it (a transfer or the runtime, not host work). One of five, each
+measured, whose sum is checked against `host_gap_ms.decode`
+(`lib/host_accounts.idle_split`). Source: device_trace."""
+from benchmark.lib.host_accounts import idle_part
+
+
+def read(ctx):
+    return idle_part(ctx, "read")

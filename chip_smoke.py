@@ -877,7 +877,11 @@ def phase_serve(out_dir: str) -> None:
         f"{stats['run_ahead_drains']}, run_ahead_discarded "
         f"{stats['run_ahead_discarded']}; prefill_fresh_dispatches "
         f"{stats['prefill_fresh_dispatches']} of "
-        f"{stats['prefill_dispatches']} prefills")
+        f"{stats['prefill_dispatches']} prefills; read_wait_s "
+        f"{stats['read_wait_s']:.3f}, gc_pause_s {stats['gc_pause_s']:.3f} "
+        f"in {stats['gc_collections']} collections, slow_ticks "
+        f"{stats['slow_ticks']} (slow_tick_excess_s "
+        f"{stats['slow_tick_excess_s']:.3f})")
     log(f"  run_serve: {len(records)} requests complete "
         f"({[r['reason'] for r in records]}), pool back to "
         f"{engine.tables.free_blocks}/{engine.tables.num_blocks} free, "

@@ -171,11 +171,15 @@ def _step_spans(events: list, rank: int, roots_only: bool = False) -> list:
     wall by design and must not count against it. ``roots_only`` also
     drops spans with a ``parent`` (``serve/prefill`` under ``serve/admit``
     under ``serve/tick``): a child's time is inside its parent's, and a
-    sum that tiles the wall must not claim it twice."""
+    sum that tiles the wall must not claim it twice. So is the time of an
+    always-on account (``journal.account``) that is not a ``setup_lap``:
+    a ``gc_pause`` or a ``slow_tick`` lies inside whatever span it
+    interrupted, on the same thread."""
     return [r for r in events
             if r.get("kind") == "span" and int(r.get("rank", 0)) == rank
             and isinstance(r.get("dur"), (int, float))
             and not r.get("thread")
+            and r.get("account") in (None, "setup_lap")
             and not (roots_only and r.get("parent") is not None)]
 
 

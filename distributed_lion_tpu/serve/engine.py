@@ -182,18 +182,42 @@ with its own ``serve/token_read``, and ``serve/commit``, and a prefill's
 A tick's span less the ``serve/token_read`` under it is what the host did
 itself; a ``serve/token_read`` is where it waits for the device, and with
 the next tick already enqueued the device does not wait for it.
-Construction is timed by always-on ``setup/place_weights``,
-``setup/init_pages`` and ``setup/build_dispatches`` spans and one
-``[setup]`` line; the compile ledger (utils/compile_cache) names each
-dispatch's program (``decode_tick``, ``prefill``, ``cow_copy``,
-``verify``) with its trace, lower and compile-or-load seconds when it is
-built, and the retrace guard names it when it counts a retrace.
+
+**Always-on accounts** (``train/journal.account``: kept whether or not
+anything listens, because the run that stalls is never the traced one).
+Construction is timed by ``setup/place_weights``, ``setup/init_pages`` and
+``setup/build_dispatches`` (``setup_lap`` accounts and one ``[setup]``
+line); the compile ledger (utils/compile_cache) names each dispatch's
+program (``decode_tick``, ``prefill``, ``cow_copy``, ``verify``) with its
+trace, lower and compile-or-load seconds when it is built, and the retrace
+guard names it when it counts a retrace. Every tick is stamped by host
+clock reads alone (eight in a decode-only tick, through the engine's
+``time_fn``; no device sync, the token path untouched):
+``stats["read_wait_s"]`` sums what the host waited in its blocking reads
+(its slack under the device's tick: it falls to zero where the host
+binds), ``stats["gc_pause_s"]`` / ``["gc_collections"]`` copy the
+collector's totals once a tick (``journal.watch_gc``), and a decode-only
+tick (no prefill admitted in it or the tick before) whose wall is over
+``SLOW_TICK_FACTOR`` times the median of the last ``SLOW_TICK_HISTORY``
+such ticks' and over ``SLOW_TICK_MIN_S`` counts in ``stats["slow_ticks"]``
+and ``["slow_tick_excess_s"]`` (wall less that median) and leaves one
+``slow_tick`` account: ``tick``, ``wall_ms``, ``median_ms``,
+``read_wait_ms`` with the longest read's ``read_of`` / ``read_tick``,
+``gc_ms``, ``admit_ms``, ``build_ms``, ``commit_ms``, ``prefills`` and
+``next_read_wait_ms``. The ``[serve] slow tick`` line is printed at once
+(stderr), with those fields; the two ticks after it fill
+``next_read_wait_ms`` in and a second line says them: near zero and then a
+whole device tick means the device had finished its queue and sat idle
+while the host was blocked, so the transfer or the runtime held the host; a
+usual wait means the device itself was late. The counters ride
+``serve_stats``.
 """
 
 from __future__ import annotations
 
 import dataclasses
 import functools
+import statistics
 import time
 from collections import deque
 from typing import Any, Callable, Dict, List, Optional
@@ -434,6 +458,23 @@ class _Slot:
     done: bool = False   # its Completion is out; a row of it still
     #                      unread is dropped (an EOS the host could not
     #                      foresee)
+
+
+# A decode-only tick (no prefill admitted in it or in the tick before: a
+# prefill's device time lands in the read of the tick after it) is a SLOW
+# tick when its wall is over SLOW_TICK_FACTOR times the median of the last
+# SLOW_TICK_HISTORY such ticks' and over SLOW_TICK_MIN_S; judged once
+# SLOW_TICK_MIN_TICKS of them are known.
+SLOW_TICK_FACTOR, SLOW_TICK_MIN_S = 8.0, 0.05
+SLOW_TICK_HISTORY, SLOW_TICK_MIN_TICKS = 256, 8
+
+
+def _new_lap() -> dict:
+    """One tick's host seconds by part, from clock reads alone: building
+    the decode operands, waiting in reads, committing; how many reads, the
+    longest of them (seconds, ``of``, ``tick``) and when the last ended."""
+    return {"build": 0.0, "read": 0.0, "commit": 0.0, "reads": 0,
+            "worst": (0.0, "", 0), "at": 0.0}
 
 
 @dataclasses.dataclass
@@ -980,9 +1021,10 @@ class ServingEngine:
         # latency behavior is testable without real sleeps; the metrics
         # plane (when armed) shares the same clock
         self._now = time_fn
-        # the span gate's profiler and the compile ledger's listeners
-        # (both idempotent)
+        # the span gate's profiler, the collector's hook and the compile
+        # ledger's listeners (all idempotent)
         journal.register_profiler(jax.profiler.TraceAnnotation)
+        journal.watch_gc()
         compile_cache.listen()
         setup = journal.SetupLaps("engine")
         params = model.params
@@ -1236,7 +1278,20 @@ class ServingEngine:
                       # names why), and rows dropped because their slot had
                       # sampled EOS in the tick before
                       "run_ahead_ticks": 0, "run_ahead_drains": 0,
-                      "run_ahead_discarded": 0}
+                      "run_ahead_discarded": 0,
+                      # always on, from host clock reads alone (module
+                      # doc, "Always-on accounts"): seconds the host was
+                      # blocked in its reads, the process's collections
+                      # as of the last tick (``journal.gc_totals``), and
+                      # the decode-only ticks that stalled
+                      "read_wait_s": 0.0, "gc_pause_s": 0.0,
+                      "gc_collections": 0, "slow_ticks": 0,
+                      "slow_tick_excess_s": 0.0}
+        self._lap = _new_lap()         # this tick's seconds by part
+        self._walls: deque = deque(maxlen=SLOW_TICK_HISTORY)
+        self._prev_prefills = 0        # prefills the tick before admitted
+        self._slow_open: List[dict] = []   # slow ticks whose next reads
+        # are still to come
         from distributed_lion_tpu.ops.attention import paged_kernel_applies
 
         paged = next(i for i in range(model.n_layer)
@@ -2083,10 +2138,8 @@ class ServingEngine:
         commit its rows: the one routine through which a token reaches
         ``gen``, ``decode_tokens`` / ``prefill_dispatches`` count it and a
         request can end by EOS or length."""
-        span = journal.span
-        with span("serve/token_read", of=u.kind, tick=u.tick):
-            vec = np.asarray(u.vec)
-        with span("serve/commit", batch=len(u.rows)):
+        vec = self._host_read(u.vec, u.kind, u.tick)
+        with journal.span("serve/commit", batch=len(u.rows)):
             first = u.kind == "prefill"
             self._absorb_moe_stats(u.st)
             self._absorb_counters(vec[self.cfg.max_seqs:],
@@ -2109,6 +2162,26 @@ class ServingEngine:
                 else:
                     self.stats["decode_tokens"] += 1
                 self._maybe_finish(i, completions, s=s, tick=u.tick)
+        lap = self._lap
+        lap["commit"] += self._now() - lap["at"]
+
+    def _host_read(self, vec, of: str, tick: int) -> np.ndarray:
+        """The blocking read of one dispatch's output (``serve/token_read``),
+        with the host's wait in it stamped always: ``stats["read_wait_s"]``
+        and this tick's lap (clock reads only; the value is untouched)."""
+        with journal.span("serve/token_read", of=of, tick=tick):
+            t0 = self._now()
+            out = np.asarray(vec)
+            t1 = self._now()
+        wait = t1 - t0
+        self.stats["read_wait_s"] += wait
+        lap = self._lap
+        lap["read"] += wait
+        lap["reads"] += 1
+        lap["at"] = t1
+        if wait >= lap["worst"][0]:
+            lap["worst"] = (wait, of, tick)
+        return out
 
     def _read_unread(self, completions: List[Completion],
                      keep: int = 0) -> None:
@@ -2191,7 +2264,9 @@ class ServingEngine:
         span = journal.span
         with span("serve/decode_tick", batch=residents):
             with span("serve/decode_build"):
+                t0 = self._now()
                 built = self._decode_operands(completions)
+                self._lap["build"] += self._now() - t0
             if built is not None:
                 rest, rows = built
                 with span("serve/decode_dispatch"):  # enqueue only
@@ -2274,11 +2349,16 @@ class ServingEngine:
         completions, self._carry = self._carry, []
         self.stats["ticks"] += 1
         span = journal.span
+        self._lap = _new_lap()
+        gc0 = journal.gc_totals()[1]
+        t_start = self._now()
         with span("serve/tick", tick=self.stats["ticks"]):
             with span("serve/expire"):
                 self._expire_deadlines(completions)
             with span("serve/admit", pending=len(self.pending)) as admit:
-                admit.set(prefills=self._admit(completions))
+                prefills = self._admit(completions)
+                admit.set(prefills=prefills)
+            t_admitted = self._now()
             if self.metrics is not None:
                 # per-token decode interval = the tick's wall time from
                 # here over however many tokens it committed (1/slot
@@ -2293,11 +2373,68 @@ class ServingEngine:
             if self.metrics is not None:
                 with span("serve/metrics"):
                     self._tick_metrics(t0, tok0)
+        self._judge_tick(t_start, t_admitted, self._now(), prefills, gc0)
         for line in compile_cache.new_lines():
             # a dispatch's program, named when it is built (its first
             # tick; a retrace later): trace, lower, compile or load
             journal.emit(line, stderr=True)
         return completions
+
+    def _judge_tick(self, t0: float, t_admitted: float, t1: float,
+                    prefills: int, gc0: float) -> None:
+        """The tick's always-on account (module doc): copy the
+        collector's totals into ``stats``, hand this tick's read wait to
+        the slow ticks still waiting for their next reads, and judge the
+        tick itself if it was decode-only. Host arithmetic on stamps
+        already taken; the median is worked out only for a tick over
+        ``SLOW_TICK_MIN_S``."""
+        lap, stats = self._lap, self.stats
+        stats["gc_collections"], stats["gc_pause_s"] = journal.gc_totals()
+        for rec in self._slow_open:
+            rec["next_read_wait_ms"].append(round(lap["read"] * 1e3, 3))
+        while self._slow_open and \
+                len(self._slow_open[0]["next_read_wait_ms"]) == 2:
+            rec = self._slow_open.pop(0)
+            journal.emit(
+                f"[serve] slow tick {rec['tick']}: next reads waited "
+                + ", ".join(f"{ms:.1f}" for ms in rec["next_read_wait_ms"])
+                + " ms", stderr=True)
+        quiet = not (prefills or self._prev_prefills)
+        self._prev_prefills = prefills
+        if not (quiet and lap["reads"]):
+            return
+        wall = t1 - t0
+        if wall > SLOW_TICK_MIN_S and len(self._walls) >= SLOW_TICK_MIN_TICKS:
+            median = statistics.median(self._walls)
+            if wall > SLOW_TICK_FACTOR * median:
+                stats["slow_ticks"] += 1
+                stats["slow_tick_excess_s"] += wall - median
+                wait, of, tick = lap["worst"]
+                rec = journal.account(
+                    "slow_tick", "serve/slow_tick", t0, t1,
+                    tick=stats["ticks"], wall_ms=round(wall * 1e3, 3),
+                    median_ms=round(median * 1e3, 3),
+                    read_wait_ms=round(lap["read"] * 1e3, 3),
+                    read_of=of, read_tick=tick,
+                    gc_ms=round((stats["gc_pause_s"] - gc0) * 1e3, 3),
+                    admit_ms=round((t_admitted - t0) * 1e3, 3),
+                    build_ms=round(lap["build"] * 1e3, 3),
+                    commit_ms=round(lap["commit"] * 1e3, 3),
+                    prefills=prefills, next_read_wait_ms=[])
+                self._slow_open.append(rec)
+                # said at once, with what is known: a stall in a run's last
+                # two ticks is on stderr all the same
+                journal.emit(
+                    f"[serve] slow tick {rec['tick']}: {rec['wall_ms']:.1f} "
+                    f"ms (median {rec['median_ms']:.1f}): read of {of} tick "
+                    f"{tick} waited {rec['read_wait_ms']:.1f}, gc "
+                    f"{rec['gc_ms']:.1f}, admit {rec['admit_ms']:.1f}, build "
+                    f"{rec['build_ms']:.1f}, commit {rec['commit_ms']:.1f}",
+                    stderr=True)
+        # a stalled wall enters the history too: one in 256 does not move a
+        # median, and a regime that stays slow becomes the median instead
+        # of a stall a tick for ever
+        self._walls.append(wall)
 
     def _tick_metrics(self, t0: float, tok0: int) -> None:
         made = self.stats["decode_tokens"] - tok0

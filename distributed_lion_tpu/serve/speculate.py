@@ -530,8 +530,8 @@ class Speculator:
             eng._guard("verify", rest)
             (draws, st), eng.pages = self._verify(
                 eng.params, eng.pages, *rest)
-            with span("serve/token_read"):
-                draws = np.asarray(draws)  # ONE host sync for the batch
+            # ONE host sync for the batch
+            draws = eng._host_read(draws, "verify", eng.stats["ticks"])
             eng._absorb_moe_stats(st)
 
         accepted_total = committed_total = 0

@@ -64,8 +64,9 @@ serialize/drain on the step thread; committer-thread spans carry
 ``thread="committer"``), and the loop's own host work between them
 (``retrace_check``, ``guard_apply``, ``sentinel_check``, ``membership``).
 The serving engine's tree hangs under ``serve/tick`` (serve/engine.py's
-module doc lists it); ``setup/*`` spans time construction. Everything
-else lands in the analyzer's ``other`` bucket.
+module doc lists it); ``setup/*`` records time construction and ``gc/*``
+the collector's pauses (both accounts, below: kept always, not gated).
+Everything else lands in the analyzer's ``other`` bucket.
 
 **One span primitive, gated by what is listening** (:func:`span`). Hot
 paths open every span through the module-level ``journal.span(name,
@@ -87,6 +88,35 @@ session starts is not in the buffer, one open when it ends is recorded
 whole, and a session is seen to have ended by the first span that opens
 after it (the loops open spans every step and tick).
 
+**Accounts: host time that is nobody's span, kept always** (:func:`account`,
+:func:`accounts`). The spans above are gated, and every end-to-end number
+is decided by runs in which nothing listens, so what a run needs in order
+to explain itself afterwards is recorded whether or not anything listens:
+a process-global bounded list of ``{kind, name, t0, t1, **fields}``
+(``time.monotonic`` seconds) that outlives the trainer and the engine,
+is NOT emptied when a profiler session begins (it spans set-up and the
+whole run) and counts what it pushes out (:func:`accounts_dropped`). An
+account also goes to the installed journal as a span record (``dur``,
+``account=<kind>``; a ``gc_pause`` or a ``slow_tick`` lies inside the span
+it interrupted, so the analyzer leaves it out of the step wall's sum).
+Three kinds, each with one producer:
+
+- ``setup_lap``: :class:`SetupLaps` (the trainer's and the engine's
+  construction, lap by lap, with ``owner``; before the first lap of a
+  process one ``setup/before`` from this module's import to the laps'
+  start: the imports after it, the backend's start, the caller's work).
+- ``gc_pause``: :func:`watch_gc`, one ``gc.callbacks`` hook. Every
+  collection is added to two running totals, count and seconds
+  (:func:`gc_totals`); a generation-2 collection, or any pause of
+  :data:`GC_LISTED_S` or more, is listed (``generation``, ``collected``).
+- ``slow_tick``: the serving engine, a decode-only tick that took several
+  times the median of the ticks before it (serve/engine.py's module doc).
+
+What is always on, then: the accounts, ``SetupLaps``' clock, the
+collector's hook, the compile ledger (utils/compile_cache) and the
+engine's per-tick stamps. What is gated: every :func:`span`, its profiler
+annotation and :func:`traced`.
+
 Layering: stdlib + ``train.resilience`` (itself pure stdlib) only — no
 jax, no numpy — so host-side consumers (``train/vote_guard``,
 ``data/native_loader``) stay importable without jax and the module can be
@@ -96,6 +126,7 @@ loaded by file path.
 from __future__ import annotations
 
 import collections
+import gc
 import itertools
 import json
 import math
@@ -226,6 +257,94 @@ def _record_traced(rec: dict) -> None:
         if len(_TRACED) == TRACED_MAX:
             _dropped += 1
         _TRACED.append(rec)
+
+
+# ------------------------------------------------------------- the accounts
+T_IMPORT = time.monotonic()   # where ``setup/before`` starts
+ACCOUNT_KINDS = ("setup_lap", "gc_pause", "slow_tick")
+ACCOUNTS_MAX = 4096           # accounts kept before the oldest drop out
+GC_LISTED_S = 1e-3            # a younger collection this long is listed
+
+# an RLock: the collector's hook runs wherever an allocation lands, also
+# inside account() itself on the same thread
+_ACC_LOCK = threading.RLock()
+_ACCOUNTS: collections.deque = collections.deque(maxlen=ACCOUNTS_MAX)
+_accounts_dropped = 0
+
+
+def account(kind: str, name: str, t0: float, t1: float, **fields) -> dict:
+    """Keep ``{kind, name, t0, t1, **fields}`` (module doc: always, with or
+    without a listener) and send the installed journal its span record.
+    Returns the kept record: its producer may fill a field in later (the
+    engine's ``next_read_wait_ms``)."""
+    global _accounts_dropped
+    if kind not in ACCOUNT_KINDS:
+        raise ValueError(f"unknown account kind {kind!r} "
+                         f"(one of {', '.join(ACCOUNT_KINDS)})")
+    rec = {"kind": kind, "name": name, "t0": t0, "t1": t1, **fields}
+    with _ACC_LOCK:
+        _accounts_dropped += len(_ACCOUNTS) == ACCOUNTS_MAX
+        _ACCOUNTS.append(rec)
+    if _ACTIVE is not None:
+        _ACTIVE.record({"kind": "span", "name": name,
+                        "dur": round(max(t1 - t0, 0.0), 9),
+                        "id": next(_IDS), "parent": None,
+                        "account": kind, **fields})
+    return rec
+
+
+def accounts(kind: Optional[str] = None, since: Optional[float] = None,
+             until: Optional[float] = None) -> list:
+    """The accounts kept, oldest first: of ``kind`` alone, and those that
+    lie wholly inside ``since``..``until`` (``time.monotonic`` seconds;
+    either end may be left open)."""
+    with _ACC_LOCK:
+        kept = list(_ACCOUNTS)
+    return [r for r in kept
+            if (kind is None or r["kind"] == kind)
+            and (since is None or r["t0"] >= since)
+            and (until is None or r["t1"] <= until)]
+
+
+def accounts_dropped() -> int:
+    """Accounts pushed out of the bounded list since the process began."""
+    return _accounts_dropped
+
+
+_GC = [0, 0.0]                 # collections and their seconds so far
+_gc_t0: Optional[float] = None
+
+
+def _on_gc(phase: str, info: dict) -> None:
+    global _gc_t0
+    if phase == "start":
+        _gc_t0 = time.monotonic()
+        return
+    t0, _gc_t0 = _gc_t0, None
+    if t0 is None:          # hooked in the middle of a collection
+        return
+    t1 = time.monotonic()
+    _GC[0] += 1
+    _GC[1] += t1 - t0
+    gen = info["generation"]
+    if gen == 2 or t1 - t0 >= GC_LISTED_S:
+        account("gc_pause", f"gc/gen{gen}", t0, t1, generation=gen,
+                collected=info["collected"])
+
+
+def watch_gc() -> None:
+    """Hook the collector (``gc.callbacks``), once: every collection from
+    here on is in :func:`gc_totals`, the long ones among :func:`accounts`."""
+    if _on_gc not in gc.callbacks:
+        gc.callbacks.append(_on_gc)
+
+
+def gc_totals() -> tuple:
+    """``(collections, seconds)`` of the watched collections so far, every
+    generation together: what a loop reads at both ends of a stretch. The
+    generations are told apart where it matters, in the ``gc_pause``
+    accounts (every full collection is listed)."""
+    return _GC[0], _GC[1]
 
 
 class Journal:
@@ -564,23 +683,27 @@ class SetupLaps:
     """Construction timed as consecutive laps: ``setup = SetupLaps("engine")``
     starts the clock, ``setup.lap("setup/init_pages")`` closes the lap
     that ran since the last one, ``setup.emit()`` prints the one
-    ``[setup]`` line. Timed whether or not anything listens (a dozen laps
-    a run, all before a profiler session can be on): each lap goes to the
-    ``[setup]`` line and, as a span record, to the installed journal; a
-    lap that raises records nothing."""
+    ``[setup]`` line. Always on (a dozen laps a run, all before a profiler
+    session can be on): each lap is a ``setup_lap`` account (``owner``,
+    absolute ``t0`` / ``t1``; :func:`account` hands the installed journal
+    its span record) and a part of the ``[setup]`` line; a lap that raises
+    records nothing. The first ``SetupLaps`` of a process also accounts for
+    what came before it, ``setup/before`` (module doc)."""
+
+    _before_said = False
 
     def __init__(self, owner: str):
         self.owner = owner
         self._t = time.monotonic()
         self._parts: list = []
+        if not SetupLaps._before_said:
+            SetupLaps._before_said = True
+            account("setup_lap", "setup/before", T_IMPORT, self._t,
+                    owner=owner)
 
     def lap(self, name: str) -> None:
         t0, self._t = self._t, time.monotonic()
-        if _ACTIVE is not None:
-            _ACTIVE.record({"kind": "span", "name": name,
-                            "dur": round(self._t - t0, 9),
-                            "id": next(_IDS), "parent": None,
-                            "owner": self.owner})
+        account("setup_lap", name, t0, self._t, owner=self.owner)
         self._parts.append(f"{name.split('/', 1)[-1]} {self._t - t0:.2f} s")
 
     def emit(self, *, stderr: bool = False) -> None:

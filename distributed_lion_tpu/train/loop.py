@@ -867,9 +867,11 @@ class Trainer:
         what :func:`apply_remat_policy` resolved ``auto`` to for the model
         inside ``loss_fn`` (None: nothing was resolved); said here, once the
         journal is up."""
-        # the span gate's profiler and the compile ledger's listeners: both
-        # idempotent, both needed before the first span / first compile
+        # the span gate's profiler, the collector's hook and the compile
+        # ledger's listeners: all idempotent, needed before the first span,
+        # the first long collection and the first compile
         journal.register_profiler(jax.profiler.TraceAnnotation)
+        journal.watch_gc()
         compile_cache.listen()
         setup = journal.SetupLaps("trainer")
         n_params = count_params(params)

@@ -32,8 +32,9 @@ compiling ``jit(step)``):
 
 Every event is kept with the ``time.monotonic()`` at which its phase ended
 (the last :data:`EVENTS_MAX`; older ones drop out of the sums and are
-counted), so ``ledger(until=t)`` / ``totals(until=t)`` tell what set-up
-paid from what compiled later in the process.
+counted: the ``[compile]`` lines say how many once there are any), so
+``ledger(until=t)`` / ``totals(until=t)`` tell what set-up paid from what
+compiled later in the process.
 """
 
 from __future__ import annotations
@@ -156,7 +157,6 @@ def totals(until: Optional[float] = None) -> dict:
         "compiles": sum(r["compiles"] for r in rows),
         "cache_hits": sum(r["cache_hits"] for r in rows),
         "cache_misses": sum(r["cache_misses"] for r in rows),
-        "events_dropped": _events_dropped,
     }
 
 
@@ -167,10 +167,12 @@ def ledger_lines(min_s: float = 1.0, only=None) -> list:
     rows = sorted(((r["trace_s"] + r["lower_s"] + r["compile_s"], name, r)
                    for name, r in ledger().items()
                    if only is None or name in only), reverse=True)
+    lost = (f"; {_events_dropped} older events dropped from the sums"
+            if _events_dropped else "")
     return [f"[compile] {name}: trace {r['trace_s']:.1f} s, lower "
             f"{r['lower_s']:.1f} s, compile or load {r['compile_s']:.1f} s "
             f"({r['compiles']} built; cache hits {r['cache_hits']}, misses "
-            f"{r['cache_misses']}, retrieval {r['retrieval_s']:.1f} s)"
+            f"{r['cache_misses']}, retrieval {r['retrieval_s']:.1f} s{lost})"
             for total, name, r in rows if total > min_s]
 
 
