@@ -39,7 +39,14 @@ VMEM scratch of ``group rows x d`` that lives through the whole group
 vocab tile. Neither accumulator is read-modify-written in HBM and
 ``dlogits`` never exists there. More rows than one group holds make more
 groups, each with its own partial head gradient ``[groups, V, d]`` that
-XLA sums (two groups at 20 x 1,024 rows, one at 4 x 1,024).
+XLA sums. Rows are padded to a whole row block and no further, in both
+directions: where the blocks do not fill the groups evenly the last group
+is short, and its missing grid steps fetch and compute nothing.
+
+The cut is taken from the call's shape (:func:`tiles_for`,
+:func:`row_groups`): at d 768, 1,024 x 512 tiles, two groups of ten blocks
+at 20 x 1,024 rows and one of four at 4 x 1,024; at d 2,304 (2 x 8,192
+rows, V 24,576), 512 x 512 tiles and four groups of eight blocks.
 
 Names on the device: ``fused_xent_fwd`` and ``fused_xent_bwd`` (``name=``
 and the innermost ``jax.named_scope``).
@@ -56,7 +63,13 @@ from jax.experimental.pallas import tpu as pltpu
 
 LANES = 128
 MASKED = -1e30            # a masked column's logit; also the running max's start
-DH_VMEM_BYTES = 32 << 20  # the float32 dh accumulator of one row group
+# the float32 dh accumulator of one row group: eight 512-row blocks at d 2,304
+# (37.7 MB: cell 10's 32 blocks are four full groups), twelve 1,024-row blocks
+# at d 768 (cell 1's twenty, 62.9 MB, stay two groups of ten)
+DH_VMEM_BYTES = 40 << 20
+# multiply-adds of one product in a backward grid step (tn x tv x d) up to
+# which the step ran at the MXU's pace: 512 x 512 x 2,304 (tiles_for)
+STEP_MACS = 512 * 512 * 2304
 
 _NT = (((1,), (1,)), ((), ()))   # a @ b^T
 _TN = (((0,), (0,)), ((), ()))   # a^T @ b
@@ -66,22 +79,52 @@ def _round_up(x: int, m: int) -> int:
     return -(-x // m) * m
 
 
-def tiles_for(n: int, v: int) -> tuple[int, int]:
-    """(rows a block, vocabulary entries a tile). On the chip at 20 x 1,024
-    rows, V 50,257, d 768 (forward / backward of one call; a product at the
-    MXU's peak is 8.03 ms, the backward holds three): 1024x512 10.06 /
-    25.20 ms, 1024x1024 10.21 / 25.22, 512x1024 10.53 / 25.45, 512x512
-    10.77 / 25.70, 2048x512 9.76 / 38.74, 1024x2048 10.10 / 34.30 (my chip
-    run, PR 29, ``scripts/xent_microbench.py``): flat between 512 and 1,024
-    either way, and past it the backward's ``[tv, tn]`` float32 temporaries
-    outgrow what the compiler keeps close."""
-    return (min(1024, _round_up(n, LANES)), min(512, _round_up(v, LANES)))
+def tiles_for(n: int, v: int, d: int) -> tuple[int, int]:
+    """(rows a block, vocabulary entries a tile): 1,024 x 512, the larger
+    of the two halved until a backward grid step's ``tn x tv x d`` is inside
+    :data:`STEP_MACS`. Forward / backward of one call on the chip
+    (``scripts/xent_microbench.py``; the backward holds three products).
+
+    20 x 1,024 rows, V 50,257, d 768, a product at the MXU's peak 8.03 ms
+    (my chip run, PR 29): 1024x512 10.06 / 25.20 ms, 1024x1024 10.21 /
+    25.22, 512x1024 10.53 / 25.45, 512x512 10.77 / 25.70, 2048x512 9.76 /
+    38.74, 1024x2048 10.10 / 34.30.
+
+    16,384 rows, V 24,576, d 2,304, a product at the peak 9.42 ms, groups x
+    row blocks a group after the tiles (my chip runs, PR 49): 512x512 4x8
+    10.98 / 29.82 (2x16 10.97 / 29.14), 1024x256 4x4 10.92 / 29.83, 512x256
+    4x8 11.31 / 30.23, 256x512 4x16 11.56 / 30.24, 1024x128 4x4 11.25 /
+    30.32, 256x256 4x16 12.82 / 31.18, 512x128 4x8 11.96 / 31.60; 1024x512
+    4x4 10.73 / 44.69 (2x8 43.90; 3+3+3+3+3+1 48.63; PR 48's 6x3 over 18
+    blocks, two of them padding, 12.21 / 51.11), 512x1024 4x8 10.89 /
+    47.74, 2048x256 4x2 10.71 / 48.37.
+
+    The forward is flat everywhere. The backward runs at 90-97% of the peak
+    up to a step of 0.6 G multiply-adds and at 58-64% from 1.2 G, at either
+    width and whichever of the three factors makes the step large (at d 768
+    1024x1024, 0.8 G, still held and 2048x512, 0.8 G, did not): a step's
+    float32 product results and ``[tv, tn]`` temporaries outgrow what the
+    compiler keeps close."""
+    tn, tv = min(1024, _round_up(n, LANES)), min(512, _round_up(v, LANES))
+
+    def halves(t):
+        return t % (2 * LANES) == 0
+
+    while tn * tv * d > STEP_MACS and (halves(tn) or halves(tv)):
+        if halves(tn) and (tn >= tv or not halves(tv)):
+            tn //= 2
+        else:
+            tv //= 2
+    return tn, tv
 
 
 def row_groups(n: int, d: int, tn: int) -> tuple[int, int]:
     """(groups, row blocks a group) for ``n`` rows: as few groups as keep a
-    group's float32 ``dh`` inside :data:`DH_VMEM_BYTES`, evenly filled (the
-    rows are padded to ``groups x blocks x tn``)."""
+    group's float32 ``dh`` inside :data:`DH_VMEM_BYTES`, evenly filled.
+    Where ``groups x blocks`` passes the ``cdiv(n, tn)`` row blocks there
+    are, the last group is short: the backward skips its missing steps
+    (nothing fetched, nothing computed), so no rows exist for the groups'
+    sake."""
     blocks = pl.cdiv(n, tn)
     groups = pl.cdiv(blocks, max(1, DH_VMEM_BYTES // (tn * d * 4)))
     return groups, pl.cdiv(blocks, groups)
@@ -158,17 +201,24 @@ def _fwd(h, w, valid_v: int, tiles, interpret: bool):
 
 # ---------------------------------------------------------------- backward
 def _bwd_kernel(w_ref, h_ref, lse_ref, lab_ref, g_ref, dw_ref, dh_ref,
-                acc_ref, *, valid_v: int):
+                acc_ref, *, valid_v: int, blocks: int | None):
+    """``blocks``: the row blocks there are, where the last group is short
+    of them (None: every group is full and no step asks)."""
     tv, tn = w_ref.shape[0], h_ref.shape[0]
     j, i = pl.program_id(1), pl.program_id(2)
     last = pl.num_programs(1) - 1
     rows = pl.ds(pl.multiple_of(i * tn, tn), tn)
 
+    def live(cond):
+        if blocks is None:
+            return cond
+        return cond & (pl.program_id(0) * pl.num_programs(2) + i < blocks)
+
     @pl.when(i == 0)
     def _():
         dw_ref[...] = jnp.zeros_like(dw_ref)
 
-    @pl.when(j == 0)
+    @pl.when(live(j == 0))
     def _():
         acc_ref[rows, :] = jnp.zeros((tn, acc_ref.shape[1]), jnp.float32)
 
@@ -190,9 +240,9 @@ def _bwd_kernel(w_ref, h_ref, lse_ref, lab_ref, g_ref, dw_ref, dh_ref,
         acc_ref[rows, :] += jax.lax.dot_general(
             dl, w, _TN, preferred_element_type=jnp.float32)
 
-    pl.when(j < last)(lambda: tile(False))
+    pl.when(live(j < last))(lambda: tile(False))
 
-    @pl.when(j == last)
+    @pl.when(live(j == last))
     def _():
         tile(True)
         dh_ref[...] = acc_ref[rows, :].astype(dh_ref.dtype)
@@ -203,28 +253,32 @@ def _bwd(h, w, lse, labels, g, valid_v: int, tiles, interpret: bool):
     the rows of the head the vocabulary's tiles cover)."""
     (n, d), (tn, tv) = h.shape, tiles
     groups, per_group = row_groups(n, d, tn)
-    nj = pl.cdiv(valid_v, tv)
-    assert n == groups * per_group * tn, (n, groups, per_group, tn)
+    nj, blocks = pl.cdiv(valid_v, tv), n // tn
+    assert n == blocks * tn, (n, tn)
+    short = groups * per_group > blocks
 
-    def block(s, j, i):
-        return s * per_group + i
+    def there(block):
+        # a short last group's missing steps stay on the last block there
+        # is: an index that does not move fetches and writes back nothing
+        return jnp.minimum(block, blocks - 1) if short else block
 
-    stat = pl.BlockSpec((1, tn), lambda s, j, i: (0, block(s, j, i)))
+    stat = pl.BlockSpec((1, tn), lambda s, j, i: (0, there(s * per_group + i)))
     with jax.named_scope("fused_xent_bwd"):
         dw, dh = pl.pallas_call(
-            functools.partial(_bwd_kernel, valid_v=valid_v),
+            functools.partial(_bwd_kernel, valid_v=valid_v,
+                              blocks=blocks if short else None),
             grid=(groups, nj, per_group),
             in_specs=[pl.BlockSpec((tv, d), lambda s, j, i: (j, 0)),
-                      pl.BlockSpec((tn, d),
-                                   lambda s, j, i: (block(s, j, i), 0)),
+                      pl.BlockSpec((tn, d), lambda s, j, i: (
+                          there(s * per_group + i), 0)),
                       stat, stat, stat],
             out_specs=[
                 pl.BlockSpec((None, tv, d), lambda s, j, i: (s, j, 0)),
                 # dh's block is written during the last vocab tile only:
                 # until then the index stays on the group's first block,
                 # which is not written back before its own last-tile step
-                pl.BlockSpec((tn, d), lambda s, j, i: (
-                    s * per_group + jnp.where(j == nj - 1, i, 0), 0)),
+                pl.BlockSpec((tn, d), lambda s, j, i: (there(
+                    s * per_group + jnp.where(j == nj - 1, i, 0)), 0)),
             ],
             out_shape=[
                 jax.ShapeDtypeStruct((groups, min(w.shape[0], nj * tv), d),
@@ -267,9 +321,8 @@ def _padded(hidden, wte, labels, valid_v, tiles):
     valid_v = valid_v if valid_v > 0 else v
     if valid_v > v:
         raise ValueError(f"valid_v {valid_v} > head rows {v}")
-    tn, tv = tiles or tiles_for(n, valid_v)
-    groups, per_group = row_groups(n, hidden.shape[1], tn)
-    pad = groups * per_group * tn - n
+    tn, tv = tiles or tiles_for(n, valid_v, hidden.shape[1])
+    pad = _round_up(n, tn) - n
     return (jnp.pad(hidden, ((0, pad), (0, 0))),
             jnp.pad(labels.astype(jnp.int32), (0, pad))[None, :],
             valid_v, (tn, tv))

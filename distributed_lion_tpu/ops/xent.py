@@ -332,19 +332,27 @@ def _fused_clm_loss_and_metrics(hidden, head, tokens, loss_mask, valid_v,
     ``hidden[:, :-1]`` is a copy); a sequence's last position has no label
     and weighs nothing. ``tiles`` and ``interpret`` are the tests' (small
     tiles through the Pallas interpreter on the CPU)."""
-    from distributed_lion_tpu.ops.pallas_xent import fused_xent, tiles_for
+    from distributed_lion_tpu.ops.pallas_xent import (
+        fused_xent,
+        row_groups,
+        tiles_for,
+    )
     from distributed_lion_tpu.train import journal
 
     b, t, d = hidden.shape
     v = valid_v if valid_v > 0 else head.shape[0]
-    tiles = tiles or tiles_for(b * t, v)
+    tiles = tiles or tiles_for(b * t, v, d)
+    groups, per_group = row_groups(b * t, d, tiles[0])
+    pad_rows = -(b * t) % tiles[0]
     name = jnp.dtype(hidden.dtype).name
     journal.resolved(
         "xent_resolved",
         f"[setup] cross-entropy: tied head auto -> pallas_fused_xent (rows "
-        f"{b * t}, vocab {v}, d {d}, {name}, tiles %dx%d)" % tiles,
+        f"{b * t}, vocab {v}, d {d}, {name}, tiles %dx%d, {groups} groups of "
+        f"{per_group} row blocks, pad rows {pad_rows})" % tiles,
         impl="pallas_fused_xent", rows=b * t, vocab=v, d=d, dtype=name,
-        tiles="%dx%d" % tiles)
+        tiles="%dx%d" % tiles, groups=groups, blocks_per_group=per_group,
+        pad_rows=pad_rows)
     last = jnp.zeros((b, 1), jnp.float32)
     labels = jnp.concatenate(
         [tokens[:, 1:], last.astype(tokens.dtype)], axis=1).reshape(-1)

@@ -1,17 +1,18 @@
 """Time the loss head's kernel pair alone on the chip at a train cell's
-shape: ``python scripts/xent_microbench.py [sequences] [tnxtv[xgroupMB]...]``.
+shape: ``python scripts/xent_microbench.py [--rows N] [--vocab V] [--d D]
+[tnxtv[xgroupMB]...]`` (the defaults are cell 1's: 20 x 1,024 rows against
+GPT-2's head; cell 10's are ``--rows 16384 --vocab 24576 --d 2304``).
 Forward and forward + backward (a vjp with a given cotangent: kernels and
 the label's gathered product only) against the dense einsum + log_softmax
-at the same rows, and the error against it. A chip-only tool."""
-import sys
+at the same rows, and the error against it; beside each timing the cut it
+ran (groups x row blocks a group, pad rows). A chip-only tool."""
+import argparse
 import time
 
 import jax
 import jax.numpy as jnp
 
 from distributed_lion_tpu.ops import pallas_xent as X
-
-V, D, T = 50257, 768, 1024
 
 
 def dense(h, w, labels):
@@ -39,9 +40,14 @@ def rel(a, b):
 def main():
     if jax.default_backend() != "tpu":
         raise SystemExit("xent_microbench needs a TPU")
-    B = int(sys.argv[1]) if len(sys.argv) > 1 else 20
-    specs = sys.argv[2:] or ["x".join(map(str, X.tiles_for(B * T, V)))]
-    n = B * T
+    ap = argparse.ArgumentParser(description=__doc__.split(":")[0])
+    ap.add_argument("--rows", type=int, default=20 * 1024)
+    ap.add_argument("--vocab", type=int, default=50257)
+    ap.add_argument("--d", type=int, default=768)
+    ap.add_argument("specs", nargs="*", metavar="tnxtv[xgroupMB]")
+    args = ap.parse_args()
+    n, V, D = args.rows, args.vocab, args.d
+    specs = args.specs or ["x".join(map(str, X.tiles_for(n, V, D)))]
     h = jax.random.normal(jax.random.key(0), (n, D), jnp.bfloat16)
     w = (jax.random.normal(jax.random.key(1), (V, D)) * 0.05
          ).astype(jnp.bfloat16)
@@ -53,27 +59,33 @@ def main():
         return jax.jit(lambda h, w, g: jax.vjp(
             lambda h, w: fn(h, w)[0], h, w)[1](g))
 
-    ref = lambda h, w: dense(h, w, labels)                 # noqa: E731
-    f0 = timed(jax.jit(ref), h, w)
-    fb0 = timed(vjp_of(ref), h, w, g)
-    print(f"rows {n}: dense fwd {f0:.2f} ms, fwd+bwd {fb0:.2f} ms; one "
-          f"product at 197 TFLOP/s {flop / 197e9:.2f} ms", flush=True)
-    want, want_idx = jax.jit(ref)(h, w)
-    want_dh, want_dw = vjp_of(ref)(h, w, g)
+    ref = jax.jit(lambda h, w: dense(h, w, labels))
+    ref_vjp = vjp_of(ref)
+    f0 = timed(ref, h, w)
+    fb0 = timed(ref_vjp, h, w, g)
+    print(f"rows {n}, vocab {V}, d {D}: dense fwd {f0:.2f} ms, fwd+bwd "
+          f"{fb0:.2f} ms; one product at 197 TFLOP/s {flop / 197e9:.2f} ms",
+          flush=True)
+    want, want_idx = ref(h, w)
+    want_dh, want_dw = ref_vjp(h, w, g)
+    budget = X.DH_VMEM_BYTES
     for spec in specs:
         tn, tv, *mb = (int(x) for x in spec.split("x"))
-        X.DH_VMEM_BYTES = (mb[0] if mb else 32) << 20
-        mine = lambda h, w: X.fused_xent(h, w, labels, 0, (tn, tv))  # noqa: E731
+        X.DH_VMEM_BYTES = mb[0] << 20 if mb else budget
+        groups, per_group = X.row_groups(n, D, tn)
+        mine = jax.jit(lambda h, w: X.fused_xent(h, w, labels, 0, (tn, tv)))
+        mine_vjp = vjp_of(mine)
         try:
-            f = timed(jax.jit(mine), h, w)
-            fb = timed(vjp_of(mine), h, w, g)
+            f = timed(mine, h, w)
+            fb = timed(mine_vjp, h, w, g)
         except Exception as e:  # a refused tile pair: say so, try the next
             print(f"tiles {spec}: {type(e).__name__}: {str(e)[:300]}",
                   flush=True)
             continue
-        got, idx = jax.jit(mine)(h, w)
-        dh, dw = vjp_of(mine)(h, w, g)
-        print(f"tiles {spec} (groups {X.row_groups(n, D, tn)[0]}): fwd "
+        got, idx = mine(h, w)
+        dh, dw = mine_vjp(h, w, g)
+        print(f"tiles {spec} (groups {groups} x {per_group} blocks for "
+              f"{-(-n // tn)}, pad rows {-n % tn}): fwd "
               f"{f:.2f} ms ({flop / f / 1.97e9:.1f}% of peak), fwd+bwd "
               f"{fb:.2f} ms (bwd {fb - f:.2f}: {3 * flop / (fb - f) / 1.97e9:.1f}%"
               f" of peak over its 3 products); nll max err "

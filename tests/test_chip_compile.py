@@ -429,15 +429,21 @@ def test_train_blocks_copy_no_attention_activation(train_blocks_hlo, B):
 XENT_KERNELS = r"fused_xent"
 
 
-@pytest.mark.parametrize("rows,valid_v", [(20 * 1024, 0), (4 * 1024, 0),
-                                          (3 * 1023, 50257)],
-                         ids=["readme-20x8", "vote-4x2", "ragged-padded"])
-def test_fused_xent_kernels_compile(one_chip, rows, valid_v):
-    """The kernel pair alone at both training cells' rows against the
-    published head ``[50257, 768]`` as it lies (the last vocabulary tile a
-    partial block), and at rows that are no multiple of a block under a
-    head padded to 50,304 rows: Mosaic takes the tiles, the 31.5 MB float32
-    ``dh`` scratch and the index maps."""
+@pytest.mark.parametrize("rows,heads,d,valid_v", [
+    (20 * 1024, 50257, 768, 0), (4 * 1024, 50257, 768, 0),
+    (3 * 1023, 50304, 768, 50257), (2 * 8192, 24576, 2304, 0),
+    (2 * 8192 + 27, 24576, 2304, 0),
+], ids=["readme-20x8", "vote-4x2", "ragged-padded", "share-2x2-8k",
+        "share-ragged-short-group"])
+def test_fused_xent_kernels_compile(one_chip, rows, heads, d, valid_v):
+    """The kernel pair alone at the training cells' rows against their
+    heads as they lie (GPT-2's ``[50257, 768]``, the last vocabulary tile a
+    partial block; cell 10's ``[24576, 2304]`` at 512 x 512 tiles, four
+    groups of eight blocks), at rows that are no multiple of a block under
+    a head padded to 50,304 rows, and at cell 10's width with rows that
+    leave the last group short (33 blocks in five groups of seven): Mosaic
+    takes the tiles, the 31.5 / 37.7 MB float32 ``dh`` scratch, the
+    skipped steps and the index maps."""
     from distributed_lion_tpu.ops import pallas_xent
 
     def s(shape, dt):
@@ -449,8 +455,8 @@ def test_fused_xent_kernels_compile(one_chip, rows, valid_v):
             return (nll * g).sum()
         return jax.grad(loss, argnums=(0, 1))(h, w)
 
-    text, _ = _compile(grads, s((rows, 768), jnp.bfloat16),
-                       s((50304 if valid_v else 50257, 768), jnp.bfloat16),
+    text, _ = _compile(grads, s((rows, d), jnp.bfloat16),
+                       s((heads, d), jnp.bfloat16),
                        s((rows,), jnp.int32), s((rows,), jnp.float32))
     for kernel in ("fused_xent_fwd", "fused_xent_bwd"):
         assert _named_custom_call(text, kernel), kernel

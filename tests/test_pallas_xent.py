@@ -151,27 +151,77 @@ def test_a_tie_between_two_logits_takes_the_first_index(pair):
     assert (np.asarray(idx) == lo).sum() > 20      # the tie decided rows
 
 
+def _computed_blocks(n, d, tn):
+    """Row blocks the backward's grid computes: a short last group's
+    missing steps are skipped, so never more than the blocks there are."""
+    groups, per_group = PX.row_groups(n, d, tn)
+    blocks = -(-n // tn)
+    assert (groups - 1) * per_group < blocks <= groups * per_group
+    return blocks
+
+
 def test_tiles_and_row_groups():
-    assert PX.tiles_for(20 * 1024, 50257) == (1024, 512)
-    assert PX.tiles_for(4 * 1024, 50257) == (1024, 512)
-    assert PX.tiles_for(150, 100) == (256, 128)
+    assert PX.tiles_for(20 * 1024, 50257, 768) == (1024, 512)
+    assert PX.tiles_for(4 * 1024, 50257, 768) == (1024, 512)
+    assert PX.tiles_for(150, 100, 768) == (256, 128)
     # 20 x 1,024 rows of 768 float32: 63 MB of dh in two groups of ten
     # blocks; 4 x 1,024 in one
     assert PX.row_groups(20 * 1024, 768, 1024) == (2, 10)
     assert PX.row_groups(4 * 1024, 768, 1024) == (1, 4)
     assert PX.row_groups(150, 128, 128) == (1, 2)
+    # cell 10: 2 x 8,192 rows of 2,304 against V 24,576. A 1,024 x 512 step
+    # is three times cell 1's: the row block halves, 32 blocks of 4.7 MB of
+    # dh fill four groups of eight, and no row is padding
+    tn, tv = PX.tiles_for(2 * 8192, 24576, 2304)
+    assert (tn, tv) == (512, 512) and tn * tv * 2304 <= PX.STEP_MACS
+    groups, per_group = PX.row_groups(2 * 8192, 2304, tn)
+    assert (groups, per_group) == (4, 8) and groups <= 6
+    assert groups * per_group * tn == 2 * 8192 and -(2 * 8192) % tn == 0
+    assert per_group * tn * 2304 * 4 <= PX.DH_VMEM_BYTES
+    # a prime row count at that width: 33 blocks in five groups of seven,
+    # the last of five; 33 computed, the 35 slots' last two skipped, and
+    # the rows padded to their own last block alone
+    n = 16411
+    tn, _ = PX.tiles_for(n, 24576, 2304)
+    assert PX.row_groups(n, 2304, tn) == (5, 7)
+    assert _computed_blocks(n, 2304, tn) == 33 and 33 * tn - n == 485
+    # the rule halves the larger tile, and only down to whole lanes
+    assert PX.tiles_for(2 * 8192, 24576, 4096) == (256, 512)
+    assert PX.tiles_for(384, 24576, 8192) == (384, 128)
 
 
-def test_more_rows_than_one_group_holds(monkeypatch):
-    """Three row groups (the float32 dh of one block each), the last padded:
-    partial head gradients a group, summed."""
-    monkeypatch.setattr(PX, "DH_VMEM_BYTES", 128 * 128 * 4)
-    hidden, head, tokens, _, _ = _case(1, 300, 200, 128, jnp.float32)
-    assert PX.row_groups(300, 128, 128) == (3, 1)
-    g = jax.grad(lambda h, w: _fused(h, w, tokens, None, 0)[0], (0, 1))
-    g0 = jax.grad(lambda h, w: _dense(h, w, tokens, None, 0)[0], (0, 1))
-    for a, b in zip(g(hidden, head), g0(hidden, head)):
+@pytest.mark.parametrize("rows,cut", [(512, (2, 2)), (640, (3, 2)),
+                                      (700, (2, 3))],
+                         ids=["even_split", "short_last_group",
+                              "ragged_rows"])
+def test_more_rows_than_one_group_holds(monkeypatch, rows, cut):
+    """More row groups than one (the float32 dh of two or three 128-row
+    blocks each): four blocks in two full groups; five in three groups, the
+    last one block short (its missing step skipped); 700 rows, no multiple
+    of the row block, padded to six blocks and no further. Partial head
+    gradients a group, summed; a masked row's gradient is zero."""
+    monkeypatch.setattr(PX, "DH_VMEM_BYTES", cut[1] * 128 * 128 * 4)
+    hidden, head, tokens, _, _ = _case(1, rows, 200, 128, jnp.float32)
+    assert PX.row_groups(rows, 128, 128) == cut
+    assert _computed_blocks(rows, 128, 128) == -(-rows // 128)
+    mask = np.ones((1, rows), np.float32)
+    mask[0, rows - 130:rows - 3] = 0.0      # across the last two blocks
+    mask = jnp.asarray(mask)
+
+    def both(fn):
+        (loss, m), g = jax.value_and_grad(
+            lambda h, w: fn(h, w, tokens, mask, 0), (0, 1),
+            has_aux=True)(hidden, head)
+        return loss, m["accuracy"], m["n_tokens"], *g
+
+    got, want = both(_fused), both(_dense)
+    np.testing.assert_allclose(got[0], want[0], rtol=2e-6)
+    assert got[1:3] == want[1:3]
+    for a, b in zip(got[3:], want[3:]):
         np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-7)
+    dh = np.asarray(got[3])[0]
+    assert not dh[rows - 131:rows - 4].any()     # their labels are masked
+    assert dh[:rows - 131].any(axis=-1).all()
 
 
 # ------------------------------------------------------------ the entry's rule
@@ -309,10 +359,15 @@ def test_on_a_tpu_backend_the_entry_takes_the_kernels_and_says_so(monkeypatch,
     assert [(e["impl"], e["rows"], e["vocab"], e["d"], e["dtype"])
             for e in events] == [("pallas_fused_xent", 128, 333, 128,
                                   "bfloat16")]
+    # which cut it took: one group of one 128-row block, no row padding
+    assert [(e["groups"], e["blocks_per_group"], e["pad_rows"])
+            for e in events] == [(1, 1, 0)]
     lines = journal.new_resolved_lines()
     assert any(line.startswith(
         "[setup] cross-entropy: tied head auto -> pallas_fused_xent "
         "(rows 128, vocab 333, d 128, bfloat16, tiles ") for line in lines)
+    assert any(line.endswith("1 groups of 1 row blocks, pad rows 0)")
+               for line in lines)
     assert journal.new_resolved_lines() == []    # said once
 
 
